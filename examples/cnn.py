@@ -63,8 +63,8 @@ def main():
 
     data = load_dataset(args.dataset, root=cfg.data_dir)
     if data["synthetic"] and args.dataset != "synthetic":
-        print(f"# no local {args.dataset} data under {cfg.data_dir}; "
-              "using the synthetic fallback")
+        print(f"# no local {args.dataset} data under {cfg.data_dir}: "
+              'training on the synthetic substitute ("synthetic": true)')
 
     optimizer = get_optimizer("adam", learning_rate=args.learning_rate)
     trainer = Trainer(get_model(args.model), topo, optimizer,

@@ -76,10 +76,7 @@ def default_on_tpu(env_var: str) -> bool:
     # graftlint: disable=GXL006 — build-time gate
     if os.environ.get(env_var) == "0":
         return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 class NoCompressor(Compressor):

@@ -134,8 +134,15 @@ def _synthetic(shape: Tuple[int, int, int], num_classes: int = 10,
 
 def load_dataset(name: str = "cifar10", root: str = "/root/data",
                  synthetic_fallback: bool = True,
-                 synthetic_train_n: int = 4096):
+                 synthetic_train_n: int = 4096, seed: int = 42):
     """Returns dict(train_x[u8 NHWC], train_y[i32], test_x, test_y, synthetic).
+
+    ``"synthetic"`` asked for by name is the seeded class-conditional
+    set.  A NAMED dataset with no local files is replaced by it only
+    under ``synthetic_fallback`` — with a ``UserWarning``, and
+    ``synthetic: True`` in the result, which callers that print or
+    record results must carry along: a run on substitute data is never
+    reported under the real dataset's name.
 
     Normalization to [0,1] floats happens in the loader/step, keeping the
     host->device transfer at 1 byte/pixel.
@@ -151,9 +158,16 @@ def load_dataset(name: str = "cifar10", root: str = "/root/data",
         loaded = _load_cifar10(os.path.join(root, name)) or _load_cifar10(root)
     synthetic = loaded is None
     if synthetic:
-        if name != "synthetic" and not synthetic_fallback:
-            raise FileNotFoundError(f"No local data for {name} under {root}")
-        loaded = _synthetic(shape, train_n=synthetic_train_n)
+        if name != "synthetic":
+            if not synthetic_fallback:
+                raise FileNotFoundError(
+                    f"No local data for {name} under {root}")
+            import warnings
+            warnings.warn(
+                f"no local {name} data under {root}: substituting the "
+                "seeded synthetic set (result carries synthetic=True)",
+                UserWarning, stacklevel=2)
+        loaded = _synthetic(shape, train_n=synthetic_train_n, seed=seed)
     xs, ys, xt, yt = loaded
     return {"train_x": xs, "train_y": ys, "test_x": xt, "test_y": yt,
             "synthetic": synthetic, "shape": shape}

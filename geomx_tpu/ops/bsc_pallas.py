@@ -1,8 +1,8 @@
 """Fused Bi-Sparse (BSC) compression Pallas kernels.
 
-Two kernels replace the dc-tier sparse hot path that BENCH_CAPTURED_r05
-showed inverting the compression win on chip (bsc 14.10 ms/step vs
-vanilla 13.64 ms despite 32x fewer wire bytes):
+Two kernels replace the dc-tier sparse hot path that a builder's capture
+(BENCH_CAPTURED_r05) showed costing more chip time than the wire bytes
+it saved:
 
 ``bsc_select_pack``
     One fused pass over the gradient bucket that computes the DGC-style
@@ -30,12 +30,14 @@ primary count before any tie's slot is known, so the kernel runs a
 primary runs while accumulating the primary count in SMEM, pass 1 emits
 the tie runs offset by that total.  Within a block, element ranks come
 from matmul prefix-sums (lane-triangular [128,128] + row-triangular
-[8,8] — Mosaic has no native cumsum) and the kept elements compact into
-a contiguous run via a one-hot [1024,128] matmul per row; the run lands
-in the output at its dynamic global offset via an async copy.  Because
-every block's emitted ranks are consecutive, runs tile the output
-exactly; slots no run covers keep the sentinel fill they were
-initialized with (``input_output_aliases``).
+[8,8] — Mosaic has no native cumsum).  The (value, index) outputs are
+lane-dense [rows, 128] slabs that stay VMEM-resident across the grid (a
+[k, 1] column cannot be sliced at an element offset on the chip: TPU
+refs are whole (8, 128) tiles), and each block's kept elements are
+placed straight into slab coordinates by two one-hot matmuls per row.
+Because every block's emitted ranks are consecutive, runs tile the
+output exactly; slots no run covers keep the sentinel pair the slabs
+are initialized with at the first grid step.
 
 Wire-format stability: the fused kernel and the jnp reference emit
 byte-identical payloads (primaries in ascending index order, then ties,
@@ -43,11 +45,10 @@ then -1/0.0 sentinel padding), so parties may mix fused and unfused
 paths in one job and checkpointed error-feedback state is
 interchangeable between them.
 
-VMEM budget per grid step: 3 input + 2 output [8,128] fp32 blocks
-(~20 KB), the [1024,128] one-hot (512 KB, transient), two [1024,1] run
-staging buffers (~1 MB physical after lane padding), and the [kpad,1]
-outputs live in HBM — comfortably inside the 16 MB scoped-vmem limit
-for any bucket size.
+VMEM budget: 3 input + 2 output [8,128] fp32 blocks per grid step
+(~20 KB), a few [128,128] / [16,128] one-hots, and the two resident
+output slabs — 8 x (k + 2048) bytes, double-buffered, which bounds k
+(``MAX_FUSED_K``); above it the kernel raises.
 
 Index arithmetic is int32 throughout: buckets are limited to 2**31-1
 elements (the bucketing default is 1 Mi elements per bucket).
@@ -65,6 +66,8 @@ MOMENTUM = 0.9  # gc.cc:200 — must match compression/bisparse.py
 _LANES = 128
 _BLK_ROWS = 8                      # one fp32 tile of rows per grid step
 _BLK = _BLK_ROWS * _LANES          # 1024 elements per grid step
+_WIN_ROWS = 2 * _BLK_ROWS           # output rows one emitted run can touch
+MAX_FUSED_K = 1 << 19               # output pairs held in VMEM (2 x 2 MiB)
 _CHUNK = 512                       # (value, index) pairs per decompress step
 _OUT_ROWS = 128                    # dense output rows per decompress block
 
@@ -119,16 +122,13 @@ def _ex_cumsum_flat(mask):
     return (ex_lane + ex_row).astype(jnp.int32)
 
 
-def _select_kernel(k, n, g_ref, u_ref, v_ref, thr_ref, vals_seed, idx_seed,
-                   newu_ref, newv_ref, vals_ref, idx_ref,
-                   cnt, run_val, run_idx, sems):
+def _select_kernel(k, n, g_ref, u_ref, v_ref, thr_ref,
+                   newu_ref, newv_ref, vals_ref, idx_ref, cnt):
     """Grid (2, nblocks): pass 0 emits primary (> thr) runs, pass 1 emits
     tie (== thr) runs and the final error-feedback zeroing.  SMEM ``cnt``:
     [0] = running primary count (pass 0; frozen total during pass 1),
     [1] = pass-1 primary re-count, [2] = running tie count."""
     import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    del vals_seed, idx_seed  # aliased into vals_ref/idx_ref (sentinel fill)
 
     pas = pl.program_id(0)
     blk = pl.program_id(1)
@@ -152,38 +152,48 @@ def _select_kernel(k, n, g_ref, u_ref, v_ref, thr_ref, vals_seed, idx_seed,
 
     def emit(emit_mask, rank_local, start):
         """Compact the block's emitted class (local ranks are consecutive
-        from 0) into a (value, index) run and copy it to output slots
-        [start, start+_BLK).  Slots past the run's true length carry the
-        sentinel pair (0.0, -1); the next block's run overwrites exactly
-        the non-sentinel prefix it owns, so the final tail stays
-        sentinel without a separate fill pass."""
-        erank = jnp.where(emit_mask, rank_local, -1)
-        slot = jax.lax.broadcasted_iota(jnp.int32, (_BLK, _LANES), 0)
-        accv = jnp.zeros((_BLK, 1), jnp.float32)
-        acci = jnp.zeros((_BLK, 1), jnp.float32)
+        from 0) into the (value, index) run that owns output slots
+        [start, start + count).  The outputs stay VMEM-resident as
+        lane-dense [rows, 128] slabs, so the run is built directly in
+        slab coordinates — target slot t -> (t // 128, t % 128) within
+        the 16-row window that starts at the 8-row tile holding ``start``
+        — by two one-hot matmuls per block row, and merged into the
+        window where a slot was hit.  Slots no run hits keep the
+        sentinel pair the slabs were initialized with."""
+        off = jnp.minimum(start, k)  # blocks past k emit nothing: park
+        row0 = pl.multiple_of(off // _BLK * _BLK_ROWS, _BLK_ROWS)
+        t = jnp.where(emit_mask, off % _BLK + rank_local, -1)
+        trow, tcol = t >> 7, t & (_LANES - 1)  # not emitted: row -1
+        win_row = jax.lax.broadcasted_iota(jnp.int32, (_WIN_ROWS, _LANES), 0)
+        lane_col = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+        accv = jnp.zeros((_WIN_ROWS, _LANES), jnp.float32)
+        acci = jnp.zeros((_WIN_ROWS, _LANES), jnp.float32)
         for r in range(_BLK_ROWS):
-            onehot = (slot == erank[r:r + 1, :]).astype(jnp.float32)
-            accv = accv + jax.lax.dot_general(
-                onehot, v2[r:r + 1, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            in_row = win_row == trow[r:r + 1, :]
+            in_col = (lane_col == tcol[r:r + 1, :]).astype(jnp.float32)
             # local flat index payload, +1 so "no hit" (0) maps to -1
             loc = (jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
                    + (r * _LANES + 1)).astype(jnp.float32)
-            acci = acci + jax.lax.dot_general(
-                onehot, loc, (((1,), (1,)), ((), ())),
+            # [16, e] x [128, e] contracted over the row's 128 elements e
+            accv = accv + jax.lax.dot_general(
+                jnp.where(in_row, v2[r:r + 1, :], 0.0), in_col,
+                (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32)
-        run_val[:] = accv
+            acci = acci + jax.lax.dot_general(
+                jnp.where(in_row, loc, 0.0), in_col,
+                (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
         ai = acci.astype(jnp.int32)
-        run_idx[:] = jnp.where(ai > 0, base + ai - 1, -1)
-        off = jnp.minimum(start, k)  # blocks past k park on the pad region
-        cv = pltpu.make_async_copy(
-            run_val, vals_ref.at[pl.ds(off, _BLK), :], sems.at[0])
-        ci = pltpu.make_async_copy(
-            run_idx, idx_ref.at[pl.ds(off, _BLK), :], sems.at[1])
-        cv.start()
-        ci.start()
-        cv.wait()
-        ci.wait()
+        win = pl.ds(row0, _WIN_ROWS)
+        vals_ref[win, :] = jnp.where(ai > 0, accv, vals_ref[win, :])
+        idx_ref[win, :] = jnp.where(ai > 0, base + ai - 1, idx_ref[win, :])
+
+    @pl.when((pas == 0) & (blk == 0))
+    def _init_outputs():
+        vals_ref[:] = jnp.zeros_like(vals_ref)
+        idx_ref[:] = jnp.full_like(idx_ref, -1)
 
     @pl.when((pas == 0) & (blk == 0))
     def _reset_primary_count():
@@ -245,8 +255,16 @@ def bsc_select_pack(g: jax.Array, u: jax.Array, v: jax.Array,
             x = jnp.concatenate([x, jnp.zeros((pad,), jnp.float32)])
         return x.reshape(rowsp, _LANES)
 
-    kpad = k + _BLK
+    # a run starts anywhere in the 8-row tile holding its first slot and
+    # is at most one block long: the 16-row window always fits
+    krows = (k // _BLK) * _BLK_ROWS + _WIN_ROWS
+    if krows * _LANES > MAX_FUSED_K:
+        raise ValueError(
+            f"bsc_select_pack keeps its k={k} output pairs VMEM-resident "
+            f"and takes k up to {MAX_FUSED_K - _WIN_ROWS * _LANES}; lower "
+            "the bucket size or the ratio, or set GEOMX_FUSED_KERNELS=0")
     blk_spec = pl.BlockSpec((_BLK_ROWS, _LANES), lambda p, b: (b, 0))
+    out_spec = pl.BlockSpec((krows, _LANES), lambda p, b: (0, 0))
     newu, newv, vals, idx = pl.pallas_call(
         functools.partial(_select_kernel, k, n),
         grid=(2, rowsp // _BLK_ROWS),
@@ -254,32 +272,19 @@ def bsc_select_pack(g: jax.Array, u: jax.Array, v: jax.Array,
             blk_spec, blk_spec, blk_spec,                       # g, u, v
             pl.BlockSpec((1, 1), lambda p, b: (0, 0),
                          memory_space=pltpu.SMEM),              # threshold
-            pl.BlockSpec(memory_space=pltpu.ANY),               # vals seed
-            pl.BlockSpec(memory_space=pltpu.ANY),               # idx seed
         ],
-        out_specs=(
-            blk_spec, blk_spec,                                 # new u, v
-            pl.BlockSpec(memory_space=pltpu.ANY),               # vals
-            pl.BlockSpec(memory_space=pltpu.ANY),               # idx
-        ),
+        out_specs=(blk_spec, blk_spec,                          # new u, v
+                   out_spec, out_spec),                         # vals, idx
         out_shape=(
             jax.ShapeDtypeStruct((rowsp, _LANES), jnp.float32),
             jax.ShapeDtypeStruct((rowsp, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((kpad, 1), jnp.float32),
-            jax.ShapeDtypeStruct((kpad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((krows, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((krows, _LANES), jnp.int32),
         ),
-        scratch_shapes=[
-            pltpu.SMEM((4,), jnp.int32),
-            pltpu.VMEM((_BLK, 1), jnp.float32),
-            pltpu.VMEM((_BLK, 1), jnp.int32),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        input_output_aliases={4: 2, 5: 3},
+        scratch_shapes=[pltpu.SMEM((4,), jnp.int32)],
         interpret=interpret,
     )(shape2(g), shape2(u), shape2(v),
-      jnp.asarray(threshold, jnp.float32).reshape(1, 1),
-      jnp.zeros((kpad, 1), jnp.float32),
-      jnp.full((kpad, 1), -1, jnp.int32))
+      jnp.asarray(threshold, jnp.float32).reshape(1, 1))
     return (vals.reshape(-1)[:k], idx.reshape(-1)[:k],
             newu.reshape(-1)[:n], newv.reshape(-1)[:n])
 
@@ -323,8 +328,13 @@ def _scatter_kernel(out_rows, vals_ref, idx_ref, out_ref):
         a = a * vals_ref[:]
         b = (col == jax.lax.broadcasted_iota(
             jnp.int32, (_CHUNK, _LANES), 1)).astype(jnp.float32)
+        # HIGHEST: the MXU's default rounds the fp32 values in ``a`` to
+        # bf16 (seen on a v5e: every reconstructed value off by up to
+        # 2.9e-3 relative); the full-precision passes make value x 1.0
+        # exact, which is what "scatter-add" promises
         out_ref[:] += jax.lax.dot_general(
             a, b, (((0,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
 

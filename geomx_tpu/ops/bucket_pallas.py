@@ -1,24 +1,40 @@
 """Fused bucket flatten/unflatten Pallas kernels.
 
 ``GradientBucketer.flatten``/``unflatten`` (compression/bucketing.py)
-lower, per leaf, to one XLA concatenate operand / dynamic-slice copy —
-~65 separate HBM-materializing copies per direction on the seed
-ResNet-20.  The bucket layout is entirely static (leaf -> (bucket,
-offset, size) resolves at trace time), so a single Pallas kernel can
-issue one async DMA per leaf inside ONE kernel launch, overlapping all
-the copies and collapsing the op soup to a single ``tpu_custom_call``
-per direction.
+lower, per leaf, to one XLA concatenate operand / dynamic-slice copy.
+The bucket layout is entirely static (leaf -> (bucket, offset, size)
+resolves at trace time), so one Pallas kernel per bucket can place every
+leaf with a straight-line program and collapse the op soup to a single
+``tpu_custom_call`` per bucket and direction.
 
-The kernels are pure data movement: every ref lives in compiler-chosen
-memory (``pl.ANY`` — in practice HBM; nothing is staged through VMEM
-except the 128-element zero block used to clear bucket tail padding).
-All offsets and sizes are Python ints baked into the kernel body, so the
-generated Mosaic program is a straight-line list of DMAs.
+Layout on the chip.  A leaf starts at an arbitrary element offset of its
+bucket, but TPU memory is tiled: a DMA (or a ref slice) must cover whole
+``(8, 128)`` fp32 tiles, so a leaf cannot be copied to ``bucket[off:]``
+as a column slice (the v5e compiler refuses ``[n, 1]`` views: "Slice
+shape along dimension 1 must be aligned to tiling (128)").  Both kernels
+therefore work on lane-dense ``[rows, 128]`` views, tile-aligned on both
+sides, and move the data between the two alignments with the vector
+unit: a row-major rotate of the loaded tiles (``pltpu.roll`` along lanes,
+a one-row carry, then along sublanes) by ``off mod 1024`` elements.
 
-Dtype handling stays OUTSIDE the kernels: callers pass 1-D fp32 views
-(``reshape(-1).astype(jnp.float32)`` — the reshape is free on contiguous
-HBM arrays, and the ``astype`` only materializes for non-fp32 leaves,
-exactly like the jnp path).
+- flatten: leaves are placed in offset order.  Each leaf's tiles are
+  rotated right and stored over the bucket tiles they straddle; only the
+  first tile of each store is a read-modify-write (it keeps what earlier
+  leaves wrote below ``off``), and whatever a leaf writes past its own
+  end is zero padding that the next leaf overwrites — or, for the last
+  leaf, the bucket's zero tail.
+- unflatten: each leaf's straddled bucket tiles are rotated left and the
+  leading tiles stored to the (tile-padded) leaf.
+
+Long leaves run as a ``fori_loop`` over fixed chunks so the Mosaic
+program stays small; every ref is whole-array VMEM (a bucket plus its
+leaves: ``~2x`` the bucket bytes), which bounds the bucket a kernel can
+take — see ``MAX_FUSED_BUCKET_ELEMS``.  Single-leaf buckets need no
+kernel at all (flatten is a pad, unflatten a slice) and never reach one.
+
+Dtype handling stays OUTSIDE the kernels: callers pass 1-D fp32 views;
+the tile padding of each leaf fuses into the ``reshape``/``astype`` that
+produced the view.
 """
 
 from __future__ import annotations
@@ -29,62 +45,136 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-_MIN_PAD_BLOCK = 128  # smallest zero block DMA'd over bucket tail padding
+_LANES = 128
+_TILE_ROWS = 8
+_TILE = _TILE_ROWS * _LANES        # one fp32 tile: 1024 elements
+_CHUNK_TILES = 64                  # tiles moved per loop step (256 KiB)
+
+# A kernel holds the bucket and its leaves in VMEM.  48 MiB of the v5e's
+# 128 MiB leaves room for the rotate temporaries; the default 4 MiB
+# bucket (compression/bucketing.DEFAULT_BUCKET_BYTES) needs ~9 MiB.
+_VMEM_CAP_BYTES = 48 * 1024 * 1024
+_VMEM_SLACK_BYTES = 4 * 1024 * 1024
+MAX_FUSED_BUCKET_ELEMS = (_VMEM_CAP_BYTES - _VMEM_SLACK_BYTES) // 8
 
 
-def _flatten_kernel(layout, bucket_sizes, *refs):
-    """refs = [*leaf_refs, zeros_ref, *bucket_out_refs, sems]."""
-    import jax.experimental.pallas as pl
+def flat_roll(x, shift: int):
+    """Rotate ``x`` [R, 128] right by ``shift`` elements in row-major
+    (flat index) order: ``out.flat[f] = x.flat[(f - shift) mod R*128]``."""
     from jax.experimental.pallas import tpu as pltpu
 
-    nleaves = len(layout)
-    leaf_refs = refs[:nleaves]
-    zeros_ref = refs[nleaves]
-    out_refs = refs[nleaves + 1:nleaves + 1 + len(bucket_sizes)]
-    sems = refs[-1]
-
-    copies = []
-    for i, (leaf_ref, (b, off, size)) in enumerate(zip(leaf_refs, layout)):
-        copies.append(pltpu.make_async_copy(
-            leaf_ref, out_refs[b].at[pl.ds(off, size), :], sems.at[i]))
-    # zero the lane-padding tail of each bucket (pad < pad_to by layout;
-    # the zeros source is sized to the largest tail by the caller)
-    fills = {}
-    for b, off, size in layout:
-        fills[b] = max(fills.get(b, 0), off + size)
-    nsem = nleaves
-    for b, total in enumerate(bucket_sizes):
-        pad = total - fills.get(b, 0)
-        if pad:
-            copies.append(pltpu.make_async_copy(
-                zeros_ref.at[pl.ds(0, pad), :],
-                out_refs[b].at[pl.ds(total - pad, pad), :],
-                sems.at[nsem]))
-            nsem += 1
-    for c in copies:
-        c.start()
-    for c in copies:
-        c.wait()
+    rows, lanes = divmod(shift, _LANES)
+    if lanes:
+        y = pltpu.roll(x, lanes, axis=1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        # lanes that wrapped belong to the row above
+        x = jnp.where(lane >= lanes, y, pltpu.roll(y, 1, axis=0))
+    if rows:
+        x = pltpu.roll(x, rows, axis=0)
+    return x
 
 
-def _unflatten_kernel(layout, nbuckets, *refs):
-    """refs = [*bucket_refs, *leaf_out_refs, sems]."""
+def _for_each_chunk(ntiles: int, chunk):
+    """``chunk(first_tile, n)`` over ``ntiles`` tiles: full chunks in a
+    ``fori_loop`` (traced ``first_tile``), the remainder statically."""
+    full = ntiles // _CHUNK_TILES
+    if full:
+        def body(c, carry):
+            chunk(c * _CHUNK_TILES, _CHUNK_TILES)
+            return carry
+        jax.lax.fori_loop(0, full, body, 0)
+    rest = ntiles - full * _CHUNK_TILES
+    if rest:
+        chunk(full * _CHUNK_TILES, rest)
+
+
+def _rows(tile, n: int):
+    """Ref row slice covering ``n`` tiles from tile index ``tile``."""
     import jax.experimental.pallas as pl
+    start = tile * _TILE_ROWS
+    if not isinstance(start, int):
+        start = pl.multiple_of(start, _TILE_ROWS)
+    return pl.ds(start, n * _TILE_ROWS)
+
+
+def _flatten_kernel(offsets, fill, *refs):
+    """refs = [*leaf_refs (tile-padded, zero tails), bucket_out_ref]."""
+    leaf_refs, out_ref = refs[:-1], refs[-1]
+    zero_from = fill // _TILE * _TILE_ROWS
+    out_ref[zero_from:, :] = jnp.zeros(
+        (out_ref.shape[0] - zero_from, _LANES), jnp.float32)
+    head_flat = (
+        jax.lax.broadcasted_iota(jnp.int32, (_TILE_ROWS, _LANES), 0) * _LANES
+        + jax.lax.broadcasted_iota(jnp.int32, (_TILE_ROWS, _LANES), 1))
+
+    for leaf_ref, off in zip(leaf_refs, offsets):
+        tile0, shift = divmod(off, _TILE)
+
+        def chunk(t, n, leaf_ref=leaf_ref, tile0=tile0, shift=shift):
+            x = leaf_ref[_rows(t, n), :]
+            if not shift:
+                out_ref[_rows(tile0 + t, n), :] = x
+                return
+            x = flat_roll(jnp.concatenate(
+                [x, jnp.zeros((_TILE_ROWS, _LANES), jnp.float32)]), shift)
+            head = _rows(tile0 + t, 1)
+            out_ref[head, :] = jnp.where(head_flat >= shift,
+                                         x[:_TILE_ROWS], out_ref[head, :])
+            out_ref[_rows(tile0 + t + 1, n), :] = x[_TILE_ROWS:]
+
+        _for_each_chunk(leaf_ref.shape[0] // _TILE_ROWS, chunk)
+
+
+def _unflatten_kernel(offsets, bucket_ref, *leaf_refs):
+    """leaf_refs are tile-padded outputs; the pad carries bucket bytes
+    past the leaf's end, which the caller slices away."""
+    for leaf_ref, off in zip(leaf_refs, offsets):
+        tile0, shift = divmod(off, _TILE)
+
+        def chunk(t, n, leaf_ref=leaf_ref, tile0=tile0, shift=shift):
+            if not shift:
+                leaf_ref[_rows(t, n), :] = bucket_ref[_rows(tile0 + t, n), :]
+                return
+            x = bucket_ref[_rows(tile0 + t, n + 1), :]
+            x = flat_roll(x, (n + 1) * _TILE - shift)
+            leaf_ref[_rows(t, n), :] = x[:n * _TILE_ROWS]
+
+        _for_each_chunk(leaf_ref.shape[0] // _TILE_ROWS, chunk)
+
+
+def _tiles(n: int) -> int:
+    return -(-n // _TILE)
+
+
+def _bucket_members(layout, b: int):
+    """(leaf index, offset, size) of bucket ``b``'s non-empty leaves in
+    offset order; they must tile the bucket's fill without gaps (the
+    flatten kernel relies on each leaf starting where the last ended)."""
+    members = sorted((off, i, size) for i, (bk, off, size)
+                     in enumerate(layout) if bk == b and size)
+    end = 0
+    for off, _i, size in members:
+        if off != end:
+            raise ValueError(
+                f"bucket {b} layout is not contiguous at offset {off} "
+                f"(previous leaf ends at {end})")
+        end = off + size
+    return [(i, off, size) for off, i, size in members], end
+
+
+def _compiler_params(total: int, leaf_sizes: Sequence[int]):
+    """Raise the scoped-VMEM limit to what the whole-array refs need."""
     from jax.experimental.pallas import tpu as pltpu
 
-    bucket_refs = refs[:nbuckets]
-    leaf_refs = refs[nbuckets:nbuckets + len(layout)]
-    sems = refs[-1]
-    copies = [
-        pltpu.make_async_copy(
-            bucket_refs[b].at[pl.ds(off, size), :], leaf_ref, sems.at[i])
-        for i, (leaf_ref, (b, off, size)) in enumerate(zip(leaf_refs,
-                                                           layout))
-    ]
-    for c in copies:
-        c.start()
-    for c in copies:
-        c.wait()
+    need = 4 * _TILE * (_tiles(total) + 1 + sum(map(_tiles, leaf_sizes)))
+    need += _VMEM_SLACK_BYTES
+    if need > _VMEM_CAP_BYTES:
+        raise ValueError(
+            f"a {total}-element multi-leaf bucket needs {need} bytes of "
+            f"VMEM; the fused bucket kernels take buckets up to "
+            f"{MAX_FUSED_BUCKET_ELEMS} elements — lower GEOMX_BUCKET_BYTES "
+            "or set GEOMX_FUSED_KERNELS=0")
+    return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "bucket_sizes",
@@ -93,7 +183,8 @@ def fused_flatten(leaves: Sequence[jax.Array],
                   layout: Tuple[Tuple[int, int, int], ...],
                   bucket_sizes: Tuple[int, ...],
                   interpret: bool = False) -> List[jax.Array]:
-    """Gather 1-D fp32 ``leaves`` into flat fp32 buckets in one kernel.
+    """Gather 1-D fp32 ``leaves`` into flat fp32 buckets, one kernel per
+    multi-leaf bucket.
 
     ``layout[i] = (bucket, offset, size)`` for leaf i; ``bucket_sizes``
     are the padded bucket lengths.  Tail padding is zero-filled, matching
@@ -103,30 +194,28 @@ def fused_flatten(leaves: Sequence[jax.Array],
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    nleaves = len(layout)
-    tail_pads = []
+    buckets = []
     for b, total in enumerate(bucket_sizes):
-        fill = max((off + size for bk, off, size in layout if bk == b),
-                   default=0)
-        if total > fill:
-            tail_pads.append(total - fill)
-    # the zeros source must cover the largest tail (pad_to is a caller
-    # knob, so tails are not bounded by the 128-lane default)
-    pad_block = max(_MIN_PAD_BLOCK, max(tail_pads, default=0))
-    out = pl.pallas_call(
-        functools.partial(_flatten_kernel, layout, bucket_sizes),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (nleaves + 1),
-        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
-                        for _ in bucket_sizes),
-        out_shape=tuple(jax.ShapeDtypeStruct((n, 1), jnp.float32)
-                        for n in bucket_sizes),
-        scratch_shapes=[pltpu.SemaphoreType.DMA(
-            (nleaves + len(tail_pads),))],
-        interpret=interpret,
-    )(*[leaf.reshape(-1, 1) for leaf in leaves],
-      jnp.zeros((pad_block, 1), jnp.float32))
-    buckets = out if isinstance(out, (tuple, list)) else (out,)
-    return [b.reshape(-1) for b in buckets]
+        members, fill = _bucket_members(layout, b)
+        if len(members) <= 1:
+            flat = (leaves[members[0][0]] if members
+                    else jnp.zeros((0,), jnp.float32))
+            buckets.append(jnp.pad(flat, (0, total - fill)))
+            continue
+        sizes = [size for _i, _off, size in members]
+        out = pl.pallas_call(
+            functools.partial(_flatten_kernel,
+                              tuple(off for _i, off, _s in members), fill),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * len(members),
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct(
+                ((_tiles(total) + 1) * _TILE_ROWS, _LANES), jnp.float32),
+            compiler_params=_compiler_params(total, sizes),
+            interpret=interpret,
+        )(*[jnp.pad(leaves[i], (0, -size % _TILE)).reshape(-1, _LANES)
+            for i, _off, size in members])
+        buckets.append(out.reshape(-1)[:total])
+    return buckets
 
 
 @functools.partial(jax.jit, static_argnames=("layout", "leaf_sizes",
@@ -135,21 +224,34 @@ def fused_unflatten(buckets: Sequence[jax.Array],
                     layout: Tuple[Tuple[int, int, int], ...],
                     leaf_sizes: Tuple[int, ...],
                     interpret: bool = False) -> List[jax.Array]:
-    """Scatter flat fp32 buckets back into 1-D fp32 leaves in one kernel
-    (the caller reshapes/casts to the original leaf shapes/dtypes)."""
+    """Scatter flat fp32 buckets back into 1-D fp32 leaves, one kernel
+    per multi-leaf bucket (the caller reshapes/casts to the original leaf
+    shapes/dtypes)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    nbuckets = len(buckets)
-    out = pl.pallas_call(
-        functools.partial(_unflatten_kernel, layout, nbuckets),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * nbuckets,
-        out_specs=tuple(pl.BlockSpec(memory_space=pl.ANY)
-                        for _ in leaf_sizes),
-        out_shape=tuple(jax.ShapeDtypeStruct((n, 1), jnp.float32)
-                        for n in leaf_sizes),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((len(layout),))],
-        interpret=interpret,
-    )(*[b.reshape(-1, 1) for b in buckets])
-    leaves = out if isinstance(out, (tuple, list)) else (out,)
-    return [leaf.reshape(-1) for leaf in leaves]
+    leaves = [jnp.zeros((0,), jnp.float32)] * len(leaf_sizes)
+    for b, bucket in enumerate(buckets):
+        members, _fill = _bucket_members(layout, b)
+        if len(members) <= 1:
+            for i, off, size in members:
+                leaves[i] = bucket[off:off + size]
+            continue
+        total = bucket.shape[0]
+        sizes = [size for _i, _off, size in members]
+        rows = (_tiles(total) + 1) * _TILE_ROWS
+        out = pl.pallas_call(
+            functools.partial(_unflatten_kernel,
+                              tuple(off for _i, off, _s in members)),
+            in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)],
+            out_specs=tuple(pl.BlockSpec(memory_space=pltpu.VMEM)
+                            for _ in members),
+            out_shape=tuple(jax.ShapeDtypeStruct(
+                (_tiles(size) * _TILE_ROWS, _LANES), jnp.float32)
+                for size in sizes),
+            compiler_params=_compiler_params(total, sizes),
+            interpret=interpret,
+        )(jnp.pad(bucket, (0, rows * _LANES - total)).reshape(rows, _LANES))
+        for (i, _off, size), leaf in zip(members, out):
+            leaves[i] = leaf.reshape(-1)[:size]
+    return leaves

@@ -375,10 +375,7 @@ def fused_attention_supported() -> bool:
     # graftlint: disable=GXL003,GXL006 — build-time gate
     if os.environ.get("GEOMX_FLASH_ATTN", "1") == "0":
         return False
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    return jax.devices()[0].platform == "tpu"
 
 
 def _dense(q, k, v, causal):

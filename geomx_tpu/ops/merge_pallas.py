@@ -20,11 +20,11 @@ bit-identical forms:
     them.
 
 ``merge_sorted_pairs`` with ``fused=True``
-    The Pallas form: one kernel invocation holding the whole pair
-    column in VMEM as an ``[L, 1]`` fp32/int32 column (the PR 4 staging
-    layout), applying the same ``rounds`` shifted combines against a
-    VMEM accumulator and extracting the per-segment totals at head
-    positions.  Interpret mode is the CPU parity oracle.
+    The Pallas form: a grid over lane-dense ``[512, 128]`` blocks of the
+    pair stream, each read with a one-tile halo of the pairs that follow
+    it, applying the same ``rounds`` shifted combines in registers and
+    extracting the per-segment totals at head positions.  Interpret
+    mode is the CPU parity oracle.
 
 Output format: same length as the input, the total of each index
 segment at its FIRST (head) position, sentinel ``(0.0, -1)`` everywhere
@@ -32,9 +32,10 @@ else — a valid sparse stream the re-selection stage consumes directly.
 Sentinel input pairs (index ``INT32_MAX`` after the sort's key mapping)
 never combine and come out as sentinels.
 
-VMEM budget: the accumulator plus the three input columns is
-``~16 bytes x L``; the caller bounds ``L`` (party-count x slot budget,
-compression/sparseagg.py) far below the scoped-vmem limit.
+VMEM budget: three input blocks, three halo tiles and two output
+blocks of 256 KiB each (double-buffered), whatever the stream length.
+The tree reads ``2**rounds - 1`` pairs ahead, which must fit the
+1024-pair halo: at most 1024 contributions per index.
 """
 
 from __future__ import annotations
@@ -47,7 +48,9 @@ import jax.numpy as jnp
 # post-sort sentinel key: real indices are < 2**31 - 1 (int32 buckets)
 SENTINEL_KEY = 2**31 - 1
 
-_SUBLANE = 8  # fp32 sublane tile: column lengths pad to a multiple
+_LANES = 128
+_BLOCK_ROWS = 512   # pairs per grid step: 512 x 128 = 64 Ki
+_HALO_ROWS = 8      # one fp32 tile of look-ahead past each block
 
 
 def merge_rounds(max_duplicates: int) -> int:
@@ -98,62 +101,68 @@ def _merge_tree_ref(svals, skey, rank, rounds: int):
             jnp.where(head, skey, -1).astype(jnp.int32))
 
 
-def _merge_kernel(L: int, rounds: int, vals_ref, idx_ref, rank_ref,
-                  outv_ref, outi_ref, acc):
-    """Single-invocation kernel: the same combining tree as
-    :func:`_merge_tree_ref`, with the shifted neighbour reads realized
-    as statically-offset column slices of the VMEM refs (the inputs are
-    padded by one tree stride past ``L``, so every slice is in
-    bounds)."""
-    acc[:] = vals_ref[:]
+def _merge_kernel(rounds: int, v_ref, vh_ref, k_ref, kh_ref, g_ref, gh_ref,
+                  outv_ref, outi_ref):
+    """One block of the pair stream plus a one-tile halo of what follows
+    it: the same combining tree as :func:`_merge_tree_ref`, with the
+    shifted neighbour reads realized as row-major rotates of the
+    lane-dense window.  A rotate wraps the window's last ``d`` entries,
+    so after all rounds the last ``2**rounds - 1`` entries are garbage —
+    inside the halo, which is not written."""
+    from geomx_tpu.ops.bucket_pallas import flat_roll
+
+    v = jnp.concatenate([v_ref[:], vh_ref[:]])
+    key = jnp.concatenate([k_ref[:], kh_ref[:]])
+    rank = jnp.concatenate([g_ref[:], gh_ref[:]])
+    size = v.shape[0] * _LANES
+    live = key != SENTINEL_KEY
     for r in range(rounds):
         d = 1 << r
-        a = acc[0:L, :]
-        b = acc[d:d + L, :]
-        ka = idx_ref[0:L, :]
-        kb = idx_ref[d:d + L, :]
-        g = rank_ref[0:L, :]
-        take = (ka == kb) & (ka != SENTINEL_KEY) & (g % (2 * d) == 0)
-        acc[0:L, :] = jnp.where(take, a + b, a)
-    ka = idx_ref[0:L, :]
-    head = (rank_ref[0:L, :] == 0) & (ka != SENTINEL_KEY)
-    outv_ref[:] = jnp.where(head, acc[0:L, :], 0.0)
-    outi_ref[:] = jnp.where(head, ka, -1)
+        pv = flat_roll(v, size - d)       # pv.flat[f] = v.flat[f + d]
+        pk = flat_roll(key, size - d)
+        take = (pk == key) & live & ((rank & (2 * d - 1)) == 0)
+        v = jnp.where(take, v + pv, v)
+    head = (rank == 0) & live
+    rows = outv_ref.shape[0]
+    outv_ref[:] = jnp.where(head, v, 0.0)[:rows]
+    outi_ref[:] = jnp.where(head, key, -1)[:rows]
 
 
 @functools.partial(jax.jit, static_argnames=("rounds", "interpret"))
 def _merge_tree_pallas(svals, skey, rank, rounds: int,
                        interpret: bool = False):
     import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
+    if (1 << rounds) > _HALO_ROWS * _LANES:
+        raise ValueError(
+            f"the fused merge reads {(1 << rounds) - 1} pairs ahead and "
+            f"its halo holds {_HALO_ROWS * _LANES}: at most "
+            f"{_HALO_ROWS * _LANES} contributions per index")
     m = svals.shape[0]
-    L = -(-m // _SUBLANE) * _SUBLANE
-    stride = 1 << max(rounds - 1, 0)          # largest shifted read
-    Lp = L + -(-stride // _SUBLANE) * _SUBLANE
+    rows = -(-m // _LANES)
+    block = min(_BLOCK_ROWS, -(-rows // _HALO_ROWS) * _HALO_ROWS)
+    nblocks = -(-rows // block)
+    padded = (nblocks * block + _HALO_ROWS) * _LANES
 
-    def col(x, fill, dtype):
-        x = x.astype(dtype)
-        pad = Lp - m
-        if pad:
-            x = jnp.concatenate([x, jnp.full((pad,), fill, dtype)])
-        return x.reshape(Lp, 1)
+    def slab(x, fill, dtype):
+        x = jnp.pad(x.astype(dtype), (0, padded - m), constant_values=fill)
+        return x.reshape(-1, _LANES)
 
+    blk = pl.BlockSpec((block, _LANES), lambda i: (i, 0))
+    halo = pl.BlockSpec((_HALO_ROWS, _LANES),
+                        lambda i: ((i + 1) * (block // _HALO_ROWS), 0))
+    v, k, g = (slab(svals, 0.0, jnp.float32),
+               slab(skey, SENTINEL_KEY, jnp.int32), slab(rank, 0, jnp.int32))
     outv, outi = pl.pallas_call(
-        functools.partial(_merge_kernel, L, rounds),
-        in_specs=[
-            pl.BlockSpec((Lp, 1), lambda: (0, 0)),
-            pl.BlockSpec((Lp, 1), lambda: (0, 0)),
-            pl.BlockSpec((Lp, 1), lambda: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((L, 1), lambda: (0, 0)),
-                   pl.BlockSpec((L, 1), lambda: (0, 0))),
-        out_shape=(jax.ShapeDtypeStruct((L, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((L, 1), jnp.int32)),
-        scratch_shapes=[pltpu.VMEM((Lp, 1), jnp.float32)],
+        functools.partial(_merge_kernel, rounds),
+        grid=(nblocks,),
+        in_specs=[blk, halo, blk, halo, blk, halo],
+        out_specs=(blk, blk),
+        out_shape=(
+            jax.ShapeDtypeStruct((nblocks * block, _LANES), jnp.float32),
+            jax.ShapeDtypeStruct((nblocks * block, _LANES), jnp.int32)),
         interpret=interpret,
-    )(col(svals, 0.0, jnp.float32), col(skey, SENTINEL_KEY, jnp.int32),
-      col(rank, 0, jnp.int32))
+    )(v, v, k, k, g, g)
     return outv.reshape(-1)[:m], outi.reshape(-1)[:m]
 
 

@@ -20,21 +20,11 @@ from geomx_tpu.topology import DC_AXIS, WORKER_AXIS
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """`jax.shard_map` across jax versions (check_vma vs check_rep kwarg)."""
-    try:
-        # AttributeError: jax versions without a top-level jax.shard_map
-        # raise it from the deprecation module's __getattr__
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    except (TypeError, AttributeError):
-        pass
-    try:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
-    except (TypeError, AttributeError):
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    """``jax.shard_map`` with the replication check off: the sync
+    algorithms return per-device state under replicated out-specs by
+    design (train/state.py)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---- per-leaf collectives (usable inside shard_map) ------------------------

@@ -1,22 +1,18 @@
-"""Native (C++) host runtime bindings + backend selection hygiene.
+"""Native (C++) host runtime bindings.
 
 The reference's transport core is native C++ (ps-lite); here the
 host-side pieces that benefit from native code — the priority send queue
 and the TSEngine scheduler state machine — are C++ (native/
-geops_runtime.cpp) behind ctypes, with automatic build-on-first-use and
+geops_runtime.cpp) behind ctypes, built from source on first use, with
 pure-Python fallbacks (geomx_tpu.transport) when no toolchain exists.
-
-``backends.scrub_platforms`` removes wedge-prone experimental JAX
-platform plugins from the backend selection order
-(``GEOMX_SCRUB_PLATFORMS``; the BENCH_r05 root cause).
 """
 
-from geomx_tpu.runtime.backends import scrub_list, scrub_platforms
 from geomx_tpu.runtime.native import (NativePriorityQueue,
                                       NativeRecordIOReader,
                                       NativeRecordIOWriter, NativeTSEngine,
-                                      load_native, native_available)
+                                      build_native, load_native,
+                                      native_available)
 
 __all__ = ["NativePriorityQueue", "NativeRecordIOReader",
-           "NativeRecordIOWriter", "NativeTSEngine", "load_native",
-           "native_available", "scrub_platforms", "scrub_list"]
+           "NativeRecordIOWriter", "NativeTSEngine", "build_native",
+           "load_native", "native_available"]

@@ -25,6 +25,25 @@ def _stale() -> bool:
         return False
 
 
+def build_native() -> bool:
+    """Rebuild ``native/libgeops.so`` from ``native/*.cpp`` (``make
+    -B``).  False — with a warning carrying the tool's own message —
+    when the host has no toolchain or the build fails; the documented
+    pure-Python paths (geomx_tpu.transport, data/recordio) then run."""
+    try:
+        subprocess.run(["make", "-C", _NATIVE_DIR, "-B"],
+                       check=True, capture_output=True, timeout=120)
+        return True
+    except (subprocess.SubprocessError, FileNotFoundError) as e:
+        import warnings
+        detail = (getattr(e, "stderr", b"") or b"").decode(
+            errors="replace").strip()[-400:]
+        warnings.warn(f"native runtime not built ({e!r}): {detail or '-'}; "
+                      "running the pure-Python paths", RuntimeWarning,
+                      stacklevel=2)
+        return False
+
+
 def load_native(build: bool = True) -> Optional[ctypes.CDLL]:
     """Load (building if needed) the native runtime; None if unavailable."""
     global _lib
@@ -34,20 +53,19 @@ def load_native(build: bool = True) -> Optional[ctypes.CDLL]:
         if _lib is not None:
             return _lib
         if build and (not os.path.exists(_LIB_PATH) or _stale()):
-            try:
-                subprocess.run(["make", "-C", _NATIVE_DIR, "-B"],
-                               check=True, capture_output=True, timeout=120)
-            except (subprocess.SubprocessError, FileNotFoundError):
-                pass  # fall through: a pre-existing .so may still bind
+            if not build_native():
+                # never bind what the sources no longer describe: after
+                # a failed build a left-over .so stays unbound
+                return None
         if not os.path.exists(_LIB_PATH):
             return None
         try:
             lib = ctypes.CDLL(_LIB_PATH)
             return _bind(lib)
         except (OSError, AttributeError):
-            # missing symbol = stale binary that could not be rebuilt:
-            # degrade to the pure-Python paths instead of crashing the
-            # capability probe (native_available)
+            # missing symbol = a binary from other sources: degrade to
+            # the pure-Python paths instead of crashing the capability
+            # probe (native_available)
             return None
 
 

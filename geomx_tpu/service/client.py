@@ -268,11 +268,11 @@ class GeoPSClient:
             # launcher's multi-host setting) advertises THIS PROCESS's
             # reachable address, taken from the local end of the server
             # connection.  When that, too, is loopback (server co-located
-            # or reached through a tunnel) nothing on this host can name
-            # our reachable address, so the chain falls back to the
+            # or reached through a port forward) nothing on this host can
+            # name our reachable address, so the chain falls back to the
             # launcher-set party host — right when workers share the
-            # server's machine, wrong across machines: multi-host
-            # tunneled workers must set GEOMX_RELAY_HOST explicitly.
+            # server's machine, wrong across machines: workers behind a
+            # forward must set GEOMX_RELAY_HOST explicitly.
             # graftlint: disable=GXL006 — host-plane knob
             adv = os.environ.get("GEOMX_RELAY_HOST")
             if not adv:

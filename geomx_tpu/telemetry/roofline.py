@@ -23,45 +23,31 @@ does not and the kernels are the lever.
 
 from __future__ import annotations
 
-import math
 from typing import Any, Dict, Optional
 
-# peak dense bf16 FLOP/s per chip by device_kind substring (public
-# specs; the bench's table, owned here so both read one source)
-PEAK_BF16 = (
-    ("v6", 918e12),        # Trillium / v6e
-    ("v5p", 459e12),
-    ("v5", 197e12),        # v5e reports "TPU v5 lite"
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 46e12),
-)
-
-# published HBM bandwidth per chip, bytes/s (same substring match)
-HBM_BYTES_PER_S = (
-    ("v6", 1640e9),
-    ("v5p", 2765e9),
-    ("v5", 819e9),
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-)
+# Published per-chip peaks, keyed by the EXACT ``device_kind`` JAX reports
+# (``jax.devices()[0].device_kind``).  The one table bench.py and the
+# roofline records read; a device that is not in it is an error, never a
+# default or a calibration — add its row, with its source, to use it.
+#
+# "TPU v5 lite" is the v5e.  Source: Google Cloud documentation, "TPU
+# v5e" system architecture — 197 TFLOP/s bf16 and 819 GB/s of HBM
+# bandwidth per chip.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+}
 
 
-def _lookup(table, device_kind: str) -> Optional[float]:
-    dk = (device_kind or "").lower()
-    for sub, val in table:
-        if sub in dk:
-            return val
-    return None
-
-
-def peak_flops(device_kind: str) -> Optional[float]:
-    return _lookup(PEAK_BF16, device_kind)
-
-
-def peak_hbm_bytes_per_s(device_kind: str) -> Optional[float]:
-    return _lookup(HBM_BYTES_PER_S, device_kind)
+def device_peaks(device_kind: str) -> Dict[str, float]:
+    """The published peaks of ``device_kind``; raises ``ValueError`` for
+    a device the table does not list."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}: "
+            f"telemetry/roofline.DEVICE_PEAKS lists {sorted(DEVICE_PEAKS)}; "
+            "a roofline or MFU needs the device's own row") from None
 
 
 def compiled_costs(compiled) -> Dict[str, Any]:
@@ -82,28 +68,6 @@ def compiled_costs(compiled) -> Dict[str, Any]:
     byt = float(ca.get("bytes accessed", 0.0) or 0.0)
     out["bytes_accessed"] = byt if byt > 0 else None
     return out
-
-
-def calibrate_peak_flops(n: int = 512, reps: int = 3) -> float:
-    """Measured matmul FLOP/s on the current default backend — the
-    *effective* peak where no published number exists (host CPU).  An
-    MFU against this calibration reads as "fraction of what this
-    machine's best dense kernel achieves", which is the honest CPU
-    analogue of the TPU spec number."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    a = jnp.ones((n, n), jnp.float32)
-    f = jax.jit(lambda x: x @ x)
-    f(a).block_until_ready()  # compile
-    best = math.inf
-    for _ in range(max(1, reps)):
-        t0 = time.perf_counter()
-        f(a).block_until_ready()
-        best = min(best, time.perf_counter() - t0)
-    return (2.0 * n ** 3) / best
 
 
 def roofline_record(*, flops: Optional[float],
@@ -206,23 +170,18 @@ def trainer_roofline(trainer, state, xb, yb, step_time_s: float,
                      ) -> Dict[str, Any]:
     """Roofline record for a live trainer: FLOPs/bytes from the compiled
     step, wire bytes from the sync algorithm's static accounting, peaks
-    from the device table (or a CPU calibration when the table has no
-    row).  ``wire_seconds``: measured/injected per-step WAN time — when
+    from the device table (``ValueError`` for a device it does not
+    list).  ``wire_seconds``: measured/injected per-step WAN time — when
     given, the wire roofline uses the *achieved* rate
     (wire_bytes/wire_seconds) so the verdict reflects the link actually
     in use."""
     import jax
 
+    if device_kind is None:
+        device_kind = jax.devices()[0].device_kind
+    peaks = device_peaks(device_kind)
     compiled = trainer.train_step.lower(state, xb, yb).compile()
     costs = compiled_costs(compiled)
-    if device_kind is None:
-        device_kind = getattr(jax.devices()[0], "device_kind", "")
-    peak = peak_flops(device_kind)
-    hbm_bw = peak_hbm_bytes_per_s(device_kind)
-    calibrated = False
-    if peak is None:
-        peak = calibrate_peak_flops()
-        calibrated = True
     params = jax.tree.map(lambda a: a[0, 0], state.params)
     wire = float((trainer.sync.wire_accounting(params) or {}).get(
         "dc_wire_bytes", 0.0)) or None
@@ -230,10 +189,10 @@ def trainer_roofline(trainer, state, xb, yb, step_time_s: float,
                if wire and wire_seconds and wire_seconds > 0 else None)
     rec = roofline_record(
         flops=costs.get("flops"), step_time_s=step_time_s,
-        peak_flops_per_s=peak, hbm_bytes=costs.get("bytes_accessed"),
-        hbm_bytes_per_s=hbm_bw, wire_bytes=wire,
+        peak_flops_per_s=peaks["bf16_flops_per_s"],
+        hbm_bytes=costs.get("bytes_accessed"),
+        hbm_bytes_per_s=peaks["hbm_bytes_per_s"], wire_bytes=wire,
         wire_bytes_per_s=wire_bw)
     rec["device_kind"] = device_kind
-    rec["peak_calibrated"] = calibrated
     rec["cost_analysis_available"] = costs.get("available", False)
     return rec

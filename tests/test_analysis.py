@@ -56,9 +56,9 @@ def test_walker_sees_nested_equations_with_provenance():
     jx = jax.make_jaxpr(outer)(jnp.zeros((8,)))
     prims = [(s.primitive, s.path) for s in walk_jaxpr(jx)]
     names = [p for p, _ in prims]
-    assert "pjit" in names and "scan" in names
+    assert "jit" in names and "scan" in names
     # nested ops carry the enclosing call path
-    assert any(p == "sin" and "pjit" in path for p, path in prims)
+    assert any(p == "sin" and "jit" in path for p, path in prims)
     assert any("scan" in path for _, path in prims)
     # walk order is stable across identical traces
     jx2 = jax.make_jaxpr(outer)(jnp.zeros((8,)))

@@ -1,6 +1,10 @@
 """bench.py survivability: the driver records the TAIL of stdout, so
 whatever kills the process, the last line must be a parseable record
-(round 4 lost its entire scorecard to rc=124 with empty output)."""
+(round 4 lost its entire scorecard to rc=124 with empty output) — and
+the exit code says whether that record is a measurement: non-zero when
+no TPU came up, when any unit carries an error, and when a signal cut
+the run short.  These tests take the explicit CPU route
+(GEOMX_BENCH_PLATFORM=cpu); the default mode never falls back to it."""
 
 import json
 import os
@@ -27,6 +31,8 @@ def test_bench_tail_parses_under_sigterm(tmp_path):
         "GEOMX_BENCH_INIT_TIMEOUT": "60",
         "GEOMX_BENCH_INIT_ATTEMPTS": "1",
         "GEOMX_BENCH_TIMEOUT": "90",
+        # the copy below runs from tmp_path: the package comes from here
+        "PYTHONPATH": REPO,
     })
     env.pop("XLA_FLAGS", None)
     # run a uniquely-named copy: the bench child re-execs its own file
@@ -75,7 +81,8 @@ def test_bench_tail_parses_under_sigterm(tmp_path):
                 break
         assert saw_config, f"no config completed within 150s: {lines[-3:]}"
         proc.send_signal(signal.SIGTERM)
-        proc.wait(timeout=30)
+        # a run cut short is a failed run: 128 + SIGTERM, never 0
+        assert proc.wait(timeout=30) == 128 + signal.SIGTERM
         # drain what the handler wrote on its way out (pump thread owns
         # the pipe; it posts None at EOF)
         while True:
@@ -177,7 +184,7 @@ def test_resume_clears_error_only_when_all_units_good():
 
 
 def test_compare_zero_watchdog_publishes_phase_forensics():
-    """The BENCH_r05 follow-through for the micro-modes: a wedged
+    """The main bench's watchdog applied to the micro-modes: a wedged
     --compare-zero run must publish the same forensic bundle the main
     bench's watchdog does — the hung phase by name, the per-phase
     timestamp trail, and the child's faulthandler stacks — instead of
@@ -210,8 +217,8 @@ def test_compare_zero_watchdog_publishes_phase_forensics():
 
 
 def test_watchdog_publishes_stacks_and_init_phases(tmp_path):
-    """Watchdog diagnosability (BENCH_r05 recorded only "backend init
-    exceeded 480s" twice, with zero clue where it hung): when the init
+    """Watchdog diagnosability (a record that says only "backend init
+    exceeded 480s" gives zero clue where it hung): when the init
     watchdog fires, the published record must carry the per-phase init
     timestamps and the child's all-thread faulthandler stack dump."""
     env = dict(os.environ)
@@ -219,7 +226,6 @@ def test_watchdog_publishes_stacks_and_init_phases(tmp_path):
         "GEOMX_BENCH_PLATFORM": "cpu",
         "GEOMX_BENCH_INIT_TIMEOUT": "5",
         "GEOMX_BENCH_INIT_ATTEMPTS": "1",
-        "GEOMX_BENCH_CPU_FALLBACK": "0",
         "GEOMX_BENCH_RESUME_ATTEMPTS": "0",
         # the hook wedges the child right after its first phase mark,
         # before the jax import, so the whole test bounds at ~10s
@@ -229,10 +235,14 @@ def test_watchdog_publishes_stacks_and_init_phases(tmp_path):
     out = subprocess.run(
         [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
         env=env, capture_output=True, text=True, timeout=90)
+    # a backend that never came up fails the run; nothing else is tried
+    assert out.returncode == 1
     lines = [ln for ln in out.stdout.splitlines() if ln.startswith("{")]
     assert lines, out.stderr[-2000:]
     rec = json.loads(lines[-1])
     assert "watchdog" in rec, rec.get("error")
+    assert [a["attempt"] for a in rec["init_attempts"]] == [1]
+    assert "degraded" not in rec and "captured_evidence" not in rec
     wd = rec["watchdog"]
     assert wd["phase"] == "backend init"
     # the child got as far as its first phase mark — and no further
@@ -245,3 +255,53 @@ def test_watchdog_publishes_stacks_and_init_phases(tmp_path):
     assert "Thread" in stacks or "File" in stacks, stacks[:500]
     assert "time.sleep" in stacks or "bench" in stacks, stacks[:500]
     assert "last init phase: child_start" in rec["error"]
+
+
+def test_default_mode_without_a_chip_fails_and_measures_nothing():
+    """No fallback: with no TPU (this sandbox holds JAX to the CPU) the
+    default mode exits non-zero, and its record carries no device, no
+    config and no value — a CPU number is never written under a device
+    metric's name.  Every attempt is the same environment in a fresh
+    process: nothing scrubbed, nothing switched off."""
+    env = dict(os.environ)
+    env.update({
+        "JAX_PLATFORMS": "cpu",
+        "GEOMX_BENCH_INIT_TIMEOUT": "120",
+        "GEOMX_BENCH_INIT_ATTEMPTS": "1",
+    })
+    for k in ("GEOMX_BENCH_PLATFORM", "XLA_FLAGS"):
+        env.pop(k, None)
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py")], cwd=REPO,
+        env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 1, out.stdout[-2000:]
+    lines = [ln for ln in out.stdout.splitlines() if ln.startswith("{")]
+    rec = json.loads(lines[-1])
+    assert "not a TPU" in rec["error"]
+    assert rec["device"] is None and rec["configs"] == {}
+    assert rec["value"] == 0.0 and rec["mfu"] is None
+    assert "degraded" not in rec
+    assert [a["attempt"] for a in rec["init_attempts"]] == [1]
+    assert "retry_env" not in rec["init_attempts"][0]
+
+
+def test_failed_unit_fails_the_run():
+    """Any unit of the final record carrying an ``error`` makes the
+    exit code non-zero — the piece of parent_main's contract the
+    subprocess tests above exercise end to end."""
+    sys.path.insert(0, REPO)
+    try:
+        import bench
+    finally:
+        sys.path.pop(0)
+    good = {"samples_per_sec_per_chip": 1.0}
+    results = {"configs": {"a": dict(good)}, "backend": {},
+               "fit_loop": None, "microbench": None, "profile": None,
+               "batch_sweep": None, "tta": None, "tta_s2d": None}
+    assert not bench._has_failures(results, None)
+    assert bench._has_failures(results, "watchdog: measurement exceeded")
+    results["configs"]["b"] = {"error": "boom"}
+    assert bench._has_failures(results, None)
+    results["configs"]["b"] = dict(good)
+    results["fit_loop"] = {"error": "died"}
+    assert bench._has_failures(results, None)

@@ -1,16 +1,79 @@
-"""The persistent compile cache must refuse the CPU backend: jaxlib
-0.4.x CPU executables deserialized from the cache corrupt the heap when
-the program donates input buffers (warm-run SIGSEGV — every jitted train
-step donates).  See utils/compile_cache.py."""
+"""The persistent compile cache is placed from outside.
+
+``JAX_COMPILATION_CACHE_DIR`` set: that directory is used and nothing —
+not the JAX config, not ``os.environ`` — is pointed anywhere else.
+Unset: ``<checkout>/.geomx_compile_cache``, resolved from the package's
+own location, so every cwd and every process agrees (the directory is
+part of JAX's cache key: a cache that moves never hits).  See
+utils/compile_cache.py."""
+
+import os
+import subprocess
+import sys
 
 import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_enable_compile_cache_vetoes_cpu_backend(tmp_path, monkeypatch):
+@pytest.fixture()
+def restore_cache_config():
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    yield
+    for k, v in was.items():
+        jax.config.update(k, v)
+
+
+def test_env_dir_is_used_and_left_alone(tmp_path, monkeypatch,
+                                        restore_cache_config):
     from geomx_tpu.utils import enable_compile_cache
 
-    assert jax.default_backend() == "cpu"  # the suite forces CPU
-    monkeypatch.delenv("GEOMX_COMPILE_CACHE_CPU", raising=False)
-    # even an explicit path is vetoed — correctness guard, not preference
-    assert enable_compile_cache(str(tmp_path / "cc")) is None
+    want = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    monkeypatch.delenv("GEOMX_COMPILE_CACHE", raising=False)
+    before_env = dict(os.environ)
+    before_cfg = jax.config.jax_compilation_cache_dir
+    assert enable_compile_cache() == want
+    assert dict(os.environ) == before_env          # nothing exported
+    assert jax.config.jax_compilation_cache_dir == before_cfg  # nothing set
+
+
+def test_default_dir_is_in_the_checkout_from_any_cwd(tmp_path, monkeypatch,
+                                                     restore_cache_config):
+    from geomx_tpu.utils import enable_compile_cache
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("GEOMX_COMPILE_CACHE", raising=False)
+    monkeypatch.chdir(tmp_path)
+    want = os.path.join(REPO, ".geomx_compile_cache")
+    assert enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want
+    assert "JAX_COMPILATION_CACHE_DIR" not in os.environ
+
+
+def test_two_processes_in_two_cwds_agree(tmp_path):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "GEOMX_COMPILE_CACHE")}
+    env["PYTHONPATH"] = REPO
+    code = ("from geomx_tpu.utils import enable_compile_cache; "
+            "print(enable_compile_cache())")
+    dirs = []
+    for cwd in (tmp_path, REPO):
+        out = subprocess.run([sys.executable, "-c", code], cwd=str(cwd),
+                             env=env, capture_output=True, text=True,
+                             timeout=120, check=True)
+        dirs.append(out.stdout.strip().splitlines()[-1])
+    assert dirs[0] == dirs[1] == os.path.join(REPO, ".geomx_compile_cache")
+
+
+def test_zero_is_the_off_switch(monkeypatch, restore_cache_config):
+    from geomx_tpu.utils import enable_compile_cache
+
+    monkeypatch.setenv("GEOMX_COMPILE_CACHE", "0")
+    before = jax.config.jax_compilation_cache_dir
     assert enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before

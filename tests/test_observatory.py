@@ -46,7 +46,7 @@ from geomx_tpu.telemetry.flight import (DENSITY_DRIFT, EXPOSED_JUMP,
                                         flight_recorder_from_config)
 from geomx_tpu.telemetry.links import LinkObservatory
 from geomx_tpu.telemetry.registry import MetricRegistry
-from geomx_tpu.telemetry.roofline import (compiled_costs, peak_flops,
+from geomx_tpu.telemetry.roofline import (compiled_costs, device_peaks,
                                           publish_roofline,
                                           roofline_record)
 from geomx_tpu.topology import HiPSTopology
@@ -260,9 +260,14 @@ def test_roofline_verdict_math_pinned():
 
 
 def test_roofline_device_table_and_publish():
-    assert peak_flops("TPU v5 lite") == pytest.approx(197e12)
-    assert peak_flops("TPU v5p") == pytest.approx(459e12)
-    assert peak_flops("weird accelerator") is None
+    v5e = device_peaks("TPU v5 lite")
+    assert v5e["bf16_flops_per_s"] == pytest.approx(197e12)
+    assert v5e["hbm_bytes_per_s"] == pytest.approx(819e9)
+    # exact device_kind only: no substring match, no default, no
+    # calibration — an unknown device is an error
+    for kind in ("TPU v5", "tpu v5 lite", "cpu", "weird accelerator"):
+        with pytest.raises(ValueError, match="no published peaks"):
+            device_peaks(kind)
     reg = MetricRegistry()
     rec = roofline_record(flops=1e9, step_time_s=1e-3,
                           peak_flops_per_s=2e12,

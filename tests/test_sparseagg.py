@@ -66,6 +66,10 @@ def _rand_pairs(rng, parties, k, n, sentinel_frac=0.15):
     (4, 64, 1024),
     (8, 100, 4096),   # three combining rounds
     (3, 1, 16),       # single pair per party
+    # 4 x 20,000 pairs over 30,000 indices: two 64 Ki-pair kernel blocks,
+    # duplicate segments straddling the block boundary (read from the
+    # halo) — the size the single-invocation kernel ran out of VMEM at
+    (4, 20_000, 30_000),
 ])
 def test_merge_sorted_pairs_parity_and_oracle(rng, parties, k, n):
     vals, idx = _rand_pairs(rng, parties, k, n)

@@ -1,0 +1,220 @@
+"""Ask the TPU's compiler, without a TPU.
+
+Every Pallas kernel of the main path is compiled by the installed
+libtpu for a *described* ``v5e:2x2`` device (no chip attached) at the
+flagship's size (ResNet-20: 65 leaves, 272,474 parameters, k = 2,725)
+and at one large size (4M elements / L = 8,192).  This is the guard the
+Mosaic-lowering tests (``jax.export`` + ``"tpu_custom_call" in
+mlir_module()``) cannot give: a kernel that lowers can still be refused
+by the chip's compiler for an unaligned slice or for VMEM it does not
+have — which is how ``bsc_select_pack``, ``fused_flatten`` and
+``fused_unflatten`` passed every interpret-mode test and could not run
+on a chip.
+
+Nothing executes here, so results are checked elsewhere (interpret-mode
+parity tests; ``chip_smoke.py`` on the chip).  Skipped only where the
+topology cannot be described.
+"""
+
+import functools
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep libtpu logs out of /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+RESNET20_PARAMS = 272_474
+RESNET20_BUCKET = 272_512        # lane-padded
+RESNET20_K = 2_726               # ceil(1% of the bucket)
+BIG = 4_000_000
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device, with the persistent compile cache off:
+    an executable compiled for an unattached chip is written to the
+    cache but cannot be read back without one."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _resnet20_leaves():
+    from geomx_tpu.models import ResNet20
+    shapes = jax.eval_shape(
+        lambda: ResNet20(num_classes=10).init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 32, 32, 3)), train=False))
+    leaves = [jax.ShapeDtypeStruct((leaf.size,), jnp.float32)
+              for leaf in jax.tree.leaves(shapes["params"])]
+    assert sum(leaf.shape[0] for leaf in leaves) == RESNET20_PARAMS
+    return leaves
+
+
+def _big_leaves():
+    # one 16 MiB bucket of odd-sized leaves: every alignment case at once
+    return [jax.ShapeDtypeStruct((n,), jnp.float32)
+            for n in (1_000_003, 2048 * 512, 999, 1_500_000, 450_001)]
+
+
+def f32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+
+def i32(*shape):
+    return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+
+def _bucket_case(direction, leaves_fn, bucket_bytes):
+    from geomx_tpu.compression.bucketing import GradientBucketer
+    from geomx_tpu.ops import fused_flatten, fused_unflatten
+    leaves = leaves_fn()
+    bk = GradientBucketer(leaves, bucket_bytes, fused=False)
+    assert bk.num_buckets == 1
+    layout, sizes = bk._layout(), tuple(bk.bucket_sizes)
+    if direction == "flatten":
+        return (lambda *ls: fused_flatten(ls, layout, sizes)), leaves
+    return ((lambda *bs: fused_unflatten(bs, layout, tuple(bk.leaf_sizes))),
+            [f32(n) for n in sizes])
+
+
+def _twobit(n):
+    from geomx_tpu.ops import quantize_2bit
+    return (lambda g, r: quantize_2bit(g, r, threshold=0.5)), [f32(n), f32(n)]
+
+
+def _twobit_inv(n):
+    from geomx_tpu.ops import dequantize_2bit
+    words = -(-n // 2048) * 128
+    return (lambda p: dequantize_2bit(p, n=n, threshold=0.5)), [i32(words)]
+
+
+def _select(n, k):
+    from geomx_tpu.ops import bsc_select_pack
+    return ((lambda g, u, v, t: bsc_select_pack(g, u, v, t, k=k)),
+            [f32(n), f32(n), f32(n), f32()])
+
+
+def _scatter(n, pairs):
+    from geomx_tpu.ops import bsc_scatter_add
+    return (lambda v, i: bsc_scatter_add(v, i, n=n)), [f32(pairs), i32(pairs)]
+
+
+def _merge(pairs, rounds):
+    from geomx_tpu.ops.merge_pallas import _merge_tree_pallas
+    return ((lambda v, k, r: _merge_tree_pallas(v, k, r, rounds=rounds)),
+            [f32(pairs), i32(pairs), i32(pairs)])
+
+
+def _sgd(n):
+    from geomx_tpu.ops import fused_sgd_momentum
+    return ((lambda p, g, m: fused_sgd_momentum(p, g, m, lr=0.1,
+                                                momentum=0.9)),
+            [f32(n)] * 3)
+
+
+def _adam(n):
+    from geomx_tpu.ops import fused_adam
+    return ((lambda p, g, m, v, a, b: fused_adam(
+        p, g, m, v, a, b, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8)),
+        [f32(n)] * 4 + [f32(), f32()])
+
+
+def _flash_fwd(L, dtype):
+    from geomx_tpu.ops import flash_attention
+    qkv = jax.ShapeDtypeStruct((2, L, 4, 64), dtype)
+    return functools.partial(flash_attention, causal=True), [qkv] * 3
+
+
+def _flash_bwd(L):
+    from geomx_tpu.ops import flash_attention_bwd
+    qkv = f32(2, L, 4, 64)
+    return (functools.partial(flash_attention_bwd, causal=True),
+            [qkv, qkv, qkv, qkv, f32(2, 4, L), qkv])
+
+
+def _ring_hop(L):
+    from geomx_tpu.parallel._fused_block import _hop_pallas
+    qkv, ml = f32(8, L, 64), f32(8, L)
+    return ((lambda q, k, v, m, l, o: _hop_pallas(
+        q, k, v, m, l, o, 0.125, True, 128, False)),
+        [qkv, qkv, qkv, ml, ml, qkv])
+
+
+CASES = {
+    "quantize_2bit-resnet20": lambda: _twobit(RESNET20_BUCKET),
+    "quantize_2bit-4M": lambda: _twobit(BIG),
+    "dequantize_2bit-resnet20": lambda: _twobit_inv(RESNET20_BUCKET),
+    "dequantize_2bit-4M": lambda: _twobit_inv(BIG),
+    "bsc_select_pack-resnet20": lambda: _select(RESNET20_BUCKET, RESNET20_K),
+    "bsc_select_pack-4M": lambda: _select(BIG, BIG // 100),
+    "bsc_scatter_add-resnet20": lambda: _scatter(RESNET20_BUCKET,
+                                                 2 * RESNET20_K),
+    "bsc_scatter_add-4M": lambda: _scatter(BIG, 4 * (BIG // 100)),
+    "fused_flatten-resnet20": lambda: _bucket_case(
+        "flatten", _resnet20_leaves, 4 << 20),
+    "fused_flatten-4M": lambda: _bucket_case("flatten", _big_leaves, 16 << 20),
+    "fused_unflatten-resnet20": lambda: _bucket_case(
+        "unflatten", _resnet20_leaves, 4 << 20),
+    "fused_unflatten-4M": lambda: _bucket_case(
+        "unflatten", _big_leaves, 16 << 20),
+    "fused_sgd_momentum-resnet20": lambda: _sgd(RESNET20_BUCKET),
+    "fused_sgd_momentum-4M": lambda: _sgd(BIG),
+    "fused_adam-resnet20": lambda: _adam(RESNET20_BUCKET),
+    "fused_adam-4M": lambda: _adam(BIG),
+    "flash_attention-f32-L100": lambda: _flash_fwd(100, jnp.float32),
+    "flash_attention-bf16-L1024": lambda: _flash_fwd(1024, jnp.bfloat16),
+    "flash_attention-bf16-L8192": lambda: _flash_fwd(8192, jnp.bfloat16),
+    "flash_attention_bwd-L100": lambda: _flash_bwd(100),
+    "flash_attention_bwd-L8192": lambda: _flash_bwd(8192),
+    "fused_ring_hop-L1024": lambda: _ring_hop(1024),
+    "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
+    "merge_tree-2x82": lambda: _merge(164, 1),
+    "merge_tree-2x2726": lambda: _merge(2 * RESNET20_K + 2, 1),
+    "merge_tree-4x20000": lambda: _merge(80_002, 2),
+    "merge_tree-4M": lambda: _merge(BIG, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_v5e_compiler_accepts(chip, case):
+    fn, shapes = CASES[case]()
+    args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
+            for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_bucket_kernels_refuse_what_vmem_cannot_hold():
+    """Above the size the whole-array VMEM refs support the kernels
+    raise — they never switch paths quietly."""
+    from geomx_tpu.ops import fused_flatten
+    from geomx_tpu.ops.bucket_pallas import MAX_FUSED_BUCKET_ELEMS
+    n = MAX_FUSED_BUCKET_ELEMS
+    layout = ((0, 0, n), (0, n, 7))
+    with pytest.raises(ValueError, match="GEOMX_BUCKET_BYTES"):
+        jax.eval_shape(
+            lambda a, b: fused_flatten((a, b), layout, (n + 128,)),
+            f32(n), f32(7))
+
+
+def test_select_pack_refuses_more_pairs_than_its_vmem_slabs_hold():
+    from geomx_tpu.ops import bsc_select_pack
+    from geomx_tpu.ops.bsc_pallas import MAX_FUSED_K
+    n = 2 * MAX_FUSED_K
+    with pytest.raises(ValueError, match="VMEM-resident"):
+        jax.eval_shape(
+            lambda g, t: bsc_select_pack(g, g, g, t, k=MAX_FUSED_K),
+            f32(n), f32())

@@ -519,7 +519,7 @@ def iter_py_files(paths):
             for root, dirs, files in os.walk(ap):
                 dirs[:] = [d for d in dirs
                            if d not in ("__pycache__", ".git",
-                                        ".jax_compile_cache")]
+                                        ".geomx_compile_cache")]
                 for f in sorted(files):
                     if f.endswith(".py"):
                         yield os.path.join(root, f)
