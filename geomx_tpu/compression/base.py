@@ -9,6 +9,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from geomx_tpu.parallel.collectives import tier_scope
+
 
 class Compressor(abc.ABC):
     """A compressed all-reduce over one mesh axis.
@@ -87,7 +89,8 @@ class NoCompressor(Compressor):
     def allreduce_leaf(self, g, state, axis_name, axis_size):
         if axis_size == 1:
             return g, state
-        return lax.psum(g, axis_name), state
+        with tier_scope(axis_name):
+            return lax.psum(g, axis_name), state
 
 
 def _parse_bool(v: str) -> bool:

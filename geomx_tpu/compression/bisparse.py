@@ -40,6 +40,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from geomx_tpu.compression.base import Compressor
+from geomx_tpu.parallel.collectives import tier_scope
+from geomx_tpu.utils.profiler import profile_scope
 
 MOMENTUM = 0.9  # hardcoded in the reference (gc.cc:200)
 
@@ -197,9 +199,9 @@ class BiSparseCompressor(Compressor):
             # always an operand).
             from geomx_tpu.ops.bsc_pallas import (bsc_select_pack,
                                                   sampled_boundary_guv)
-            from geomx_tpu.utils.profiler import profile_scope
-            thr = sampled_boundary_guv(g_flat, u, v,
-                                       k if eff_k is None else eff_k)
+            with profile_scope("compress/boundary", category="kernel"):
+                thr = sampled_boundary_guv(g_flat, u, v,
+                                           k if eff_k is None else eff_k)
             with profile_scope("bsc/select_pack", category="kernel",
                               args={"n": n, "k": k}):
                 vals, idx, u, v = bsc_select_pack(
@@ -264,7 +266,6 @@ class BiSparseCompressor(Compressor):
             # fused scatter-add: no XLA scatter, no per-party dense
             # intermediate (ops/bsc_pallas.py)
             from geomx_tpu.ops.bsc_pallas import bsc_scatter_add
-            from geomx_tpu.utils.profiler import profile_scope
             with profile_scope("bsc/scatter_add", category="kernel",
                               args={"n": n, "pairs": int(vals.shape[0])}):
                 return bsc_scatter_add(vals, idx, n,
@@ -281,12 +282,15 @@ class BiSparseCompressor(Compressor):
             _note_dense_fallback(n, self.min_sparse_size)
             if axis_size == 1:
                 return g, state
-            return lax.psum(g, axis_name), state
+            with profile_scope("compress/exchange", category="comm"), \
+                    tier_scope(axis_name):
+                return lax.psum(g, axis_name), state
         u, v = state
         vals, idx, u, v = self.compress(
             g.reshape(-1).astype(jnp.float32), u.reshape(-1), v.reshape(-1))
         if axis_size == 1:
-            out = self.decompress(vals, idx, n)
+            with profile_scope("compress/merge", category="kernel"):
+                out = self.decompress(vals, idx, n)
         elif self.sparse_agg:
             # compressed-domain merge (compression/sparseagg.py): route
             # pairs to their index-range owners, merge by sorted-index
@@ -297,15 +301,20 @@ class BiSparseCompressor(Compressor):
             from geomx_tpu.compression.sparseagg import sparse_allreduce
             if self.sparse_agg_parties is None:
                 self._wire_axis_size = int(axis_size)
-            out, v = sparse_allreduce(
-                vals, idx, n, axis_name, axis_size, self.decompress,
-                ef_buffer=v, merge_fused=self.fused,
-                interpret=self.fused_interpret)
+            # its routing and its merge interleave: one scope for both
+            with profile_scope("compress/exchange", category="comm"):
+                out, v = sparse_allreduce(
+                    vals, idx, n, axis_name, axis_size, self.decompress,
+                    ef_buffer=v, merge_fused=self.fused,
+                    interpret=self.fused_interpret)
         else:
             # the wire transfer: 2k floats per party over the dc tier
-            all_vals = lax.all_gather(vals, axis_name).reshape(-1)
-            all_idx = lax.all_gather(idx, axis_name).reshape(-1)
-            out = self.decompress(all_vals, all_idx, n)
+            with profile_scope("compress/exchange", category="comm"), \
+                    tier_scope(axis_name):
+                all_vals = lax.all_gather(vals, axis_name).reshape(-1)
+                all_idx = lax.all_gather(idx, axis_name).reshape(-1)
+            with profile_scope("compress/merge", category="kernel"):
+                out = self.decompress(all_vals, all_idx, n)
         return (out.reshape(shape).astype(dtype),
                 (u.reshape(shape), v.reshape(shape)))
 

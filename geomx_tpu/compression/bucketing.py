@@ -249,8 +249,9 @@ class BucketedCompressor(Compressor):
                 "the same pytree structure)")
         out_buckets, new_states = [], []
         for i, (b, s) in enumerate(zip(buckets, state)):
-            # host-side trace span + XLA TraceAnnotation: the bucket's ops
-            # carry this label (and its payload size) into device profiles
+            # the bucket's ops carry this label in their op names; the
+            # host span and its payload size are of the trace of the
+            # step, not of its run (utils/profiler.py)
             with profile_scope(
                     f"{axis_name}_allreduce/bucket{i}", category="comm",
                     args={"bucket": i, "elems": bk.bucket_fill[i],
@@ -269,9 +270,12 @@ class BucketedCompressor(Compressor):
         if not leaves:
             return grads, state
         bk = self._bucketer(leaves)
+        with profile_scope("compress/flatten"):
+            buckets = bk.flatten(leaves)
         out_buckets, new_states = self.allreduce_buckets(
-            bk.flatten(leaves), state, axis_name, axis_size, bk)
-        return treedef.unflatten(bk.unflatten(out_buckets)), new_states
+            buckets, state, axis_name, axis_size, bk)
+        with profile_scope("compress/unflatten"):
+            return treedef.unflatten(bk.unflatten(out_buckets)), new_states
 
     # -- the ZeRO shard view (train/zero.py) ---------------------------------
     def zero_bucketer(self, leaves: Sequence[Any]) -> GradientBucketer:
@@ -343,10 +347,12 @@ class BucketedCompressor(Compressor):
     def allreduce_leaf(self, g: jax.Array, state: Any, axis_name: str,
                        axis_size: int) -> Tuple[jax.Array, Any]:
         bk = self._bucketer([g])
-        bucket = bk.flatten([g])[0]
+        with profile_scope("compress/flatten"):
+            bucket = bk.flatten([g])[0]
         out, new_state = self.inner.allreduce_leaf(bucket, state, axis_name,
                                                    axis_size)
-        return bk.unflatten([out])[0], new_state
+        with profile_scope("compress/unflatten"):
+            return bk.unflatten([out])[0], new_state
 
     # -- accounting ----------------------------------------------------------
     def wire_bytes(self, grads: Any) -> int:

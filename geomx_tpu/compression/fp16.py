@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from geomx_tpu.compression.base import Compressor
+from geomx_tpu.parallel.collectives import tier_scope
 
 
 class FP16Compressor(Compressor):
@@ -50,7 +51,8 @@ class FP16Compressor(Compressor):
             flat = lattice_allreduce_fp16(g.reshape(-1), axis_name,
                                           axis_size)
             return flat.reshape(g.shape).astype(g.dtype), state
-        gathered = lax.all_gather(wire, axis_name)        # [axis, *shape] 16-bit
+        with tier_scope(axis_name):
+            gathered = lax.all_gather(wire, axis_name)  # [axis, *shape] 16-bit
         total = jnp.sum(gathered.astype(g.dtype), axis=0)  # fp32 accumulate
         return total, state
 

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from geomx_tpu.compression.base import Compressor
+from geomx_tpu.parallel.collectives import tier_scope
 
 _CODES_PER_WORD = 16  # 2 bits per element, int32 words
 
@@ -119,7 +120,8 @@ class TwoBitCompressor(Compressor):
         if axis_size == 1:
             out = self.dequantize(words, gf.shape[0])
         else:
-            gathered = lax.all_gather(words, axis_name)      # [axis, words] int32
+            with tier_scope(axis_name):
+                gathered = lax.all_gather(words, axis_name)  # [axis, words] int32
             # sum of per-party signs, then scale once — exact since every
             # party's dequantized values live on the same ±threshold grid
             codes = (gathered[:, :, None] >>
@@ -160,7 +162,8 @@ class TwoBitCompressor(Compressor):
         if axis_size == 1:
             out = dequantize_2bit(packed, n, self.threshold, interpret=interp)
         else:
-            gathered = lax.all_gather(packed, axis_name)  # [axis, words]
+            with tier_scope(axis_name):
+                gathered = lax.all_gather(packed, axis_name)  # [axis, words]
             parts = [dequantize_2bit(gathered[i], n, self.threshold,
                                      interpret=interp)
                      for i in range(axis_size)]

@@ -36,6 +36,7 @@ import numpy as np
 from geomx_tpu.data.samplers import (ClassSplitSampler, SplitSampler,
                                      class_sorted_indices)
 from geomx_tpu.topology import HiPSTopology
+from geomx_tpu.utils.profiler import profile_scope
 
 
 def gather_batch(dx, dy, sel, key, augment: bool, pad: int):
@@ -200,9 +201,13 @@ class GeoDataLoader:
         return sel, jax.random.PRNGKey(self.seed + epoch)
 
     def _batches(self, epoch: int) -> Iterator[Tuple[jax.Array, jax.Array]]:
+        # the loader/* spans run on whichever thread assembles: the
+        # producer thread under prefetch, where they show beside the
+        # loop's fit/next_batch wait in a profile
         topo = self.topology
-        order = self._epoch_order(epoch)
-        rng = np.random.RandomState(self.seed + epoch + 1)  # augment stream
+        with profile_scope("loader/epoch_start", args={"epoch": epoch}):
+            order = self._epoch_order(epoch)
+            rng = np.random.RandomState(self.seed + epoch + 1)  # augment
         b = self.batch_size
         if self.device_cache:
             ekey = jax.random.PRNGKey(self.seed + epoch)
@@ -215,17 +220,22 @@ class GeoDataLoader:
                                    augment=self.augment, pad=self.pad)
             return
         for step in range(self.steps_per_epoch):
-            sel = np.stack([idx[step * b:(step + 1) * b] for idx in order])
-            xflat = self.x[sel.reshape(-1)]
-            if self.augment:
-                xflat = self._augment_batch(xflat, rng)
-            xb = xflat.reshape(
-                (topo.num_parties, topo.workers_per_party, b) + self.x.shape[1:])
-            yb = self.y[sel.reshape(-1)].reshape(
-                (topo.num_parties, topo.workers_per_party, b))
+            with profile_scope("loader/assemble", args={"step": step}):
+                sel = np.stack(
+                    [idx[step * b:(step + 1) * b] for idx in order])
+                xflat = self.x[sel.reshape(-1)]
+                if self.augment:
+                    xflat = self._augment_batch(xflat, rng)
+                xb = xflat.reshape(
+                    (topo.num_parties, topo.workers_per_party, b)
+                    + self.x.shape[1:])
+                yb = self.y[sel.reshape(-1)].reshape(
+                    (topo.num_parties, topo.workers_per_party, b))
             if self.x_sharding is not None:
-                xb = jax.device_put(xb, self.x_sharding)
-                yb = jax.device_put(yb, self.y_sharding)
+                with profile_scope("loader/device_put",
+                                   args={"step": step}):
+                    xb = jax.device_put(xb, self.x_sharding)
+                    yb = jax.device_put(yb, self.y_sharding)
             yield xb, yb
 
     def _augment_batch(self, x: np.ndarray,

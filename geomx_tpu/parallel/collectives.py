@@ -17,6 +17,7 @@ import jax
 from jax import lax
 
 from geomx_tpu.topology import DC_AXIS, WORKER_AXIS
+from geomx_tpu.utils.profiler import profile_scope
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
@@ -27,26 +28,38 @@ def shard_map_compat(f, mesh, in_specs, out_specs):
                          out_specs=out_specs, check_vma=False)
 
 
+def tier_scope(axis_name: str):
+    """``collective/worker`` / ``collective/dc``: the scope a collective
+    over one mesh tier is traced under, so that a profile charges it to
+    that tier (telemetry/layers.py).  The sync tiers and compressors open
+    it around their own ``lax`` collectives."""
+    return profile_scope(f"collective/{axis_name}", category="comm")
+
+
 # ---- per-leaf collectives (usable inside shard_map) ------------------------
 
 def psum_worker(tree: Any) -> Any:
     """Intra-party aggregation — the worker → local-server merge
     (reference: src/kvstore/kvstore_dist_server.h:1324 `== NumWorkers`)."""
-    return lax.psum(tree, WORKER_AXIS)
+    with tier_scope(WORKER_AXIS):
+        return lax.psum(tree, WORKER_AXIS)
 
 
 def psum_dc(tree: Any) -> Any:
     """Cross-party aggregation — the local-server → global-server merge
     (reference: src/kvstore/kvstore_dist_server.h:1305-1318)."""
-    return lax.psum(tree, DC_AXIS)
+    with tier_scope(DC_AXIS):
+        return lax.psum(tree, DC_AXIS)
 
 
 def pmean_worker(tree: Any) -> Any:
-    return lax.pmean(tree, WORKER_AXIS)
+    with tier_scope(WORKER_AXIS):
+        return lax.pmean(tree, WORKER_AXIS)
 
 
 def pmean_dc(tree: Any) -> Any:
-    return lax.pmean(tree, DC_AXIS)
+    with tier_scope(DC_AXIS):
+        return lax.pmean(tree, DC_AXIS)
 
 
 def hier_psum(tree: Any) -> Any:
@@ -68,7 +81,8 @@ def all_gather_dc(x: jax.Array, axis: int = 0, tiled: bool = False) -> jax.Array
     compressed gradient; every party reconstructs the aggregate locally —
     the SPMD analogue of server-side decompress-and-merge
     (reference: kvstore_dist_server.h:1099-1114 BSCDecompress into store_)."""
-    return lax.all_gather(x, DC_AXIS, axis=axis, tiled=tiled)
+    with tier_scope(DC_AXIS):
+        return lax.all_gather(x, DC_AXIS, axis=axis, tiled=tiled)
 
 
 def party_index() -> jax.Array:

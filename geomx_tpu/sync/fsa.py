@@ -30,6 +30,8 @@ import jax
 from jax import lax
 
 from geomx_tpu.compression.base import Compressor, NoCompressor
+from geomx_tpu.parallel.collectives import tier_scope
+from geomx_tpu.utils.profiler import profile_scope
 from geomx_tpu.sync.base import SyncAlgorithm
 from geomx_tpu.topology import DC_AXIS, WORKER_AXIS
 
@@ -111,8 +113,10 @@ class FSA(SyncAlgorithm):
         # worker tier: the scatter IS the reduce (and a 1/W wire saving
         # per ICI link); a configured worker compressor is bypassed —
         # build_train_step warns, mirroring MultiGPS
-        shards = [plan.scatter_bucket(b, WORKER_AXIS)
-                  for b in bk.flatten(leaves)]
+        with profile_scope("compress/flatten"):
+            buckets = bk.flatten(leaves)
+        with tier_scope(WORKER_AXIS):
+            shards = [plan.scatter_bucket(b, WORKER_AXIS) for b in buckets]
         w = self.party_weight()
         if w is not None:
             # degraded mode: identical exclusion algebra to sync_grads,
@@ -130,16 +134,20 @@ class FSA(SyncAlgorithm):
                          step: jax.Array) -> Tuple[Any, Any]:
         # keep non-trainable stats (BatchNorm) consistent across replicas
         if self.workers_per_party > 1:
-            model_state = lax.pmean(model_state, WORKER_AXIS)
+            with tier_scope(WORKER_AXIS):
+                model_state = lax.pmean(model_state, WORKER_AXIS)
         if self.num_parties > 1:
             w = self.party_weight()
-            if w is None:
-                model_state = lax.pmean(model_state, DC_AXIS)
-            else:
-                # renormalized survivor mean, same algebra as the grads
-                nl = self.num_live
-                model_state = jax.tree.map(
-                    lambda x: lax.psum(x * w, DC_AXIS) / nl, model_state)
+            with tier_scope(DC_AXIS):
+                if w is None:
+                    model_state = lax.pmean(model_state, DC_AXIS)
+                else:
+                    # renormalized survivor mean, same algebra as the
+                    # grads
+                    nl = self.num_live
+                    model_state = jax.tree.map(
+                        lambda x: lax.psum(x * w, DC_AXIS) / nl,
+                        model_state)
         return model_state, state
 
     def reset_comm_state(self, params: Any, state: Any,

@@ -25,6 +25,7 @@ from geomx_tpu.sync.base import SyncAlgorithm
 from geomx_tpu.telemetry import probes as _probes
 from geomx_tpu.topology import DC_AXIS, SP_AXIS, WORKER_AXIS, HiPSTopology
 from geomx_tpu.train.state import TrainState, state_specs
+from geomx_tpu.utils.profiler import profile_scope
 
 
 def cross_entropy_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
@@ -320,13 +321,16 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
         its state, allocated shard-shaped by Trainer.init_state) sees
         flat 1/W bucket shards; one all_gather per bucket rebuilds the
         replicated params for the next forward."""
-        shard_g, sync_state = sync.sync_grad_shards(grads, params,
-                                                    sync_state, step)
-        params, opt_state = zplan.apply_shard_update(
-            tx, shard_g, params, opt_state, WORKER_AXIS)
+        with profile_scope("step/sync_grads"):
+            shard_g, sync_state = sync.sync_grad_shards(grads, params,
+                                                        sync_state, step)
+        with profile_scope("step/optimizer"):
+            params, opt_state = zplan.apply_shard_update(
+                tx, shard_g, params, opt_state, WORKER_AXIS)
         # param-space hook still runs on the rebuilt replicated params
         # (MixedSync's stale-pull refresh)
-        params, sync_state = sync.sync_params(params, sync_state, step)
+        with profile_scope("step/sync_params"):
+            params, sync_state = sync.sync_params(params, sync_state, step)
         return params, opt_state, sync_state
 
     def _mgps_sync_update(grads, params, opt_state, sync_state, step):
@@ -339,58 +343,60 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
         flat_ws = treedef.flatten_up_to(sync_state["worker_comp"])
         widx = lax.axis_index(WORKER_AXIS)
 
-        mixed_g, new_ws = [], []
-        for p, g, ws in zip(flat_p, flat_g, flat_ws):
-            if mgps.is_big(p.size):
-                # the scatter IS the worker-tier reduce (and compression:
-                # each link moves 1/W of the tensor)
-                mixed_g.append(mgps.scatter_grad_leaf(g, WORKER_AXIS))
-                new_ws.append(ws)
+        with profile_scope("step/sync_grads"):
+            mixed_g, new_ws = [], []
+            for p, g, ws in zip(flat_p, flat_g, flat_ws):
+                if mgps.is_big(p.size):
+                    # the scatter IS the worker-tier reduce (and compression:
+                    # each link moves 1/W of the tensor)
+                    mixed_g.append(mgps.scatter_grad_leaf(g, WORKER_AXIS))
+                    new_ws.append(ws)
+                else:
+                    g, ws = sync.worker_compressor.allreduce_leaf(
+                        g, ws, WORKER_AXIS, nw)
+                    mixed_g.append(g / nw if nw > 1 else g)
+                    new_ws.append(ws)
+            # dc tier on the mixed tree: big leaves cross the WAN as shards
+            dc = sync.dc_compressor
+            if getattr(dc, "fuses_tree", False):
+                # EXPLICIT composition with tree-fusing compressors (tree-
+                # level DGT): one schedule per layout group.  A single flat
+                # schedule over the whole mixed tree ranks blocks that mix
+                # worker-axis shard content (different per worker slot) with
+                # replicated leaves, so its send decisions differ across
+                # workers and replicated leaves' aggregates diverge within a
+                # party.  The split keeps the replicated group's schedule a
+                # function of replicated content only (see
+                # MultiGPSPlan.split_mixed; state initialized group-wise by
+                # Trainer.init_state).
+                sizes = [p.size for p in flat_p]
+                big, small = mgps.split_mixed(sizes, mixed_g)
+                dst = sync_state["dc_comp"]
+                big_s, small_s = dst["sharded"], dst["replicated"]
+                if big:
+                    big, big_s = dc.allreduce(big, big_s, DC_AXIS, np_)
+                if small:
+                    small, small_s = dc.allreduce(small, small_s, DC_AXIS, np_)
+                mixed_g = treedef.unflatten(
+                    mgps.stitch_mixed(sizes, big, small))
+                dstate = {"sharded": big_s, "replicated": small_s}
             else:
-                g, ws = sync.worker_compressor.allreduce_leaf(
-                    g, ws, WORKER_AXIS, nw)
-                mixed_g.append(g / nw if nw > 1 else g)
-                new_ws.append(ws)
-        # dc tier on the mixed tree: big leaves cross the WAN as shards
-        dc = sync.dc_compressor
-        if getattr(dc, "fuses_tree", False):
-            # EXPLICIT composition with tree-fusing compressors (tree-
-            # level DGT): one schedule per layout group.  A single flat
-            # schedule over the whole mixed tree ranks blocks that mix
-            # worker-axis shard content (different per worker slot) with
-            # replicated leaves, so its send decisions differ across
-            # workers and replicated leaves' aggregates diverge within a
-            # party.  The split keeps the replicated group's schedule a
-            # function of replicated content only (see
-            # MultiGPSPlan.split_mixed; state initialized group-wise by
-            # Trainer.init_state).
-            sizes = [p.size for p in flat_p]
-            big, small = mgps.split_mixed(sizes, mixed_g)
-            dst = sync_state["dc_comp"]
-            big_s, small_s = dst["sharded"], dst["replicated"]
-            if big:
-                big, big_s = dc.allreduce(big, big_s, DC_AXIS, np_)
-            if small:
-                small, small_s = dc.allreduce(small, small_s, DC_AXIS, np_)
-            mixed_g = treedef.unflatten(
-                mgps.stitch_mixed(sizes, big, small))
-            dstate = {"sharded": big_s, "replicated": small_s}
-        else:
-            mixed_g, dstate = dc.allreduce(
-                treedef.unflatten(mixed_g), sync_state["dc_comp"],
-                DC_AXIS, np_)
-        if np_ > 1:
-            mixed_g = jax.tree.map(lambda x: x / np_, mixed_g)
+                mixed_g, dstate = dc.allreduce(
+                    treedef.unflatten(mixed_g), sync_state["dc_comp"],
+                    DC_AXIS, np_)
+            if np_ > 1:
+                mixed_g = jax.tree.map(lambda x: x / np_, mixed_g)
 
-        mixed_p = treedef.unflatten([
-            mgps.shard_param_leaf(p, widx) if mgps.is_big(p.size) else p
-            for p in flat_p])
-        updates, opt_state = tx.update(mixed_g, opt_state, mixed_p)
-        new_mixed = optax.apply_updates(mixed_p, updates)
-        params = treedef.unflatten([
-            mgps.unshard_param_leaf(nm, p, WORKER_AXIS)
-            if mgps.is_big(p.size) else nm
-            for p, nm in zip(flat_p, treedef.flatten_up_to(new_mixed))])
+        with profile_scope("step/optimizer"):
+            mixed_p = treedef.unflatten([
+                mgps.shard_param_leaf(p, widx) if mgps.is_big(p.size) else p
+                for p in flat_p])
+            updates, opt_state = tx.update(mixed_g, opt_state, mixed_p)
+            new_mixed = optax.apply_updates(mixed_p, updates)
+            params = treedef.unflatten([
+                mgps.unshard_param_leaf(nm, p, WORKER_AXIS)
+                if mgps.is_big(p.size) else nm
+                for p, nm in zip(flat_p, treedef.flatten_up_to(new_mixed))])
         sync_state = {"dc_comp": dstate,
                       "worker_comp": treedef.unflatten(new_ws)}
         return params, opt_state, sync_state
@@ -425,22 +431,27 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
                     "control-enabled Trainer (init_state adds the "
                     f"{CONTROL_KEY!r} subtree)")
 
-        fwd_params = sync.forward_params(params, sync_state)
-        (loss, (model_state, logits)), grads = grad_fn(
-            fwd_params, model_state, xb, yb)
+        # the scopes below are the step's layer boundaries, one fixed
+        # vocabulary (telemetry/layers.py): metadata on the ops traced
+        # under them, never an op of their own
+        with profile_scope("step/forward_backward"):
+            fwd_params = sync.forward_params(params, sync_state)
+            (loss, (model_state, logits)), grads = grad_fn(
+                fwd_params, model_state, xb, yb)
 
-        if sp > 1:
-            # sequence parallelism: each sp device back-propagated only
-            # its sequence shard's path (the model's forward psum/
-            # attention collectives ride the sp axis); the true gradient
-            # is the SUM of the shard contributions.  After this, grads
-            # are identical across sp and the dc/worker sync tiers see
-            # one consistent replica per (party, worker).
-            grads = lax.psum(grads, SP_AXIS)
-            model_state = jax.tree.map(
-                lambda a: lax.pmean(a, SP_AXIS)
-                if jnp.issubdtype(a.dtype, jnp.floating) else a,
-                model_state)
+            if sp > 1:
+                # sequence parallelism: each sp device back-propagated
+                # only its sequence shard's path (the model's forward
+                # psum/attention collectives ride the sp axis); the true
+                # gradient is the SUM of the shard contributions.  After
+                # this, grads are identical across sp and the dc/worker
+                # sync tiers see one consistent replica per (party,
+                # worker).
+                grads = lax.psum(grads, SP_AXIS)
+                model_state = jax.tree.map(
+                    lambda a: lax.pmean(a, SP_AXIS)
+                    if jnp.issubdtype(a.dtype, jnp.floating) else a,
+                    model_state)
 
         # kept for the probes: this device's gradients before any
         # cross-party aggregation (pure aliases — no traced ops)
@@ -465,8 +476,9 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
                 params, opt_state, sync_state = _zero_sync_update(
                     grads, params, opt_state, sync_state, step)
             else:
-                grads, sync_state = sync.sync_grads(grads, params,
-                                                    sync_state, step)
+                with profile_scope("step/sync_grads"):
+                    grads, sync_state = sync.sync_grads(grads, params,
+                                                        sync_state, step)
                 # only algorithms whose sync output is mesh-replicated
                 # feed the replicated-value probes (HFA's identity
                 # sync_grads keeps per-device gradients, and publishing
@@ -474,27 +486,29 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
                 # would silently misreport)
                 if sync.grads_replicated_after_sync:
                     synced_grads = grads
-                if fopt_spec is not None:
-                    # fused apply: params and grads flatten onto the
-                    # bucket layout the dc tier already defined
-                    # (opt_state lives on the same layout —
-                    # Trainer.init_state), one Pallas pass per bucket
-                    flat_p, tdef = jax.tree.flatten(params)
-                    bk = fopt_bucketer(flat_p)
-                    new_pb, opt_state = fused_apply(
-                        fopt_spec, bk.flatten(flat_p),
-                        bk.flatten(tdef.flatten_up_to(grads)),
-                        opt_state, interpret=fopt_interp)
-                    params = tdef.unflatten(bk.unflatten(new_pb))
-                else:
-                    updates, opt_state = tx.update(grads, opt_state,
-                                                   params)
-                    params = optax.apply_updates(params, updates)
-                params, sync_state = sync.sync_params(params, sync_state,
-                                                      step)
-            model_state, sync_state = sync.sync_model_state(model_state,
-                                                            sync_state,
-                                                            step)
+                with profile_scope("step/optimizer"):
+                    if fopt_spec is not None:
+                        # fused apply: params and grads flatten onto the
+                        # bucket layout the dc tier already defined
+                        # (opt_state lives on the same layout —
+                        # Trainer.init_state), one Pallas pass per bucket
+                        flat_p, tdef = jax.tree.flatten(params)
+                        bk = fopt_bucketer(flat_p)
+                        new_pb, opt_state = fused_apply(
+                            fopt_spec, bk.flatten(flat_p),
+                            bk.flatten(tdef.flatten_up_to(grads)),
+                            opt_state, interpret=fopt_interp)
+                        params = tdef.unflatten(bk.unflatten(new_pb))
+                    else:
+                        updates, opt_state = tx.update(grads, opt_state,
+                                                       params)
+                        params = optax.apply_updates(params, updates)
+                with profile_scope("step/sync_params"):
+                    params, sync_state = sync.sync_params(
+                        params, sync_state, step)
+            with profile_scope("step/sync_model_state"):
+                model_state, sync_state = sync.sync_model_state(
+                    model_state, sync_state, step)
         if ctl is not None:
             # operands pass through unchanged (actuation is host-side);
             # rejoining after the hooks keeps the state structure stable
@@ -502,43 +516,44 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
             from geomx_tpu.control.actuators import CONTROL_KEY
             sync_state = dict(sync_state, **{CONTROL_KEY: ctl})
 
-        acc = jnp.mean(jnp.argmax(logits, -1) == yb)
-        metrics = {"loss": loss, "accuracy": acc}
-        # global mean over every worker for reporting
-        if sp > 1:
-            metrics = jax.lax.pmean(metrics, SP_AXIS)
-        metrics = jax.lax.pmean(metrics, WORKER_AXIS)
-        pw = sync.party_weight()
-        if pw is None:
-            metrics = jax.lax.pmean(metrics, DC_AXIS)
-        else:
-            # degraded membership: report the mean over SURVIVORS — a
-            # dead party's loss/accuracy describes data that never
-            # reached the aggregate
-            metrics = jax.tree.map(
-                lambda x: jax.lax.psum(x * pw, DC_AXIS) / sync.num_live,
-                metrics)
-        # step metadata: the live-party count baked into this traced
-        # step (static — the membership epoch is a recompile boundary);
-        # bench.py --compare-resilience reads it back as evidence that
-        # degraded steps really ran the renormalized survivor mean
-        metrics["num_live_parties"] = jnp.asarray(sync.num_live,
-                                                  jnp.float32)
-        if telem:
-            # step-health probes ride the replicated metrics output
-            # (every value is mesh-replicated by construction); the host
-            # plane (Trainer fit loop) publishes them to the metric
-            # registry and the event log
-            metrics["telemetry"] = _probes.collect_step_probes(
-                raw_grads, synced_grads, sync, sync_state, inline_sink,
-                params)
-            if ctl is not None:
-                # the live ratio scale rides the probe dict so the
-                # registry (and the controller's own sensors) see the
-                # operand the step actually ran with — replicated by
-                # construction (every device holds the same state copy)
-                metrics["telemetry"]["control_ratio_scale"] = \
-                    ctl["bsc_ratio_scale"]
+        with profile_scope("step/metrics"):
+            acc = jnp.mean(jnp.argmax(logits, -1) == yb)
+            metrics = {"loss": loss, "accuracy": acc}
+            # global mean over every worker for reporting
+            if sp > 1:
+                metrics = jax.lax.pmean(metrics, SP_AXIS)
+            metrics = jax.lax.pmean(metrics, WORKER_AXIS)
+            pw = sync.party_weight()
+            if pw is None:
+                metrics = jax.lax.pmean(metrics, DC_AXIS)
+            else:
+                # degraded membership: report the mean over SURVIVORS — a
+                # dead party's loss/accuracy describes data that never
+                # reached the aggregate
+                metrics = jax.tree.map(
+                    lambda x: jax.lax.psum(x * pw, DC_AXIS) / sync.num_live,
+                    metrics)
+            # step metadata: the live-party count baked into this traced
+            # step (static — the membership epoch is a recompile boundary);
+            # bench.py --compare-resilience reads it back as evidence that
+            # degraded steps really ran the renormalized survivor mean
+            metrics["num_live_parties"] = jnp.asarray(sync.num_live,
+                                                      jnp.float32)
+            if telem:
+                # step-health probes ride the replicated metrics output
+                # (every value is mesh-replicated by construction); the host
+                # plane (Trainer fit loop) publishes them to the metric
+                # registry and the event log
+                metrics["telemetry"] = _probes.collect_step_probes(
+                    raw_grads, synced_grads, sync, sync_state, inline_sink,
+                    params)
+                if ctl is not None:
+                    # the live ratio scale rides the probe dict so the
+                    # registry (and the controller's own sensors) see the
+                    # operand the step actually ran with — replicated by
+                    # construction (every device holds the same state copy)
+                    metrics["telemetry"]["control_ratio_scale"] = \
+                        ctl["bsc_ratio_scale"]
 
         new_state = TrainState(
             step=step + 1,

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import time
 from typing import Callable, Optional
 
 import jax
@@ -21,6 +22,7 @@ from geomx_tpu.config import GeoConfig
 from geomx_tpu.data.loader import GeoDataLoader
 from geomx_tpu.sync import get_sync_algorithm
 from geomx_tpu.sync.base import SyncAlgorithm
+from geomx_tpu.telemetry import layers
 from geomx_tpu.topology import HiPSTopology
 from geomx_tpu.train.state import (TrainState, replicate_tree,
                                    unreplicate_tree)
@@ -167,6 +169,11 @@ class Trainer:
         self._audit_gate = audit_severity_gate(self.config) \
             if self._audit else "error"
         self._audit_args = None     # (state, x, y) ShapeDtypeStructs
+        # the step's abstract arguments (shapes, dtypes, shardings), taken
+        # from the first batch a fit sees, and the counters of the last
+        # fit's host loop (telemetry/layers.py)
+        self._step_args = None
+        self.loop_stats: Optional[layers.LoopStats] = None
         self._audit_sigs: dict = {}  # membership key -> signature
         self._telem_last_it = 0
         # flight recorder (telemetry/flight.py, GEOMX_FLIGHT): a bounded
@@ -456,6 +463,17 @@ class Trainer:
             jax.make_jaxpr(step_fn)(st, xb, yb), ctx)
         return ctx.extras["collective_signature"], findings
 
+    def _abstract_step_args(self, state: TrainState, xb, yb):
+        """(state, x, y) as ``jax.ShapeDtypeStruct`` trees with their
+        shardings: what the active step program can be traced or lowered
+        from again.  Taken once per program, from the first batch seen."""
+        if self._step_args is None:
+            self._step_args = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
+                (state, xb, yb))
+        return self._step_args
+
     def _audit_capture(self, state: TrainState, xb, yb) -> None:
         """Arm the auditor: record abstract step arguments and the
         active program's collective signature (once per Trainer; the
@@ -463,9 +481,7 @@ class Trainer:
         compile, no device work."""
         if not self._audit or self._audit_args is not None:
             return
-        self._audit_args = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            (state, xb, yb))
+        self._audit_args = self._abstract_step_args(state, xb, yb)
         self._audit_sigs[self._membership] = self._step_signature(
             self.train_step)
 
@@ -657,6 +673,7 @@ class Trainer:
         # it is installed — a depth change legitimately changes the
         # collective sequence, so the diff-vs-reference check is
         # re-ARMED on the new program rather than diffed across depths
+        self._step_args = None  # the next fit records the new structure
         if self._audit and self._audit_args is not None:
             _, xb_s, yb_s = self._audit_args
             self._audit_args = (jax.tree.map(
@@ -874,6 +891,12 @@ class Trainer:
                 flat[name] = [float(v) for v in arr]
                 for p, v in enumerate(arr):
                     fam_p.labels(probe=name, party=str(p)).set(float(v))
+        if self.loop_stats is not None:
+            fam_l = reg.gauge("geomx_fit_phase_seconds",
+                              "Seconds the running fit's host loop has "
+                              "spent in each phase", ("phase",))
+            for phase, rec in self.loop_stats.phases.items():
+                fam_l.labels(phase=phase).set(rec["total_s"])
         steps = iteration - self._telem_last_it
         if steps > 0:
             reg.counter("geomx_train_steps_total",
@@ -979,8 +1002,7 @@ class Trainer:
             "params_bytes_per_chip": _per_chip_bytes(state.params),
         }
         try:
-            ma = self.train_step.lower(state, xb, yb).compile() \
-                .memory_analysis()
+            ma = self._compiled_step(state, xb, yb).memory_analysis()
         except Exception as e:  # backend without AOT memory stats
             out["memory_analysis"] = {"unavailable": repr(e)}
             return out
@@ -999,6 +1021,30 @@ class Trainer:
             + fields.get("output_size_in_bytes", 0))
         out["memory_analysis"] = fields
         return out
+
+    def _compiled_step(self, state, xb, yb):
+        """The active step program, compiled ahead of time for these
+        arguments (arrays or ``jax.ShapeDtypeStruct`` trees); a program
+        the step already ran comes from the compile caches."""
+        return self.train_step.lower(state, xb, yb).compile()
+
+    def step_layers(self, state, xb, yb) -> dict:
+        """The program's table of its own step: ``{"ops": {instruction
+        name: OpLayer}, "instructions", "unscoped", "unnamed", "seconds"}``
+        from the compiled step's HLO text (telemetry/layers.op_layers).
+        A profile names device events by instruction, so the table
+        charges each to a scope of the vocabulary and a layer.
+        ``unscoped`` counts the instructions that carry no scope of the
+        vocabulary; ``unnamed`` those among them that carry no op name at
+        all, which the compiler made (copies, layout changes).  Takes
+        ``last_step_signature()`` as gladly as arrays, so a fresh trainer
+        can produce it after the timed work."""
+        begin = time.perf_counter()
+        ops = layers.op_layers(self._compiled_step(state, xb, yb).as_text())
+        return {"ops": ops, "instructions": len(ops),
+                "unscoped": sum(1 for v in ops.values() if not v.scope),
+                "unnamed": sum(1 for v in ops.values() if v.scope is None),
+                "seconds": time.perf_counter() - begin}
 
     def publish_memory_metrics(self, state: TrainState, xb, yb) -> None:
         """Publish the per-chip step-memory gauges (telemetry plane;
@@ -1180,6 +1226,8 @@ class Trainer:
         prof = get_profiler()
         fit_since_us = prof.now_us() if prof.running else None
         self._attr_window_us = fit_since_us
+        stats = self.loop_stats = layers.LoopStats()
+        layers.record_fit(stats, self._step_args)
         if scan_epochs:
             if not getattr(loader, "device_cache", False):
                 raise ValueError("scan_epochs requires device_cache=True "
@@ -1187,13 +1235,20 @@ class Trainer:
             run = self._epoch_runner(loader)
             it = 0
             for epoch in range(epochs):
-                sel, key = loader.epoch_indices(epoch)
-                state, ms = run(state, loader._dev_x, loader._dev_y,
-                                sel, key)
+                # one dispatch an epoch: the phases are per epoch here,
+                # and `step` is the epoch's first iteration
+                stats.step = it
+                with stats.phase("fit/next_batch"):
+                    sel, key = loader.epoch_indices(epoch)
+                with stats.phase("fit/dispatch"):
+                    state, ms = run(state, loader._dev_x, loader._dev_y,
+                                    sel, key)
                 it += loader.steps_per_epoch
+                stats.steps = it
                 fields = {}
                 if log_every:
-                    ms = jax.device_get(ms)
+                    with stats.phase("fit/log_sync"):
+                        ms = jax.device_get(ms)
                     fields.update(
                         loss=float(np.mean(ms["loss"])),
                         train_acc=float(np.mean(ms["accuracy"])))
@@ -1210,11 +1265,15 @@ class Trainer:
                         self._publish_telemetry(ms["telemetry"], it,
                                                 stacked=True)
                 if eval_data is not None:
-                    fields["test_acc"] = self.evaluate(state, *eval_data)
+                    with stats.phase("fit/eval"):
+                        fields["test_acc"] = self.evaluate(state,
+                                                           *eval_data)
                 if fields:
                     rec = measure.add(epoch=epoch, iteration=it, **fields)
-                    log_fn(json.dumps(rec))
-            jax.block_until_ready(state.step)
+                    with stats.phase("fit/log_fn"):
+                        log_fn(json.dumps(rec))
+            with stats.phase("fit/log_sync"):
+                jax.block_until_ready(state.step)
             self._capsule_checkpoint(prof)
             return state, measure.records
         # Virtual CPU meshes deadlock XLA's collective rendezvous with more
@@ -1226,16 +1285,33 @@ class Trainer:
         on_cpu = jax.devices()[0].platform == "cpu"
         sync_every = 1 if on_cpu else max(1, log_every or 32)
         it = 0
-        # step-time attribution (telemetry/attribution.py): when the
-        # host profiler is running, every step dispatch is bracketed as
-        # a train/step + train/compute span pair so attribute_trace can
-        # partition the fit's wall clock into compute / comms / stall.
-        # scope() no-ops when the profiler is off.  Caveat: with async
-        # dispatch the compute span measures dispatch+host time only —
-        # the CPU backend (and any blocking sync_every boundary) is the
-        # regime where it is the real step.
+        # Host spans and always-on counters of the loop
+        # (telemetry/layers.py).  Each iteration is a train/step span
+        # holding the phases fit/dispatch, fit/log_sync, fit/eval and
+        # fit/log_fn; the wait for its batch, fit/next_batch, comes just
+        # before it (an epoch's last wait finds no batch and is no step).
+        # All carry the same `step`.  The spans reach a jax.profiler
+        # session always and the Chrome trace when the host profiler
+        # runs; LoopStats needs neither.  train/compute brackets dispatch
+        # and boundary wait for attribute_trace: with async dispatch that
+        # is host time only, and the device's share of it is the
+        # boundary wait (on the CPU backend, which syncs every step, it
+        # is the real step).  The kernel- and comm-category spans the
+        # step's own code opens are entered while jit TRACES it, once a
+        # compile: on the host they time tracing, not compute or
+        # communication.
         for epoch in range(epochs):
-            for xb, yb in loader.epoch(epoch, prefetch=self._prefetch):
+            batches = iter(loader.epoch(epoch, prefetch=self._prefetch))
+            while True:
+                stats.step = it
+                with stats.phase("fit/next_batch"):
+                    batch = next(batches, None)
+                if batch is None:
+                    break
+                xb, yb = batch
+                if self._step_args is None:
+                    layers.record_fit(
+                        stats, self._abstract_step_args(state, xb, yb))
                 # arm the auditor on the first batch (abstract trace of
                 # the active program; no-op unless GEOMX_AUDIT is on)
                 self._audit_capture(state, xb, yb)
@@ -1246,8 +1322,10 @@ class Trainer:
                 with prof.scope("train/step", "step",
                                 args={"step": it}):
                     with prof.scope("train/compute", "compute"):
-                        state, metrics = self.train_step(state, xb, yb)
+                        with stats.phase("fit/dispatch"):
+                            state, metrics = self.train_step(state, xb, yb)
                         it += 1
+                        stats.steps = it
                         # the log/sync boundary wait is device compute
                         # (on the CPU backend the whole step; on an
                         # accelerator the async-dispatch catch-up), so
@@ -1258,21 +1336,29 @@ class Trainer:
                         # --compare-mfu) measures
                         synced = None
                         if log_every and it % log_every == 0:
-                            synced = jax.device_get(metrics)
+                            with stats.phase("fit/log_sync"):
+                                synced = jax.device_get(metrics)
                         elif it % sync_every == 0:
-                            jax.block_until_ready(metrics["loss"])
-                fields = {}
-                if synced is not None:
-                    metrics = synced
-                    fields.update(loss=float(metrics["loss"]),
-                                  train_acc=float(metrics["accuracy"]))
-                    if self._telemetry and "telemetry" in metrics:
-                        self._publish_telemetry(metrics["telemetry"], it)
-                if eval_data is not None and eval_every and it % eval_every == 0:
-                    fields["test_acc"] = self.evaluate(state, *eval_data)
-                if fields:
-                    rec = measure.add(epoch=epoch, iteration=it, **fields)
-                    log_fn(json.dumps(rec))
+                            with stats.phase("fit/log_sync"):
+                                jax.block_until_ready(metrics["loss"])
+                    fields = {}
+                    if synced is not None:
+                        metrics = synced
+                        fields.update(loss=float(metrics["loss"]),
+                                      train_acc=float(metrics["accuracy"]))
+                        if self._telemetry and "telemetry" in metrics:
+                            self._publish_telemetry(metrics["telemetry"],
+                                                    it)
+                    if eval_data is not None and eval_every \
+                            and it % eval_every == 0:
+                        with stats.phase("fit/eval"):
+                            fields["test_acc"] = self.evaluate(state,
+                                                               *eval_data)
+                    if fields:
+                        rec = measure.add(epoch=epoch, iteration=it,
+                                          **fields)
+                        with stats.phase("fit/log_fn"):
+                            log_fn(json.dumps(rec))
             if self._telemetry and not log_every and it:
                 # no log boundary ever synced this epoch: publish the
                 # epoch's last step so the registry/event log still track
@@ -1281,9 +1367,11 @@ class Trainer:
                 if "telemetry" in last:
                     self._publish_telemetry(last["telemetry"], it)
             if eval_data is not None and not eval_every:
-                rec = measure.add(epoch=epoch, iteration=it,
-                                  test_acc=self.evaluate(state, *eval_data))
-                log_fn(json.dumps(rec))
+                with stats.phase("fit/eval"):
+                    acc = self.evaluate(state, *eval_data)
+                rec = measure.add(epoch=epoch, iteration=it, test_acc=acc)
+                with stats.phase("fit/log_fn"):
+                    log_fn(json.dumps(rec))
         if self._telemetry and prof.running:
             # publish the fit's phase-fraction summary from the step
             # spans recorded above (geomx_phase_fraction gauges) — the

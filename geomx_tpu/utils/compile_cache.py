@@ -8,9 +8,25 @@ run after the first into a warm start.  The reference amortizes its
 jit-compiled framework the equivalent is making compilation itself
 persistent.
 
-The cache is keyed by XLA's hash of the lowered program + compile
-options + device kind, so stale entries are never *hit*, only ignored;
-it is safe to share one directory across branches and code versions.
+The cache is keyed by JAX's hash of the lowered program + compile
+options + device kind, so an entry whose program differs is never *hit*,
+only ignored; it is safe to share one directory across branches and code
+versions.  One case is not covered: JAX strips locations from the
+program before it hashes it, and the names ``profile_scope`` gives the
+step's ops travel as locations.  Two builds that differ only in scope
+names then share an entry, and the later one loads the earlier one's
+program, old names and all: same speed, but ``Trainer.step_layers`` and
+a profile's op names read the old vocabulary (the chip benchmark's
+``unscoped_device_pct`` then reads near 100).  A step that holds a
+Pallas kernel is safe, because the kernel's serialized body carries the
+names into the hash: every cell of the chip benchmark does, and a change
+of this PR 24 run on a cache the parent had filled read 0.36% (PERF.md).
+A program with no kernel is not.  The cure is a cleared cache or
+``jax_compilation_cache_include_metadata_in_key``, which makes names,
+source paths and lines part of every key (a moved checkout or a moved
+line then compiles cold, and an ahead-of-time lowering no longer shares
+the entry of the call that ran).  The tests turn it on (tests/conftest.py);
+the program does not.
 The directory itself is part of JAX's key, so it must not move between
 runs: it is placed from outside through ``JAX_COMPILATION_CACHE_DIR``,
 and otherwise sits at one fixed path inside the checkout.

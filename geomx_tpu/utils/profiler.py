@@ -24,21 +24,11 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-
-_ANN_CLS: Any = False  # False = unresolved; None = jax unavailable
-
-
-def _trace_annotation_cls():
-    """jax.profiler.TraceAnnotation, resolved once (failed imports are not
-    cached by Python, so retrying per scope would tax the push hot path)."""
-    global _ANN_CLS
-    if _ANN_CLS is False:
-        try:
-            import jax.profiler as jp
-            _ANN_CLS = jp.TraceAnnotation
-        except Exception:
-            _ANN_CLS = None
-    return _ANN_CLS
+# the two JAX halves of a scope, resolved once at import: a name-stack
+# entry that tags every op traced inside it, and a host span on the
+# profiler's own clock (the one the device planes share)
+from jax import named_scope as _named_scope
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 
 class Profiler:
@@ -165,34 +155,31 @@ class Profiler:
     @contextlib.contextmanager
     def scope(self, name: str, category: str = "host",
               args: Optional[Dict] = None):
-        """Record a named duration; also annotates the XLA trace so the
-        scope shows up inside TensorBoard device profiles (the analogue of
-        engine ops carrying profiler names, kvstore_dist.h:654).
+        """The program's one span primitive; one call feeds three sinks.
 
-        ``args`` attaches structured metadata to the Chrome-trace event —
-        the bucketed communication engine uses it to report per-bucket
-        payload sizes ({"bucket", "elems", "padded", "payload_bytes"})."""
-        if not self.running:
-            yield
-            return
-        begin = self._now_us()
-        ann_cls = _trace_annotation_cls()
-        ann = None
-        if ann_cls is not None:
+        Always: ``jax.named_scope(name)``: inside a ``jit`` trace every
+        op traced under it carries the name in its ``op_name`` metadata
+        (``tf_op`` in a profile, ``metadata={op_name=...}`` in the
+        compiled HLO; ``telemetry/layers.py`` reads the latter), outside
+        it is a name-stack push; and ``jax.profiler.TraceAnnotation(name,
+        **args)``: a host span in the profiler's own trace while a
+        ``jax.profiler`` session runs, near-free otherwise.  Only while
+        this profiler is ``running``: the Chrome-trace event, with
+        ``args`` as its structured metadata (the bucketed communication
+        engine reports per-bucket payload sizes there).
+
+        Inside jitted code the host span and the Chrome event measure the
+        *tracing* of the enclosed code, once per compile, not its run on
+        the device; the device time is found through the op names."""
+        with _named_scope(name), _TraceAnnotation(name, **(args or {})):
+            if not self.running:
+                yield
+                return
+            begin = self._now_us()
             try:
-                ann = ann_cls(name)
-                ann.__enter__()
-            except Exception:
-                ann = None
-        try:
-            yield
-        finally:
-            if ann is not None:
-                try:
-                    ann.__exit__(None, None, None)
-                except Exception:
-                    pass
-            self.add_event(name, begin, self._now_us(), category, args)
+                yield
+            finally:
+                self.add_event(name, begin, self._now_us(), category, args)
 
     # ---- device (XLA) traces ----------------------------------------------
     def start_device_trace(self, logdir: str) -> None:

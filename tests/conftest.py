@@ -13,13 +13,18 @@ if "xla_force_host_platform_device_count" not in flags:
 
 # Persistent XLA compilation cache: the suite's wall time is dominated by
 # CPU compiles of the training-step programs, and the programs are stable
-# across runs, so warm reruns cut minutes.  Keyed by HLO hash — stale
-# entries are simply never hit.  The directory is JAX_COMPILATION_CACHE_DIR
+# across runs, so warm reruns cut minutes.  Keyed by the program's hash,
+# scope names and source lines included: tests read scopes from compiled
+# programs (Trainer.step_layers), and without the metadata in the key a
+# directory filled by an earlier build answers with that build's names
+# (utils/compile_cache.py).  The directory is JAX_COMPILATION_CACHE_DIR
 # where set, else <checkout>/.geomx_compile_cache (utils/compile_cache.py);
 # GEOMX_TEST_COMPILE_CACHE=0 disables.
 if os.environ.get("GEOMX_TEST_COMPILE_CACHE") != "0":
+    import jax
     from geomx_tpu.utils import enable_compile_cache
     enable_compile_cache(min_compile_seconds=0.7)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 import numpy as np  # noqa: E402
 import pytest  # noqa: E402

@@ -356,6 +356,19 @@ def test_bucketed_allreduce_emits_per_bucket_payload_spans(rng):
     prof.reset()
 
 
+def test_bucket_span_names_are_in_a_jitted_calls_hlo(rng):
+    """The same scope names reach the device: inside jit they are the
+    `op_name` metadata of the bucket's instructions in the compiled HLO."""
+    tree = _tree(rng)
+    bc = BucketedCompressor(FP16Compressor(), bucket_bytes=1024 * 4)
+    state = bc.init_state(tree)
+    hlo = jax.jit(lambda t, s: bc.allreduce(t, s, "dc", 1)).lower(
+        tree, state).compile().as_text()
+    for rep in bc.bucket_report(tree):
+        assert f"/dc_allreduce/bucket{rep['bucket']}/" in hlo
+    assert "/compress/flatten/" in hlo and "/compress/unflatten/" in hlo
+
+
 # ---------- end-to-end: default bucketed training == per-leaf ----------
 
 def test_bucketed_training_matches_per_leaf_losses(topo2x4):

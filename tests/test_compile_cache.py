@@ -77,3 +77,46 @@ def test_zero_is_the_off_switch(monkeypatch, restore_cache_config):
     before = jax.config.jax_compilation_cache_dir
     assert enable_compile_cache() is None
     assert jax.config.jax_compilation_cache_dir == before
+
+
+_SCOPED_AFTER_UNSCOPED = """
+import os, sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+from geomx_tpu.utils.profiler import profile_scope
+
+def program(scoped):
+    def f(x):
+        if scoped:
+            with profile_scope("step/optimizer"):
+                return jnp.tanh(x) * 2.0
+        return jnp.tanh(x) * 2.0
+    return jax.jit(f).lower(jnp.ones((64, 64))).compile().as_text()
+
+assert "step/optimizer" not in program(False)
+filled = len(os.listdir(sys.argv[1]))
+assert filled >= 1
+stale = program(True)
+print("TRAP", "step/optimizer" not in stale, len(os.listdir(sys.argv[1])) == filled)
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+cured = program(True)
+print("CURE", "step/optimizer" in cured, len(os.listdir(sys.argv[1])) > filled)
+"""
+
+
+def test_scope_names_and_the_cache_key(tmp_path):
+    """What utils/compile_cache.py says of scope names: JAX strips
+    locations before it hashes a program, so a kernel-less program under a
+    new scope loads the entry the unscoped build wrote and carries no
+    names; with the metadata in the key (as tests/conftest.py sets it) it
+    compiles anew and carries them."""
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    out = subprocess.run(
+        [sys.executable, "-c", _SCOPED_AFTER_UNSCOPED, str(tmp_path)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip().splitlines()[-2:] == ["TRAP True True",
+                                                    "CURE True True"]
