@@ -18,9 +18,8 @@ it saved:
 ``bsc_scatter_add``
     The decompress: accumulates all parties' gathered (value, index)
     pairs into the dense bucket without materializing a per-party dense
-    intermediate or an XLA scatter.  Exploits that the wire format is
-    two ascending index runs per party (see below), so each pair chunk
-    touches ~1 output block and the rest are skipped.
+    intermediate or an XLA scatter, in work proportional to the pairs
+    plus the output, not to their product (see below).
 
 Algorithm notes (select/pack).  The reference scan's two-tier rule
 (strictly-above-boundary elements claim slots first, boundary ties queue
@@ -50,8 +49,30 @@ VMEM budget: 3 input + 2 output [8,128] fp32 blocks per grid step
 output slabs — 8 x (k + 2048) bytes, double-buffered, which bounds k
 (``MAX_FUSED_K``); above it the kernel raises.
 
+Algorithm notes (decompress).  The output is cut into blocks of
+``_OUT_ROWS`` x 128 elements and the pairs, sorted by index (one
+``lax.sort`` of the m pairs; sentinels last), into chunks of ``_CHUNK``.
+Sorted, chunk c holds pairs for blocks lo_c..hi_c with hi_c <= lo_(c+1),
+so the (block, chunk) meetings that do any work form a staircase of at
+most ``blocks + chunks`` visits.  ``scatter_visits`` computes that list
+in XLA from each chunk's first and last key, and the kernel's 1-D grid
+walks it: the two visit lists are scalar-prefetch operands that the
+BlockSpecs' index maps read, so Pallas streams each chunk and writes
+each output block once, and a visit is one one-hot matmul.  Cost in
+grid steps: ``ceil(n / 16384) + ceil(m / 512)``, e.g. 1,908 + 611 for
+BERT-large's token embedding (n = 31,254,528, m = 312,546); the grid
+this replaced visited every block once per chunk, 1,165,788 steps for
+that bucket and 856 ms of a 2.6 s step, 99% of them finding nothing to
+do.  A bucket of one block or one chunk needs no order and no schedule
+(every block meets every chunk; the product is the sum) and takes
+neither.
+
 Index arithmetic is int32 throughout: buckets are limited to 2**31-1
-elements (the bucketing default is 1 Mi elements per bucket).
+elements.  Buckets are as large as the largest leaf: the bucketing
+default is 1 Mi elements of capacity, but a leaf above that gets a
+bucket of its own (``compression/bucketing.py``), 31 M elements for
+BERT-large's embedding.  Size a kernel's schedule for that, not for the
+default.
 """
 
 from __future__ import annotations
@@ -70,6 +91,7 @@ _WIN_ROWS = 2 * _BLK_ROWS           # output rows one emitted run can touch
 MAX_FUSED_K = 1 << 19               # output pairs held in VMEM (2 x 2 MiB)
 _CHUNK = 512                       # (value, index) pairs per decompress step
 _OUT_ROWS = 128                    # dense output rows per decompress block
+_SENTINEL_KEY = 2 ** 31 - 1        # a sentinel pair's sort key: after every index
 
 
 def fused_kernels_enabled() -> bool:
@@ -289,51 +311,71 @@ def bsc_select_pack(g: jax.Array, u: jax.Array, v: jax.Array,
             newu.reshape(-1)[:n], newv.reshape(-1)[:n])
 
 
-def _scatter_kernel(out_rows, vals_ref, idx_ref, out_ref):
-    """Grid (out_blocks, pair_chunks), chunks innermost so the output
-    block stays VMEM-resident while every chunk streams past it.  The
-    scatter-add is two one-hot compares and one MXU matmul:
-    ``out[r, l] += sum_p (row_p == r) * v_p * (col_p == l)`` — exact
-    scatter-add semantics, no XLA scatter, no per-party dense buffer.
-    Because each party's index run is ascending, a chunk spans a narrow
-    index range and the min/max guard skips every other block (the
-    sentinel pairs, idx -1, never match any block)."""
+def scatter_visits(key: jax.Array, blocks: int, block_elems: int):
+    """The decompress's schedule: which (output block, pair chunk) visits
+    to make, for ``key`` [chunks, _CHUNK] ascending (sentinels last, as
+    INT32_MAX).  Returns ``(blk, chk, total)``: int32 [blocks + chunks]
+    visit lists and the number of live visits, at most
+    ``blocks + chunks``.
+
+    Chunk c holds pairs for blocks lo_c..hi_c, and lo_c <= hi_c <=
+    lo_(c+1) because the keys ascend, so the visits walk a staircase:
+    chunk c is paired with blocks lo_c .. max(hi_c, lo_(c+1) - 1) — its
+    own, plus the empty blocks up to the next chunk's first, which a
+    visit has to zero.  The first chunk starts at block 0, the last
+    chunk that holds a pair runs to the last block, a chunk of nothing
+    but sentinels gets no visit.  A block's visits are consecutive, so
+    the output block stays in VMEM across them and is written once.
+    Visits past ``total`` repeat the last block and do nothing."""
+    chunks = key.shape[0]
+    lo = jnp.minimum(key[:, 0] // block_elems, blocks)
+    hi = jnp.minimum(key[:, -1] // block_elems, blocks - 1)
+    first = jnp.where(jnp.arange(chunks) == 0, 0, lo)
+    last = jnp.maximum(hi, jnp.append(lo[1:], blocks) - 1)
+    count = last - first + 1
+    end = jnp.cumsum(count)
+    t = jnp.arange(blocks + chunks, dtype=jnp.int32)
+    # one fused compare-and-count over [visits, chunks]: no loop
+    chk = jnp.minimum(
+        jnp.searchsorted(end, t, side="right", method="compare_all"),
+        chunks - 1).astype(jnp.int32)
+    # a chunk's j-th visit is its first block + j: t - (end - count) is j
+    blk = jnp.minimum(t + (first - end + count)[chk], blocks - 1)
+    return blk.astype(jnp.int32), chk, end[-1:].astype(jnp.int32)
+
+
+def _scatter_kernel(out_rows, blk_ref, chk_ref, total_ref,
+                    vals_ref, key_ref, out_ref):
+    """One visit of the schedule: pair chunk ``chk[t]`` [1, _CHUNK]
+    against output block ``blk[t]`` [out_rows, 128].  The scatter-add is
+    two one-hot compares and one MXU matmul, ``out[r, l] += sum_p
+    (row_p == r) * v_p * (col_p == l)`` — exact scatter-add semantics, no
+    XLA scatter, no per-party dense buffer.  Keys of other blocks and the
+    sentinels' INT32_MAX give a row outside the block and match
+    nothing."""
     import jax.experimental.pallas as pl
 
-    blk = pl.program_id(0)
-    chunk = pl.program_id(1)
+    t = pl.program_id(0)
+    blk = blk_ref[t]
 
-    @pl.when(chunk == 0)
+    @pl.when((t == 0) | (blk != blk_ref[jnp.maximum(t - 1, 0)]))
     def _zero_output_block():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    ix = idx_ref[:]                                             # [S, 1]
-    lo = blk * out_rows * _LANES
-    hi = lo + out_rows * _LANES
-    # range guard reduces in f32 (Mosaic implements no integer
-    # reductions); f32 rounds large indices by up to 0.5 ULP, so widen
-    # the window by 256 (covers int32 range) — a false inclusion only
-    # costs one skippable matmul, never correctness
-    ixf = ix.astype(jnp.float32)
-    cmax = jnp.max(ixf)
-    cmin = jnp.min(jnp.where(ix >= 0, ixf, jnp.float32(2. ** 31)))
-
-    @pl.when((cmax >= lo - 256) & (cmin < hi + 256))
-    def _scatter_window():
-        valid = ix >= 0
-        row = jnp.where(valid, ix // _LANES - blk * out_rows, -1)
-        col = jnp.where(valid, ix % _LANES, -1)
-        a = (row == jax.lax.broadcasted_iota(
-            jnp.int32, (_CHUNK, out_rows), 1)).astype(jnp.float32)
-        a = a * vals_ref[:]
+    @pl.when(t < total_ref[0])
+    def _scatter_chunk():
+        local = key_ref[:] - blk * (out_rows * _LANES)          # [1, S]
+        row, col = local >> 7, local & (_LANES - 1)
+        a = jnp.where(row == jax.lax.broadcasted_iota(
+            jnp.int32, (out_rows, _CHUNK), 0), vals_ref[:], 0.0)
         b = (col == jax.lax.broadcasted_iota(
-            jnp.int32, (_CHUNK, _LANES), 1)).astype(jnp.float32)
+            jnp.int32, (_LANES, _CHUNK), 0)).astype(jnp.float32)
         # HIGHEST: the MXU's default rounds the fp32 values in ``a`` to
         # bf16 (seen on a v5e: every reconstructed value off by up to
         # 2.9e-3 relative); the full-precision passes make value x 1.0
         # exact, which is what "scatter-add" promises
         out_ref[:] += jax.lax.dot_general(
-            a, b, (((0,), (0,)), ((), ())),
+            a, b, (((1,), (1,)), ((), ())),
             precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
@@ -344,29 +386,54 @@ def bsc_scatter_add(vals: jax.Array, idx: jax.Array, n: int,
     """Fused dense reconstruction: scatter-add (value, index) pairs into
     a flat fp32 vector of length ``n``.  Negative indices are sentinel
     padding and contribute nothing; colliding indices accumulate (the
-    all-parties aggregate of compression/bisparse.py's decompress)."""
+    all-parties aggregate of compression/bisparse.py's decompress).  The
+    pairs may come in any order."""
     import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     m = vals.shape[0]
-    mp = max(_CHUNK, -(-m // _CHUNK) * _CHUNK)
-    if mp != m:
-        vals = jnp.concatenate(
-            [vals.astype(jnp.float32), jnp.zeros((mp - m,), jnp.float32)])
-        idx = jnp.concatenate(
-            [idx.astype(jnp.int32), jnp.full((mp - m,), -1, jnp.int32)])
-    rows = max(1, -(-n // _LANES))
-    out_rows = min(_OUT_ROWS, -(-rows // _BLK_ROWS) * _BLK_ROWS)
-    rowsp = -(-rows // out_rows) * out_rows
+    chunks = max(1, -(-m // _CHUNK))
+    # whole (8, 128) tiles; the last output block may hang over the end
+    # (Pallas masks what it writes there), so a bucket of whole tiles,
+    # which every large one is, comes back without a copy
+    rows = -(-max(n, 1) // _BLK) * _BLK_ROWS
+    out_rows = min(_OUT_ROWS, rows)
+    blocks = -(-rows // out_rows)
+    idx = idx.astype(jnp.int32)
+    key = jnp.where(idx < 0, _SENTINEL_KEY, idx)
+    vals = vals.astype(jnp.float32)
+    if chunks * _CHUNK != m:
+        pad = (0, chunks * _CHUNK - m)
+        key = jnp.pad(key, pad, constant_values=_SENTINEL_KEY)
+        vals = jnp.pad(vals, pad)
+    if blocks == 1 or chunks == 1:
+        # every block meets every chunk and the product is the sum: the
+        # schedule is static and the pairs' order does not matter
+        t = jnp.arange(blocks * chunks, dtype=jnp.int32)
+        blk, chk = t // chunks, t % chunks
+        total = jnp.full((1,), blocks * chunks, jnp.int32)
+    else:
+        # one ascending order, whatever came: a party's primaries then
+        # its ties, several parties' runs, `lax.top_k`'s order of
+        # magnitude.  A sort of the m pairs, never of anything the
+        # bucket's size; on the chip it is a tenth of this function's
+        # time (PERF.md, PR 25), so pairs already in order pay it too
+        key, vals = jax.lax.sort((key, vals), num_keys=1, is_stable=False)
+        blk, chk, total = scatter_visits(
+            key.reshape(chunks, _CHUNK), blocks, out_rows * _LANES)
+    pairs = pl.BlockSpec((None, 1, _CHUNK),
+                         lambda t, blk, chk, total: (chk[t], 0, 0))
     out = pl.pallas_call(
         functools.partial(_scatter_kernel, out_rows),
-        grid=(rowsp // out_rows, mp // _CHUNK),
-        in_specs=[
-            pl.BlockSpec((_CHUNK, 1), lambda b, c: (c, 0)),
-            pl.BlockSpec((_CHUNK, 1), lambda b, c: (c, 0)),
-        ],
-        out_specs=pl.BlockSpec((out_rows, _LANES), lambda b, c: (b, 0)),
-        out_shape=jax.ShapeDtypeStruct((rowsp, _LANES), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(blk.shape[0],),
+            in_specs=[pairs, pairs],
+            out_specs=pl.BlockSpec(
+                (out_rows, _LANES), lambda t, blk, chk, total: (blk[t], 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.float32),
         interpret=interpret,
-    )(vals.astype(jnp.float32).reshape(mp, 1),
-      idx.astype(jnp.int32).reshape(mp, 1))
+    )(blk, chk, total, vals.reshape(chunks, 1, _CHUNK),
+      key.reshape(chunks, 1, _CHUNK))
     return out.reshape(-1)[:n]

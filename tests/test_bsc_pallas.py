@@ -181,6 +181,138 @@ def test_scatter_add_all_sentinel():
     np.testing.assert_array_equal(np.asarray(out), np.zeros(500))
 
 
+from geomx_tpu.ops.bsc_pallas import _CHUNK, _OUT_ROWS  # noqa: E402
+
+_BLOCK = _OUT_ROWS * 128   # elements of one decompress output block
+
+
+def _party_run(rng, n, k, real):
+    """One party's wire format: ``real`` distinct ascending indices, the
+    last tenth of them a second ascending run (the ties), then
+    sentinels up to k."""
+    idx = np.full(k, -1, np.int32)
+    picked = rng.choice(n, real, replace=False)
+    ties = real // 10
+    idx[:real - ties] = np.sort(picked[:real - ties])
+    idx[real - ties:real] = np.sort(picked[real - ties:])
+    return idx
+
+
+def _decompress_cases():
+    rng = np.random.RandomState(25)
+    cases = {}
+    n = 5 * _BLOCK
+    cases["one-ascending-run"] = (
+        n, np.sort(rng.choice(n, 3000, replace=False)).astype(np.int32))
+    for parties in (1, 2, 4):
+        # every party draws from the same tenth of the bucket: the runs
+        # collide with each other, as parties' selections do
+        cases[f"{2 * parties}-runs-{parties}-parties"] = (n, np.concatenate(
+            [_party_run(rng, n // 10, 1400, 1300) * 10 % n
+             for _ in range(parties)]))
+    cases["unsorted-as-top-k-hands-them-over"] = (
+        n, rng.randint(0, n, 2600).astype(np.int32))
+    cases["all-sentinels-many-chunks"] = (n, np.full(1300, -1, np.int32))
+    cases["every-pair-in-one-block"] = (
+        n, (2 * _BLOCK + np.sort(rng.choice(_BLOCK, 1500, replace=False))
+            ).astype(np.int32))
+    cases["first-and-last-block-only"] = (n, np.concatenate(
+        [np.sort(rng.choice(_BLOCK, 700, replace=False)),
+         4 * _BLOCK + np.sort(rng.choice(_BLOCK, 700, replace=False)),
+         np.full(136, -1)]).astype(np.int32))
+    # 40 pairs in block 0, 300 in block 1, the chunk's other 172 and the
+    # next chunk in block 2: chunk 0 straddles three blocks
+    cases["a-chunk-straddles-three-blocks"] = (n, np.concatenate(
+        [np.sort(rng.choice(_BLOCK, 40, replace=False)),
+         _BLOCK + np.sort(rng.choice(_BLOCK, 300, replace=False)),
+         2 * _BLOCK + np.sort(rng.choice(_BLOCK, 600, replace=False))]
+    ).astype(np.int32))
+    cases["n-below-one-block"] = (
+        7040, np.sort(rng.choice(7040, 1100, replace=False)).astype(np.int32))
+    odd = 3 * _BLOCK + 8 * 128 * 5 + 77     # whole tiles short of a block
+    cases["n-not-a-multiple-of-the-block"] = (
+        odd, np.append(np.sort(rng.choice(odd, 1500, replace=False)),
+                       odd - 1).astype(np.int32))
+    cases["m-not-a-multiple-of-the-chunk"] = (
+        n, np.sort(rng.choice(n, 2 * _CHUNK + 13, replace=False)
+                   ).astype(np.int32))
+    cases["one-chunk-many-blocks"] = (
+        n, np.sort(rng.choice(n, 300, replace=False))[::-1].astype(np.int32))
+    return cases
+
+
+_DECOMPRESS_CASES = _decompress_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_DECOMPRESS_CASES))
+def test_scatter_add_against_the_jnp_oracle(case):
+    """Exact scatter-add whatever the pairs' order and wherever they
+    fall: whole-number values make every collision sum exact, so the
+    result is bit-identical to ``zeros(n).at[idx].add(vals)`` in any
+    summation order."""
+    n, idx = _DECOMPRESS_CASES[case]
+    rng = np.random.RandomState(len(case))
+    vals = np.where(idx >= 0, np.round(rng.normal(0, 8, idx.shape[0])),
+                    3.0).astype(np.float32)   # a sentinel's value is dropped
+    got = bsc_scatter_add(jnp.asarray(vals), jnp.asarray(idx), n,
+                          interpret=True)
+    want = jnp.zeros((n,), jnp.float32).at[
+        jnp.where(idx >= 0, idx, n)].add(jnp.asarray(vals), mode="drop")
+    assert got.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _visits(idx, n):
+    """The schedule ``bsc_scatter_add`` computes for ascending ``idx``,
+    as numpy: (blk, chk) of the live visits, blocks, chunks."""
+    from geomx_tpu.ops.bsc_pallas import _SENTINEL_KEY, scatter_visits
+    blocks = -(-n // _BLOCK)
+    chunks = -(-idx.shape[0] // _CHUNK)
+    key = np.full(chunks * _CHUNK, _SENTINEL_KEY, np.int32)
+    key[:idx.shape[0]] = np.where(idx >= 0, idx, _SENTINEL_KEY)
+    blk, chk, total = jax.jit(
+        lambda k: scatter_visits(k, blocks, _BLOCK))(
+            jnp.asarray(key.reshape(chunks, _CHUNK)))
+    assert blk.shape == chk.shape == (blocks + chunks,)
+    total = int(total[0])
+    return (np.asarray(blk)[:total], np.asarray(chk)[:total], blocks, chunks,
+            key.reshape(chunks, _CHUNK))
+
+
+@pytest.mark.parametrize("n,k,emitted", [
+    (31_254_528, 312_546, 0.957),   # BERT-large's token embedding bucket
+    (4_194_304, 41_944, 1.0),       # an FFN matrix
+    (2_359_296, 23_593, 0.5),       # ResNet-18 layer4, half sentinels
+])
+def test_decompress_visits_are_the_sum_not_the_product(n, k, emitted):
+    """What the schedule is for: (block, chunk) visits in proportion to
+    ``out_blocks + chunks``, where the parent's grid made their product
+    (1,165,788 steps for the embedding bucket).  Read from the visit
+    list the implementation computes; the kernel is not run."""
+    rng = np.random.RandomState(k % 1000)
+    real = int(k * emitted)
+    idx = np.full(k, -1, np.int32)
+    idx[:real] = np.sort(rng.choice(n, real, replace=False))
+    blk, chk, blocks, chunks, key = _visits(idx, n)
+    assert len(blk) <= blocks + 2 * chunks
+    assert len(blk) <= blocks + chunks          # the bound it is built to
+    # every block is visited, in order, its visits next to each other
+    assert blk[0] == 0 and blk[-1] == blocks - 1
+    assert set(np.diff(blk)) <= {0, 1} and set(np.diff(chk)) <= {0, 1}
+    # every chunk meets every block one of its pairs falls into
+    want = {(int(b), c) for c in range(chunks)
+            for b in np.unique(key[c][key[c] < n] // _BLOCK)}
+    assert want <= set(zip(blk.tolist(), chk.tolist()))
+
+
+def test_decompress_visits_with_nothing_to_scatter():
+    """All sentinels: one chunk still walks every block, to zero it."""
+    blk, chk, blocks, chunks, _ = _visits(np.full(2000, -1, np.int32),
+                                          5 * _BLOCK)
+    np.testing.assert_array_equal(blk, np.arange(blocks))
+    assert set(chk) == {0}
+
+
 def test_value_carrying_matmuls_are_full_precision():
     """Found on a v5e (PR 21), invisible in interpret mode: at the MXU's
     default precision an fp32 ``dot_general`` rounds its operands to
@@ -197,9 +329,13 @@ def test_value_carrying_matmuls_are_full_precision():
                 if site.primitive == "dot_general"]
 
     highest = (jax.lax.Precision.HIGHEST,) * 2
-    f, i = jnp.zeros((64,), jnp.float32), jnp.zeros((64,), jnp.int32)
-    assert dot_precisions(lambda v, ix: bsc_scatter_add(v, ix, n=4096),
-                          f, i) == [highest]
+    # the decompress: every matmul in it carries values, however many
+    # the schedule makes; on pairs enough for the staircase schedule too
+    for m in (64, 2048):
+        f, i = jnp.zeros((m,), jnp.float32), jnp.zeros((m,), jnp.int32)
+        dec = dot_precisions(lambda v, ix: bsc_scatter_add(v, ix, n=65536),
+                             f, i)
+        assert dec and set(dec) == {highest}, dec
     g = jnp.zeros((4096,), jnp.float32)
     sel = dot_precisions(lambda a, t: bsc_select_pack(a, a, a, t, k=41),
                          g, jnp.float32(0.5))
@@ -336,6 +472,24 @@ def test_bucket_kernels_lower_to_tpu_mosaic_without_a_device(rng):
 
 # ---------- lowered-HLO structure regression ----------
 
+def _largest_before_the_kernel(fn, *args):
+    """Elements of the largest array any equation produces ahead of the
+    first Pallas kernel (all of them where there is none): what a path
+    sets up in HBM beside its output."""
+    import itertools
+
+    from geomx_tpu.analysis.core import walk_jaxpr
+
+    sites = itertools.takewhile(
+        lambda site: site.primitive != "pallas_call",
+        walk_jaxpr(jax.make_jaxpr(fn)(*args)))
+    # a `jit` equation wraps the equations that follow; its results are
+    # theirs
+    return max(int(np.prod(v.aval.shape)) for site in sites
+               if site.primitive not in ("jit", "pjit")
+               for v in site.eqn.outvars if hasattr(v.aval, "shape"))
+
+
 def test_fused_paths_remove_dense_intermediates(rng):
     """The structural claim of the fused kernel layer, checked on the
     shared lowered-HLO assertions library (geomx_tpu/analysis/hlo.py —
@@ -369,11 +523,19 @@ def test_fused_paths_remove_dense_intermediates(rng):
     # concats) stay; everything dense-sized is gone
     assert sel["dense_unfused"] >= 3 and sel["dense_fused"] == 0, sel
 
+    # the decompress: no XLA scatter, and nothing of the bucket's size
+    # beside the output.  Its schedule sorts the m PAIRS where they come
+    # out of order and works on per-chunk arrays; what the guard forbids
+    # is a sort, scan or scatter over the n ELEMENTS
     dec = compare_paths(
         lambda a, b: cj.decompress(a, b, n),
         lambda a, b: cf.decompress(a, b, n), vals, idx,
-        dense_ops=("scatter", "sort"))
+        dense_ops=("scatter",))
     assert_dense_intermediates_removed(dec)
+    assert _largest_before_the_kernel(
+        lambda a, b: cf.decompress(a, b, n), vals, idx) <= m + 512
+    assert _largest_before_the_kernel(
+        lambda a, b: cj.decompress(a, b, n), vals, idx) == n
 
     leaves = [jnp.asarray(rng.normal(0, 1, s).astype(np.float32))
               for s in (432, 16, 2304, 16, 9216, 64, 640, 10)]

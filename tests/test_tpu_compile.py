@@ -3,7 +3,8 @@
 Every Pallas kernel of the main path is compiled by the installed
 libtpu for a *described* ``v5e:2x2`` device (no chip attached) at the
 flagship's size (ResNet-20: 65 leaves, 272,474 parameters, k = 2,725)
-and at one large size (4M elements / L = 8,192).  This is the guard the
+and at one large size (4M elements / L = 8,192); the decompress also at
+the chip benchmark's own bucket sizes.  This is the guard the
 Mosaic-lowering tests (``jax.export`` + ``"tpu_custom_call" in
 mlir_module()``) cannot give: a kernel that lowers can still be refused
 by the chip's compiler for an unaligned slice or for VMEM it does not
@@ -163,6 +164,14 @@ CASES = {
     "bsc_scatter_add-resnet20": lambda: _scatter(RESNET20_BUCKET,
                                                  2 * RESNET20_K),
     "bsc_scatter_add-4M": lambda: _scatter(BIG, 4 * (BIG // 100)),
+    # the benchmark's own buckets (a leaf larger than a bucket's capacity
+    # has a bucket of its own): n, k = ceil(n / 100)
+    "bsc_scatter_add-bertlarge-embedding": lambda: _scatter(31_254_528,
+                                                            312_546),
+    "bsc_scatter_add-bertlarge-ffn": lambda: _scatter(4_194_304, 41_944),
+    "bsc_scatter_add-bertlarge-ffn-2-parties": lambda: _scatter(
+        4_194_304, 2 * 41_944),
+    "bsc_scatter_add-resnet18-layer4": lambda: _scatter(2_359_296, 23_593),
     "fused_flatten-resnet20": lambda: _bucket_case(
         "flatten", _resnet20_leaves, 4 << 20),
     "fused_flatten-4M": lambda: _bucket_case("flatten", _big_leaves, 16 << 20),
