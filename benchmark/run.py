@@ -2,9 +2,11 @@
 
     python3 -m benchmark.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-The last line of standard output is the result object the driver reads;
-earlier lines carry the itemised set-up, every segment's rate, every
-number compared beside its limit, and the time the check took.
+The last line of standard output is the result object the driver reads
+(its last key, `checks`, holds every number compared beside its limit, and
+the same are standard error's last lines); earlier lines carry the itemised set-up, every segment's rate, the host
+loop's counters of the window (`LOOP_STATS`), every number compared
+beside its limit, and the time the check took.
 
 Order of one run (PERF.md, section 2):
 
@@ -290,10 +292,10 @@ def run_reference(cell, shapes, x, y, seed: int, precision: str = "float32"):
     for i in range(traffic["n_check"]):
         xs, ys = x[i * rows:(i + 1) * rows], y[i * rows:(i + 1) * rows]
         batches.append((xs.reshape(slots + (-1,) + xs.shape[1:]),
-                        ys.reshape(slots + (-1,))))
-    params = make_weights(cell["family"], shapes, seed)
+                        ys.reshape(slots + (-1,) + ys.shape[1:])))
     return reference_steps(
-        cell["family"].reference_loss(config, Numerics(precision)), params,
+        cell["family"].reference_loss(config, Numerics(precision)),
+        lambda: make_weights(cell["family"], shapes, seed),
         batches, config["optimizer"], traffic["geoconfig"]["compression"],
         traffic["bucket_bytes"])
 
@@ -447,6 +449,11 @@ def run_cell(reg: Registry, name: str, seed: int, seconds: float,
                          "rates_per_chip": window["rates_per_chip"],
                          "window_s": window["window_s"]})})
 
+    # the window's host-loop counters, untraced runs too: a stall gets its
+    # phase and step (`phases[p].max_s`, `max_step`)
+    from benchmark.layer_metrics import _step_layers
+    loop_stats = _step_layers.loop_stats({})
+
     t_check = time.perf_counter()
     reference = run_reference(cell, shapes, x, y, seed)
     numbers = check.compare(program, reference,
@@ -466,15 +473,22 @@ def run_cell(reg: Registry, name: str, seed: int, seconds: float,
               "count": jax.device_count(), "memory_peak_bytes": int(peak_bytes)}
     result = {"correct": bool(correct), "attempted": attempted,
               "failed": failed, "metrics": {}, "device": device}
+    # each number compared beside its limit, in the result's line too, as
+    # its last key (a value that is not finite goes as its name: the line
+    # stays JSON)
+    checks = {line["number"]: {
+        "value": line["value"] if math.isfinite(line["value"])
+        else repr(line["value"]), "limit": line["limit"]} for line in lines}
     if rehearsal:
         result["segments"] = window["segments"]
+        result["checks"] = checks
         return result
 
     wire = trainer_wire_bytes(cell, shapes)
     context = {
         "cell": cell, "peaks": peaks, "window": window, "program": program,
         "compiles_in_window": compiles_in_window, "shapes": shapes,
-        "trace": None,
+        "trace": None, "loop_stats": loop_stats,
     }
     if trace:
         from benchmark import trace_reduce
@@ -506,6 +520,7 @@ def run_cell(reg: Registry, name: str, seed: int, seconds: float,
             "peak_hbm_gib": {"value": peak_bytes / 2 ** 30, "unit": "GiB"},
             "setup_s": {"value": setup_s, "unit": "s"},
         }
+    result["checks"] = checks
     return result
 
 
@@ -530,6 +545,9 @@ def main(argv=None) -> int:
     args = parser.parse_args(argv)
     result = run_cell(Registry(ROOT), args.workload, args.seed, args.seconds,
                       bool(args.trace))
+    for name, rec in result["checks"].items():      # standard error's last lines
+        print(f"CHECK {name} = {rec['value']} (limit {rec['limit']})",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

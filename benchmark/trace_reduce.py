@@ -7,7 +7,8 @@ plane `/device:TPU:<n>` per chip with the lines `XLA Modules` (one event
 per execution of a jitted program, named `jit_<fn>(<hash>)`), `XLA Ops`
 (one event per HLO instruction the core ran, in order, named by the
 instruction's whole text: `%fusion.3 = bf16[...] fusion(...)`; a Pallas
-kernel is `%<jitted function>.<n> = ... custom-call(...)`), and `Async
+kernel is `%<jitted function>.<n> = ... custom-call(...)`; a `%while` is
+one event that encloses the events of its body's ops), and `Async
 XLA Ops` (DMA and collective spans that run beside the core).  The plane
 `/host:CPU` has one line per thread; Python-level spans
 (`TraceAnnotation`, `PjitFunction(<fn>)`) are on the line `python3`.
@@ -53,6 +54,23 @@ def merged(intervals):
             out[-1][1] = max(out[-1][1], b)
         else:
             out.append([a, b])
+    return out
+
+
+def own_ns(events):
+    """[(name, nanoseconds)] of one line's events: each event's span less
+    the spans of the events nested directly in it.  On the `XLA Ops` line a
+    `while` is one event that encloses the events of its body's ops, every
+    iteration's (looked at on the chip, PERF.md, PR 26): summing spans
+    would count a loop's time twice, the own times sum to the busy time."""
+    out, open_ = [], []                 # open_: (end, index into out)
+    for name, a, b in sorted(events, key=lambda e: (e[1], -e[2])):
+        while open_ and open_[-1][0] <= a:
+            open_.pop()
+        if open_:
+            out[open_[-1][1]][1] -= min(b, open_[-1][0]) - a
+        out.append([name, b - a])
+        open_.append((b, len(out) - 1))
     return out
 
 
@@ -135,11 +153,11 @@ def reduce_planes(chips: dict, host: list) -> dict:
 
     ops = inside[busiest]
     by_op, by_family = {}, {}
-    for text, a, b in ops:
+    for text, own in own_ns(ops):
         name = op_name(text)
-        by_op[name] = by_op.get(name, 0.0) + (b - a)
+        by_op[name] = by_op.get(name, 0.0) + own
         fam = op_family(name)
-        by_family[fam] = by_family.get(fam, 0.0) + (b - a)
+        by_family[fam] = by_family.get(fam, 0.0) + own
 
     busy = merged([(a, b) for _, a, b in ops])
     gaps = sorted(((b0, a1) for (_, b0), (a1, _) in zip(busy, busy[1:])),

@@ -38,7 +38,15 @@ def test_sound_runs_are_correct(name, capsys):
     assert result["metrics"] == {}          # no number under a metric's name
     # every number compared is printed beside its limit
     assert out.count('CHECK {"number"') >= 5
+    # the window's host-loop counters, once, though nothing was traced
+    assert out.count("LOOP_STATS {") == 1 and '"fit/log_sync"' in out
     assert '"limit": null' not in out
+    # and in the result's line, as its last key
+    assert list(result)[-1] == "checks"
+    assert set(result["checks"]) >= {"loss_gap", "first_grad_error",
+                                     "delta_gap", "compiles_in_window"}
+    assert all(rec["value"] <= rec["limit"]
+               for rec in result["checks"].values())
 
 
 def test_float32_program_meets_the_reference_to_rounding(capsys):

@@ -87,3 +87,64 @@ def test_the_window_is_whole_step_programs_on_made_up_planes():
     assert s["idle_gaps"][0][1:] == [pytest.approx(50e-9), pytest.approx(80e-9)]
     with pytest.raises(ValueError):
         trace_reduce.reduce_planes({"/device:TPU:0": {"XLA Ops": []}}, [])
+
+
+def test_an_events_own_time_is_its_span_less_what_is_nested_in_it():
+    """A `while` encloses its body's ops on the `XLA Ops` line: summed by
+    name, the own times give the busy time, the spans count the loop
+    twice.  Made-up events: a loop of two iterations (a fusion and a
+    copy each, a 5 ns hole in the second), an inner loop nested in the
+    second iteration's fusion slot, and an op after the loop."""
+    events = [("%while.1 = () while()", 100.0, 200.0),
+              ("%fusion.1 = f32[] fusion()", 100.0, 130.0),
+              ("%copy.2 = f32[] copy()", 130.0, 150.0),
+              ("%while.7 = () while()", 150.0, 180.0),
+              ("%fusion.9 = f32[] fusion()", 150.0, 175.0),
+              ("%copy.2 = f32[] copy()", 180.0, 195.0),
+              ("%reduce.3 = f32[] reduce()", 200.0, 240.0)]
+    own = trace_reduce.own_ns(events[::-1])         # any order
+    assert [(trace_reduce.op_name(t), ns) for t, ns in own] == [
+        ("while.1", 5.0), ("fusion.1", 30.0), ("copy.2", 20.0),
+        ("while.7", 5.0), ("fusion.9", 25.0), ("copy.2", 15.0),
+        ("reduce.3", 40.0)]
+    chips = {"/device:TPU:0": {
+        "XLA Ops": events, "XLA Modules": [("jit_step(1)", 100.0, 240.0)]}}
+    s = trace_reduce.reduce_planes(chips, [])
+    assert s["busy_s_busiest"] == pytest.approx(140e-9)
+    assert sum(s["by_op_s"].values()) == pytest.approx(140e-9)
+    assert s["by_family_s"]["while"] == pytest.approx(10e-9)
+    assert s["by_family_s"]["copy"] == pytest.approx(35e-9)
+    # events that only touch are not nested
+    flat = [("%a.1 = f32[] a()", 0.0, 10.0), ("%b.1 = f32[] b()", 10.0, 30.0)]
+    assert [ns for _, ns in trace_reduce.own_ns(flat)] == [10.0, 20.0]
+
+
+def test_a_scans_while_is_not_counted_twice_on_the_recorded_trace():
+    """`record_trace.py scan` on a TPU v5 lite (PR 26): three calls of one
+    jitted step that holds a matmul, a `lax.scan` of eight iterations and
+    two reductions.  On the `XLA Ops` line each call's `%while` is one
+    event of 743 us that encloses its body's 24 op events, so the 105
+    events' spans sum to 4.790 ms where the device was busy for 2.560 ms:
+    the per-op and per-family times are own times and sum to the busy
+    time, the loop itself keeping 97 ns."""
+    path = os.path.join(ROOT, "benchmark", "testdata", "scan.xplane.pb")
+    chips, host = trace_reduce.read_planes(path)
+    ops = chips["/device:TPU:0"]["XLA Ops"]
+    loops = [b - a for text, a, b in ops
+             if trace_reduce.op_name(text) == "while"]
+    assert len(ops) == 105 and len(loops) == 3
+    assert sum(loops) == pytest.approx(2230.029e3)
+    assert sum(b - a for _, a, b in ops) == pytest.approx(4789.627e3)
+    s = trace_reduce.reduce_planes(chips, host)
+    assert s["steps"] == 3 and s["step_module"] == "jit_scan_step"
+    assert s["busy_s_busiest"] == pytest.approx(2559.695e-6, rel=1e-9)
+    assert sum(s["by_op_s"].values()) == pytest.approx(s["busy_s_busiest"],
+                                                       rel=1e-9)
+    assert sum(s["by_family_s"].values()) == pytest.approx(
+        s["busy_s_busiest"], rel=1e-9)
+    assert s["by_family_s"]["while"] == pytest.approx(97e-9)
+    # the body's matmul, 24 executions of 90.468 us, and the one before the
+    # loop's three
+    assert s["by_family_s"]["convert_reduce_fusion"] == pytest.approx(
+        2188.875e-6, rel=1e-6)
+    assert s["by_family_s"]["fusion"] == pytest.approx(272.221e-6, rel=1e-6)
