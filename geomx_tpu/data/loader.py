@@ -229,8 +229,10 @@ class GeoDataLoader:
                 xb = xflat.reshape(
                     (topo.num_parties, topo.workers_per_party, b)
                     + self.x.shape[1:])
+                # class labels [N] or per-token labels [N, L]
                 yb = self.y[sel.reshape(-1)].reshape(
-                    (topo.num_parties, topo.workers_per_party, b))
+                    (topo.num_parties, topo.workers_per_party, b)
+                    + self.y.shape[1:])
             if self.x_sharding is not None:
                 with profile_scope("loader/device_put",
                                    args={"step": step}):

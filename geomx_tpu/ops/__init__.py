@@ -21,6 +21,13 @@ makes one HBM round trip:
   flat fp32 buckets in one VMEM-resident pass per bucket, replacing
   the per-leaf optax chain on the hot path (``GEOMX_FUSED_OPTIM``).
 
+Two ops of a decoder's layers are imported from their modules:
+``kda.kda_chunked`` (the chunkwise gated delta rule with a per-channel
+decay; plain XLA) and ``held_experts.held_experts`` (the routed experts
+one chip holds: the sorted assignments walked a pool at a time in a loop
+of as many trips as pools exist, the SwiGLU as JAX's megablox grouped
+products).
+
 Kernels run natively on TPU and in Pallas interpret mode elsewhere
 (tests exercise them on CPU via interpret mode).
 ``GEOMX_FUSED_KERNELS=0`` is the master opt-out for the fused

@@ -53,10 +53,12 @@ def _fa_kernel_nolse(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                acc_ref, *, scale, block_q, block_k, num_k, kv_len,
                causal):
-    """Grid (BH, nq, nk), k innermost.  Blocks: q/o [1, block_q, D];
-    k/v [1, block_k, D]; lse out [1, block_q, LANES] (lane-replicated;
-    None on the inference path).  Scratch m/l [block_q, LANES] and acc
-    [block_q, D] carry the online softmax across the k dim."""
+    """Grid (BH, nq, nk), k innermost.  Blocks: q [1, block_q, D], k
+    [1, block_k, D]; v [1, block_k, Dv] and o [1, block_q, Dv] (Dv may
+    differ from D: latent attention has 192-wide q/k and 128-wide v);
+    lse out [1, block_q, LANES] (lane-replicated; None on the inference
+    path).  Scratch m/l [block_q, LANES] and acc [block_q, Dv] carry the
+    online softmax across the k dim."""
     iq = pl.program_id(1)
     ik = pl.program_id(2)
 
@@ -128,13 +130,13 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                              causal: bool = False, block_q: int = 128,
                              block_k: int = 128, interpret: bool = False,
                              with_lse: bool = True):
-    """Fused attention forward; returns (out [B, L, H, D] in q's dtype,
+    """Fused attention forward; returns (out [B, L, H, Dv] in q's dtype,
     lse [B, H, L] f32 or None) — lse is the per-row logsumexp the flash
     backward kernels consume.  ``with_lse=False`` (the inference path)
     skips the lse output entirely: XLA cannot dead-code-eliminate a
     Pallas output, so a discarded lse would still cost its HBM write."""
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
 
     bq, bk = min(block_q, Lq), min(block_k, Lk)
@@ -149,7 +151,7 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
 
     common = dict(scale=scale, block_q=bq, block_k=bk, num_k=nk,
                   kv_len=Lk, causal=causal)
-    ospec = pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0))
+    ospec = pl.BlockSpec((1, bq, Dv), lambda bh, iq, ik: (bh, iq, 0))
     lspec = pl.BlockSpec((1, bq, _LANES), lambda bh, iq, ik: (bh, iq, 0))
     res = pl.pallas_call(
         functools.partial(_fa_kernel if with_lse else _fa_kernel_nolse,
@@ -158,23 +160,23 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
         in_specs=[
             pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
             pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
+            pl.BlockSpec((1, bk, Dv), lambda bh, iq, ik: (bh, ik, 0)),
         ],
         out_specs=[ospec, lspec] if with_lse else ospec,
         out_shape=(
-            [jax.ShapeDtypeStruct((B * H, Lqp, D), q.dtype),
+            [jax.ShapeDtypeStruct((B * H, Lqp, Dv), q.dtype),
              jax.ShapeDtypeStruct((B * H, Lqp, _LANES), jnp.float32)]
             if with_lse
-            else jax.ShapeDtypeStruct((B * H, Lqp, D), q.dtype)),
+            else jax.ShapeDtypeStruct((B * H, Lqp, Dv), q.dtype)),
         scratch_shapes=[
             pltpu.VMEM((bq, _LANES), jnp.float32),  # running max m
             pltpu.VMEM((bq, _LANES), jnp.float32),  # normalizer l
-            pltpu.VMEM((bq, D), jnp.float32),       # output accumulator
+            pltpu.VMEM((bq, Dv), jnp.float32),      # output accumulator
         ],
         interpret=interpret,
     )(qh, kh, vh)
     out, lse = res if with_lse else (res, None)
-    out = out.reshape(B, H, Lqp, D).transpose(0, 2, 1, 3)[:, :Lq]
+    out = out.reshape(B, H, Lqp, Dv).transpose(0, 2, 1, 3)[:, :Lq]
     if with_lse:
         lse = lse[..., 0].reshape(B, H, Lqp)[..., :Lq]
     return out, lse
@@ -186,9 +188,9 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: bool = False) -> jax.Array:
     """Fused attention forward: softmax(QK^T / sqrt(D)) V.
 
-    q, k, v: [B, L, H, D] (L may differ between q and k/v only via
-    padding — the kernel masks keys past k's length).  Returns [B, L, H,
-    D] in q's dtype.  Gradients flow via the flash backward of
+    q, k: [B, L, H, D]; v: [B, L, H, Dv], Dv = D or not (L may differ
+    between q and k/v only via padding — the kernel masks keys past k's
+    length).  Returns [B, L, H, Dv] in q's dtype.  Gradients flow via the flash backward of
     :func:`fused_attention`; differentiate THAT, not this.
     """
     return flash_attention_with_lse(q, k, v, causal=causal,
@@ -303,9 +305,10 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
     the [L, L] score matrix — p is recomputed per tile from the
     forward's logsumexp (the standard flash-attention backward;
     delta_i = rowsum(dO_i * O_i) folds the softmax normalizer's
-    gradient)."""
+    gradient).  v, out and do may have a head size of their own (Dv);
+    equal sizes give the kernels they always gave."""
     B, Lq, H, D = q.shape
-    Lk = k.shape[1]
+    Lk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
     # delta: [B, H, Lq] — cheap elementwise jnp, no reason to fuse
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -333,13 +336,15 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
     common = dict(scale=scale, block_q=bq, block_k=bk, q_len=Lq,
                   kv_len=Lk, causal=causal)
     qspec = pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, i, 0))
+    dospec = pl.BlockSpec((1, bq, Dv), lambda bh, i, j: (bh, i, 0))
     kspec_q = pl.BlockSpec((1, bk, D), lambda bh, i, j: (bh, j, 0))
+    vspec_q = pl.BlockSpec((1, bk, Dv), lambda bh, i, j: (bh, j, 0))
     rspec = pl.BlockSpec((1, bq, _LANES), lambda bh, i, j: (bh, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, num_k=nk, **common),
         grid=(B * H, nq, nk),
-        in_specs=[qspec, kspec_q, kspec_q, qspec, rspec, rspec],
+        in_specs=[qspec, kspec_q, vspec_q, dospec, rspec, rspec],
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B * H, Lqp, D), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
@@ -348,22 +353,24 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
 
     # dkv grid: (BH, nk, nq) — q innermost; index maps swap accordingly
     kspec_k = pl.BlockSpec((1, bk, D), lambda bh, i, j: (bh, i, 0))
+    vspec_k = pl.BlockSpec((1, bk, Dv), lambda bh, i, j: (bh, i, 0))
     qspec_k = pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, j, 0))
+    dospec_k = pl.BlockSpec((1, bq, Dv), lambda bh, i, j: (bh, j, 0))
     rspec_k = pl.BlockSpec((1, bq, _LANES), lambda bh, i, j: (bh, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, num_q=nq, **common),
         grid=(B * H, nk, nq),
-        in_specs=[kspec_k, kspec_k, qspec_k, qspec_k, rspec_k, rspec_k],
-        out_specs=[kspec_k, kspec_k],
+        in_specs=[kspec_k, vspec_k, qspec_k, dospec_k, rspec_k, rspec_k],
+        out_specs=[kspec_k, vspec_k],
         out_shape=[jax.ShapeDtypeStruct((B * H, Lkp, D), jnp.float32),
-                   jax.ShapeDtypeStruct((B * H, Lkp, D), jnp.float32)],
+                   jax.ShapeDtypeStruct((B * H, Lkp, Dv), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
     )(kh, vh, qh, doh, lseh, deltah)
 
     def back(x, L, Lp):
-        return x.reshape(B, H, Lp, D).transpose(0, 2, 1, 3)[:, :L]
+        return x.reshape(B, H, Lp, x.shape[-1]).transpose(0, 2, 1, 3)[:, :L]
 
     return back(dq, Lq, Lqp), back(dk, Lk, Lkp), back(dv, Lk, Lkp)
 

@@ -1,0 +1,223 @@
+"""The routed experts one chip holds, of an expert layer that routes over
+all of them (expert parallelism's per-chip part, without the exchange).
+
+Every token chooses ``k`` of ``num_experts`` experts; this chip holds
+``E`` of them, ids ``[offset, offset + E)``.  The assignments that fall
+on held experts are sorted by expert and walked in pools of consecutive
+assignments: gather the pool's tokens once, run the SwiGLU as grouped
+products over the experts' runs inside the pool (JAX's megablox kernels:
+a tile of ``rows`` assignments at a time, only the tiles that hold an
+assignment, each with its expert's weights), scatter-add the weighted
+result once.  The first pool has ``2 * E * rows`` places, twice what even
+routing sends here when ``rows`` is an expert's share, and is always
+walked: a row gather or scatter costs the chip the same per index
+whether the row exists or not (0.1 and 0.4 us, PERF.md), so up to twice
+even load the layer's time hardly moves with what the router does, at
+the price of gathering and scattering places that are mostly empty when
+little arrives.  What arrives beyond it is walked in pools of
+``2 * rows`` by a loop of as many trips as it needs: one expert may take
+every token (``T`` rows, the worst case) and nothing is dropped, because
+no capacity exists to overflow.  The walk counts the rows it processed;
+`dropped` is what arrived less that.
+
+A loop with a data-dependent trip count has no reverse-mode derivative in
+JAX, so the backward pass is written out (`custom_vjp`): the same walk,
+each pool's hidden layer recomputed, weight gradients accumulated in the
+kernels' output.
+
+The kernels run natively on a TPU and in Pallas interpret mode elsewhere.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+# the kernels' tiles over the contracted and the output dimension, at most
+GMM_TILES = (1152, 768)
+TGMM_TILES = (768, 512)
+
+
+def _tile(n: int, cap: int) -> int:
+    """The largest multiple of 128 up to `cap` that divides `n`, or all of
+    `n` where it is no larger than `cap` (or has no such divisor)."""
+    if n <= cap:
+        return n
+    fits = [t for t in range(128, cap + 1, 128) if n % t == 0]
+    return fits[-1] if fits else n
+
+
+def _gmm(lhs, rhs, sizes, rows, interpret, transpose_rhs=False):
+    """lhs [m, k] x rhs [E, k, n] (or [E, n, k], transposed) by runs of
+    `sizes` rows -> [m, n] float32; rows past the runs are not written."""
+    k = lhs.shape[1]
+    n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
+    return gmm(lhs, rhs, sizes, jnp.float32,
+               (rows, _tile(k, GMM_TILES[0]), _tile(n, GMM_TILES[1])),
+               transpose_rhs=transpose_rhs, interpret=interpret)
+
+
+def _tgmm(lhs, rhs, sizes, rows, interpret, into):
+    """`into` [E, k, n] float32 plus, for each run of `sizes` rows,
+    lhs[run]^T [k, rows] x rhs[run] [rows, n]."""
+    return tgmm(lhs.T, rhs, sizes, jnp.float32,
+                (rows, _tile(lhs.shape[1], TGMM_TILES[0]),
+                 _tile(rhs.shape[1], TGMM_TILES[1])),
+                existing_out=into, interpret=interpret)
+
+
+def _pools(num_held: int, rows: int, assignments: int):
+    """(places of the first pool, of each later one, of all that the
+    sorted assignments are padded to)."""
+    first, later = 2 * num_held * rows, 2 * rows
+    beyond = -(-max(assignments - first, 0) // later) * later
+    return first, later, first + beyond
+
+
+def _plan(idx, weights, num_held: int, offset: int, rows: int):
+    """The held assignments in order of their expert, padded to whole
+    pools.  idx [T, k] global expert ids, weights [T, k]."""
+    t, k = idx.shape
+    local = idx.reshape(-1) - offset
+    held = (local >= 0) & (local < num_held)
+    key = jnp.where(held, local, num_held).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    counts = jnp.sum(key[:, None] == jnp.arange(num_held)[None, :], axis=0,
+                     dtype=jnp.int32)                              # [E]
+    pad = (0, _pools(num_held, rows, t * k)[2] - t * k)
+    return {"token": jnp.pad((order // k).astype(jnp.int32), pad),
+            "weight": jnp.pad(
+                weights.reshape(-1)[order].astype(jnp.float32), pad),
+            "order": order, "counts": counts, "ends": jnp.cumsum(counts),
+            "tokens": t}
+
+
+def _walk(plan, num_held: int, rows: int, body, carry):
+    """`body(lo, pool, carry)` over the first pool, always, and over as
+    many later ones as the held assignments reach."""
+    first, later, _ = _pools(num_held, rows, plan["order"].size)
+    carry = body(0, first, carry)
+    trips = -(-jnp.maximum(plan["ends"][-1] - first, 0) // later)
+    return lax.fori_loop(
+        0, trips, lambda c, carry: body(first + c * later, later, carry),
+        carry)
+
+
+def _pool(plan, lo, pool: int):
+    """(token ids, weights, valid, rows of each expert's run) of the
+    `pool` sorted assignments from `lo` on.  Rows past the last held
+    assignment point outside the token range (a gather fills them with
+    zeros, a scatter drops them)."""
+    i = jnp.arange(pool, dtype=jnp.int32)
+    valid = lo + i < plan["ends"][-1]
+    token = jnp.where(valid, lax.dynamic_slice(plan["token"], (lo,), (pool,)),
+                      plan["tokens"] + i)
+    weight = jnp.where(valid,
+                       lax.dynamic_slice(plan["weight"], (lo,), (pool,)), 0.0)
+    ends = jnp.clip(plan["ends"], lo, lo + pool)
+    starts = jnp.clip(plan["ends"] - plan["counts"], lo, lo + pool)
+    return token, weight, valid, ends - starts
+
+
+def _hidden(xs, gate_up, sizes, valid, rows, interpret):
+    """(a, u, silu(a) * u) of a pool, float32; zeros in rows of no run."""
+    au = jnp.where(valid[:, None],
+                   _gmm(xs, gate_up, sizes, rows, interpret), 0.0)
+    a, u = jnp.split(au, 2, axis=-1)
+    return a, u, jax.nn.silu(a) * u
+
+
+def _cast(x, gate, up, down):
+    return (jnp.concatenate([gate, up], axis=-1).astype(x.dtype),
+            down.astype(x.dtype))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def held_experts(x, idx, weights, gate, up, down, offset: int, rows: int,
+                 interpret: bool | None = None):
+    """x [T, d] (the compute dtype), idx [T, k] int, weights [T, k] f32,
+    gate / up [E, d, f], down [E, f, d] (the masters: they are cast to
+    x's dtype once a call, and their gradients come back unrounded).
+    `rows`: the assignments a kernel tile holds.  `interpret`: run the
+    kernels in interpret mode; None: wherever the backend is no TPU.
+    Returns (y [T, d] float32, counts [E] int32, dropped int32)."""
+    return _forward(x, idx, weights, gate, up, down, offset, rows,
+                    interpret)[0]
+
+
+def _forward(x, idx, weights, gate, up, down, offset, rows, interpret):
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    plan = _plan(idx, weights, gate.shape[0], offset, rows)
+    gate_up, down = _cast(x, gate, up, down)
+
+    def body(lo, pool, carry):
+        y, done = carry
+        token, weight, valid, sizes = _pool(plan, lo, pool)
+        xs = x.at[token].get(mode="fill", fill_value=0)
+        h = _hidden(xs, gate_up, sizes, valid, rows, interpret)[2]
+        out = _gmm(h.astype(x.dtype), down, sizes, rows, interpret)
+        out = jnp.where(valid[:, None], out * weight[:, None], 0.0)
+        return (y.at[token].add(out, mode="drop"),
+                done + jnp.sum(valid, dtype=jnp.int32))
+
+    y, done = _walk(
+        plan, gate.shape[0], rows, body,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32)))
+    counts = plan["counts"]
+    return (y, counts, jnp.sum(counts) - done), plan
+
+
+def _fwd(x, idx, weights, gate, up, down, offset, rows, interpret):
+    out, plan = _forward(x, idx, weights, gate, up, down, offset, rows,
+                         interpret)
+    return out, (x, idx, weights, gate, up, down, plan)
+
+
+def _bwd(offset, rows, interpret, res, cotangents):
+    x, idx, weights, gate, up, down, plan = res
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    dy = cotangents[0].astype(x.dtype)
+    gate_up, down_c = _cast(x, gate, up, down)
+
+    def body(lo, pool, carry):
+        dx, dw, dgate_up, ddown = carry
+        token, weight, valid, sizes = _pool(plan, lo, pool)
+        xs = x.at[token].get(mode="fill", fill_value=0)
+        dys = dy.at[token].get(mode="fill", fill_value=0)
+        a, u, h = _hidden(xs, gate_up, sizes, valid, rows, interpret)
+        # <h W_down, dy> = <h, dy W_down^T>: one product gives the weight's
+        # gradient and, scaled by the weight, the hidden layer's
+        g = jnp.where(valid[:, None],
+                      _gmm(dys, down_c, sizes, rows, interpret, True), 0.0)
+        dw = lax.dynamic_update_slice(dw, jnp.sum(h * g, axis=-1), (lo,))
+        dh = g * weight[:, None]
+        sig = jax.nn.sigmoid(a)
+        dau = jnp.concatenate([dh * u * sig * (1.0 + a * (1.0 - sig)),
+                               dh * a * sig], axis=-1).astype(x.dtype)
+        dout = (dys * weight[:, None]).astype(x.dtype)
+        ddown = _tgmm(h.astype(x.dtype), dout, sizes, rows, interpret, ddown)
+        dgate_up = _tgmm(xs, dau, sizes, rows, interpret, dgate_up)
+        dxs = jnp.where(valid[:, None],
+                        _gmm(dau, gate_up, sizes, rows, interpret, True), 0.0)
+        return (dx.at[token].add(dxs, mode="drop"), dw, dgate_up, ddown)
+
+    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
+    dx, dw, dgate_up, ddown = _walk(
+        plan, gate.shape[0], rows, body,
+        (zeros(x), zeros(plan["weight"]), zeros(gate_up), zeros(down)))
+    dgate, dup = jnp.split(dgate_up, 2, axis=-1)
+    # back from sorted order to [T, k]
+    dweights = jnp.zeros((idx.size,), jnp.float32).at[plan["order"]].set(
+        dw[:idx.size], unique_indices=True).reshape(weights.shape)
+    return (dx.astype(x.dtype), None, dweights.astype(weights.dtype),
+            dgate.astype(gate.dtype), dup.astype(up.dtype),
+            ddown.astype(down.dtype))
+
+
+held_experts.defvjp(_fwd, _bwd)

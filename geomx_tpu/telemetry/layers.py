@@ -18,6 +18,8 @@ The vocabulary (docs/telemetry.md has the operator's table):
   compression engine (compression/) inside ``step/sync_grads``;
 - ``<axis>_pipeline/*``: the pipelined sync engine (sync/pipeline.py);
 - ``collective/worker``, ``collective/dc``: the tier collectives;
+- ``kda/*``, ``mla/*``, ``moe/*``, ``lm/loss``: a decoder's layers inside
+  ``step/forward_backward`` (models/kimi_linear.py);
 - ``train/step``, ``fit/*``, ``loader/*``: host spans of the loop.
 """
 
@@ -35,6 +37,16 @@ from geomx_tpu.utils.profiler import profile_scope
 # (``dc_allreduce/bucket``) covers a family.
 SCOPES = (
     ("step/forward_backward", "step program"),
+    # a decoder's layers, opened inside step/forward_backward
+    # (models/kimi_linear.py, ops/kda.py)
+    ("kda/proj", "step program"),
+    ("kda/scan", "kernels"),
+    ("mla/proj", "step program"),
+    ("mla/attention", "kernels"),
+    ("moe/route", "step program"),
+    ("moe/experts", "step program"),
+    ("moe/shared", "step program"),
+    ("lm/loss", "step program"),
     ("step/optimizer", "step program"),
     ("step/metrics", "step program"),
     ("step/sync_grads", "sync algorithm"),
@@ -135,7 +147,12 @@ def op_layers(hlo_text: Iterable[str]) -> Dict[str, OpLayer]:
     ``conditional``, async wrappers), transitively.  Names inside fused
     computations repeat across the module and are left out; a fusion
     carries one ``op_name``, its root's, so ops that straddle a scope
-    boundary are charged to the root's scope.
+    boundary are charged to the root's scope.  An instruction without an
+    ``op_name`` inside the body of a ``while`` or a branch of a
+    ``conditional`` is the loop's own cost (the copies to and from fast
+    memory that the compiler schedules around a scan's steps) and takes
+    the loop's scope, layer and direction; at entry level it stays
+    :data:`UNNAMED`.
 
     ``hlo_text``: ``compiled.as_text()`` or any iterable of its lines (a
     BERT-large step with a kernel call per bucket runs to hundreds of
@@ -143,7 +160,6 @@ def op_layers(hlo_text: Iterable[str]) -> Dict[str, OpLayer]:
     if isinstance(hlo_text, str):
         hlo_text = _lines(hlo_text)
     computations: Dict[str, list] = {}
-    calls: Dict[str, set] = {}
     entry = current = None
     for line in hlo_text:
         if current is None:
@@ -151,7 +167,6 @@ def op_layers(hlo_text: Iterable[str]) -> Dict[str, OpLayer]:
             if head:
                 current = head.group(1)
                 computations[current] = []
-                calls[current] = set()
                 if line.startswith("ENTRY"):
                     entry = current
             continue
@@ -169,26 +184,30 @@ def op_layers(hlo_text: Iterable[str]) -> Dict[str, OpLayer]:
         if opcode in _NO_EVENT:
             continue
         name = _OP_NAME.search(rest)
-        computations[current].append(
-            (ins.group(1), name.group(1) if name else ""))
+        bodies, wrapped = [], []         # of a loop or branch / of a call
         for m in _CALLED.finditer(rest):
             if m.group(1):
-                calls[current].add(m.group(1))
+                bodies.append(m.group(1))
             else:
-                calls[current].update(
+                bodies.extend(
                     t.strip().lstrip("%") for t in m.group(2).split(","))
         if opcode == "call" or opcode.endswith("-start"):
-            calls[current].update(_CALL_TARGET.findall(rest))
+            wrapped = _CALL_TARGET.findall(rest)
+        computations[current].append(
+            (ins.group(1), name.group(1) if name else "", bodies, wrapped))
     table: Dict[str, OpLayer] = {}
-    todo, seen = [entry] if entry else [], set()
+    todo, seen = [(entry, UNNAMED)] if entry else [], set()
     while todo:
-        comp = todo.pop()
+        comp, loop = todo.pop()
         if comp in seen or comp not in computations:
             continue
         seen.add(comp)
-        for name, op_name in computations[comp]:
-            table[name] = classify_op_name(op_name) if op_name else UNNAMED
-        todo.extend(calls[comp])
+        for name, op_name, bodies, wrapped in computations[comp]:
+            table[name] = classify_op_name(op_name) if op_name else loop
+            # only a scope of the vocabulary is handed down
+            inner = table[name] if table[name].scope else UNNAMED
+            todo.extend((body, inner) for body in bodies)
+            todo.extend((target, UNNAMED) for target in wrapped)
     return table
 
 
@@ -204,7 +223,10 @@ class LoopStats:
     Updated in place as each phase ends, so a fit left by an exception
     (the benchmark's ``log_fn`` ends its window so) leaves them whole.
     ``step`` is the iteration in flight, set by the loop; the spans a
-    phase opens carry it."""
+    phase opens carry it.  ``counters``: what the model counts in a step
+    (``metrics["counters"]``: named scalars, e.g. the assignments an
+    expert layer dropped), added up over the steps read at log
+    boundaries: count, total, last, max."""
 
     def __init__(self):
         self.step = 0
@@ -212,7 +234,19 @@ class LoopStats:
         self.wall_s = 0.0
         self.phases = {name: {"count": 0, "total_s": 0.0, "max_s": 0.0,
                               "max_step": -1} for name in FIT_PHASES}
+        self.counters: Dict[str, dict] = {}
         self._start = time.perf_counter()
+
+    def count(self, values: dict) -> None:
+        """One step's counters, as read at a log boundary."""
+        for name, value in values.items():
+            value = float(value)
+            rec = self.counters.setdefault(
+                name, {"count": 0, "total": 0.0, "last": 0.0, "max": value})
+            rec["count"] += 1
+            rec["total"] += value
+            rec["last"] = value
+            rec["max"] = max(rec["max"], value)
 
     @contextlib.contextmanager
     def phase(self, name: str):
@@ -232,8 +266,11 @@ class LoopStats:
                 self.wall_s = end - self._start
 
     def as_dict(self) -> dict:
-        return {"steps": self.steps, "wall_s": self.wall_s,
-                "phases": {k: dict(v) for k, v in self.phases.items()}}
+        out = {"steps": self.steps, "wall_s": self.wall_s,
+               "phases": {k: dict(v) for k, v in self.phases.items()}}
+        if self.counters:
+            out["counters"] = {k: dict(v) for k, v in self.counters.items()}
+        return out
 
 
 # What the last ``Trainer.fit`` of this process left behind, for a reader

@@ -72,8 +72,17 @@ def resolve_precision(config=None) -> str:
 
 
 def make_loss_fn(apply_fn: Callable, mutable_keys=("batch_stats",),
-                 compute_dtype=None):
-    """Standard classification loss closure over a flax apply_fn.
+                 compute_dtype=None, model_loss=None):
+    """Loss closure over a flax apply_fn: ``loss_fn(params, model_state,
+    x, y) -> (loss, (new_model_state, aux))``.  ``aux`` is a dict with
+    ``accuracy`` and, optionally, ``counters`` (named scalars a step,
+    which `fit` adds up in `LoopStats.counters`); the step's metrics are
+    the loss and ``aux``.
+
+    By default the loss is class-label cross-entropy on the model's whole
+    logits.  ``model_loss``: the name of a method of a model that brings
+    its own, ``method(x, y, train=True) -> (loss, aux)`` (a decoder's
+    next-token loss, blocked so that no whole logits array lives).
 
     Images arrive uint8 NHWC; normalization to [0,1] happens on-device so
     the host->device transfer stays 1 byte/pixel.
@@ -93,14 +102,19 @@ def make_loss_fn(apply_fn: Callable, mutable_keys=("batch_stats",),
             x = x.astype(compute_dtype)
         variables = {"params": params, **model_state}
         mut = [k for k in mutable_keys if k in model_state]
+        if model_loss is not None:
+            out = apply_fn(variables, x, y, train=True, method=model_loss,
+                           **({"mutable": mut} if mut else {}))
+            (loss, aux), new_model_state = out if mut else (out, model_state)
+            return loss, (new_model_state, aux)
         if mut:
             logits, new_model_state = apply_fn(variables, x, train=True,
                                                mutable=mut)
         else:
             logits = apply_fn(variables, x, train=True)
             new_model_state = model_state
-        loss = cross_entropy_loss(logits, y)
-        return loss, (new_model_state, logits)
+        aux = {"accuracy": jnp.mean(jnp.argmax(logits, -1) == y)}
+        return cross_entropy_loss(logits, y), (new_model_state, aux)
 
     return loss_fn
 
@@ -436,7 +450,7 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
         # under them, never an op of their own
         with profile_scope("step/forward_backward"):
             fwd_params = sync.forward_params(params, sync_state)
-            (loss, (model_state, logits)), grads = grad_fn(
+            (loss, (model_state, aux)), grads = grad_fn(
                 fwd_params, model_state, xb, yb)
 
             if sp > 1:
@@ -517,8 +531,7 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
             sync_state = dict(sync_state, **{CONTROL_KEY: ctl})
 
         with profile_scope("step/metrics"):
-            acc = jnp.mean(jnp.argmax(logits, -1) == yb)
-            metrics = {"loss": loss, "accuracy": acc}
+            metrics = {"loss": loss, **aux}
             # global mean over every worker for reporting
             if sp > 1:
                 metrics = jax.lax.pmean(metrics, SP_AXIS)

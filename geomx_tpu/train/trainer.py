@@ -24,8 +24,8 @@ from geomx_tpu.sync import get_sync_algorithm
 from geomx_tpu.sync.base import SyncAlgorithm
 from geomx_tpu.telemetry import layers
 from geomx_tpu.topology import HiPSTopology
-from geomx_tpu.train.state import (TrainState, replicate_tree,
-                                   unreplicate_tree)
+from geomx_tpu.train.state import (TrainState, replicate_consuming,
+                                   replicate_tree, unreplicate_tree)
 from geomx_tpu.train.step import build_eval_step, build_train_step, make_loss_fn
 from geomx_tpu.utils.metrics import Measure
 
@@ -59,8 +59,12 @@ class Trainer:
         from geomx_tpu.train.step import resolve_precision
         self._precision = resolve_precision(self.config)
         compute_dtype = jnp.bfloat16 if self._precision == "bf16" else None
-        self.loss_fn = make_loss_fn(model.apply,
-                                    compute_dtype=compute_dtype)
+        # a model that brings its own loss (models/kimi_linear.py) has a
+        # method `loss_and_aux(x, y, train)`
+        self.loss_fn = make_loss_fn(
+            model.apply, compute_dtype=compute_dtype,
+            model_loss="loss_and_aux" if hasattr(model, "loss_and_aux")
+            else None)
         if self._precision == "bf16":
             mdt = getattr(model, "dtype", None)
             if mdt is None or mdt == jnp.float32:
@@ -319,23 +323,21 @@ class Trainer:
                     f"{type(sync_state).__name__}")
             sync_state = dict(sync_state)
             sync_state[CONTROL_KEY] = init_control_operands()
-        state = TrainState(
-            step=jnp.zeros((), jnp.int32),
-            params=params, opt_state=opt_state,
-            model_state=model_state, sync_state=sync_state)
         # the replicated scalar must carry the SAME NamedSharding the
         # compiled step emits for it: a SingleDeviceSharding here makes
         # the second train_step/epoch-runner call a jit cache MISS (the
         # input sharding is part of the key) — one full recompile
         from jax.sharding import NamedSharding, PartitionSpec
-        return TrainState(
-            step=jax.device_put(state.step,
-                                NamedSharding(self.mesh, PartitionSpec())),
-            params=replicate_tree(state.params, self.topology, self.mesh),
-            opt_state=replicate_tree(state.opt_state, self.topology, self.mesh),
-            model_state=replicate_tree(state.model_state, self.topology, self.mesh),
-            sync_state=replicate_tree(state.sync_state, self.topology, self.mesh),
-        )
+        step = jax.device_put(jnp.zeros((), jnp.int32),
+                              NamedSharding(self.mesh, PartitionSpec()))
+        # leaf by leaf, each source let go once its copy exists: the
+        # device never holds the state twice
+        parts = [params, opt_state, model_state, sync_state]
+        del params, opt_state, model_state, sync_state, variables
+        params, opt_state, model_state, sync_state = replicate_consuming(
+            parts, self.topology, self.mesh)
+        return TrainState(step=step, params=params, opt_state=opt_state,
+                          model_state=model_state, sync_state=sync_state)
 
     def make_loader(self, x, y, batch_size: int, split_by_class: bool = False,
                     seed: int = 0, augment: bool = False,
@@ -1349,6 +1351,8 @@ class Trainer:
                         if self._telemetry and "telemetry" in metrics:
                             self._publish_telemetry(metrics["telemetry"],
                                                     it)
+                        if "counters" in metrics:
+                            stats.count(metrics["counters"])
                     if eval_data is not None and eval_every \
                             and it % eval_every == 0:
                         with stats.phase("fit/eval"):

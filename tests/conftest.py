@@ -65,6 +65,17 @@ def pytest_collection_modifyitems(config, items):
         item.add_marker(skip)
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _collected_garbage_between_files():
+    """A file's tests start without the cyclic garbage of the files that
+    ran before them in this worker: trainers and their device arrays that
+    only the collector frees, at a moment of its choosing, which a test
+    that counts live device bytes
+    (benchmark/test_benchmark_reference.py) reads as its own."""
+    import gc
+    gc.collect()
+
+
 @pytest.fixture(scope="session")
 def topo2x4():
     return HiPSTopology(num_parties=2, workers_per_party=4)

@@ -161,3 +161,49 @@ def test_flash_backward_lowers_to_tpu_mosaic_without_a_device():
 
     exp = jax_export.export(jax.jit(f), platforms=("tpu",))(q, q, q, q)
     assert "tpu_custom_call" in exp.mlir_module()
+
+
+def _latent_inputs(seed, b, length, h, d_qk, d_v):
+    rng = np.random.RandomState(seed)
+    draw = lambda d: jnp.asarray(
+        rng.normal(size=(b, length, h, d)).astype(np.float32))
+    return draw(d_qk), draw(d_qk), draw(d_v), draw(d_v)
+
+
+@pytest.mark.parametrize("length,d_qk,d_v", [
+    (64, 192, 128),     # latent attention's head sizes
+    (100, 24, 16),      # ragged L with unequal sizes
+    (64, 16, 32),       # v wider than q and k
+])
+def test_unequal_head_sizes_forward_and_both_backward_kernels(length, d_qk,
+                                                              d_v):
+    """q and k one head size, v (and so out and dO) another, causal: the
+    forward, the dq kernel and the dk/dv kernel against the dense
+    reference's values and vjp."""
+    from geomx_tpu.ops.flash_attention import (flash_attention_bwd,
+                                               flash_attention_with_lse)
+    q, k, v, g = _latent_inputs(21, 1, length, 2, d_qk, d_v)
+    out, lse = flash_attention_with_lse(q, k, v, causal=True, block_q=32,
+                                        block_k=32, interpret=True)
+    assert out.shape == v.shape
+    dense = lambda q, k, v: full_attention_reference(q, k, v, causal=True)
+    ref, vjp = jax.vjp(dense, q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=5e-6, rtol=1e-5)
+    grads = flash_attention_bwd(q, k, v, out, lse, g, causal=True,
+                                block_q=32, block_k=32, interpret=True)
+    for got, want in zip(grads, vjp(g)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=3e-5, rtol=3e-5)
+
+
+def test_unequal_head_sizes_through_fused_attention_gradients():
+    q, k, v, _ = _latent_inputs(22, 2, 48, 2, 24, 16)
+    fused = lambda q, k, v: jnp.sum(fused_attention(q, k, v, True, True) ** 2)
+    dense = lambda q, k, v: jnp.sum(
+        full_attention_reference(q, k, v, causal=True) ** 2)
+    for got, want in zip(jax.grad(fused, argnums=(0, 1, 2))(q, k, v),
+                         jax.grad(dense, argnums=(0, 1, 2))(q, k, v)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)

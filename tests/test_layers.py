@@ -137,6 +137,62 @@ ENTRY %main (a: f32[4]) -> f32[4] {
     assert layers.op_layers(hlo.splitlines()) == table
 
 
+def test_an_unnamed_instruction_in_a_loop_takes_the_loops_scope():
+    """The compiler's own copies inside a scan's body carry no op_name:
+    they are the loop's cost and go where the `while` goes, through a
+    nested loop too; at entry level, and in the body of a loop that has
+    no name itself, they stay unnamed."""
+    hlo = """HloModule jit_s
+
+%inner (q: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %q = (s32[], f32[4]{0}) parameter(0)
+  %g = f32[4]{0} get-tuple-element(%q), index=1
+  %copy-start.9 = (f32[4]{0:S(1)}, f32[4]{0}, u32[]) copy-start(%g)
+  %copy-done.9 = f32[4]{0:S(1)} copy-done(%copy-start.9)
+  ROOT %t.1 = (s32[], f32[4]{0}) tuple(%g, %copy-done.9)
+}
+
+%body (p: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %p = (s32[], f32[4]{0}) parameter(0)
+  %copy.4 = (s32[], f32[4]{0}) copy(%p)
+  %while.2 = (s32[], f32[4]{0}) while(%copy.4), condition=%cond, body=%inner, metadata={op_name="jit(s)/step/forward_backward/transpose(jvp(mixer))/kda/scan/while"}
+  %while.3 = (s32[], f32[4]{0}) while(%copy.4), condition=%cond, body=%bare
+  ROOT %t = (s32[], f32[4]{0}) tuple(%while.2, %while.3)
+}
+
+%bare (r: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %r = (s32[], f32[4]{0}) parameter(0)
+  ROOT %copy.6 = (s32[], f32[4]{0}) copy(%r)
+}
+
+%lonely (r.1: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %r.1 = (s32[], f32[4]{0}) parameter(0)
+  ROOT %copy.8 = (s32[], f32[4]{0}) copy(%r.1)
+}
+
+%cond (p.1: (s32[], f32[4])) -> pred[] {
+  %p.1 = (s32[], f32[4]{0}) parameter(0)
+  ROOT %lt = pred[] constant(false)
+}
+
+ENTRY %main (a: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %a = (s32[], f32[4]{0}) parameter(0)
+  %copy.1 = (s32[], f32[4]{0}) copy(%a)
+  %while.5 = (s32[], f32[4]{0}) while(%copy.1), condition=%cond, body=%lonely
+  ROOT %while.1 = (s32[], f32[4]{0}) while(%while.5), condition=%cond, body=%body, metadata={op_name="jit(s)/step/forward_backward/jvp(mixer)/while"}
+}
+"""
+    table = layers.op_layers(hlo)
+    outer = ("step/forward_backward", "step program", "forward")
+    assert table["while.1"] == table["copy.4"] == outer
+    assert table["while.3"] == table["copy.6"] == outer
+    scan = ("step/forward_backward/kda/scan", "kernels", "backward")
+    assert table["while.2"] == scan
+    assert table["copy-start.9"] == table["copy-done.9"] == scan
+    assert table["copy.1"] == table["while.5"] == table["copy.8"] \
+        == layers.UNNAMED
+
+
 class _SleepyLoader(GeoDataLoader):
     """Sleeps before every fourth batch: steps 3, 7, 11, ..."""
 

@@ -146,6 +146,21 @@ def _flash_bwd(L):
             [qkv, qkv, qkv, qkv, f32(2, 4, L), qkv])
 
 
+def _latent_fwd(L):
+    """Latent attention's head sizes: 192-wide q and k, 128-wide v."""
+    from geomx_tpu.ops import flash_attention
+    bf16 = lambda d: jax.ShapeDtypeStruct((1, L, 8, d), jnp.bfloat16)
+    return (functools.partial(flash_attention, causal=True),
+            [bf16(192), bf16(192), bf16(128)])
+
+
+def _latent_bwd(L):
+    from geomx_tpu.ops import flash_attention_bwd
+    qk, v = f32(1, L, 8, 192), f32(1, L, 8, 128)
+    return (functools.partial(flash_attention_bwd, causal=True),
+            [qk, qk, v, v, f32(1, 8, L), v])
+
+
 def _ring_hop(L):
     from geomx_tpu.parallel._fused_block import _hop_pallas
     qkv, ml = f32(8, L, 64), f32(8, L)
@@ -188,6 +203,9 @@ CASES = {
     "flash_attention-bf16-L8192": lambda: _flash_fwd(8192, jnp.bfloat16),
     "flash_attention_bwd-L100": lambda: _flash_bwd(100),
     "flash_attention_bwd-L8192": lambda: _flash_bwd(8192),
+    "flash_attention-latent-192-128-L8192": lambda: _latent_fwd(8192),
+    "flash_attention_bwd-latent-192-128-L8192": lambda: _latent_bwd(8192),
+    "flash_attention_bwd-latent-192-128-L100": lambda: _latent_bwd(100),
     "fused_ring_hop-L1024": lambda: _ring_hop(1024),
     "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
     "merge_tree-2x82": lambda: _merge(164, 1),
@@ -202,6 +220,51 @@ def test_v5e_compiler_accepts(chip, case):
     fn, shapes = CASES[case]()
     args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
             for s in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_kda_scan(chip, direction):
+    """The chunked delta-rule scan (plain XLA: a `while` over the chunks
+    and the batched products around it) at the chip benchmark's widths,
+    one sequence of 8,192 tokens and 8 of the 32 heads of 128, bf16
+    operands: what the compiler makes of the sub-block layout, and that it
+    fits."""
+    from geomx_tpu.ops.kda import kda_chunked
+    wide = lambda dtype: jax.ShapeDtypeStruct((1, 8, 8192, 128), dtype,
+                                              sharding=chip)
+    args = [wide(jnp.float32), wide(jnp.float32), wide(jnp.bfloat16),
+            wide(jnp.float32),
+            jax.ShapeDtypeStruct((1, 8, 8192), jnp.float32, sharding=chip)]
+    run = functools.partial(kda_chunked, dtype=jnp.bfloat16)
+    if direction == "backward":
+        fn = jax.grad(lambda *a: jnp.sum(run(*a)), argnums=(0, 1, 2, 3, 4))
+    else:
+        fn = run
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert " while(" in compiled.as_text()
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_held_experts(chip, direction):
+    """The held experts' walk at the chip benchmark's sizes (16,384 tokens
+    of 2,304, top 8 of 256, 8 held experts of 1,024, bf16 operands): the
+    grouped-product kernels' tiles have to fit VMEM, forward and
+    backward."""
+    from geomx_tpu.ops.held_experts import held_experts
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=chip)
+    args = [on((16384, 2304), jnp.bfloat16), on((16384, 8), jnp.int32),
+            on((16384, 8), jnp.float32), on((8, 2304, 1024), jnp.float32),
+            on((8, 2304, 1024), jnp.float32), on((8, 1024, 2304), jnp.float32)]
+    run = lambda x, idx, w, *mats: held_experts(x, idx, w, *mats, 0, 512,
+                                                False)
+    if direction == "backward":
+        fn = jax.grad(lambda x, idx, w, *mats: jnp.sum(
+            run(x, idx, w, *mats)[0]), argnums=(0, 2, 3, 4, 5))
+    else:
+        fn = run
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
