@@ -80,8 +80,8 @@ class BiSparseCompressor(Compressor):
         or "sampled" (the reference's sampled-boundary scan,
         ops/sampled_topk.py).  Default: GEOMX_BSC_SELECT if set, else —
         on a TPU with the fused kernels enabled — "sampled" (the fused
-        ops/bsc_pallas.py path IS the sampled scan, now one VMEM-resident
-        pass), else "approx" on TPU and "exact" elsewhere (deterministic
+        ops/bsc_pallas.py path IS the sampled scan, as two streaming
+        passes), else "approx" on TPU and "exact" elsewhere (deterministic
         behavioral tests vs the reference recurrences run on CPU).
         ``approx`` is the legacy boolean spelling of exact/approx.
 
@@ -191,19 +191,23 @@ class BiSparseCompressor(Compressor):
             eff_k = jnp.clip(jnp.round(k * scale), 1.0,
                              float(k)).astype(jnp.int32)
         if self.fused_select:
-            # one VMEM-resident pass: momentum math, boundary select,
-            # fixed-k pack and EF reset fused (ops/bsc_pallas.py); only
-            # the ~8k-element threshold probe runs in XLA.  A traced
-            # eff_k raises the sampled boundary so the kernel emits
-            # ~eff_k pairs — the kernel itself is untouched (thr was
-            # always an operand).
+            # momentum math, boundary select, fixed-k pack and EF reset
+            # fused (ops/bsc_pallas.py: a counting and a placing pass
+            # over the bucket, one call where it is one tile); only the
+            # ~8k-element threshold probe and the placement's schedule
+            # run in XLA.  A traced eff_k raises the sampled boundary so
+            # the kernel emits ~eff_k pairs — the kernel itself is
+            # untouched (thr was always an operand).
             from geomx_tpu.ops.bsc_pallas import (bsc_select_pack,
-                                                  sampled_boundary_guv)
+                                                  sampled_boundary_guv,
+                                                  select_pack_shape)
             with profile_scope("compress/boundary", category="kernel"):
                 thr = sampled_boundary_guv(g_flat, u, v,
                                            k if eff_k is None else eff_k)
+            tiles, out_blocks, _ = select_pack_shape(n, k)
             with profile_scope("bsc/select_pack", category="kernel",
-                              args={"n": n, "k": k}):
+                              args={"n": n, "k": k, "tiles": tiles,
+                                    "out_blocks": out_blocks}):
                 vals, idx, u, v = bsc_select_pack(
                     g_flat, u, v, thr, k, interpret=self.fused_interpret)
             # in-situ achieved payload (telemetry/probes.py): the

@@ -10,7 +10,8 @@ makes one HBM round trip:
 - ``dequantize_2bit``: unpack + scale.
 - ``bsc_select_pack`` / ``bsc_scatter_add``: the Bi-Sparse dc-tier hot
   path — momentum correction, sampled-boundary select, fixed-k
-  (value, index) pack and error-feedback reset fused into one pass,
+  (value, index) pack and error-feedback reset fused (a counting and a
+  placing pass over the bucket, one call where it is one tile),
   plus the dense scatter-add reconstruction (docs/kernels.md).
 - ``fused_flatten`` / ``fused_unflatten``: the bucket (un)flatten as a
   single DMA kernel instead of one XLA copy per pytree leaf.

@@ -5,15 +5,14 @@ Two kernels replace the dc-tier sparse hot path that a builder's capture
 it saved:
 
 ``bsc_select_pack``
-    One fused pass over the gradient bucket that computes the DGC-style
-    momentum correction ``u' = 0.9*u + g; v' = v + u'``, applies the
-    sampled magnitude boundary, emits the fixed-``k`` (value, index)
-    wire pairs, and zeroes the error-feedback buffers at the emitted
-    coordinates — everything the unfused XLA graph spreads over a
-    mask+cumsum+scatter chain of ~6 HBM-materialized intermediates
-    (``ops/sampled_topk.py``).  Bit-exact with that jnp reference:
-    identical values, indices (including the -1 sentinel padding and the
-    first-k-in-index-order tie rule), and residuals.
+    Computes the DGC-style momentum correction ``u' = 0.9*u + g; v' = v +
+    u'``, applies the sampled magnitude boundary, emits the fixed-``k``
+    (value, index) wire pairs, and zeroes the error-feedback buffers at
+    the emitted coordinates — everything the unfused XLA graph spreads
+    over a mask+cumsum+scatter chain of ~6 HBM-materialized
+    intermediates (``ops/sampled_topk.py``).  Bit-exact with that jnp
+    reference: identical values, indices (including the -1 sentinel
+    padding and the first-k-in-index-order tie rule), and residuals.
 
 ``bsc_scatter_add``
     The decompress: accumulates all parties' gathered (value, index)
@@ -21,22 +20,40 @@ it saved:
     intermediate or an XLA scatter, in work proportional to the pairs
     plus the output, not to their product (see below).
 
-Algorithm notes (select/pack).  The reference scan's two-tier rule
-(strictly-above-boundary elements claim slots first, boundary ties queue
-after *all* primaries — ``sampled_threshold_select``) needs the total
-primary count before any tie's slot is known, so the kernel runs a
-2-pass sequential grid over [8, 128] fp32 blocks: pass 0 emits the
-primary runs while accumulating the primary count in SMEM, pass 1 emits
-the tie runs offset by that total.  Within a block, element ranks come
-from matmul prefix-sums (lane-triangular [128,128] + row-triangular
-[8,8] — Mosaic has no native cumsum).  The (value, index) outputs are
-lane-dense [rows, 128] slabs that stay VMEM-resident across the grid (a
-[k, 1] column cannot be sliced at an element offset on the chip: TPU
-refs are whole (8, 128) tiles), and each block's kept elements are
-placed straight into slab coordinates by two one-hot matmuls per row.
-Because every block's emitted ranks are consecutive, runs tile the
-output exactly; slots no run covers keep the sentinel pair the slabs
-are initialized with at the first grid step.
+Algorithm notes (select/pack): count first, then place by a schedule.
+The reference scan's two-tier rule (strictly-above-boundary elements
+claim slots first, boundary ties queue after *all* primaries —
+``sampled_threshold_select``) makes a pair's slot a prefix sum over the
+whole bucket.  So the bucket is cut into tiles of ``_TILE_ROWS`` x 128
+elements and goes through two kernels.  ``bsc_select_pack_count`` does
+the momentum arithmetic and counts each tile's primaries and ties.  XLA
+turns the ``2 * tiles`` counts into first slots (one exclusive prefix
+sum: the primaries' tiles, then the ties') and into the placement's
+schedule (``place_visits``): slots ascend with the tiles inside a class,
+so the (tile, output block) meetings that hold a pair form a staircase
+of at most ``tiles + out_blocks`` visits a class.  ``bsc_select_pack_
+place`` walks it on a 1-D grid, the visit lists scalar-prefetch operands
+that the BlockSpecs' index maps read: a tile streams in once for its
+primaries and once more only if it holds a tie that gets a slot, each
+output block of ``_PAIR_ROWS`` x 128 pairs is written once, nothing
+stays resident, and k has no limit.  A tile's
+first visit writes its new u and v (what to zero is decided from the
+prefix sums: all of a class, none of it, or by rank in the one tile slot
+k falls in) and compacts the class it places; a tile with no pair of the
+class pays neither ranks nor compaction, so ties cost what ties there
+are.  Within a tile, ranks come from matmul prefix-sums (lane-triangular
+[128,128] + row-triangular [rows,rows] on 0/1 operands — Mosaic has no
+native cumsum), and the kept elements move to their consecutive slots by
+``_compact``: log2(tile) whole-frame rolls with three selects each, no
+one-hot and no value through the MXU, the same work at any density.  The
+(value, index) outputs are lane-dense [rows, 128] (a [k, 1] column
+cannot be sliced at an element offset on the chip: TPU refs are whole
+(8, 128) tiles); the compaction lands a tile's run at the lane and
+sublane its first slot has in its output tile, so placing is an aligned
+row-window merge.  Slots no run covers keep the sentinel pair every
+output block starts from.  A bucket of one tile needs no schedule and no
+prefix sum and takes neither: one call of one kernel does all of the
+above with the output slabs whole in VMEM.
 
 Wire-format stability: the fused kernel and the jnp reference emit
 byte-identical payloads (primaries in ascending index order, then ties,
@@ -44,10 +61,11 @@ then -1/0.0 sentinel padding), so parties may mix fused and unfused
 paths in one job and checkpointed error-feedback state is
 interchangeable between them.
 
-VMEM budget: 3 input + 2 output [8,128] fp32 blocks per grid step
-(~20 KB), a few [128,128] / [16,128] one-hots, and the two resident
-output slabs — 8 x (k + 2048) bytes, double-buffered, which bounds k
-(``MAX_FUSED_K``); above it the kernel raises.
+VMEM budget (placing pass): 3 input + 2 output [256,128] fp32 tiles and
+two [64,128] output blocks per grid step, double-buffered (~1.4 MB), the
+[392,128] value and index frames (~0.4 MB) and a few frame-sized
+temporaries of the compaction.  The visit lists and first slots are int32
+in SMEM: 4 x (5 tiles + 2 out_blocks) bytes.
 
 Algorithm notes (decompress).  The output is cut into blocks of
 ``_OUT_ROWS`` x 128 elements and the pairs, sorted by index (one
@@ -85,10 +103,11 @@ import jax.numpy as jnp
 MOMENTUM = 0.9  # gc.cc:200 — must match compression/bisparse.py
 
 _LANES = 128
-_BLK_ROWS = 8                      # one fp32 tile of rows per grid step
-_BLK = _BLK_ROWS * _LANES          # 1024 elements per grid step
-_WIN_ROWS = 2 * _BLK_ROWS           # output rows one emitted run can touch
-MAX_FUSED_K = 1 << 19               # output pairs held in VMEM (2 x 2 MiB)
+_BLK_ROWS = 8                      # one fp32 (8, 128) tile of rows
+_BLK = _BLK_ROWS * _LANES          # 1024 elements
+_TILE_ROWS = 256                   # select/pack: rows per dense grid step
+_TILE = _TILE_ROWS * _LANES        # 32768 elements
+_PAIR_ROWS = 64                    # select/pack: output rows per block
 _CHUNK = 512                       # (value, index) pairs per decompress step
 _OUT_ROWS = 128                    # dense output rows per decompress block
 _SENTINEL_KEY = 2 ** 31 - 1        # a sentinel pair's sort key: after every index
@@ -124,131 +143,358 @@ def sampled_boundary_guv(g: jax.Array, u: jax.Array, v: jax.Array, k,
     return ssorted[boundary_position(m, k, n)]
 
 
-def _ex_cumsum_flat(mask):
-    """Exclusive prefix count of ``mask`` [8, 128] in row-major (flat
+def _momentum_classes(g_ref, u_ref, v_ref, thr, base, n):
+    """The rule by the element: momentum correction, then the two classes
+    a coordinate can be emitted in.  Returns ``(u', v', primary,
+    secondary)`` for the block whose first flat index is ``base``."""
+    u2 = u_ref[:] * MOMENTUM + g_ref[:]
+    v2 = v_ref[:] + u2
+    absv = jnp.abs(v2)
+    flat = base + _local_index(u2.shape[0])
+    # zero padding (and whatever a block that hangs over the end reads)
+    # must not claim tie slots when thr == 0
+    valid = flat < n
+    return u2, v2, (absv > thr) & valid, (absv == thr) & valid
+
+
+def _local_index(rows):
+    return (jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0) * _LANES
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1))
+
+
+def _count(mask):
+    # counts reduce in f32 (exact up to 2**24, a tile is 2**15; Mosaic
+    # implements no integer reductions)
+    return jnp.sum(mask.astype(jnp.float32)).astype(jnp.int32)
+
+
+def _ex_rank(mask):
+    """Exclusive prefix count of ``mask`` [rows, 128] in row-major (flat
     index) order, as int32.  Mosaic lowers no cumsum primitive; the
     standard TPU spelling is a pair of triangular matmuls (lane-level
-    [128,128], then row offsets via a strictly-lower [8,8])."""
+    [128,128], then row offsets via a strictly-lower [rows,rows]).  The
+    operands are 0/1 and row totals up to 128, exact in the MXU's bf16
+    pass with its float32 accumulation: no ``HIGHEST``."""
+    rows = mask.shape[0]
     m = mask.astype(jnp.float32)
     lane_lt = (jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
                < jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 1)
                ).astype(jnp.float32)
     ex_lane = jax.lax.dot_general(m, lane_lt, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    rowtot = jnp.sum(m, axis=1, keepdims=True)                     # [8, 1]
-    row_gt = (jax.lax.broadcasted_iota(jnp.int32, (_BLK_ROWS, _BLK_ROWS), 1)
-              < jax.lax.broadcasted_iota(jnp.int32, (_BLK_ROWS, _BLK_ROWS), 0)
+    rowtot = jnp.broadcast_to(jnp.sum(m, axis=1, keepdims=True),
+                              (rows, _LANES))
+    row_lt = (jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1)
+              < jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
               ).astype(jnp.float32)
-    ex_row = jax.lax.dot_general(row_gt, rowtot, (((1,), (0,)), ((), ())),
+    ex_row = jax.lax.dot_general(row_lt, rowtot, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
     return (ex_lane + ex_row).astype(jnp.int32)
 
 
-def _select_kernel(k, n, g_ref, u_ref, v_ref, thr_ref,
-                   newu_ref, newv_ref, vals_ref, idx_ref, cnt):
-    """Grid (2, nblocks): pass 0 emits primary (> thr) runs, pass 1 emits
-    tie (== thr) runs and the final error-feedback zeroing.  SMEM ``cnt``:
-    [0] = running primary count (pass 0; frozen total during pass 1),
-    [1] = pass-1 primary re-count, [2] = running tie count."""
+def _src_bits(rows):
+    """Bits of a tile-local flat index."""
+    return max(1, (rows * _LANES - 1).bit_length())
+
+
+def _flat_roll(x, step):
+    """``y[p] = x[(p + step) mod size]`` over the row-major order of
+    ``x`` [rows, 128], ``step`` a static power of two."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = x.shape[0]
+    if step >= _LANES:
+        return pltpu.roll(x, rows - step // _LANES, axis=0)
+    same_row = pltpu.roll(x, _LANES - step, axis=1)
+    next_row = pltpu.roll(same_row, rows - 1, axis=0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where(lane < _LANES - step, same_row, next_row)
+
+
+def _compact(keep, rank, v2, lead):
+    """Move a tile's kept elements, in index order, to the consecutive
+    flat positions ``lead + rank`` of a frame of ``rows + 8`` rows whose
+    row 8 is the tile's first (``lead`` < 1024 is where the tile's first
+    pair falls in its (8, 128) output tile, so the frame's rows are the
+    output's rows from that tile on).  Returns ``(values, packed)``
+    [rows + 8, 128]; ``packed`` is 0 where no pair landed and carries the
+    pair's tile-local source index in its low ``_src_bits(rows)`` bits.
+
+    The move is the compress of Hacker's Delight 7-4, by the vreg: a kept
+    element has to travel ``d = 1024 + local - lead - rank`` >= 1 places
+    toward the front, d never decreases from one kept element to the next
+    and grows by at most their distance less one, so taking d's bits
+    lowest first (those with bit b set move 2**b places, all at once) no
+    two kept elements ever meet, and one that would wrap around has the
+    bit clear.  A step is a roll of the whole frame and three selects: no
+    one-hot, no matmul, the values never leave float32, and the cost is
+    the same at any density."""
+    rows = keep.shape[0]
+    sbits = _src_bits(rows)
+    local = _local_index(rows)
+    dist = (_BLK + local - lead) - rank
+    head = jnp.zeros((_BLK_ROWS, _LANES), jnp.int32)
+    packed = jnp.concatenate(
+        [head, jnp.where(keep, (dist << sbits) | local, 0)], axis=0)
+    val = jnp.concatenate([head.astype(jnp.float32), v2], axis=0)
+    for b in range((_BLK + rows * _LANES - 1).bit_length()):
+        bit = 1 << (sbits + b)
+        coming_p, coming_v = _flat_roll(packed, 1 << b), _flat_roll(val, 1 << b)
+        arrives = (coming_p & bit) != 0
+        leaves = (packed & bit) != 0
+        packed = jnp.where(arrives, coming_p, jnp.where(leaves, 0, packed))
+        val = jnp.where(arrives, coming_v, val)
+    return val, packed
+
+
+def _select_tile_kernel(k, n, g_ref, u_ref, v_ref, thr_ref,
+                        newu_ref, newv_ref, vals_ref, idx_ref):
+    """A bucket of one tile: the counts, the ranks, the error-feedback
+    reset and both classes' placement in one call, the (value, index)
+    slabs whole in VMEM.  No schedule and no prefix sum."""
     import jax.experimental.pallas as pl
 
-    pas = pl.program_id(0)
-    blk = pl.program_id(1)
-    thr = thr_ref[0, 0]
-    u2 = u_ref[:] * MOMENTUM + g_ref[:]
-    v2 = v_ref[:] + u2
-    absv = jnp.abs(v2)
-    base = blk * _BLK
-    flat = base + (
-        jax.lax.broadcasted_iota(jnp.int32, (_BLK_ROWS, _LANES), 0) * _LANES
-        + jax.lax.broadcasted_iota(jnp.int32, (_BLK_ROWS, _LANES), 1))
-    valid = flat < n  # zero padding must not claim tie slots when thr == 0
-    primary = (absv > thr) & valid
-    secondary = (absv == thr) & valid
-    p_rank = _ex_cumsum_flat(primary)
-    s_rank = _ex_cumsum_flat(secondary)
-    # counts reduce in f32 (exact up to the 1024-element block; Mosaic
-    # implements no integer reductions)
-    p_cnt = jnp.sum(primary.astype(jnp.float32)).astype(jnp.int32)
-    s_cnt = jnp.sum(secondary.astype(jnp.float32)).astype(jnp.int32)
+    rows = g_ref.shape[0]
+    u2, v2, primary, secondary = _momentum_classes(
+        g_ref, u_ref, v_ref, thr_ref[0, 0], 0, n)
+    p_rank, s_rank = _ex_rank(primary), _ex_rank(secondary)
+    p_cnt, s_cnt = _count(primary), _count(secondary)
+    keep_p = primary & (p_rank < k)
+    keep_s = secondary & (p_cnt + s_rank < k)  # ties queue after ALL primaries
+    keep = keep_p | keep_s
+    newu_ref[:] = jnp.where(keep, 0.0, u2)
+    newv_ref[:] = jnp.where(keep, 0.0, v2)
+    vals_ref[:] = jnp.zeros_like(vals_ref)
+    idx_ref[:] = jnp.full_like(idx_ref, -1)
 
-    def emit(emit_mask, rank_local, start):
-        """Compact the block's emitted class (local ranks are consecutive
-        from 0) into the (value, index) run that owns output slots
-        [start, start + count).  The outputs stay VMEM-resident as
-        lane-dense [rows, 128] slabs, so the run is built directly in
-        slab coordinates — target slot t -> (t // 128, t % 128) within
-        the 16-row window that starts at the 8-row tile holding ``start``
-        — by two one-hot matmuls per block row, and merged into the
-        window where a slot was hit.  Slots no run hits keep the
-        sentinel pair the slabs were initialized with."""
-        off = jnp.minimum(start, k)  # blocks past k emit nothing: park
-        row0 = pl.multiple_of(off // _BLK * _BLK_ROWS, _BLK_ROWS)
-        t = jnp.where(emit_mask, off % _BLK + rank_local, -1)
-        trow, tcol = t >> 7, t & (_LANES - 1)  # not emitted: row -1
-        win_row = jax.lax.broadcasted_iota(jnp.int32, (_WIN_ROWS, _LANES), 0)
-        lane_col = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
-        accv = jnp.zeros((_WIN_ROWS, _LANES), jnp.float32)
-        acci = jnp.zeros((_WIN_ROWS, _LANES), jnp.float32)
-        for r in range(_BLK_ROWS):
-            in_row = win_row == trow[r:r + 1, :]
-            in_col = (lane_col == tcol[r:r + 1, :]).astype(jnp.float32)
-            # local flat index payload, +1 so "no hit" (0) maps to -1
-            loc = (jax.lax.broadcasted_iota(jnp.int32, (1, _LANES), 1)
-                   + (r * _LANES + 1)).astype(jnp.float32)
-            # [16, e] x [128, e] contracted over the row's 128 elements e
-            accv = accv + jax.lax.dot_general(
-                jnp.where(in_row, v2[r:r + 1, :], 0.0), in_col,
-                (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
-            acci = acci + jax.lax.dot_general(
-                jnp.where(in_row, loc, 0.0), in_col,
-                (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32)
-        ai = acci.astype(jnp.int32)
-        win = pl.ds(row0, _WIN_ROWS)
-        vals_ref[win, :] = jnp.where(ai > 0, accv, vals_ref[win, :])
-        idx_ref[win, :] = jnp.where(ai > 0, base + ai - 1, idx_ref[win, :])
+    def place(kept, rank, start, count):
+        @pl.when((count > 0) & (start < k))
+        def _():
+            val, packed = _compact(kept, rank, v2, start % _BLK)
+            win = pl.ds(pl.multiple_of(start // _BLK * _BLK_ROWS, _BLK_ROWS),
+                        rows + _BLK_ROWS)
+            hit = packed != 0
+            vals_ref[win, :] = jnp.where(hit, val, vals_ref[win, :])
+            idx_ref[win, :] = jnp.where(
+                hit, packed & ((1 << _src_bits(rows)) - 1), idx_ref[win, :])
 
-    @pl.when((pas == 0) & (blk == 0))
-    def _init_outputs():
+    place(keep_p, p_rank, 0, p_cnt)
+    place(keep_s, s_rank, p_cnt, s_cnt)
+
+
+def _count_kernel(n, g_ref, u_ref, v_ref, thr_ref, cnt_ref):
+    """Pass 1 of a bucket of several tiles: tile t's count of primaries
+    and of ties, into lane t of rows 0 and 1 of the one [8, tiles] output
+    block, which stays in VMEM across the grid (4 bytes a tile and class
+    in HBM, and lane-dense for the prefix sums that read it)."""
+    import jax.experimental.pallas as pl
+
+    t = pl.program_id(0)
+    _, _, primary, secondary = _momentum_classes(
+        g_ref, u_ref, v_ref, thr_ref[0, 0], t * _TILE, n)
+
+    @pl.when(t == 0)
+    def _():
+        cnt_ref[:] = jnp.zeros_like(cnt_ref)
+
+    row = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, cnt_ref.shape, 1)
+    cnt_ref[:] = jnp.where(
+        lane == t, jnp.where(row == 0, _count(primary), _count(secondary)),
+        cnt_ref[:])
+
+
+def select_pack_shape(n: int, k: int):
+    """``(tiles, out_blocks, out_block_rows)`` of a bucket of ``n``
+    elements and ``k`` slots, from shapes alone: the dense passes' grid
+    and the (value, index) output's blocks.  One tile means no schedule
+    (``bsc_select_pack`` then makes one call whose slabs hold every
+    slot)."""
+    tiles = max(1, -(-n // _TILE))
+    krows = -(-max(int(k), 1) // _BLK) * _BLK_ROWS
+    if tiles == 1:
+        return 1, 1, krows
+    out_rows = min(_PAIR_ROWS, krows)
+    return tiles, -(-krows // out_rows), out_rows
+
+
+def place_visits(p_cnt: jax.Array, s_cnt: jax.Array, k: int,
+                 out_blocks: int, block_slots: int):
+    """The placement's schedule, from the counting pass's per-tile counts
+    of primaries and of ties.  Returns ``(item, blk, total, start)``:
+    int32 [2 * tiles + out_blocks] visit lists, the number of live visits
+    and the [2 * tiles + 1] first slots.
+
+    An item is a tile in a class: item i < tiles is tile i's primaries,
+    item tiles + i its ties, and ``start`` is the exclusive prefix sum
+    over the items in that order, which IS the slot rule (primaries in
+    index order, then ties).  Slots ascend with the items, so item i
+    meets output blocks ``start[i] // block_slots`` to ``(start[i + 1] -
+    1) // block_slots`` (cut at slot k) and the (item, block) meetings
+    that hold a pair form a staircase.  Every primary item is visited at
+    least once, pairs or none, because its visit also writes the tile's
+    new u and v; a tie item with no pair below slot k is not visited at
+    all; the last live item walks on to the last output block, so every
+    block is written (the sentinels).  At most ``tiles + live tie items +
+    out_blocks`` visits.  Visits past ``total`` repeat the last live one
+    and do nothing."""
+    tiles = p_cnt.shape[0]
+    items = 2 * tiles
+    cnt = jnp.concatenate([p_cnt, s_cnt]).astype(jnp.int32)
+    end = jnp.cumsum(cnt)
+    start = end - cnt
+    lo_slot, hi_slot = jnp.minimum(start, k), jnp.minimum(end, k)
+    holds = hi_slot > lo_slot
+    i = jnp.arange(items, dtype=jnp.int32)
+    live = (i < tiles) | holds
+    last_live = jnp.max(jnp.where(live, i, -1))
+    lo = jnp.minimum(lo_slot // block_slots, out_blocks - 1)
+    hi = jnp.where(holds, (hi_slot - 1) // block_slots, lo)
+    hi = jnp.where(i == last_live, out_blocks - 1, hi)
+    count = jnp.where(live, hi - lo + 1, 0)
+    vend = jnp.cumsum(count)
+    t = jnp.arange(items + out_blocks, dtype=jnp.int32)
+    # one fused compare-and-count over [visits, items]: no loop
+    item = jnp.searchsorted(vend, t, side="right",
+                            method="compare_all").astype(jnp.int32)
+    dead = t >= vend[-1]
+    item = jnp.where(dead, last_live, item)
+    # an item's j-th visit is its first block + j: t - (vend - count) is j
+    blk = jnp.where(dead, out_blocks - 1, t + (lo - vend + count)[item])
+    return (item, blk.astype(jnp.int32), vend[-1:].astype(jnp.int32),
+            jnp.append(start, end[-1]).astype(jnp.int32))
+
+
+def _place_kernel(k, n, tiles, item_ref, blk_ref, total_ref, start_ref,
+                  g_ref, u_ref, v_ref, thr_ref,
+                  newu_ref, newv_ref, vals_ref, idx_ref,
+                  cval, cidx, holds_ref):
+    """Pass 2: one visit of the schedule, item ``item[t]`` (a tile in a
+    class) against output block ``blk[t]``.  An item's first visit does
+    the tile's dense work: new u and v (every coordinate kept in EITHER
+    class zeroed, decided from the prefix sums: all of a class, none of
+    it, or by rank in the one tile slot k falls in), and, where the item
+    holds a pair, the compaction of its class into the ``cval`` /
+    ``cidx`` frame, which stays for the item's other visits.  A visit
+    then merges the frame's rows that fall in its output block.  A tile
+    with no pair of the class pays neither ranks nor compaction."""
+    import jax.experimental.pallas as pl
+
+    t = pl.program_id(0)
+    item, blk = item_ref[t], blk_ref[t]
+    before = jnp.maximum(t - 1, 0)
+    live = t < total_ref[0]
+    out_rows = vals_ref.shape[0]
+    frame = _TILE_ROWS + _BLK_ROWS
+
+    @pl.when(t == 0)
+    def _no_pairs_around_the_frame():
+        cidx[:] = jnp.full_like(cidx, -1)
+
+    @pl.when((t == 0) | (blk != blk_ref[before]))
+    def _sentinels():
         vals_ref[:] = jnp.zeros_like(vals_ref)
         idx_ref[:] = jnp.full_like(idx_ref, -1)
 
-    @pl.when((pas == 0) & (blk == 0))
-    def _reset_primary_count():
-        cnt[0] = 0
+    @pl.when(live & ((t == 0) | (item != item_ref[before])))
+    def _tile():
+        ties = item >= tiles
+        tile = jnp.where(ties, item - tiles, item)
+        u2, v2, primary, secondary = _momentum_classes(
+            g_ref, u_ref, v_ref, thr_ref[0, 0], tile * _TILE, n)
 
-    @pl.when(pas == 0)
-    def _emit_primaries():
-        p_pre = cnt[0]
-        keep_p = primary & (p_pre + p_rank < k)
-        # interim EF state (pass 1 rewrites it with the tie zeroing too)
-        newu_ref[:] = jnp.where(keep_p, 0.0, u2)
-        newv_ref[:] = jnp.where(keep_p, 0.0, v2)
-        emit(keep_p, p_rank, p_pre)
-        cnt[0] = p_pre + p_cnt
+        def ranks(mask, lo, hi, placed):
+            # all of a class kept (rank 0 passes) or none of it (nothing
+            # does) needs no rank; placing it does, and so does the tile
+            # slot k falls in
+            need = (hi > lo) & (lo < k) & (placed | (hi > k))
+            return jax.lax.cond(
+                need, _ex_rank, lambda m: jnp.zeros(m.shape, jnp.int32), mask)
 
-    @pl.when((pas == 1) & (blk == 0))
-    def _reset_tie_counts():
-        cnt[1] = 0
-        cnt[2] = 0
-
-    @pl.when(pas == 1)
-    def _emit_ties():
-        np_tot = cnt[0]  # total primaries: ties queue after ALL of them
-        p_pre = cnt[1]
-        s_pre = cnt[2]
-        keep_p = primary & (p_pre + p_rank < k)
-        keep_s = secondary & (np_tot + s_pre + s_rank < k)
+        p_lo, p_hi = start_ref[tile], start_ref[tile + 1]
+        s_lo, s_hi = start_ref[tiles + tile], start_ref[tiles + tile + 1]
+        p_rank = ranks(primary, p_lo, p_hi, jnp.logical_not(ties))
+        s_rank = ranks(secondary, s_lo, s_hi, ties)
+        keep_p = primary & (p_lo + p_rank < k)
+        keep_s = secondary & (s_lo + s_rank < k)
         keep = keep_p | keep_s
         newu_ref[:] = jnp.where(keep, 0.0, u2)
         newv_ref[:] = jnp.where(keep, 0.0, v2)
-        emit(keep_s, s_rank, np_tot + s_pre)
-        cnt[1] = p_pre + p_cnt
-        cnt[2] = s_pre + s_cnt
+        lo = start_ref[item]
+        holds = (start_ref[item + 1] > lo) & (lo < k)
+        holds_ref[0] = holds.astype(jnp.int32)
+
+        @pl.when(holds)
+        def _compact_the_class():
+            # (a select between two masks is one Mosaic does not lower)
+            kept = jnp.where(ties, keep_s.astype(jnp.int32),
+                             keep_p.astype(jnp.int32)) != 0
+            val, packed = _compact(kept, jnp.where(ties, s_rank, p_rank),
+                                   v2, lo % _BLK)
+            rows = pl.ds(out_rows, frame)
+            cval[rows, :] = val
+            cidx[rows, :] = jnp.where(
+                packed != 0,
+                tile * _TILE + (packed & ((1 << _src_bits(_TILE_ROWS)) - 1)),
+                -1)
+
+    @pl.when(live & (holds_ref[0] == 1))
+    def _merge():
+        # frame row r is output row first + r; before and after the frame
+        # lie out_rows rows that hold no pair, so a block that the item
+        # only walks through (the last item's, to the end) reads those
+        first = start_ref[item] // _BLK * _BLK_ROWS
+        shift = jnp.clip(blk * out_rows - first, -out_rows, frame)
+        win = pl.ds(pl.multiple_of(out_rows + shift, _BLK_ROWS), out_rows)
+        idx = cidx[win, :]
+        vals_ref[:] = jnp.where(idx >= 0, cval[win, :], vals_ref[:])
+        idx_ref[:] = jnp.where(idx >= 0, idx, idx_ref[:])
+
+
+def _tile_rows(x, n, tiles):
+    """A flat bucket as [rows, 128] float32: whole lanes, and whole
+    (8, 128) tiles where it is one tile.  The last tile of several may
+    hang over the end (Pallas masks what it writes there, the kernels
+    mask what they read), so a bucket of whole lanes, which every large
+    one is, goes in and comes out without a copy."""
+    rows = -(-max(n, 1) // _LANES)
+    if tiles == 1:
+        rows = -(-rows // _BLK_ROWS) * _BLK_ROWS
+    x = x.reshape(-1).astype(jnp.float32)
+    if rows * _LANES != n:
+        x = jnp.concatenate([x, jnp.zeros((rows * _LANES - n,), jnp.float32)])
+    return x.reshape(rows, _LANES)
+
+
+def _threshold_spec():
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def select_pack_counts(g: jax.Array, u: jax.Array, v: jax.Array,
+                       threshold: jax.Array, interpret: bool = False):
+    """The counting pass of a bucket of several tiles: int32 [tiles]
+    counts of primaries (|v'| > threshold) and of ties (== threshold),
+    what ``place_visits`` makes the schedule from."""
+    import jax.experimental.pallas as pl
+
+    n = g.shape[0]
+    tiles = -(-n // _TILE)
+    tile_spec = pl.BlockSpec((_TILE_ROWS, _LANES), lambda t: (t, 0))
+    lanes = -(-tiles // _LANES) * _LANES
+    counts = pl.pallas_call(
+        functools.partial(_count_kernel, n),
+        grid=(tiles,),
+        in_specs=[tile_spec, tile_spec, tile_spec, _threshold_spec()],
+        out_specs=pl.BlockSpec((_BLK_ROWS, lanes), lambda t: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((_BLK_ROWS, lanes), jnp.int32),
+        name="bsc_select_pack_count",
+        interpret=interpret,
+    )(*(_tile_rows(x, n, tiles) for x in (g, u, v)),
+      jnp.asarray(threshold, jnp.float32).reshape(1, 1))
+    return counts[0, :tiles], counts[1, :tiles]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
@@ -267,46 +513,57 @@ def bsc_select_pack(g: jax.Array, u: jax.Array, v: jax.Array,
 
     n = g.shape[0]
     k = int(k)
-    rows = max(1, -(-n // _LANES))
-    rowsp = -(-rows // _BLK_ROWS) * _BLK_ROWS
-    pad = rowsp * _LANES - n
+    tiles, out_blocks, out_rows = select_pack_shape(n, k)
+    operands = [_tile_rows(x, n, tiles) for x in (g, u, v)]
+    operands.append(jnp.asarray(threshold, jnp.float32).reshape(1, 1))
+    rows = operands[0].shape[0]
+    dense = jax.ShapeDtypeStruct((rows, _LANES), jnp.float32)
 
-    def shape2(x):
-        x = x.reshape(-1).astype(jnp.float32)
-        if pad:
-            x = jnp.concatenate([x, jnp.zeros((pad,), jnp.float32)])
-        return x.reshape(rowsp, _LANES)
+    def pairs(krows):
+        return (jax.ShapeDtypeStruct((krows, _LANES), jnp.float32),
+                jax.ShapeDtypeStruct((krows, _LANES), jnp.int32))
 
-    # a run starts anywhere in the 8-row tile holding its first slot and
-    # is at most one block long: the 16-row window always fits
-    krows = (k // _BLK) * _BLK_ROWS + _WIN_ROWS
-    if krows * _LANES > MAX_FUSED_K:
-        raise ValueError(
-            f"bsc_select_pack keeps its k={k} output pairs VMEM-resident "
-            f"and takes k up to {MAX_FUSED_K - _WIN_ROWS * _LANES}; lower "
-            "the bucket size or the ratio, or set GEOMX_FUSED_KERNELS=0")
-    blk_spec = pl.BlockSpec((_BLK_ROWS, _LANES), lambda p, b: (b, 0))
-    out_spec = pl.BlockSpec((krows, _LANES), lambda p, b: (0, 0))
-    newu, newv, vals, idx = pl.pallas_call(
-        functools.partial(_select_kernel, k, n),
-        grid=(2, rowsp // _BLK_ROWS),
-        in_specs=[
-            blk_spec, blk_spec, blk_spec,                       # g, u, v
-            pl.BlockSpec((1, 1), lambda p, b: (0, 0),
-                         memory_space=pltpu.SMEM),              # threshold
-        ],
-        out_specs=(blk_spec, blk_spec,                          # new u, v
-                   out_spec, out_spec),                         # vals, idx
-        out_shape=(
-            jax.ShapeDtypeStruct((rowsp, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((rowsp, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((krows, _LANES), jnp.float32),
-            jax.ShapeDtypeStruct((krows, _LANES), jnp.int32),
-        ),
-        scratch_shapes=[pltpu.SMEM((4,), jnp.int32)],
-        interpret=interpret,
-    )(shape2(g), shape2(u), shape2(v),
-      jnp.asarray(threshold, jnp.float32).reshape(1, 1))
+    if tiles == 1:
+        whole = pl.BlockSpec(memory_space=pltpu.VMEM)
+        newu, newv, vals, idx = pl.pallas_call(
+            functools.partial(_select_tile_kernel, k, n),
+            in_specs=[whole, whole, whole, _threshold_spec()],
+            out_specs=(whole,) * 4,
+            # a class's run starts anywhere in the 8-row tile holding its
+            # first slot and is at most the tile long
+            out_shape=(dense, dense) + pairs(out_rows + rows + _BLK_ROWS),
+            name="bsc_select_pack",
+            interpret=interpret,
+        )(*operands)
+    else:
+        item, blk, total, start = place_visits(
+            *select_pack_counts(g, u, v, threshold, interpret=interpret),
+            k, out_blocks, out_rows * _LANES)
+
+        def of_tile(t, item, blk, total, start):
+            return (jnp.where(item[t] >= tiles, item[t] - tiles, item[t]), 0)
+
+        def of_block(t, item, blk, total, start):
+            return (blk[t], 0)
+
+        tile_spec = pl.BlockSpec((_TILE_ROWS, _LANES), of_tile)
+        pair_spec = pl.BlockSpec((out_rows, _LANES), of_block)
+        frame_rows = 2 * out_rows + _TILE_ROWS + _BLK_ROWS
+        newu, newv, vals, idx = pl.pallas_call(
+            functools.partial(_place_kernel, k, n, tiles),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(item.shape[0],),
+                in_specs=[tile_spec, tile_spec, tile_spec, _threshold_spec()],
+                out_specs=(tile_spec, tile_spec, pair_spec, pair_spec),
+                scratch_shapes=[pltpu.VMEM((frame_rows, _LANES), jnp.float32),
+                                pltpu.VMEM((frame_rows, _LANES), jnp.int32),
+                                pltpu.SMEM((1,), jnp.int32)],
+            ),
+            out_shape=(dense, dense) + pairs(out_blocks * out_rows),
+            name="bsc_select_pack_place",
+            interpret=interpret,
+        )(item, blk, total, start, *operands)
     return (vals.reshape(-1)[:k], idx.reshape(-1)[:k],
             newu.reshape(-1)[:n], newv.reshape(-1)[:n])
 

@@ -52,10 +52,15 @@ def _assert_bitwise(ref, fus):
 
 @pytest.mark.parametrize("n,ratio", [
     (5000, 0.01),     # odd size: padding rows + partial final block
-    (1024, 0.05),     # exactly one kernel block
-    (1023, 0.03),     # one element short of a block
-    (131072, 0.01),   # many blocks, k spans several emit runs
+    (1024, 0.05),     # exactly one (8, 128) tile
+    (1023, 0.03),     # one element short of it
+    (131072, 0.01),   # four whole tiles, one output block
     (10, 0.5),        # tiny: n < lane width
+    (32768, 0.02),    # exactly one tile: the path with no schedule
+    (32769, 0.02),    # one element more: two tiles, the second all padding
+    (100000, 0.3),    # n no multiple of the tile nor of a lane; 30,000
+                      # pairs over four output blocks, tiles that span two
+    (70000, 0.9),     # nearly everything emitted: the densest placement
 ])
 def test_select_pack_parity_random(rng, n, ratio):
     cj, cf = _pair(ratio=ratio)
@@ -125,6 +130,145 @@ def test_select_pack_mixed_primary_and_ties(rng):
     ref, fus = _compress_pair(cj, cf, jnp.asarray(g),
                               jnp.zeros((n,)), jnp.zeros((n,)))
     _assert_bitwise(ref, fus)
+
+
+def _direct_pair(g, u, v, thr, k):
+    """(jnp chain, fused-interpret) at a boundary the caller gives."""
+    from geomx_tpu.ops.bsc_pallas import MOMENTUM
+    from geomx_tpu.ops.sampled_topk import sampled_threshold_select
+
+    @jax.jit
+    def chain(g, u, v, thr):
+        u2 = u * MOMENTUM + g
+        v2 = v + u2
+        vals, idx, keep = sampled_threshold_select(v2, jnp.abs(v2), k, thr=thr)
+        return vals, idx, jnp.where(keep, 0.0, u2), jnp.where(keep, 0.0, v2)
+
+    thr = jnp.float32(thr)
+    return chain(g, u, v, thr), bsc_select_pack(g, u, v, thr, k,
+                                                interpret=True)
+
+
+def _schedule_cases():
+    """Buckets of several tiles (32,768 elements) and output blocks
+    (8,192 pairs) that walk the placement's schedule through its corners,
+    as ``name -> (g, thr, k)`` with u = v = 0, so v' = g."""
+    rng = np.random.RandomState(28)
+    tile = 32768
+    cases = {}
+    # k falls inside the third tile: the one tile whose keep needs ranks;
+    # every later tile keeps nothing and places nothing
+    g = rng.normal(0, 1, 5 * tile).astype(np.float32)
+    cases["k-inside-a-tile"] = (g, 1.0, int((np.abs(g[:2 * tile]) > 1).sum())
+                                + 1234)
+    # ties only: everything tied at thr, the first k in index order win,
+    # over two output blocks and one tile's worth of slots
+    cases["ties-only"] = (np.full(3 * tile + 777, -0.75, np.float32), 0.75,
+                          tile // 2 + 5)
+    # thr == 0 on an all-zero bucket that ends inside a lane: the padding
+    # must claim no slot, nor what the last tile reads past the end
+    cases["zero-boundary-padding"] = (np.zeros(2 * tile + 4000 + 77,
+                                               np.float32), 0.0,
+                                      2 * tile + 4077)
+    # a tile wholly zero between two dense ones, primaries and ties mixed
+    # (quantized magnitudes), the ties queueing after ALL primaries
+    g = np.round(rng.normal(0, 2, 3 * tile)).astype(np.float32)
+    g[tile:2 * tile] = 0
+    cases["zero-tile-between"] = (g, 3.0, int((np.abs(g) > 3).sum()) + 900)
+    # n no multiple of the tile, pairs enough for three output blocks,
+    # fewer than k emitted: a sentinel tail that spans a whole block
+    g = rng.normal(0, 1, 6 * tile + 12345).astype(np.float32)
+    cases["sentinel-tail"] = (g, 1.5, int((np.abs(g) > 1.5).sum()) + 9000)
+    # a dense stretch inside zeros (the embedding's shape): one tile emits
+    # 32,768 pairs, four output blocks from one tile's frame
+    g = np.zeros(4 * tile, np.float32)
+    g[tile + 100:2 * tile + 100] = rng.normal(0, 1, tile) + 5.0
+    cases["one-tile-fills-blocks"] = (g, 0.5, tile + 4000)
+    # below one tile, both classes, ties starting mid-row
+    g = np.round(rng.normal(0, 2, 20000)).astype(np.float32)
+    cases["one-tile-both-classes"] = (g, 2.0,
+                                      int((np.abs(g) > 2).sum()) + 333)
+    return cases
+
+
+_SCHEDULE_CASES = _schedule_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_SCHEDULE_CASES))
+def test_select_pack_schedule_against_the_jnp_chain(case):
+    g, thr, k = _SCHEDULE_CASES[case]
+    g = jnp.asarray(g)
+    z = jnp.zeros_like(g)
+    ref, fus = _direct_pair(g, z, z, thr, k)
+    _assert_bitwise(ref, fus)
+    assert (np.asarray(fus[1]) >= 0).any()
+
+
+def _place_visits(p_cnt, s_cnt, k):
+    """``place_visits`` on hand-made per-tile counts, as numpy: the live
+    (item, block) visits, the static list's length, tiles, out_blocks."""
+    from geomx_tpu.ops.bsc_pallas import place_visits, select_pack_shape
+    tiles = len(p_cnt)
+    _, out_blocks, out_rows = select_pack_shape(tiles * 32768, k)
+    item, blk, total, start = jax.jit(
+        lambda p, s: place_visits(p, s, k, out_blocks, out_rows * 128))(
+            jnp.asarray(p_cnt, jnp.int32), jnp.asarray(s_cnt, jnp.int32))
+    assert item.shape == blk.shape == (2 * tiles + out_blocks,)
+    np.testing.assert_array_equal(
+        np.asarray(start), np.cumsum([0] + list(p_cnt) + list(s_cnt)))
+    total = int(total[0])
+    item, blk = np.asarray(item), np.asarray(blk)
+    # visits past the live ones repeat the last: no block moves
+    assert (item[total:] == item[total - 1]).all()
+    assert (blk[total:] == blk[total - 1]).all()
+    return item[:total], blk[:total], tiles, out_blocks, out_rows * 128
+
+
+@pytest.mark.parametrize("name,p_cnt,s_cnt,k", [
+    # BERT-large's token embedding at a uniform 1%: 954 tiles, 39 blocks
+    ("uniform", [328] * 954, [0] * 954, 312_546),
+    # pairs in a quarter of the tiles only, a sentinel tail
+    ("rows", [1200 if t % 4 == 0 else 0 for t in range(954)], [0] * 954,
+     312_546),
+    # more above the boundary than slots: the first tiles take them all
+    ("overflow", [16000] * 954, [5] * 954, 312_546),
+    # a tie here and there, after all primaries
+    ("few-ties", [300] * 128, [1 if t in (3, 77) else 0 for t in range(128)],
+     41_944),
+    # nothing but ties
+    ("ties-only", [0] * 128, [32768] * 128, 41_944),
+    # nothing at all
+    ("empty", [0] * 72, [0] * 72, 23_593),
+])
+def test_placement_visits_are_tiles_plus_blocks(name, p_cnt, s_cnt, k):
+    """What the schedule is for: a class's (tile, output block) visits
+    number at most tiles + out_blocks, a class with no element gets none,
+    and ties cost what ties there are."""
+    item, blk, tiles, out_blocks, slots = _place_visits(p_cnt, s_cnt, k)
+    primary, ties = item < tiles, item >= tiles
+    assert primary.sum() <= tiles + out_blocks
+    assert ties.sum() <= tiles + out_blocks
+    # every tile's new u and v are written: each primary item is there
+    np.testing.assert_array_equal(np.unique(item[primary]), np.arange(tiles))
+    # every output block is written, in order, its visits together
+    assert blk[0] == 0 and blk[-1] == out_blocks - 1
+    assert set(np.diff(blk)) <= {0, 1} and (np.diff(item) >= 0).all()
+    # an item meets every block that one of its slots below k falls in
+    start = np.cumsum([0] + list(p_cnt) + list(s_cnt))
+    met = set(zip(item.tolist(), blk.tolist()))
+    for i in range(2 * tiles):
+        lo, hi = min(start[i], k), min(start[i + 1], k)
+        if hi > lo:
+            assert {(i, b) for b in range(lo // slots,
+                                          (hi - 1) // slots + 1)} <= met
+    # tie items: exactly those with a slot below k, none for a class with
+    # no element
+    live_ties = {tiles + t for t in range(tiles)
+                 if min(start[tiles + t + 1], k) > min(start[tiles + t], k)}
+    assert set(item[ties].tolist()) == live_ties
+    if not any(s_cnt):
+        assert not ties.any()
+    assert len(item) <= tiles + len(live_ties) + out_blocks
 
 
 def test_select_pack_threshold_probe_matches_reference(rng):
@@ -317,9 +461,9 @@ def test_value_carrying_matmuls_are_full_precision():
     """Found on a v5e (PR 21), invisible in interpret mode: at the MXU's
     default precision an fp32 ``dot_general`` rounds its operands to
     bf16, so the one-hot matmuls that move VALUES (the scatter-add, the
-    select kernel's compaction) returned every value up to 2.9e-3 off.
-    They must ask for ``Precision.HIGHEST`` (value x 1.0 is then exact);
-    the 0/1-operand prefix-sum matmuls need not."""
+    select kernel's compaction before PR 28) returned every value up to
+    2.9e-3 off.  They must ask for ``Precision.HIGHEST`` (value x 1.0 is
+    then exact); the 0/1-operand prefix-sum matmuls need not."""
     from geomx_tpu.analysis.core import walk_jaxpr
 
     def dot_precisions(fn, *args):
@@ -336,11 +480,15 @@ def test_value_carrying_matmuls_are_full_precision():
         dec = dot_precisions(lambda v, ix: bsc_scatter_add(v, ix, n=65536),
                              f, i)
         assert dec and set(dec) == {highest}, dec
-    g = jnp.zeros((4096,), jnp.float32)
-    sel = dot_precisions(lambda a, t: bsc_select_pack(a, a, a, t, k=41),
-                         g, jnp.float32(0.5))
-    # per emit(): 8 block rows x (values, indices); two emit sites
-    assert sel.count(highest) == 2 * 8 * 2, sel
+    # the select/pack moves its values by rolls and selects: its only
+    # matmuls are the 0/1 prefix sums, exact at the default precision,
+    # in the one-tile kernel and in the placing pass alike
+    for n in (4096, 100_000):
+        g = jnp.zeros((n,), jnp.float32)
+        sel = dot_precisions(
+            lambda a, t: bsc_select_pack(a, a, a, t, k=n // 100),
+            g, jnp.float32(0.5))
+        assert sel and highest not in sel, sel
 
 
 # ---------- round trip through the compressed all-reduce ----------

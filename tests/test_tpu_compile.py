@@ -176,6 +176,13 @@ CASES = {
     "dequantize_2bit-4M": lambda: _twobit_inv(BIG),
     "bsc_select_pack-resnet20": lambda: _select(RESNET20_BUCKET, RESNET20_K),
     "bsc_select_pack-4M": lambda: _select(BIG, BIG // 100),
+    # the benchmark's own buckets, and one the resident output slabs of
+    # before PR 28 could not take (k above 1 << 19): nothing executes
+    "bsc_select_pack-bertlarge-embedding": lambda: _select(31_254_528,
+                                                           312_546),
+    "bsc_select_pack-bertlarge-ffn": lambda: _select(4_194_304, 41_944),
+    "bsc_select_pack-one-tile": lambda: _select(7_040, 71),
+    "bsc_select_pack-64Mi": lambda: _select(1 << 26, 671_089),
     "bsc_scatter_add-resnet20": lambda: _scatter(RESNET20_BUCKET,
                                                  2 * RESNET20_K),
     "bsc_scatter_add-4M": lambda: _scatter(BIG, 4 * (BIG // 100)),
@@ -282,11 +289,19 @@ def test_fused_bucket_kernels_refuse_what_vmem_cannot_hold():
             f32(n), f32(7))
 
 
-def test_select_pack_refuses_more_pairs_than_its_vmem_slabs_hold():
-    from geomx_tpu.ops import bsc_select_pack
-    from geomx_tpu.ops.bsc_pallas import MAX_FUSED_K
-    n = 2 * MAX_FUSED_K
-    with pytest.raises(ValueError, match="VMEM-resident"):
-        jax.eval_shape(
-            lambda g, t: bsc_select_pack(g, g, g, t, k=MAX_FUSED_K),
-            f32(n), f32())
+def test_select_pack_kernels_carry_the_name_the_benchmark_reads(chip):
+    """`select_pack_roofline_pct` and `compress_kernels_ms` find the
+    select/pack's kernels by the prefix `bsc_select_pack` of their
+    instruction names (benchmark/trace_reduce.family_time_s): both passes
+    of a bucket of several tiles, and the one call of a bucket of one."""
+    import re
+    for n, want in ((4_194_304, {"bsc_select_pack_count",
+                                 "bsc_select_pack_place"}),
+                    (7_040, {"bsc_select_pack"})):
+        fn, shapes = _select(n, -(-n // 100))
+        args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
+                for s in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                           r'"tpu_custom_call"', text)
+        assert {c.split(".")[0] for c in calls} == want, calls
