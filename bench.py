@@ -522,8 +522,10 @@ def _microbench_kernels(peak, on_tpu: bool):
     def _slope(step, init_carry, lo=lo, hi=hi):
         return _scan_slope(step, init_carry, lo, hi, reps)
 
-    from geomx_tpu.compression.twobit import TwoBitCompressor
-    jnp_q = TwoBitCompressor(0.5, use_pallas=False).quantize
+    from geomx_tpu.ops.twobit_pallas import quantize_2bit_ref
+
+    def jnp_q(g, r):
+        return quantize_2bit_ref(g, r, 0.5)
     z32 = jnp.zeros((), jnp.int32)
 
     # the error-feedback residual carries; the packed words fold into an
@@ -570,10 +572,12 @@ def _microbench_kernels(peak, on_tpu: bool):
         lambda v: v * (1.0 + 1e-12 * jax.lax.approx_max_k(
             jnp.abs(v), k)[0][0]), g) * 1e3, 4)
 
+    from geomx_tpu.ops.bsc_pallas import sampled_boundary_guv
     from geomx_tpu.ops.sampled_topk import sampled_threshold_select
 
     def _sampled_step(v):
-        vals, _idx, _keep = sampled_threshold_select(v, jnp.abs(v), k)
+        thr = sampled_boundary_guv(jnp.zeros_like(v), jnp.zeros_like(v), v, k)
+        vals, _idx, _keep = sampled_threshold_select(v, jnp.abs(v), k, thr)
         return v * (1.0 + 1e-12 * vals[0])
     out["bsc_topk_sampled_ms"] = round(
         _slope(_sampled_step, g) * 1e3, 4)
@@ -1094,16 +1098,6 @@ def compare_bucketing_main(argv):
     _emit(result)
 
 
-# --------------------------------------------------------------------------
-# --compare-kernels: fused Pallas compression kernels vs unfused XLA chains
-# --------------------------------------------------------------------------
-
-# The HLO matchers this mode reports with live in the analysis
-# subsystem (geomx_tpu/analysis/hlo.py, docs/analysis.md) — one owner
-# for the "dense intermediates are GONE from the fused graphs" claim,
-# shared with tests/test_bsc_pallas.py instead of duplicated here.
-
-
 def _roofline_fields(make_record):
     """The roofline columns of a micro-mode record.  These modes run on
     whatever backend the process has; MFU and the bound verdict are
@@ -1127,152 +1121,6 @@ def _roofline_fields(make_record):
         "cost_analysis_available": roof["cost_analysis_available"],
         "wire_bytes_per_step": roof["wire_bytes_per_step"],
     }
-
-
-def _time_ms(fn, *args, reps: int = 3, inner: int = 2):
-    """min-of-reps wall time per call of the jitted ``fn`` (compile
-    excluded).  Dispatch overhead is included — fine for the fused-vs-
-    unfused comparisons this mode makes, which differ by milliseconds of
-    HBM traffic, and for the CPU CI smoke where only the jnp path runs."""
-    import jax
-
-    fn_j = jax.jit(fn)
-    jax.block_until_ready(fn_j(*args))
-    best = float("inf")
-    for _ in range(max(1, reps)):
-        t0 = time.perf_counter()
-        for _ in range(max(1, inner)):
-            out = fn_j(*args)
-        jax.block_until_ready(out)
-        best = min(best, (time.perf_counter() - t0) / max(1, inner))
-    return round(best * 1e3, 4)
-
-
-def _compare_kernels(sizes=(65536, 1048576), ratio: float = 0.01,
-                     parties: int = 4):
-    """One JSON line for the fused compression kernel layer
-    (ops/bsc_pallas.py, ops/bucket_pallas.py): per-kernel time per
-    bucket size and the lowered-HLO materialization counts proving the
-    fused path drops the dense intermediates.  On CPU the timings come
-    from the jnp reference path and ``"fused": false`` — the HLO counts
-    still compare both paths via TPU cross-lowering."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from geomx_tpu.analysis.hlo import compare_paths
-    from geomx_tpu.compression import BiSparseCompressor
-    from geomx_tpu.compression.bucketing import GradientBucketer
-    from geomx_tpu.ops.bsc_pallas import fused_kernels_enabled
-
-    fused_on = fused_kernels_enabled()
-    out = {"mode": "compare_kernels", "fused": fused_on,
-           "platform": jax.devices()[0].platform, "ratio": ratio,
-           "parties": parties, "sizes": {}}
-
-    c_jnp = BiSparseCompressor(ratio=ratio, select="sampled",
-                               min_sparse_size=1, fused=False)
-    c_fused = BiSparseCompressor(ratio=ratio, select="sampled",
-                                 min_sparse_size=1, fused=True)
-    rng = np.random.RandomState(0)
-    for n in sizes:
-        k = c_jnp.k_for(n)
-        g = jnp.asarray(rng.randn(n).astype(np.float32))
-        u = jnp.zeros((n,), jnp.float32)
-        v = jnp.asarray(rng.randn(n).astype(np.float32) * 0.1)
-        vals = jnp.asarray(rng.randn(parties * k).astype(np.float32))
-        idx = jnp.asarray(rng.randint(-1, n, parties * k).astype(np.int32))
-        rec = {"k": k, "pairs": parties * k}
-
-        def sel_jnp(g, u, v):
-            return c_jnp.compress(g, u, v)
-
-        def sel_fused(g, u, v):
-            return c_fused.compress(g, u, v)
-
-        def dec_jnp(a, b):
-            return c_jnp.decompress(a, b, n)
-
-        def dec_fused(a, b):
-            return c_fused.decompress(a, b, n)
-        try:
-            # the unfused select chain's dense intermediates: the rank
-            # cumsum (reduce_window/while) and the slot scatter; the
-            # unfused decompress's: the XLA scatter-add.  The sample
-            # sort/gathers (8k elements) appear in BOTH paths and are
-            # not dense-sized.
-            rec["select_hlo"] = compare_paths(
-                sel_jnp, sel_fused, g, u, v,
-                dense_ops=("scatter", "reduce_window", "while",
-                           "dynamic_update_slice"))
-            rec["decompress_hlo"] = compare_paths(
-                dec_jnp, dec_fused, vals, idx,
-                # the fused decompress sorts its m PAIRS (not dense-
-                # sized); the dense op it removes is the scatter
-                dense_ops=("scatter",))
-        except Exception as e:  # keep the line emitting on exotic jaxlibs
-            rec["hlo_error"] = repr(e)
-        rec["select_jnp_ms"] = _time_ms(sel_jnp, g, u, v)
-        rec["decompress_jnp_ms"] = _time_ms(dec_jnp, vals, idx)
-        if fused_on:
-            rec["select_fused_ms"] = _time_ms(sel_fused, g, u, v)
-            rec["decompress_fused_ms"] = _time_ms(dec_fused, vals, idx)
-        out["sizes"][str(n)] = rec
-
-    # bucket (un)flatten: a ResNet-20-like leaf population (the seed
-    # bench model has ~65 leaves) into default-capacity buckets
-    leaf_sizes = ([432, 16, 16] + [2304, 16, 16] * 6 + [4608, 32, 32]
-                  + [4608, 32, 32] * 5 + [512] + [9216, 64, 64]
-                  + [18432, 64, 64] * 5 + [2048] + [640, 10])
-    leaves = [jnp.asarray(rng.randn(s).astype(np.float32))
-              for s in leaf_sizes]
-    bk_jnp = GradientBucketer(leaves, fused=False)
-    bk_fused = GradientBucketer(leaves, fused=fused_on)
-    flat = bk_jnp.flatten(leaves)
-    frec = {"num_leaves": len(leaves), "num_buckets": bk_jnp.num_buckets}
-    try:
-        # per-leaf copies: flatten is one concatenate operand per leaf,
-        # unflatten one (static) slice per leaf.  The fused unflatten
-        # still trims each tile-padded kernel output to its leaf's size
-        # (TPU refs are whole (8,128) tiles), so its slice count is
-        # reported, not claimed removed.
-        frec["flatten_hlo"] = compare_paths(
-            lambda *ls: bk_jnp.flatten(list(ls)),
-            lambda *ls: GradientBucketer(
-                leaves, fused=True).flatten(list(ls)), *leaves,
-            dense_ops=("concatenate", "dynamic_update_slice"))
-        frec["unflatten_hlo"] = compare_paths(
-            lambda *bs: bk_jnp.unflatten(list(bs)),
-            lambda *bs: GradientBucketer(
-                leaves, fused=True).unflatten(list(bs)), *flat,
-            dense_ops=("slice", "dynamic_slice"),
-            extra_ops=("stablehlo.slice",))
-    except Exception as e:
-        frec["hlo_error"] = repr(e)
-    frec["flatten_jnp_ms"] = _time_ms(
-        lambda *ls: bk_jnp.flatten(list(ls)), *leaves)
-    frec["unflatten_jnp_ms"] = _time_ms(
-        lambda *bs: bk_jnp.unflatten(list(bs)), *flat)
-    if fused_on:
-        frec["flatten_fused_ms"] = _time_ms(
-            lambda *ls: bk_fused.flatten(list(ls)), *leaves)
-        frec["unflatten_fused_ms"] = _time_ms(
-            lambda *bs: bk_fused.unflatten(list(bs)), *flat)
-    out["bucket"] = frec
-    return out
-
-
-def compare_kernels_main(argv):
-    kwargs = {}
-    for a in argv:
-        if a.startswith("--sizes="):
-            kwargs["sizes"] = tuple(int(s) for s in
-                                    a.split("=", 1)[1].split(",") if s)
-        elif a.startswith("--ratio="):
-            kwargs["ratio"] = float(a.split("=", 1)[1])
-        elif a.startswith("--parties="):
-            kwargs["parties"] = int(a.split("=", 1)[1])
-    _emit(_compare_kernels(**kwargs))
 
 
 # --------------------------------------------------------------------------
@@ -1638,18 +1486,18 @@ def _bsc_shard_wire_format(shard_elems: int = 2048,
     import numpy as np
 
     from geomx_tpu.compression.bisparse import BiSparseCompressor
+    from geomx_tpu.ops.bsc_pallas import (bsc_select_pack,
+                                          sampled_boundary_guv,
+                                          select_pack_ref)
 
     rng = np.random.RandomState(7)
     g = jnp.asarray(rng.standard_normal(shard_elems), jnp.float32)
     u = jnp.zeros_like(g)
     v = jnp.zeros_like(g)
-    jnp_path = BiSparseCompressor(ratio=ratio, select="sampled",
-                                  fused=False, min_sparse_size=1)
-    fused_path = BiSparseCompressor(ratio=ratio, select="sampled",
-                                    fused=True, fused_interpret=True,
-                                    min_sparse_size=1)
-    va, ia, _, _ = jnp_path.compress(g, u, v)
-    vb, ib, _, _ = fused_path.compress(g, u, v)
+    k = BiSparseCompressor(ratio=ratio).k_for(shard_elems)
+    thr = sampled_boundary_guv(g, u, v, k)
+    va, ia, _, _ = select_pack_ref(g, u, v, thr, k)
+    vb, ib, _, _ = bsc_select_pack(g, u, v, thr, k, interpret=True)
     ident = (np.asarray(va).tobytes() == np.asarray(vb).tobytes()
              and np.asarray(ia).tobytes() == np.asarray(ib).tobytes())
     return {"wire_format_bit_identical": bool(ident),
@@ -5386,10 +5234,11 @@ def _sparseagg_dc_bit_parity(parties: int = 3, n: int = 8192,
         z = jnp.zeros((parties, n), jnp.float32)
         return [np.asarray(a) for a in jax.jit(fn)(g, z, z)]
 
-    base = dict(ratio=ratio, select="sampled", min_sparse_size=1,
-                sparse_agg=True)
-    oj = run(BiSparseCompressor(fused=False, **base))
-    of = run(BiSparseCompressor(fused=True, fused_interpret=True, **base))
+    from geomx_tpu.ops.dispatch import kernels
+    base = dict(ratio=ratio, min_sparse_size=1, sparse_agg=True)
+    oj = run(BiSparseCompressor(**base))
+    with kernels("interpret"):
+        of = run(BiSparseCompressor(**base))
     bit = all(np.array_equal(a, b) for a, b in zip(oj, of))
     consistent = all(np.array_equal(oj[0][0], oj[0][p])
                      for p in range(parties))
@@ -5499,8 +5348,7 @@ def _sparseagg_lattice_structure(parties: int = 3) -> dict:
         return codes.sum(0) * thr
 
     fp = structure(FP16Compressor(sparse_agg=True), with_state=False)
-    tb = structure(TwoBitCompressor(0.5, use_pallas=False,
-                                    sparse_agg=True), with_state=True)
+    tb = structure(TwoBitCompressor(0.5, sparse_agg=True), with_state=True)
     scale_tol = 3.0 * float(np.abs(g).max()) * parties * parties / 32767.0
     return {
         "fp16": fp, "twobit": tb,
@@ -5560,10 +5408,11 @@ def _sparseagg_zero_parity(parties: int = 3, ratio: float = 0.02) -> dict:
         return ([np.asarray(a) for a in jax.tree.leaves(out)]
                 + [np.asarray(a) for a in jax.tree.leaves(s2)])
 
-    base = dict(ratio=ratio, select="sampled", min_sparse_size=1,
-                sparse_agg=True)
-    oj = run(BiSparseCompressor(fused=False, **base))
-    of = run(BiSparseCompressor(fused=True, fused_interpret=True, **base))
+    from geomx_tpu.ops.dispatch import kernels
+    base = dict(ratio=ratio, min_sparse_size=1, sparse_agg=True)
+    oj = run(BiSparseCompressor(**base))
+    with kernels("interpret"):
+        of = run(BiSparseCompressor(**base))
     bit = len(oj) == len(of) and all(
         np.array_equal(a, b) for a, b in zip(oj, of))
     return {"zero_shard_bit_exact_paths": bool(bit),
@@ -5610,7 +5459,7 @@ def _compare_sparseagg(model_name: str = "resnet20", steps: int = 5,
     sample = jnp.zeros((2, 32, 32, 3), jnp.float32)
     params = jax.jit(lambda r, x: model.init(r, x, train=False))(
         jax.random.PRNGKey(0), sample)["params"]
-    sa_spec = f"bsc,{ratio},select=exact,sparse_agg=1,fused=0"
+    sa_spec = f"bsc,{ratio},sparse_agg=1"
     bucketed = BucketedCompressor(get_compressor(sa_spec))
     findings = audit_compressed_path(bucketed, params,
                                      num_parties=parties)
@@ -6935,13 +6784,7 @@ def compare_fleetscope_main(argv):
 
 
 def main():
-    if "--compare-kernels" in sys.argv:
-        # kernel micro-mode: in-process, single device is enough (no
-        # collectives traced); CPU emits the jnp path with fused: false
-        os.environ.setdefault("JAX_PLATFORMS",
-                              os.environ.get("GEOMX_BENCH_PLATFORM", "cpu"))
-        compare_kernels_main(sys.argv[1:])
-    elif "--audit" in sys.argv:
+    if "--audit" in sys.argv:
         # static-analysis acceptance smoke: in-process on the CPU
         # backend with a 4-device virtual mesh (env before first
         # import) — the scatter_wire_lie corpus entry needs a 4-wide

@@ -185,6 +185,8 @@ def kernels_phase(seed: int, interpret: bool = False, model=None) -> dict:
     """Each Pallas kernel against its jnp reference at the flagship's
     size (``model``: a smaller stand-in for the CPU tests).  Both sides
     run under jit on the same device."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
@@ -192,6 +194,11 @@ def kernels_phase(seed: int, interpret: bool = False, model=None) -> dict:
     from geomx_tpu.compression.bucketing import GradientBucketer
     from geomx_tpu.ops import (dequantize_2bit, fused_adam,
                                fused_sgd_momentum, quantize_2bit)
+    from geomx_tpu.ops.bsc_pallas import (bsc_scatter_add, bsc_select_pack,
+                                          sampled_boundary_guv,
+                                          scatter_add_ref, select_pack_ref)
+    from geomx_tpu.ops.bucket_pallas import (flatten_ref, fused_flatten,
+                                             fused_unflatten, unflatten_ref)
     from geomx_tpu.ops.merge_pallas import merge_sorted_pairs
     from geomx_tpu.ops.optim_pallas import adam_ref, sgd_momentum_ref
 
@@ -222,48 +229,52 @@ def kernels_phase(seed: int, interpret: bool = False, model=None) -> dict:
         checked[name] = f"rtol={rtol} atol={atol}"
 
     # bucket (un)flatten: a pure permutation
-    bk_ref = GradientBucketer(leaves, fused=False)
-    bk_fused = GradientBucketer(leaves, fused=True, fused_interpret=interpret)
-    buckets = jax.jit(lambda *ls: bk_ref.flatten(list(ls)))(*leaves)
+    bk = GradientBucketer(leaves)
+    flat = [leaf.reshape(-1) for leaf in leaves]
+    layout, sizes = bk._layout(), tuple(bk.bucket_sizes)
+    leaf_sizes = tuple(bk.leaf_sizes)
+    buckets = jax.jit(lambda *ls: flatten_ref(ls, layout, sizes))(*flat)
     same("fused_flatten",
-         jax.jit(lambda *ls: bk_fused.flatten(list(ls)))(*leaves), buckets)
+         fused_flatten(flat, layout, sizes, interpret=interpret), buckets)
     same("fused_unflatten",
-         jax.jit(lambda *bs: bk_fused.unflatten(list(bs)))(*buckets),
-         jax.jit(lambda *bs: bk_ref.unflatten(list(bs)))(*buckets))
+         fused_unflatten(buckets, layout, leaf_sizes, interpret=interpret),
+         jax.jit(lambda *bs: unflatten_ref(bs, layout, leaf_sizes))(*buckets))
 
     # Bi-Sparse select/pack on the flagship's one bucket, two "parties"
     g = buckets[0]
     n = int(g.shape[0])
-    spec = dict(ratio=0.01, select="sampled", min_sparse_size=1)
-    c_ref = BiSparseCompressor(fused=False, **spec)
-    c_fused = BiSparseCompressor(fused=True, fused_interpret=interpret,
-                                 **spec)
+    k = BiSparseCompressor(0.01).k_for(n)
     u = jnp.asarray(rng.normal(0, 0.1, n).astype(np.float32))
     v = jnp.asarray(rng.normal(0, 0.2, n).astype(np.float32))
     g2 = jnp.asarray(rng.normal(0, 1, n).astype(np.float32))
-    compress_ref = jax.jit(c_ref.compress)
-    compress_fused = jax.jit(c_fused.compress)
+
+    def compress_with(select):
+        return jax.jit(lambda g, u, v: select(
+            g, u, v, sampled_boundary_guv(g, u, v, k), k))
+
+    compress_ref = compress_with(select_pack_ref)
+    compress_fused = compress_with(functools.partial(
+        bsc_select_pack, interpret=interpret))
     sel = compress_fused(g, u, v)
     same("bsc_select_pack", sel, compress_ref(g, u, v))
     sel2 = compress_fused(g2, u, v)
     same("bsc_select_pack/2", sel2, compress_ref(g2, u, v))
-    k = int(sel[0].shape[0])
     require(int((np.asarray(sel[1]) >= 0).sum()) > k // 2,
             "bsc_select_pack emitted fewer than k/2 real pairs on "
             "gaussian input")
 
     # decompress: bitwise without collisions, to rounding with them
     def dec_ref(a, b):
-        return c_ref.decompress(a, b, n)
+        return scatter_add_ref(a, b, n)
 
     def dec_fused(a, b):
-        return c_fused.decompress(a, b, n)
+        return bsc_scatter_add(a, b, n, interpret=interpret)
 
-    same("bsc_scatter_add", jax.jit(dec_fused)(sel[0], sel[1]),
+    same("bsc_scatter_add", dec_fused(sel[0], sel[1]),
          jax.jit(dec_ref)(sel[0], sel[1]))
     all_vals = jnp.concatenate([sel[0], sel2[0]])
     all_idx = jnp.concatenate([sel[1], sel2[1]])
-    close("bsc_scatter_add/2 parties", jax.jit(dec_fused)(all_vals, all_idx),
+    close("bsc_scatter_add/2 parties", dec_fused(all_vals, all_idx),
           jax.jit(dec_ref)(all_vals, all_idx), rtol=0.0, atol=1e-5)
 
     # compressed-domain merge of the same two parties' pairs

@@ -204,8 +204,7 @@ def _dense_compressed_path() -> List[Finding]:
             return (out.reshape(g.shape).astype(g.dtype),
                     (u.reshape(g.shape), v.reshape(g.shape)))
 
-    comp = DenseLeakBSC(ratio=0.01, select="exact", min_sparse_size=1,
-                        fused=False)
+    comp = DenseLeakBSC(ratio=0.01, min_sparse_size=1)
     return audit_compressed_path(comp, jnp.zeros((8192,), jnp.float32))
 
 
@@ -241,8 +240,7 @@ def _dense_merge() -> List[Finding]:
             return (out.reshape(g.shape).astype(g.dtype),
                     (u.reshape(g.shape), v.reshape(g.shape)))
 
-    comp = DenseMergeBSC(ratio=0.01, select="exact", min_sparse_size=1,
-                         fused=False, sparse_agg=False)
+    comp = DenseMergeBSC(ratio=0.01, min_sparse_size=1, sparse_agg=False)
     return audit_compressed_path(comp, jnp.zeros((8192,), jnp.float32))
 
 

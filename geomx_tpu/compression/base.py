@@ -70,17 +70,6 @@ class Compressor(abc.ABC):
         return sum(self.wire_bytes_leaf(leaf) for leaf in jax.tree.leaves(grads))
 
 
-def default_on_tpu(env_var: str) -> bool:
-    """Shared policy for TPU-only fast paths: on unless ``env_var`` is set
-    to "0"; off (and deterministic) everywhere else.  Used for the fused
-    Pallas 2-bit kernels and BSC's approximate top-k."""
-    import os
-    # graftlint: disable=GXL006 — build-time gate
-    if os.environ.get(env_var) == "0":
-        return False
-    return jax.default_backend() == "tpu"
-
-
 class NoCompressor(Compressor):
     """Dense fp32 all-reduce (the reference's default uncompressed path)."""
 
@@ -115,14 +104,13 @@ _SPEC_GRAMMAR = {
     "fp16": ([], {"bf16": _parse_bool, "sparse_agg": _parse_bool}),
     "2bit": (["threshold"], {"threshold": float,
                              "sparse_agg": _parse_bool}),
-    "bsc": (["ratio"], {"ratio": float, "select": str,
+    "bsc": (["ratio"], {"ratio": float,
                         "min_sparse_size": _parse_int,
-                        "approx": _parse_bool, "fused": _parse_bool,
                         "sparse_agg": _parse_bool,
                         "sparse_agg_parties": _parse_int}),
     "mpq": (["ratio", "size_lower_bound"],
             {"ratio": float, "size_lower_bound": _parse_int,
-             "bf16": _parse_bool, "approx": _parse_bool}),
+             "bf16": _parse_bool}),
 }
 
 
@@ -132,7 +120,7 @@ def get_compressor(spec) -> Compressor:
     Mirrors GradientCompression::DecodeParams
     (reference: src/kvstore/gradient_compression.cc:91-100), extended
     with ``key=value`` arguments for knobs the positional form cannot
-    express: ``"bsc,0.01,select=sampled,min_sparse_size=2048"``,
+    express: ``"bsc,0.01,min_sparse_size=2048"``,
     ``"fp16,bf16=1"``, ``"mpq,ratio=0.02,size_lower_bound=100000"``.
     Positional args must precede keyword args; unknown keys are rejected
     with the valid vocabulary in the error.

@@ -14,12 +14,21 @@ Reference semantics (src/kvstore/gradient_compression.cc:191-336):
   only those, again as fixed-size (value, index) pairs — so the pull is
   sparse too ("bi-directional").
 
-TPU-native design:
+Design here:
 
-- Exact (or optionally TPU-approximate) top-k via ``lax.top_k`` /
-  ``lax.approx_max_k`` instead of the sampled-boundary scan — the fixed
-  payload size ``k = ceil(ratio*N)`` is what XLA's static shapes want, and
-  it is precisely the size the reference allocates for the wire buffer.
+- The selection is the reference's own: a magnitude boundary from a
+  sorted probe of ~8k fixed positions (``ops/bsc_pallas.
+  sampled_boundary_guv``), then one two-tier scan (elements strictly
+  above the boundary claim slots first, in index order; boundary ties
+  fill what remains).  It emits AT MOST ``k = ceil(ratio*N)`` pairs,
+  ~97% of k at BERT-large's sizes; unfilled slots ride as sentinels and
+  the unsent mass stays in u/v.  O(N), no sort of anything the bucket's
+  size.  There is no other rule: this is what the chip runs, what the
+  tests run, and what the benchmark's plain reference implements.
+- This module says what is sent, what is kept back and what it costs on
+  the wire.  How select/pack and the scatter-add are computed (a Pallas
+  kernel on a TPU, their jnp forms elsewhere) is ``ops/dispatch.py``'s
+  decision, made from the platform; nothing here can override it.
 - The all-gather of the (values, indices) pairs across the ``dc`` axis is
   the push; every party scatter-adds all parties' pairs into a dense
   aggregate locally. Because the aggregate has <= k*P non-zeros by
@@ -40,11 +49,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from geomx_tpu.compression.base import Compressor
+from geomx_tpu.ops import dispatch
+from geomx_tpu.ops.bsc_pallas import sampled_boundary_guv, select_pack_shape
 from geomx_tpu.parallel.collectives import tier_scope
 from geomx_tpu.utils.profiler import profile_scope
-
-MOMENTUM = 0.9  # hardcoded in the reference (gc.cc:200)
-
 
 def _note_dense_fallback(n: int, min_sparse_size: int) -> None:
     """The silent "too small to sparsify, send dense fp32" decision,
@@ -69,28 +77,12 @@ def _note_dense_fallback(n: int, min_sparse_size: int) -> None:
 class BiSparseCompressor(Compressor):
     name = "bsc"
 
-    def __init__(self, ratio: float = 0.01, approx: "bool | None" = None,
-                 min_sparse_size: int = 1024,
-                 select: "str | None" = None,
-                 fused: "bool | None" = None,
-                 fused_interpret: bool = False,
+    def __init__(self, ratio: float = 0.01, min_sparse_size: int = 1024,
                  sparse_agg: "bool | None" = None,
                  sparse_agg_parties: "int | None" = None):
-        """``select``: "exact" (lax.top_k), "approx" (lax.approx_max_k),
-        or "sampled" (the reference's sampled-boundary scan,
-        ops/sampled_topk.py).  Default: GEOMX_BSC_SELECT if set, else —
-        on a TPU with the fused kernels enabled — "sampled" (the fused
-        ops/bsc_pallas.py path IS the sampled scan, as two streaming
-        passes), else "approx" on TPU and "exact" elsewhere (deterministic
-        behavioral tests vs the reference recurrences run on CPU).
-        ``approx`` is the legacy boolean spelling of exact/approx.
-
-        ``fused``: use the Pallas kernels (ops/bsc_pallas.py) — the
-        select/pack kernel when ``select == "sampled"`` (the other
-        selections keep their lax.top_k forms) and the scatter-add
-        decompress for every selection.  Default: on when the backend is
-        TPU and GEOMX_FUSED_KERNELS != 0.  ``fused_interpret`` runs the
-        kernels in Pallas interpret mode (CPU parity tests).
+        """``min_sparse_size``: tensors smaller than this aren't worth
+        sparsifying (the 2*k payload would approach the dense size) and
+        go dense fp32.
 
         ``sparse_agg``: merge in the compressed domain — the
         owner-routed sparse allreduce of compression/sparseagg.py
@@ -107,39 +99,9 @@ class BiSparseCompressor(Compressor):
         width of the most recent traced allreduce is used (2 before
         any trace) — pass it when calling ``wire_bytes`` before the
         first trace or when one instance serves multiple widths."""
-        import os
         if ratio <= 0:
             raise ValueError("threshold must be greater than 0")
         self.ratio = float(ratio)
-        from geomx_tpu.ops.bsc_pallas import fused_kernels_enabled
-        if select is None:
-            if approx is not None:
-                select = "approx" if approx else "exact"
-            else:
-                # empty string (an unset-but-exported launcher variable)
-                # falls back to the platform default
-                # graftlint: disable=GXL006 — constructor default
-                select = os.environ.get("GEOMX_BSC_SELECT") or None
-            if select is None:
-                if fused or (fused is None and fused_kernels_enabled()):
-                    select = "sampled"
-                else:
-                    from geomx_tpu.compression.base import default_on_tpu
-                    select = "approx" if default_on_tpu(
-                        "GEOMX_BSC_APPROX_TOPK") else "exact"
-        if select not in ("exact", "approx", "sampled"):
-            raise ValueError(f"unknown BSC selection {select!r}")
-        self.select = select
-        self.approx = select == "approx"
-        if fused is None:
-            fused = fused_kernels_enabled()
-        self.fused = bool(fused)
-        # the fused select kernel implements the sampled scan only; the
-        # fused decompress applies to every selection mode
-        self.fused_select = self.fused and select == "sampled"
-        self.fused_interpret = bool(fused_interpret)
-        # tensors smaller than this aren't worth sparsifying: 2*k payload
-        # would approach the dense size; send dense fp32 instead
         self.min_sparse_size = int(min_sparse_size)
         if sparse_agg is None:
             from geomx_tpu.compression.sparseagg import sparse_agg_enabled
@@ -167,117 +129,48 @@ class BiSparseCompressor(Compressor):
                 jnp.zeros(leaf.shape, jnp.float32))
 
     def compress(self, g_flat: jax.Array, u: jax.Array, v: jax.Array):
-        """Momentum-corrected top-k selection with error feedback.
-
-        Returns (values[k], indices[k], new_u, new_v).
+        """Momentum-corrected sampled-boundary selection with error
+        feedback.  Returns (values[k], indices[k], new_u, new_v).
 
         Graft Pilot ratio retuning (control/, docs/control.md): when a
-        control context is open, the EFFECTIVE selection count is
-        ``eff_k = round(k * scale)`` with ``scale`` a TRACED scalar
-        operand — the wire buffers stay ``k`` slots (static shapes, no
-        recompile; the configured ratio is the capacity), unemitted
-        slots ride as sentinels, and the unsent mass stays in the
-        error-feedback buffers exactly as an under-full sampled scan
-        leaves it.  With no context open (``GEOMX_CONTROL=0``) this
-        method traces byte-identically to the pre-control build.
+        control context is open, the boundary is the one that lets
+        ``eff_k = round(k * scale)`` pairs through, ``scale`` a TRACED
+        scalar operand — the wire buffers stay ``k`` slots (static
+        shapes, no recompile; the configured ratio is the capacity),
+        unemitted slots ride as sentinels, and the unsent mass stays in
+        the error-feedback buffers.  With no context open
+        (``GEOMX_CONTROL=0``) the boundary's position is static.
         """
         from geomx_tpu.control.actuators import current_ratio_scale
         from geomx_tpu.telemetry.probes import record_inline
         n = g_flat.shape[0]
         k = self.k_for(n)
         scale = current_ratio_scale()
-        eff_k = None
+        eff_k = k
         if scale is not None:
             eff_k = jnp.clip(jnp.round(k * scale), 1.0,
                              float(k)).astype(jnp.int32)
-        if self.fused_select:
-            # momentum math, boundary select, fixed-k pack and EF reset
-            # fused (ops/bsc_pallas.py: a counting and a placing pass
-            # over the bucket, one call where it is one tile); only the
-            # ~8k-element threshold probe and the placement's schedule
-            # run in XLA.  A traced eff_k raises the sampled boundary so
-            # the kernel emits ~eff_k pairs — the kernel itself is
-            # untouched (thr was always an operand).
-            from geomx_tpu.ops.bsc_pallas import (bsc_select_pack,
-                                                  sampled_boundary_guv,
-                                                  select_pack_shape)
-            with profile_scope("compress/boundary", category="kernel"):
-                thr = sampled_boundary_guv(g_flat, u, v,
-                                           k if eff_k is None else eff_k)
-            tiles, out_blocks, _ = select_pack_shape(n, k)
-            with profile_scope("bsc/select_pack", category="kernel",
-                              args={"n": n, "k": k, "tiles": tiles,
-                                    "out_blocks": out_blocks}):
-                vals, idx, u, v = bsc_select_pack(
-                    g_flat, u, v, thr, k, interpret=self.fused_interpret)
-            # in-situ achieved payload (telemetry/probes.py): the
-            # sampled boundary emits <= k real pairs, the rest ride as
-            # sentinels — wasted wire the configured ratio hides.  The
-            # thunk keeps the disabled path op-free.
-            record_inline("bsc_emitted_fraction",
-                          lambda: jnp.sum(idx >= 0) / k)
-            return vals, idx, u, v
-        u = u * MOMENTUM + g_flat
-        v = v + u
-        absv = jnp.abs(v)
-        if self.select == "sampled":
-            # the reference's own algorithm (sampled boundary + one
-            # zipping scan, gc.cc:219-259) — O(n), no sort/top-k.  The
-            # control plane's eff_k only moves the boundary quantile
-            # (a traced gather index); the scan's shapes are untouched.
-            from geomx_tpu.ops.sampled_topk import (sampled_boundary,
-                                                    sampled_threshold_select)
-            thr = None if eff_k is None else sampled_boundary(absv, eff_k)
-            vals, idx, keep = sampled_threshold_select(v, absv, k, thr=thr)
-            # error feedback: emitted coordinates reset (gc.cc:250-252)
-            v = jnp.where(keep, 0.0, v)
-            u = jnp.where(keep, 0.0, u)
-            record_inline("bsc_emitted_fraction",
-                          lambda: jnp.sum(idx >= 0) / k)
-            return vals, idx, u, v
-        if self.select == "approx":
-            _, idx = lax.approx_max_k(absv, k)
-        else:
-            _, idx = lax.top_k(absv, k)
-        idx = idx.astype(jnp.int32)
-        if eff_k is not None:
-            # ranked selection under a traced eff_k: slots past eff_k
-            # become sentinels BEFORE error feedback, so the mass they
-            # would have carried stays in u/v (out-of-range scatter
-            # coordinates drop instead of clamping onto element n-1)
-            keepslot = jnp.arange(k, dtype=jnp.int32) < eff_k
-            vals = jnp.where(keepslot, v[idx], 0.0)
-            sent = jnp.where(keepslot, idx, n).astype(jnp.int32)
-            v = v.at[sent].set(0.0, mode="drop")
-            u = u.at[sent].set(0.0, mode="drop")
-            out_idx = jnp.where(keepslot, idx, -1).astype(jnp.int32)
-            record_inline("bsc_emitted_fraction",
-                          lambda: jnp.sum(out_idx >= 0) / k)
-            return vals, out_idx, u, v
-        vals = v[idx]
-        # error feedback: sent coordinates reset in both buffers (gc.cc:250-252)
-        v = v.at[idx].set(0.0)
-        u = u.at[idx].set(0.0)
-        # exact/approx top-k always fills all k slots
-        record_inline("bsc_emitted_fraction", lambda: jnp.ones((), jnp.float32))
+        with profile_scope("compress/boundary", category="kernel"):
+            thr = sampled_boundary_guv(g_flat, u, v, eff_k)
+        tiles, out_blocks, _ = select_pack_shape(n, k)
+        with profile_scope("bsc/select_pack", category="kernel",
+                           args={"n": n, "k": k, "tiles": tiles,
+                                 "out_blocks": out_blocks}):
+            vals, idx, u, v = dispatch.select_pack(g_flat, u, v, thr, k)
+        # in-situ achieved payload (telemetry/probes.py): the sampled
+        # boundary emits <= k real pairs, the rest ride as sentinels —
+        # wasted wire the configured ratio hides.  The thunk keeps the
+        # disabled path op-free.
+        record_inline("bsc_emitted_fraction", lambda: jnp.sum(idx >= 0) / k)
         return vals, idx, u, v
 
     def decompress(self, vals: jax.Array, idx: jax.Array, n: int) -> jax.Array:
         """Scatter-add (value, index) pairs into a dense vector
         (reference BSCDecompress, gc.cc:310-336). Negative indices are
         padding sentinels and are dropped."""
-        if self.fused:
-            # fused scatter-add: no XLA scatter, no per-party dense
-            # intermediate (ops/bsc_pallas.py)
-            from geomx_tpu.ops.bsc_pallas import bsc_scatter_add
-            with profile_scope("bsc/scatter_add", category="kernel",
-                              args={"n": n, "pairs": int(vals.shape[0])}):
-                return bsc_scatter_add(vals, idx, n,
-                                       interpret=self.fused_interpret)
-        valid = idx >= 0
-        safe_idx = jnp.where(valid, idx, 0)
-        contrib = jnp.where(valid, vals, 0.0)
-        return jnp.zeros((n,), jnp.float32).at[safe_idx].add(contrib)
+        with profile_scope("bsc/scatter_add", category="kernel",
+                           args={"n": n, "pairs": int(vals.shape[0])}):
+            return dispatch.scatter_add(vals, idx, n)
 
     def allreduce_leaf(self, g: jax.Array, state: Any, axis_name: str,
                        axis_size: int) -> Tuple[jax.Array, Any]:
@@ -309,8 +202,7 @@ class BiSparseCompressor(Compressor):
             with profile_scope("compress/exchange", category="comm"):
                 out, v = sparse_allreduce(
                     vals, idx, n, axis_name, axis_size, self.decompress,
-                    ef_buffer=v, merge_fused=self.fused,
-                    interpret=self.fused_interpret)
+                    ef_buffer=v)
         else:
             # the wire transfer: 2k floats per party over the dc tier
             with profile_scope("compress/exchange", category="comm"), \

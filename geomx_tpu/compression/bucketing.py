@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 
 from geomx_tpu.compression.base import Compressor
+from geomx_tpu.ops import dispatch
 from geomx_tpu.utils.profiler import profile_scope
 
 # 4 MiB of fp32 per bucket: large enough that a ResNet/transformer
@@ -87,16 +88,9 @@ class GradientBucketer:
 
     def __init__(self, leaves: Sequence[Any],
                  bucket_bytes: int = DEFAULT_BUCKET_BYTES,
-                 pad_to: int = _LANE_PAD,
-                 fused: "Optional[bool]" = None,
-                 fused_interpret: bool = False):
+                 pad_to: int = _LANE_PAD):
         if bucket_bytes <= 0:
             raise ValueError(f"bucket_bytes must be > 0, got {bucket_bytes}")
-        if fused is None:
-            from geomx_tpu.ops.bsc_pallas import fused_kernels_enabled
-            fused = fused_kernels_enabled()
-        self.fused = bool(fused)
-        self.fused_interpret = bool(fused_interpret)
         self.pad_to = max(1, int(pad_to))
         self.capacity = max(self.pad_to, int(bucket_bytes) // 4)
         self.leaf_shapes = [tuple(leaf.shape) for leaf in leaves]
@@ -129,46 +123,25 @@ class GradientBucketer:
                      zip(self.assignments, self.leaf_sizes))
 
     def flatten(self, leaves: Sequence[jax.Array]) -> List[jax.Array]:
-        """Pytree leaves -> list of flat fp32 buckets (padded).
-
-        With the fused kernels enabled, one Pallas DMA kernel gathers
-        every leaf into its bucket slot (ops/bucket_pallas.py) instead
-        of one XLA concatenate operand per leaf; the jnp path below is
-        the bit-identical fallback and parity oracle."""
-        if self.fused and self.num_buckets > 0:
-            from geomx_tpu.ops.bucket_pallas import fused_flatten
-            flat = [leaf.reshape(-1).astype(jnp.float32) for leaf in leaves]
-            return fused_flatten(flat, self._layout(),
-                                 tuple(self.bucket_sizes),
-                                 interpret=self.fused_interpret)
-        pieces: List[List[jax.Array]] = [[] for _ in range(self.num_buckets)]
-        for leaf, (b, _off) in zip(leaves, self.assignments):
-            pieces[b].append(leaf.reshape(-1).astype(jnp.float32))
-        buckets = []
-        for i, ps in enumerate(pieces):
-            pad = self.bucket_sizes[i] - self.bucket_fill[i]
-            if pad:
-                ps = ps + [jnp.zeros((pad,), jnp.float32)]
-            buckets.append(ps[0] if len(ps) == 1 else jnp.concatenate(ps))
-        return buckets
+        """Pytree leaves -> list of flat fp32 buckets (padded): on a TPU
+        one Pallas DMA kernel per multi-leaf bucket, elsewhere one XLA
+        concatenate operand per leaf (ops/dispatch.py decides)."""
+        if not self.num_buckets:
+            return []
+        flat = [leaf.reshape(-1).astype(jnp.float32) for leaf in leaves]
+        return dispatch.flatten_buckets(flat, self._layout(),
+                                        tuple(self.bucket_sizes))
 
     def unflatten(self, buckets: Sequence[jax.Array]) -> List[jax.Array]:
         """Flat buckets -> leaves with their original shapes and dtypes."""
-        if self.fused and self.num_buckets > 0:
-            from geomx_tpu.ops.bucket_pallas import fused_unflatten
-            flat = fused_unflatten([b.reshape(-1) for b in buckets],
-                                   self._layout(), tuple(self.leaf_sizes),
-                                   interpret=self.fused_interpret)
-            return [f.reshape(shape).astype(dtype)
-                    for f, shape, dtype in zip(flat, self.leaf_shapes,
-                                               self.leaf_dtypes)]
-        out = []
-        for (b, off), shape, dtype, size in zip(
-                self.assignments, self.leaf_shapes, self.leaf_dtypes,
-                self.leaf_sizes):
-            out.append(buckets[b][off:off + size].reshape(shape)
-                       .astype(dtype))
-        return out
+        if not self.num_buckets:
+            return []
+        flat = dispatch.unflatten_buckets(
+            [b.reshape(-1) for b in buckets], self._layout(),
+            tuple(self.leaf_sizes))
+        return [f.reshape(shape).astype(dtype)
+                for f, shape, dtype in zip(flat, self.leaf_shapes,
+                                           self.leaf_dtypes)]
 
 
 def _resolve_bucket_bytes(bucket_bytes: Optional[int]) -> int:
@@ -196,9 +169,7 @@ class BucketedCompressor(Compressor):
 
     def __init__(self, inner: Compressor,
                  bucket_bytes: Optional[int] = None,
-                 pad_to: int = _LANE_PAD,
-                 fused: Optional[bool] = None,
-                 fused_interpret: bool = False):
+                 pad_to: int = _LANE_PAD):
         self.inner = inner
         self.name = inner.name
         self.bucket_bytes = _resolve_bucket_bytes(bucket_bytes)
@@ -207,8 +178,6 @@ class BucketedCompressor(Compressor):
                              "use the bare inner compressor to disable "
                              "bucketing")
         self.pad_to = pad_to
-        self.fused = fused
-        self.fused_interpret = fused_interpret
         self._bucketers: dict = {}
 
     # -- layout cache (one per tree structure, resolved at trace time) ------
@@ -216,9 +185,7 @@ class BucketedCompressor(Compressor):
         key = tuple((tuple(leaf.shape), jnp.dtype(leaf.dtype).str) for leaf in leaves)
         bk = self._bucketers.get(key)
         if bk is None:
-            bk = GradientBucketer(leaves, self.bucket_bytes, self.pad_to,
-                                  fused=self.fused,
-                                  fused_interpret=self.fused_interpret)
+            bk = GradientBucketer(leaves, self.bucket_bytes, self.pad_to)
             self._bucketers[key] = bk
         return bk
 

@@ -29,12 +29,10 @@ class MPQCompressor(Compressor):
     name = "mpq"
 
     def __init__(self, ratio: float = 0.01, size_lower_bound: int = 200_000,
-                 bf16: bool = False, approx: "bool | None" = None):
+                 bf16: bool = False):
         self.size_lower_bound = int(size_lower_bound)
         self.small = FP16Compressor(bf16=bf16)
-        # approx=None inherits BiSparseCompressor's platform default
-        # (approximate top-k on TPU, exact elsewhere)
-        self.large = BiSparseCompressor(ratio=ratio, approx=approx)
+        self.large = BiSparseCompressor(ratio=ratio)
 
     def _route(self, leaf: jax.Array) -> Compressor:
         return self.large if leaf.size >= self.size_lower_bound else self.small

@@ -148,8 +148,6 @@ def owner_route(vals, idx, n: int, num_parties: int, slots: int):
 
 def sparse_allreduce(vals, idx, n: int, axis_name: str, axis_size: int,
                      decompress, *, ef_buffer=None,
-                     merge_fused: bool = False,
-                     interpret: bool = False,
                      slack: "float | None" = None,
                      pull_slack: "float | None" = None):
     """The owner-routed compressed-domain allreduce (module docstring).
@@ -162,12 +160,12 @@ def sparse_allreduce(vals, idx, n: int, axis_name: str, axis_size: int,
     the routing overflow — pairs past a destination's slot budget —
     BEFORE the collectives launch, so their mass retries next round;
     returns ``(dense_out, new_ef_buffer)`` (``new_ef_buffer`` is None
-    when no buffer was handed in).  ``merge_fused`` selects the Pallas
-    merge kernel; the jnp path is bit-identical (ops/merge_pallas.py)."""
+    when no buffer was handed in).  The merge is ops/merge_pallas.py's:
+    a Pallas kernel on a TPU, its bit-identical jnp tree elsewhere."""
     import jax.numpy as jnp
     from jax import lax
 
-    from geomx_tpu.ops.merge_pallas import merge_sorted_pairs
+    from geomx_tpu.ops.dispatch import merge_pairs
     from geomx_tpu.telemetry.probes import record_inline
 
     k = int(vals.shape[0])
@@ -184,8 +182,7 @@ def sparse_allreduce(vals, idx, n: int, axis_name: str, axis_size: int,
     ri = lax.all_to_all(buf_i, axis_name, split_axis=0, concat_axis=0)
     # rows arrive in party order regardless of wall-clock scheduling:
     # the merged bits are a function of the contribution multiset alone
-    mvals, midx = merge_sorted_pairs(rv.reshape(-1), ri.reshape(-1), P,
-                                     fused=merge_fused, interpret=interpret)
+    mvals, midx = merge_pairs(rv.reshape(-1), ri.reshape(-1), P)
     score = jnp.where(midx >= 0, jnp.abs(mvals), -1.0)
     top_score, top_pos = lax.top_k(score, kr)
     tvals = jnp.where(top_score >= 0, mvals[top_pos], 0.0)

@@ -29,14 +29,13 @@ one chip holds: the sorted assignments walked a pool at a time in a loop
 of as many trips as pools exist, the SwiGLU as JAX's megablox grouped
 products).
 
-Kernels run natively on TPU and in Pallas interpret mode elsewhere
-(tests exercise them on CPU via interpret mode).
-``GEOMX_FUSED_KERNELS=0`` is the master opt-out for the fused
-compression kernels (``fused_kernels_enabled``).
+The names exported here are the kernels themselves (native on a TPU,
+``interpret=True`` for the CPU parity tests).  The compression engine does
+not call them: it calls ``ops.dispatch``, the one place that decides
+between a kernel and its jnp form, from the platform.
 """
 
-from geomx_tpu.ops.bsc_pallas import (bsc_scatter_add, bsc_select_pack,
-                                      fused_kernels_enabled)
+from geomx_tpu.ops.bsc_pallas import bsc_scatter_add, bsc_select_pack
 from geomx_tpu.ops.bucket_pallas import fused_flatten, fused_unflatten
 from geomx_tpu.ops.flash_attention import (flash_attention,
                                            flash_attention_bwd,
@@ -49,11 +48,10 @@ from geomx_tpu.ops.optim_pallas import (FusedOptimSpec, FusedOptimizer,
                                         fused_optimizer,
                                         fused_sgd_momentum, fused_spec_of,
                                         unfused_apply)
-from geomx_tpu.ops.twobit_pallas import (dequantize_2bit, pallas_supported,
-                                         quantize_2bit)
+from geomx_tpu.ops.twobit_pallas import dequantize_2bit, quantize_2bit
 
-__all__ = ["quantize_2bit", "dequantize_2bit", "pallas_supported",
-           "bsc_select_pack", "bsc_scatter_add", "fused_kernels_enabled",
+__all__ = ["quantize_2bit", "dequantize_2bit",
+           "bsc_select_pack", "bsc_scatter_add",
            "fused_flatten", "fused_unflatten",
            "flash_attention", "flash_attention_bwd",
            "flash_attention_with_lse", "fused_attention",

@@ -113,26 +113,17 @@ _OUT_ROWS = 128                    # dense output rows per decompress block
 _SENTINEL_KEY = 2 ** 31 - 1        # a sentinel pair's sort key: after every index
 
 
-def fused_kernels_enabled() -> bool:
-    """Master gate for the fused compression kernels: on when the default
-    backend is a TPU unless ``GEOMX_FUSED_KERNELS=0`` opts out (the
-    shared TPU-fast-path policy, compression/base.default_on_tpu).  The
-    jnp reference paths stay bit-exact on every backend and serve as the
-    parity oracle (tests/test_bsc_pallas.py)."""
-    from geomx_tpu.compression.base import default_on_tpu
-    return default_on_tpu("GEOMX_FUSED_KERNELS")
-
-
 def sampled_boundary_guv(g: jax.Array, u: jax.Array, v: jax.Array, k,
                          sample: int = 8192):
-    """The sampled magnitude boundary computed WITHOUT materializing the
-    dense momentum-corrected tensor: gathers the ~``sample`` probe
-    positions of g/u/v and applies the momentum arithmetic to just those
-    — the full ``|v + (0.9u + g)|`` lives only inside the fused kernel.
-    Same quantile rule as ``ops.sampled_topk.sampled_boundary``; ``k``
-    may be a traced scalar (the control plane's effective-k operand) —
-    the boundary position becomes a traced gather index, the kernel's
-    static shapes never change."""
+    """The sampled magnitude boundary: the (1 - k/n) quantile of the
+    sorted momentum-corrected magnitudes at ~``sample`` probe positions,
+    computed WITHOUT materializing the dense tensor: it gathers g/u/v at
+    the probe positions (``sampled_topk.sample_positions``) and applies
+    the momentum arithmetic to just those.  The one boundary of every
+    path: the kernel and the jnp scan select against the same scalar.
+    ``k`` may be a traced scalar (the control plane's effective-k
+    operand) — the boundary position becomes a traced gather index, no
+    shape changes."""
     from geomx_tpu.ops.sampled_topk import boundary_position, sample_positions
 
     n = g.shape[0]
@@ -141,6 +132,29 @@ def sampled_boundary_guv(g: jax.Array, u: jax.Array, v: jax.Array, k,
     m = samp.shape[0]
     ssorted = jnp.sort(samp)
     return ssorted[boundary_position(m, k, n)]
+
+
+def select_pack_ref(g: jax.Array, u: jax.Array, v: jax.Array,
+                    threshold: jax.Array, k: int):
+    """jnp form of :func:`bsc_select_pack`, the only path off a TPU and
+    the kernel's oracle: momentum correction, the two-tier scan of
+    ``sampled_topk.sampled_threshold_select``, error-feedback reset of
+    the emitted coordinates (gc.cc:250-252).  Same four outputs."""
+    from geomx_tpu.ops.sampled_topk import sampled_threshold_select
+
+    u = u * MOMENTUM + g
+    v = v + u
+    vals, idx, keep = sampled_threshold_select(v, jnp.abs(v), k, threshold)
+    return vals, idx, jnp.where(keep, 0.0, u), jnp.where(keep, 0.0, v)
+
+
+def scatter_add_ref(vals: jax.Array, idx: jax.Array, n: int) -> jax.Array:
+    """jnp form of :func:`bsc_scatter_add` (XLA's scatter-add): bitwise
+    equal where no two pairs share an index, to rounding where they do
+    (the order of the sum differs)."""
+    valid = idx >= 0
+    return jnp.zeros((n,), jnp.float32).at[jnp.where(valid, idx, 0)].add(
+        jnp.where(valid, vals, 0.0))
 
 
 def _momentum_classes(g_ref, u_ref, v_ref, thr, base, n):
@@ -505,8 +519,7 @@ def bsc_select_pack(g: jax.Array, u: jax.Array, v: jax.Array,
     Args: flat fp32 ``g``/``u``/``v`` of equal length ``n``; ``threshold``
     a traced scalar (the sampled magnitude boundary); static ``k``.
     Returns ``(vals[k], idx[k] int32 with -1 sentinels, new_u[n],
-    new_v[n])`` — bit-identical to the ``sampled_threshold_select`` +
-    error-feedback jnp chain in compression/bisparse.py.
+    new_v[n])`` — bit-identical to :func:`select_pack_ref`.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu

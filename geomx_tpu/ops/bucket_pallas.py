@@ -172,8 +172,7 @@ def _compiler_params(total: int, leaf_sizes: Sequence[int]):
         raise ValueError(
             f"a {total}-element multi-leaf bucket needs {need} bytes of "
             f"VMEM; the fused bucket kernels take buckets up to "
-            f"{MAX_FUSED_BUCKET_ELEMS} elements — lower GEOMX_BUCKET_BYTES "
-            "or set GEOMX_FUSED_KERNELS=0")
+            f"{MAX_FUSED_BUCKET_ELEMS} elements — lower GEOMX_BUCKET_BYTES")
     return pltpu.CompilerParams(vmem_limit_bytes=need)
 
 
@@ -255,3 +254,27 @@ def fused_unflatten(buckets: Sequence[jax.Array],
         for (i, _off, size), leaf in zip(members, out):
             leaves[i] = leaf.reshape(-1)[:size]
     return leaves
+
+
+def flatten_ref(leaves: Sequence[jax.Array],
+                layout: Tuple[Tuple[int, int, int], ...],
+                bucket_sizes: Tuple[int, ...]) -> List[jax.Array]:
+    """jnp form of :func:`fused_flatten` (one XLA concatenate operand per
+    leaf), bit-identical: the only path off a TPU and the kernel's
+    oracle."""
+    buckets = []
+    for b, total in enumerate(bucket_sizes):
+        members, fill = _bucket_members(layout, b)
+        pieces = [leaves[i] for i, _off, _size in members]
+        if total - fill or not pieces:
+            pieces.append(jnp.zeros((total - fill,), jnp.float32))
+        buckets.append(pieces[0] if len(pieces) == 1
+                       else jnp.concatenate(pieces))
+    return buckets
+
+
+def unflatten_ref(buckets: Sequence[jax.Array],
+                  layout: Tuple[Tuple[int, int, int], ...],
+                  leaf_sizes: Tuple[int, ...]) -> List[jax.Array]:
+    """jnp form of :func:`fused_unflatten` (one slice per leaf)."""
+    return [buckets[b][off:off + size] for b, off, size in layout]

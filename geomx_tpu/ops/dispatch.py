@@ -1,0 +1,109 @@
+"""Kernel or XLA: the one place that decides, from the platform.
+
+Every op of the compression engine exists twice under ``ops/``: a Pallas
+kernel and a jnp form with the same results (bit for bit, except where an
+op's docstring says otherwise).  The kernel is what a TPU runs; the jnp
+form is the only path elsewhere and the oracle the tests hold the kernel
+to.  The functions below are what ``compression/`` calls: each picks its
+implementation while the program is traced, from :func:`kernel_mode`.
+Nothing above ``ops/`` (a compressor's arguments, the spec string,
+``GeoConfig``, the environment) can choose, and nothing above it asks.
+
+``tools/*_timing.py`` and ``chip_smoke.py`` compare kernel and oracle by
+calling both by name (``bsc_select_pack`` / ``select_pack_ref`` and so
+on), not through here.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import contextvars
+from typing import Optional
+
+import jax
+
+from geomx_tpu.ops import bsc_pallas, bucket_pallas, merge_pallas, twobit_pallas
+
+_OVERRIDE: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
+    "geomx_kernel_mode", default=None)
+
+
+def kernel_mode() -> Optional[str]:
+    """How an op that has a Pallas kernel runs in the program being
+    traced: ``"native"`` (compiled by Mosaic: on a TPU), ``"interpret"``
+    (the kernel under the Pallas interpreter) or ``None`` (no kernel: the
+    op's XLA form, or interpret mode for an op that has no other)."""
+    forced = _OVERRIDE.get()
+    if forced is not None:
+        return forced
+    return "native" if jax.default_backend() == "tpu" else None
+
+
+@contextlib.contextmanager
+def kernels(mode: str):
+    """Tests and tools only: trace what is inside with the kernels in
+    ``"interpret"`` mode (parity on the CPU) or lowered ``"native"`` (to
+    compile for a described TPU from a CPU host; such a program lowers
+    anywhere and runs only on a TPU).  It acts while a program is TRACED:
+    wrap the first call or the ``.lower()`` of a fresh ``jax.jit``, since
+    a function jitted before keeps the program it was traced to."""
+    if mode not in ("interpret", "native"):
+        raise ValueError(f"kernels(): 'interpret' or 'native', got {mode!r}")
+    token = _OVERRIDE.set(mode)
+    try:
+        yield
+    finally:
+        _OVERRIDE.reset(token)
+
+
+def _door(kernel, ref, doc):
+    """An op of the engine: ``kernel`` where :func:`kernel_mode` names
+    one, else ``ref``; same arguments, same results."""
+    def op(*args):
+        mode = kernel_mode()
+        if mode is None:
+            return ref(*args)
+        return kernel(*args, interpret=mode == "interpret")
+    op.__doc__ = doc
+    return op
+
+
+select_pack = _door(
+    bsc_pallas.bsc_select_pack, bsc_pallas.select_pack_ref,
+    """``(g, u, v, threshold, k)``: Bi-Sparse select/pack of one flat
+    bucket against the boundary: ``(vals[k], idx[k], new_u, new_v)``.""")
+scatter_add = _door(
+    bsc_pallas.bsc_scatter_add, bsc_pallas.scatter_add_ref,
+    """``(vals, idx, n)``: Bi-Sparse decompress, pairs into a dense [n].""")
+flatten_buckets = _door(
+    bucket_pallas.fused_flatten, bucket_pallas.flatten_ref,
+    """``(leaves, layout, bucket_sizes)``: 1-D fp32 leaves -> flat fp32
+    buckets (``bucket_pallas``'s layout).""")
+unflatten_buckets = _door(
+    bucket_pallas.fused_unflatten, bucket_pallas.unflatten_ref,
+    """``(buckets, layout, leaf_sizes)``: flat buckets -> 1-D leaves.""")
+quantize_2bit = _door(
+    twobit_pallas.quantize_2bit, twobit_pallas.quantize_2bit_ref,
+    """``(g, residual, threshold)``: ``(packed int32 words, new
+    residual)``.  The words are opaque: the kernel's layout is row-blocked,
+    the jnp form's is not, each the inverse of its own
+    :func:`dequantize_2bit`; :func:`twobit_words` is the count either puts
+    on the wire.""")
+dequantize_2bit = _door(
+    twobit_pallas.dequantize_2bit, twobit_pallas.dequantize_2bit_ref,
+    """``(words, n, threshold)``: the values a party sent, dense [n].""")
+
+
+def twobit_words(n: int) -> int:
+    """int32 words :func:`quantize_2bit` emits here for ``n`` elements."""
+    if kernel_mode() is None:
+        return twobit_pallas.words_ref(n)
+    return twobit_pallas.words_kernel(n)
+
+
+def merge_pairs(vals, idx, max_duplicates: int):
+    """Merge a (value, index) pair stream by index (``merge_pallas``)."""
+    mode = kernel_mode()
+    return merge_pallas.merge_sorted_pairs(
+        vals, idx, max_duplicates, fused=mode is not None,
+        interpret=mode == "interpret")

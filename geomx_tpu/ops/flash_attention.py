@@ -376,13 +376,10 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
 
 
 def fused_attention_supported() -> bool:
-    """True when the native kernel path is active: on TPU, unless the
-    GEOMX_FLASH_ATTN=0 kill-switch forces the dense fallback."""
-    import os
-    # graftlint: disable=GXL003,GXL006 — build-time gate
-    if os.environ.get("GEOMX_FLASH_ATTN", "1") == "0":
-        return False
-    return jax.devices()[0].platform == "tpu"
+    """True when the native kernel path is active (ops/dispatch.py: on a
+    TPU)."""
+    from geomx_tpu.ops.dispatch import kernel_mode
+    return kernel_mode() == "native"
 
 
 def _dense(q, k, v, causal):

@@ -37,6 +37,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
+from geomx_tpu.ops.dispatch import kernel_mode
+
 # the kernels' tiles over the contracted and the output dimension, at most
 GMM_TILES = (1152, 768)
 TGMM_TILES = (768, 512)
@@ -151,7 +153,7 @@ def held_experts(x, idx, weights, gate, up, down, offset: int, rows: int,
 
 def _forward(x, idx, weights, gate, up, down, offset, rows, interpret):
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernel_mode() != "native"
     plan = _plan(idx, weights, gate.shape[0], offset, rows)
     gate_up, down = _cast(x, gate, up, down)
 
@@ -181,7 +183,7 @@ def _fwd(x, idx, weights, gate, up, down, offset, rows, interpret):
 def _bwd(offset, rows, interpret, res, cotangents):
     x, idx, weights, gate, up, down, plan = res
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernel_mode() != "native"
     dy = cotangents[0].astype(x.dtype)
     gate_up, down_c = _cast(x, gate, up, down)
 

@@ -38,9 +38,8 @@ def sample_positions(n: int, sample: int = 8192) -> np.ndarray:
 
 def boundary_position(m: int, k, n: int):
     """Index into the sorted ``m``-element probe for the (1 - k/n)
-    quantile.  A Python-int ``k`` resolves statically (the jaxpr stays
-    byte-identical to the historical static path — the telemetry-style
-    identity guarantee GEOMX_CONTROL=0 pins); a TRACED ``k`` (the Graft
+    quantile.  A Python-int ``k`` resolves statically (what
+    GEOMX_CONTROL=0 traces); a TRACED ``k`` (the Graft
     Pilot's no-recompile ratio operand, control/actuators.py) returns a
     traced position the gather below consumes without a shape change."""
     if isinstance(k, (int, np.integer)):
@@ -49,33 +48,15 @@ def boundary_position(m: int, k, n: int):
     return jnp.clip(pos, 0, m - 1).astype(jnp.int32)
 
 
-def sampled_boundary(absv: jax.Array, k, sample: int = 8192):
-    """The sampled magnitude boundary: the (1 - k/n) quantile of a
-    sorted ~``sample``-element probe of ``absv``.  Shared by the jnp
-    reference scan below and the fused Pallas kernel
-    (ops/bsc_pallas.bsc_select_pack), so both paths select against the
-    bit-identical threshold.  ``k`` may be a traced scalar (see
-    :func:`boundary_position`); the probe positions and output shape
-    never depend on it."""
-    n = absv.shape[0]
-    m = min(n, int(sample))
-    samp = absv[jnp.asarray(sample_positions(n, sample), jnp.int32)]
-    ssorted = jnp.sort(samp)
-    return ssorted[boundary_position(m, k, n)]
-
-
-def sampled_threshold_select(v: jax.Array, absv: jax.Array, k: int,
-                             sample: int = 8192, thr=None):
-    """Select ~top-k of ``absv`` by a sampled magnitude boundary.
+def sampled_threshold_select(v: jax.Array, absv: jax.Array, k: int, thr):
+    """Select ~top-k of ``absv`` against the magnitude boundary ``thr``
+    (``bsc_pallas.sampled_boundary_guv``).
 
     Returns (vals[k], idx[k] int32 with -1 sentinels, keep[n] bool —
     the dense mask of emitted coordinates, for error-feedback resets).
-    ``thr`` overrides the boundary (callers that already computed it).
     """
     n = absv.shape[0]
     k = int(k)
-    if thr is None:
-        thr = sampled_boundary(absv, k, sample)
     # two-tier selection: strictly-above-boundary elements claim slots
     # FIRST, boundary-tied elements fill whatever remains.  A plain
     # inclusive mask starves real mass on sparse gradients (thr == 0 ->

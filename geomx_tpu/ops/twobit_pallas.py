@@ -1,8 +1,11 @@
-"""Fused 2-bit quantization Pallas kernels.
+"""Fused 2-bit quantization Pallas kernels, and their jnp form.
 
-Semantics identical to compression/twobit.py's jnp path (which mirrors the
-reference Quantize2BitImpl): codes 0/1/2 = {0, +threshold, -threshold},
-residual error feedback, 16 codes packed per int32 word.
+Semantics of the reference Quantize2BitImpl: codes 0/1/2 = {0,
++threshold, -threshold}, residual error feedback, 16 codes packed per
+int32 word.  The jnp form below (``quantize_2bit_ref`` /
+``dequantize_2bit_ref``, the only path off a TPU) packs 16 consecutive
+elements a word; the kernels pack by the lane, as follows.  Both are
+self-inverse and dequantize to identical values.
 
 Layout: gradients are processed as [rows, 2048] fp32 blocks; within a
 block, word (row, lane) packs the 16 elements {row*2048 + lane + 128*j}
@@ -27,10 +30,6 @@ _BLOCK_COLS = _PACK * _LANES  # 2048 fp32 elements -> 128 packed int32
 # the 16 MB scoped-vmem limit that a gridless call blows through at
 # multi-million-element inputs (observed on v5e at 4M elements).
 _BLOCK_ROWS = 256
-
-
-def pallas_supported() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 def _kernel(thr, g_ref, r_ref, packed_ref, newr_ref):
@@ -131,3 +130,52 @@ def dequantize_2bit(packed: jax.Array, n: int, threshold: float,
         interpret=interpret,
     )(p2)
     return out.reshape(-1)[:n]
+
+
+# -- the jnp form -----------------------------------------------------------
+
+def pack2bit(codes: jax.Array) -> jax.Array:
+    """Pack int codes in {0,1,2} ({zero, +thr, -thr}) into int32 words,
+    16 consecutive codes a word."""
+    pad = (-codes.shape[0]) % _PACK
+    if pad:
+        codes = jnp.concatenate([codes, jnp.zeros((pad,), codes.dtype)])
+    codes = codes.reshape(-1, _PACK).astype(jnp.int32)
+    shifts = jnp.arange(_PACK, dtype=jnp.int32) * 2
+    return jnp.sum(codes << shifts[None, :], axis=1, dtype=jnp.int32)
+
+
+def unpack2bit(words: jax.Array, n: int) -> jax.Array:
+    """Inverse of pack2bit; returns int32 codes of length n."""
+    shifts = jnp.arange(_PACK, dtype=jnp.int32) * 2
+    codes = (words[:, None] >> shifts[None, :]) & 3
+    return codes.reshape(-1)[:n]
+
+
+def _codes_to_values(codes: jax.Array, threshold: float) -> jax.Array:
+    return jnp.where(codes == 1, threshold,
+                     jnp.where(codes == 2, -threshold, 0.0)).astype(jnp.float32)
+
+
+def quantize_2bit_ref(g: jax.Array, residual: jax.Array, threshold: float):
+    """Returns (packed int32 [ceil(n/16)], new residual [n])."""
+    r = residual.reshape(-1).astype(jnp.float32) \
+        + g.reshape(-1).astype(jnp.float32)
+    codes = jnp.where(r >= threshold, 1,
+                      jnp.where(r <= -threshold, 2, 0)).astype(jnp.int32)
+    return pack2bit(codes), r - _codes_to_values(codes, threshold)
+
+
+def dequantize_2bit_ref(words: jax.Array, n: int, threshold: float):
+    return _codes_to_values(unpack2bit(words, n), threshold)
+
+
+def words_ref(n: int) -> int:
+    """Words :func:`quantize_2bit_ref` emits for ``n`` elements."""
+    return -(-n // _PACK)
+
+
+def words_kernel(n: int) -> int:
+    """Words :func:`quantize_2bit` emits: 128 a 2048-element row, so a
+    small leaf pads up to one row (the same n/16 asymptote)."""
+    return _LANES * (-(-n // _BLOCK_COLS))

@@ -62,9 +62,8 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``use_fused``: compute each hop with the fused Pallas flash block
     (`parallel/_fused_block.py`) instead of the jnp streaming block —
     same math, but the per-hop [Lq, Lk] score matrix never reaches HBM.
-    Default: on TPU when the local length tiles (GEOMX_FLASH_ATTN=0
-    disables); ``_interpret=True`` runs the kernel in Pallas interpret
-    mode (CPU equivalence tests).
+    Default: on TPU when the local length tiles; ``_interpret=True``
+    runs the kernel in Pallas interpret mode (CPU equivalence tests).
     """
     n = lax.psum(1, axis_name)
     idx = lax.axis_index(axis_name)

@@ -93,8 +93,8 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     ``use_fused``: run the on-device attention with the fused Pallas
     flash kernels via `ops.fused_attention` (default: on TPU with a
-    lane-aligned head dim; GEOMX_FLASH_ATTN=0 disables).  Flash in
-    BOTH directions: the backward recomputes p per tile from the
+    lane-aligned head dim).  Flash in BOTH directions: the backward
+    recomputes p per tile from the
     forward's logsumexp (`ops.flash_attention_bwd`), so the [L, L]
     scores never exist in HBM — unlike autodiff of the streaming jnp
     path, whose scan residuals total O(L^2).

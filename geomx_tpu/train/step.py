@@ -310,17 +310,18 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
                     "engine (GEOMX_BUCKET_BYTES > 0): the kernels apply "
                     "the update over the flat fp32 buckets")
             fopt_bucketer = dc.zero_bucketer
-        # interpret mode off-TPU (CI, CPU meshes) — same resolution as
-        # the compression kernels' pallas_supported path.
-        # GEOMX_FUSED_OPTIM_INTERPRET overrides (=0 forces the native
+        # interpret mode off-TPU (CI, CPU meshes): ops/dispatch.py's
+        # answer, as for every other kernel.
+        # GEOMX_FUSED_OPTIM_INTERPRET overrides it (=0 forces the native
         # Mosaic lowering: bench --compare-mfu uses it to cross-lower
         # the step for the DCE structure gate on a CPU host — such a
         # build LOWERS anywhere but only RUNS on TPU)
         import os as _os
+        from geomx_tpu.ops.dispatch import kernel_mode
         # graftlint: disable=GXL006 — build-time gate
         _ov = _os.environ.get("GEOMX_FUSED_OPTIM_INTERPRET")
         if _ov is None:
-            fopt_interp = jax.default_backend() != "tpu"
+            fopt_interp = kernel_mode() != "native"
         else:
             fopt_interp = _ov.strip().lower() not in ("0", "false", "")
         if zplan is not None:

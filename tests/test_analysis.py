@@ -433,8 +433,7 @@ def test_purity_post_collective_counts_only_after_last_collective():
     from geomx_tpu.compression.bisparse import BiSparseCompressor
 
     comp = BucketedCompressor(
-        BiSparseCompressor(ratio=0.05, select="exact", min_sparse_size=1,
-                           fused=False, sparse_agg=False),
+        BiSparseCompressor(ratio=0.05, min_sparse_size=1, sparse_agg=False),
         bucket_bytes=16 * 1024)
     params = [jnp.zeros((4000,), jnp.float32),
               jnp.zeros((3800,), jnp.float32)]

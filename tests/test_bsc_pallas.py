@@ -12,8 +12,9 @@ Three layers of evidence, all on CPU:
   surfaces in CI, not on chip.
 - *Structure*: the lowered-HLO op counts show the unfused chain's dense
   intermediates (scatter, cumsum expansion, per-leaf copies) are GONE
-  from the fused path — the regression bench.py --compare-kernels
-  reports.
+  from the fused path.
+- *The door*: the engine gets its kernels through ops/dispatch.py, from
+  the platform alone: no environment name, spec key or argument selects.
 """
 
 import jax
@@ -25,15 +26,32 @@ from geomx_tpu.compression import BiSparseCompressor
 from geomx_tpu.compression.bucketing import GradientBucketer
 from geomx_tpu.ops.bsc_pallas import (bsc_scatter_add, bsc_select_pack,
                                       sampled_boundary_guv)
+from geomx_tpu.ops.dispatch import kernels
+
+
+class _Under:
+    """``obj`` with every method traced under ``kernels(mode)``: what a
+    TPU ("native") or a parity test ("interpret") gets through
+    ops/dispatch.py where the CPU default gets the jnp forms."""
+
+    def __init__(self, obj, mode):
+        self._obj, self._mode = obj, mode
+
+    def __getattr__(self, name):
+        attr = getattr(self._obj, name)
+        if not callable(attr):
+            return attr
+
+        def call(*args, **kwargs):
+            with kernels(self._mode):
+                return attr(*args, **kwargs)
+        return call
 
 
 def _pair(ratio=0.01, **kw):
-    """(jnp-reference, fused-interpret) compressors with identical
-    semantics knobs."""
-    base = dict(ratio=ratio, select="sampled", min_sparse_size=1)
-    base.update(kw)
-    return (BiSparseCompressor(fused=False, **base),
-            BiSparseCompressor(fused=True, fused_interpret=True, **base))
+    """(jnp-reference, fused-interpret) views of one compressor."""
+    c = BiSparseCompressor(ratio=ratio, min_sparse_size=1, **kw)
+    return c, _Under(c, "interpret")
 
 
 def _compress_pair(cj, cf, g, u, v):
@@ -134,19 +152,11 @@ def test_select_pack_mixed_primary_and_ties(rng):
 
 def _direct_pair(g, u, v, thr, k):
     """(jnp chain, fused-interpret) at a boundary the caller gives."""
-    from geomx_tpu.ops.bsc_pallas import MOMENTUM
-    from geomx_tpu.ops.sampled_topk import sampled_threshold_select
-
-    @jax.jit
-    def chain(g, u, v, thr):
-        u2 = u * MOMENTUM + g
-        v2 = v + u2
-        vals, idx, keep = sampled_threshold_select(v2, jnp.abs(v2), k, thr=thr)
-        return vals, idx, jnp.where(keep, 0.0, u2), jnp.where(keep, 0.0, v2)
+    from geomx_tpu.ops.bsc_pallas import select_pack_ref
 
     thr = jnp.float32(thr)
-    return chain(g, u, v, thr), bsc_select_pack(g, u, v, thr, k,
-                                                interpret=True)
+    return (jax.jit(select_pack_ref, static_argnums=4)(g, u, v, thr, k),
+            bsc_select_pack(g, u, v, thr, k, interpret=True))
 
 
 def _schedule_cases():
@@ -272,9 +282,9 @@ def test_placement_visits_are_tiles_plus_blocks(name, p_cnt, s_cnt, k):
 
 
 def test_select_pack_threshold_probe_matches_reference(rng):
-    """sampled_boundary_guv (gathers only) must equal the jnp path's
-    boundary from the dense momentum-corrected tensor."""
-    from geomx_tpu.ops.sampled_topk import sampled_boundary
+    """sampled_boundary_guv (gathers only) must equal the quantile of the
+    dense momentum-corrected tensor at the same probe positions."""
+    from geomx_tpu.ops.sampled_topk import boundary_position, sample_positions
 
     n, k = 30000, 300
     g = jnp.asarray(rng.normal(0, 1, n).astype(np.float32))
@@ -285,7 +295,9 @@ def test_select_pack_threshold_probe_matches_reference(rng):
     def both(g, u, v):
         u2 = u * 0.9 + g
         v2 = v + u2
-        return (sampled_boundary(jnp.abs(v2), k),
+        pos = sample_positions(n)
+        probe = jnp.sort(jnp.abs(v2)[jnp.asarray(pos, jnp.int32)])
+        return (probe[boundary_position(len(pos), k, n)],
                 sampled_boundary_guv(g, u, v, k))
 
     dense, gathered = both(g, u, v)
@@ -503,12 +515,10 @@ def test_fused_bsc_allreduce_matches_jnp_path(topo2x4, mesh2x4):
     rng = np.random.RandomState(11)
     g = rng.normal(0, 0.8, size=(2, 8192)).astype(np.float32)
     out_j, st_j = _run_dc_allreduce(
-        BiSparseCompressor(0.01, select="sampled", min_sparse_size=1,
-                           fused=False), g, topo2x4, mesh2x4)
-    out_f, st_f = _run_dc_allreduce(
-        BiSparseCompressor(0.01, select="sampled", min_sparse_size=1,
-                           fused=True, fused_interpret=True),
-        g, topo2x4, mesh2x4)
+        BiSparseCompressor(0.01, min_sparse_size=1), g, topo2x4, mesh2x4)
+    with kernels("interpret"):
+        out_f, st_f = _run_dc_allreduce(
+            BiSparseCompressor(0.01, min_sparse_size=1), g, topo2x4, mesh2x4)
     np.testing.assert_allclose(out_f, out_j, atol=1e-6)
     for a, b in zip(jax.tree.leaves(st_j), jax.tree.leaves(st_f)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-6)
@@ -522,9 +532,8 @@ def test_fused_bucket_flatten_roundtrip_parity(rng):
               [((16, 8), jnp.float32), ((5,), jnp.float32),
                ((300,), jnp.float32), ((7, 3, 2), jnp.bfloat16),
                ((1000,), jnp.float32), ((1,), jnp.float32)]]
-    bj = GradientBucketer(leaves, bucket_bytes=2048, fused=False)
-    bf = GradientBucketer(leaves, bucket_bytes=2048, fused=True,
-                          fused_interpret=True)
+    bj = GradientBucketer(leaves, bucket_bytes=2048)
+    bf = _Under(bj, "interpret")
     fb, jb = bf.flatten(leaves), bj.flatten(leaves)
     assert len(fb) == len(jb) == bj.num_buckets
     for a, b in zip(fb, jb):
@@ -541,10 +550,8 @@ def test_fused_flatten_wide_pad_to(rng):
     largest tail)."""
     leaves = [jnp.asarray(rng.normal(0, 1, s).astype(np.float32))
               for s in (700, 3, 129)]
-    bj = GradientBucketer(leaves, bucket_bytes=1 << 20, pad_to=512,
-                          fused=False)
-    bf = GradientBucketer(leaves, bucket_bytes=1 << 20, pad_to=512,
-                          fused=True, fused_interpret=True)
+    bj = GradientBucketer(leaves, bucket_bytes=1 << 20, pad_to=512)
+    bf = _Under(bj, "interpret")
     for a, b in zip(bf.flatten(leaves), bj.flatten(leaves)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -560,11 +567,10 @@ def test_fused_bucketed_compressor_matches_jnp(topo2x4, mesh2x4):
     rng = np.random.RandomState(5)
     g = rng.normal(0, 1, size=(2, 3000)).astype(np.float32)
     out_j, _ = _run_dc_allreduce(
-        BucketedCompressor(NoCompressor(), 4096, fused=False),
-        g, topo2x4, mesh2x4)
-    out_f, _ = _run_dc_allreduce(
-        BucketedCompressor(NoCompressor(), 4096, fused=True,
-                           fused_interpret=True), g, topo2x4, mesh2x4)
+        BucketedCompressor(NoCompressor(), 4096), g, topo2x4, mesh2x4)
+    with kernels("interpret"):
+        out_f, _ = _run_dc_allreduce(
+            BucketedCompressor(NoCompressor(), 4096), g, topo2x4, mesh2x4)
     np.testing.assert_array_equal(out_f, out_j)
 
 
@@ -600,7 +606,7 @@ def test_bucket_kernels_lower_to_tpu_mosaic_without_a_device(rng):
 
     leaves = [jnp.asarray(rng.normal(0, 1, s).astype(np.float32))
               for s in (130, 5, 1000, 64)]
-    bk = GradientBucketer(leaves, bucket_bytes=4096, fused=False)
+    bk = GradientBucketer(leaves, bucket_bytes=4096)
     layout = tuple((b, off, size) for (b, off), size in
                    zip(bk.assignments, bk.leaf_sizes))
 
@@ -640,8 +646,7 @@ def _largest_before_the_kernel(fn, *args):
 
 def test_fused_paths_remove_dense_intermediates(rng):
     """The structural claim of the fused kernel layer, checked on the
-    shared lowered-HLO assertions library (geomx_tpu/analysis/hlo.py —
-    the same matchers bench.py --compare-kernels reports with): the ops
+    shared lowered-HLO assertions library (geomx_tpu/analysis/hlo.py): the ops
     that materialize a dense gradient-sized intermediate in the unfused
     graphs (scatter, cumsum expansion, per-leaf concatenate/slice
     copies) must be ABSENT from the fused graphs, which instead carry
@@ -653,8 +658,7 @@ def test_fused_paths_remove_dense_intermediates(rng):
     cj, _ = _pair(ratio=0.01)
     # NON-interpret fused compressor: the HLO must contain the real
     # custom call (interpret mode traces the kernel as while loops)
-    cf = BiSparseCompressor(ratio=0.01, select="sampled",
-                            min_sparse_size=1, fused=True)
+    cf = _Under(cj, "native")
     g = jnp.asarray(rng.normal(0, 1, n).astype(np.float32))
     z = jnp.zeros((n,), jnp.float32)
     m = 4 * cj.k_for(n)
@@ -688,60 +692,73 @@ def test_fused_paths_remove_dense_intermediates(rng):
     leaves = [jnp.asarray(rng.normal(0, 1, s).astype(np.float32))
               for s in (432, 16, 2304, 16, 9216, 64, 640, 10)]
     flat_v = compare_paths(
-        lambda *ls: GradientBucketer(
-            leaves, 65536, fused=False).flatten(list(ls)),
-        lambda *ls: GradientBucketer(
-            leaves, 65536, fused=True).flatten(list(ls)), *leaves,
+        lambda *ls: GradientBucketer(leaves, 65536).flatten(list(ls)),
+        lambda *ls: _Under(GradientBucketer(leaves, 65536),
+                           "native").flatten(list(ls)), *leaves,
         dense_ops=("concatenate", "dynamic_update_slice"))
     assert_dense_intermediates_removed(flat_v)
     assert flat_v["fused"]["tpu_custom_calls"] == 1
 
 
-def test_compare_kernels_emits_on_cpu():
-    """The bench micro-mode's contract on a CPU host: one JSON line,
-    "fused": false, jnp timings present, and every HLO verdict shows
-    the dense intermediates removed."""
-    import bench
+# ---------- the door (ops/dispatch.py) ----------
 
-    out = bench._compare_kernels(sizes=(8192,), ratio=0.01, parties=2)
-    assert out["mode"] == "compare_kernels"
-    assert out["fused"] is False
-    rec = out["sizes"]["8192"]
-    assert rec["select_jnp_ms"] > 0 and rec["decompress_jnp_ms"] > 0
-    assert "select_fused_ms" not in rec  # no TPU: jnp path only
-    assert rec["select_hlo"]["dense_intermediates_removed"]
-    assert rec["decompress_hlo"]["dense_intermediates_removed"]
-    assert out["bucket"]["flatten_hlo"]["dense_intermediates_removed"]
-    assert out["bucket"]["unflatten_hlo"]["fused"]["tpu_custom_calls"] == 1
+def _bucket_allreduce_jaxpr(spec="bsc,0.01"):
+    """The jaxpr of a default ``spec`` bucket allreduce as a cell builds
+    it (spec string -> get_compressor -> bucketed)."""
+    from geomx_tpu.compression import get_compressor
+    from geomx_tpu.compression.bucketing import maybe_bucketed
+
+    comp = maybe_bucketed(get_compressor(spec))
+    grads = [jnp.zeros((300, 40)), jnp.zeros((77,)), jnp.zeros((40000,))]
+    state = comp.init_state(grads)
+    return str(jax.make_jaxpr(
+        lambda g, s: comp.allreduce(g, s, "dc", 1))(grads, state))
 
 
-# ---------- gating ----------
-
-def test_fused_gating_defaults_and_select_interaction(monkeypatch):
-    """On CPU the default is the jnp path; GEOMX_FUSED_KERNELS=0 is a
-    hard opt-out; an explicit fused=True applies the select kernel only
-    to the sampled scan (exact/approx keep their lax.top_k forms) while
-    the decompress kernel applies everywhere."""
-    from geomx_tpu.ops.bsc_pallas import fused_kernels_enabled
-
-    assert fused_kernels_enabled() is False  # CPU backend
-    c = BiSparseCompressor(0.01)
-    assert c.fused is False and c.select in ("exact", "approx")
-
-    cf = BiSparseCompressor(0.01, select="exact", fused=True)
-    assert cf.fused and not cf.fused_select
-    cs = BiSparseCompressor(0.01, select="sampled", fused=True)
-    assert cs.fused and cs.fused_select
-
-    monkeypatch.setenv("GEOMX_FUSED_KERNELS", "0")
-    assert fused_kernels_enabled() is False
+@pytest.mark.parametrize("name,value", [
+    ("GEOMX_BSC_SELECT", "exact"), ("GEOMX_BSC_APPROX_TOPK", "0"),
+    ("GEOMX_FUSED_KERNELS", "0"), ("GEOMX_TWOBIT_PALLAS", "0"),
+    ("GEOMX_FLASH_ATTN", "0")])
+def test_the_engine_reads_no_environment(monkeypatch, name, value):
+    """The five names that used to choose a code path choose nothing."""
+    want = _bucket_allreduce_jaxpr()
+    monkeypatch.setenv(name, value)
+    assert _bucket_allreduce_jaxpr() == want
+    assert "top_k" not in want and "approx_top_k" not in want
 
 
-def test_bsc_spec_accepts_fused_key():
+@pytest.mark.parametrize("sizes", [
+    pytest.param([(50, 100), (2000,), (40,)], id="one-tile"),
+    pytest.param([(300, 300), (77,), (40000,)], id="several-tiles"),
+    pytest.param([(20, 20), (77,)], id="under-min_sparse_size"),
+])
+def test_the_door_gives_the_kernels_the_jnp_forms_results(rng, sizes):
+    """A cell's bucket allreduce on the CPU default (jnp forms) and under
+    the interpret hook (the kernels a TPU runs): outputs and (u, v) bit
+    for bit, twice over so the error feedback is exercised."""
+    from geomx_tpu.compression.bucketing import BucketedCompressor
+
+    comp = BucketedCompressor(BiSparseCompressor(0.01))
+    grads = [jnp.asarray(rng.normal(0, 1, s).astype(np.float32))
+             for s in sizes]
+
+    def run():
+        fn = jax.jit(lambda g, s: comp.allreduce(g, s, "dc", 1))
+        out, state = fn(grads, comp.init_state(grads))
+        return fn(out, state)
+
+    want = run()
+    with kernels("interpret"):
+        got = run()
+    sparse = sum(int(np.prod(s)) for s in sizes) >= 1024
+    assert bool(jax.tree.leaves(want[1])) == sparse
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("key", ["select=sampled", "approx=1", "fused=1"])
+def test_the_spec_grammar_has_no_key_that_picks_an_implementation(key):
     from geomx_tpu.compression import get_compressor
 
-    c = get_compressor("bsc,0.02,select=sampled,fused=1")
-    assert isinstance(c, BiSparseCompressor)
-    assert c.fused and c.fused_select
-    with pytest.raises(ValueError):
-        get_compressor("bsc,0.02,fused=maybe")
+    with pytest.raises(ValueError, match="valid keys.*min_sparse_size"):
+        get_compressor(f"bsc,0.02,{key}")

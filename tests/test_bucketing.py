@@ -153,11 +153,11 @@ def test_bucketed_bsc_single_leaf_matches_per_leaf(rng):
     error-feedback state must round-trip exactly."""
     n = 1024
     g = jnp.asarray(rng.normal(size=(n,)).astype(np.float32))
-    c = BiSparseCompressor(ratio=0.05, min_sparse_size=1, select="exact")
+    c = BiSparseCompressor(ratio=0.05, min_sparse_size=1)
     out_pl, (u_pl, v_pl) = c.allreduce_leaf(g, c.init_leaf_state(g), "x", 1)
 
     bc = BucketedCompressor(
-        BiSparseCompressor(ratio=0.05, min_sparse_size=1, select="exact"),
+        BiSparseCompressor(ratio=0.05, min_sparse_size=1),
         bucket_bytes=n * 4)
     tree = {"w": g}
     out_b, st_b = bc.allreduce(tree, bc.init_state(tree), "x", 1)
@@ -176,7 +176,7 @@ def test_bucketed_bsc_global_selection_conserves_mass(rng):
     through the bucket layout (emitted + retained == pushed)."""
     tree = _tree(rng)
     bc = BucketedCompressor(
-        BiSparseCompressor(ratio=0.05, min_sparse_size=1, select="exact"),
+        BiSparseCompressor(ratio=0.05, min_sparse_size=1),
         bucket_bytes=1 << 20)
     out, st = bc.allreduce(tree, bc.init_state(tree), "x", 1)
     leaves, treedef = jax.tree.flatten(tree)

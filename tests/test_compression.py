@@ -11,7 +11,7 @@ from jax.sharding import PartitionSpec as P
 from geomx_tpu.compression import (BiSparseCompressor, FP16Compressor,
                                    MPQCompressor, NoCompressor,
                                    TwoBitCompressor, get_compressor)
-from geomx_tpu.compression.twobit import pack2bit, unpack2bit
+from geomx_tpu.ops.twobit_pallas import pack2bit, unpack2bit
 from geomx_tpu.parallel.collectives import shard_map_compat
 from geomx_tpu.topology import DC_AXIS, WORKER_AXIS
 
@@ -33,15 +33,15 @@ def test_get_compressor_specs():
 
 
 def test_get_compressor_keyword_args():
-    """"bsc,0.01" cannot express select=/min_sparse_size=; the key=value
-    extension can, mixing with positionals."""
-    c = get_compressor("bsc,0.01,select=sampled,min_sparse_size=2048")
+    """"bsc,0.01" cannot express min_sparse_size=/sparse_agg=; the
+    key=value extension can, mixing with positionals."""
+    c = get_compressor("bsc,0.01,sparse_agg=0,min_sparse_size=2048")
     assert isinstance(c, BiSparseCompressor)
     assert c.ratio == pytest.approx(0.01)
-    assert c.select == "sampled" and c.min_sparse_size == 2048
+    assert c.sparse_agg is False and c.min_sparse_size == 2048
     # pure-keyword form
-    c2 = get_compressor("bsc,ratio=0.05,select=exact")
-    assert c2.ratio == pytest.approx(0.05) and c2.select == "exact"
+    c2 = get_compressor("bsc,ratio=0.05,sparse_agg=1")
+    assert c2.ratio == pytest.approx(0.05) and c2.sparse_agg is True
     import jax.numpy as jnp
     assert get_compressor("fp16,bf16=1").wire_dtype == jnp.bfloat16
     m = get_compressor("mpq,ratio=0.02,size_lower_bound=5000")
@@ -57,7 +57,7 @@ def test_get_compressor_rejects_bad_keyword_specs():
     with pytest.raises(ValueError, match="valid keys"):
         get_compressor("fp16,ratio=0.5")
     with pytest.raises(ValueError, match="after keyword"):
-        get_compressor("bsc,select=exact,0.01")
+        get_compressor("bsc,sparse_agg=0,0.01")
     with pytest.raises(ValueError, match="Duplicate"):
         get_compressor("bsc,0.01,ratio=0.02")
     with pytest.raises(ValueError, match="Too many positional"):
@@ -280,7 +280,7 @@ def test_dgt_wire_bytes_amortizes_drain_rounds():
 
 
 def test_bsc_sampled_boundary_selection():
-    """select="sampled" reproduces the reference's own BSCompress
+    """Bi-Sparse reproduces the reference's own BSCompress
     algorithm (sampled magnitude boundary + one zipping scan with
     sentinel padding, gc.cc:219-259): fixed k slots, exact error-feedback
     mass conservation, and near-top-k selected mass on heavy-tailed
@@ -288,7 +288,7 @@ def test_bsc_sampled_boundary_selection():
     import jax.numpy as jnp
 
     n, ratio = 64 * 1024, 0.01
-    c = BiSparseCompressor(ratio=ratio, min_sparse_size=1, select="sampled")
+    c = BiSparseCompressor(ratio=ratio, min_sparse_size=1)
     rng = np.random.RandomState(0)
     g = (rng.randn(n) ** 3).astype(np.float32)  # heavy-tailed
     u0 = jnp.zeros((n,), jnp.float32)
@@ -319,7 +319,7 @@ def test_bsc_sampled_mode_trains_through_allreduce():
     sentinel indices (the decompress drops them)."""
     import jax.numpy as jnp
 
-    c = BiSparseCompressor(ratio=0.05, min_sparse_size=1, select="sampled")
+    c = BiSparseCompressor(ratio=0.05, min_sparse_size=1)
     n = 4096
     g = jnp.asarray(np.random.RandomState(1).randn(n), np.float32)
     state = c.init_leaf_state(g)
@@ -338,7 +338,7 @@ def test_bsc_sampled_handles_sparse_gradients():
     import jax.numpy as jnp
 
     n = 64 * 1024
-    c = BiSparseCompressor(ratio=0.01, min_sparse_size=1, select="sampled")
+    c = BiSparseCompressor(ratio=0.01, min_sparse_size=1)
     g = np.zeros(n, np.float32)
     g[-100:] = 100.0  # all mass at the tail, invisible to naive ties
     vals, idx, _, v2 = c.compress(jnp.asarray(g), jnp.zeros((n,)),

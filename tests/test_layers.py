@@ -11,6 +11,7 @@ import optax
 import pytest
 
 from geomx_tpu.compression.bisparse import BiSparseCompressor
+from geomx_tpu.ops.dispatch import kernels
 from geomx_tpu.config import GeoConfig
 from geomx_tpu.data.datasets import load_dataset
 from geomx_tpu.data.loader import GeoDataLoader
@@ -33,11 +34,7 @@ def _trainer(compression: str, parties=2, workers=2, **config):
     topo = HiPSTopology(num_parties=parties, workers_per_party=workers)
     cfg = GeoConfig(num_parties=parties, workers_per_party=workers,
                     **config)
-    dc = None
-    if compression == "bsc":
-        # the chip's path (sampled boundary, fused select/pack and
-        # scatter-add) in Pallas interpret mode
-        dc = BiSparseCompressor(0.01, fused=True, fused_interpret=True)
+    dc = BiSparseCompressor(0.01) if compression == "bsc" else None
     return Trainer(GeoCNN(num_classes=10), topo, optax.adam(1e-3),
                    sync=FSA(dc_compressor=dc, bucket_bytes=64 * 1024),
                    config=cfg)
@@ -59,13 +56,16 @@ def test_step_hlo_holds_the_scopes_and_the_table_reads_them(compression,
     trainer = _trainer(compression)
     state = trainer.init_state(jax.random.PRNGKey(0), data["train_x"][:2])
     xb, yb = _first_batch(trainer, data)
-    lowered = trainer.train_step.lower(state, xb, yb).as_text(
-        debug_info=True)
+    # the chip's path (fused select/pack, scatter-add and bucket copies)
+    # in Pallas interpret mode
+    with kernels("interpret"):
+        lowered = trainer.train_step.lower(state, xb, yb).as_text(
+            debug_info=True)
+        table = trainer.step_layers(state, xb, yb)
     expected = STEP_SCOPES + (BSC_SCOPES if compression == "bsc" else ())
     missing = [s for s in expected if s + "/" not in lowered]
     assert not missing, missing
 
-    table = trainer.step_layers(state, xb, yb)
     ops = table["ops"].values()
     assert table["instructions"] == len(table["ops"]) > 50
     directions = {v.direction for v in ops}
@@ -207,6 +207,16 @@ class _Leave(Exception):
     pass
 
 
+def _phases_fill_the_wall(stats):
+    """`wall_s` closes with the last phase, so what it holds beyond the
+    phases is the loop's own statements between them: milliseconds,
+    whatever the window's length and the machine's load, and less than
+    one of the loader's sleeps, so a wait that no phase took shows."""
+    phased = sum(p["total_s"] for p in stats.phases.values())
+    assert phased <= stats.wall_s
+    assert stats.wall_s - phased < 0.04
+
+
 def test_loop_stats_hold_the_loaders_sleeps_and_survive_log_fn(data):
     # prefetch 0: the loader assembles in the loop's own thread, so each
     # sleep is a wait of fit/next_batch
@@ -228,8 +238,7 @@ def test_loop_stats_hold_the_loaders_sleeps_and_survive_log_fn(data):
     assert phases["fit/dispatch"]["count"] == 16
     assert phases["fit/log_fn"]["count"] == 4
     assert phases["fit/eval"]["count"] == 0
-    assert sum(p["total_s"] for p in phases.values()) == pytest.approx(
-        stats.wall_s, rel=0.02)
+    _phases_fill_the_wall(stats)
     waits = phases["fit/next_batch"]
     assert 4 * 0.05 <= waits["total_s"] < 4 * 0.05 + 0.1
     assert waits["max_s"] >= 0.05 and waits["max_step"] % 4 == 3
@@ -250,8 +259,7 @@ def test_loop_stats_hold_the_loaders_sleeps_and_survive_log_fn(data):
     assert left is trainer.loop_stats and left is not stats
     assert left.steps == 8 and left.phases["fit/log_fn"]["count"] == 2
     assert left.phases["fit/next_batch"]["count"] == 8
-    assert sum(p["total_s"] for p in left.phases.values()) == pytest.approx(
-        left.wall_s, rel=0.02)
+    _phases_fill_the_wall(left)
     assert left.as_dict()["phases"]["fit/dispatch"]["count"] == 8
 
 

@@ -1,5 +1,5 @@
 """Pallas kernel tests (interpret mode on CPU) — parity with the jnp
-reference implementations in compression/twobit.py."""
+reference implementations beside them in ops/."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -48,22 +48,33 @@ def test_pallas_compressor_matches_jnp_path(topo2x4, mesh2x4):
 
     rng = np.random.RandomState(7)
     g = rng.normal(0, 0.8, size=(2, 4096)).astype(np.float32)
+    from geomx_tpu.ops.dispatch import kernels
+
     out_j, _ = _run_dc_allreduce(TwoBitCompressor(0.5), g, topo2x4, mesh2x4)
-    out_p, _ = _run_dc_allreduce(
-        TwoBitCompressor(0.5, use_pallas=True, pallas_interpret=True),
-        g, topo2x4, mesh2x4)
+    with kernels("interpret"):
+        out_p, _ = _run_dc_allreduce(TwoBitCompressor(0.5), g, topo2x4,
+                                     mesh2x4)
     np.testing.assert_allclose(out_p, out_j, atol=1e-6)
 
 
 # ---------- sampled_topk padding-sentinel semantics ----------
 
+def sampled_select(v, k):
+    """The scan against the boundary of |v| itself (u = g = 0)."""
+    from geomx_tpu.ops.bsc_pallas import sampled_boundary_guv
+    from geomx_tpu.ops.sampled_topk import sampled_threshold_select
+
+    zero = jnp.zeros_like(v)
+    return sampled_threshold_select(
+        v, jnp.abs(v), k, sampled_boundary_guv(zero, zero, v, k))
+
+
 def test_sampled_select_all_zero_input_emits_k_slots():
     from geomx_tpu.compression import BiSparseCompressor
-    from geomx_tpu.ops.sampled_topk import sampled_threshold_select
 
     n, k = 4096, 40
     v = jnp.zeros((n,), jnp.float32)
-    vals, idx, keep = sampled_threshold_select(v, jnp.abs(v), k)
+    vals, idx, keep = sampled_select(v, k)
     # exactly k wire slots, regardless of input content
     assert vals.shape == (k,) and idx.shape == (k,)
     # zero boundary ties everything; the fixed buffer fills with k
@@ -76,11 +87,9 @@ def test_sampled_select_all_zero_input_emits_k_slots():
 
 
 def test_sampled_select_ties_fill_exactly_k():
-    from geomx_tpu.ops.sampled_topk import sampled_threshold_select
-
     n, k = 2048, 32
     v = jnp.full((n,), -0.75, jnp.float32)  # every element tied at |thr|
-    vals, idx, keep = sampled_threshold_select(v, jnp.abs(v), k)
+    vals, idx, keep = sampled_select(v, k)
     assert vals.shape == (k,) and idx.shape == (k,)
     valid = np.asarray(idx) >= 0
     assert valid.sum() == k  # ties fill the buffer, never overflow it
@@ -92,13 +101,12 @@ def test_sampled_select_ties_fill_exactly_k():
 
 def test_sampled_select_n_smaller_than_k_pads_with_sentinels():
     from geomx_tpu.compression import BiSparseCompressor
-    from geomx_tpu.ops.sampled_topk import sampled_threshold_select
 
     n, k = 10, 32
     rng = np.random.RandomState(3)
     g = rng.randn(n).astype(np.float32)
     v = jnp.asarray(g)
-    vals, idx, keep = sampled_threshold_select(v, jnp.abs(v), k)
+    vals, idx, keep = sampled_select(v, k)
     # still exactly k wire slots: n real coordinates + (k - n) sentinels
     assert vals.shape == (k,) and idx.shape == (k,)
     idx_np = np.asarray(idx)
@@ -119,7 +127,7 @@ def test_bsc_sampled_compress_drops_sentinels_through_decompress():
     from geomx_tpu.compression import BiSparseCompressor
 
     n = 8192
-    c = BiSparseCompressor(ratio=0.01, min_sparse_size=1, select="sampled")
+    c = BiSparseCompressor(ratio=0.01, min_sparse_size=1)
     g = np.zeros(n, np.float32)
     g[7] = 3.0
     g[4096] = -2.0  # only 2 nonzeros; k = 82 slots mostly padding-bound

@@ -205,10 +205,11 @@ def test_bsc_sparse_agg_parity_and_consistency(rng):
         z = jnp.zeros((P_, n), jnp.float32)
         return [np.asarray(a) for a in jax.jit(fn)(g, z, z)]
 
-    base = dict(ratio=0.01, select="sampled", min_sparse_size=1,
-                sparse_agg=True)
-    oj = run(BiSparseCompressor(fused=False, **base))
-    of = run(BiSparseCompressor(fused=True, fused_interpret=True, **base))
+    from geomx_tpu.ops.dispatch import kernels
+    base = dict(ratio=0.01, min_sparse_size=1, sparse_agg=True)
+    oj = run(BiSparseCompressor(**base))
+    with kernels("interpret"):
+        of = run(BiSparseCompressor(**base))
     for name, a, b in zip(("out", "u", "v"), oj, of):
         np.testing.assert_array_equal(a, b, err_msg=name)
     out = oj[0]
@@ -237,15 +238,13 @@ def test_bsc_default_off_keeps_gather_path():
         jx = jax.make_jaxpr(fn)(z, z, z)
         return [s.primitive for s in walk_jaxpr(jx)]
 
-    legacy = BiSparseCompressor(ratio=0.01, select="exact",
-                                min_sparse_size=1, fused=False,
+    legacy = BiSparseCompressor(ratio=0.01, min_sparse_size=1,
                                 sparse_agg=False)
     prims = trace(legacy)
     assert "all_gather" in prims and "all_to_all" not in prims
     leaf = jnp.zeros((n,), jnp.float32)
     assert legacy.wire_bytes_leaf(leaf) == 2 * legacy.k_for(n) * 4
-    routed = BiSparseCompressor(ratio=0.01, select="exact",
-                                min_sparse_size=1, fused=False,
+    routed = BiSparseCompressor(ratio=0.01, min_sparse_size=1,
                                 sparse_agg=True)
     prims2 = trace(routed)
     assert "all_to_all" in prims2
@@ -265,8 +264,7 @@ def test_dense_fallback_counter_and_reason():
         ).value
 
     before = total()
-    comp = BiSparseCompressor(ratio=0.1, min_sparse_size=1 << 20,
-                              select="exact", fused=False)
+    comp = BiSparseCompressor(ratio=0.1, min_sparse_size=1 << 20)
     jax.make_jaxpr(lambda g: comp.allreduce_leaf(
         g, (), DC_AXIS, 1)[0])(jnp.zeros((128,), jnp.float32))
     assert total() == before + 1
@@ -290,10 +288,8 @@ def test_twobit_lattice_matches_legacy_exactly(rng):
         return [np.asarray(a) for a in
                 jax.jit(fn)(g, jnp.zeros((P_, n), jnp.float32))]
 
-    legacy = run(TwoBitCompressor(0.5, use_pallas=False,
-                                  sparse_agg=False))
-    lattice = run(TwoBitCompressor(0.5, use_pallas=False,
-                                   sparse_agg=True))
+    legacy = run(TwoBitCompressor(0.5, sparse_agg=False))
+    lattice = run(TwoBitCompressor(0.5, sparse_agg=True))
     # the ±threshold grid sums exactly in both forms: identical bits
     np.testing.assert_array_equal(legacy[0], lattice[0])
     np.testing.assert_array_equal(legacy[1], lattice[1])
@@ -321,8 +317,8 @@ def test_fp16_lattice_shared_scale_accuracy(rng):
 def test_lattice_wire_bytes_honest():
     leaf = jnp.zeros((4096,), jnp.float32)
     assert FP16Compressor(sparse_agg=True).wire_bytes_leaf(leaf) == 8192
-    assert TwoBitCompressor(0.5, use_pallas=False,
-                            sparse_agg=True).wire_bytes_leaf(leaf) == 4096
+    assert TwoBitCompressor(0.5, sparse_agg=True).wire_bytes_leaf(
+        leaf) == 4096
 
 
 # ---------- host-plane merge ----------

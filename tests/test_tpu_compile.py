@@ -82,7 +82,7 @@ def _bucket_case(direction, leaves_fn, bucket_bytes):
     from geomx_tpu.compression.bucketing import GradientBucketer
     from geomx_tpu.ops import fused_flatten, fused_unflatten
     leaves = leaves_fn()
-    bk = GradientBucketer(leaves, bucket_bytes, fused=False)
+    bk = GradientBucketer(leaves, bucket_bytes)
     assert bk.num_buckets == 1
     layout, sizes = bk._layout(), tuple(bk.bucket_sizes)
     if direction == "flatten":
@@ -305,3 +305,37 @@ def test_select_pack_kernels_carry_the_name_the_benchmark_reads(chip):
         calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
                            r'"tpu_custom_call"', text)
         assert {c.split(".")[0] for c in calls} == want, calls
+
+
+def test_the_bucket_allreduce_gets_the_kernels_through_the_door(chip):
+    """What a cell compiles: "bsc,0.01" -> get_compressor -> the bucketed
+    dc-tier allreduce, traced under the `native` hook (what
+    ops/dispatch.py answers on a TPU).  Buckets of one tile and of
+    several: the custom calls carry the names the benchmark reads, and
+    no top-k of any kind is left in the program."""
+    import re
+    from geomx_tpu.compression import get_compressor
+    from geomx_tpu.compression.bucketing import maybe_bucketed
+    from geomx_tpu.ops.dispatch import kernels
+
+    comp = maybe_bucketed(get_compressor("bsc,0.01"), bucket_bytes=64 * 1024)
+    shapes = [(100, 70), (33,), (1_500_000,), (64, 64)]
+    grads = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)
+             for s in shapes]
+    state = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        jax.eval_shape(comp.init_state, grads))
+    with kernels("native"):
+        lowered = jax.jit(
+            lambda g, s: comp.allreduce(g, s, "dc", 1)).lower(grads, state)
+    text = lowered.compile().as_text()
+    calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert {"bsc_select_pack", "bsc_select_pack_count",
+            "bsc_select_pack_place", "bsc_scatter_add", "fused_flatten",
+            "fused_unflatten"} == {c.split(".")[0] for c in calls}, calls
+    for scope in ("compress/boundary", "bsc/select_pack", "bsc/scatter_add",
+                  "compress/merge", "compress/flatten", "compress/unflatten",
+                  "dc_allreduce/bucket0"):
+        assert scope + "/" in text, scope
+    assert not re.search(r"\b(approx-)?top-?k\b|TopK", text)

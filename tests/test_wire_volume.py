@@ -30,12 +30,13 @@ def test_wire_bytes_match_actual_payloads():
     fp16 = FP16Compressor()
     assert fp16.wire_bytes_leaf(leaf) == n * 2  # fp16 payload
 
-    two = TwoBitCompressor(0.5, use_pallas=False)
-    # jnp path gathers int32 words, 16 codes each
+    from geomx_tpu.ops.dispatch import kernels
+    two = TwoBitCompressor(0.5)
+    # the jnp form gathers int32 words, 16 codes each
     assert two.wire_bytes_leaf(leaf) == 4 * ((n + 15) // 16)
-    twop = TwoBitCompressor(0.5, use_pallas=True)
-    # pallas path gathers 128 int32 words per 2048-element row
-    assert twop.wire_bytes_leaf(leaf) == 4 * 128 * (-(-n // 2048))
+    with kernels("native"):
+        # the kernel gathers 128 int32 words per 2048-element row
+        assert two.wire_bytes_leaf(leaf) == 4 * 128 * (-(-n // 2048))
 
     bsc = BiSparseCompressor(ratio=0.01, min_sparse_size=1)
     k = bsc.k_for(n)

@@ -260,7 +260,7 @@ def test_zero_compressed_shard_path_purity():
     params = {"a": jnp.zeros((6000,), jnp.float32),
               "b": jnp.zeros((300,), jnp.float32)}
     comp = BucketedCompressor(BiSparseCompressor(
-        ratio=0.05, min_sparse_size=16, fused=False, select="exact"))
+        ratio=0.05, min_sparse_size=16))
     ZeroPlan(W_).bind_compressor(comp)
     assert audit_zero_compressed_path(comp, params, num_shards=W_) == []
 
@@ -277,7 +277,7 @@ def test_zero_compressed_shard_path_purity():
                     (u.reshape(g.shape), v.reshape(g.shape)))
 
     leaky = BucketedCompressor(DenseLeak(
-        ratio=0.05, min_sparse_size=16, fused=False, select="exact"))
+        ratio=0.05, min_sparse_size=16))
     ZeroPlan(W_).bind_compressor(leaky)
     findings = audit_zero_compressed_path(leaky, params, num_shards=W_)
     assert findings and all(f.rule_id == "GX-PURITY-001"

@@ -11,8 +11,8 @@ gives milliseconds per call:
 - ``fused``: ``ops.bsc_pallas.bsc_scatter_add``, as the engine calls it;
 - ``fused_shuffled``: the same on the pairs in random order (what
   ``lax.top_k`` or several parties hand over: pays the sort);
-- ``xla`` / ``xla_shuffled``: ``BiSparseCompressor(fused=False)
-  .decompress``, XLA's own scatter-add, on the same two orders;
+- ``xla`` / ``xla_shuffled``: ``ops.bsc_pallas.scatter_add_ref``, XLA's
+  own scatter-add (what runs off a TPU), on the same two orders;
 - ``other``: ``bsc_scatter_add(vals, idx, n)`` of the module given with
   ``--other`` (a parent commit's file), to compare schedules.
 
@@ -61,8 +61,7 @@ def main(argv=None) -> int:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from geomx_tpu.compression import BiSparseCompressor
-    from geomx_tpu.ops.bsc_pallas import bsc_scatter_add
+    from geomx_tpu.ops.bsc_pallas import bsc_scatter_add, scatter_add_ref
 
     if jax.default_backend() != "tpu":
         print("not a TPU: a time from here is not a device time",
@@ -74,7 +73,6 @@ def main(argv=None) -> int:
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
         kernels["other"] = module.bsc_scatter_add
-    unfused = BiSparseCompressor(0.01, select="sampled", fused=False)
     skip = set(filter(None, args.skip.split(",")))
     for size in args.sizes:
         n, m, count = (int(x) for x in size.split(":"))
@@ -94,8 +92,8 @@ def main(argv=None) -> int:
                                          for c in range(count)])
 
         variants = {
-            "xla": (every_call(unfused.decompress), ascending),
-            "xla_shuffled": (every_call(unfused.decompress), shuffled),
+            "xla": (every_call(scatter_add_ref), ascending),
+            "xla_shuffled": (every_call(scatter_add_ref), shuffled),
             "fused": (every_call(kernels["fused"]), ascending),
             "fused_shuffled": (every_call(kernels["fused"]), shuffled),
         }

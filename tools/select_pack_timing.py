@@ -12,8 +12,8 @@ needs it):
 - ``fused``: ``ops.bsc_pallas.bsc_select_pack``, as the engine calls it;
 - ``other``: ``bsc_select_pack`` of the module given with ``--other`` (a
   parent commit's file), to compare schedules;
-- ``xla``: ``BiSparseCompressor(fused=False).compress``, the jnp chain
-  (mask, cumsum, scatter of all n indices).
+- ``xla``: ``ops.bsc_pallas.select_pack_ref``, the jnp chain (mask,
+  cumsum, scatter of all n indices), what runs off a TPU.
 
 The gradients (``--shapes``):
 
@@ -36,8 +36,10 @@ asks for (PERF.md).
     python tools/select_pack_timing.py 31254528:4 4194304:16 7040:64
 """
 import argparse
+import functools
 import importlib.util
 import json
+import math
 import os
 import statistics
 import sys
@@ -97,7 +99,6 @@ def main(argv=None) -> int:
 
     import jax
     import jax.numpy as jnp
-    from geomx_tpu.compression import BiSparseCompressor
     from geomx_tpu.ops import bsc_pallas
 
     on_chip = jax.default_backend() == "tpu"
@@ -111,14 +112,12 @@ def main(argv=None) -> int:
         module = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(module)
         kernels["other"] = module.bsc_select_pack
-    unfused = BiSparseCompressor(RATIO, select="sampled", fused=False,
-                                 min_sparse_size=1)
     skip = set(filter(None, args.skip.split(",")))
 
-    def with_boundary(kernel, k):
+    def with_boundary(select, k):
         def one(g, u, v):
             thr = bsc_pallas.sampled_boundary_guv(g, u, v, k)
-            return kernel(g, u, v, thr, k, interpret=args.interpret)
+            return select(g, u, v, thr, k)
         return one
 
     def unequal(got, want):
@@ -130,7 +129,7 @@ def main(argv=None) -> int:
     ok = True
     for size in args.sizes:
         n, count = (int(x) for x in size.split(":"))
-        k = unfused.k_for(n)
+        k = max(1, math.ceil(n * RATIO))
         tiles, out_blocks, out_rows = bsc_pallas.select_pack_shape(n, k)
 
         def every_call(one):
@@ -138,9 +137,11 @@ def main(argv=None) -> int:
                                             for c in range(count)])
 
         # one program a variant and size, whatever the gradient's shape
-        variants = {"xla": every_call(unfused.compress)}
+        variants = {"xla": every_call(
+            with_boundary(bsc_pallas.select_pack_ref, k))}
         for name, kernel in kernels.items():
-            variants[name] = every_call(with_boundary(kernel, k))
+            variants[name] = every_call(with_boundary(functools.partial(
+                kernel, interpret=args.interpret), k))
         for shape in args.shapes.split(","):
             g, u, v = gradients(shape, n, count, seed=n % 9973)
             line = {"n": n, "k": k, "shape": shape, "calls": count,
