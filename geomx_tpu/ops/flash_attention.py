@@ -1,31 +1,52 @@
-"""Fused flash-attention Pallas kernel.
+"""Fused flash-attention Pallas kernels.
 
 The reference has no attention operator at all (its workloads are CNNs;
-SURVEY.md §5 "long-context: absent") — this kernel backs the framework's
+SURVEY.md §5 "long-context: absent") — these kernels back the framework's
 first-class long-context path (`models/seq_classifier.py`,
-`parallel/ring_attention.py`) with a TPU-native fused implementation:
-one pass over KV tiles with an online softmax held in VMEM scratch, so
-the [L, L] score matrix never touches HBM.  The unfused XLA graph
-materializes scores + probabilities ([B, H, L, L] each, f32) — at
-L=4096 that is 2 x 64 MB per (batch, head) of HBM traffic this kernel
-never pays.
+`models/kimi_linear.py`, `parallel/ulysses.py`) with a TPU-native fused
+implementation: the [L, L] score matrix never touches HBM, forward or
+backward.
 
-Both directions are flash on the kernel path: the forward saves the
-per-row logsumexp, and `flash_attention_bwd` recomputes p per tile
-from it (dq kernel over k tiles; dk/dv kernel over q tiles, with
-delta = rowsum(dO * O) folding the normalizer's gradient) — the
-[L, L] score matrix never exists in HBM forward OR backward.  Off-TPU
-the dense jnp reference runs both ways via `jax.custom_vjp`; gradients
-agree to f32 tolerance either way.
+**How much a grid step does** (docs/kernels.md has the long form).  The
+kernels read q, k, v as `[B, L, H * D]` (the caller's `[B, L, H, D]`, no
+transpose) and a step takes a block of rows for a *group of heads*: a
+lane-aligned slab of `heads * D` columns.  :func:`attention_plan`, a pure
+function of the shapes and the dtype, picks the blocks and the heads a
+step under :data:`VMEM_BUDGET`; `block_q` / `block_k` given by a caller
+are honoured.  The grid walks a static list of the (q block, k block)
+pairs that hold a score, so a causal call neither visits nor fetches the
+blocks above the diagonal, and only the pairs that cross the diagonal or
+the end of a padded length run the masked body.
 
-Numerics match `parallel/ring_attention.full_attention_reference` to
-f32 tolerance (tests/test_flash_attention.py), including fully-masked
-rows (causal + padding) which produce zeros, not NaNs.
+**Precision.**  Every product takes its operands in the dtype the caller
+gave (bf16 in, bf16 on the MXU) and accumulates in float32
+(`preferred_element_type`); `p` and `ds` are rounded to that dtype for
+the products they feed, as every other product of a bf16 model is.  The
+scores, the running max, the normaliser, `lse`, `delta`, `exp` and every
+accumulator are float32; the softmax scale is applied to the float32
+scores and to the accumulated `dq` / `dk`, never to an operand.  Float32
+callers get float32 operands at the MXU's default precision, as before.
+
+**Backward.**  The backward works on transposed scores (`s^T = K Q^T`),
+so that `lse` and `delta` are rows that broadcast down the sublanes and
+only `dq` needs a transpose, a small one.  Where the whole sequence is one
+block pair (L <= 512) ONE kernel computes `s`, `p`, `dp`, `ds` once and
+all three gradients from them (five products), with
+`delta = rowsum(P * dP)` (= rowsum(dO * O)) taken inside it; longer
+sequences run a dq kernel and a dk/dv kernel (seven products) with
+`delta` from XLA as rows of L values.  `lse` crosses HBM as `[B, H, L]`
+float32 rows.  Off a TPU the dense jnp reference runs both ways via
+`jax.custom_vjp`.
+
+Numerics match `parallel/ring_attention.full_attention_reference`
+(tests/test_flash_attention.py), including fully-masked rows (causal +
+padding) which produce zeros, not NaNs.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -33,158 +54,367 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from geomx_tpu.utils.profiler import profile_scope
+
 _NEG_INF = -1e30  # large-but-finite: -inf breaks the m-correction exp
+_LANES = 128
+
+# What a kernel's blocks, scratch and score-sized temporaries may take:
+# three quarters of the 16 MiB of VMEM that Mosaic gives a v5e kernel
+# unasked.
+VMEM_BUDGET = 12 * 2 ** 20
+MAX_BLOCK = 512      # rows of q or k a step: a [512, 512] f32 score tile
+MAX_HEADS = 8        # heads a step: bounds the unrolled code
 
 
-_LANES = 128  # m/l scratch is lane-replicated 2-D: TPU Mosaic has
-# historically rejected 1-D VMEM refs (the upstream JAX flash kernel
-# pads to (block_q, 128) for the same reason)
+class AttentionPlan(NamedTuple):
+    """What one grid step does (:func:`attention_plan`)."""
+    block_q: int
+    block_k: int
+    heads: int              # heads a step, a divisor of H
+    fused_backward: bool    # one backward kernel, else dq and dk/dv kernels
+    vmem_bytes: int         # the largest kernel's estimate
 
 
-def _fa_kernel_nolse(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-                     acc_ref, **kw):
-    """Inference variant: no lse output (a Pallas output cannot be
-    dead-code-eliminated by XLA, so the no-grad path must not emit
-    one)."""
-    _fa_kernel(q_ref, k_ref, v_ref, o_ref, None, m_ref, l_ref,
-               acc_ref, **kw)
+def _default_block(length: int) -> int:
+    """The whole (128-padded) sequence up to MAX_BLOCK rows, else the
+    largest of MAX_BLOCK, its half, ... down to 128 that divides it."""
+    padded = -(-length // _LANES) * _LANES
+    if padded <= MAX_BLOCK:
+        return padded
+    block = MAX_BLOCK
+    while padded % block:
+        block //= 2
+    return block
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-               acc_ref, *, scale, block_q, block_k, num_k, kv_len,
-               causal):
-    """Grid (BH, nq, nk), k innermost.  Blocks: q [1, block_q, D], k
-    [1, block_k, D]; v [1, block_k, Dv] and o [1, block_q, Dv] (Dv may
-    differ from D: latent attention has 192-wide q/k and 128-wide v);
-    lse out [1, block_q, LANES] (lane-replicated; None on the inference
-    path).  Scratch m/l [block_q, LANES] and acc [block_q, Dv] carry the
-    online softmax across the k dim."""
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)      # [Bq, D]
-        k = k_ref[0].astype(jnp.float32)      # [Bk, D]
-        v = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-
-        rows = iq * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = ik * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = cols < kv_len                  # padded keys contribute 0
-        if causal:
-            mask = mask & (cols <= rows)
-        s = jnp.where(mask, s, _NEG_INF)
-
-        m_prev = m_ref[:, :1]                 # [Bq, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(mask, p, 0.0)           # exp(NEG_INF-m) underflows,
-        # but a fully-masked row has m_new = NEG_INF where it would not
-        corr = jnp.exp(m_prev - m_new)        # [Bq, 1]
-        l_new = l_ref[:, :1] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
-
-    if causal:
-        # a block whose every column is in the masked future contributes
-        # nothing — skip its matmuls entirely (~half the grid at nq == nk)
-        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_accumulate)
-    else:
-        _accumulate()
-
-    @pl.when(ik == num_k - 1)
-    def _finalize():
-        l_sum = jnp.maximum(l_ref[:, :1], 1e-20)  # fully-masked rows -> 0 out
-        o_ref[0] = (acc_ref[:] / l_sum).astype(o_ref.dtype)
-        if lse_ref is not None:
-            # logsumexp per row, for the backward's p = exp(s - lse)
-            lse_ref[0] = jnp.broadcast_to(m_ref[:, :1] + jnp.log(l_sum),
-                                          (block_q, _LANES))
+def _vmem_bytes(bq, bk, heads, d, dv, itemsize, multi_block):
+    """Bytes of VMEM the backward (the largest of the kernels) asks for:
+    double-buffered blocks of q, k, v, dO in and dq, dk, dv out, the
+    float32 accumulators, and the [bk, bq] temporaries (s, p, dp, ds in
+    float32, p and ds rounded).  With several blocks a kernel makes either
+    dq or dk and dv, so the largest is the dk/dv kernel."""
+    qk, vv = heads * d, heads * dv
+    blocks_in = bq * qk + bk * qk + bk * vv + bq * vv
+    blocks_out = bk * (qk + vv) if multi_block else bq * qk + bk * (qk + vv)
+    return (2 * itemsize * (blocks_in + blocks_out) + 4 * blocks_out
+            + bq * bk * (4 * 4 + 2 * itemsize))
 
 
-def _heads_first(x, B, H, L):
-    """[B, L, H, D] -> [B*H, L, D]: one grid row per (batch, head)."""
-    return x.transpose(0, 2, 1, 3).reshape(B * H, L, x.shape[-1])
+def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
+                   dtype, causal: bool, block_q: Optional[int] = None,
+                   block_k: Optional[int] = None) -> AttentionPlan:
+    """Blocks and heads a grid step, from the shapes alone.
+
+    Blocks: a given `block_q` / `block_k` is honoured (capped at the
+    length); else :func:`_default_block`.  Heads a step: the largest
+    divisor of H, at most MAX_HEADS, whose slab of `heads * D` (and
+    `heads * Dv`) columns is whole 128-lane tiles (or all of them) and
+    whose backward fits VMEM_BUDGET; the smallest such slab where none
+    fits.  One backward kernel where one block pair is the whole
+    sequence.  `causal` chooses no size: it shortens the list of block
+    pairs the grid walks (:func:`_block_pairs`)."""
+    del causal
+    bq = min(block_q, q_len) if block_q else _default_block(q_len)
+    bk = min(block_k, kv_len) if block_k else _default_block(kv_len)
+    fused = -(-q_len // bq) == 1 and -(-kv_len // bk) == 1
+    itemsize = jnp.dtype(dtype).itemsize
+    slabs = [g for g in range(1, heads + 1) if heads % g == 0 and (
+        g == heads or ((g * d) % _LANES == 0 and (g * dv) % _LANES == 0))]
+    cost = lambda g: _vmem_bytes(bq, bk, g, d, dv, itemsize, not fused)
+    fits = [g for g in slabs if g <= MAX_HEADS and cost(g) <= VMEM_BUDGET]
+    g = max(fits) if fits else min(slabs)
+    return AttentionPlan(bq, bk, g, fused, cost(g))
+
+
+def _block_pairs(nq, nk, bq, bk, q_len, kv_len, causal, k_inner):
+    """The (q block, k block) pairs that hold a score, as the grid's last
+    axis walks them: q-major with k inner (forward, dq) or k-major with q
+    inner (dk/dv).  Returns int32 lists `(qi, kj, flags)` for scalar
+    prefetch; flags: 1 the first pair of its run (same outer block), 2 the
+    last, 4 the pair needs the mask (it crosses the diagonal, or holds
+    padded rows or columns); and `bodies`, the kinds of pair the list
+    holds (False unmasked, True masked), which are the bodies a kernel
+    needs (:func:`_run_bodies`)."""
+    pairs = [(i, j) for i in range(nq) for j in range(nk)
+             if not causal or j * bk <= i * bq + bq - 1]
+    outer = 0 if k_inner else 1
+    pairs.sort(key=lambda p: (p[outer], p[1 - outer]))
+    flags = []
+    for t, (i, j) in enumerate(pairs):
+        first = t == 0 or pairs[t - 1][outer] != pairs[t][outer]
+        last = t == len(pairs) - 1 or pairs[t + 1][outer] != pairs[t][outer]
+        masked = ((causal and j * bk + bk - 1 > i * bq)
+                  or (j + 1) * bk > kv_len or (i + 1) * bq > q_len)
+        flags.append(first + 2 * last + 4 * masked)
+    as_i32 = lambda xs: np.asarray(xs, np.int32)
+    return ((as_i32([p[0] for p in pairs]), as_i32([p[1] for p in pairs]),
+             as_i32(flags)), sorted({bool(f & 4) for f in flags}))
+
+
+def _window(h, d, width):
+    """Head h's columns [h d, (h + 1) d) of a slab `width` wide: the
+    128-aligned window (lo, hi) that holds them and where they sit in it
+    (a, b).  A 64-wide head shares its window with its neighbour; a
+    192-wide one takes two tiles, one of them shared."""
+    start, stop = h * d, (h + 1) * d
+    lo = start // _LANES * _LANES
+    hi = min(-(-stop // _LANES) * _LANES, width)
+    return lo, hi, start - lo, stop - lo
+
+
+def _only(x, a, b, axis=1):
+    """x with everything outside [a, b) along `axis` set to zero: the
+    other heads of a shared window drop out of a product's contraction
+    (a 64-deep contraction costs the MXU what a 128-deep one does)."""
+    if a == 0 and b == x.shape[axis]:
+        return x
+    at = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    keep = (at >= a) & (at < b)
+    return jnp.where(keep, x.astype(jnp.float32), 0.0).astype(x.dtype)
+
+
+def _put(ref, lo, hi, a, b, new, axis=1, add=False):
+    """Write (or add) `new` into head columns [a, b) of window [lo, hi) of
+    a float32 scratch; the window's other columns keep what they hold.
+    `axis` 0: the same on rows (the transposed dq)."""
+    at = (slice(None), slice(lo, hi)) if axis else (slice(lo, hi),
+                                                    slice(None))
+    if a == 0 and b == hi - lo:
+        ref[at] = ref[at] + new if add else new
+        return
+    old = ref[at]
+    index = jax.lax.broadcasted_iota(jnp.int32, old.shape, axis)
+    keep = (index >= a) & (index < b)
+    ref[at] = jnp.where(keep, old + new if add else new, old)
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b^T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a^T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _run_bodies(step, flags, bodies):
+    """`step(masked)` for the pair at hand: where the call's list holds
+    both kinds of pair, two bodies under `pl.when`; else the one."""
+    if len(bodies) == 1:
+        step(bodies[0])
+        return
+    for masked in bodies:
+        pl.when(((flags & 4) != 0) == masked)(
+            functools.partial(step, masked))
+
+
+def _fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, *refs, scale,
+                heads, d, dv, bq, bk, kv_len, causal, single, with_lse,
+                bodies):
+    """One (q block, k block) pair of a group of heads.  Blocks: q
+    [1, bq, heads d], k [1, bk, heads d], v [1, bk, heads dv], out
+    [1, bq, heads dv], lse [1, heads, 1, bq] (rows; absent on the
+    inference path).  Scratch: acc [bq, heads dv] float32 and, where a q
+    block meets several k blocks (not `single`), running max and
+    normaliser [heads, bq, LANES] (lane-replicated)."""
+    refs = list(refs)
+    o_ref = refs.pop(0)
+    lse_ref = refs.pop(0) if with_lse else None
+    acc_ref = refs.pop(0)
+    m_ref, l_ref = refs if not single else (None, None)
+    t = pl.program_id(2)
+    i, j, flags = qi_ref[t], kj_ref[t], fl_ref[t]
+    dtype = v_ref.dtype
+
+    if not single:
+        @pl.when((flags & 1) != 0)
+        def _init():
+            m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+            l_ref[:] = jnp.zeros_like(l_ref)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    def finish(lse_cols):
+        """The q block's output, and its lse: per-head [bq, 1] columns ->
+        rows, one transpose a q block."""
+        o_ref[0] = acc_ref[:].astype(o_ref.dtype)
+        if not with_lse:
+            return
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bq, _LANES), 1)
+        packed = jnp.zeros((bq, _LANES), jnp.float32)
+        for h, col in enumerate(lse_cols):
+            packed = jnp.where(lane == h, col, packed)
+        rows = packed.T
+        for h in range(heads):
+            lse_ref[0, h] = rows[h:h + 1, :]
+
+    def step(masked):
+        if masked:
+            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+            mask = cols < kv_len              # padded keys contribute 0
+            if causal:
+                mask = mask & (cols <= rows)
+        lse_cols = []
+        for h in range(heads):
+            lo, hi, a, b = _window(h, d, heads * d)
+            vlo, vhi, va, vb = _window(h, dv, heads * dv)
+            q = _only(q_ref[0, :, lo:hi], a, b)
+            s = _dot(q, k_ref[0, :, lo:hi], _NT) * scale     # [bq, bk] f32
+            if masked:
+                s = jnp.where(mask, s, _NEG_INF)
+            m_new = jnp.max(s, axis=-1, keepdims=True)
+            if not single:
+                m_prev = m_ref[h, :, :1]
+                m_new = jnp.maximum(m_prev, m_new)
+            p = jnp.exp(s - m_new)
+            if masked:
+                # exp(NEG_INF - m) underflows, but a fully-masked row has
+                # m_new = NEG_INF where it would not
+                p = jnp.where(mask, p, 0.0)
+            l_new = jnp.sum(p, axis=-1, keepdims=True)
+            pv = _dot(p.astype(dtype), v_ref[0, :, vlo:vhi], _NN)
+            if single:
+                l_sum = jnp.maximum(l_new, 1e-20)  # fully-masked rows -> 0
+                _put(acc_ref, vlo, vhi, va, vb, pv * (1.0 / l_sum))
+                lse_cols.append(m_new + jnp.log(l_sum))
+            else:
+                corr = jnp.exp(m_prev - m_new)
+                l_new = l_ref[h, :, :1] * corr + l_new
+                _put(acc_ref, vlo, vhi, va, vb,
+                     acc_ref[:, vlo:vhi] * corr + pv)
+                m_ref[h] = jnp.broadcast_to(m_new, (bq, _LANES))
+                l_ref[h] = jnp.broadcast_to(l_new, (bq, _LANES))
+        if single:
+            finish(lse_cols)
+
+    _run_bodies(step, flags, bodies)
+
+    if not single:
+        @pl.when((flags & 2) != 0)
+        def _finalize():
+            lse_cols = []
+            for h in range(heads):
+                vlo, vhi, va, vb = _window(h, dv, heads * dv)
+                l_sum = jnp.maximum(l_ref[h, :, :1], 1e-20)
+                _put(acc_ref, vlo, vhi, va, vb,
+                     acc_ref[:, vlo:vhi] * (1.0 / l_sum))
+                lse_cols.append(m_ref[h, :, :1] + jnp.log(l_sum))
+            finish(lse_cols)
 
 
 def _pad_seq(x, p):
     return jnp.pad(x, ((0, 0), (0, p), (0, 0), (0, 0))) if p else x
 
 
+def _slabs(x, pad):
+    """[B, L, H, D] -> [B, L + pad, H D]: heads side by side, no
+    transpose."""
+    x = _pad_seq(x, pad)
+    return x.reshape(x.shape[0], x.shape[1], -1)
+
+
+class _Call(NamedTuple):
+    """What the wrappers share: the plan, the operands' common dtype, the
+    padding and block counts, and the BlockSpecs of a group of heads'
+    slabs (rows at the pair's q block or k block, `d` or `dv` wide) and of
+    the per-row float32 rows (lse, delta)."""
+    plan: AttentionPlan
+    dtype: jnp.dtype
+    pad_q: int
+    pad_k: int
+    nq: int
+    nk: int
+    q: pl.BlockSpec
+    k: pl.BlockSpec
+    v: pl.BlockSpec
+    o: pl.BlockSpec
+    rows: pl.BlockSpec
+
+
+def _prepare(q, k, v, causal, block_q, block_k) -> _Call:
+    B, Lq, H, D = q.shape
+    Lk, Dv = k.shape[1], v.shape[-1]
+    dtype = jnp.result_type(q.dtype, k.dtype, v.dtype)
+    plan = attention_plan(Lq, Lk, H, D, Dv, dtype, causal, block_q, block_k)
+    bq, bk, G = plan.block_q, plan.block_k, plan.heads
+    pq, pk = (-Lq) % bq, (-Lk) % bk
+    at_q = lambda b, g, t, qi, kj, fl: (b, qi[t], g)
+    at_k = lambda b, g, t, qi, kj, fl: (b, kj[t], g)
+    return _Call(
+        plan, dtype, pq, pk, (Lq + pq) // bq, (Lk + pk) // bk,
+        q=pl.BlockSpec((1, bq, G * D), at_q),
+        k=pl.BlockSpec((1, bk, G * D), at_k),
+        v=pl.BlockSpec((1, bk, G * Dv), at_k),
+        o=pl.BlockSpec((1, bq, G * Dv), at_q),
+        rows=pl.BlockSpec((1, G, 1, bq),
+                          lambda b, g, t, qi, kj, fl: (b, g, 0, qi[t])))
+
+
+def _grid_spec(pairs, grid, in_specs, out_specs, scratch_shapes):
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=grid + (len(pairs[0]),),
+        in_specs=in_specs, out_specs=out_specs,
+        scratch_shapes=scratch_shapes)
+
+
+_SEMANTICS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret", "with_lse"))
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
-                             causal: bool = False, block_q: int = 128,
-                             block_k: int = 128, interpret: bool = False,
+                             causal: bool = False,
+                             block_q: Optional[int] = None,
+                             block_k: Optional[int] = None,
+                             interpret: bool = False,
                              with_lse: bool = True):
     """Fused attention forward; returns (out [B, L, H, Dv] in q's dtype,
     lse [B, H, L] f32 or None) — lse is the per-row logsumexp the flash
     backward kernels consume.  ``with_lse=False`` (the inference path)
     skips the lse output entirely: XLA cannot dead-code-eliminate a
-    Pallas output, so a discarded lse would still cost its HBM write."""
+    Pallas output, so a discarded lse would still cost its HBM write.
+    ``block_q`` / ``block_k`` None: :func:`attention_plan` chooses."""
     B, Lq, H, D = q.shape
     Lk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
-
-    bq, bk = min(block_q, Lq), min(block_k, Lk)
-    pq, pk = (-Lq) % bq, (-Lk) % bk
-    qp, kp, vp = _pad_seq(q, pq), _pad_seq(k, pk), _pad_seq(v, pk)
-    Lqp, Lkp = Lq + pq, Lk + pk
-    nq, nk = Lqp // bq, Lkp // bk
-
-    qh = _heads_first(qp, B, H, Lqp)
-    kh = _heads_first(kp, B, H, Lkp)
-    vh = _heads_first(vp, B, H, Lkp)
-
-    common = dict(scale=scale, block_q=bq, block_k=bk, num_k=nk,
-                  kv_len=Lk, causal=causal)
-    ospec = pl.BlockSpec((1, bq, Dv), lambda bh, iq, ik: (bh, iq, 0))
-    lspec = pl.BlockSpec((1, bq, _LANES), lambda bh, iq, ik: (bh, iq, 0))
+    call = _prepare(q, k, v, causal, block_q, block_k)
+    bq, bk, G = call.plan.block_q, call.plan.block_k, call.plan.heads
+    Lqp = Lq + call.pad_q
+    pairs, bodies = _block_pairs(call.nq, call.nk, bq, bk, Lq, Lk, causal,
+                                 k_inner=True)
+    single = call.nk == 1
+    out_shape = jax.ShapeDtypeStruct((B, Lqp, H * Dv), q.dtype)
+    lse_shape = jax.ShapeDtypeStruct((B, H, 1, Lqp), jnp.float32)
+    scratch = [pltpu.VMEM((bq, G * Dv), jnp.float32)]
+    if not single:
+        scratch += [pltpu.VMEM((G, bq, _LANES), jnp.float32)] * 2
+    slabs = lambda x, pad: _slabs(x.astype(call.dtype), pad)
     res = pl.pallas_call(
-        functools.partial(_fa_kernel if with_lse else _fa_kernel_nolse,
-                          **common),
-        grid=(B * H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, D), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bk, D), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, Dv), lambda bh, iq, ik: (bh, ik, 0)),
-        ],
-        out_specs=[ospec, lspec] if with_lse else ospec,
-        out_shape=(
-            [jax.ShapeDtypeStruct((B * H, Lqp, Dv), q.dtype),
-             jax.ShapeDtypeStruct((B * H, Lqp, _LANES), jnp.float32)]
-            if with_lse
-            else jax.ShapeDtypeStruct((B * H, Lqp, Dv), q.dtype)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, _LANES), jnp.float32),  # running max m
-            pltpu.VMEM((bq, _LANES), jnp.float32),  # normalizer l
-            pltpu.VMEM((bq, Dv), jnp.float32),      # output accumulator
-        ],
-        interpret=interpret,
-    )(qh, kh, vh)
+        functools.partial(_fwd_kernel, scale=scale, heads=G, d=D, dv=Dv,
+                          bq=bq, bk=bk, kv_len=Lk, causal=causal,
+                          single=single, with_lse=with_lse, bodies=bodies),
+        grid_spec=_grid_spec(pairs, (B, H // G), [call.q, call.k, call.v],
+                             [call.o, call.rows] if with_lse else call.o,
+                             scratch),
+        out_shape=[out_shape, lse_shape] if with_lse else out_shape,
+        compiler_params=_SEMANTICS, interpret=interpret,
+        name="flash_attention_fwd",
+    )(*pairs, slabs(q, call.pad_q), slabs(k, call.pad_k),
+      slabs(v, call.pad_k))
     out, lse = res if with_lse else (res, None)
-    out = out.reshape(B, H, Lqp, Dv).transpose(0, 2, 1, 3)[:, :Lq]
+    out = out.reshape(B, Lqp, H, Dv)[:, :Lq]
     if with_lse:
-        lse = lse[..., 0].reshape(B, H, Lqp)[..., :Lq]
+        lse = lse[:, :, 0, :Lq]
     return out, lse
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                    causal: bool = False, block_q: int = 128,
-                    block_k: int = 128,
+                    causal: bool = False, block_q: Optional[int] = None,
+                    block_k: Optional[int] = None,
                     interpret: bool = False) -> jax.Array:
     """Fused attention forward: softmax(QK^T / sqrt(D)) V.
 
@@ -199,180 +429,147 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                                     with_lse=False)[0]
 
 
-def _bwd_masks(iq, ik, block_q, block_k, q_len, kv_len, causal):
-    """Shared [Bq, Bk] validity mask for the backward tiles: real q rows,
-    real k cols, and (optionally) the causal triangle."""
-    rows = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ik * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = (rows < q_len) & (cols < kv_len)
-    if causal:
-        mask = mask & (cols <= rows)
-    return mask
+def _bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                lse_ref, *refs, scale, heads, d, dv, bq, bk, q_len, kv_len,
+                causal, want_dq, want_dkv, delta_in, bodies):
+    """One (q block, k block) pair of a group of heads, on transposed
+    scores: s^T = K Q^T [bk, bq], so lse and delta [1, bq] broadcast down
+    the sublanes.  Makes dq (`want_dq`: pairs arrive q-major), dk and dv
+    (`want_dkv`: k-major) or, where one pair is the whole sequence, all
+    three from one s, p, dp, ds.  `delta_in`: delta arrives as rows
+    [1, heads, 1, bq]; else it is rowsum(P * dP) over the pair, which is
+    rowsum(dO * O) only where the pair holds every key.  Scratch, float32:
+    dq^T [heads d, bq], dk [bk, heads d], dv [bk, heads dv]."""
+    refs = list(refs)
+    delta_ref = refs.pop(0) if delta_in else None
+    dq_ref = refs.pop(0) if want_dq else None
+    dk_ref, dv_ref = (refs.pop(0), refs.pop(0)) if want_dkv else (None, None)
+    dqt_acc = refs.pop(0) if want_dq else None
+    dk_acc, dv_acc = refs if want_dkv else (None, None)
+    t = pl.program_id(2)
+    i, j, flags = qi_ref[t], kj_ref[t], fl_ref[t]
+    dtype = q_ref.dtype
 
-
-def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               acc_ref, *, scale, block_q, block_k, num_k, q_len, kv_len,
-               causal):
-    """dq = sum_k ds @ K * scale, ds = p * (dO V^T - delta).  Grid
-    (BH, nq, nk), k innermost; dq accumulates in VMEM scratch."""
-    iq = pl.program_id(1)
-    ik = pl.program_id(2)
-
-    @pl.when(ik == 0)
+    @pl.when((flags & 1) != 0)
     def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+        for acc in (dqt_acc, dk_acc, dv_acc):
+            if acc is not None:
+                acc[:] = jnp.zeros_like(acc)
 
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = _bwd_masks(iq, ik, block_q, block_k, q_len, kv_len, causal)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, :, :1]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, :, :1])
-        acc_ref[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
+    def step(masked):
+        if masked:
+            rows = i * bq + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            cols = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+            mask = (rows < q_len) & (cols < kv_len)
+            if causal:
+                mask = mask & (cols <= rows)
+        for h in range(heads):
+            lo, hi, a, b = _window(h, d, heads * d)
+            vlo, vhi, va, vb = _window(h, dv, heads * dv)
+            q, k = q_ref[0, :, lo:hi], k_ref[0, :, lo:hi]
+            do = do_ref[0, :, vlo:vhi]
+            st = _dot(_only(k, a, b), q, _NT) * scale        # [bk, bq] f32
+            pt = jnp.exp(st - lse_ref[0, h])
+            if masked:
+                pt = jnp.where(mask, pt, 0.0)
+            dpt = _dot(_only(v_ref[0, :, vlo:vhi], va, vb), do, _NT)
+            if delta_in:
+                delta = delta_ref[0, h]
+            else:
+                delta = jnp.sum(pt * dpt, axis=0, keepdims=True)
+            dst = (pt * (dpt - delta)).astype(dtype)
+            if want_dkv:
+                _put(dv_acc, vlo, vhi, va, vb,
+                     _dot(pt.astype(dtype), do, _NN), add=True)
+                _put(dk_acc, lo, hi, a, b, _dot(dst, q, _NN), add=True)
+            if want_dq:
+                # dq^T = K^T dS^T: the transpose falls on k, not on ds
+                _put(dqt_acc, lo, hi, a, b, _dot(k, dst, _TN), axis=0,
+                     add=True)
 
-    if causal:
-        pl.when(ik * block_k <= iq * block_q + block_q - 1)(_accumulate)
-    else:
-        _accumulate()
+    _run_bodies(step, flags, bodies)
 
-    @pl.when(ik == num_k - 1)
+    @pl.when((flags & 2) != 0)
     def _finalize():
-        dq_ref[0] = acc_ref[:]
-
-
-def _dkv_kernel(k_ref, v_ref, q_ref, do_ref, lse_ref, delta_ref,
-                dk_ref, dv_ref, dk_acc, dv_acc, *, scale, block_q,
-                block_k, num_q, q_len, kv_len, causal):
-    """dk = sum_q ds^T @ Q * scale; dv = sum_q p^T @ dO.  Grid
-    (BH, nk, nq), q innermost; dk/dv accumulate in VMEM scratch."""
-    ik = pl.program_id(1)
-    iq = pl.program_id(2)
-
-    @pl.when(iq == 0)
-    def _init():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    def _accumulate():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        mask = _bwd_masks(iq, ik, block_q, block_k, q_len, kv_len, causal)
-        p = jnp.where(mask, jnp.exp(s - lse_ref[0, :, :1]), 0.0)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0, :, :1])
-        dk_acc[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        dv_acc[:] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        # a q tile entirely above the diagonal of this k tile never
-        # attends to it
-        pl.when(iq * block_q + block_q - 1 >= ik * block_k)(_accumulate)
-    else:
-        _accumulate()
-
-    @pl.when(iq == num_q - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[:]
-        dv_ref[0] = dv_acc[:]
+        if want_dq:
+            dq_ref[0] = (dqt_acc[:] * scale).T.astype(dq_ref.dtype)
+        if want_dkv:
+            dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
+            dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
                                     "interpret"))
 def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
-                        block_q: int = 128, block_k: int = 128,
+                        block_q: Optional[int] = None,
+                        block_k: Optional[int] = None,
                         interpret: bool = False):
-    """Flash backward: (dq, dk, dv) in f32, without ever materializing
-    the [L, L] score matrix — p is recomputed per tile from the
-    forward's logsumexp (the standard flash-attention backward;
-    delta_i = rowsum(dO_i * O_i) folds the softmax normalizer's
-    gradient).  v, out and do may have a head size of their own (Dv);
-    equal sizes give the kernels they always gave."""
+    """Flash backward: (dq, dk, dv), each in its input's dtype (from
+    float32 accumulators), without ever materializing the [L, L] score
+    matrix — p is recomputed per block pair from the forward's logsumexp
+    (the standard flash-attention backward; delta_i = rowsum(dO_i * O_i)
+    folds the softmax normalizer's gradient).  v, out and do may have a
+    head size of their own (Dv).  One kernel where the plan's block pair
+    is the whole sequence (`out` is then not read: delta is rowsum(P dP)
+    inside it), else a dq kernel and a dk/dv kernel."""
     B, Lq, H, D = q.shape
     Lk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
-    # delta: [B, H, Lq] — cheap elementwise jnp, no reason to fuse
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1).transpose(0, 2, 1)
-
-    bq, bk = min(block_q, Lq), min(block_k, Lk)
-    pq, pk = (-Lq) % bq, (-Lk) % bk
-    qp, dop = _pad_seq(q, pq), _pad_seq(do, pq)
-    kp, vp = _pad_seq(k, pk), _pad_seq(v, pk)
+    call = _prepare(q, k, v, causal, block_q, block_k)
+    plan, pq, pk = call.plan, call.pad_q, call.pad_k
+    bq, bk, G = plan.block_q, plan.block_k, plan.heads
     Lqp, Lkp = Lq + pq, Lk + pk
-    nq, nk = Lqp // bq, Lkp // bk
 
-    qh = _heads_first(qp, B, H, Lqp)
-    doh = _heads_first(dop, B, H, Lqp)
-    kh = _heads_first(kp, B, H, Lkp)
-    vh = _heads_first(vp, B, H, Lkp)
+    def rows(x):     # [B, H, Lq] f32 -> [B, H, 1, Lqp]
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, pq))) if pq else x
+        return x.reshape(B, H, 1, Lqp)
 
-    def rows_first(x):  # [B, H, Lq] -> [B*H, Lqp, LANES] lane-replicated
-        xp = jnp.pad(x, ((0, 0), (0, 0), (0, pq))) if pq else x
-        return jnp.broadcast_to(
-            xp.reshape(B * H, Lqp, 1), (B * H, Lqp, _LANES))
+    slabs = lambda x, pad: _slabs(x.astype(call.dtype), pad)
+    operands = [slabs(q, pq), slabs(k, pk), slabs(v, pk), slabs(do, pq),
+                rows(lse)]
+    if not plan.fused_backward:
+        # delta: [B, H, Lq] rows — one fused multiply-reduce over dO and O
+        operands.append(rows(jnp.sum(
+            do.astype(jnp.float32) * out.astype(jnp.float32),
+            axis=-1).transpose(0, 2, 1)))
+    in_specs = [call.q, call.k, call.v, call.o] \
+        + [call.rows] * (len(operands) - 4)
+    dq_shape = jax.ShapeDtypeStruct((B, Lqp, H * D), q.dtype)
+    dk_shape = jax.ShapeDtypeStruct((B, Lkp, H * D), k.dtype)
+    dv_shape = jax.ShapeDtypeStruct((B, Lkp, H * Dv), v.dtype)
+    dq_acc = pltpu.VMEM((G * D, bq), jnp.float32)
+    dkv_acc = [pltpu.VMEM((bk, G * D), jnp.float32),
+               pltpu.VMEM((bk, G * Dv), jnp.float32)]
 
-    lseh, deltah = rows_first(lse), rows_first(delta)
+    def kernel_call(name, k_inner, want_dq, want_dkv):
+        pairs, bodies = _block_pairs(call.nq, call.nk, bq, bk, Lq, Lk,
+                                     causal, k_inner)
+        kernel = functools.partial(
+            _bwd_kernel, scale=scale, heads=G, d=D, dv=Dv, bq=bq, bk=bk,
+            q_len=Lq, kv_len=Lk, causal=causal, want_dq=want_dq,
+            want_dkv=want_dkv, delta_in=not plan.fused_backward,
+            bodies=bodies)
+        return pl.pallas_call(
+            kernel,
+            grid_spec=_grid_spec(
+                pairs, (B, H // G), in_specs,
+                [call.q] * want_dq + [call.k, call.v] * want_dkv,
+                [dq_acc] * want_dq + dkv_acc * want_dkv),
+            out_shape=[dq_shape] * want_dq + [dk_shape, dv_shape] * want_dkv,
+            compiler_params=_SEMANTICS, interpret=interpret, name=name,
+        )(*pairs, *operands)
 
-    common = dict(scale=scale, block_q=bq, block_k=bk, q_len=Lq,
-                  kv_len=Lk, causal=causal)
-    qspec = pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, i, 0))
-    dospec = pl.BlockSpec((1, bq, Dv), lambda bh, i, j: (bh, i, 0))
-    kspec_q = pl.BlockSpec((1, bk, D), lambda bh, i, j: (bh, j, 0))
-    vspec_q = pl.BlockSpec((1, bk, Dv), lambda bh, i, j: (bh, j, 0))
-    rspec = pl.BlockSpec((1, bq, _LANES), lambda bh, i, j: (bh, i, 0))
+    if plan.fused_backward:
+        dq, dk, dv = kernel_call("flash_attention_bwd", True, True, True)
+    else:
+        dq, = kernel_call("flash_attention_bwd_dq", True, True, False)
+        dk, dv = kernel_call("flash_attention_bwd_dkv", False, False, True)
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, num_k=nk, **common),
-        grid=(B * H, nq, nk),
-        in_specs=[qspec, kspec_q, vspec_q, dospec, rspec, rspec],
-        out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B * H, Lqp, D), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
-        interpret=interpret,
-    )(qh, kh, vh, doh, lseh, deltah)
+    def back(x, L):
+        return x.reshape(B, x.shape[1], H, -1)[:, :L]
 
-    # dkv grid: (BH, nk, nq) — q innermost; index maps swap accordingly
-    kspec_k = pl.BlockSpec((1, bk, D), lambda bh, i, j: (bh, i, 0))
-    vspec_k = pl.BlockSpec((1, bk, Dv), lambda bh, i, j: (bh, i, 0))
-    qspec_k = pl.BlockSpec((1, bq, D), lambda bh, i, j: (bh, j, 0))
-    dospec_k = pl.BlockSpec((1, bq, Dv), lambda bh, i, j: (bh, j, 0))
-    rspec_k = pl.BlockSpec((1, bq, _LANES), lambda bh, i, j: (bh, j, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, num_q=nq, **common),
-        grid=(B * H, nk, nq),
-        in_specs=[kspec_k, vspec_k, qspec_k, dospec_k, rspec_k, rspec_k],
-        out_specs=[kspec_k, vspec_k],
-        out_shape=[jax.ShapeDtypeStruct((B * H, Lkp, D), jnp.float32),
-                   jax.ShapeDtypeStruct((B * H, Lkp, Dv), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, Dv), jnp.float32)],
-        interpret=interpret,
-    )(kh, vh, qh, doh, lseh, deltah)
-
-    def back(x, L, Lp):
-        return x.reshape(B, H, Lp, x.shape[-1]).transpose(0, 2, 1, 3)[:, :L]
-
-    return back(dq, Lq, Lqp), back(dk, Lk, Lkp), back(dv, Lk, Lkp)
+    return back(dq, Lq), back(dk, Lk), back(dv, Lk)
 
 
 def fused_attention_supported() -> bool:
@@ -392,6 +589,12 @@ def _dense(q, k, v, causal):
         v.astype(jnp.float32), causal=causal).astype(q.dtype)
 
 
+# the scope every instruction of attention's core sits under, kernels and
+# the pads and reshapes around them alike (telemetry/layers.SCOPES;
+# `attention_ms` reads it)
+_SCOPE = "attn/core"
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def fused_attention(q, k, v, causal: bool = False,
                     interpret: bool = False):
@@ -401,31 +604,31 @@ def fused_attention(q, k, v, causal: bool = False,
     kernel path BOTH directions are flash: the backward recomputes p
     per tile from the forward's saved logsumexp, so the [L, L] score
     matrix never exists in HBM forward or backward."""
-    if interpret or fused_attention_supported():
-        return flash_attention(q, k, v, causal=causal,
-                               interpret=interpret)
-    return _dense(q, k, v, causal)
+    with profile_scope(_SCOPE, "kernel"):
+        if interpret or fused_attention_supported():
+            return flash_attention(q, k, v, causal=causal,
+                                   interpret=interpret)
+        return _dense(q, k, v, causal)
 
 
 def _fused_fwd(q, k, v, causal, interpret):
-    if interpret or fused_attention_supported():
-        out, lse = flash_attention_with_lse(q, k, v, causal=causal,
-                                            interpret=interpret)
-        return out, (q, k, v, out, lse)
-    return _dense(q, k, v, causal), (q, k, v, None, None)
+    with profile_scope(_SCOPE, "kernel"):
+        if interpret or fused_attention_supported():
+            out, lse = flash_attention_with_lse(q, k, v, causal=causal,
+                                                interpret=interpret)
+            return out, (q, k, v, out, lse)
+        return _dense(q, k, v, causal), (q, k, v, None, None)
 
 
 def _fused_bwd(causal, interpret, res, g):
     q, k, v, out, lse = res
-    if lse is not None:  # kernel path: flash backward
-        dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, g,
-                                         causal=causal,
-                                         interpret=interpret)
-        return (dq.astype(q.dtype), dk.astype(k.dtype),
-                dv.astype(v.dtype))
-    _, vjp = jax.vjp(lambda q_, k_, v_: _dense(q_, k_, v_, causal),
-                     q, k, v)
-    return vjp(g)
+    with profile_scope(_SCOPE, "kernel"):
+        if lse is not None:  # kernel path: flash backward
+            return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
+                                       interpret=interpret)
+        _, vjp = jax.vjp(lambda q_, k_, v_: _dense(q_, k_, v_, causal),
+                         q, k, v)
+        return vjp(g)
 
 
 fused_attention.defvjp(_fused_fwd, _fused_bwd)

@@ -30,12 +30,11 @@ from geomx_tpu.parallel.ring_attention import _block
 
 def _fused_block_aligned(seq_len: int) -> bool:
     """Mirror of ring_attention's hop-block gate for the post-all_to_all
-    full sequence: the flash kernel tiles the (padded) sequence in
-    blocks of ``min(128, L)``, and Mosaic needs that block sublane-
-    aligned (f32 tile = 8 sublanes).  L >= 128 always tiles at 128;
-    shorter sequences pass only when the padded block (= L itself) is
-    8-aligned — otherwise the jnp streaming path, which works for any
-    shape, must serve."""
+    full sequence: sequences of 128 and more always pass; shorter ones
+    only when 8-aligned (the f32 tile's sublanes), else the jnp streaming
+    path serves.  The flash kernels themselves now pad any length to
+    whole 128-row blocks (`ops.flash_attention.attention_plan`), so this
+    gate only keeps the two paths' split where it was."""
     return min(128, seq_len) % 8 == 0
 
 

@@ -20,6 +20,8 @@ The vocabulary (docs/telemetry.md has the operator's table):
 - ``collective/worker``, ``collective/dc``: the tier collectives;
 - ``kda/*``, ``mla/*``, ``moe/*``, ``lm/loss``: a decoder's layers inside
   ``step/forward_backward`` (models/kimi_linear.py);
+- ``attn/core``: the attention kernels and what surrounds them
+  (ops/flash_attention.fused_attention), forward and backward;
 - ``train/step``, ``fit/*``, ``loader/*``: host spans of the loop.
 """
 
@@ -47,6 +49,10 @@ SCOPES = (
     ("moe/experts", "step program"),
     ("moe/shared", "step program"),
     ("lm/loss", "step program"),
+    # attention's core, forward and backward, opened by
+    # ops/flash_attention.fused_attention; in a decoder it nests inside
+    # mla/attention
+    ("attn/core", "kernels"),
     ("step/optimizer", "step program"),
     ("step/metrics", "step program"),
     ("step/sync_grads", "sync algorithm"),

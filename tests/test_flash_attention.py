@@ -16,20 +16,26 @@ from geomx_tpu.ops.flash_attention import flash_attention, fused_attention
 from geomx_tpu.parallel.ring_attention import full_attention_reference
 
 
-@pytest.mark.parametrize("shape,causal", [
-    ((2, 64, 4, 32), False),
-    ((2, 64, 4, 32), True),
-    ((1, 100, 2, 16), True),    # ragged L: padded keys must be masked
-    ((2, 128, 4, 64), False),
-    ((1, 16, 1, 8), True),      # L smaller than the default block
+@pytest.mark.parametrize("shape,causal,blocks", [
+    ((2, 64, 4, 32), False, (32, 32)),
+    ((2, 64, 4, 32), True, (32, 32)),
+    ((1, 100, 2, 16), True, (32, 32)),  # ragged L: padded keys masked
+    ((2, 128, 4, 64), False, (32, 32)),
+    ((1, 16, 1, 8), True, (32, 32)),    # L smaller than the given block
+    # the plan's own blocks (None): the BERT cells' layer, one block pair
+    # and two heads a step in float32; a padded length, one masked pair;
+    # 640 = 5 blocks of 128, pairs that cross, touch and miss the diagonal
+    ((1, 512, 16, 64), False, (None, None)),
+    ((2, 300, 2, 64), True, (None, None)),
+    ((1, 640, 4, 32), True, (None, None)),
 ])
-def test_forward_matches_dense_reference(shape, causal):
+def test_forward_matches_dense_reference(shape, causal, blocks):
     rng = np.random.RandomState(0)
     q, k, v = (jnp.asarray(rng.normal(size=shape).astype(np.float32))
                for _ in range(3))
     ref = full_attention_reference(q, k, v, causal=causal)
-    out = flash_attention(q, k, v, causal=causal, block_q=32, block_k=32,
-                          interpret=True)
+    out = flash_attention(q, k, v, causal=causal, block_q=blocks[0],
+                          block_k=blocks[1], interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-6, rtol=1e-5)
 
@@ -80,42 +86,80 @@ def test_gradients_match_dense_reference():
                                    atol=1e-5, rtol=1e-5)
 
 
-def test_fully_masked_rows_are_zero_not_nan():
+@pytest.mark.parametrize("block", [16, None])
+def test_fully_masked_rows_are_zero_not_nan(block):
     """Causal row 0 with kv padding: a row whose only unmasked key is
     itself still normalizes; rows past kv_len see only padding and must
-    produce 0, never NaN (the -inf-minus--inf trap)."""
+    produce 0, never NaN (the -inf-minus--inf trap).  The padded rows'
+    lse (NEG_INF) must not reach the gradients either."""
+    from geomx_tpu.ops.flash_attention import (flash_attention_bwd,
+                                               flash_attention_with_lse)
     rng = np.random.RandomState(4)
     q, k, v = (jnp.asarray(rng.normal(size=(1, 20, 1, 8))
                            .astype(np.float32)) for _ in range(3))
-    out = flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
-                          interpret=True)
+    out, lse = flash_attention_with_lse(q, k, v, causal=True, block_q=block,
+                                        block_k=block, interpret=True)
     assert not bool(jnp.any(jnp.isnan(out)))
+    np.testing.assert_allclose(np.asarray(out[:, 0]), np.asarray(v[:, 0]),
+                               atol=1e-6)   # row 0 sees key 0 alone
+    for grad in flash_attention_bwd(q, k, v, out, lse, out, causal=True,
+                                    block_q=block, block_k=block,
+                                    interpret=True):
+        assert bool(jnp.all(jnp.isfinite(grad)))
 
 
-def test_lowers_to_tpu_mosaic_without_a_device():
+# (B, L, H, D, Dv, dtype, causal): the Mosaic-lowering tests' sizes
+LOWERED = {
+    "f32-L256": (2, 256, 4, 64, 64, jnp.float32, True),
+    # the BERT cells' layer: 16 x 512 x 16 x 64, bf16, four heads a step
+    "bf16-bert": (16, 512, 16, 64, 64, jnp.bfloat16, False),
+    # the decoder's latent attention: one sequence of 8,192, 192/128 heads
+    "bf16-latent-L8192": (1, 8192, 32, 192, 128, jnp.bfloat16, True),
+}
+
+
+def _lowered_shapes(case):
+    b, length, h, d, dv, dtype, causal = LOWERED[case]
+    qk = jax.ShapeDtypeStruct((b, length, h, d), dtype)
+    v = jax.ShapeDtypeStruct((b, length, h, dv), dtype)
+    lse = jax.ShapeDtypeStruct((b, h, length), jnp.float32)
+    return qk, v, lse, causal
+
+
+@pytest.mark.parametrize("case", sorted(LOWERED))
+def test_lowers_to_tpu_mosaic_without_a_device(case):
     """Cross-platform export runs the Pallas->Mosaic lowering pass for
     the TPU target on any host — catching tiling/shape rejections (1-D
     scratch, iota rank, pl.when predicates) without TPU hardware.  Only
     Mosaic->binary compilation remains device-side."""
     from jax import export as jax_export
-
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.normal(size=(2, 256, 4, 64)), jnp.float32)
+    qk, v, _, causal = _lowered_shapes(case)
 
     def f(q, k, v):
-        return flash_attention(q, k, v, causal=True)
+        return flash_attention(q, k, v, causal=causal)
 
-    exp = jax_export.export(jax.jit(f), platforms=("tpu",))(q, q, q)
+    exp = jax_export.export(jax.jit(f), platforms=("tpu",))(qk, qk, v)
     assert "tpu_custom_call" in exp.mlir_module()
 
 
-@pytest.mark.parametrize("shape,causal", [
-    ((2, 64, 4, 32), False),
-    ((2, 64, 4, 32), True),
-    ((1, 100, 2, 16), True),    # ragged L: padded q rows and k cols
-    ((1, 96, 2, 16), True),     # several tiles both directions
+@pytest.mark.parametrize("shape,causal,blocks", [
+    ((2, 64, 4, 32), False, (32, 32)),
+    ((2, 64, 4, 32), True, (32, 32)),
+    ((1, 100, 2, 16), True, (32, 32)),  # ragged L: padded q rows, k cols
+    ((1, 96, 2, 16), True, (32, 32)),   # several tiles both directions
+    # the plan's own blocks: the BERT cells' layer (ONE backward kernel,
+    # delta = rowsum(P dP) inside it), the same causal, and a padded length
+    ((1, 512, 16, 64), False, (None, None)),
+    ((1, 512, 4, 64), True, (None, None)),
+    ((2, 300, 2, 64), True, (None, None)),
+    # an 8k-style plan at a small size: q blocks of 128 against k blocks
+    # of 64, so pairs cross, touch and miss the diagonal, and 320 = 2.5
+    # q blocks pads the last one; then 5 x 5 blocks of the plan's own
+    ((1, 320, 2, 32), True, (128, 64)),
+    ((1, 640, 4, 32), True, (None, None)),
+    ((1, 640, 4, 32), False, (None, None)),
 ])
-def test_flash_backward_matches_dense_vjp(shape, causal):
+def test_flash_backward_matches_dense_vjp(shape, causal, blocks):
     """flash_attention_bwd (tile-recompute from the saved lse) against
     the dense reference's vjp, for an arbitrary cotangent."""
     from geomx_tpu.ops.flash_attention import (flash_attention_bwd,
@@ -127,11 +171,12 @@ def test_flash_backward_matches_dense_vjp(shape, causal):
     g = jnp.asarray(rng.normal(size=shape).astype(np.float32))
 
     out, lse = flash_attention_with_lse(q, k, v, causal=causal,
-                                        block_q=32, block_k=32,
+                                        block_q=blocks[0], block_k=blocks[1],
                                         interpret=True)
     dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                                     block_q=32, block_k=32,
+                                     block_q=blocks[0], block_k=blocks[1],
                                      interpret=True)
+    assert dq.dtype == q.dtype and dk.dtype == k.dtype
 
     def dense(q, k, v):
         return full_attention_reference(q, k, v, causal=causal)
@@ -146,20 +191,18 @@ def test_flash_backward_matches_dense_vjp(shape, causal):
                                atol=2e-5, rtol=2e-5)
 
 
-def test_flash_backward_lowers_to_tpu_mosaic_without_a_device():
+@pytest.mark.parametrize("case", sorted(LOWERED))
+def test_flash_backward_lowers_to_tpu_mosaic_without_a_device(case):
     from jax import export as jax_export
 
-    from geomx_tpu.ops.flash_attention import (flash_attention_bwd,
-                                               flash_attention_with_lse)
+    from geomx_tpu.ops.flash_attention import flash_attention_bwd
+    qk, v, lse, causal = _lowered_shapes(case)
 
-    rng = np.random.RandomState(13)
-    q = jnp.asarray(rng.normal(size=(2, 256, 4, 64)), jnp.float32)
+    def f(q, k, v, out, lse, g):
+        return flash_attention_bwd(q, k, v, out, lse, g, causal=causal)
 
-    def f(q, k, v, g):
-        out, lse = flash_attention_with_lse(q, k, v, causal=True)
-        return flash_attention_bwd(q, k, v, out, lse, g, causal=True)
-
-    exp = jax_export.export(jax.jit(f), platforms=("tpu",))(q, q, q, q)
+    exp = jax_export.export(jax.jit(f), platforms=("tpu",))(
+        qk, qk, v, v, lse, v)
     assert "tpu_custom_call" in exp.mlir_module()
 
 
@@ -170,28 +213,32 @@ def _latent_inputs(seed, b, length, h, d_qk, d_v):
     return draw(d_qk), draw(d_qk), draw(d_v), draw(d_v)
 
 
-@pytest.mark.parametrize("length,d_qk,d_v", [
-    (64, 192, 128),     # latent attention's head sizes
-    (100, 24, 16),      # ragged L with unequal sizes
-    (64, 16, 32),       # v wider than q and k
+@pytest.mark.parametrize("length,d_qk,d_v,block", [
+    (64, 192, 128, 32),     # latent attention's head sizes
+    (100, 24, 16, 32),      # ragged L with unequal sizes
+    (64, 16, 32, 32),       # v wider than q and k
+    # the decoder's plan at a small size: blocks of the plan's own (5 x 5
+    # of 128, the last padded), two 192-wide heads a step in three lane
+    # tiles of which the middle one is shared, 128-wide v
+    (600, 192, 128, None),
 ])
 def test_unequal_head_sizes_forward_and_both_backward_kernels(length, d_qk,
-                                                              d_v):
+                                                              d_v, block):
     """q and k one head size, v (and so out and dO) another, causal: the
     forward, the dq kernel and the dk/dv kernel against the dense
     reference's values and vjp."""
     from geomx_tpu.ops.flash_attention import (flash_attention_bwd,
                                                flash_attention_with_lse)
     q, k, v, g = _latent_inputs(21, 1, length, 2, d_qk, d_v)
-    out, lse = flash_attention_with_lse(q, k, v, causal=True, block_q=32,
-                                        block_k=32, interpret=True)
+    out, lse = flash_attention_with_lse(q, k, v, causal=True, block_q=block,
+                                        block_k=block, interpret=True)
     assert out.shape == v.shape
     dense = lambda q, k, v: full_attention_reference(q, k, v, causal=True)
     ref, vjp = jax.vjp(dense, q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-6, rtol=1e-5)
     grads = flash_attention_bwd(q, k, v, out, lse, g, causal=True,
-                                block_q=32, block_k=32, interpret=True)
+                                block_q=block, block_k=block, interpret=True)
     for got, want in zip(grads, vjp(g)):
         assert got.shape == want.shape
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -207,3 +254,138 @@ def test_unequal_head_sizes_through_fused_attention_gradients():
                          jax.grad(dense, argnums=(0, 1, 2))(q, k, v)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
+
+
+# bf16 has 8 bits of mantissa: rounding to nearest is off by at most
+# u = 2^-9 of the value.  On a bf16 call every product takes bf16 operands
+# and sums in float32, so against the dense float32 form ON THE SAME
+# ROUNDED INPUTS a result differs by its roundings alone, at most four on
+# any path: `p` (into P V and P^T dO) or `ds` (into dS K and dS^T Q), `out`
+# inside delta where delta is rowsum(dO O), and the result's own cast.
+# Each is at most u of its term; the terms of a row's sum have mixed signs,
+# so the sum of their errors is held to twice the largest magnitude's
+# share: 4 roundings x 2 x u = 2^-6 of the reference's largest value.
+# Readings: 1.3e-3 to 4.8e-3.  Rounding an operand to 4 bits less (u = 2^-5)
+# reads 3e-2 and more, so the bound still tells precisions apart.
+BF16_TOLERANCE = 2.0 ** -6
+
+
+@pytest.mark.parametrize("b,length,h,d_qk,d_v,causal,blocks", [
+    # the BERT cells' layer, four heads a step, one backward kernel
+    (1, 512, 16, 64, 64, False, (None, None)),
+    (1, 512, 4, 64, 64, True, (None, None)),
+    # the decoder's plan at 1,024: 2 x 2 blocks of 512, two backward kernels
+    (1, 1024, 2, 192, 128, True, (None, None)),
+    # given blocks, honoured: pairs that cross, touch and miss the diagonal
+    (2, 320, 4, 32, 32, True, (128, 64)),
+], ids=["bert-layer", "bert-layer-causal", "latent-1024", "given-blocks"])
+def test_bf16_operands_p_and_ds_rounded_against_dense_on_rounded_inputs(
+        b, length, h, d_qk, d_v, causal, blocks):
+    from geomx_tpu.ops.flash_attention import (flash_attention_bwd,
+                                               flash_attention_with_lse)
+    rounded = [x.astype(jnp.bfloat16)
+               for x in _latent_inputs(31, b, length, h, d_qk, d_v)]
+    q, k, v, g = rounded
+    out, lse = flash_attention_with_lse(q, k, v, causal=causal,
+                                        block_q=blocks[0], block_k=blocks[1],
+                                        interpret=True)
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    grads = flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
+                                block_q=blocks[0], block_k=blocks[1],
+                                interpret=True)
+    assert [x.dtype for x in grads] == [jnp.bfloat16] * 3
+    f32 = lambda x: x.astype(jnp.float32)
+    ref, vjp = jax.vjp(
+        lambda q, k, v: full_attention_reference(q, k, v, causal=causal),
+        f32(q), f32(k), f32(v))
+    for got, want in zip((out, *grads), (ref, *vjp(f32(g)))):
+        gap = float(jnp.max(jnp.abs(f32(got) - want)) / jnp.max(jnp.abs(want)))
+        assert gap <= BF16_TOLERANCE, gap
+
+
+@pytest.mark.parametrize("args,want", [
+    # the BERT cells' layer: the whole sequence one block pair, four heads
+    # (two 128-lane tiles) a step, one backward kernel
+    ((512, 512, 16, 64, 64, jnp.bfloat16, False),
+     (512, 512, 4, True, 10_485_760)),
+    # the decoder's latent attention: 16 x 16 blocks of 512 (136 causal
+    # pairs), two 192-wide heads a step, dq and dk/dv kernels
+    ((8192, 8192, 32, 192, 128, jnp.bfloat16, True),
+     (512, 512, 2, False, 10_485_760)),
+    # the timing tool's third shape
+    ((2048, 2048, 16, 64, 64, jnp.bfloat16, False),
+     (512, 512, 4, False, 9_437_184)),
+    # float32 operands are twice as wide: half the heads a step
+    ((512, 512, 16, 64, 64, jnp.float32, False),
+     (512, 512, 2, True, 10_747_904)),
+    # nothing fits the budget: the smallest lane-aligned slab
+    ((8192, 8192, 32, 192, 128, jnp.float32, True),
+     (512, 512, 2, False, 15_466_496)),
+    # a short ragged length is padded to whole lanes; a slab that cannot be
+    # lane-aligned takes every head
+    ((100, 100, 2, 24, 16, jnp.float32, True), (128, 128, 2, True, 753_664)),
+    # 640 = 5 x 128: the largest of 512, 256, 128 that divides it
+    ((640, 640, 4, 32, 32, jnp.float32, True), (128, 128, 4, False, 1_310_720)),
+], ids=["bert-bf16", "latent-bf16", "mid-bf16", "bert-f32", "latent-f32",
+        "ragged", "five-blocks"])
+def test_attention_plan_from_the_shapes(args, want):
+    from geomx_tpu.ops.flash_attention import (VMEM_BUDGET, AttentionPlan,
+                                               attention_plan)
+    plan = attention_plan(*args)
+    assert plan == AttentionPlan(*want)
+    # only float32 at 192/128 passes the budget (its smallest slab)
+    assert (plan.vmem_bytes > VMEM_BUDGET) == (want[4] == 15_466_496)
+    assert args[2] % plan.heads == 0
+
+
+@pytest.mark.parametrize("given", [(32, 64), (128, None), (None, 256)])
+def test_attention_plan_honours_given_blocks(given):
+    from geomx_tpu.ops.flash_attention import attention_plan
+    plan = attention_plan(1024, 1024, 8, 64, 64, jnp.bfloat16, True, *given)
+    assert plan.block_q == (given[0] or 512)
+    assert plan.block_k == (given[1] or 512)
+    assert not plan.fused_backward
+
+
+@pytest.mark.parametrize("causal,want", [
+    # q-major with k inner: (q block, k block, first + 2 last + 4 masked)
+    (False, [(0, 0, 1), (0, 1, 2), (1, 0, 1), (1, 1, 2)]),
+    (True, [(0, 0, 7), (1, 0, 1), (1, 1, 6)]),
+])
+def test_block_pairs_walk_only_what_holds_a_score(causal, want):
+    from geomx_tpu.ops.flash_attention import _block_pairs
+    (qi, kj, flags), bodies = _block_pairs(2, 2, 128, 128, 256, 256, causal,
+                                           k_inner=True)
+    assert list(zip(qi.tolist(), kj.tolist(), flags.tolist())) == want
+    assert bodies == ([False, True] if causal else [False])
+    # k-major for dk/dv: the diagonal pair opens each run; a padded length
+    # marks the last row and column of pairs
+    (qi, kj, flags), _ = _block_pairs(2, 2, 128, 128, 200, 200, causal,
+                                      k_inner=False)
+    assert sorted(zip(kj.tolist(), qi.tolist())) == list(
+        zip(kj.tolist(), qi.tolist()))
+    assert all(f & 4 for i, j, f in zip(qi, kj, flags) if i == 1 or j == 1)
+
+
+def test_fused_attention_opens_attn_core_forward_and_backward():
+    """`attention_ms` reads scope `attn/core`: every instruction of the
+    forward and of the backward, kernels and what surrounds them, has to
+    sit under it in the compiled program (here the interpreted kernels'
+    loops), nested in whatever scope the model opened."""
+    from geomx_tpu.telemetry.layers import layer_of, op_layers
+    from geomx_tpu.utils.profiler import profile_scope
+    assert layer_of("attn/core") == "kernels"
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def step(q, k, v):
+        def loss(q, k, v):
+            with jax.named_scope("model"), profile_scope("mla/attention"):
+                return jnp.sum(fused_attention(q, k, v, True, True) ** 2)
+        with profile_scope("step/forward_backward"):
+            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(step).lower(q, q, q).compile().as_text()
+    found = {(entry.scope, entry.direction)
+             for entry in op_layers(text).values() if entry.scope}
+    nested = "step/forward_backward/mla/attention/attn/core"
+    assert (nested, "forward") in found and (nested, "backward") in found

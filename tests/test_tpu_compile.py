@@ -161,6 +161,22 @@ def _latent_bwd(L):
             [qk, qk, v, v, f32(1, 8, L), v])
 
 
+def _cell_attention(which, direction):
+    """The chip benchmark's own attention calls, bf16: a BERT-large layer
+    (16 x 512 x 16 x 64, four heads a step, one backward kernel) and one
+    sequence of the decoder's latent attention (8,192 x 32 x 192/128,
+    causal: 136 block pairs of 512, dq and dk/dv kernels)."""
+    from geomx_tpu.ops import flash_attention_bwd, flash_attention_with_lse
+    b, L, h, d, dv, causal = {"bert": (16, 512, 16, 64, 64, False),
+                              "latent": (1, 8192, 32, 192, 128, True)}[which]
+    bf16 = lambda e: jax.ShapeDtypeStruct((b, L, h, e), jnp.bfloat16)
+    if direction == "forward":
+        return (functools.partial(flash_attention_with_lse, causal=causal),
+                [bf16(d), bf16(d), bf16(dv)])
+    return (functools.partial(flash_attention_bwd, causal=causal),
+            [bf16(d), bf16(d), bf16(dv), bf16(dv), f32(b, h, L), bf16(dv)])
+
+
 def _ring_hop(L):
     from geomx_tpu.parallel._fused_block import _hop_pallas
     qkv, ml = f32(8, L, 64), f32(8, L)
@@ -213,6 +229,14 @@ CASES = {
     "flash_attention-latent-192-128-L8192": lambda: _latent_fwd(8192),
     "flash_attention_bwd-latent-192-128-L8192": lambda: _latent_bwd(8192),
     "flash_attention_bwd-latent-192-128-L100": lambda: _latent_bwd(100),
+    "flash_attention-bf16-bert-layer": lambda: _cell_attention(
+        "bert", "forward"),
+    "flash_attention_bwd-bf16-bert-layer": lambda: _cell_attention(
+        "bert", "backward"),
+    "flash_attention-bf16-latent-sequence": lambda: _cell_attention(
+        "latent", "forward"),
+    "flash_attention_bwd-bf16-latent-sequence": lambda: _cell_attention(
+        "latent", "backward"),
     "fused_ring_hop-L1024": lambda: _ring_hop(1024),
     "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
     "merge_tree-2x82": lambda: _merge(164, 1),
@@ -305,6 +329,30 @@ def test_select_pack_kernels_carry_the_name_the_benchmark_reads(chip):
         calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
                            r'"tpu_custom_call"', text)
         assert {c.split(".")[0] for c in calls} == want, calls
+
+
+@pytest.mark.parametrize("which,want", [
+    ("bert", {"flash_attention_fwd", "flash_attention_bwd"}),
+    ("latent", {"flash_attention_fwd", "flash_attention_bwd_dq",
+                "flash_attention_bwd_dkv"}),
+])
+def test_attention_kernels_carry_the_name_the_benchmark_reads(chip, which,
+                                                              want):
+    """`flash_attn_roofline_pct` finds the kernels by the prefix
+    `flash_attention` of their instruction names
+    (benchmark/layer_metrics/flash_attn_roofline_pct.PREFIXES): a kernel
+    under another name would leave the share's divisor short."""
+    import re
+    calls = []
+    for direction in ("forward", "backward"):
+        fn, shapes = _cell_attention(which, direction)
+        args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
+                for s in shapes]
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        calls += re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                            r'"tpu_custom_call"', text)
+    assert {c.split(".")[0] for c in calls} == want, calls
+    assert all(c.startswith("flash_attention") for c in calls)
 
 
 def test_the_bucket_allreduce_gets_the_kernels_through_the_door(chip):
