@@ -1,7 +1,8 @@
 """A causal decoder of Kimi-Linear blocks: Kimi Delta Attention (KDA,
-`ops/kda.py`) and latent attention (MLA, through `ops/flash_attention.py`
-with 192-wide q/k and 128-wide v) as mixers, a SwiGLU MLP or an expert
-layer that holds some of its experts (`ops/held_experts.py`) as feed-
+`ops/kda.py`; on a TPU the kernels of `ops/kda_pallas.py`) and latent
+attention (MLA, through `ops/flash_attention.py` with 192-wide q/k and
+128-wide v) as mixers, a SwiGLU MLP or an expert layer that holds some of
+its experts (`ops/held_experts.py`) as feed-
 forward, pre-RMSNorm residual blocks, an untied head.
 
     h += Mixer(RMSNorm(h));  h += FFN(RMSNorm(h))
@@ -35,9 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from geomx_tpu.ops import dispatch
 from geomx_tpu.ops.flash_attention import fused_attention
 from geomx_tpu.ops.held_experts import held_experts
-from geomx_tpu.ops.kda import kda_chunked
 from geomx_tpu.utils.profiler import profile_scope
 
 A_LOG_CENTRE = 1.96          # mean of log U(1, 16)
@@ -78,9 +79,9 @@ def causal_conv(x, kernel):
 class KDAMixer(nn.Module):
     """Parameters are stored as the published matrices ([hidden, heads x
     head], ...); the products write heads-major activations [B, H, L, e]
-    directly, the layout `kda_chunked` cuts into chunks without moving
-    data (a [L, H x e] -> [L, H, e] reshape of an activation is a copy on
-    a TPU: the tiled minor dimensions change)."""
+    directly, the layout the scan (`ops.dispatch.kda`) cuts into chunks
+    without moving data (a [L, H x e] -> [L, H, e] reshape of an
+    activation is a copy on a TPU: the tiled minor dimensions change)."""
     num_heads: int
     head_dim: int
     conv_size: int
@@ -125,8 +126,8 @@ class KDAMixer(nn.Module):
                 "bld,dh->bhl", x, mat("beta_kernel", (hidden, h)).astype(dt),
                 preferred_element_type=jnp.float32))
             gate = jax.nn.sigmoid(low("g"))
-        o = kda_chunked(q, k, v, g, beta, chunk=self.chunk, sub=self.sub,
-                        dtype=dt)
+        o = dispatch.kda(q, k, v, g, beta, chunk=self.chunk, sub=self.sub,
+                         dtype=dt)
         with profile_scope("kda/proj", "compute"):
             o = RMSNorm(self.eps, name="out_norm")(o) * gate
             out = mat("out_kernel", (width, hidden)).reshape(h, d, hidden)

@@ -23,8 +23,10 @@ makes one HBM round trip:
   the per-leaf optax chain on the hot path (``GEOMX_FUSED_OPTIM``).
 
 Two ops of a decoder's layers are imported from their modules:
-``kda.kda_chunked`` (the chunkwise gated delta rule with a per-channel
-decay; plain XLA) and ``held_experts.held_experts`` (the routed experts
+``dispatch.kda`` (the chunkwise gated delta rule with a per-channel
+decay: the kernel pair ``kda_pallas.kda_scan`` with the chunk-to-chunk
+state in VMEM, forward and backward, or its jnp form ``kda.kda_chunked``)
+and ``held_experts.held_experts`` (the routed experts
 one chip holds: the sorted assignments walked a pool at a time in a loop
 of as many trips as pools exist, the SwiGLU as JAX's megablox grouped
 products).

@@ -1,11 +1,12 @@
 """Kernel or XLA: the one place that decides, from the platform.
 
-Every op of the compression engine exists twice under ``ops/``: a Pallas
-kernel and a jnp form with the same results (bit for bit, except where an
-op's docstring says otherwise).  The kernel is what a TPU runs; the jnp
-form is the only path elsewhere and the oracle the tests hold the kernel
-to.  The functions below are what ``compression/`` calls: each picks its
-implementation while the program is traced, from :func:`kernel_mode`.
+Every op of the compression engine, and a decoder's KDA scan, exists
+twice under ``ops/``: a Pallas kernel and a jnp form with the same results
+(bit for bit, except where an op's docstring says otherwise).  The kernel
+is what a TPU runs; the jnp form is the only path elsewhere and the oracle
+the tests hold the kernel to.  The functions below are what
+``compression/`` and ``models/`` call: each picks its implementation
+while the program is traced, from :func:`kernel_mode`.
 Nothing above ``ops/`` (a compressor's arguments, the spec string,
 ``GeoConfig``, the environment) can choose, and nothing above it asks.
 
@@ -22,7 +23,8 @@ from typing import Optional
 
 import jax
 
-from geomx_tpu.ops import bsc_pallas, bucket_pallas, merge_pallas, twobit_pallas
+from geomx_tpu.ops import (bsc_pallas, bucket_pallas, kda as kda_jnp,
+                           kda_pallas, merge_pallas, twobit_pallas)
 
 _OVERRIDE: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "geomx_kernel_mode", default=None)
@@ -107,3 +109,18 @@ def merge_pairs(vals, idx, max_duplicates: int):
     return merge_pallas.merge_sorted_pairs(
         vals, idx, max_duplicates, fused=mode is not None,
         interpret=mode == "interpret")
+
+
+def kda(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
+        dtype=jax.numpy.float32):
+    """The chunked gated delta rule, heads-major (``ops/kda.kda_chunked``
+    says what the arguments are): the kernel pair of ``kda_pallas`` with
+    its own backward where :func:`kernel_mode` names one, else the jnp
+    form and JAX's backward.  Equal within the roundings of ``dtype``
+    (``tests/test_kda_kernel.py`` states them)."""
+    mode = kernel_mode()
+    if mode is None:
+        return kda_jnp.kda_chunked(q, k, v, g, beta, chunk=chunk, sub=sub,
+                                   dtype=dtype)
+    return kda_pallas.kda_scan(q, k, v, g, beta, chunk, sub, dtype,
+                               mode == "interpret")

@@ -24,8 +24,10 @@ factors <= 0, one matrix product), a block on the diagonal is summed
 channel by channel under its mask.
 
 The backward pass is JAX's own, through the scan; callers rematerialise
-(`jax.checkpoint`) the layer that holds the call.  Plain XLA: no Pallas
-kernel (PERF.md, PR 27, says what that costs).
+(`jax.checkpoint`) the layer that holds the call.  This is the jnp form:
+the only path off a TPU and the oracle of the Pallas kernel pair in
+`ops/kda_pallas.py`, which a TPU runs (`ops/dispatch.kda` decides;
+PERF.md, PR 31, says what plain XLA cost).
 """
 
 from __future__ import annotations

@@ -278,6 +278,33 @@ def test_v5e_compiler_accepts_the_kda_scan(chip, direction):
 
 
 @pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_kda_kernels(chip, direction):
+    """The scan's Pallas kernels (`ops/kda_pallas.py`) lowered native at
+    the chip benchmark's shape, one sequence of 8,192 tokens and all 32
+    heads of 128, bf16 operands, through the door `KDAMixer` calls: the
+    tiling of a chunk's slices, the sublane rolls of the decay pass, the
+    float32 products at `HIGHEST`, and the VMEM the plan asks of the
+    compiler (`vmem_limit_bytes`), forward and backward."""
+    from geomx_tpu.ops import dispatch, kda_pallas
+    wide = lambda dtype: jax.ShapeDtypeStruct((1, 32, 8192, 128), dtype,
+                                              sharding=chip)
+    args = [wide(jnp.float32), wide(jnp.float32), wide(jnp.bfloat16),
+            wide(jnp.float32),
+            jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32, sharding=chip)]
+    run = lambda *a: dispatch.kda(*a, chunk=64, sub=16, dtype=jnp.bfloat16)
+    if direction == "backward":
+        fn = jax.grad(lambda *a: jnp.sum(run(*a)), argnums=(0, 1, 2, 3, 4))
+    else:
+        fn = run
+    with dispatch.kernels("native"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and " while(" not in text
+    assert ("kda_scan_bwd" in text) == (direction == "backward")
+    plan = kda_pallas.kda_plan(8192, 32, 128, 128, 64, jnp.bfloat16)
+    assert (plan.heads, plan.chunks) == (4, 4)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
 def test_v5e_compiler_accepts_the_held_experts(chip, direction):
     """The held experts' walk at the chip benchmark's sizes (16,384 tokens
     of 2,304, top 8 of 256, 8 held experts of 1,024, bf16 operands): the
