@@ -194,7 +194,8 @@ def kernels_phase(seed: int, interpret: bool = False, model=None) -> dict:
     from geomx_tpu.compression.bucketing import GradientBucketer
     from geomx_tpu.ops import (dequantize_2bit, fused_adam,
                                fused_sgd_momentum, quantize_2bit)
-    from geomx_tpu.ops.bsc_pallas import (bsc_scatter_add, bsc_select_pack,
+    from geomx_tpu.ops.bsc_pallas import (bsc_sampled_boundary,
+                                          bsc_scatter_add, bsc_select_pack,
                                           sampled_boundary_guv,
                                           scatter_add_ref, select_pack_ref)
     from geomx_tpu.ops.bucket_pallas import (flatten_ref, fused_flatten,
@@ -247,6 +248,18 @@ def kernels_phase(seed: int, interpret: bool = False, model=None) -> dict:
     u = jnp.asarray(rng.normal(0, 0.1, n).astype(np.float32))
     v = jnp.asarray(rng.normal(0, 0.2, n).astype(np.float32))
     g2 = jnp.asarray(rng.normal(0, 1, n).astype(np.float32))
+
+    # the boundary both compress with: the streamed probe (what the
+    # engine's door takes at this size on a TPU) against the gathers
+    def boundary_with(probe):
+        return jax.jit(lambda g, u, v: probe(g, u, v, k))
+
+    for name, grad in (("bsc_sampled_boundary", g),
+                       ("bsc_sampled_boundary/2", g2)):
+        same(name,
+             boundary_with(functools.partial(
+                 bsc_sampled_boundary, interpret=interpret))(grad, u, v),
+             boundary_with(sampled_boundary_guv)(grad, u, v))
 
     def compress_with(select):
         return jax.jit(lambda g, u, v: select(

@@ -50,7 +50,7 @@ from jax import lax
 
 from geomx_tpu.compression.base import Compressor
 from geomx_tpu.ops import dispatch
-from geomx_tpu.ops.bsc_pallas import sampled_boundary_guv, select_pack_shape
+from geomx_tpu.ops.bsc_pallas import select_pack_shape
 from geomx_tpu.parallel.collectives import tier_scope
 from geomx_tpu.utils.profiler import profile_scope
 
@@ -151,7 +151,7 @@ class BiSparseCompressor(Compressor):
             eff_k = jnp.clip(jnp.round(k * scale), 1.0,
                              float(k)).astype(jnp.int32)
         with profile_scope("compress/boundary", category="kernel"):
-            thr = sampled_boundary_guv(g_flat, u, v, eff_k)
+            thr = dispatch.sampled_boundary(g_flat, u, v, eff_k)
         tiles, out_blocks, _ = select_pack_shape(n, k)
         with profile_scope("bsc/select_pack", category="kernel",
                            args={"n": n, "k": k, "tiles": tiles,

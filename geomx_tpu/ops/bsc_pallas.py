@@ -1,8 +1,15 @@
 """Fused Bi-Sparse (BSC) compression Pallas kernels.
 
-Two kernels replace the dc-tier sparse hot path that a builder's capture
+Three kernels replace the dc-tier sparse hot path that a builder's capture
 (BENCH_CAPTURED_r05) showed costing more chip time than the wire bytes
 it saved:
+
+``bsc_boundary_probe``
+    The boundary's samples: the momentum-corrected magnitudes at the
+    probe's static positions, taken while g, u and v stream by once, in
+    place of three XLA gathers that pay 8.6 ns an index whatever the
+    bucket's size (0.21 ms a bucket, 22.7 ms of a 214.7 ms BERT-large
+    step: PERF.md, PR 32).  Bit-exact with the gathered samples.
 
 ``bsc_select_pack``
     Computes the DGC-style momentum correction ``u' = 0.9*u + g; v' = v +
@@ -65,7 +72,38 @@ VMEM budget (placing pass): 3 input + 2 output [256,128] fp32 tiles and
 two [64,128] output blocks per grid step, double-buffered (~1.4 MB), the
 [392,128] value and index frames (~0.4 MB) and a few frame-sized
 temporaries of the compaction.  The visit lists and first slots are int32
-in SMEM: 4 x (5 tiles + 2 out_blocks) bytes.
+in SMEM: 4 x (5 tiles + 2 out_blocks) bytes.  Boundary probe: 3 input
+[256,128] fp32 tiles, double-buffered (0.8 MB), the position and sample
+slabs (2 x 32 KB, resident), the tile's magnitudes and their three bf16
+pieces (0.3 MB) and one [256,128] product: under 1.5 MB.  Two int32 a tile
+in SMEM.
+
+Algorithm notes (boundary probe).  The positions are static
+(``sampled_topk.sample_positions``), so the host sorts them at trace time
+and the kernel gets them as one [m / 128, 128] int32 slab, ascending, that
+stays in VMEM beside the [m / 128, 128] float32 slab of samples it fills:
+sample s of the sorted order lands at row s // 128, lane s % 128 (position
+order, not Weyl order; the sort that follows makes that invisible).  A 1-D
+grid walks the bucket in the select/pack's tiles.  A step computes ``|v +
+(u * MOMENTUM + g)|`` on its tile, in the order of operations of
+``sampled_boundary_guv``'s line, and then visits the slab rows that hold
+one of its positions (first row and count are scalar-prefetch lists: 1 or
+2 rows a tile at 4 M elements, 3 at 1 M, all 64 for a bucket of one tile).
+A visit is the decompress's one-hot product the other way round: the
+row's 128 positions give a [128, 128] one-hot of their lanes, ``tile @
+one-hot`` brings each position's lane to its slab lane in every tile row,
+a row mask and a sublane sum pick the tile row.  The tile goes through
+the MXU as three bf16 pieces that sum to it exactly (``x = hi + mid +
+lo``, 8 + 8 + 8 significand bits, split once a tile; the one-hot is exact
+in bf16), three passes where ``Precision.HIGHEST`` would make six and
+split again at every visit; each product is one value times 1.0 plus
+zeros, and ``(hi + mid) + lo`` is exact in float32.  Mosaic loads and
+stores no single row at a dynamic, unaligned sublane, so a slab row is
+read and merged through the aligned window of eight rows that holds it.
+Cost: the bucket's 12 n bytes once and one grid step a tile, so above
+``_PROBE_GATHER_ABOVE`` elements the three gathers are cheaper and stay
+(``bsc_sampled_boundary``); a bucket no larger than the probe IS its
+sample and needs no fetch at all.
 
 Algorithm notes (decompress).  The output is cut into blocks of
 ``_OUT_ROWS`` x 128 elements and the pairs, sorted by index (one
@@ -111,6 +149,13 @@ _PAIR_ROWS = 64                    # select/pack: output rows per block
 _CHUNK = 512                       # (value, index) pairs per decompress step
 _OUT_ROWS = 128                    # dense output rows per decompress block
 _SENTINEL_KEY = 2 ** 31 - 1        # a sentinel pair's sort key: after every index
+# boundary probe: above this many elements three gathers of 8,192 indices
+# cost less than streaming the bucket's 12 n bytes.  tools/
+# boundary_timing.py on a v5e (PERF.md section 5, PR 32): gathers 0.186 ms
+# a call at 4,194,304 and at 8,388,608 elements alike, the kernel 0.078
+# and 0.310 (past ~4 M the three operands no longer stay in fast memory
+# and a tile costs twice as much); between them the lines cross near 5.9 M
+_PROBE_GATHER_ABOVE = 6 * 1024 * 1024
 
 
 def sampled_boundary_guv(g: jax.Array, u: jax.Array, v: jax.Array, k,
@@ -132,6 +177,141 @@ def sampled_boundary_guv(g: jax.Array, u: jax.Array, v: jax.Array, k,
     m = samp.shape[0]
     ssorted = jnp.sort(samp)
     return ssorted[boundary_position(m, k, n)]
+
+
+@functools.lru_cache(maxsize=None)
+def probe_plan(n: int, sample: int = 8192):
+    """The probe kernel's static schedule for a bucket of ``n`` elements:
+    ``(positions, first_row, row_count)``.  ``positions`` is the probe's
+    positions ascending, int32 [m / 128, 128]; a tile's are consecutive
+    there, so it fills slab rows ``first_row[t]`` to ``first_row[t] +
+    row_count[t] - 1`` (a row two tiles share is visited by both; a tile
+    that holds no position visits none)."""
+    import numpy as np
+    from geomx_tpu.ops.sampled_topk import sample_positions
+
+    pos = np.sort(sample_positions(n, sample))
+    tile = pos // _TILE
+    tiles = -(-n // _TILE)
+    first = np.zeros((tiles,), np.int32)
+    count = np.zeros((tiles,), np.int32)
+    held = np.unique(tile)
+    lo = np.searchsorted(tile, held, side="left") // _LANES
+    hi = (np.searchsorted(tile, held, side="right") - 1) // _LANES
+    first[held], count[held] = lo, hi - lo + 1
+    return pos.reshape(-1, _LANES).astype(np.int32), first, count
+
+
+def _probe_kernel(n, first_ref, count_ref, pos_ref, g_ref, u_ref, v_ref,
+                  out_ref):
+    """One tile of the boundary probe: its momentum-corrected magnitudes,
+    then one visit for every slab row that holds one of its positions (see
+    the module docstring)."""
+    import jax.experimental.pallas as pl
+
+    t = pl.program_id(0)
+    rows = g_ref.shape[0]
+    x = jnp.abs(v_ref[:] + (u_ref[:] * MOMENTUM + g_ref[:]))
+    if n % (rows * _LANES):
+        # no position is >= n, but the products below read the whole
+        # tile and 0 x whatever hangs over the end need not be 0
+        x = jnp.where(t * _TILE + _local_index(rows) < n, x, 0.0)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    lane_of = jax.lax.broadcasted_iota(jnp.int32, (_LANES, _LANES), 0)
+    row_of = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0)
+    sub_of = jax.lax.broadcasted_iota(jnp.int32, (_BLK_ROWS, _LANES), 0)
+
+    def to_slab_lanes(piece, pick):
+        return jax.lax.dot_general(piece, pick, (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+
+    def visit(i, carry):
+        r = first_ref[t] + i
+        window = pl.ds(pl.multiple_of(r // _BLK_ROWS * _BLK_ROWS, _BLK_ROWS),
+                       _BLK_ROWS)
+        mine = sub_of == r % _BLK_ROWS
+        local = pos_ref[window, :] - t * _TILE
+        local = jnp.where(mine & (local >= 0) & (local < _TILE), local, -1)
+        # slab row r alone, as [1, 128]: every other entry is -1, so the
+        # maximum is it (in float32, exact below 2**24: Mosaic reduces no
+        # integers); -1 where the position is another tile's
+        loc = jnp.max(local.astype(jnp.float32), axis=0,
+                      keepdims=True).astype(jnp.int32)
+        pick = (lane_of == (loc & (_LANES - 1))).astype(jnp.bfloat16)
+        inrow = ((to_slab_lanes(hi, pick) + to_slab_lanes(mid, pick))
+                 + to_slab_lanes(lo, pick))
+        z = jnp.sum(jnp.where(row_of == (loc >> 7), inrow, 0.0),
+                    axis=0, keepdims=True)
+        out_ref[window, :] = jnp.where(mine & (loc >= 0), z,
+                                       out_ref[window, :])
+        return carry
+
+    jax.lax.fori_loop(0, count_ref[t], visit, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("sample", "interpret"))
+def bsc_boundary_probe(g: jax.Array, u: jax.Array, v: jax.Array,
+                       sample: int = 8192, interpret: bool = False):
+    """The boundary's ``sample`` samples of a bucket larger than that,
+    ``|v + (u * MOMENTUM + g)|`` at ``sample_positions(n, sample)``, in
+    ascending order of position, from one streamed pass over g, u and v.
+    Each equals the gathered sample bit for bit where the 128-element row
+    around it is finite: the one-hot products make 0 x inf a NaN, so a
+    non-finite element (or one above 3.39e38, bf16's largest) turns the
+    samples of its own row to NaN, where the gathers let only a sampled
+    element reach the boundary.  A magnitude under 2**-102 has a bf16
+    piece that is subnormal, which the chip flushes to 0."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n = g.shape[0]
+    pos, first, count = probe_plan(n, sample)
+    tiles = first.shape[0]
+    operands = [_tile_rows(x, n, tiles) for x in (g, u, v)]
+    rows = min(_TILE_ROWS, operands[0].shape[0])
+    tile_spec = pl.BlockSpec((rows, _LANES), lambda t, first, count: (t, 0))
+    slab = pl.BlockSpec(pos.shape, lambda t, first, count: (0, 0))
+    out = pl.pallas_call(
+        functools.partial(_probe_kernel, n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(tiles,),
+            in_specs=[slab, tile_spec, tile_spec, tile_spec],
+            out_specs=slab,
+        ),
+        out_shape=jax.ShapeDtypeStruct(pos.shape, jnp.float32),
+        name="bsc_boundary_probe",
+        interpret=interpret,
+    )(jnp.asarray(first), jnp.asarray(count), jnp.asarray(pos), *operands)
+    return out.reshape(-1)
+
+
+def bsc_sampled_boundary(g: jax.Array, u: jax.Array, v: jax.Array, k,
+                         sample: int = 8192,
+                         gather_above: int = _PROBE_GATHER_ABOVE,
+                         interpret: bool = False):
+    """:func:`sampled_boundary_guv` on a TPU: the same float, the samples
+    fetched at what the bucket's size makes cheapest.  A bucket no larger
+    than the probe is its own sample (the positions are a permutation: the
+    multiplier is prime and larger than n): no fetch.  Up to
+    ``gather_above`` elements :func:`bsc_boundary_probe` streams the
+    bucket once; above it three gathers of ``sample`` indices cost less
+    than the bucket's bytes.  The sort, the quantile's position (``k``
+    static or traced) and the index are the jnp form's."""
+    from geomx_tpu.ops.sampled_topk import boundary_position
+
+    n = g.shape[0]
+    m = min(n, int(sample))
+    if n > gather_above or (m < n and m % _BLK):
+        return sampled_boundary_guv(g, u, v, k, sample)
+    if m == n:
+        samp = jnp.abs(v + (u * MOMENTUM + g))
+    else:
+        samp = bsc_boundary_probe(g, u, v, sample=sample, interpret=interpret)
+    return jnp.sort(samp)[boundary_position(m, k, n)]
 
 
 def select_pack_ref(g: jax.Array, u: jax.Array, v: jax.Array,

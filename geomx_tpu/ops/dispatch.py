@@ -11,8 +11,9 @@ Nothing above ``ops/`` (a compressor's arguments, the spec string,
 ``GeoConfig``, the environment) can choose, and nothing above it asks.
 
 ``tools/*_timing.py`` and ``chip_smoke.py`` compare kernel and oracle by
-calling both by name (``bsc_select_pack`` / ``select_pack_ref`` and so
-on), not through here.
+calling both by name (``bsc_select_pack`` / ``select_pack_ref``,
+``bsc_sampled_boundary`` / ``sampled_boundary_guv`` and so on), not
+through here.
 """
 
 from __future__ import annotations
@@ -70,6 +71,12 @@ def _door(kernel, ref, doc):
     return op
 
 
+sampled_boundary = _door(
+    bsc_pallas.bsc_sampled_boundary, bsc_pallas.sampled_boundary_guv,
+    """``(g, u, v, k)``: the Bi-Sparse boundary of one flat bucket, the
+    (1 - k/n) quantile of the probe's momentum-corrected magnitudes; ``k``
+    static or traced.  The kernel path picks how the samples are fetched
+    from the bucket's size (``bsc_pallas.bsc_sampled_boundary``).""")
 select_pack = _door(
     bsc_pallas.bsc_select_pack, bsc_pallas.select_pack_ref,
     """``(g, u, v, threshold, k)``: Bi-Sparse select/pack of one flat

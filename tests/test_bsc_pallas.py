@@ -24,7 +24,9 @@ import pytest
 
 from geomx_tpu.compression import BiSparseCompressor
 from geomx_tpu.compression.bucketing import GradientBucketer
-from geomx_tpu.ops.bsc_pallas import (bsc_scatter_add, bsc_select_pack,
+from geomx_tpu.ops.bsc_pallas import (bsc_boundary_probe,
+                                      bsc_sampled_boundary, bsc_scatter_add,
+                                      bsc_select_pack, probe_plan,
                                       sampled_boundary_guv)
 from geomx_tpu.ops.dispatch import kernels
 
@@ -302,6 +304,183 @@ def test_select_pack_threshold_probe_matches_reference(rng):
 
     dense, gathered = both(g, u, v)
     assert float(dense) == float(gathered)
+
+
+# ---------- the boundary probe: kernel against the gathers ----------
+
+def _probe_case(shape, n, seed=0):
+    """g, u, v of a bucket, u and v non-zero (``tools/boundary_timing.py``
+    makes the same shapes on the chip)."""
+    rng = np.random.default_rng(seed + n)
+    if shape == "zero":
+        return (jnp.zeros((n,), jnp.float32),) * 3
+    g, u, v = (rng.normal(0, s, n).astype(np.float32) for s in (1, .1, .2))
+    if shape == "rows":
+        held = np.repeat(rng.uniform(size=-(-n // 128)) < 0.25, 128)[:n]
+        held[:128] = True
+        g, u, v = g * held, u * held, v * held
+    return jnp.asarray(g), jnp.asarray(u), jnp.asarray(v)
+
+
+@pytest.mark.parametrize("shape", ["uniform", "rows", "zero"])
+@pytest.mark.parametrize("n", [1_024, 7_040, 8_192, 8_320, 32_768, 40_000,
+                               133_120, 300_000])
+def test_the_probe_gives_the_gathers_boundary(n, shape):
+    """The door's boundary under the interpret hook (what a TPU runs:
+    no fetch up to 8,192 elements, the streamed kernel above: one tile,
+    several, a last tile that hangs over the end) is
+    ``sampled_boundary_guv``'s float, bit for bit."""
+    from geomx_tpu.ops import dispatch
+
+    g, u, v = _probe_case(shape, n)
+    k = -(-n // 100)
+    want = jax.jit(lambda g, u, v: sampled_boundary_guv(g, u, v, k))(g, u, v)
+    with kernels("interpret"):
+        got = jax.jit(
+            lambda g, u, v: dispatch.sampled_boundary(g, u, v, k))(g, u, v)
+    assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert (float(want) == 0.0) == (shape == "zero")
+
+
+@pytest.mark.parametrize("n", [8_320, 40_000, 300_000])
+def test_the_probe_kernel_gives_every_gathered_sample(n):
+    """Not the boundary alone: all 8,192 samples, in position order."""
+    from geomx_tpu.ops.bsc_pallas import MOMENTUM
+    from geomx_tpu.ops.sampled_topk import sample_positions
+
+    g, u, v = _probe_case("uniform", n)
+    pos = jnp.asarray(np.sort(sample_positions(n)), jnp.int32)
+    want = jax.jit(lambda g, u, v: jnp.abs(
+        v[pos] + (u[pos] * MOMENTUM + g[pos])))(g, u, v)
+    got = bsc_boundary_probe(g, u, v, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _primitives(fn, *args):
+    from geomx_tpu.analysis.core import walk_jaxpr
+    return [(site.primitive, site.eqn)
+            for site in walk_jaxpr(jax.make_jaxpr(fn)(*args),
+                                   enter_opaque=True)]
+
+
+def _probe_kernels(eqns):
+    return [e.params["name"] for p, e in eqns if p == "pallas_call"]
+
+
+@pytest.mark.parametrize("n", [1_024, 8_192])
+def test_a_bucket_no_larger_than_the_probe_takes_no_fetch(n):
+    """m == n: the positions are a permutation, so the dense expression
+    is the sample: no gather and no kernel."""
+    g = jnp.zeros((n,), jnp.float32)
+    eqns = _primitives(
+        lambda g, u, v: bsc_sampled_boundary(g, u, v, 11, interpret=True),
+        g, g, g)
+    assert not _probe_kernels(eqns)
+    assert "gather" not in [p for p, _ in eqns]
+    assert "sort" in [p for p, _ in eqns]
+
+
+def test_a_bucket_above_the_threshold_keeps_the_gathers():
+    """Streaming costs the bucket's bytes, gathering the probe's indices:
+    above ``gather_above`` elements the gathers stay.  The threshold is
+    the function's own argument (a constant in the engine's call)."""
+    n, k = 40_000, 400
+    g, u, v = _probe_case("uniform", n)
+
+    def door(**kw):
+        return lambda g, u, v: bsc_sampled_boundary(g, u, v, k,
+                                                    interpret=True, **kw)
+
+    streamed = _primitives(door(), g, u, v)
+    assert _probe_kernels(streamed) == ["bsc_boundary_probe"]
+    assert "gather" not in [p for p, _ in streamed]
+    gathered = _primitives(door(gather_above=n - 1), g, u, v)
+    assert not _probe_kernels(gathered)
+    assert [p for p, _ in gathered].count("gather") == 3
+    assert (np.asarray(jax.jit(door())(g, u, v)).tobytes()
+            == np.asarray(jax.jit(door(gather_above=n - 1))(g, u, v)
+                          ).tobytes())
+
+
+@pytest.mark.parametrize("n", [8_192, 40_000])
+def test_a_traced_k_gives_the_static_boundary_and_traces_once(n):
+    """The control plane's ``eff_k`` is a traced scalar: same boundary,
+    one trace however it changes, no shape that depends on it."""
+    g, u, v = _probe_case("uniform", n)
+    traces = []
+
+    @jax.jit
+    def traced(g, u, v, k):
+        traces.append(1)
+        return bsc_sampled_boundary(g, u, v, k, interpret=True)
+
+    for k in (n // 100, n // 200, 1):
+        want = jax.jit(lambda g, u, v: sampled_boundary_guv(g, u, v, k))(
+            g, u, v)
+        got = traced(g, u, v, jnp.int32(k))
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
+    assert len(traces) == 1
+
+
+def test_the_probe_moves_its_values_as_exact_bf16_pieces():
+    """Its one-hot products carry values, so they must be exact on the
+    chip: the tile goes in as three bfloat16 pieces that sum to it (one
+    MXU pass each, value x 1.0), never as float32 operands at the
+    default precision, which the unit would round to bf16."""
+    g = jnp.zeros((40_000,), jnp.float32)
+    dots = [e for p, e in _primitives(
+        lambda g: bsc_boundary_probe(g, g, g, interpret=True), g)
+        if p == "dot_general"]
+    assert len(dots) == 3
+    for e in dots:
+        assert [x.aval.dtype for x in e.invars] == [jnp.bfloat16] * 2
+        assert e.params["preferred_element_type"] == jnp.float32
+    x = np.random.default_rng(0).normal(0, 1, 4096).astype(np.float32)
+    x[:4] = [0.0, 1e-30, 3.3e38, 1.0 + 2.0 ** -23]
+    x = jnp.abs(jnp.asarray(x))
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    back = (hi.astype(jnp.float32) + mid.astype(jnp.float32)
+            ) + lo.astype(jnp.float32)
+    np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
+
+
+@pytest.mark.parametrize("n", [8_320, 133_120, 1_048_576, 4_194_304,
+                               8_388_608])
+def test_the_probe_plan_covers_every_position_once(n):
+    """Host arithmetic only: a tile's positions are consecutive in the
+    sorted slab and its rows ``first .. first + count - 1`` hold them
+    all; the visits are the rows plus the tiles that share one."""
+    from geomx_tpu.ops.bsc_pallas import _TILE
+    from geomx_tpu.ops.sampled_topk import sample_positions
+
+    pos, first, count = probe_plan(n)
+    assert pos.shape == (64, 128) and pos.dtype == np.int32
+    flat = pos.reshape(-1)
+    np.testing.assert_array_equal(flat, np.sort(sample_positions(n)))
+    assert len(np.unique(flat)) == 8192 and flat.max() < n
+    tiles = -(-n // _TILE)
+    assert first.shape == count.shape == (tiles,)
+    for t in range(tiles):
+        slots = np.nonzero(flat // _TILE == t)[0]
+        if len(slots) == 0:
+            assert count[t] == 0
+            continue
+        assert first[t] == slots[0] // 128
+        assert first[t] + count[t] - 1 == slots[-1] // 128
+    assert 64 <= count.sum() <= 64 + tiles
+
+
+def test_the_probe_lowers_to_tpu_mosaic_without_a_device():
+    from jax import export as jax_export
+
+    g = jnp.zeros((40_000,), jnp.float32)
+    exp = jax_export.export(
+        jax.jit(lambda g, u, v: bsc_sampled_boundary(g, u, v, 400)),
+        platforms=("tpu",))(g, g, g)
+    assert "tpu_custom_call" in exp.mlir_module()
 
 
 # ---------- scatter-add decompress parity ----------

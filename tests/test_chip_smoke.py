@@ -74,7 +74,8 @@ def test_kernels_phase_in_interpret_mode(tiny, capsys):
     rec = chip_smoke.kernels_phase(3, interpret=True, model=tiny["model"])
     assert _last_json(capsys) == rec
     assert rec["native"] is False and rec["k"] >= 1
-    for name in ("fused_flatten", "fused_unflatten", "bsc_select_pack",
+    for name in ("fused_flatten", "fused_unflatten", "bsc_sampled_boundary",
+                 "bsc_select_pack",
                  "bsc_scatter_add", "merge_tree",
                  "fused_sgd_momentum/moment", "fused_adam/moments"):
         assert rec["checked"][name] == "bitwise", name

@@ -108,6 +108,12 @@ def _select(n, k):
             [f32(n), f32(n), f32(n), f32()])
 
 
+def _probe(n):
+    from geomx_tpu.ops.bsc_pallas import bsc_sampled_boundary
+    return ((lambda g, u, v: bsc_sampled_boundary(g, u, v, -(-n // 100))),
+            [f32(n), f32(n), f32(n)])
+
+
 def _scatter(n, pairs):
     from geomx_tpu.ops import bsc_scatter_add
     return (lambda v, i: bsc_scatter_add(v, i, n=n)), [f32(pairs), i32(pairs)]
@@ -190,6 +196,10 @@ CASES = {
     "quantize_2bit-4M": lambda: _twobit(BIG),
     "dequantize_2bit-resnet20": lambda: _twobit_inv(RESNET20_BUCKET),
     "dequantize_2bit-4M": lambda: _twobit_inv(BIG),
+    "bsc_boundary_probe-resnet20": lambda: _probe(RESNET20_BUCKET),
+    "bsc_boundary_probe-1Mi": lambda: _probe(1_048_576),
+    "bsc_boundary_probe-bertlarge-ffn": lambda: _probe(4_194_304),
+    "bsc_boundary_probe-one-tile": lambda: _probe(8_320),
     "bsc_select_pack-resnet20": lambda: _select(RESNET20_BUCKET, RESNET20_K),
     "bsc_select_pack-4M": lambda: _select(BIG, BIG // 100),
     # the benchmark's own buckets, and one the resident output slabs of
@@ -358,6 +368,62 @@ def test_select_pack_kernels_carry_the_name_the_benchmark_reads(chip):
         assert {c.split(".")[0] for c in calls} == want, calls
 
 
+def test_the_probe_kernel_carries_a_name_no_metric_divides_by(chip):
+    """`select_pack_roofline_pct` divides by the time of every kernel
+    whose name starts with `bsc_select_pack`, `compress_kernels_ms` sums
+    its list of prefixes: the probe's kernel is in neither, and
+    `boundary_ms` finds it by its scope."""
+    import re
+    from benchmark.layer_metrics import compress_kernels_ms
+    fn, shapes = _probe(4_194_304)
+    args = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip)
+            for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                       r'"tpu_custom_call"', text)
+    assert {c.split(".")[0] for c in calls} == {"bsc_boundary_probe"}, calls
+    assert not "bsc_boundary_probe".startswith(compress_kernels_ms.PREFIXES)
+    assert "gather" not in text
+
+
+def test_the_probe_adds_no_program_to_a_step(chip, monkeypatch):
+    """Loaded program code counts against `peak_hbm_gib` (PERF.md, PRs 25
+    and 28), and a step holds a probe for every bucket.  One bucketed
+    Bi-Sparse allreduce over the three sizes above, the door's kernels
+    against the same program with the gathers (the door's choice undone
+    by hand): the generated code may not grow by more than 64 KiB a
+    bucket.  Measured here: it shrinks (0.62 MB a probe against the
+    gathers' 0.71)."""
+    from geomx_tpu.compression.bisparse import BiSparseCompressor
+    from geomx_tpu.compression.bucketing import BucketedCompressor
+    from geomx_tpu.ops import bsc_pallas, dispatch
+    from geomx_tpu.ops.dispatch import kernels
+
+    sizes = [(RESNET20_BUCKET,), (1_048_576,), (4_194_304,)]
+
+    def code_bytes():
+        comp = BucketedCompressor(BiSparseCompressor(0.01))
+        grads = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=chip)
+                 for s in sizes]
+        state = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+            jax.eval_shape(comp.init_state, grads))
+        with kernels("native"):
+            lowered = jax.jit(
+                lambda g, s: comp.allreduce(g, s, "dc", 1)).lower(grads, state)
+        compiled = lowered.compile()
+        return (compiled.memory_analysis().generated_code_size_in_bytes,
+                compiled.as_text().count("bsc_boundary_probe"))
+
+    door, probes = code_bytes()
+    assert probes >= len(sizes)
+    monkeypatch.setattr(dispatch, "sampled_boundary",
+                        bsc_pallas.sampled_boundary_guv)
+    gathers, probes = code_bytes()
+    assert probes == 0
+    assert door <= gathers + len(sizes) * 64 * 1024, (door, gathers)
+
+
 @pytest.mark.parametrize("which,want", [
     ("bert", {"flash_attention_fwd", "flash_attention_bwd"}),
     ("latent", {"flash_attention_fwd", "flash_attention_bwd_dq",
@@ -406,7 +472,7 @@ def test_the_bucket_allreduce_gets_the_kernels_through_the_door(chip):
     text = lowered.compile().as_text()
     calls = re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
                        r'"tpu_custom_call"', text)
-    assert {"bsc_select_pack", "bsc_select_pack_count",
+    assert {"bsc_boundary_probe", "bsc_select_pack", "bsc_select_pack_count",
             "bsc_select_pack_place", "bsc_scatter_add", "fused_flatten",
             "fused_unflatten"} == {c.split(".")[0] for c in calls}, calls
     for scope in ("compress/boundary", "bsc/select_pack", "bsc/scatter_add",
