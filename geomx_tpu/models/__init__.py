@@ -6,6 +6,7 @@ The reference's demo workloads are Gluon CNNs on MNIST/FashionMNIST/CIFAR10
 
 import jax.numpy as jnp
 
+from geomx_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from geomx_tpu.models.cnn import GeoCNN
 from geomx_tpu.models.kimi_linear import KimiLinearConfig, KimiLinearLM
 from geomx_tpu.models.mlp import MLP, AlexNet
@@ -15,7 +16,8 @@ from geomx_tpu.models.seq_classifier import SeqClassifier
 
 __all__ = ["GeoCNN", "MLP", "AlexNet",
            "ResNet", "ResNet20", "ResNet32", "ResNet56", "ResNet18",
-           "SeqClassifier", "KimiLinearConfig", "KimiLinearLM", "get_model"]
+           "SeqClassifier", "KimiLinearConfig", "KimiLinearLM", "AfmoeConfig",
+           "AfmoeLM", "get_model"]
 
 # GEOMX_PRECISION -> the models' compute dtype.  Params always stay
 # fp32 (flax casts per-op from the fp32 masters); every model's
@@ -29,14 +31,17 @@ def get_model(name: str, num_classes: int = 10, precision: str = None,
     resolved by ``train.step.resolve_precision``) pins the compute
     dtype explicitly; the default ``None`` keeps each model's
     historical default (byte-identical traces).  ``sizes``: the fields of
-    `KimiLinearConfig` for ``"kimi_linear"``, a causal decoder that brings
-    its own next-token loss (no ``num_classes``)."""
+    `KimiLinearConfig` for ``"kimi_linear"`` and of `AfmoeConfig` for
+    ``"afmoe"``, causal decoders that bring their own next-token loss (no
+    ``num_classes``)."""
     name = name.lower()
     dt = {}
     if precision is not None:
         dt = {"dtype": _PRECISION_DTYPE[precision]}
     if name == "kimi_linear":
         return KimiLinearLM(KimiLinearConfig(**sizes), **dt)
+    if name == "afmoe":
+        return AfmoeLM(AfmoeConfig(**sizes), **dt)
     if name in ("cnn", "geocnn", "lenet"):
         return GeoCNN(num_classes=num_classes, **dt)
     if name == "mlp":

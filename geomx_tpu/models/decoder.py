@@ -1,0 +1,282 @@
+"""What the causal decoders share (`models/kimi_linear.py`,
+`models/afmoe.py`): RMSNorm, the SwiGLU MLP, the router, the expert layer
+that holds some of its experts (`ops/held_experts.py`), the residual block
+whose two halves are rematerialised apart, the model around the blocks and
+its blocked next-token loss.
+
+    h += [PostNorm](Mixer(RMSNorm(h)));  h += [PostNorm](FFN(RMSNorm(h)))
+
+A decoder's configuration (a frozen dataclass) gives the widths the
+feed-forward half reads (`FFNBranch`), `layers` ((mixer, ffn) a block),
+`vocab`, `hidden`, `eps`, `loss_block`, `remat`, and three things of its
+own: `make_mixer(kind, dtype)` (the module under ``core`` of a block's
+mixer half), `post_norms` (a second RMSNorm on each half's output) and
+`embedding_scale`.
+
+The model brings its own loss (`loss_and_aux`): mean next-token
+cross-entropy in float32, blocked over tokens so that no whole logits
+array lives; `train/step.make_loss_fn` takes it from there.  In the
+backward pass each block's mixer is rematerialised a sequence at a time and
+its feed-forward half on its own.
+
+Scopes (telemetry/layers.SCOPES): ``moe/route``, ``moe/experts``,
+``moe/shared``, ``lm/loss``, and the mixers' own.  Module names are
+``mixer``, ``ffn``, ``core``, ``norm`` and ``post_norm`` so that flax's own
+name stack never reads as one of them.
+"""
+
+from __future__ import annotations
+
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from geomx_tpu.ops.held_experts import held_experts
+from geomx_tpu.utils.profiler import profile_scope
+
+_HIGHEST = lax.Precision.HIGHEST
+
+
+class RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
+        x32 = x.astype(jnp.float32)
+        y = x32 * lax.rsqrt(
+            jnp.mean(jnp.square(x32), -1, keepdims=True) + self.eps)
+        return (y * scale).astype(x.dtype)
+
+
+def _normal(std: float = 0.02):
+    return nn.initializers.normal(std)
+
+
+def _fan_in(key, shape, dtype=jnp.float32):
+    return jax.random.normal(key, shape, dtype) * shape[-2] ** -0.5
+
+
+def swiglu(x, gate, up, down):
+    return jnp.dot(jax.nn.silu(jnp.dot(x, gate)) * jnp.dot(x, up), down)
+
+
+class MLP(nn.Module):
+    width: int
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        hidden, dt = x.shape[-1], self.dtype
+        mat = lambda name, shape: self.param(name, _fan_in, shape).astype(dt)
+        return swiglu(x, mat("gate_kernel", (hidden, self.width)),
+                      mat("up_kernel", (hidden, self.width)),
+                      mat("down_kernel", (self.width, hidden)))
+
+
+def route(x, router, bias, top_k: int, scaling: float):
+    """Sigmoid scores in float32, the ``top_k`` largest of score + bias,
+    weights normalised over the selected and scaled.  x [T, d]."""
+    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
+                                    precision=_HIGHEST))
+    _, idx = lax.top_k(scores + bias, top_k)
+    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    return idx, scaling * picked / jnp.sum(picked, -1, keepdims=True)
+
+
+class HeldExpertsLayer(nn.Module):
+    """Routes over ``num_experts``, holds ``num_held`` of them from
+    ``offset`` on, and computes the shared expert plus its own experts'
+    part of the result.  The selection bias is not trained by gradient:
+    zeros, outside ``params``.  Returns (y, assignments that arrived at
+    each held expert [num_held], assignments dropped: 0)."""
+    num_experts: int
+    num_held: int
+    offset: int
+    top_k: int
+    width: int
+    scaling: float
+    shared_experts: int = 1
+    rows: int = 512             # assignments a tile of the kernels holds
+    dtype: Any = jnp.float32
+    pool: int | None = None     # places of the first pool; None: 2 E rows
+
+    @nn.compact
+    def __call__(self, x):
+        hidden, dt = x.shape[-1], self.dtype
+        tokens = x.reshape(-1, hidden)
+        mat = lambda name, shape: self.param(name, _fan_in, shape)
+        with profile_scope("moe/route", "compute"):
+            idx, weights = route(
+                tokens, mat("router_kernel", (hidden, self.num_experts)),
+                jnp.zeros((self.num_experts,), jnp.float32), self.top_k,
+                self.scaling)
+        with profile_scope("moe/shared", "compute"):
+            wide = self.shared_experts * self.width
+            y = swiglu(tokens,
+                       mat("shared_gate_kernel", (hidden, wide)).astype(dt),
+                       mat("shared_up_kernel", (hidden, wide)).astype(dt),
+                       mat("shared_down_kernel", (wide, hidden)).astype(dt))
+        with profile_scope("moe/experts", "compute"):
+            into = (self.num_held, hidden, self.width)
+            routed, counts, dropped = held_experts(
+                tokens, idx, weights, mat("experts_gate_kernel", into),
+                mat("experts_up_kernel", into),
+                mat("experts_down_kernel",
+                    (self.num_held, self.width, hidden)),
+                self.offset, self.rows, None, self.pool)
+            y = (y + routed).astype(dt)
+        return y.reshape(x.shape), counts, dropped
+
+
+class MixerBranch(nn.Module):
+    """``h + [PostNorm](Mixer(RMSNorm(h)))`` for ONE sequence ``h``
+    [L, hidden], in `nn.scan`'s (carry, x) form: a block runs it sequence
+    by sequence, each rematerialised on its own, so that the backward pass
+    holds one sequence's mixer internals at a time (at 8,192 tokens a KDA
+    layer's are ~2 GB)."""
+    kind: str                   # the configuration's own mixer kinds
+    cfg: Any
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, carry, h):
+        c = self.cfg
+        x = RMSNorm(c.eps, name="norm")(h[None])
+        y = c.make_mixer(self.kind, self.dtype)(x)
+        if c.post_norms:
+            y = RMSNorm(c.eps, name="post_norm")(y)
+        return carry, h + y[0]
+
+
+class FFNBranch(nn.Module):
+    """``h + [PostNorm](FFN(RMSNorm(h)))`` over the whole batch; returns
+    (h, assignments that arrived at each held expert, assignments
+    dropped)."""
+    kind: str                   # "mlp" | "moe"
+    cfg: Any
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        c, dt = self.cfg, self.dtype
+        x = RMSNorm(c.eps, name="norm")(h)
+        if self.kind == "mlp":
+            y = MLP(c.dense_width, dt, name="core")(x)
+            counts, dropped = jnp.zeros((0,), jnp.int32), \
+                jnp.zeros((), jnp.int32)
+        else:
+            y, counts, dropped = HeldExpertsLayer(
+                c.num_experts, c.experts_held, c.expert_offset, c.top_k,
+                c.expert_width, c.routed_scaling, c.shared_experts,
+                c.expert_rows, dt, c.expert_pool, name="core")(x)
+        if c.post_norms:
+            y = RMSNorm(c.eps, name="post_norm")(y)
+        return h + y, counts, dropped
+
+
+class Block(nn.Module):
+    mixer: str
+    ffn: str
+    cfg: Any
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, h):
+        remat = nn.remat if self.cfg.remat else (lambda m, **_: m)
+        per_sequence = nn.scan(
+            remat(MixerBranch, prevent_cse=False),
+            variable_broadcast="params", split_rngs={"params": False})
+        _, h = per_sequence(self.mixer, self.cfg, self.dtype,
+                            name="mixer")((), h)
+        return remat(FFNBranch)(self.ffn, self.cfg, self.dtype,
+                                name="ffn")(h)
+
+
+class DecoderLM(nn.Module):
+    cfg: Any
+    dtype: Any = jnp.float32
+
+    def setup(self):
+        c = self.cfg
+        self.embedding = self.param("embedding", _normal(0.02),
+                                    (c.vocab, c.hidden))
+        self.blocks = [Block(mixer, ffn, c, self.dtype, name=f"layer{i + 1}")
+                       for i, (mixer, ffn) in enumerate(c.layers)]
+        self.final_norm = RMSNorm(c.eps, name="final_norm")
+        self.head_kernel = self.param("head_kernel", _fan_in,
+                                      (c.hidden, c.vocab))
+
+    def features(self, tokens):
+        """(normed features [B, L, hidden], assignments that arrived at
+        each held expert of each expert layer, assignments dropped)."""
+        h = self.embedding.astype(self.dtype)[tokens.astype(jnp.int32)]
+        if self.cfg.embedding_scale != 1.0:
+            h = h * jnp.asarray(self.cfg.embedding_scale, self.dtype)
+        arrived, dropped = [], jnp.zeros((), jnp.int32)
+        for block in self.blocks:
+            h, counts, lost = block(h)
+            arrived.append(counts)
+            dropped = dropped + lost
+        return self.final_norm(h), jnp.concatenate(arrived), dropped
+
+    def __call__(self, tokens, train: bool = False):
+        """Whole logits [B, L, vocab] in float32: init, eval, small
+        inputs.  Training takes `loss_and_aux`."""
+        return jnp.dot(self.features(tokens)[0],
+                       self.head_kernel.astype(self.dtype),
+                       preferred_element_type=jnp.float32)
+
+    def loss_and_aux(self, tokens, labels, train: bool = True):
+        """(mean cross-entropy of ``labels`` [B, L], aux).  ``aux`` holds
+        ``accuracy`` and, where an expert layer exists, ``counters``:
+        scalars a step (assignments per held expert and layer as min,
+        mean, max, and those dropped)."""
+        h, arrived, dropped = self.features(tokens)
+        with profile_scope("lm/loss", "compute"):
+            total, hits = blocked_cross_entropy(
+                h.reshape(-1, h.shape[-1]),
+                self.head_kernel.astype(self.dtype), labels.reshape(-1),
+                self.cfg.loss_block)
+        aux = {"accuracy": hits / labels.size}
+        if arrived.size:
+            arrived = arrived.astype(jnp.float32)
+            aux["counters"] = {
+                "moe/assignments_min": jnp.min(arrived),
+                "moe/assignments_mean": jnp.mean(arrived),
+                "moe/assignments_max": jnp.max(arrived),
+                "moe/dropped": dropped.astype(jnp.float32)}
+        return total / labels.size, aux
+
+
+def blocked_cross_entropy(h, head, labels, block: int):
+    """(sum of cross-entropies, number of argmax hits) over tokens h
+    [T, d], ``block`` tokens at a time: a block's float32 logits are the
+    most that lives, forward and (rematerialised) backward."""
+    t = h.shape[0]
+    block = min(block, t)
+    pad = (-t) % block
+    if pad:
+        h = jnp.pad(h, ((0, pad), (0, 0)))
+        labels = jnp.pad(labels, (0, pad), constant_values=-1)
+
+    @jax.checkpoint
+    def one(carry, xs):
+        h_, y_ = xs
+        logits = jnp.dot(h_, head, preferred_element_type=jnp.float32)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logits, jnp.maximum(y_, 0)[:, None], axis=-1)[:, 0]
+        real = y_ >= 0
+        hits = jnp.sum(real & (jnp.argmax(logits, -1) == y_))
+        return (carry[0] + jnp.sum(jnp.where(real, logz - picked, 0.0)),
+                carry[1] + hits.astype(jnp.float32)), None
+
+    (total, hits), _ = lax.scan(
+        one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+        (h.reshape(-1, block, h.shape[-1]),
+         labels.astype(jnp.int32).reshape(-1, block)))
+    return total, hits

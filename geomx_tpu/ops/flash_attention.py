@@ -3,9 +3,9 @@
 The reference has no attention operator at all (its workloads are CNNs;
 SURVEY.md §5 "long-context: absent") — these kernels back the framework's
 first-class long-context path (`models/seq_classifier.py`,
-`models/kimi_linear.py`, `parallel/ulysses.py`) with a TPU-native fused
-implementation: the [L, L] score matrix never touches HBM, forward or
-backward.
+`models/kimi_linear.py`, `models/afmoe.py`, `parallel/ulysses.py`) with a
+TPU-native fused implementation: the [L, L] score matrix never touches
+HBM, forward or backward.
 
 **How much a grid step does** (docs/kernels.md has the long form).  The
 kernels read q, k, v as `[B, L, H * D]` (the caller's `[B, L, H, D]`, no
@@ -17,6 +17,18 @@ are honoured.  The grid walks a static list of the (q block, k block)
 pairs that hold a score, so a causal call neither visits nor fetches the
 blocks above the diagonal, and only the pairs that cross the diagonal or
 the end of a padded length run the masked body.
+
+**Grouped-query heads and a causal band.**  k and v may have fewer heads
+than q (`Hq % Hkv == 0`; query head n reads key/value head `n // (Hq //
+Hkv)`): they cross HBM with their own `Hkv` heads and no wider copy
+exists anywhere.  A step's query heads share one key/value head, or are
+whole groups; the kernels that make dk and dv take whole groups a step
+and sum a group's contributions in their float32 accumulators.  `window`
+(causal calls only) keeps key j for query i iff `0 <= i - j < window`:
+the pairs wholly under the band leave the list like those above the
+diagonal, and the pairs that cross its lower edge run the masked body,
+forward, dq and dk/dv alike.  Equal head counts and no window give the
+specs and the lists they gave before either existed.
 
 **Precision.**  Every product takes its operands in the dtype the caller
 gave (bf16 in, bf16 on the MXU) and accumulates in float32
@@ -88,56 +100,113 @@ def _default_block(length: int) -> int:
     return block
 
 
-def _vmem_bytes(bq, bk, heads, d, dv, itemsize, multi_block):
-    """Bytes of VMEM the backward (the largest of the kernels) asks for:
-    double-buffered blocks of q, k, v, dO in and dq, dk, dv out, the
-    float32 accumulators, and the [bk, bq] temporaries (s, p, dp, ds in
-    float32, p and ds rounded).  With several blocks a kernel makes either
-    dq or dk and dv, so the largest is the dk/dv kernel."""
-    qk, vv = heads * d, heads * dv
-    blocks_in = bq * qk + bk * qk + bk * vv + bq * vv
-    blocks_out = bk * (qk + vv) if multi_block else bq * qk + bk * (qk + vv)
-    return (2 * itemsize * (blocks_in + blocks_out) + 4 * blocks_out
-            + bq * bk * (4 * 4 + 2 * itemsize))
+def _kv_heads(heads: int, group: int) -> int:
+    """Key/value heads behind `heads` query heads of one step: one while
+    the step stays inside a group, else the whole groups' own."""
+    return max(heads // group, 1)
+
+
+def _vmem_bytes(bq, bk, heads, group, d, dv, itemsize, multi_block):
+    """Bytes of VMEM the largest of the kernels asks for: double-buffered
+    blocks in and out, the float32 accumulators, and the [bk, bq]
+    temporaries (s, p, dp, ds in float32, p and ds rounded).  `heads`
+    query heads a step read `_kv_heads` key/value heads; a kernel that
+    makes dk and dv takes whole groups (`max(heads, group)` query heads).
+    With several blocks a kernel makes either dq or dk and dv, and the
+    forward keeps a lane-replicated running max and normaliser; with equal
+    head counts and equal blocks the dk/dv kernel is the largest."""
+    def blocks(g):
+        qk, vv = g * d, g * dv
+        kk, kv = _kv_heads(g, group) * d, _kv_heads(g, group) * dv
+        return qk, vv, kk, kv, bq * (qk + vv) + bk * (kk + kv)
+
+    scores = bq * bk * (4 * 4 + 2 * itemsize)
+    qk, vv, kk, kv, blocks_in = blocks(max(heads, group))
+    if not multi_block:
+        out = bq * qk + bk * (kk + kv)
+        return 2 * itemsize * (blocks_in + out) + 4 * out + scores
+    out = bk * (kk + kv)
+    dkv = 2 * itemsize * (blocks_in + out) + 4 * out + scores
+    qk, vv, kk, kv, blocks_in = blocks(heads)
+    dq = 2 * itemsize * (blocks_in + bq * qk) + 4 * bq * qk + scores
+    fwd = (2 * itemsize * blocks_in + 4 * bq * vv
+           + 2 * 4 * heads * bq * _LANES + bq * bk * (2 * 4 + itemsize))
+    return max(dkv, dq, fwd)
 
 
 def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
                    dtype, causal: bool, block_q: Optional[int] = None,
-                   block_k: Optional[int] = None) -> AttentionPlan:
+                   block_k: Optional[int] = None,
+                   kv_heads: Optional[int] = None) -> AttentionPlan:
     """Blocks and heads a grid step, from the shapes alone.
 
     Blocks: a given `block_q` / `block_k` is honoured (capped at the
     length); else :func:`_default_block`.  Heads a step: the largest
     divisor of H, at most MAX_HEADS, whose slab of `heads * D` (and
     `heads * Dv`) columns is whole 128-lane tiles (or all of them) and
-    whose backward fits VMEM_BUDGET; the smallest such slab where none
-    fits.  One backward kernel where one block pair is the whole
-    sequence.  `causal` chooses no size: it shortens the list of block
-    pairs the grid walks (:func:`_block_pairs`)."""
+    whose kernels fit VMEM_BUDGET; the smallest such slab where none
+    fits.  With `kv_heads` < H (grouped-query heads) a step's query heads
+    share one key/value head or are whole groups, and the key/value slab
+    is lane-aligned too; the kernels that make dk and dv then take
+    `max(heads, H // kv_heads)` query heads.  One backward kernel where
+    one block pair is the whole sequence (and, grouped, whole groups fit
+    it).  `causal` (and a window) choose no size: they shorten the list
+    of block pairs the grid walks
+    (:func:`_block_pairs`)."""
     del causal
+    kv_heads = kv_heads or heads
+    if heads % kv_heads:
+        raise ValueError(f"{heads} query heads over {kv_heads} key/value "
+                         f"heads: not whole groups")
+    group = heads // kv_heads
     bq = min(block_q, q_len) if block_q else _default_block(q_len)
     bk = min(block_k, kv_len) if block_k else _default_block(kv_len)
     fused = -(-q_len // bq) == 1 and -(-kv_len // bk) == 1
     itemsize = jnp.dtype(dtype).itemsize
-    slabs = [g for g in range(1, heads + 1) if heads % g == 0 and (
-        g == heads or ((g * d) % _LANES == 0 and (g * dv) % _LANES == 0))]
-    cost = lambda g: _vmem_bytes(bq, bk, g, d, dv, itemsize, not fused)
-    fits = [g for g in slabs if g <= MAX_HEADS and cost(g) <= VMEM_BUDGET]
+
+    def aligned(g, all_of):
+        return g == all_of or ((g * d) % _LANES == 0
+                               and (g * dv) % _LANES == 0)
+
+    slabs = [g for g in range(1, heads + 1)
+             if heads % g == 0 and (g % group == 0 or group % g == 0)
+             and aligned(g, heads)
+             and aligned(_kv_heads(g, group), kv_heads)]
+    cost = lambda g: _vmem_bytes(bq, bk, g, group, d, dv, itemsize,
+                                 not fused)
+    fit = lambda g: g <= MAX_HEADS and cost(g) <= VMEM_BUDGET
+    if fused and group > 1 and not any(map(fit, slabs)):
+        fused = False       # whole groups do not fit one backward kernel
+    fits = [g for g in slabs if fit(g)]
     g = max(fits) if fits else min(slabs)
     return AttentionPlan(bq, bk, g, fused, cost(g))
 
 
-def _block_pairs(nq, nk, bq, bk, q_len, kv_len, causal, k_inner):
+def _band(window, causal: bool, kv_len: int):
+    """`window` as the kernels take it: None where it hides nothing (no
+    window, or one that reaches past the first key)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError("a window is a causal band of at least one key")
+    return None if window >= kv_len else int(window)
+
+
+def _block_pairs(nq, nk, bq, bk, q_len, kv_len, causal, k_inner,
+                 window=None):
     """The (q block, k block) pairs that hold a score, as the grid's last
     axis walks them: q-major with k inner (forward, dq) or k-major with q
-    inner (dk/dv).  Returns int32 lists `(qi, kj, flags)` for scalar
-    prefetch; flags: 1 the first pair of its run (same outer block), 2 the
-    last, 4 the pair needs the mask (it crosses the diagonal, or holds
-    padded rows or columns); and `bodies`, the kinds of pair the list
-    holds (False unmasked, True masked), which are the bodies a kernel
-    needs (:func:`_run_bodies`)."""
+    inner (dk/dv).  `window`: query i sees key j iff 0 <= i - j < window,
+    so the pairs wholly under the band hold none.  Returns int32 lists
+    `(qi, kj, flags)` for scalar prefetch; flags: 1 the first pair of its
+    run (same outer block), 2 the last, 4 the pair needs the mask (it
+    crosses the diagonal or the band's lower edge, or holds padded rows or
+    columns); and `bodies`, the kinds of pair the list holds (False
+    unmasked, True masked), which are the bodies a kernel needs
+    (:func:`_run_bodies`)."""
     pairs = [(i, j) for i in range(nq) for j in range(nk)
-             if not causal or j * bk <= i * bq + bq - 1]
+             if (not causal or j * bk <= i * bq + bq - 1)
+             and (window is None or j * bk + bk - 1 > i * bq - window)]
     outer = 0 if k_inner else 1
     pairs.sort(key=lambda p: (p[outer], p[1 - outer]))
     flags = []
@@ -145,6 +214,8 @@ def _block_pairs(nq, nk, bq, bk, q_len, kv_len, causal, k_inner):
         first = t == 0 or pairs[t - 1][outer] != pairs[t][outer]
         last = t == len(pairs) - 1 or pairs[t + 1][outer] != pairs[t][outer]
         masked = ((causal and j * bk + bk - 1 > i * bq)
+                  or (window is not None
+                      and i * bq + bq - 1 - j * bk >= window)
                   or (j + 1) * bk > kv_len or (i + 1) * bq > q_len)
         flags.append(first + 2 * last + 4 * masked)
     as_i32 = lambda xs: np.asarray(xs, np.int32)
@@ -161,6 +232,18 @@ def _window(h, d, width):
     lo = start // _LANES * _LANES
     hi = min(-(-stop // _LANES) * _LANES, width)
     return lo, hi, start - lo, stop - lo
+
+
+def _windows(h, group, d, q_width, kv_width):
+    """Query head h's window in its slab and that of its key/value head
+    `h // group` in theirs, each as :func:`_window` gives it.  Where the
+    two heads sit differently in their windows (grouped heads narrower
+    than a lane tile) both are the heads' own columns."""
+    kh = h // group
+    at_q, at_kv = _window(h, d, q_width), _window(kh, d, kv_width)
+    if at_q[1] - at_q[0] != at_kv[1] - at_kv[0] or at_q[2:] != at_kv[2:]:
+        at_q, at_kv = (h * d, (h + 1) * d, 0, d), (kh * d, (kh + 1) * d, 0, d)
+    return at_q, at_kv
 
 
 def _only(x, a, b, axis=1):
@@ -211,10 +294,11 @@ def _run_bodies(step, flags, bodies):
 
 
 def _fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, *refs, scale,
-                heads, d, dv, bq, bk, kv_len, causal, single, with_lse,
-                bodies):
+                heads, group, d, dv, bq, bk, kv_len, causal, window, single,
+                with_lse, bodies):
     """One (q block, k block) pair of a group of heads.  Blocks: q
-    [1, bq, heads d], k [1, bk, heads d], v [1, bk, heads dv], out
+    [1, bq, heads d], k [1, bk, kv d], v [1, bk, kv dv] (kv =
+    `_kv_heads(heads, group)` heads; query head h reads `h // group`), out
     [1, bq, heads dv], lse [1, heads, 1, bq] (rows; absent on the
     inference path).  Scratch: acc [bq, heads dv] float32 and, where a q
     block meets several k blocks (not `single`), running max and
@@ -256,12 +340,17 @@ def _fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, *refs, scale,
             mask = cols < kv_len              # padded keys contribute 0
             if causal:
                 mask = mask & (cols <= rows)
+            if window is not None:
+                mask = mask & (rows - cols < window)
         lse_cols = []
+        kv = _kv_heads(heads, group)
         for h in range(heads):
-            lo, hi, a, b = _window(h, d, heads * d)
-            vlo, vhi, va, vb = _window(h, dv, heads * dv)
+            (lo, hi, a, b), (klo, khi, _, _) = _windows(
+                h, group, d, heads * d, kv * d)
+            (vlo, vhi, va, vb), (kvlo, kvhi, _, _) = _windows(
+                h, group, dv, heads * dv, kv * dv)
             q = _only(q_ref[0, :, lo:hi], a, b)
-            s = _dot(q, k_ref[0, :, lo:hi], _NT) * scale     # [bq, bk] f32
+            s = _dot(q, k_ref[0, :, klo:khi], _NT) * scale   # [bq, bk] f32
             if masked:
                 s = jnp.where(mask, s, _NEG_INF)
             m_new = jnp.max(s, axis=-1, keepdims=True)
@@ -274,7 +363,7 @@ def _fwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, *refs, scale,
                 # m_new = NEG_INF where it would not
                 p = jnp.where(mask, p, 0.0)
             l_new = jnp.sum(p, axis=-1, keepdims=True)
-            pv = _dot(p.astype(dtype), v_ref[0, :, vlo:vhi], _NN)
+            pv = _dot(p.astype(dtype), v_ref[0, :, kvlo:kvhi], _NN)
             if single:
                 l_sum = jnp.maximum(l_new, 1e-20)  # fully-masked rows -> 0
                 _put(acc_ref, vlo, vhi, va, vb, pv * (1.0 / l_sum))
@@ -315,17 +404,11 @@ def _slabs(x, pad):
     return x.reshape(x.shape[0], x.shape[1], -1)
 
 
-class _Call(NamedTuple):
-    """What the wrappers share: the plan, the operands' common dtype, the
-    padding and block counts, and the BlockSpecs of a group of heads'
-    slabs (rows at the pair's q block or k block, `d` or `dv` wide) and of
-    the per-row float32 rows (lse, delta)."""
-    plan: AttentionPlan
-    dtype: jnp.dtype
-    pad_q: int
-    pad_k: int
-    nq: int
-    nk: int
+class _Specs(NamedTuple):
+    """BlockSpecs of one kernel: a group of query heads' slabs (rows at
+    the pair's q block, `d` or `dv` wide), their key/value heads' slabs
+    (rows at the pair's k block) and the per-row float32 rows (lse,
+    delta)."""
     q: pl.BlockSpec
     k: pl.BlockSpec
     v: pl.BlockSpec
@@ -333,23 +416,57 @@ class _Call(NamedTuple):
     rows: pl.BlockSpec
 
 
-def _prepare(q, k, v, causal, block_q, block_k) -> _Call:
+class _Call(NamedTuple):
+    """What the wrappers share: the plan, the operands' common dtype, the
+    padding and block counts, query heads a key/value head (`group`), the
+    band, and the sizes the BlockSpecs are made from."""
+    plan: AttentionPlan
+    dtype: jnp.dtype
+    pad_q: int
+    pad_k: int
+    nq: int
+    nk: int
+    group: int
+    window: Optional[int]
+    d: int
+    dv: int
+
+    def specs(self, heads: int) -> _Specs:
+        """For a kernel that takes `heads` query heads a grid step (axis 1
+        counts such steps): their key/value slab is the step's own where
+        it holds whole groups, else the one its group shares."""
+        bq, bk, group = self.plan.block_q, self.plan.block_k, self.group
+        kv = _kv_heads(heads, group)
+        if heads % group:
+            at_kv = lambda g: g * heads // group
+        else:
+            at_kv = lambda g: g
+        at_q = lambda b, g, t, qi, kj, fl: (b, qi[t], g)
+        at_k = lambda b, g, t, qi, kj, fl: (b, kj[t], at_kv(g))
+        return _Specs(
+            q=pl.BlockSpec((1, bq, heads * self.d), at_q),
+            k=pl.BlockSpec((1, bk, kv * self.d), at_k),
+            v=pl.BlockSpec((1, bk, kv * self.dv), at_k),
+            o=pl.BlockSpec((1, bq, heads * self.dv), at_q),
+            rows=pl.BlockSpec((1, heads, 1, bq),
+                              lambda b, g, t, qi, kj, fl: (b, g, 0, qi[t])))
+
+    def pairs(self, q_len, kv_len, causal, k_inner):
+        return _block_pairs(self.nq, self.nk, self.plan.block_q,
+                            self.plan.block_k, q_len, kv_len, causal,
+                            k_inner, self.window)
+
+
+def _prepare(q, k, v, causal, window, block_q, block_k) -> _Call:
     B, Lq, H, D = q.shape
-    Lk, Dv = k.shape[1], v.shape[-1]
+    Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     dtype = jnp.result_type(q.dtype, k.dtype, v.dtype)
-    plan = attention_plan(Lq, Lk, H, D, Dv, dtype, causal, block_q, block_k)
-    bq, bk, G = plan.block_q, plan.block_k, plan.heads
+    plan = attention_plan(Lq, Lk, H, D, Dv, dtype, causal, block_q, block_k,
+                          kv_heads=Hkv)
+    bq, bk = plan.block_q, plan.block_k
     pq, pk = (-Lq) % bq, (-Lk) % bk
-    at_q = lambda b, g, t, qi, kj, fl: (b, qi[t], g)
-    at_k = lambda b, g, t, qi, kj, fl: (b, kj[t], g)
-    return _Call(
-        plan, dtype, pq, pk, (Lq + pq) // bq, (Lk + pk) // bk,
-        q=pl.BlockSpec((1, bq, G * D), at_q),
-        k=pl.BlockSpec((1, bk, G * D), at_k),
-        v=pl.BlockSpec((1, bk, G * Dv), at_k),
-        o=pl.BlockSpec((1, bq, G * Dv), at_q),
-        rows=pl.BlockSpec((1, G, 1, bq),
-                          lambda b, g, t, qi, kj, fl: (b, g, 0, qi[t])))
+    return _Call(plan, dtype, pq, pk, (Lq + pq) // bq, (Lk + pk) // bk,
+                 H // Hkv, _band(window, causal, Lk), D, Dv)
 
 
 def _grid_spec(pairs, grid, in_specs, out_specs, scratch_shapes):
@@ -365,27 +482,30 @@ _SEMANTICS = pltpu.CompilerParams(
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
-                                    "interpret", "with_lse"))
+                                    "interpret", "with_lse", "window"))
 def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
                              causal: bool = False,
                              block_q: Optional[int] = None,
                              block_k: Optional[int] = None,
                              interpret: bool = False,
-                             with_lse: bool = True):
+                             with_lse: bool = True,
+                             window: Optional[int] = None):
     """Fused attention forward; returns (out [B, L, H, Dv] in q's dtype,
     lse [B, H, L] f32 or None) — lse is the per-row logsumexp the flash
     backward kernels consume.  ``with_lse=False`` (the inference path)
     skips the lse output entirely: XLA cannot dead-code-eliminate a
     Pallas output, so a discarded lse would still cost its HBM write.
-    ``block_q`` / ``block_k`` None: :func:`attention_plan` chooses."""
+    ``block_q`` / ``block_k`` None: :func:`attention_plan` chooses.  k and
+    v may have fewer heads than q (whole groups); ``window``: the causal
+    band's width in keys."""
     B, Lq, H, D = q.shape
     Lk, Dv = k.shape[1], v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
-    call = _prepare(q, k, v, causal, block_q, block_k)
+    call = _prepare(q, k, v, causal, window, block_q, block_k)
     bq, bk, G = call.plan.block_q, call.plan.block_k, call.plan.heads
     Lqp = Lq + call.pad_q
-    pairs, bodies = _block_pairs(call.nq, call.nk, bq, bk, Lq, Lk, causal,
-                                 k_inner=True)
+    pairs, bodies = call.pairs(Lq, Lk, causal, k_inner=True)
+    spec = call.specs(G)
     single = call.nk == 1
     out_shape = jax.ShapeDtypeStruct((B, Lqp, H * Dv), q.dtype)
     lse_shape = jax.ShapeDtypeStruct((B, H, 1, Lqp), jnp.float32)
@@ -394,11 +514,12 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
         scratch += [pltpu.VMEM((G, bq, _LANES), jnp.float32)] * 2
     slabs = lambda x, pad: _slabs(x.astype(call.dtype), pad)
     res = pl.pallas_call(
-        functools.partial(_fwd_kernel, scale=scale, heads=G, d=D, dv=Dv,
-                          bq=bq, bk=bk, kv_len=Lk, causal=causal,
+        functools.partial(_fwd_kernel, scale=scale, heads=G,
+                          group=call.group, d=D, dv=Dv, bq=bq, bk=bk,
+                          kv_len=Lk, causal=causal, window=call.window,
                           single=single, with_lse=with_lse, bodies=bodies),
-        grid_spec=_grid_spec(pairs, (B, H // G), [call.q, call.k, call.v],
-                             [call.o, call.rows] if with_lse else call.o,
+        grid_spec=_grid_spec(pairs, (B, H // G), [spec.q, spec.k, spec.v],
+                             [spec.o, spec.rows] if with_lse else spec.o,
                              scratch),
         out_shape=[out_shape, lse_shape] if with_lse else out_shape,
         compiler_params=_SEMANTICS, interpret=interpret,
@@ -415,31 +536,36 @@ def flash_attention_with_lse(q: jax.Array, k: jax.Array, v: jax.Array,
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, block_q: Optional[int] = None,
                     block_k: Optional[int] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: bool = False,
+                    window: Optional[int] = None) -> jax.Array:
     """Fused attention forward: softmax(QK^T / sqrt(D)) V.
 
-    q, k: [B, L, H, D]; v: [B, L, H, Dv], Dv = D or not (L may differ
-    between q and k/v only via padding — the kernel masks keys past k's
-    length).  Returns [B, L, H, Dv] in q's dtype.  Gradients flow via the flash backward of
-    :func:`fused_attention`; differentiate THAT, not this.
+    q: [B, L, H, D]; k: [B, L, Hkv, D]; v: [B, L, Hkv, Dv], Dv = D or not,
+    Hkv = H or a divisor of it (L may differ between q and k/v only via
+    padding — the kernel masks keys past k's length); ``window``: with
+    ``causal``, key j is seen by query i iff 0 <= i - j < window.  Returns
+    [B, L, H, Dv] in q's dtype.  Gradients flow via the flash backward
+    of :func:`fused_attention`; differentiate THAT, not this.
     """
     return flash_attention_with_lse(q, k, v, causal=causal,
                                     block_q=block_q, block_k=block_k,
-                                    interpret=interpret,
-                                    with_lse=False)[0]
+                                    interpret=interpret, with_lse=False,
+                                    window=window)[0]
 
 
 def _bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, *refs, scale, heads, d, dv, bq, bk, q_len, kv_len,
-                causal, want_dq, want_dkv, delta_in, bodies):
+                lse_ref, *refs, scale, heads, group, d, dv, bq, bk, q_len,
+                kv_len, causal, window, want_dq, want_dkv, delta_in, bodies):
     """One (q block, k block) pair of a group of heads, on transposed
     scores: s^T = K Q^T [bk, bq], so lse and delta [1, bq] broadcast down
-    the sublanes.  Makes dq (`want_dq`: pairs arrive q-major), dk and dv
-    (`want_dkv`: k-major) or, where one pair is the whole sequence, all
-    three from one s, p, dp, ds.  `delta_in`: delta arrives as rows
-    [1, heads, 1, bq]; else it is rowsum(P * dP) over the pair, which is
-    rowsum(dO * O) only where the pair holds every key.  Scratch, float32:
-    dq^T [heads d, bq], dk [bk, heads d], dv [bk, heads dv]."""
+    the sublanes.  Query head h reads key/value head `h // group`; a
+    group's heads add into that head's dk and dv.  Makes dq (`want_dq`:
+    pairs arrive q-major), dk and dv (`want_dkv`: k-major) or, where one
+    pair is the whole sequence, all three from one s, p, dp, ds.
+    `delta_in`: delta arrives as rows [1, heads, 1, bq]; else it is
+    rowsum(P * dP) over the pair, which is rowsum(dO * O) only where the
+    pair holds every key.  Scratch, float32: dq^T [heads d, bq], dk
+    [bk, kv d], dv [bk, kv dv]."""
     refs = list(refs)
     delta_ref = refs.pop(0) if delta_in else None
     dq_ref = refs.pop(0) if want_dq else None
@@ -463,25 +589,30 @@ def _bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
             mask = (rows < q_len) & (cols < kv_len)
             if causal:
                 mask = mask & (cols <= rows)
+            if window is not None:
+                mask = mask & (rows - cols < window)
+        kv = _kv_heads(heads, group)
         for h in range(heads):
-            lo, hi, a, b = _window(h, d, heads * d)
-            vlo, vhi, va, vb = _window(h, dv, heads * dv)
-            q, k = q_ref[0, :, lo:hi], k_ref[0, :, lo:hi]
+            (lo, hi, a, b), (klo, khi, ka, kb) = _windows(
+                h, group, d, heads * d, kv * d)
+            (vlo, vhi, va, vb), (kvlo, kvhi, kva, kvb) = _windows(
+                h, group, dv, heads * dv, kv * dv)
+            q, k = q_ref[0, :, lo:hi], k_ref[0, :, klo:khi]
             do = do_ref[0, :, vlo:vhi]
-            st = _dot(_only(k, a, b), q, _NT) * scale        # [bk, bq] f32
+            st = _dot(_only(k, ka, kb), q, _NT) * scale      # [bk, bq] f32
             pt = jnp.exp(st - lse_ref[0, h])
             if masked:
                 pt = jnp.where(mask, pt, 0.0)
-            dpt = _dot(_only(v_ref[0, :, vlo:vhi], va, vb), do, _NT)
+            dpt = _dot(_only(v_ref[0, :, kvlo:kvhi], kva, kvb), do, _NT)
             if delta_in:
                 delta = delta_ref[0, h]
             else:
                 delta = jnp.sum(pt * dpt, axis=0, keepdims=True)
             dst = (pt * (dpt - delta)).astype(dtype)
             if want_dkv:
-                _put(dv_acc, vlo, vhi, va, vb,
+                _put(dv_acc, kvlo, kvhi, kva, kvb,
                      _dot(pt.astype(dtype), do, _NN), add=True)
-                _put(dk_acc, lo, hi, a, b, _dot(dst, q, _NN), add=True)
+                _put(dk_acc, klo, khi, ka, kb, _dot(dst, q, _NN), add=True)
             if want_dq:
                 # dq^T = K^T dS^T: the transpose falls on k, not on ds
                 _put(dqt_acc, lo, hi, a, b, _dot(k, dst, _TN), axis=0,
@@ -500,11 +631,12 @@ def _bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("causal", "block_q", "block_k",
-                                    "interpret"))
+                                    "interpret", "window"))
 def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
                         block_q: Optional[int] = None,
                         block_k: Optional[int] = None,
-                        interpret: bool = False):
+                        interpret: bool = False,
+                        window: Optional[int] = None):
     """Flash backward: (dq, dk, dv), each in its input's dtype (from
     float32 accumulators), without ever materializing the [L, L] score
     matrix — p is recomputed per block pair from the forward's logsumexp
@@ -512,13 +644,15 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
     folds the softmax normalizer's gradient).  v, out and do may have a
     head size of their own (Dv).  One kernel where the plan's block pair
     is the whole sequence (`out` is then not read: delta is rowsum(P dP)
-    inside it), else a dq kernel and a dk/dv kernel."""
+    inside it), else a dq kernel and a dk/dv kernel.  With grouped heads
+    dk and dv have k's and v's own heads: a kernel that makes them takes
+    whole groups a step and sums over each."""
     B, Lq, H, D = q.shape
-    Lk, Dv = k.shape[1], v.shape[-1]
+    Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
-    call = _prepare(q, k, v, causal, block_q, block_k)
+    call = _prepare(q, k, v, causal, window, block_q, block_k)
     plan, pq, pk = call.plan, call.pad_q, call.pad_k
-    bq, bk, G = plan.block_q, plan.block_k, plan.heads
+    bq, bk = plan.block_q, plan.block_k
     Lqp, Lkp = Lq + pq, Lk + pk
 
     def rows(x):     # [B, H, Lq] f32 -> [B, H, 1, Lqp]
@@ -533,28 +667,31 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
         operands.append(rows(jnp.sum(
             do.astype(jnp.float32) * out.astype(jnp.float32),
             axis=-1).transpose(0, 2, 1)))
-    in_specs = [call.q, call.k, call.v, call.o] \
-        + [call.rows] * (len(operands) - 4)
     dq_shape = jax.ShapeDtypeStruct((B, Lqp, H * D), q.dtype)
-    dk_shape = jax.ShapeDtypeStruct((B, Lkp, H * D), k.dtype)
-    dv_shape = jax.ShapeDtypeStruct((B, Lkp, H * Dv), v.dtype)
-    dq_acc = pltpu.VMEM((G * D, bq), jnp.float32)
-    dkv_acc = [pltpu.VMEM((bk, G * D), jnp.float32),
-               pltpu.VMEM((bk, G * Dv), jnp.float32)]
+    dk_shape = jax.ShapeDtypeStruct((B, Lkp, Hkv * D), k.dtype)
+    dv_shape = jax.ShapeDtypeStruct((B, Lkp, Hkv * Dv), v.dtype)
 
     def kernel_call(name, k_inner, want_dq, want_dkv):
-        pairs, bodies = _block_pairs(call.nq, call.nk, bq, bk, Lq, Lk,
-                                     causal, k_inner)
+        # dk and dv are sums over whole groups of query heads
+        G = max(plan.heads, call.group) if want_dkv else plan.heads
+        kv = _kv_heads(G, call.group)
+        spec = call.specs(G)
+        pairs, bodies = call.pairs(Lq, Lk, causal, k_inner)
         kernel = functools.partial(
-            _bwd_kernel, scale=scale, heads=G, d=D, dv=Dv, bq=bq, bk=bk,
-            q_len=Lq, kv_len=Lk, causal=causal, want_dq=want_dq,
-            want_dkv=want_dkv, delta_in=not plan.fused_backward,
-            bodies=bodies)
+            _bwd_kernel, scale=scale, heads=G, group=call.group, d=D, dv=Dv,
+            bq=bq, bk=bk, q_len=Lq, kv_len=Lk, causal=causal,
+            window=call.window, want_dq=want_dq, want_dkv=want_dkv,
+            delta_in=not plan.fused_backward, bodies=bodies)
+        dq_acc = pltpu.VMEM((G * D, bq), jnp.float32)
+        dkv_acc = [pltpu.VMEM((bk, kv * D), jnp.float32),
+                   pltpu.VMEM((bk, kv * Dv), jnp.float32)]
         return pl.pallas_call(
             kernel,
             grid_spec=_grid_spec(
-                pairs, (B, H // G), in_specs,
-                [call.q] * want_dq + [call.k, call.v] * want_dkv,
+                pairs, (B, H // G),
+                [spec.q, spec.k, spec.v, spec.o]
+                + [spec.rows] * (len(operands) - 4),
+                [spec.q] * want_dq + [spec.k, spec.v] * want_dkv,
                 [dq_acc] * want_dq + dkv_acc * want_dkv),
             out_shape=[dq_shape] * want_dq + [dk_shape, dv_shape] * want_dkv,
             compiler_params=_SEMANTICS, interpret=interpret, name=name,
@@ -566,10 +703,10 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
         dq, = kernel_call("flash_attention_bwd_dq", True, True, False)
         dk, dv = kernel_call("flash_attention_bwd_dkv", False, False, True)
 
-    def back(x, L):
-        return x.reshape(B, x.shape[1], H, -1)[:, :L]
+    def back(x, L, heads):
+        return x.reshape(B, x.shape[1], heads, -1)[:, :L]
 
-    return back(dq, Lq), back(dk, Lk), back(dv, Lk)
+    return back(dq, Lq, H), back(dk, Lk, Hkv), back(dv, Lk, Hkv)
 
 
 def fused_attention_supported() -> bool:
@@ -579,14 +716,32 @@ def fused_attention_supported() -> bool:
     return kernel_mode() == "native"
 
 
-def _dense(q, k, v, causal):
-    """f32-upcast dense attention — delegates the math to the numerical
-    baseline (`full_attention_reference`), so the backward's gradients
-    match it by construction."""
-    from geomx_tpu.parallel.ring_attention import full_attention_reference
-    return full_attention_reference(
-        q.astype(jnp.float32), k.astype(jnp.float32),
-        v.astype(jnp.float32), causal=causal).astype(q.dtype)
+def _dense(q, k, v, causal, window=None):
+    """f32-upcast dense attention.  Equal head counts and no window
+    delegate the math to the numerical baseline
+    (`full_attention_reference`), so the backward's gradients match it by
+    construction; grouped heads (query head n reads key/value head
+    n // group, no wider copy of k or v) and the causal band are the same
+    form with the heads cut into groups and one more mask."""
+    q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+    B, L, H, D = q.shape
+    Lk, Hkv = k.shape[1], k.shape[2]
+    window = _band(window, causal, Lk)
+    if H == Hkv and window is None:
+        from geomx_tpu.parallel.ring_attention import \
+            full_attention_reference
+        return full_attention_reference(q32, k32, v32,
+                                        causal=causal).astype(q.dtype)
+    s = jnp.einsum("bqgrd,bkgd->bgrqk", q32.reshape(B, L, Hkv, H // Hkv, D),
+                   k32) / jnp.sqrt(jnp.asarray(D, jnp.float32))
+    if causal:
+        back = jnp.arange(L)[:, None] - jnp.arange(Lk)[None, :]
+        seen = back >= 0
+        if window is not None:
+            seen = seen & (back < window)
+        s = jnp.where(seen, s, -jnp.inf)
+    o = jnp.einsum("bgrqk,bkgd->bqgrd", jax.nn.softmax(s, axis=-1), v32)
+    return o.reshape(B, L, H, v.shape[-1]).astype(q.dtype)
 
 
 # the scope every instruction of attention's core sits under, kernels and
@@ -595,39 +750,44 @@ def _dense(q, k, v, causal):
 _SCOPE = "attn/core"
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def fused_attention(q, k, v, causal: bool = False,
-                    interpret: bool = False):
+                    interpret: bool = False,
+                    window: Optional[int] = None):
     """Differentiable attention with platform dispatch built in: the
     Pallas kernels on TPU (or under ``interpret=True``), the dense jnp
     reference elsewhere — callers never gate on platform.  On the
     kernel path BOTH directions are flash: the backward recomputes p
     per tile from the forward's saved logsumexp, so the [L, L] score
-    matrix never exists in HBM forward or backward."""
+    matrix never exists in HBM forward or backward.  k and v may have
+    fewer heads than q (grouped-query heads: `Hq % Hkv == 0`, no wider
+    copy is made on either path); ``window`` with ``causal``: query i sees
+    key j iff 0 <= i - j < window."""
     with profile_scope(_SCOPE, "kernel"):
         if interpret or fused_attention_supported():
             return flash_attention(q, k, v, causal=causal,
-                                   interpret=interpret)
-        return _dense(q, k, v, causal)
+                                   interpret=interpret, window=window)
+        return _dense(q, k, v, causal, window)
 
 
-def _fused_fwd(q, k, v, causal, interpret):
+def _fused_fwd(q, k, v, causal, interpret, window):
     with profile_scope(_SCOPE, "kernel"):
         if interpret or fused_attention_supported():
             out, lse = flash_attention_with_lse(q, k, v, causal=causal,
-                                                interpret=interpret)
+                                                interpret=interpret,
+                                                window=window)
             return out, (q, k, v, out, lse)
-        return _dense(q, k, v, causal), (q, k, v, None, None)
+        return _dense(q, k, v, causal, window), (q, k, v, None, None)
 
 
-def _fused_bwd(causal, interpret, res, g):
+def _fused_bwd(causal, interpret, window, res, g):
     q, k, v, out, lse = res
     with profile_scope(_SCOPE, "kernel"):
         if lse is not None:  # kernel path: flash backward
             return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                                       interpret=interpret)
-        _, vjp = jax.vjp(lambda q_, k_, v_: _dense(q_, k_, v_, causal),
-                         q, k, v)
+                                       interpret=interpret, window=window)
+        _, vjp = jax.vjp(
+            lambda q_, k_, v_: _dense(q_, k_, v_, causal, window), q, k, v)
         return vjp(g)
 
 

@@ -8,8 +8,10 @@ assignments: gather the pool's tokens once, run the SwiGLU as grouped
 products over the experts' runs inside the pool (JAX's megablox kernels:
 a tile of ``rows`` assignments at a time, only the tiles that hold an
 assignment, each with its expert's weights), scatter-add the weighted
-result once.  The first pool has ``2 * E * rows`` places, twice what even
-routing sends here when ``rows`` is an expert's share, and is always
+result once.  The first pool has ``pool`` places (``2 * E * rows`` where
+none is given: twice what even routing sends here when ``rows`` is an
+expert's share; a caller whose experts see more gives twice its own even
+load, a multiple of ``rows``) and is always
 walked: a row gather or scatter costs the chip the same per index
 whether the row exists or not (0.1 and 0.4 us, PERF.md), so up to twice
 even load the layer's time hardly moves with what the router does, at
@@ -72,15 +74,18 @@ def _tgmm(lhs, rhs, sizes, rows, interpret, into):
                 existing_out=into, interpret=interpret)
 
 
-def _pools(num_held: int, rows: int, assignments: int):
+def _pools(num_held: int, rows: int, assignments: int, pool=None):
     """(places of the first pool, of each later one, of all that the
     sorted assignments are padded to)."""
-    first, later = 2 * num_held * rows, 2 * rows
+    first, later = pool or 2 * num_held * rows, 2 * rows
+    if first % rows:
+        raise ValueError(f"a first pool of {first} places is not whole "
+                         f"tiles of {rows}")
     beyond = -(-max(assignments - first, 0) // later) * later
     return first, later, first + beyond
 
 
-def _plan(idx, weights, num_held: int, offset: int, rows: int):
+def _plan(idx, weights, num_held: int, offset: int, rows: int, pool=None):
     """The held assignments in order of their expert, padded to whole
     pools.  idx [T, k] global expert ids, weights [T, k]."""
     t, k = idx.shape
@@ -90,7 +95,7 @@ def _plan(idx, weights, num_held: int, offset: int, rows: int):
     order = jnp.argsort(key, stable=True)
     counts = jnp.sum(key[:, None] == jnp.arange(num_held)[None, :], axis=0,
                      dtype=jnp.int32)                              # [E]
-    pad = (0, _pools(num_held, rows, t * k)[2] - t * k)
+    pad = (0, _pools(num_held, rows, t * k, pool)[2] - t * k)
     return {"token": jnp.pad((order // k).astype(jnp.int32), pad),
             "weight": jnp.pad(
                 weights.reshape(-1)[order].astype(jnp.float32), pad),
@@ -98,10 +103,10 @@ def _plan(idx, weights, num_held: int, offset: int, rows: int):
             "tokens": t}
 
 
-def _walk(plan, num_held: int, rows: int, body, carry):
-    """`body(lo, pool, carry)` over the first pool, always, and over as
+def _walk(plan, num_held: int, rows: int, pool, body, carry):
+    """`body(lo, places, carry)` over the first pool, always, and over as
     many later ones as the held assignments reach."""
-    first, later, _ = _pools(num_held, rows, plan["order"].size)
+    first, later, _ = _pools(num_held, rows, plan["order"].size, pool)
     carry = body(0, first, carry)
     trips = -(-jnp.maximum(plan["ends"][-1] - first, 0) // later)
     return lax.fori_loop(
@@ -138,28 +143,29 @@ def _cast(x, gate, up, down):
             down.astype(x.dtype))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def held_experts(x, idx, weights, gate, up, down, offset: int, rows: int,
-                 interpret: bool | None = None):
+                 interpret: bool | None = None, pool: int | None = None):
     """x [T, d] (the compute dtype), idx [T, k] int, weights [T, k] f32,
     gate / up [E, d, f], down [E, f, d] (the masters: they are cast to
     x's dtype once a call, and their gradients come back unrounded).
     `rows`: the assignments a kernel tile holds.  `interpret`: run the
     kernels in interpret mode; None: wherever the backend is no TPU.
+    `pool`: the places of the first pool, whole tiles; None: 2 E rows.
     Returns (y [T, d] float32, counts [E] int32, dropped int32)."""
     return _forward(x, idx, weights, gate, up, down, offset, rows,
-                    interpret)[0]
+                    interpret, pool)[0]
 
 
-def _forward(x, idx, weights, gate, up, down, offset, rows, interpret):
+def _forward(x, idx, weights, gate, up, down, offset, rows, interpret, pool):
     if interpret is None:
         interpret = kernel_mode() != "native"
-    plan = _plan(idx, weights, gate.shape[0], offset, rows)
+    plan = _plan(idx, weights, gate.shape[0], offset, rows, pool)
     gate_up, down = _cast(x, gate, up, down)
 
-    def body(lo, pool, carry):
+    def body(lo, places, carry):
         y, done = carry
-        token, weight, valid, sizes = _pool(plan, lo, pool)
+        token, weight, valid, sizes = _pool(plan, lo, places)
         xs = x.at[token].get(mode="fill", fill_value=0)
         h = _hidden(xs, gate_up, sizes, valid, rows, interpret)[2]
         out = _gmm(h.astype(x.dtype), down, sizes, rows, interpret)
@@ -168,28 +174,28 @@ def _forward(x, idx, weights, gate, up, down, offset, rows, interpret):
                 done + jnp.sum(valid, dtype=jnp.int32))
 
     y, done = _walk(
-        plan, gate.shape[0], rows, body,
+        plan, gate.shape[0], rows, pool, body,
         (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32)))
     counts = plan["counts"]
     return (y, counts, jnp.sum(counts) - done), plan
 
 
-def _fwd(x, idx, weights, gate, up, down, offset, rows, interpret):
+def _fwd(x, idx, weights, gate, up, down, offset, rows, interpret, pool):
     out, plan = _forward(x, idx, weights, gate, up, down, offset, rows,
-                         interpret)
+                         interpret, pool)
     return out, (x, idx, weights, gate, up, down, plan)
 
 
-def _bwd(offset, rows, interpret, res, cotangents):
+def _bwd(offset, rows, interpret, pool, res, cotangents):
     x, idx, weights, gate, up, down, plan = res
     if interpret is None:
         interpret = kernel_mode() != "native"
     dy = cotangents[0].astype(x.dtype)
     gate_up, down_c = _cast(x, gate, up, down)
 
-    def body(lo, pool, carry):
+    def body(lo, places, carry):
         dx, dw, dgate_up, ddown = carry
-        token, weight, valid, sizes = _pool(plan, lo, pool)
+        token, weight, valid, sizes = _pool(plan, lo, places)
         xs = x.at[token].get(mode="fill", fill_value=0)
         dys = dy.at[token].get(mode="fill", fill_value=0)
         a, u, h = _hidden(xs, gate_up, sizes, valid, rows, interpret)
@@ -211,7 +217,7 @@ def _bwd(offset, rows, interpret, res, cotangents):
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
     dx, dw, dgate_up, ddown = _walk(
-        plan, gate.shape[0], rows, body,
+        plan, gate.shape[0], rows, pool, body,
         (zeros(x), zeros(plan["weight"]), zeros(gate_up), zeros(down)))
     dgate, dup = jnp.split(dgate_up, 2, axis=-1)
     # back from sorted order to [T, k]

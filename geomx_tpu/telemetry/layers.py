@@ -18,8 +18,9 @@ The vocabulary (docs/telemetry.md has the operator's table):
   compression engine (compression/) inside ``step/sync_grads``;
 - ``<axis>_pipeline/*``: the pipelined sync engine (sync/pipeline.py);
 - ``collective/worker``, ``collective/dc``: the tier collectives;
-- ``kda/*``, ``mla/*``, ``moe/*``, ``lm/loss``: a decoder's layers inside
-  ``step/forward_backward`` (models/kimi_linear.py);
+- ``kda/*``, ``mla/*``, ``gqa/*``, ``moe/*``, ``lm/loss``: a decoder's
+  layers inside ``step/forward_backward`` (models/kimi_linear.py,
+  models/afmoe.py, models/decoder.py);
 - ``attn/core``: the attention kernels and what surrounds them
   (ops/flash_attention.fused_attention), forward and backward;
 - ``train/step``, ``fit/*``, ``loader/*``: host spans of the loop.
@@ -40,18 +41,22 @@ from geomx_tpu.utils.profiler import profile_scope
 SCOPES = (
     ("step/forward_backward", "step program"),
     # a decoder's layers, opened inside step/forward_backward
-    # (models/kimi_linear.py, ops/kda.py)
+    # (models/kimi_linear.py, models/afmoe.py, models/decoder.py,
+    # ops/kda.py)
     ("kda/proj", "step program"),
     ("kda/scan", "kernels"),
     ("mla/proj", "step program"),
     ("mla/attention", "kernels"),
+    ("gqa/proj", "step program"),
+    ("gqa/window", "kernels"),
+    ("gqa/global", "kernels"),
     ("moe/route", "step program"),
     ("moe/experts", "step program"),
     ("moe/shared", "step program"),
     ("lm/loss", "step program"),
     # attention's core, forward and backward, opened by
     # ops/flash_attention.fused_attention; in a decoder it nests inside
-    # mla/attention
+    # mla/attention, gqa/window or gqa/global
     ("attn/core", "kernels"),
     ("step/optimizer", "step program"),
     ("step/metrics", "step program"),

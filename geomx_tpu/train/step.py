@@ -569,10 +569,16 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
                     metrics["telemetry"]["control_ratio_scale"] = \
                         ctl["bsc_ratio_scale"]
 
+        # where the compiler fuses an optimizer update on its own (a
+        # decoder's gradients leave a loop), the fusion's root is the
+        # reshape that puts the mesh dims back on the leaf, and the update
+        # takes that reshape's scope: the optimizer's
+        with profile_scope("step/optimizer"):
+            new_params, new_opt_state = expand(params), expand(opt_state)
         new_state = TrainState(
             step=step + 1,
-            params=expand(params),
-            opt_state=expand(opt_state),
+            params=new_params,
+            opt_state=new_opt_state,
             model_state=expand(model_state),
             sync_state=expand(sync_state),
         )

@@ -16,26 +16,77 @@ from geomx_tpu.ops.flash_attention import flash_attention, fused_attention
 from geomx_tpu.parallel.ring_attention import full_attention_reference
 
 
-@pytest.mark.parametrize("shape,causal,blocks", [
-    ((2, 64, 4, 32), False, (32, 32)),
-    ((2, 64, 4, 32), True, (32, 32)),
-    ((1, 100, 2, 16), True, (32, 32)),  # ragged L: padded keys masked
-    ((2, 128, 4, 64), False, (32, 32)),
-    ((1, 16, 1, 8), True, (32, 32)),    # L smaller than the given block
+def dense_reference(q, k, v, causal, window=None):
+    """`full_attention_reference`, with k and v repeated to q's heads
+    (query head n reads key/value head n // group) and, under `window`,
+    key j kept for query i iff 0 <= i - j < window."""
+    group = q.shape[2] // k.shape[2]
+    if group == 1 and window is None:
+        return full_attention_reference(q, k, v, causal=causal)
+    k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        back = (jnp.arange(q.shape[1])[:, None]
+                - jnp.arange(k.shape[1])[None, :])
+        seen = back >= 0
+        if window is not None:
+            seen = seen & (back < window)
+        s = jnp.where(seen, s, -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
+
+
+def _qkv(rng, shape, kv_heads=None):
+    """q of `shape` [B, L, H, D]; k and v with `kv_heads` heads (H where
+    None)."""
+    b, length, h, d = shape
+    kv = (b, length, kv_heads or h, d)
+    return tuple(jnp.asarray(rng.normal(size=s).astype(np.float32))
+                 for s in (shape, kv, kv))
+
+
+# (kv heads, window) of the cases without grouped heads or a band
+PLAIN = (None, None)
+# grouped-query heads and the causal band: 8:1 and 2:1 groups; windows
+# smaller than a block, not a multiple of a block, and longer than the
+# sequence (= plain causal); 640 = 5 blocks of the plan's own 128, so
+# pairs lie wholly under the band, cross its lower edge, miss it, touch
+# and cross the diagonal; 64-wide heads in groups sit differently in
+# their lane tiles than their key/value head does
+GROUPED = [
+    ((1, 64, 8, 16), True, (32, 32), (1, None)),
+    ((2, 96, 4, 32), True, (32, 32), (2, 20)),
+    ((1, 96, 8, 16), True, (32, 32), (1, 40)),
+    ((1, 100, 4, 16), True, (32, 32), (2, 33)),
+    ((1, 96, 2, 16), True, (32, 32), (None, 1000)),
+    ((1, 640, 4, 64), True, (None, None), (2, 200)),
+    ((1, 640, 16, 128), True, (None, None), (2, 130)),
+    # one block pair is the whole sequence: ONE backward kernel sums dk
+    # and dv over the groups
+    ((1, 128, 4, 32), True, (None, None), (2, None)),
+    ((2, 128, 8, 64), True, (None, None), (1, 50)),
+    ((1, 128, 4, 32), False, (None, None), (2, None)),
+]
+
+
+@pytest.mark.parametrize("shape,causal,blocks,grouped", [
+    ((2, 64, 4, 32), False, (32, 32), PLAIN),
+    ((2, 64, 4, 32), True, (32, 32), PLAIN),
+    ((1, 100, 2, 16), True, (32, 32), PLAIN),  # ragged L: padded keys masked
+    ((2, 128, 4, 64), False, (32, 32), PLAIN),
+    ((1, 16, 1, 8), True, (32, 32), PLAIN),    # L smaller than the given block
     # the plan's own blocks (None): the BERT cells' layer, one block pair
     # and two heads a step in float32; a padded length, one masked pair;
     # 640 = 5 blocks of 128, pairs that cross, touch and miss the diagonal
-    ((1, 512, 16, 64), False, (None, None)),
-    ((2, 300, 2, 64), True, (None, None)),
-    ((1, 640, 4, 32), True, (None, None)),
-])
-def test_forward_matches_dense_reference(shape, causal, blocks):
-    rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.normal(size=shape).astype(np.float32))
-               for _ in range(3))
-    ref = full_attention_reference(q, k, v, causal=causal)
+    ((1, 512, 16, 64), False, (None, None), PLAIN),
+    ((2, 300, 2, 64), True, (None, None), PLAIN),
+    ((1, 640, 4, 32), True, (None, None), PLAIN),
+] + GROUPED)
+def test_forward_matches_dense_reference(shape, causal, blocks, grouped):
+    kv_heads, window = grouped
+    q, k, v = _qkv(np.random.RandomState(0), shape, kv_heads)
+    ref = dense_reference(q, k, v, causal, window)
     out = flash_attention(q, k, v, causal=causal, block_q=blocks[0],
-                          block_k=blocks[1], interpret=True)
+                          block_k=blocks[1], interpret=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-6, rtol=1e-5)
 
@@ -86,6 +137,40 @@ def test_gradients_match_dense_reference():
                                    atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("interpret", [True, False],
+                         ids=["kernels", "dense-fall-back"])
+@pytest.mark.parametrize("shape,kv_heads,window", [
+    ((1, 96, 8, 16), 1, 40), ((2, 64, 4, 32), 2, None),
+    ((1, 64, 2, 16), None, 24), ((1, 64, 4, 16), 2, 500),
+], ids=["8:1-window", "2:1", "window", "window-past-the-start"])
+def test_fused_attention_grouped_and_windowed_gradients(shape, kv_heads,
+                                                        window, interpret):
+    """`fused_attention` with fewer key/value heads and a causal band,
+    through the kernels and through the dense fall-back a CPU takes:
+    values and all three gradients against the repeated-heads reference."""
+    q, k, v = _qkv(np.random.RandomState(5), shape, kv_heads)
+    fused = lambda q, k, v: jnp.sum(
+        fused_attention(q, k, v, True, interpret, window) ** 2)
+    dense = lambda q, k, v: jnp.sum(
+        dense_reference(q, k, v, True, window) ** 2)
+    np.testing.assert_allclose(float(fused(q, k, v)), float(dense(q, k, v)),
+                               rtol=1e-5)
+    for got, want in zip(jax.grad(fused, argnums=(0, 1, 2))(q, k, v),
+                         jax.grad(dense, argnums=(0, 1, 2))(q, k, v)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_a_window_is_a_causal_band():
+    q = jnp.ones((1, 32, 2, 8), jnp.float32)
+    with pytest.raises(ValueError, match="causal band"):
+        flash_attention(q, q, q, causal=False, window=8, interpret=True)
+    three = jnp.ones((1, 32, 3, 8), jnp.float32)
+    with pytest.raises(ValueError, match="whole groups"):
+        flash_attention(q, three, three, interpret=True)
+
+
 @pytest.mark.parametrize("block", [16, None])
 def test_fully_masked_rows_are_zero_not_nan(block):
     """Causal row 0 with kv padding: a row whose only unmasked key is
@@ -108,22 +193,33 @@ def test_fully_masked_rows_are_zero_not_nan(block):
         assert bool(jnp.all(jnp.isfinite(grad)))
 
 
-# (B, L, H, D, Dv, dtype, causal): the Mosaic-lowering tests' sizes
+# (B, L, H, D, Dv, dtype, causal[, kv heads, window]): the Mosaic-lowering
+# tests' sizes
 LOWERED = {
     "f32-L256": (2, 256, 4, 64, 64, jnp.float32, True),
     # the BERT cells' layer: 16 x 512 x 16 x 64, bf16, four heads a step
     "bf16-bert": (16, 512, 16, 64, 64, jnp.bfloat16, False),
     # the decoder's latent attention: one sequence of 8,192, 192/128 heads
     "bf16-latent-L8192": (1, 8192, 32, 192, 128, jnp.bfloat16, True),
+    # the second decoder's window layers: 32 query heads on 4 key/value
+    # heads of 128, a band of 2,048 keys; and narrow grouped heads, which
+    # take their own columns of a lane tile
+    "bf16-grouped-window-L8192": (1, 8192, 32, 128, 128, jnp.bfloat16, True,
+                                  4, 2048),
+    "f32-grouped-64-L1024": (1, 1024, 8, 64, 64, jnp.float32, True, 2, 300),
 }
 
 
 def _lowered_shapes(case):
-    b, length, h, d, dv, dtype, causal = LOWERED[case]
-    qk = jax.ShapeDtypeStruct((b, length, h, d), dtype)
-    v = jax.ShapeDtypeStruct((b, length, h, dv), dtype)
+    """(q, k, v, out, lse, causal, window) of a case."""
+    b, length, h, d, dv, dtype, causal = LOWERED[case][:7]
+    kv_heads, window = (LOWERED[case][7:] or (h, None))
+    q = jax.ShapeDtypeStruct((b, length, h, d), dtype)
+    k = jax.ShapeDtypeStruct((b, length, kv_heads, d), dtype)
+    v = jax.ShapeDtypeStruct((b, length, kv_heads, dv), dtype)
+    out = jax.ShapeDtypeStruct((b, length, h, dv), dtype)
     lse = jax.ShapeDtypeStruct((b, h, length), jnp.float32)
-    return qk, v, lse, causal
+    return q, k, v, out, lse, causal, window
 
 
 @pytest.mark.parametrize("case", sorted(LOWERED))
@@ -133,53 +229,60 @@ def test_lowers_to_tpu_mosaic_without_a_device(case):
     scratch, iota rank, pl.when predicates) without TPU hardware.  Only
     Mosaic->binary compilation remains device-side."""
     from jax import export as jax_export
-    qk, v, _, causal = _lowered_shapes(case)
+    q, k, v, _, _, causal, window = _lowered_shapes(case)
 
     def f(q, k, v):
-        return flash_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal=causal, window=window)
 
-    exp = jax_export.export(jax.jit(f), platforms=("tpu",))(qk, qk, v)
+    exp = jax_export.export(jax.jit(f), platforms=("tpu",))(q, k, v)
     assert "tpu_custom_call" in exp.mlir_module()
 
 
-@pytest.mark.parametrize("shape,causal,blocks", [
-    ((2, 64, 4, 32), False, (32, 32)),
-    ((2, 64, 4, 32), True, (32, 32)),
-    ((1, 100, 2, 16), True, (32, 32)),  # ragged L: padded q rows, k cols
-    ((1, 96, 2, 16), True, (32, 32)),   # several tiles both directions
+@pytest.mark.parametrize("shape,causal,blocks,grouped", [
+    ((2, 64, 4, 32), False, (32, 32), PLAIN),
+    ((2, 64, 4, 32), True, (32, 32), PLAIN),
+    ((1, 100, 2, 16), True, (32, 32), PLAIN),  # ragged L: padded q rows, k cols
+    ((1, 96, 2, 16), True, (32, 32), PLAIN),   # several tiles both directions
     # the plan's own blocks: the BERT cells' layer (ONE backward kernel,
     # delta = rowsum(P dP) inside it), the same causal, and a padded length
-    ((1, 512, 16, 64), False, (None, None)),
-    ((1, 512, 4, 64), True, (None, None)),
-    ((2, 300, 2, 64), True, (None, None)),
+    ((1, 512, 16, 64), False, (None, None), PLAIN),
+    ((1, 512, 4, 64), True, (None, None), PLAIN),
+    ((2, 300, 2, 64), True, (None, None), PLAIN),
     # an 8k-style plan at a small size: q blocks of 128 against k blocks
     # of 64, so pairs cross, touch and miss the diagonal, and 320 = 2.5
     # q blocks pads the last one; then 5 x 5 blocks of the plan's own
-    ((1, 320, 2, 32), True, (128, 64)),
-    ((1, 640, 4, 32), True, (None, None)),
-    ((1, 640, 4, 32), False, (None, None)),
-])
-def test_flash_backward_matches_dense_vjp(shape, causal, blocks):
+    ((1, 320, 2, 32), True, (128, 64), PLAIN),
+    ((1, 640, 4, 32), True, (None, None), PLAIN),
+    ((1, 640, 4, 32), False, (None, None), PLAIN),
+] + GROUPED)
+def test_flash_backward_matches_dense_vjp(shape, causal, blocks, grouped):
     """flash_attention_bwd (tile-recompute from the saved lse) against
-    the dense reference's vjp, for an arbitrary cotangent."""
-    from geomx_tpu.ops.flash_attention import (flash_attention_bwd,
+    the dense reference's vjp, for an arbitrary cotangent; with grouped
+    heads dk and dv come back with k's and v's own heads."""
+    from geomx_tpu.ops.flash_attention import (attention_plan,
+                                               flash_attention_bwd,
                                                flash_attention_with_lse)
 
+    kv_heads, window = grouped
     rng = np.random.RandomState(12)
-    q, k, v = (jnp.asarray(rng.normal(size=shape).astype(np.float32))
-               for _ in range(3))
+    q, k, v = _qkv(rng, shape, kv_heads)
     g = jnp.asarray(rng.normal(size=shape).astype(np.float32))
+    if blocks == (None, None) and shape[1] <= 512:
+        assert attention_plan(shape[1], shape[1], shape[2], shape[3],
+                              shape[3], q.dtype, causal,
+                              kv_heads=kv_heads).fused_backward
 
     out, lse = flash_attention_with_lse(q, k, v, causal=causal,
                                         block_q=blocks[0], block_k=blocks[1],
-                                        interpret=True)
+                                        interpret=True, window=window)
     dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
                                      block_q=blocks[0], block_k=blocks[1],
-                                     interpret=True)
+                                     interpret=True, window=window)
     assert dq.dtype == q.dtype and dk.dtype == k.dtype
+    assert dk.shape == k.shape and dv.shape == v.shape
 
     def dense(q, k, v):
-        return full_attention_reference(q, k, v, causal=causal)
+        return dense_reference(q, k, v, causal, window)
 
     _, vjp = jax.vjp(dense, q, k, v)
     rq, rk, rv = vjp(g)
@@ -196,13 +299,14 @@ def test_flash_backward_lowers_to_tpu_mosaic_without_a_device(case):
     from jax import export as jax_export
 
     from geomx_tpu.ops.flash_attention import flash_attention_bwd
-    qk, v, lse, causal = _lowered_shapes(case)
+    q, k, v, out, lse, causal, window = _lowered_shapes(case)
 
     def f(q, k, v, out, lse, g):
-        return flash_attention_bwd(q, k, v, out, lse, g, causal=causal)
+        return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
+                                   window=window)
 
     exp = jax_export.export(jax.jit(f), platforms=("tpu",))(
-        qk, qk, v, v, lse, v)
+        q, k, v, out, lse, out)
     assert "tpu_custom_call" in exp.mlir_module()
 
 
@@ -326,8 +430,23 @@ def test_bf16_operands_p_and_ds_rounded_against_dense_on_rounded_inputs(
     ((100, 100, 2, 24, 16, jnp.float32, True), (128, 128, 2, True, 753_664)),
     # 640 = 5 x 128: the largest of 512, 256, 128 that divides it
     ((640, 640, 4, 32, 32, jnp.float32, True), (128, 128, 4, False, 1_310_720)),
+    # grouped-query heads (the last number: key/value heads), 32 on 4 of
+    # 128: four query heads a step share one key/value head forward and in
+    # dq (the running max and normaliser of eight would pass the budget),
+    # the dk/dv kernel takes the whole group of eight, which is the
+    # largest; a band chooses no size
+    ((8192, 8192, 32, 128, 128, jnp.bfloat16, True, None, None, 4),
+     (512, 512, 4, False, 11_010_048)),
+    # one block pair, but a whole group of eight 128-wide heads does not
+    # fit ONE backward kernel: two kernels
+    ((512, 512, 32, 128, 128, jnp.bfloat16, True, None, None, 4),
+     (512, 512, 4, False, 11_010_048)),
+    # whole groups a step where they fit: 16 on 8 of 64, bf16
+    ((512, 512, 16, 64, 64, jnp.bfloat16, True, None, None, 8),
+     (512, 512, 8, True, 12_582_912)),
 ], ids=["bert-bf16", "latent-bf16", "mid-bf16", "bert-f32", "latent-f32",
-        "ragged", "five-blocks"])
+        "ragged", "five-blocks", "grouped-32-on-4", "grouped-one-pair",
+        "grouped-whole-groups"])
 def test_attention_plan_from_the_shapes(args, want):
     from geomx_tpu.ops.flash_attention import (VMEM_BUDGET, AttentionPlan,
                                                attention_plan)
@@ -365,6 +484,33 @@ def test_block_pairs_walk_only_what_holds_a_score(causal, want):
     assert sorted(zip(kj.tolist(), qi.tolist())) == list(
         zip(kj.tolist(), qi.tolist()))
     assert all(f & 4 for i, j, f in zip(qi, kj, flags) if i == 1 or j == 1)
+
+
+def test_block_pairs_under_a_window_fall_with_the_band():
+    """8,192 in blocks of 512 under a band of 2,048 keys: a q block meets
+    its own k block and the four before it, 70 of the causal 136 pairs;
+    the diagonal pair and the one that crosses the band's lower edge run
+    the masked body; no window, or one that hides nothing, is the causal
+    list."""
+    from geomx_tpu.ops.flash_attention import _band, _block_pairs
+    causal = _block_pairs(16, 16, 512, 512, 8192, 8192, True, True)
+    assert len(causal[0][0]) == 136
+    for k_inner in (True, False):
+        (qi, kj, flags), bodies = _block_pairs(
+            16, 16, 512, 512, 8192, 8192, True, k_inner, 2048)
+        assert len(qi) == 70 and bodies == [False, True]
+        assert all(0 <= i - j <= 4 for i, j in zip(qi, kj))
+        assert all(bool(f & 4) == (i - j in (0, 4))
+                   for i, j, f in zip(qi, kj, flags))
+    assert _band(8192, True, 8192) is None and _band(None, True, 64) is None
+    assert _band(2048, True, 8192) == 2048
+    same = _block_pairs(16, 16, 512, 512, 8192, 8192, True, True,
+                        _band(9000, True, 8192))
+    assert all((a == b).all() for a, b in zip(same[0], causal[0]))
+    # a window narrower than a block still keeps the diagonal pairs
+    (qi, kj, _), _ = _block_pairs(4, 4, 128, 128, 512, 512, True, True, 16)
+    assert list(zip(qi.tolist(), kj.tolist())) == [
+        (0, 0), (1, 0), (1, 1), (2, 1), (2, 2), (3, 2), (3, 3)]
 
 
 def test_fused_attention_opens_attn_core_forward_and_backward():

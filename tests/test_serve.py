@@ -948,7 +948,15 @@ def test_native_wire_roundtrip_and_ledger_accounting():
         np.testing.assert_allclose(
             out2["outputs"], np.ones((1, 784), np.float32) @ W,
             rtol=1e-4)
-        s = get_request_ledger().summary()
+        # the server counts a reply's bytes after it has sent them, so
+        # the client may hold the reply before the ledger holds its frame
+        deadline = time.monotonic() + 5.0
+        while True:
+            s = get_request_ledger().summary()
+            if s["wire"]["native"]["frames"] >= 4 \
+                    or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert s["by_transport"].get("native", 0) == 3
         lane = s["wire"]["native"]
         assert lane["frames"] == 4          # 2 rx + 2 tx

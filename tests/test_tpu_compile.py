@@ -171,16 +171,37 @@ def _cell_attention(which, direction):
     """The chip benchmark's own attention calls, bf16: a BERT-large layer
     (16 x 512 x 16 x 64, four heads a step, one backward kernel) and one
     sequence of the decoder's latent attention (8,192 x 32 x 192/128,
-    causal: 136 block pairs of 512, dq and dk/dv kernels)."""
+    causal: 136 block pairs of 512, dq and dk/dv kernels); one sequence of
+    the second decoder's grouped-query attention (8,192 x 32 query heads
+    on 4 key/value heads of 128), in a window layer (a band of 2,048 keys:
+    70 pairs) and in a global one."""
     from geomx_tpu.ops import flash_attention_bwd, flash_attention_with_lse
-    b, L, h, d, dv, causal = {"bert": (16, 512, 16, 64, 64, False),
-                              "latent": (1, 8192, 32, 192, 128, True)}[which]
-    bf16 = lambda e: jax.ShapeDtypeStruct((b, L, h, e), jnp.bfloat16)
+    b, L, h, kv, d, dv, causal, window = {
+        "bert": (16, 512, 16, 16, 64, 64, False, None),
+        "latent": (1, 8192, 32, 32, 192, 128, True, None),
+        "window": (1, 8192, 32, 4, 128, 128, True, 2048),
+        "global": (1, 8192, 32, 4, 128, 128, True, None)}[which]
+    bf16 = lambda heads, e: jax.ShapeDtypeStruct((b, L, heads, e),
+                                                 jnp.bfloat16)
     if direction == "forward":
-        return (functools.partial(flash_attention_with_lse, causal=causal),
-                [bf16(d), bf16(d), bf16(dv)])
-    return (functools.partial(flash_attention_bwd, causal=causal),
-            [bf16(d), bf16(d), bf16(dv), bf16(dv), f32(b, h, L), bf16(dv)])
+        return (functools.partial(flash_attention_with_lse, causal=causal,
+                                  window=window),
+                [bf16(h, d), bf16(kv, d), bf16(kv, dv)])
+    return (functools.partial(flash_attention_bwd, causal=causal,
+                              window=window),
+            [bf16(h, d), bf16(kv, d), bf16(kv, dv), bf16(h, dv),
+             f32(b, h, L), bf16(h, dv)])
+
+
+def _grouped_narrow():
+    """Grouped heads narrower than a lane tile (8 on 2 of 64, float32, a
+    band of 300 keys over 1,024): a query head and its key/value head sit
+    differently in their tiles, so the kernels slice the heads' own
+    columns."""
+    from geomx_tpu.ops import flash_attention_bwd
+    q, kv = f32(1, 1024, 8, 64), f32(1, 1024, 2, 64)
+    return (functools.partial(flash_attention_bwd, causal=True, window=300),
+            [q, kv, kv, q, f32(1, 8, 1024), q])
 
 
 def _ring_hop(L):
@@ -247,6 +268,15 @@ CASES = {
         "latent", "forward"),
     "flash_attention_bwd-bf16-latent-sequence": lambda: _cell_attention(
         "latent", "backward"),
+    "flash_attention-bf16-grouped-window-sequence": lambda: _cell_attention(
+        "window", "forward"),
+    "flash_attention_bwd-bf16-grouped-window-sequence":
+        lambda: _cell_attention("window", "backward"),
+    "flash_attention-bf16-grouped-global-sequence": lambda: _cell_attention(
+        "global", "forward"),
+    "flash_attention_bwd-bf16-grouped-global-sequence":
+        lambda: _cell_attention("global", "backward"),
+    "flash_attention_bwd-f32-grouped-64-wide": lambda: _grouped_narrow(),
     "fused_ring_hop-L1024": lambda: _ring_hop(1024),
     "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
     "merge_tree-2x82": lambda: _merge(164, 1),
@@ -314,20 +344,31 @@ def test_v5e_compiler_accepts_the_kda_kernels(chip, direction):
     assert (plan.heads, plan.chunks) == (4, 4)
 
 
+# (tokens, hidden, held, width, tile, first pool): the two decoder cells'
+# expert layers
+HELD_EXPERTS = {
+    "kimi-8-of-256": (16384, 2304, 8, 1024, 512, None),
+    "trinity-16-of-128": (16384, 2048, 16, 1024, 512, 32768),
+}
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
-def test_v5e_compiler_accepts_the_held_experts(chip, direction):
-    """The held experts' walk at the chip benchmark's sizes (16,384 tokens
-    of 2,304, top 8 of 256, 8 held experts of 1,024, bf16 operands): the
-    grouped-product kernels' tiles have to fit VMEM, forward and
-    backward."""
+@pytest.mark.parametrize("cell", sorted(HELD_EXPERTS))
+def test_v5e_compiler_accepts_the_held_experts(chip, cell, direction):
+    """The held experts' walk at the chip benchmark's sizes (16,384 tokens,
+    top 8, bf16 operands: 8 held experts of 1,024 at hidden 2,304 with the
+    default first pool of 8,192 places; 16 held at hidden 2,048 with a
+    first pool of 32,768): the grouped-product kernels' tiles have to fit
+    VMEM, forward and backward."""
     from geomx_tpu.ops.held_experts import held_experts
+    tokens, d, held, f, rows, pool = HELD_EXPERTS[cell]
     on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=chip)
-    args = [on((16384, 2304), jnp.bfloat16), on((16384, 8), jnp.int32),
-            on((16384, 8), jnp.float32), on((8, 2304, 1024), jnp.float32),
-            on((8, 2304, 1024), jnp.float32), on((8, 1024, 2304), jnp.float32)]
-    run = lambda x, idx, w, *mats: held_experts(x, idx, w, *mats, 0, 512,
-                                                False)
+    args = [on((tokens, d), jnp.bfloat16), on((tokens, 8), jnp.int32),
+            on((tokens, 8), jnp.float32), on((held, d, f), jnp.float32),
+            on((held, d, f), jnp.float32), on((held, f, d), jnp.float32)]
+    run = lambda x, idx, w, *mats: held_experts(x, idx, w, *mats, 0, rows,
+                                                False, pool)
     if direction == "backward":
         fn = jax.grad(lambda x, idx, w, *mats: jnp.sum(
             run(x, idx, w, *mats)[0]), argnums=(0, 2, 3, 4, 5))

@@ -1,4 +1,4 @@
-"""Time the held experts' layer on the chip, at the benchmark cell's size.
+"""Time the held experts' layer on the chip, at a benchmark cell's size.
 
 Makes ``T`` tokens of width ``d`` (bfloat16), ``E`` held experts of width
 ``f`` and, for each named load, a routing in which held expert ``e``
@@ -6,10 +6,17 @@ gets exactly the load's ``e``-th count of assignments (each on a token
 of its own, the other choices on absent experts); times one jitted
 program that runs `ops.held_experts.held_experts` forward and backward
 (value and every gradient) and prints milliseconds per call, medians
-over ``--reps`` runs after a warm-up:
+over ``--reps`` runs after a warm-up.  ``--config <file>`` takes ``T``,
+``d``, ``f``, ``E``, the router's width and the experts a token from a
+configuration's file (`benchmark/configs/*.json`: the decoder cells'), and
+the tile and first pool from its ``program``; the loads are the Kimi
+cell's patterns (8 held experts, 512 assignments each under even routing)
+repeated over the held experts and scaled to the file's even load.
 
-- ``new``: the module as it stands; ``new@k,n/k,n``: the same with other
-  caps on the kernels' tiles (`GMM_TILES` / `TGMM_TILES`);
+- ``new``: the module as it stands, once for each ``--shapes`` entry
+  ``rows[:pool]`` (the kernels' tile, the first pool's places; no pool:
+  2 E rows); ``new@k,n/k,n``: the same with other caps on the kernels'
+  tiles (`GMM_TILES` / `TGMM_TILES`);
 - ``other``: ``held_experts`` of the module given with ``--other`` (a
   parent commit's file), with every gradient's distance from ``new``'s
   over its norm.
@@ -21,6 +28,9 @@ once: the rematerialised forward needs the sorted assignments again, not
 the walk.  One JSON line per variant and load.
 
     python tools/held_experts_timing.py --other _scratch/held_experts_old.py
+    python tools/held_experts_timing.py \
+        --config benchmark/configs/trinity-mini-ep8.json \
+        --shapes "512:32768 1024 512"
 """
 import argparse
 import importlib.util
@@ -32,6 +42,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+# at 8 held experts and an even load of 512 each (the Kimi cell)
 LOADS = {
     "even": [512] * 8,
     "seeded": [4, 60, 200, 350, 500, 700, 900, 3406],
@@ -42,12 +53,26 @@ LOADS = {
 }
 
 
+def loads(held: int, even: int, tokens: int) -> dict:
+    """LOADS' patterns over `held` experts at `even` assignments each; an
+    expert gets a token at most once."""
+    return {name: [min(tokens, round(pattern[e % 8] * even / 512))
+                   for e in range(held)]
+            for name, pattern in LOADS.items()}
+
+
 def routing(rng, tokens, top_k, counts, absent):
-    """idx [T, k]: expert e (column e) on counts[e] distinct tokens."""
+    """idx [T, k]: expert e on counts[e] distinct tokens, each in the
+    token's next free choice (more experts may be held than a token has
+    choices)."""
     import numpy as np
     idx = np.full((tokens, top_k), absent, np.int32)
+    used = np.zeros(tokens, np.int64)
     for e, count in enumerate(counts):
-        idx[rng.choice(tokens, size=min(count, tokens), replace=False), e] = e
+        free = np.flatnonzero(used < top_k)
+        chosen = rng.choice(free, size=min(count, free.size), replace=False)
+        idx[chosen, used[chosen]] = e
+        used[chosen] += 1
     return idx
 
 
@@ -64,10 +89,17 @@ def median_ms(fn, args, reps):
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--config", default=None,
+                        help="a decoder configuration's file: sizes, tile "
+                             "and pool come from it")
     parser.add_argument("--tokens", type=int, default=16384)
     parser.add_argument("--hidden", type=int, default=2304)
     parser.add_argument("--width", type=int, default=1024)
-    parser.add_argument("--rows", type=int, default=512)
+    parser.add_argument("--held", type=int, default=8)
+    parser.add_argument("--router", type=int, default=256)
+    parser.add_argument("--shapes", default=None,
+                        help="`rows[:pool]` variants, separated by spaces; "
+                             "default: the configuration's, else 512")
     parser.add_argument("--reps", type=int, default=5)
     parser.add_argument("--other", default=None,
                         help="path of another held_experts.py to time too")
@@ -85,19 +117,43 @@ def main(argv=None) -> int:
     from geomx_tpu.ops import held_experts as ours
 
     rng = np.random.default_rng(0)
-    top_k, held = 8, 8
+    top_k, held, router = 8, args.held, args.router
     t, d, f = args.tokens, args.hidden, args.width
+    shapes = args.shapes or "512"
+    if args.config:
+        with open(args.config) as fh:
+            config = json.load(fh)
+        top_k = config.get("num_experts_per_tok",
+                           config.get("num_experts_per_token"))
+        held, router = config["num_experts"], config["router_experts"]
+        t = config["per_chip_batch"] * config["sequence_length"]
+        d, f = config["hidden_size"], config["moe_intermediate_size"]
+        run_keys = config.get("program", {})
+        pool = run_keys.get("expert_pool_places")
+        shapes = args.shapes or (
+            str(run_keys.get("expert_block_rows", 512))
+            + (f":{pool}" if pool else ""))
+    shapes = [tuple(int(v) for v in entry.split(":"))
+              for entry in shapes.split()]
+    # a shape's first pool: its own, else the module's default 2 E rows
+    first_pool = lambda shape: (shape[1:] or (2 * held * shape[0],))[0]
+    by_load = loads(held, t * top_k // router, t)
     x = jnp.asarray(rng.standard_normal((t, d)), jnp.bfloat16)
     w = jnp.asarray(rng.uniform(0.1, 0.5, (t, top_k)), jnp.float32)
     r = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
     mats = [jnp.asarray(rng.standard_normal(s) * 0.02, jnp.float32)
             for s in ((held, d, f), (held, d, f), (held, f, d))]
-    idxs = {name: jnp.asarray(routing(rng, t, top_k, LOADS[name], 200))
+    idxs = {name: jnp.asarray(routing(rng, t, top_k, by_load[name],
+                                      router - 1))
             for name in args.loads.split(",")}
 
-    def program(fn):
+    def program(fn, rows, pool=None):
+        # a module of before the first pool had a size of its own takes none
+        more = () if pool is None else (None, pool)
+
         def loss(x_, w_, gate, up, down, idx):
-            y, counts, dropped = fn(x_, idx, w_, gate, up, down, 0, args.rows)
+            y, counts, dropped = fn(x_, idx, w_, gate, up, down, 0, rows,
+                                    *more)
             return jnp.sum(y * r), (counts, dropped)
         return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4),
                                           has_aux=True))
@@ -111,22 +167,25 @@ def main(argv=None) -> int:
             g, tg = caps.split("/")
             ours.GMM_TILES = tuple(int(v) for v in g.split(","))
             ours.TGMM_TILES = tuple(int(v) for v in tg.split(","))
-        run = program(ours.held_experts)
-        for load, idx in idxs.items():
-            (_, (counts, dropped)), grads = run(x, w, *mats, idx)
-            assert int(dropped) == 0 and [int(c) for c in counts] == [
-                min(c, t) for c in LOADS[load]], (load, counts, dropped)
-            if name == "new":
-                reference[load] = grads
-            print(json.dumps({"variant": name, "load": load,
-                              "assignments": sum(LOADS[load]),
-                              "ms": median_ms(run, (x, w, *mats, idx),
-                                              args.reps)}), flush=True)
+        for shape in shapes:
+            run = program(ours.held_experts, *shape)
+            for load, idx in idxs.items():
+                (_, (counts, dropped)), grads = run(x, w, *mats, idx)
+                assert int(dropped) == 0 and [int(c) for c in counts] \
+                    == by_load[load], (load, counts, dropped)
+                if name == "new" and shape == shapes[0]:
+                    reference[load] = grads
+                print(json.dumps({
+                    "variant": name, "rows": shape[0],
+                    "pool": first_pool(shape),
+                    "load": load, "assignments": sum(by_load[load]),
+                    "ms": median_ms(run, (x, w, *mats, idx), args.reps)}),
+                    flush=True)
     if args.pieces:
-        pool = 2 * held * args.rows
+        pool = first_pool(shapes[0])
         rows = jnp.asarray(rng.standard_normal((pool, d)), jnp.float32)
         for load in ("even", "none"):
-            n = min(sum(LOADS[load]), pool)
+            n = min(sum(by_load[load]), pool)
             token = jnp.asarray(np.concatenate([
                 rng.integers(0, t, n), t + np.arange(pool - n)]), jnp.int32)
             pieces = {
@@ -145,7 +204,7 @@ def main(argv=None) -> int:
         spec = importlib.util.spec_from_file_location("other", args.other)
         other = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(other)
-        run = program(other.held_experts)
+        run = program(other.held_experts, shapes[0][0])
         for load, idx in idxs.items():
             _, grads = run(x, w, *mats, idx)
             off = [float(jnp.linalg.norm((a - b).astype(jnp.float32).ravel())
@@ -153,7 +212,7 @@ def main(argv=None) -> int:
                              b.astype(jnp.float32).ravel()), 1e-30))
                    for a, b in zip(reference[load], grads)]
             print(json.dumps({"variant": "other", "load": load,
-                              "assignments": sum(LOADS[load]),
+                              "assignments": sum(by_load[load]),
                               "ms": median_ms(run, (x, w, *mats, idx),
                                               args.reps),
                               "new_vs_other_grad_error": dict(zip(
