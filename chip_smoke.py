@@ -327,6 +327,27 @@ def kernels_phase(seed: int, interpret: bool = False, model=None) -> dict:
     same("fused_adam/moments", got[1:], want[1:])
     close("fused_adam/params", got[0], want[0], rtol=1e-6, atol=1e-8)
 
+    # an expert pool's rows back into the token array: 1,024 places in
+    # two tiles, the second half full; a token sits in up to three runs,
+    # so the segments meet a row again (small whole numbers: exact in any
+    # order)
+    from geomx_tpu.ops.moe_rows_pallas import (moe_row_scatter_add,
+                                               row_scatter_add_ref)
+    tokens, wide, places = 512, 256, 1024
+    runs = np.asarray([300, 0, 412, 56], np.int32)
+    valid = int(runs.sum())
+    place_token = jnp.asarray(np.concatenate(
+        [rng.permutation(tokens)[:size] for size in runs]
+        + [tokens + np.arange(places - valid)]).astype(np.int32))
+    y0 = jnp.asarray(rng.randint(-8, 9, (tokens, wide)).astype(np.float32))
+    addends = jnp.asarray(np.where(
+        np.arange(places)[:, None] < valid,
+        rng.randint(-8, 9, (places, wide)), 0).astype(np.float32))
+    same("moe_row_scatter_add",
+         moe_row_scatter_add(y0, addends, place_token, jnp.asarray(runs),
+                             interpret=interpret),
+         jax.jit(row_scatter_add_ref)(y0, addends, place_token, runs))
+
     return emit("kernels", native=not interpret, elements=n, k=k,
                 leaves=len(leaves), checked=checked)
 

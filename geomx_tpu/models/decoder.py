@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from geomx_tpu.ops.held_experts import held_experts
+from geomx_tpu.ops.held_experts import held_experts, places_walked
 from geomx_tpu.utils.profiler import profile_scope
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -234,7 +234,8 @@ class DecoderLM(nn.Module):
         """(mean cross-entropy of ``labels`` [B, L], aux).  ``aux`` holds
         ``accuracy`` and, where an expert layer exists, ``counters``:
         scalars a step (assignments per held expert and layer as min,
-        mean, max, and those dropped)."""
+        mean, max, those dropped, and the rows the expert layers' dispatch
+        moved over the places their pools walked)."""
         h, arrived, dropped = self.features(tokens)
         with profile_scope("lm/loss", "compute"):
             total, hits = blocked_cross_entropy(
@@ -243,12 +244,18 @@ class DecoderLM(nn.Module):
                 self.cfg.loss_block)
         aux = {"accuracy": hits / labels.size}
         if arrived.size:
+            c = self.cfg
+            walked = jnp.sum(places_walked(
+                jnp.sum(arrived.reshape(-1, c.experts_held), axis=1),
+                c.experts_held, c.expert_rows, c.expert_pool))
+            moved = jnp.sum(arrived) - dropped
             arrived = arrived.astype(jnp.float32)
             aux["counters"] = {
                 "moe/assignments_min": jnp.min(arrived),
                 "moe/assignments_mean": jnp.mean(arrived),
                 "moe/assignments_max": jnp.max(arrived),
-                "moe/dropped": dropped.astype(jnp.float32)}
+                "moe/dropped": dropped.astype(jnp.float32),
+                "moe/pool_fill": moved / walked.astype(jnp.float32)}
         return total / labels.size, aux
 
 

@@ -1,12 +1,13 @@
 """Kernel or XLA: the one place that decides, from the platform.
 
-Every op of the compression engine, and a decoder's KDA scan, exists
-twice under ``ops/``: a Pallas kernel and a jnp form with the same results
-(bit for bit, except where an op's docstring says otherwise).  The kernel
-is what a TPU runs; the jnp form is the only path elsewhere and the oracle
-the tests hold the kernel to.  The functions below are what
-``compression/`` and ``models/`` call: each picks its implementation
-while the program is traced, from :func:`kernel_mode`.
+Every op of the compression engine, a decoder's KDA scan and its expert
+pools' row scatter-add exist twice under ``ops/``: a Pallas kernel and
+a jnp form with the same results (bit for bit, except where an op's
+docstring says otherwise).  The kernel is what a TPU runs; the jnp form
+is the only path elsewhere and the oracle the tests hold the kernel to.
+The functions below are what ``compression/``, ``models/`` and
+``ops/held_experts.py`` call: each picks its implementation while the
+program is traced, from :func:`kernel_mode`.
 Nothing above ``ops/`` (a compressor's arguments, the spec string,
 ``GeoConfig``, the environment) can choose, and nothing above it asks.
 
@@ -25,7 +26,8 @@ from typing import Optional
 import jax
 
 from geomx_tpu.ops import (bsc_pallas, bucket_pallas, kda as kda_jnp,
-                           kda_pallas, merge_pallas, twobit_pallas)
+                           kda_pallas, merge_pallas, moe_rows_pallas,
+                           twobit_pallas)
 
 _OVERRIDE: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "geomx_kernel_mode", default=None)
@@ -131,3 +133,21 @@ def kda(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
                                    dtype=dtype)
     return kda_pallas.kda_scan(q, k, v, g, beta, chunk, sub, dtype,
                                mode == "interpret")
+
+
+def row_scatter_add(y, out, token, sizes):
+    """An expert pool's rows back into the token array, ``y[token] += out``
+    in float32: ``y`` [T, d], ``out`` [places, d], ``token`` [places];
+    ``sizes`` [E] are the places of each expert's run, in order from place
+    0 (a token is unique inside a run); the places behind the runs are
+    dropped.  The kernel of ``moe_rows_pallas`` where :func:`kernel_mode`
+    names one and a row of ``d`` floats is whole tiles as a slab
+    (``moe_rows_pallas.slabs_are_whole``: the width decides, as a bucket's
+    size decides how the boundary probe fetches), else XLA's scatter-add.
+    Equal wherever a token sits at most twice in the pool, and within the
+    reordering of a float32 sum of its addends elsewhere."""
+    mode = kernel_mode()
+    if mode is None or not moe_rows_pallas.slabs_are_whole(y.shape[1]):
+        return moe_rows_pallas.row_scatter_add_ref(y, out, token, sizes)
+    return moe_rows_pallas.moe_row_scatter_add(
+        y, out, token, sizes, interpret=mode == "interpret")

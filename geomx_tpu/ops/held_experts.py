@@ -11,12 +11,19 @@ assignment, each with its expert's weights), scatter-add the weighted
 result once.  The first pool has ``pool`` places (``2 * E * rows`` where
 none is given: twice what even routing sends here when ``rows`` is an
 expert's share; a caller whose experts see more gives twice its own even
-load, a multiple of ``rows``) and is always
-walked: a row gather or scatter costs the chip the same per index
-whether the row exists or not (0.1 and 0.4 us, PERF.md), so up to twice
-even load the layer's time hardly moves with what the router does, at
-the price of gathering and scattering places that are mostly empty when
-little arrives.  What arrives beyond it is walked in pools of
+load, a multiple of ``rows``) and is always walked, so up to twice even
+load the layer's program does not change with what the router does.  The
+pool's rows come in by XLA's row gather, which costs what its bytes cost
+inside a program (6 ns a place on a v5e, PERF.md), and go back through
+``ops.dispatch.row_scatter_add`` (both under scope ``moe/dispatch``): on
+a TPU, at a width of whole tiles (a multiple of 1,024: the door says
+why), the kernel of ``ops/moe_rows_pallas.py``, two copies a row that
+arrived and none for a tile of places that holds no assignment, so the
+move costs the rows, not the places; elsewhere XLA's own scatter-add,
+which on a v5e pays by the index whether the row exists or not (93 ns a
+place in the step: why the first pool was made large and walked whatever
+arrives).
+What arrives beyond it is walked in pools of
 ``2 * rows`` by a loop of as many trips as it needs: one expert may take
 every token (``T`` rows, the worst case) and nothing is dropped, because
 no capacity exists to overflow.  The walk counts the rows it processed;
@@ -27,7 +34,8 @@ JAX, so the backward pass is written out (`custom_vjp`): the same walk,
 each pool's hidden layer recomputed, weight gradients accumulated in the
 kernels' output.
 
-The kernels run natively on a TPU and in Pallas interpret mode elsewhere.
+The grouped-product kernels run natively on a TPU and in Pallas interpret
+mode elsewhere; the row moves take their jnp forms off a TPU.
 """
 
 from __future__ import annotations
@@ -39,7 +47,12 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
 
-from geomx_tpu.ops.dispatch import kernel_mode
+from geomx_tpu.ops.dispatch import kernel_mode, row_scatter_add
+from geomx_tpu.utils.profiler import profile_scope
+
+# the pools' row moves, forward and backward, whichever implementation
+# `ops/dispatch.py` picks (telemetry/layers.SCOPES)
+_DISPATCH = "moe/dispatch"
 
 # the kernels' tiles over the contracted and the output dimension, at most
 GMM_TILES = (1152, 768)
@@ -114,6 +127,13 @@ def _walk(plan, num_held: int, rows: int, pool, body, carry):
         carry)
 
 
+def places_walked(assignments, num_held: int, rows: int, pool=None):
+    """Places the walk covers when `assignments` (a traced count) fall on
+    the held experts: the first pool and the later ones they reach."""
+    first, later, _ = _pools(num_held, rows, 0, pool)
+    return first + -(-jnp.maximum(assignments - first, 0) // later) * later
+
+
 def _pool(plan, lo, pool: int):
     """(token ids, weights, valid, rows of each expert's run) of the
     `pool` sorted assignments from `lo` on.  Rows past the last held
@@ -166,12 +186,14 @@ def _forward(x, idx, weights, gate, up, down, offset, rows, interpret, pool):
     def body(lo, places, carry):
         y, done = carry
         token, weight, valid, sizes = _pool(plan, lo, places)
-        xs = x.at[token].get(mode="fill", fill_value=0)
+        with profile_scope(_DISPATCH, "kernel"):
+            xs = x.at[token].get(mode="fill", fill_value=0)
         h = _hidden(xs, gate_up, sizes, valid, rows, interpret)[2]
         out = _gmm(h.astype(x.dtype), down, sizes, rows, interpret)
         out = jnp.where(valid[:, None], out * weight[:, None], 0.0)
-        return (y.at[token].add(out, mode="drop"),
-                done + jnp.sum(valid, dtype=jnp.int32))
+        with profile_scope(_DISPATCH, "kernel"):
+            y = row_scatter_add(y, out, token, sizes)
+        return y, done + jnp.sum(sizes)
 
     y, done = _walk(
         plan, gate.shape[0], rows, pool, body,
@@ -196,8 +218,9 @@ def _bwd(offset, rows, interpret, pool, res, cotangents):
     def body(lo, places, carry):
         dx, dw, dgate_up, ddown = carry
         token, weight, valid, sizes = _pool(plan, lo, places)
-        xs = x.at[token].get(mode="fill", fill_value=0)
-        dys = dy.at[token].get(mode="fill", fill_value=0)
+        with profile_scope(_DISPATCH, "kernel"):
+            xs = x.at[token].get(mode="fill", fill_value=0)
+            dys = dy.at[token].get(mode="fill", fill_value=0)
         a, u, h = _hidden(xs, gate_up, sizes, valid, rows, interpret)
         # <h W_down, dy> = <h, dy W_down^T>: one product gives the weight's
         # gradient and, scaled by the weight, the hidden layer's
@@ -213,7 +236,9 @@ def _bwd(offset, rows, interpret, pool, res, cotangents):
         dgate_up = _tgmm(xs, dau, sizes, rows, interpret, dgate_up)
         dxs = jnp.where(valid[:, None],
                         _gmm(dau, gate_up, sizes, rows, interpret, True), 0.0)
-        return (dx.at[token].add(dxs, mode="drop"), dw, dgate_up, ddown)
+        with profile_scope(_DISPATCH, "kernel"):
+            dx = row_scatter_add(dx, dxs, token, sizes)
+        return dx, dw, dgate_up, ddown
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
     dx, dw, dgate_up, ddown = _walk(

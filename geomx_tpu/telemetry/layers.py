@@ -52,6 +52,9 @@ SCOPES = (
     ("gqa/global", "kernels"),
     ("moe/route", "step program"),
     ("moe/experts", "step program"),
+    # a pool's row gathers and scatter-adds, opened inside moe/experts
+    # (ops/held_experts.py)
+    ("moe/dispatch", "step program"),
     ("moe/shared", "step program"),
     ("lm/loss", "step program"),
     # attention's core, forward and backward, opened by

@@ -264,7 +264,8 @@ def test_the_compiled_step_names_the_decoders_layers():
     scopes = {entry.scope for entry in op_layers(text).values()
               if entry.scope}
     for needle in ("gqa/proj", "gqa/window/attn/core", "gqa/global/attn/core",
-                   "moe/route", "moe/experts", "moe/shared", "lm/loss"):
+                   "moe/route", "moe/experts", "moe/dispatch", "moe/shared",
+                   "lm/loss"):
         assert any(needle in s for s in scopes), (needle, sorted(scopes))
 
 
@@ -294,3 +295,5 @@ def test_trainer_takes_the_loss_from_the_model_and_counts():
     counters = trainer.loop_stats.as_dict()["counters"]
     assert counters["moe/dropped"]["total"] == 0.0
     assert counters["moe/assignments_mean"]["count"] == 8
+    assert counters["moe/pool_fill"]["count"] == 8
+    assert 0.0 < counters["moe/pool_fill"]["max"] <= 1.0

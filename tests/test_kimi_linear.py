@@ -92,7 +92,7 @@ def test_whole_logits_agree_with_the_blocked_loss_and_the_reference():
     np.testing.assert_allclose(loss, jnp.mean(logz - picked), rtol=1e-6)
     assert set(aux["counters"]) == {
         "moe/assignments_min", "moe/assignments_mean", "moe/assignments_max",
-        "moe/dropped"}
+        "moe/dropped", "moe/pool_fill"}
     assert float(aux["counters"]["moe/dropped"]) == 0.0
     # 2 expert layers x 4 held of 16 experts: 80 tokens x top-4 a layer,
     # a quarter of them here on average
@@ -100,6 +100,12 @@ def test_whole_logits_agree_with_the_blocked_loss_and_the_reference():
     assert (counters["moe/assignments_min"] <= counters["moe/assignments_mean"]
             <= counters["moe/assignments_max"] <= 80)
     assert 0 < counters["moe/assignments_mean"] * 8 <= 2 * 80 * 4
+    # rows moved over places walked: each layer's first pool (2 x 4 held x
+    # the tile's rows) holds what arrived
+    pools = 2 * 2 * 4 * model.cfg.expert_rows
+    np.testing.assert_allclose(
+        counters["moe/pool_fill"],
+        counters["moe/assignments_mean"] * 8 / pools, rtol=1e-6)
 
 
 def test_a_model_without_expert_layers_counts_nothing():
@@ -172,6 +178,7 @@ def test_the_new_scopes_are_pairs_of_the_vocabulary():
                          ("mla/attention", "kernels"),
                          ("moe/route", "step program"),
                          ("moe/experts", "step program"),
+                         ("moe/dispatch", "step program"),
                          ("moe/shared", "step program"),
                          ("lm/loss", "step program")]:
         assert layer_of(scope) == layer
@@ -203,5 +210,6 @@ def test_the_compiled_step_names_the_decoders_layers():
     scopes = {entry.scope for entry in op_layers(text).values()
               if entry.scope}
     for needle in ("kda/proj", "kda/scan", "mla/proj", "mla/attention",
-                   "moe/route", "moe/experts", "moe/shared", "lm/loss"):
+                   "moe/route", "moe/experts", "moe/dispatch", "moe/shared",
+                   "lm/loss"):
         assert any(needle in s for s in scopes), (needle, sorted(scopes))

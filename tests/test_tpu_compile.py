@@ -352,6 +352,13 @@ HELD_EXPERTS = {
 }
 
 
+def _kernel_calls(text):
+    """The compiled program's Pallas calls by instruction name, in order."""
+    import re
+    return re.findall(r"%([\w.-]+) = [^\n]*custom_call_target="
+                      r'"tpu_custom_call"', text)
+
+
 @pytest.mark.parametrize("direction", ["forward", "backward"])
 @pytest.mark.parametrize("cell", sorted(HELD_EXPERTS))
 def test_v5e_compiler_accepts_the_held_experts(chip, cell, direction):
@@ -359,7 +366,13 @@ def test_v5e_compiler_accepts_the_held_experts(chip, cell, direction):
     top 8, bf16 operands: 8 held experts of 1,024 at hidden 2,304 with the
     default first pool of 8,192 places; 16 held at hidden 2,048 with a
     first pool of 32,768): the grouped-product kernels' tiles have to fit
-    VMEM, forward and backward."""
+    VMEM, forward and backward; at 2,048 the pools' rows go back through
+    the kernel `moe_row_scatter_add` (ops/moe_rows_pallas.py: y forward, dx
+    backward), which `moe_dispatch_ms` finds by its scope, and no XLA
+    scatter of rows is left (the gathers are XLA's: they cost what their
+    bytes cost); at 2,304 the door keeps XLA's scatter-add."""
+    import re
+    from geomx_tpu.ops import dispatch
     from geomx_tpu.ops.held_experts import held_experts
     tokens, d, held, f, rows, pool = HELD_EXPERTS[cell]
     on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
@@ -374,8 +387,35 @@ def test_v5e_compiler_accepts_the_held_experts(chip, cell, direction):
             run(x, idx, w, *mats)[0]), argnums=(0, 2, 3, 4, 5))
     else:
         fn = run
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    with dispatch.kernels("native"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = _kernel_calls(text)
+    moves = [c.split(".")[0] for c in calls if c.startswith("moe_row")]
+    assert any(c.startswith("gmm") for c in calls), calls
+    xla_scatters = re.search(r"= f32\[\d+,%d\]\S* scatter\(" % d, text)
+    if d % 1024 == 0:
+        # the first pool and the `while`'s later pools, each once
+        assert moves == ["moe_row_scatter_add"] * 2, calls
+        assert not xla_scatters
+    else:
+        # slabs of 2,304 would pad: the door keeps XLA's scatter-add
+        assert moves == [] and xla_scatters, calls
+
+
+@pytest.mark.parametrize("cell", sorted(HELD_EXPERTS))
+def test_the_row_kernel_carries_the_name_the_docs_give(chip, cell):
+    """The scatter-add kernel alone at a cell's first pool: one custom
+    call, by its name, at the tile its VMEM budget gives."""
+    from geomx_tpu.ops import moe_rows_pallas as rows_ops
+    tokens, d, held, _, rows, pool = HELD_EXPERTS[cell]
+    places = pool or 2 * held * rows
+    on = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=chip)
+    text = jax.jit(rows_ops.moe_row_scatter_add).lower(
+        on((tokens, d), jnp.float32), on((places, d), jnp.float32),
+        on((places,), jnp.int32), on((held,), jnp.int32)).compile().as_text()
+    calls = _kernel_calls(text)
+    assert [c.split(".")[0] for c in calls] == ["moe_row_scatter_add"], calls
+    assert rows_ops.tile_rows(places, d) == (512 if d == 2048 else 256)
 
 
 def test_fused_bucket_kernels_refuse_what_vmem_cannot_hold():

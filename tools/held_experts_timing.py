@@ -21,11 +21,15 @@ repeated over the held experts and scaled to the file's even load.
   parent commit's file), with every gradient's distance from ``new``'s
   over its norm.
 
-``--pieces`` times the first pool's gather and its scatter-add alone (a
-host-timed call costs ~0.6 ms whatever it does).  A step of the cell calls
-the layer four times (its four expert layers), each forward and backward
-once: the rematerialised forward needs the sorted assignments again, not
-the walk.  One JSON line per variant and load.
+``--pieces`` times the first pool's row gather (XLA's) and its scatter-add
+(XLA's, and the kernel of `ops/moe_rows_pallas.py`) alone at four loads,
+many calls in one program with the operands made inside it (a host-timed
+call costs ~0.6 ms whatever it does, and reads cold operands), every row
+of the kernel's compared with XLA's (exit 2 where they differ).  A step of
+the cell calls the layer four times (its four expert layers), each forward
+and backward once; the rematerialised forward needs the sorted assignments
+again and, where the block has a post-norm that reads `y` (the Trinity
+cell), the walk too.  One JSON line per variant and load.
 
     python tools/held_experts_timing.py --other _scratch/held_experts_old.py
     python tools/held_experts_timing.py \
@@ -87,6 +91,105 @@ def median_ms(fn, args, reps):
     return statistics.median(times)
 
 
+PIECE_LOADS = ("none", "even", "seeded", "one_takes_all")
+CALLS = 8       # of a piece in one timed program
+
+
+def pieces(args, x, r, by_load, pool, rng) -> bool:
+    """The first pool's two moves alone: XLA's row gather, and the
+    scatter-add as XLA's (plain and with `unique_indices`) beside the
+    kernel (`ops/moe_rows_pallas.py`), into a made y and into the zeros a
+    walk starts from (the kernel's relayout of y is then nothing).
+    `CALLS` calls share one jitted program that makes each call's operands
+    inside it (one elementwise pass behind an optimization barrier: what
+    the step's layers leave the move), and the making, timed alone, is
+    taken off.  One JSON line a
+    load and piece; False where the kernel's rows differ from XLA's."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from geomx_tpu.ops import moe_rows_pallas as rows_ops
+
+    t, d = x.shape
+    interpret = jax.default_backend() != "tpu"
+    out0 = jnp.asarray(rng.standard_normal((pool, d)), jnp.float32)
+
+    def sorted_places(counts):
+        """(token [pool], sizes [E]): each expert's run on tokens of its
+        own, cut at the pool's end; ids past the runs point outside."""
+        ends = np.minimum(np.cumsum(counts), pool)
+        sizes = np.diff(ends, prepend=0)
+        runs = [rng.choice(t, size, replace=False) for size in sizes]
+        pad = t + np.arange(pool - ends[-1])
+        return (jnp.asarray(np.concatenate(runs + [pad]), jnp.int32),
+                jnp.asarray(sizes, jnp.int32))
+
+    def every_call(move, operands):
+        """One program: `CALLS` times make the operands and move them
+        (None: make them only)."""
+        def one(c, acc, token, sizes):
+            step = 1.0 + c.astype(jnp.float32) * 2.0 ** -10
+            made = jax.lax.optimization_barrier(tuple(
+                (a * step.astype(a.dtype)) for a in operands))
+            moved = jax.lax.optimization_barrier(
+                made[0] if move is None else move(*made, token, sizes))
+            return acc + moved[0, 0].astype(jnp.float32)
+        return jax.jit(lambda token, sizes: jax.lax.fori_loop(
+            0, CALLS, lambda c, acc: one(c, acc, token, sizes),
+            jnp.zeros((), jnp.float32)))
+
+    gathers = {
+        "xla": lambda x_, token, sizes: x_.at[token].get(
+            mode="fill", fill_value=0)}
+    scatters = {
+        "xla": lambda y, out, token, sizes: rows_ops.row_scatter_add_ref(
+            y, out, token, None),
+        "xla_unique": lambda y, out, token, sizes: y.at[token].add(
+            out, mode="drop", unique_indices=True),
+        "kernel": lambda y, out, token, sizes: rows_ops.moe_row_scatter_add(
+            y, out, token, sizes, interpret=interpret)}
+    # as a walk's first pool does it: into the zeros y starts from
+    zeros = lambda move: lambda out, token, sizes: move(
+        jnp.zeros((t, d), jnp.float32), out, token, sizes)
+    ok = True
+    for piece, moves, operands in (
+            ("gather", gathers, (x,)),
+            ("scatter_add", scatters, (r, out0)),
+            ("scatter_add_into_zeros",
+             {name: zeros(move) for name, move in scatters.items()},
+             (out0,))):
+        make = every_call(None, operands)
+        programs = {name: every_call(move, operands)
+                    for name, move in moves.items()}
+        for load in PIECE_LOADS:
+            token, sizes = sorted_places(by_load[load])
+            real = int(jnp.sum(sizes))
+            line = {"piece": piece, "load": load, "places": pool, "d": d,
+                    "dtype": str(operands[0].dtype), "real": real}
+            if "kernel" in moves:
+                line["tile"] = rows_ops.tile_rows(pool, d)
+                want = jax.jit(moves["xla"])(*operands, token, sizes)
+                gap = jnp.abs(
+                    jax.jit(moves["kernel"])(*operands, token, sizes) - want)
+                line["kernel_unequal"] = int(jnp.sum(gap > 0))
+                line["kernel_largest_gap"] = float(jnp.max(gap))
+                # a token that sits in three runs or more may get its
+                # addends in another order
+                ok = ok and line["kernel_largest_gap"] <= 1e-5 * max(
+                    1.0, float(jnp.max(jnp.abs(want))))
+            if not interpret:
+                made = median_ms(make, (token, sizes), args.reps)
+                line["make_ms"] = made / CALLS
+                for name, fn in programs.items():
+                    line[name + "_ms"] = (median_ms(
+                        fn, (token, sizes), args.reps) - made) / CALLS
+                line["xla_ns_a_place"] = 1e6 * line["xla_ms"] / pool
+                if "kernel" in moves and real:
+                    line["kernel_ns_a_row"] = 1e6 * line["kernel_ms"] / real
+            print(json.dumps(line), flush=True)
+    return ok
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--config", default=None,
@@ -116,6 +219,7 @@ def main(argv=None) -> int:
     import numpy as np
     from geomx_tpu.ops import held_experts as ours
 
+    ok = True
     rng = np.random.default_rng(0)
     top_k, held, router = 8, args.held, args.router
     t, d, f = args.tokens, args.hidden, args.width
@@ -182,29 +286,12 @@ def main(argv=None) -> int:
                     "ms": median_ms(run, (x, w, *mats, idx), args.reps)}),
                     flush=True)
     if args.pieces:
-        pool = first_pool(shapes[0])
-        rows = jnp.asarray(rng.standard_normal((pool, d)), jnp.float32)
-        for load in ("even", "none"):
-            n = min(sum(by_load[load]), pool)
-            token = jnp.asarray(np.concatenate([
-                rng.integers(0, t, n), t + np.arange(pool - n)]), jnp.int32)
-            pieces = {
-                "gather": jax.jit(lambda tok: x.at[tok].get(
-                    mode="fill", fill_value=0)),
-                "scatter_add": jax.jit(lambda tok: r.at[tok].add(
-                    rows, mode="drop")),
-                "scatter_add_unique": jax.jit(lambda tok: r.at[tok].add(
-                    rows, mode="drop", unique_indices=True)),
-            }
-            for name, fn in pieces.items():
-                print(json.dumps({"piece": name, "rows": pool, "real": n,
-                                  "ms": median_ms(fn, (token,), args.reps)}),
-                      flush=True)
+        ok = pieces(args, x, r, by_load, first_pool(shapes[0]), rng)
     if args.other:
         spec = importlib.util.spec_from_file_location("other", args.other)
         other = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(other)
-        run = program(other.held_experts, shapes[0][0])
+        run = program(other.held_experts, *shapes[0])
         for load, idx in idxs.items():
             _, grads = run(x, w, *mats, idx)
             off = [float(jnp.linalg.norm((a - b).astype(jnp.float32).ravel())
@@ -218,7 +305,7 @@ def main(argv=None) -> int:
                               "new_vs_other_grad_error": dict(zip(
                                   ("x", "weights", "gate", "up", "down"),
                                   off))}), flush=True)
-    return 0
+    return 0 if ok else 2
 
 
 if __name__ == "__main__":
