@@ -92,33 +92,6 @@ def emit(phase: str, **fields) -> dict:
     return rec
 
 
-class CompileCounter:
-    """Counts what XLA was asked to compile and what the persistent
-    cache answered, from JAX's own monitoring events."""
-
-    def __init__(self):
-        from jax import monitoring
-        self.compiles = 0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, name, _secs, **_kw):
-        if name == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-
-    def _on_event(self, name, **_kw):
-        if name == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif name == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-    def snapshot(self) -> dict:
-        return {"compiles": self.compiles, "cache_hits": self.cache_hits,
-                "cache_misses": self.cache_misses}
-
-
 def flagship():
     from geomx_tpu.models import ResNet20
     return ResNet20(num_classes=10)
@@ -680,7 +653,10 @@ def main(argv=None) -> int:
     from geomx_tpu.data import load_dataset
 
     device_phase(args.chips)
-    counter = CompileCounter()
+    # what XLA was asked to compile and what the persistent cache
+    # answered: the program's own record (telemetry/layers.CompileLog)
+    from geomx_tpu.telemetry.layers import compile_log
+    counter = compile_log()
     t0 = time.perf_counter()
     # six global batches for the mesh check, the 20 fit steps otherwise
     samples = (6 * 4 if args.chips == 4 else 20) * PER_CHIP_BATCH

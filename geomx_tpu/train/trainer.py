@@ -9,6 +9,7 @@ its JSON measurement reporter (examples/utils.py:120-192).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import time
 from typing import Callable, Optional
@@ -42,6 +43,21 @@ class Trainer:
         un-meshed paths (init, eval, predict).  Required when ``model``
         calls axis collectives (e.g. sequence-parallel attention over the
         sp axis), which only trace inside the sharded train step."""
+        # the lifecycle record (telemetry/layers.py): set-up's spans, the
+        # allocator at the edges of set-up and of each fit, and the
+        # process's CompileLog, which the first trainer installs
+        self.lifecycle = layers.Lifecycle(layers.compile_log())
+        with self.lifecycle.span("setup/build"):
+            self._build(model, topology, optimizer, sync, config, mesh,
+                        donate, single_device_model)
+        self.lifecycle.step_fun = getattr(self.train_step, "__name__", None)
+        # the devices, not the trainer: the record outlives it
+        # (layers.last_lifecycle)
+        self.lifecycle.memory_source = functools.partial(
+            layers.fullest_device_stats, list(self.mesh.devices.flat))
+
+    def _build(self, model, topology, optimizer, sync, config, mesh, donate,
+               single_device_model):
         self.model = model
         self._sd_model = single_device_model or model
         self.topology = topology
@@ -140,7 +156,6 @@ class Trainer:
         # Trainer needs it for shard-shaped state init, the sharded
         # drain program, and checkpoint/catch-up layout handling
         self._zero_plan = getattr(self.sync, "zero_plan", None)
-        self._memory_gauge_published = False
         self.eval_step, self._logits_fn = build_eval_step(
             self._sd_model.apply)
         self._batch_sharding = topology.batch_sharding(self.mesh)
@@ -246,98 +261,108 @@ class Trainer:
         """sample_input: one local batch [b, H, W, C] (uint8 images) or
         [b, L] (integer token ids — passed through un-normalized)."""
         from geomx_tpu.train.step import _norm_input
-        x0 = _norm_input(jnp.asarray(sample_input))
-        # jit the init: one compiled program instead of thousands of eager
-        # dispatches
-        variables = jax.jit(
-            lambda r, x: self._sd_model.init(r, x, train=False))(rng, x0)
-        variables = dict(variables)
-        params = variables.pop("params")
-        model_state = variables  # batch_stats etc.
-        if self._mgps is not None:
-            # MultiGPS ZeRO-1: optimizer + compressor state for big leaves
-            # is allocated per worker-axis shard (the 1/W memory saving);
-            # every (dc, worker) slot then tracks only its own shard
-            mixed = self._mgps.mixed_example(params)
-            opt_state = self.tx.init(mixed)
-            sync_state = self.sync.init_state(mixed, model_state=model_state)
-            dc = getattr(self.sync, "dc_compressor", None)
-            if dc is not None and getattr(dc, "fuses_tree", False):
-                # tree-fusing dc compressors (tree-level DGT) run one
-                # flat schedule per layout group under MultiGPS — shard
-                # leaves and replicated leaves must not share blocks
-                # (train/step.py _mgps_sync_update splits the same way)
-                sizes = [leaf.size for leaf in jax.tree.leaves(params)]
-                big, small = self._mgps.split_mixed(
-                    sizes, jax.tree.leaves(mixed))
-                sync_state = dict(sync_state, dc_comp={
-                    "sharded": dc.init_state(big),
-                    "replicated": dc.init_state(small)})
-        elif self._zero_plan is not None:
-            # ZeRO: the optimizer runs on flat 1/W bucket shards, so its
-            # state is allocated shard-shaped — the per-chip memory
-            # saving IS this allocation.  The sync algorithm's zero-
-            # aware init sizes the dc-tier EF residuals the same way.
-            shards = self._zero_plan.shard_example(
-                params, self._zero_plan.bucketed)
-            opt_state = self.tx.init(shards)
-            sync_state = self.sync.init_state(params,
-                                              model_state=model_state)
-        elif self._fused_optim:
-            # fused apply: the optimizer state lives on the flat bucket
-            # layout (one fp32 vector per bucket, lane-padded sizes) —
-            # the same layout the dc tier already fuses gradients onto,
-            # so the kernels update params, moments and wire buckets in
-            # one coordinate system
-            from geomx_tpu.compression.bucketing import BucketedCompressor
-            from geomx_tpu.sync.pipeline import PipelinedCompressor
-            dc = getattr(self.sync, "dc_compressor",
-                         getattr(getattr(self.sync, "inner", None),
-                                 "dc_compressor", None))
-            if isinstance(dc, PipelinedCompressor):
-                dc = dc.inner
-            if not isinstance(dc, BucketedCompressor):
-                raise ValueError(
-                    "GEOMX_FUSED_OPTIM requires the bucketed dc-tier "
-                    "engine (GEOMX_BUCKET_BYTES > 0): the kernels apply "
-                    "the update over the flat fp32 buckets")
-            bk = dc.zero_bucketer(jax.tree.leaves(params))
-            opt_state = self.tx.init(
-                [jnp.zeros((n,), jnp.float32) for n in bk.bucket_sizes])
-            sync_state = self.sync.init_state(params,
-                                              model_state=model_state)
-        else:
-            opt_state = self.tx.init(params)
-            sync_state = self.sync.init_state(params,
-                                              model_state=model_state)
-        if self._control:
-            # control operands join sync_state so they ride the traced
-            # step as INPUTS: retuning them is a host-side rewrite of
-            # one scalar leaf, never a recompile (control/actuators.py)
-            from geomx_tpu.control.actuators import (CONTROL_KEY,
-                                                     init_control_operands)
-            if not isinstance(sync_state, dict):
-                raise ValueError(
-                    "GEOMX_CONTROL needs a dict-shaped sync state to "
-                    f"carry its operands; {self.sync.name!r} returns "
-                    f"{type(sync_state).__name__}")
-            sync_state = dict(sync_state)
-            sync_state[CONTROL_KEY] = init_control_operands()
-        # the replicated scalar must carry the SAME NamedSharding the
-        # compiled step emits for it: a SingleDeviceSharding here makes
-        # the second train_step/epoch-runner call a jit cache MISS (the
-        # input sharding is part of the key) — one full recompile
-        from jax.sharding import NamedSharding, PartitionSpec
-        step = jax.device_put(jnp.zeros((), jnp.int32),
-                              NamedSharding(self.mesh, PartitionSpec()))
-        # leaf by leaf, each source let go once its copy exists: the
-        # device never holds the state twice
-        parts = [params, opt_state, model_state, sync_state]
-        del params, opt_state, model_state, sync_state, variables
-        params, opt_state, model_state, sync_state = replicate_consuming(
-            parts, self.topology, self.mesh)
-        return TrainState(step=step, params=params, opt_state=opt_state,
-                          model_state=model_state, sync_state=sync_state)
+        life = self.lifecycle
+        life.mark("setup/init_state:begin")
+        with life.span("setup/init_state"):
+            with life.span("setup/model_init"):
+                x0 = _norm_input(jnp.asarray(sample_input))
+                # jit the init: one compiled program instead of thousands of
+                # eager dispatches
+                variables = jax.jit(
+                    lambda r, x: self._sd_model.init(r, x, train=False))(rng, x0)
+            variables = dict(variables)
+            params = variables.pop("params")
+            model_state = variables  # batch_stats etc.
+            with life.span("setup/state_init"):
+                if self._mgps is not None:
+                    # MultiGPS ZeRO-1: optimizer + compressor state for big
+                    # leaves is allocated per worker-axis shard (the 1/W memory
+                    # saving); every (dc, worker) slot tracks only its own shard
+                    mixed = self._mgps.mixed_example(params)
+                    opt_state = self.tx.init(mixed)
+                    sync_state = self.sync.init_state(mixed,
+                                                      model_state=model_state)
+                    dc = getattr(self.sync, "dc_compressor", None)
+                    if dc is not None and getattr(dc, "fuses_tree", False):
+                        # tree-fusing dc compressors (tree-level DGT) run one
+                        # flat schedule per layout group under MultiGPS — shard
+                        # leaves and replicated leaves must not share blocks
+                        # (train/step.py _mgps_sync_update splits the same way)
+                        sizes = [leaf.size for leaf in jax.tree.leaves(params)]
+                        big, small = self._mgps.split_mixed(
+                            sizes, jax.tree.leaves(mixed))
+                        sync_state = dict(sync_state, dc_comp={
+                            "sharded": dc.init_state(big),
+                            "replicated": dc.init_state(small)})
+                elif self._zero_plan is not None:
+                    # ZeRO: the optimizer runs on flat 1/W bucket shards, so its
+                    # state is allocated shard-shaped — the per-chip memory
+                    # saving IS this allocation.  The sync algorithm's zero-
+                    # aware init sizes the dc-tier EF residuals the same way.
+                    shards = self._zero_plan.shard_example(
+                        params, self._zero_plan.bucketed)
+                    opt_state = self.tx.init(shards)
+                    sync_state = self.sync.init_state(params,
+                                                      model_state=model_state)
+                elif self._fused_optim:
+                    # fused apply: the optimizer state lives on the flat bucket
+                    # layout (one fp32 vector per bucket, lane-padded sizes) —
+                    # the same layout the dc tier already fuses gradients onto,
+                    # so the kernels update params, moments and wire buckets in
+                    # one coordinate system
+                    from geomx_tpu.compression.bucketing import BucketedCompressor
+                    from geomx_tpu.sync.pipeline import PipelinedCompressor
+                    dc = getattr(self.sync, "dc_compressor",
+                                 getattr(getattr(self.sync, "inner", None),
+                                         "dc_compressor", None))
+                    if isinstance(dc, PipelinedCompressor):
+                        dc = dc.inner
+                    if not isinstance(dc, BucketedCompressor):
+                        raise ValueError(
+                            "GEOMX_FUSED_OPTIM requires the bucketed dc-tier "
+                            "engine (GEOMX_BUCKET_BYTES > 0): the kernels apply "
+                            "the update over the flat fp32 buckets")
+                    bk = dc.zero_bucketer(jax.tree.leaves(params))
+                    opt_state = self.tx.init(
+                        [jnp.zeros((n,), jnp.float32) for n in bk.bucket_sizes])
+                    sync_state = self.sync.init_state(params,
+                                                      model_state=model_state)
+                else:
+                    opt_state = self.tx.init(params)
+                    sync_state = self.sync.init_state(params,
+                                                      model_state=model_state)
+                if self._control:
+                    # control operands join sync_state so they ride the traced
+                    # step as INPUTS: retuning them is a host-side rewrite of
+                    # one scalar leaf, never a recompile (control/actuators.py)
+                    from geomx_tpu.control.actuators import (CONTROL_KEY,
+                                                             init_control_operands)
+                    if not isinstance(sync_state, dict):
+                        raise ValueError(
+                            "GEOMX_CONTROL needs a dict-shaped sync state to "
+                            f"carry its operands; {self.sync.name!r} returns "
+                            f"{type(sync_state).__name__}")
+                    sync_state = dict(sync_state)
+                    sync_state[CONTROL_KEY] = init_control_operands()
+            # the replicated scalar must carry the SAME NamedSharding the
+            # compiled step emits for it: a SingleDeviceSharding here makes
+            # the second train_step/epoch-runner call a jit cache MISS (the
+            # input sharding is part of the key) — one full recompile
+            from jax.sharding import NamedSharding, PartitionSpec
+            step = jax.device_put(jnp.zeros((), jnp.int32),
+                                  NamedSharding(self.mesh, PartitionSpec()))
+            # leaf by leaf, each source let go once its copy exists: the
+            # device never holds the state twice
+            parts = [params, opt_state, model_state, sync_state]
+            del params, opt_state, model_state, sync_state, variables
+            with life.span("setup/replicate"):
+                params, opt_state, model_state, sync_state = replicate_consuming(
+                    parts, self.topology, self.mesh)
+            state = TrainState(step=step, params=params,
+                               opt_state=opt_state, model_state=model_state,
+                               sync_state=sync_state)
+        life.mark("setup/init_state:end")
+        return state
 
     def make_loader(self, x, y, batch_size: int, split_by_class: bool = False,
                     seed: int = 0, augment: bool = False,
@@ -985,24 +1010,18 @@ class Trainer:
 
     def step_memory_stats(self, state: TrainState, xb, yb):
         """Compiled-step memory accounting from XLA's
-        ``compiled.memory_analysis()`` — the measured source for the
-        ``geomx_step_memory_bytes`` gauge and bench ``--compare-zero``'s
-        memory claim.  Adds the sharded-state accounting (bytes of
-        optimizer + sync state one chip holds, from the placed arrays'
-        shapes) so the 1/W claim is checkable even where the backend
-        offers no analysis object."""
-        n_dev = max(1, len(self.mesh.devices.reshape(-1)))
-
-        def _per_chip_bytes(tree):
-            return sum(leaf.size * leaf.dtype.itemsize
-                       for leaf in jax.tree.leaves(tree)
-                       if hasattr(leaf, "size")) / n_dev
-
-        out = {
-            "opt_state_bytes_per_chip": _per_chip_bytes(state.opt_state),
-            "sync_state_bytes_per_chip": _per_chip_bytes(state.sync_state),
-            "params_bytes_per_chip": _per_chip_bytes(state.params),
-        }
+        ``compiled.memory_analysis()`` — the source of bench
+        ``--compare-zero``'s memory claim.  Adds the sharded-state
+        accounting (bytes of optimizer + sync state one chip holds, from
+        the placed arrays' shapes) so the 1/W claim is checkable even
+        where the backend offers no analysis object.  Its
+        ``temp_size_in_bytes`` is not what a TPU loads: the compiler sizes
+        temporaries to what it believes free (PERF.md section 7, PR 27);
+        what the chip reserved for the loaded step is
+        ``lifecycle.step_reserved_bytes()``."""
+        placed = layers.state_bytes_per_chip(state, self.mesh.devices.size)
+        out = {f"{name}_bytes_per_chip": placed[name]
+               for name in ("opt_state", "sync_state", "params")}
         try:
             ma = self._compiled_step(state, xb, yb).memory_analysis()
         except Exception as e:  # backend without AOT memory stats
@@ -1048,28 +1067,32 @@ class Trainer:
                 "unnamed": sum(1 for v in ops.values() if v.scope is None),
                 "seconds": time.perf_counter() - begin}
 
-    def publish_memory_metrics(self, state: TrainState, xb, yb) -> None:
-        """Publish the per-chip step-memory gauges (telemetry plane;
-        once per trainer — the program is static).  One extra AOT
-        lower+compile; only runs when telemetry is enabled."""
-        if self._memory_gauge_published:
+    def _first_boundary(self, step: int) -> None:
+        """The trainer's first step has its results on the host."""
+        self.lifecycle.first_boundary(step)
+        self.publish_memory_metrics()
+
+    def publish_memory_metrics(self) -> None:
+        """Set the per-chip step-memory gauges
+        (``geomx_step_memory_bytes{component}``) from the lifecycle
+        record: the state's classes from the placed arrays the first step
+        got; ``compiled_step`` from what the allocator reserved between
+        that dispatch and its results (the loaded program's scratch
+        space), left out until then and where the backend has no
+        allocator statistics.  ``fit`` calls it at both points; it does
+        nothing with telemetry off, and nothing is lowered or compiled
+        for it."""
+        if not self._telemetry:
             return
-        self._memory_gauge_published = True
         from geomx_tpu.telemetry import get_registry
-        stats = self.step_memory_stats(state, xb, yb)
-        reg = get_registry()
-        fam = reg.gauge("geomx_step_memory_bytes",
-                        "Per-chip training-step memory by component",
-                        ("component",))
-        for comp in ("opt_state_bytes_per_chip",
-                     "sync_state_bytes_per_chip",
-                     "params_bytes_per_chip"):
-            fam.labels(component=comp.replace("_bytes_per_chip", "")) \
-                .set(float(stats[comp]))
-        ma = stats.get("memory_analysis", {})
-        if "step_memory_bytes" in ma:
-            fam.labels(component="compiled_step").set(
-                float(ma["step_memory_bytes"]))
+        fam = get_registry().gauge(
+            "geomx_step_memory_bytes",
+            "Per-chip training-step memory by component", ("component",))
+        for component, value in self.lifecycle.state_bytes.items():
+            fam.labels(component=component).set(float(value))
+        reserved = self.lifecycle.step_reserved_bytes()
+        if reserved is not None:
+            fam.labels(component="compiled_step").set(float(reserved))
 
     def predict_logits(self, state: TrainState, x: np.ndarray,
                        batch_size: int = 512) -> np.ndarray:
@@ -1213,180 +1236,210 @@ class Trainer:
 
         Returns (state, list of record dicts).
         """
-        measure = measure if measure is not None else Measure()
-        measure.reset_clock()
-        # iteration numbering restarts per fit, so the telemetry delta
-        # base must too — a stale high-water mark from a previous fit
-        # would silently swallow this fit's step/byte counter increments
-        self._telem_last_it = 0
-        # step-time attribution windows restart per fit too: mark the
-        # trace clock now so a long-lived process whose global profiler
-        # accumulated spans across earlier fits (or other profiled work)
-        # attributes only THIS fit's steps — both for the fit-end
-        # geomx_phase_fraction summary and the per-publish flight windows
-        from geomx_tpu.utils.profiler import get_profiler
-        prof = get_profiler()
-        fit_since_us = prof.now_us() if prof.running else None
-        self._attr_window_us = fit_since_us
-        stats = self.loop_stats = layers.LoopStats()
-        layers.record_fit(stats, self._step_args)
-        if scan_epochs:
-            if not getattr(loader, "device_cache", False):
-                raise ValueError("scan_epochs requires device_cache=True "
-                                 "on the loader")
-            run = self._epoch_runner(loader)
-            it = 0
-            for epoch in range(epochs):
-                # one dispatch an epoch: the phases are per epoch here,
-                # and `step` is the epoch's first iteration
-                stats.step = it
-                with stats.phase("fit/next_batch"):
-                    sel, key = loader.epoch_indices(epoch)
-                with stats.phase("fit/dispatch"):
-                    state, ms = run(state, loader._dev_x, loader._dev_y,
-                                    sel, key)
-                it += loader.steps_per_epoch
-                stats.steps = it
-                fields = {}
-                if log_every:
-                    with stats.phase("fit/log_sync"):
-                        ms = jax.device_get(ms)
-                    fields.update(
-                        loss=float(np.mean(ms["loss"])),
-                        train_acc=float(np.mean(ms["accuracy"])))
-                    if self._telemetry and "telemetry" in ms:
-                        # scanned epoch: probe values carry a leading
-                        # step dimension; publish the last step's
-                        self._publish_telemetry(ms["telemetry"], it,
-                                                stacked=True)
-                elif self._telemetry:
-                    # log_every=0: still publish the epoch's last step
-                    # (same fallback the non-scanned loop has)
-                    ms = jax.device_get(ms)
-                    if "telemetry" in ms:
-                        self._publish_telemetry(ms["telemetry"], it,
-                                                stacked=True)
-                if eval_data is not None:
-                    with stats.phase("fit/eval"):
-                        fields["test_acc"] = self.evaluate(state,
-                                                           *eval_data)
-                if fields:
-                    rec = measure.add(epoch=epoch, iteration=it, **fields)
-                    with stats.phase("fit/log_fn"):
-                        log_fn(json.dumps(rec))
-            with stats.phase("fit/log_sync"):
-                jax.block_until_ready(state.step)
-            self._capsule_checkpoint(prof)
-            return state, measure.records
-        # Virtual CPU meshes deadlock XLA's collective rendezvous with more
-        # than a few in-flight async programs, so there we consume metrics
-        # every step.  On a real accelerator that blocking device_get would
-        # serialize host work into the step time and cap MFU; instead let
-        # XLA's async dispatch run ahead and only sync on log/eval
-        # boundaries (bounded every `sync_every` steps as a backstop).
-        on_cpu = jax.devices()[0].platform == "cpu"
-        sync_every = 1 if on_cpu else max(1, log_every or 32)
-        it = 0
-        # Host spans and always-on counters of the loop
-        # (telemetry/layers.py).  Each iteration is a train/step span
-        # holding the phases fit/dispatch, fit/log_sync, fit/eval and
-        # fit/log_fn; the wait for its batch, fit/next_batch, comes just
-        # before it (an epoch's last wait finds no batch and is no step).
-        # All carry the same `step`.  The spans reach a jax.profiler
-        # session always and the Chrome trace when the host profiler
-        # runs; LoopStats needs neither.  train/compute brackets dispatch
-        # and boundary wait for attribute_trace: with async dispatch that
-        # is host time only, and the device's share of it is the
-        # boundary wait (on the CPU backend, which syncs every step, it
-        # is the real step).  The kernel- and comm-category spans the
-        # step's own code opens are entered while jit TRACES it, once a
-        # compile: on the host they time tracing, not compute or
-        # communication.
-        for epoch in range(epochs):
-            batches = iter(loader.epoch(epoch, prefetch=self._prefetch))
-            while True:
-                stats.step = it
-                with stats.phase("fit/next_batch"):
-                    batch = next(batches, None)
-                if batch is None:
-                    break
-                xb, yb = batch
-                if self._step_args is None:
-                    layers.record_fit(
-                        stats, self._abstract_step_args(state, xb, yb))
-                # arm the auditor on the first batch (abstract trace of
-                # the active program; no-op unless GEOMX_AUDIT is on)
-                self._audit_capture(state, xb, yb)
-                if self._telemetry and not self._memory_gauge_published:
-                    # once per trainer: the per-chip step-memory gauges
-                    # (geomx_step_memory_bytes) from the compiled program
-                    self.publish_memory_metrics(state, xb, yb)
-                with prof.scope("train/step", "step",
-                                args={"step": it}):
-                    with prof.scope("train/compute", "compute"):
-                        with stats.phase("fit/dispatch"):
-                            state, metrics = self.train_step(state, xb, yb)
-                        it += 1
-                        stats.steps = it
-                        # the log/sync boundary wait is device compute
-                        # (on the CPU backend the whole step; on an
-                        # accelerator the async-dispatch catch-up), so
-                        # it stays inside the compute span — attributed
-                        # host_stall is then genuinely the input
-                        # pipeline and dispatch gaps, which is what the
-                        # GEOMX_PREFETCH acceptance (bench.py
-                        # --compare-mfu) measures
-                        synced = None
-                        if log_every and it % log_every == 0:
-                            with stats.phase("fit/log_sync"):
-                                synced = jax.device_get(metrics)
-                        elif it % sync_every == 0:
-                            with stats.phase("fit/log_sync"):
-                                jax.block_until_ready(metrics["loss"])
+        # no wrapper around the loop: one more Python frame under the
+        # step's first call costs JAX's trace and lowering of a BERT-large
+        # step 0.6 s (PERF.md section 6, PR 36)
+        try:
+            measure = measure if measure is not None else Measure()
+            measure.reset_clock()
+            # iteration numbering restarts per fit, so the telemetry delta
+            # base must too — a stale high-water mark from a previous fit
+            # would silently swallow this fit's step/byte counter increments
+            self._telem_last_it = 0
+            # step-time attribution windows restart per fit too: mark the
+            # trace clock now so a long-lived process whose global profiler
+            # accumulated spans across earlier fits (or other profiled work)
+            # attributes only THIS fit's steps — both for the fit-end
+            # geomx_phase_fraction summary and the per-publish flight windows
+            from geomx_tpu.utils.profiler import get_profiler
+            prof = get_profiler()
+            fit_since_us = prof.now_us() if prof.running else None
+            self._attr_window_us = fit_since_us
+            stats = self.loop_stats = layers.LoopStats()
+            life = self.lifecycle
+            n_devices = self.mesh.devices.size
+            layers.record_fit(stats, self._step_args, life)
+            if scan_epochs:
+                if not getattr(loader, "device_cache", False):
+                    raise ValueError("scan_epochs requires device_cache=True "
+                                     "on the loader")
+                run = self._epoch_runner(loader)
+                it = 0
+                for epoch in range(epochs):
+                    # one dispatch an epoch: the phases are per epoch here,
+                    # and `step` is the epoch's first iteration
+                    stats.step = it
+                    with stats.phase("fit/next_batch"):
+                        sel, key = loader.epoch_indices(epoch)
+                    with stats.phase("fit/dispatch"):
+                        if life.first_dispatch_due:
+                            with life.first_dispatch(state, n_devices, it):
+                                state, ms = run(state, loader._dev_x,
+                                                loader._dev_y, sel, key)
+                            self.publish_memory_metrics()
+                        else:
+                            state, ms = run(state, loader._dev_x, loader._dev_y,
+                                            sel, key)
+                    it += loader.steps_per_epoch
+                    stats.steps = it
                     fields = {}
-                    if synced is not None:
-                        metrics = synced
-                        fields.update(loss=float(metrics["loss"]),
-                                      train_acc=float(metrics["accuracy"]))
-                        if self._telemetry and "telemetry" in metrics:
-                            self._publish_telemetry(metrics["telemetry"],
-                                                    it)
-                        if "counters" in metrics:
-                            stats.count(metrics["counters"])
-                    if eval_data is not None and eval_every \
-                            and it % eval_every == 0:
+                    if log_every:
+                        with stats.phase("fit/log_sync"):
+                            ms = jax.device_get(ms)
+                        if life.first_boundary_due:
+                            self._first_boundary(it)
+                        fields.update(
+                            loss=float(np.mean(ms["loss"])),
+                            train_acc=float(np.mean(ms["accuracy"])))
+                        if self._telemetry and "telemetry" in ms:
+                            # scanned epoch: probe values carry a leading
+                            # step dimension; publish the last step's
+                            self._publish_telemetry(ms["telemetry"], it,
+                                                    stacked=True)
+                    elif self._telemetry:
+                        # log_every=0: still publish the epoch's last step
+                        # (same fallback the non-scanned loop has)
+                        ms = jax.device_get(ms)
+                        if "telemetry" in ms:
+                            self._publish_telemetry(ms["telemetry"], it,
+                                                    stacked=True)
+                    if eval_data is not None:
                         with stats.phase("fit/eval"):
                             fields["test_acc"] = self.evaluate(state,
                                                                *eval_data)
                     if fields:
-                        rec = measure.add(epoch=epoch, iteration=it,
-                                          **fields)
+                        rec = measure.add(epoch=epoch, iteration=it, **fields)
                         with stats.phase("fit/log_fn"):
                             log_fn(json.dumps(rec))
-            if self._telemetry and not log_every and it:
-                # no log boundary ever synced this epoch: publish the
-                # epoch's last step so the registry/event log still track
-                # a log_every=0 run (one device_get per epoch)
-                last = jax.device_get(metrics)
-                if "telemetry" in last:
-                    self._publish_telemetry(last["telemetry"], it)
-            if eval_data is not None and not eval_every:
-                with stats.phase("fit/eval"):
-                    acc = self.evaluate(state, *eval_data)
-                rec = measure.add(epoch=epoch, iteration=it, test_acc=acc)
-                with stats.phase("fit/log_fn"):
-                    log_fn(json.dumps(rec))
-        if self._telemetry and prof.running:
-            # publish the fit's phase-fraction summary from the step
-            # spans recorded above (geomx_phase_fraction gauges) — the
-            # scrapeable form of bench --attribute's breakdown
-            from geomx_tpu.telemetry.attribution import (
-                attribute_trace, publish_attribution)
-            att = attribute_trace(prof.to_doc(), since_us=fit_since_us)
-            if att["num_steps"]:
-                publish_attribution(att["summary"])
-        self._capsule_checkpoint(prof)
-        return state, measure.records
+                with stats.phase("fit/log_sync"):
+                    jax.block_until_ready(state.step)
+                if life.first_boundary_due:
+                    self._first_boundary(it)
+                self._capsule_checkpoint(prof)
+                return state, measure.records
+            # Virtual CPU meshes deadlock XLA's collective rendezvous with more
+            # than a few in-flight async programs, so there we consume metrics
+            # every step.  On a real accelerator that blocking device_get would
+            # serialize host work into the step time and cap MFU; instead let
+            # XLA's async dispatch run ahead and only sync on log/eval
+            # boundaries (bounded every `sync_every` steps as a backstop).
+            on_cpu = jax.devices()[0].platform == "cpu"
+            sync_every = 1 if on_cpu else max(1, log_every or 32)
+            it = 0
+            # Host spans and always-on counters of the loop
+            # (telemetry/layers.py).  Each iteration is a train/step span
+            # holding the phases fit/dispatch, fit/log_sync, fit/eval and
+            # fit/log_fn; the wait for its batch, fit/next_batch, comes just
+            # before it (an epoch's last wait finds no batch and is no step).
+            # All carry the same `step`.  The spans reach a jax.profiler
+            # session always and the Chrome trace when the host profiler
+            # runs; LoopStats needs neither.  train/compute brackets dispatch
+            # and boundary wait for attribute_trace: with async dispatch that
+            # is host time only, and the device's share of it is the
+            # boundary wait (on the CPU backend, which syncs every step, it
+            # is the real step).  The kernel- and comm-category spans the
+            # step's own code opens are entered while jit TRACES it, once a
+            # compile: on the host they time tracing, not compute or
+            # communication.
+            for epoch in range(epochs):
+                batches = iter(loader.epoch(epoch, prefetch=self._prefetch))
+                while True:
+                    stats.step = it
+                    with stats.phase("fit/next_batch"):
+                        batch = next(batches, None)
+                    if batch is None:
+                        break
+                    xb, yb = batch
+                    if self._step_args is None:
+                        layers.record_fit(
+                            stats, self._abstract_step_args(state, xb, yb), life)
+                    # arm the auditor on the first batch (abstract trace of
+                    # the active program; no-op unless GEOMX_AUDIT is on)
+                    self._audit_capture(state, xb, yb)
+                    with prof.scope("train/step", "step",
+                                    args={"step": it}):
+                        with prof.scope("train/compute", "compute"):
+                            with stats.phase("fit/dispatch"):
+                                if life.first_dispatch_due:
+                                    # once a trainer: trace, lower, fetch or
+                                    # compile, load and enqueue
+                                    with life.first_dispatch(state, n_devices,
+                                                             it):
+                                        state, metrics = self.train_step(
+                                            state, xb, yb)
+                                    self.publish_memory_metrics()
+                                else:
+                                    state, metrics = self.train_step(state, xb,
+                                                                     yb)
+                            it += 1
+                            stats.steps = it
+                            # the log/sync boundary wait is device compute
+                            # (on the CPU backend the whole step; on an
+                            # accelerator the async-dispatch catch-up), so
+                            # it stays inside the compute span — attributed
+                            # host_stall is then genuinely the input
+                            # pipeline and dispatch gaps, which is what the
+                            # GEOMX_PREFETCH acceptance (bench.py
+                            # --compare-mfu) measures
+                            synced = None
+                            if log_every and it % log_every == 0:
+                                with stats.phase("fit/log_sync"):
+                                    synced = jax.device_get(metrics)
+                                if life.first_boundary_due:
+                                    self._first_boundary(it)
+                            elif it % sync_every == 0:
+                                with stats.phase("fit/log_sync"):
+                                    jax.block_until_ready(metrics["loss"])
+                                if life.first_boundary_due:
+                                    self._first_boundary(it)
+                        fields = {}
+                        if synced is not None:
+                            metrics = synced
+                            fields.update(loss=float(metrics["loss"]),
+                                          train_acc=float(metrics["accuracy"]))
+                            if self._telemetry and "telemetry" in metrics:
+                                self._publish_telemetry(metrics["telemetry"],
+                                                        it)
+                            if "counters" in metrics:
+                                stats.count(metrics["counters"])
+                        if eval_data is not None and eval_every \
+                                and it % eval_every == 0:
+                            with stats.phase("fit/eval"):
+                                fields["test_acc"] = self.evaluate(state,
+                                                                   *eval_data)
+                        if fields:
+                            rec = measure.add(epoch=epoch, iteration=it,
+                                              **fields)
+                            with stats.phase("fit/log_fn"):
+                                log_fn(json.dumps(rec))
+                if self._telemetry and not log_every and it:
+                    # no log boundary ever synced this epoch: publish the
+                    # epoch's last step so the registry/event log still track
+                    # a log_every=0 run (one device_get per epoch)
+                    last = jax.device_get(metrics)
+                    if "telemetry" in last:
+                        self._publish_telemetry(last["telemetry"], it)
+                if eval_data is not None and not eval_every:
+                    with stats.phase("fit/eval"):
+                        acc = self.evaluate(state, *eval_data)
+                    rec = measure.add(epoch=epoch, iteration=it, test_acc=acc)
+                    with stats.phase("fit/log_fn"):
+                        log_fn(json.dumps(rec))
+            if self._telemetry and prof.running:
+                # publish the fit's phase-fraction summary from the step
+                # spans recorded above (geomx_phase_fraction gauges) — the
+                # scrapeable form of bench --attribute's breakdown
+                from geomx_tpu.telemetry.attribution import (
+                    attribute_trace, publish_attribution)
+                att = attribute_trace(prof.to_doc(), since_us=fit_since_us)
+                if att["num_steps"]:
+                    publish_attribution(att["summary"])
+            self._capsule_checkpoint(prof)
+            return state, measure.records
+        finally:
+            # also where log_fn left the loop by an exception
+            self.lifecycle.mark("fit/end", self.loop_stats.steps
+                                if self.loop_stats is not None else 0)
 
     def _capsule_checkpoint(self, prof) -> None:
         """Refresh the run capsule at a fit boundary: attach the

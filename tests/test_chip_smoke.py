@@ -20,6 +20,7 @@ import pytest
 import chip_smoke
 from geomx_tpu.data import load_dataset
 from geomx_tpu.models import get_model
+from geomx_tpu.telemetry.layers import compile_log
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SCRIPT = os.path.join(REPO, "chip_smoke.py")
@@ -62,7 +63,7 @@ def tiny():
     """A stand-in small enough for the CPU: the small CNN, 256 samples."""
     data = load_dataset("synthetic", synthetic_train_n=256, seed=3)
     return {"data": data, "model": get_model("cnn"),
-            "counter": chip_smoke.CompileCounter()}
+            "counter": compile_log()}
 
 
 def _last_json(capsys):
