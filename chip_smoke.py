@@ -321,6 +321,23 @@ def kernels_phase(seed: int, interpret: bool = False, model=None) -> dict:
                              interpret=interpret),
          jax.jit(row_scatter_add_ref)(y0, addends, place_token, runs))
 
+    # a grouped-query mixer's norm + rotary pass: 8 heads on 2 of 128, 300
+    # tokens (a ragged second tile), bf16; XLA elides the jnp form's round
+    # trip through bf16 between norm and rotary and the kernel keeps it:
+    # the last place of bf16 may differ
+    from geomx_tpu.ops import gqa_elementwise as ge
+    wide = lambda heads: jnp.asarray(rng.normal(
+        0, 1, (1, 300, heads, 128)).astype(np.float32)).astype(jnp.bfloat16)
+    scales = [jnp.asarray(1 + 0.1 * rng.normal(0, 1, 128), jnp.float32)
+              for _ in range(2)]
+    qk = wide(8), wide(2)
+    close("gqa_norm_rotary",
+          [a.astype(jnp.float32) for a in ge.norm_rotary(
+              *qk, *scales, 1e-5, 10000.0, interpret)],
+          [a.astype(jnp.float32) for a in jax.jit(
+              lambda *a: ge.norm_rotary_ref(*a, 1e-5, 10000.0))(
+                  *qk, *scales)], rtol=0.0, atol=0.04)
+
     return emit("kernels", native=not interpret, elements=n, k=k,
                 leaves=len(leaves), checked=checked)
 

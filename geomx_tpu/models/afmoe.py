@@ -31,17 +31,21 @@ import dataclasses
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
-from geomx_tpu.models.decoder import DecoderLM, RMSNorm, _fan_in
+from geomx_tpu.models.decoder import DecoderLM, _fan_in
+from geomx_tpu.ops import dispatch
 from geomx_tpu.ops.flash_attention import fused_attention
+from geomx_tpu.ops.gqa_elementwise import gated_ref
 from geomx_tpu.utils.profiler import profile_scope
 
 
 def rotary(x, theta: float):
     """Rotate-half rotary positions over the whole head, positions
-    0..L-1, angles and arithmetic in float32.  x [B, L, H, d]."""
+    0..L-1, angles and arithmetic in float32.  x [B, L, H, d].  The plain
+    form: the mixer's own pass is `ops/gqa_elementwise.norm_rotary`, which
+    `tests/test_gqa_elementwise.py` and `tools/gqa_proj_timing.py` hold to
+    `decoder.RMSNorm` followed by this."""
     length, d = x.shape[1], x.shape[-1]
     half = d // 2
     inverse = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
@@ -51,6 +55,15 @@ def rotary(x, theta: float):
     x32 = x.astype(jnp.float32)
     turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], -1)
     return (x32 * cos + turned * sin).astype(x.dtype)
+
+
+class HeadScale(nn.Module):
+    """The learned scale of a per-head RMSNorm, where `decoder.RMSNorm`
+    keeps it (``<name>/scale``, ones)."""
+
+    @nn.compact
+    def __call__(self, d: int):
+        return self.param("scale", nn.initializers.ones, (d,))
 
 
 class GQAMixer(nn.Module):
@@ -77,19 +90,18 @@ class GQAMixer(nn.Module):
                 return jnp.dot(x, mat(name, (hidden, n * d))).reshape(
                     b, length, n, d)
 
-            q = RMSNorm(self.eps, name="q_norm")(heads("q_kernel", h))
-            k = RMSNorm(self.eps, name="k_norm")(heads("k_kernel", kv))
+            q, k = dispatch.gqa_norm_rotary(
+                heads("q_kernel", h), heads("k_kernel", kv),
+                HeadScale(name="q_norm")(d), HeadScale(name="k_norm")(d),
+                self.eps, None if self.window is None else self.rope_theta)
             v = heads("v_kernel", kv)
-            gate = jax.nn.sigmoid(jnp.dot(
-                x, mat("gate_kernel", (hidden, h * d)),
-                preferred_element_type=jnp.float32))
-            if self.window is not None:
-                q, k = rotary(q, self.rope_theta), rotary(k, self.rope_theta)
+            gate = jnp.dot(x, mat("gate_kernel", (hidden, h * d)),
+                           preferred_element_type=jnp.float32)
         with profile_scope("gqa/global" if self.window is None
                            else "gqa/window", "kernel"):
             o = fused_attention(q, k, v, True, False, self.window)
         with profile_scope("gqa/proj", "compute"):
-            o = (o.reshape(b, length, h * d) * gate).astype(dt)
+            o = gated_ref(o.reshape(b, length, h * d), gate)
             return jnp.dot(o, mat("out_kernel", (h * d, hidden)))
 
 

@@ -1,13 +1,13 @@
 """Kernel or XLA: the one place that decides, from the platform.
 
-Every op of the compression engine, a decoder's KDA scan and its expert
-pools' row scatter-add exist twice under ``ops/``: a Pallas kernel and
-a jnp form with the same results (bit for bit, except where an op's
-docstring says otherwise).  The kernel is what a TPU runs; the jnp form
-is the only path elsewhere and the oracle the tests hold the kernel to.
-The functions below are what ``compression/``, ``models/`` and
-``ops/held_experts.py`` call: each picks its implementation while the
-program is traced, from :func:`kernel_mode`.
+Every op of the compression engine, a decoder's KDA scan, its expert
+pools' row scatter-add and a grouped-query mixer's q/k norm with rotary
+exist twice under ``ops/``: a Pallas kernel and a jnp form with the same
+results (bit for bit, except where an op's docstring says otherwise).  The
+kernel is what a TPU runs; the jnp form is the only path elsewhere and the
+oracle the tests hold the kernel to.  The functions below are what
+``compression/``, ``models/`` and ``ops/held_experts.py`` call: each picks
+its implementation while the program is traced, from :func:`kernel_mode`.
 Nothing above ``ops/`` (a compressor's arguments, the spec string,
 ``GeoConfig``, the environment) can choose, and nothing above it asks.
 
@@ -25,9 +25,9 @@ from typing import Optional
 
 import jax
 
-from geomx_tpu.ops import (bsc_pallas, bucket_pallas, kda as kda_jnp,
-                           kda_pallas, merge_pallas, moe_rows_pallas,
-                           twobit_pallas)
+from geomx_tpu.ops import (bsc_pallas, bucket_pallas, gqa_elementwise,
+                           kda as kda_jnp, kda_pallas, merge_pallas,
+                           moe_rows_pallas, twobit_pallas)
 
 _OVERRIDE: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "geomx_kernel_mode", default=None)
@@ -151,3 +151,26 @@ def row_scatter_add(y, out, token, sizes):
         return moe_rows_pallas.row_scatter_add_ref(y, out, token, sizes)
     return moe_rows_pallas.moe_row_scatter_add(
         y, out, token, sizes, interpret=mode == "interpret")
+
+
+def gqa_norm_rotary(q, k, q_scale, k_scale, eps: float, theta):
+    """A grouped-query mixer's per-head RMSNorm of q ``[B, L, H, d]`` and
+    k ``[B, L, KV, d]`` (one learned scale each) and, where ``theta`` is
+    not None, rotate-half rotary at positions 0..L-1: ``(q, k)`` in their
+    own dtype.  With rotary, the kernel pair of ``gqa_elementwise`` with
+    its own backward where :func:`kernel_mode` names one and the shapes
+    are the kernels' (``gqa_elementwise.norm_rotary_plan``: a head of
+    whole lane tiles, bf16 or float32, ``MIN_TILE`` tokens or more); else,
+    and for the norm alone (which XLA streams as one pass itself), the
+    jnp form and JAX's backward.  Equal forward wherever XLA keeps the jnp
+    form's rounding to the caller's dtype between norm and rotary; on a
+    TPU it elides that round trip, and the kernels, which keep it, differ
+    from its program in the last place of bf16 (PERF.md, PR 37)."""
+    mode = kernel_mode()
+    if (mode is None or theta is None or k.dtype != q.dtype
+            or gqa_elementwise.norm_rotary_plan(q.shape, k.shape,
+                                                q.dtype) is None):
+        return gqa_elementwise.norm_rotary_ref(q, k, q_scale, k_scale, eps,
+                                               theta)
+    return gqa_elementwise.norm_rotary(q, k, q_scale, k_scale, eps, theta,
+                                       mode == "interpret")

@@ -60,7 +60,6 @@ def test_the_shared_pieces_have_one_copy():
     for name in ("RMSNorm", "MLP", "swiglu", "route", "HeldExpertsLayer",
                  "blocked_cross_entropy", "MixerBranch", "FFNBranch", "Block"):
         assert getattr(kimi_linear, name) is getattr(decoder, name), name
-    assert afmoe.RMSNorm is decoder.RMSNorm
     assert issubclass(afmoe.AfmoeLM, decoder.DecoderLM)
     assert issubclass(kimi_linear.KimiLinearLM, decoder.DecoderLM)
 

@@ -418,6 +418,47 @@ def test_the_row_kernel_carries_the_name_the_docs_give(chip, cell):
     assert rows_ops.tile_rows(places, d) == (512 if d == 2048 else 256)
 
 
+@pytest.mark.parametrize("kind", ["window", "global"])
+def test_v5e_compiler_accepts_the_gqa_mixers_streamed_pass(chip, kind):
+    """`models/afmoe.GQAMixer` at the Trinity cell's shape (one sequence of
+    8,192 tokens, hidden 2,048, bf16, 32 query heads on 4 of 128; a window
+    of 2,048 with rotary, or global without), forward and gradient in one
+    program.  A window layer's q/k norm + rotary is the kernel pair of
+    `ops/gqa_elementwise.py`, each once and by the names
+    `tools/scope_ops.py --scope gqa/proj` lists them under, and its plans
+    fit their VMEM budget; a global layer's norm alone is XLA's (PERF.md,
+    PR 37).  Neither program holds a concatenate as large as q or k
+    (rotary's halves)."""
+    import math
+    import re
+    from geomx_tpu.models.afmoe import GQAMixer
+    from geomx_tpu.ops import dispatch
+    from geomx_tpu.ops import gqa_elementwise as ge
+    length, hidden, heads, kv_heads, d = 8192, 2048, 32, 4, 128
+    mixer = GQAMixer(heads, kv_heads, d, 2048 if kind == "window" else None,
+                     10000.0, 1e-5, jnp.bfloat16)
+    on = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                           sharding=chip)
+    x = jax.ShapeDtypeStruct((1, length, hidden), jnp.bfloat16)
+    params = jax.tree.map(on, jax.eval_shape(
+        mixer.init, jax.random.PRNGKey(0), x)["params"])
+    loss = lambda p, x: jnp.sum(
+        mixer.apply({"params": p}, x).astype(jnp.float32))
+    with dispatch.kernels("native"):
+        text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+            params, on(x)).compile().as_text()
+    calls = [c.split(".")[0] for c in _kernel_calls(text)]
+    for name in ("gqa_norm_rotary_fwd", "gqa_norm_rotary_bwd"):
+        assert calls.count(name) == (kind == "window"), calls
+    wide = {length * heads * d, length * kv_heads * d}
+    for shape in re.findall(r"= \w+\[([\d,]+)\]\S* concatenate\(", text):
+        assert math.prod(int(n) for n in shape.split(",")) not in wide, shape
+    q, k = (1, length, heads, d), (1, length, kv_heads, d)
+    for backward in (False, True):
+        plan = ge.norm_rotary_plan(q, k, jnp.bfloat16, backward)
+        assert plan.tile >= 128 and plan.vmem_bytes <= ge.VMEM_BUDGET
+
+
 def test_fused_bucket_kernels_refuse_what_vmem_cannot_hold():
     """Above the size the whole-array VMEM refs support the kernels
     raise — they never switch paths quietly."""
