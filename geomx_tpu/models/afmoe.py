@@ -33,7 +33,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
-from geomx_tpu.models.decoder import DecoderLM, _fan_in
+from geomx_tpu.models.decoder import DecoderLM, HeadScale, _fan_in
 from geomx_tpu.ops import dispatch
 from geomx_tpu.ops.flash_attention import fused_attention
 from geomx_tpu.ops.gqa_elementwise import gated_ref
@@ -55,15 +55,6 @@ def rotary(x, theta: float):
     x32 = x.astype(jnp.float32)
     turned = jnp.concatenate([-x32[..., half:], x32[..., :half]], -1)
     return (x32 * cos + turned * sin).astype(x.dtype)
-
-
-class HeadScale(nn.Module):
-    """The learned scale of a per-head RMSNorm, where `decoder.RMSNorm`
-    keeps it (``<name>/scale``, ones)."""
-
-    @nn.compact
-    def __call__(self, d: int):
-        return self.param("scale", nn.initializers.ones, (d,))
 
 
 class GQAMixer(nn.Module):
@@ -135,6 +126,7 @@ class AfmoeConfig:
     remat: bool = True
 
     post_norms = True           # N2 and N4: a norm after each half too
+    expert_form = {}            # SwiGLU experts in the hidden width
 
     def make_mixer(self, kind: str, dtype):
         if kind not in ("window", "global"):

@@ -1,17 +1,24 @@
 """What the causal decoders share (`models/kimi_linear.py`,
-`models/afmoe.py`): RMSNorm, the SwiGLU MLP, the router, the expert layer
-that holds some of its experts (`ops/held_experts.py`), the residual block
-whose two halves are rematerialised apart, the model around the blocks and
-its blocked next-token loss.
+`models/afmoe.py`, `models/nemotron_h.py`): RMSNorm, the SwiGLU MLP, the
+router, the expert layer that holds some of its experts
+(`ops/held_experts.py`), the residual block whose two halves are
+rematerialised apart, the model around the blocks and its blocked
+next-token loss.
 
     h += [PostNorm](Mixer(RMSNorm(h)));  h += [PostNorm](FFN(RMSNorm(h)))
 
+A block has both halves or ONE of them ((mixer, None) or (None, ffn): a
+Nemotron-H layer is a mixer alone or a feed-forward alone, one norm and
+one residual add).
+
 A decoder's configuration (a frozen dataclass) gives the widths the
-feed-forward half reads (`FFNBranch`), `layers` ((mixer, ffn) a block),
-`vocab`, `hidden`, `eps`, `loss_block`, `remat`, and three things of its
-own: `make_mixer(kind, dtype)` (the module under ``core`` of a block's
-mixer half), `post_norms` (a second RMSNorm on each half's output) and
-`embedding_scale`.
+feed-forward half reads (`FFNBranch`), `layers` ((mixer, ffn) a block,
+either None), `vocab`, `hidden`, `eps`, `loss_block`, `remat`, and four
+things of its own: `make_mixer(kind, dtype)` (the module under ``core``
+of a block's mixer half), `post_norms` (a second RMSNorm on each half's
+output), `embedding_scale` and `expert_form` (further fields of
+`HeldExpertsLayer`: {} for SwiGLU experts in the hidden width; a
+LatentMoE gives ``latent``, ``gated`` False and ``shared_width``).
 
 The model brings its own loss (`loss_and_aux`): mean next-token
 cross-entropy in float32, blocked over tokens so that no whole logits
@@ -20,14 +27,14 @@ backward pass each block's mixer is rematerialised a sequence at a time and
 its feed-forward half on its own.
 
 Scopes (telemetry/layers.SCOPES): ``moe/route``, ``moe/experts``,
-``moe/shared``, ``lm/loss``, and the mixers' own.  Module names are
-``mixer``, ``ffn``, ``core``, ``norm`` and ``post_norm`` so that flax's own
-name stack never reads as one of them.
+``moe/shared``, ``moe/latent``, ``lm/loss``, and the mixers' own.  Module
+names are ``mixer``, ``ffn``, ``core``, ``norm`` and ``post_norm`` so that
+flax's own name stack never reads as one of them.
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -64,6 +71,31 @@ def swiglu(x, gate, up, down):
     return jnp.dot(jax.nn.silu(jnp.dot(x, gate)) * jnp.dot(x, up), down)
 
 
+def relu2(x, up, down):
+    """The un-gated squared-ReLU MLP, relu(x W1)^2 W2."""
+    return jnp.dot(jnp.square(jax.nn.relu(jnp.dot(x, up))), down)
+
+
+def causal_conv(x, kernel):
+    """Depthwise causal convolution over time, heads-major: x [B, H, L,
+    e], kernel [taps, H, e]; tap ``taps - 1`` multiplies the current
+    token."""
+    taps, length = kernel.shape[0], x.shape[2]
+    padded = jnp.pad(x, ((0, 0), (0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, :, j:j + length] * kernel[j][:, None, :]
+               for j in range(taps))
+
+
+class HeadScale(nn.Module):
+    """The learned scale of a norm applied by its caller (a per-head or
+    per-group RMSNorm), where `RMSNorm` keeps it (``<name>/scale``,
+    ones)."""
+
+    @nn.compact
+    def __call__(self, d: int):
+        return self.param("scale", nn.initializers.ones, (d,))
+
+
 class MLP(nn.Module):
     width: int
     dtype: Any = jnp.float32
@@ -79,11 +111,18 @@ class MLP(nn.Module):
 
 def route(x, router, bias, top_k: int, scaling: float):
     """Sigmoid scores in float32, the ``top_k`` largest of score + bias,
-    weights normalised over the selected and scaled.  x [T, d]."""
+    weights normalised over the selected and scaled.  x [T, d].  The
+    picked scores are read by a compare-and-sum over the experts, not by
+    a gather: XLA's gather of [T, k] out of [T, E] costs 10 ns an index on
+    a v5e, its transpose (a scatter-add) 9 ns, and both want the scores
+    relaid flat (52 of a 572 ms step at 22 of 512: PERF.md, PR 38); the
+    sum's one non-zero term is the score itself, forward and backward, so
+    nothing is rounded."""
     scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
                                     precision=_HIGHEST))
     _, idx = lax.top_k(scores + bias, top_k)
-    picked = jnp.take_along_axis(scores, idx, axis=-1)
+    hit = idx[..., None] == jnp.arange(scores.shape[-1])     # [T, k, E]
+    picked = jnp.sum(jnp.where(hit, scores[:, None, :], 0.0), -1)
     return idx, scaling * picked / jnp.sum(picked, -1, keepdims=True)
 
 
@@ -91,8 +130,13 @@ class HeldExpertsLayer(nn.Module):
     """Routes over ``num_experts``, holds ``num_held`` of them from
     ``offset`` on, and computes the shared expert plus its own experts'
     part of the result.  The selection bias is not trained by gradient:
-    zeros, outside ``params``.  Returns (y, assignments that arrived at
-    each held expert [num_held], assignments dropped: 0)."""
+    zeros, outside ``params``.  ``gated`` False: shared and routed experts
+    are un-gated squared-ReLU MLPs (no gate kernels).  ``latent``: the
+    routed experts live in that width, between a down- and an up-
+    projection of their own (LatentMoE; router and shared expert read the
+    hidden state).  ``shared_width``: the shared expert's own width where
+    it is not ``shared_experts x width``.  Returns (y, assignments that
+    arrived at each held expert [num_held], assignments dropped: 0)."""
     num_experts: int
     num_held: int
     offset: int
@@ -103,32 +147,48 @@ class HeldExpertsLayer(nn.Module):
     rows: int = 512             # assignments a tile of the kernels holds
     dtype: Any = jnp.float32
     pool: int | None = None     # places of the first pool; None: 2 E rows
+    gated: bool = True
+    latent: int | None = None
+    shared_width: int | None = None
 
     @nn.compact
     def __call__(self, x):
         hidden, dt = x.shape[-1], self.dtype
         tokens = x.reshape(-1, hidden)
         mat = lambda name, shape: self.param(name, _fan_in, shape)
+        cast = lambda name, shape: mat(name, shape).astype(dt)
         with profile_scope("moe/route", "compute"):
             idx, weights = route(
                 tokens, mat("router_kernel", (hidden, self.num_experts)),
                 jnp.zeros((self.num_experts,), jnp.float32), self.top_k,
                 self.scaling)
         with profile_scope("moe/shared", "compute"):
-            wide = self.shared_experts * self.width
-            y = swiglu(tokens,
-                       mat("shared_gate_kernel", (hidden, wide)).astype(dt),
-                       mat("shared_up_kernel", (hidden, wide)).astype(dt),
-                       mat("shared_down_kernel", (wide, hidden)).astype(dt))
+            wide = self.shared_width or self.shared_experts * self.width
+            gate = ([cast("shared_gate_kernel", (hidden, wide))]
+                    if self.gated else [])
+            y = (swiglu if self.gated else relu2)(
+                tokens, *gate, cast("shared_up_kernel", (hidden, wide)),
+                cast("shared_down_kernel", (wide, hidden)))
+        inner = self.latent or hidden
+        if self.latent:
+            with profile_scope("moe/latent", "compute"):
+                tokens = jnp.dot(tokens,
+                                 cast("latent_down_kernel", (hidden, inner)))
         with profile_scope("moe/experts", "compute"):
-            into = (self.num_held, hidden, self.width)
+            into = (self.num_held, inner, self.width)
             routed, counts, dropped = held_experts(
-                tokens, idx, weights, mat("experts_gate_kernel", into),
+                tokens, idx, weights,
+                mat("experts_gate_kernel", into) if self.gated else None,
                 mat("experts_up_kernel", into),
                 mat("experts_down_kernel",
-                    (self.num_held, self.width, hidden)),
+                    (self.num_held, self.width, inner)),
                 self.offset, self.rows, None, self.pool)
-            y = (y + routed).astype(dt)
+            if not self.latent:
+                y = (y + routed).astype(dt)
+        if self.latent:
+            with profile_scope("moe/latent", "compute"):
+                y = y + jnp.dot(routed.astype(dt),
+                                cast("latent_up_kernel", (inner, hidden)))
         return y.reshape(x.shape), counts, dropped
 
 
@@ -172,26 +232,34 @@ class FFNBranch(nn.Module):
             y, counts, dropped = HeldExpertsLayer(
                 c.num_experts, c.experts_held, c.expert_offset, c.top_k,
                 c.expert_width, c.routed_scaling, c.shared_experts,
-                c.expert_rows, dt, c.expert_pool, name="core")(x)
+                c.expert_rows, dt, c.expert_pool, name="core",
+                **c.expert_form)(x)
         if c.post_norms:
             y = RMSNorm(c.eps, name="post_norm")(y)
         return h + y, counts, dropped
 
 
 class Block(nn.Module):
-    mixer: str
-    ffn: str
+    """The halves it has, mixer first: ``mixer`` or ``ffn`` None leaves
+    that half out, parameters and all.  Returns (h, assignments that
+    arrived at each held expert: none without an expert layer, assignments
+    dropped)."""
+    mixer: Optional[str]
+    ffn: Optional[str]
     cfg: Any
     dtype: Any = jnp.float32
 
     @nn.compact
     def __call__(self, h):
         remat = nn.remat if self.cfg.remat else (lambda m, **_: m)
-        per_sequence = nn.scan(
-            remat(MixerBranch, prevent_cse=False),
-            variable_broadcast="params", split_rngs={"params": False})
-        _, h = per_sequence(self.mixer, self.cfg, self.dtype,
-                            name="mixer")((), h)
+        if self.mixer is not None:
+            per_sequence = nn.scan(
+                remat(MixerBranch, prevent_cse=False),
+                variable_broadcast="params", split_rngs={"params": False})
+            _, h = per_sequence(self.mixer, self.cfg, self.dtype,
+                                name="mixer")((), h)
+        if self.ffn is None:
+            return h, jnp.zeros((0,), jnp.int32), jnp.zeros((), jnp.int32)
         return remat(FFNBranch)(self.ffn, self.cfg, self.dtype,
                                 name="ffn")(h)
 

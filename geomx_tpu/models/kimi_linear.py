@@ -37,23 +37,14 @@ from jax import lax
 from geomx_tpu.models.decoder import (MLP, Block, DecoderLM,  # noqa: F401
                                       FFNBranch, HeldExpertsLayer,
                                       MixerBranch, RMSNorm, _fan_in, _normal,
-                                      blocked_cross_entropy, route, swiglu)
+                                      blocked_cross_entropy, causal_conv,
+                                      route, swiglu)
 from geomx_tpu.ops import dispatch
 from geomx_tpu.ops.flash_attention import fused_attention
 from geomx_tpu.utils.profiler import profile_scope
 
 A_LOG_CENTRE = 1.96          # mean of log U(1, 16)
 DT_BIAS_CENTRE = -4.6        # inverse softplus of 0.01
-
-
-def causal_conv(x, kernel):
-    """Depthwise causal convolution over time, heads-major: x [B, H, L,
-    e], kernel [taps, H, e]; tap ``taps - 1`` multiplies the current
-    token."""
-    taps, length = kernel.shape[0], x.shape[2]
-    padded = jnp.pad(x, ((0, 0), (0, 0), (taps - 1, 0), (0, 0)))
-    return sum(padded[:, :, j:j + length] * kernel[j][:, None, :]
-               for j in range(taps))
 
 
 class KDAMixer(nn.Module):
@@ -188,6 +179,7 @@ class KimiLinearConfig:
     post_norms = False          # pre-norm residual halves, nothing after
     embedding_scale = 1.0
     expert_pool = None          # first pool: 2 x held x expert_rows places
+    expert_form = {}            # SwiGLU experts in the hidden width
 
     def make_mixer(self, kind: str, dtype):
         if kind == "kda":

@@ -22,14 +22,16 @@ makes one HBM round trip:
   flat fp32 buckets in one VMEM-resident pass per bucket, replacing
   the per-leaf optax chain on the hot path (``GEOMX_FUSED_OPTIM``).
 
-Two ops of a decoder's layers are imported from their modules:
+Three ops of a decoder's layers are imported from their modules:
 ``dispatch.kda`` (the chunkwise gated delta rule with a per-channel
 decay: the kernel pair ``kda_pallas.kda_scan`` with the chunk-to-chunk
-state in VMEM, forward and backward, or its jnp form ``kda.kda_chunked``)
+state in VMEM, forward and backward, or its jnp form ``kda.kda_chunked``),
+``dispatch.ssd`` (the Mamba-2 state-space scan in its chunkwise matrix
+form, ``ssd.ssd_chunked``: plain matrix products, no kernel yet)
 and ``held_experts.held_experts`` (the routed experts
 one chip holds: the sorted assignments walked a pool at a time in a loop
-of as many trips as pools exist, the SwiGLU as JAX's megablox grouped
-products).
+of as many trips as pools exist, the experts (SwiGLU, or un-gated
+squared ReLU) as JAX's megablox grouped products).
 
 The names exported here are the kernels themselves (native on a TPU,
 ``interpret=True`` for the CPU parity tests).  The compression engine does

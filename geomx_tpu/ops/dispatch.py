@@ -2,7 +2,9 @@
 
 Every op of the compression engine, a decoder's KDA scan, its expert
 pools' row scatter-add and a grouped-query mixer's q/k norm with rotary
-exist twice under ``ops/``: a Pallas kernel and a jnp form with the same
+exist twice under ``ops/`` (the Mamba-2 scan, :func:`ssd`, has its jnp
+form only so far and comes through here all the same): a Pallas kernel
+and a jnp form with the same
 results (bit for bit, except where an op's docstring says otherwise).  The
 kernel is what a TPU runs; the jnp form is the only path elsewhere and the
 oracle the tests hold the kernel to.  The functions below are what
@@ -27,7 +29,7 @@ import jax
 
 from geomx_tpu.ops import (bsc_pallas, bucket_pallas, gqa_elementwise,
                            kda as kda_jnp, kda_pallas, merge_pallas,
-                           moe_rows_pallas, twobit_pallas)
+                           moe_rows_pallas, ssd as ssd_jnp, twobit_pallas)
 
 _OVERRIDE: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "geomx_kernel_mode", default=None)
@@ -133,6 +135,15 @@ def kda(q, k, v, g, beta, chunk: int = 64, sub: int = 16,
                                    dtype=dtype)
     return kda_pallas.kda_scan(q, k, v, g, beta, chunk, sub, dtype,
                                mode == "interpret")
+
+
+def ssd(x, dt, a, b, c, chunk: int = 128, dtype=jax.numpy.float32):
+    """The Mamba-2 state-space scan in its chunkwise matrix form
+    (``ops/ssd.ssd_chunked`` says what the arguments are).  One
+    implementation on every platform today, plain matrix products under
+    scope ``ssd/scan``; a kernel, once written, is chosen here from
+    :func:`kernel_mode` as :func:`kda`'s is."""
+    return ssd_jnp.ssd_chunked(x, dt, a, b, c, chunk=chunk, dtype=dtype)
 
 
 def row_scatter_add(y, out, token, sizes):

@@ -4,11 +4,15 @@ all of them (expert parallelism's per-chip part, without the exchange).
 Every token chooses ``k`` of ``num_experts`` experts; this chip holds
 ``E`` of them, ids ``[offset, offset + E)``.  The assignments that fall
 on held experts are sorted by expert and walked in pools of consecutive
-assignments: gather the pool's tokens once, run the SwiGLU as grouped
+assignments: gather the pool's tokens once, run the experts as grouped
 products over the experts' runs inside the pool (JAX's megablox kernels:
 a tile of ``rows`` assignments at a time, only the tiles that hold an
 assignment, each with its expert's weights), scatter-add the weighted
-result once.  The first pool has ``pool`` places (``2 * E * rows`` where
+result once.  An expert is a SwiGLU, ``(silu(x W_gate) * x W_up)
+W_down``, or, where no gate is given, the un-gated squared ReLU
+``relu(x W_up)^2 W_down`` (a LatentMoE's experts, which live in a latent
+width): the same plan, pools, row moves and products, one product fewer
+a pool each way.  The first pool has ``pool`` places (``2 * E * rows`` where
 none is given: twice what even routing sends here when ``rows`` is an
 expert's share; a caller whose experts see more gives twice its own even
 load, a multiple of ``rows``) and is always walked, so up to twice even
@@ -28,6 +32,11 @@ What arrives beyond it is walked in pools of
 every token (``T`` rows, the worst case) and nothing is dropped, because
 no capacity exists to overflow.  The walk counts the rows it processed;
 `dropped` is what arrived less that.
+
+The grouped products skip a pool's tiles that hold no assignment and the
+row moves pay by the row, so a layer's time follows the router's load
+(0.16 ms a tile of 512 forward and backward at K = 1,024, N = 2,688 on a
+v5e: PERF.md, PR 38).
 
 A loop with a data-dependent trip count has no reverse-mode derivative in
 JAX, so the backward pass is written out (`custom_vjp`): the same walk,
@@ -150,17 +159,33 @@ def _pool(plan, lo, pool: int):
     return token, weight, valid, ends - starts
 
 
-def _hidden(xs, gate_up, sizes, valid, rows, interpret):
-    """(a, u, silu(a) * u) of a pool, float32; zeros in rows of no run."""
-    au = jnp.where(valid[:, None],
-                   _gmm(xs, gate_up, sizes, rows, interpret), 0.0)
-    a, u = jnp.split(au, 2, axis=-1)
-    return a, u, jax.nn.silu(a) * u
+def _hidden(xs, into, sizes, valid, rows, interpret, gated: bool):
+    """(the first product ``xs`` x ``into``, the hidden layer it gives) of
+    a pool, float32; zeros in rows of no run.  Gated: the product is
+    [a, u] and the hidden layer silu(a) * u; else relu(a)^2."""
+    pre = jnp.where(valid[:, None],
+                    _gmm(xs, into, sizes, rows, interpret), 0.0)
+    if not gated:
+        return pre, jnp.square(jax.nn.relu(pre))
+    a, u = jnp.split(pre, 2, axis=-1)
+    return pre, jax.nn.silu(a) * u
+
+
+def _hidden_bwd(pre, dh, gated: bool):
+    """The first product's cotangent from the hidden layer's."""
+    if not gated:
+        return dh * 2.0 * jax.nn.relu(pre)
+    a, u = jnp.split(pre, 2, axis=-1)
+    sig = jax.nn.sigmoid(a)
+    return jnp.concatenate([dh * u * sig * (1.0 + a * (1.0 - sig)),
+                            dh * a * sig], axis=-1)
 
 
 def _cast(x, gate, up, down):
-    return (jnp.concatenate([gate, up], axis=-1).astype(x.dtype),
-            down.astype(x.dtype))
+    """(the first product's weights: [gate, up], or up alone where there
+    is no gate; the second's), in x's dtype."""
+    into = up if gate is None else jnp.concatenate([gate, up], axis=-1)
+    return into.astype(x.dtype), down.astype(x.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
@@ -168,7 +193,8 @@ def held_experts(x, idx, weights, gate, up, down, offset: int, rows: int,
                  interpret: bool | None = None, pool: int | None = None):
     """x [T, d] (the compute dtype), idx [T, k] int, weights [T, k] f32,
     gate / up [E, d, f], down [E, f, d] (the masters: they are cast to
-    x's dtype once a call, and their gradients come back unrounded).
+    x's dtype once a call, and their gradients come back unrounded);
+    ``gate`` None: un-gated squared-ReLU experts.
     `rows`: the assignments a kernel tile holds.  `interpret`: run the
     kernels in interpret mode; None: wherever the backend is no TPU.
     `pool`: the places of the first pool, whole tiles; None: 2 E rows.
@@ -180,15 +206,16 @@ def held_experts(x, idx, weights, gate, up, down, offset: int, rows: int,
 def _forward(x, idx, weights, gate, up, down, offset, rows, interpret, pool):
     if interpret is None:
         interpret = kernel_mode() != "native"
-    plan = _plan(idx, weights, gate.shape[0], offset, rows, pool)
-    gate_up, down = _cast(x, gate, up, down)
+    gated = gate is not None
+    plan = _plan(idx, weights, up.shape[0], offset, rows, pool)
+    into, down = _cast(x, gate, up, down)
 
     def body(lo, places, carry):
         y, done = carry
         token, weight, valid, sizes = _pool(plan, lo, places)
         with profile_scope(_DISPATCH, "kernel"):
             xs = x.at[token].get(mode="fill", fill_value=0)
-        h = _hidden(xs, gate_up, sizes, valid, rows, interpret)[2]
+        h = _hidden(xs, into, sizes, valid, rows, interpret, gated)[1]
         out = _gmm(h.astype(x.dtype), down, sizes, rows, interpret)
         out = jnp.where(valid[:, None], out * weight[:, None], 0.0)
         with profile_scope(_DISPATCH, "kernel"):
@@ -196,7 +223,7 @@ def _forward(x, idx, weights, gate, up, down, offset, rows, interpret, pool):
         return y, done + jnp.sum(sizes)
 
     y, done = _walk(
-        plan, gate.shape[0], rows, pool, body,
+        plan, up.shape[0], rows, pool, body,
         (jnp.zeros(x.shape, jnp.float32), jnp.zeros((), jnp.int32)))
     counts = plan["counts"]
     return (y, counts, jnp.sum(counts) - done), plan
@@ -213,44 +240,42 @@ def _bwd(offset, rows, interpret, pool, res, cotangents):
     if interpret is None:
         interpret = kernel_mode() != "native"
     dy = cotangents[0].astype(x.dtype)
-    gate_up, down_c = _cast(x, gate, up, down)
+    gated = gate is not None
+    into, down_c = _cast(x, gate, up, down)
 
     def body(lo, places, carry):
-        dx, dw, dgate_up, ddown = carry
+        dx, dw, dinto, ddown = carry
         token, weight, valid, sizes = _pool(plan, lo, places)
         with profile_scope(_DISPATCH, "kernel"):
             xs = x.at[token].get(mode="fill", fill_value=0)
             dys = dy.at[token].get(mode="fill", fill_value=0)
-        a, u, h = _hidden(xs, gate_up, sizes, valid, rows, interpret)
+        pre, h = _hidden(xs, into, sizes, valid, rows, interpret, gated)
         # <h W_down, dy> = <h, dy W_down^T>: one product gives the weight's
         # gradient and, scaled by the weight, the hidden layer's
         g = jnp.where(valid[:, None],
                       _gmm(dys, down_c, sizes, rows, interpret, True), 0.0)
         dw = lax.dynamic_update_slice(dw, jnp.sum(h * g, axis=-1), (lo,))
-        dh = g * weight[:, None]
-        sig = jax.nn.sigmoid(a)
-        dau = jnp.concatenate([dh * u * sig * (1.0 + a * (1.0 - sig)),
-                               dh * a * sig], axis=-1).astype(x.dtype)
+        dpre = _hidden_bwd(pre, g * weight[:, None], gated).astype(x.dtype)
         dout = (dys * weight[:, None]).astype(x.dtype)
         ddown = _tgmm(h.astype(x.dtype), dout, sizes, rows, interpret, ddown)
-        dgate_up = _tgmm(xs, dau, sizes, rows, interpret, dgate_up)
+        dinto = _tgmm(xs, dpre, sizes, rows, interpret, dinto)
         dxs = jnp.where(valid[:, None],
-                        _gmm(dau, gate_up, sizes, rows, interpret, True), 0.0)
+                        _gmm(dpre, into, sizes, rows, interpret, True), 0.0)
         with profile_scope(_DISPATCH, "kernel"):
             dx = row_scatter_add(dx, dxs, token, sizes)
-        return dx, dw, dgate_up, ddown
+        return dx, dw, dinto, ddown
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)
-    dx, dw, dgate_up, ddown = _walk(
-        plan, gate.shape[0], rows, pool, body,
-        (zeros(x), zeros(plan["weight"]), zeros(gate_up), zeros(down)))
-    dgate, dup = jnp.split(dgate_up, 2, axis=-1)
+    dx, dw, dinto, ddown = _walk(
+        plan, up.shape[0], rows, pool, body,
+        (zeros(x), zeros(plan["weight"]), zeros(into), zeros(down)))
+    dgate, dup = jnp.split(dinto, 2, axis=-1) if gated else (None, dinto)
     # back from sorted order to [T, k]
     dweights = jnp.zeros((idx.size,), jnp.float32).at[plan["order"]].set(
         dw[:idx.size], unique_indices=True).reshape(weights.shape)
     return (dx.astype(x.dtype), None, dweights.astype(weights.dtype),
-            dgate.astype(gate.dtype), dup.astype(up.dtype),
-            ddown.astype(down.dtype))
+            dgate.astype(gate.dtype) if gated else None,
+            dup.astype(up.dtype), ddown.astype(down.dtype))
 
 
 held_experts.defvjp(_fwd, _bwd)

@@ -18,9 +18,9 @@ The vocabulary (docs/telemetry.md has the operator's table):
   compression engine (compression/) inside ``step/sync_grads``;
 - ``<axis>_pipeline/*``: the pipelined sync engine (sync/pipeline.py);
 - ``collective/worker``, ``collective/dc``: the tier collectives;
-- ``kda/*``, ``mla/*``, ``gqa/*``, ``moe/*``, ``lm/loss``: a decoder's
-  layers inside ``step/forward_backward`` (models/kimi_linear.py,
-  models/afmoe.py, models/decoder.py);
+- ``kda/*``, ``mla/*``, ``gqa/*``, ``ssd/*``, ``moe/*``, ``lm/loss``: a
+  decoder's layers inside ``step/forward_backward`` (models/kimi_linear.py,
+  models/afmoe.py, models/nemotron_h.py, models/decoder.py);
 - ``attn/core``: the attention kernels and what surrounds them
   (ops/flash_attention.fused_attention), forward and backward;
 - ``train/step``, ``fit/*``, ``loader/*``: host spans of the loop;
@@ -54,8 +54,8 @@ from geomx_tpu.utils.profiler import profile_scope
 SCOPES = (
     ("step/forward_backward", "step program"),
     # a decoder's layers, opened inside step/forward_backward
-    # (models/kimi_linear.py, models/afmoe.py, models/decoder.py,
-    # ops/kda.py)
+    # (models/kimi_linear.py, models/afmoe.py, models/nemotron_h.py,
+    # models/decoder.py, ops/kda.py)
     ("kda/proj", "step program"),
     ("kda/scan", "kernels"),
     ("mla/proj", "step program"),
@@ -63,12 +63,17 @@ SCOPES = (
     ("gqa/proj", "step program"),
     ("gqa/window", "kernels"),
     ("gqa/global", "kernels"),
+    # a Mamba-2 layer (models/nemotron_h.py, ops/ssd.py)
+    ("ssd/proj", "step program"),
+    ("ssd/scan", "kernels"),
     ("moe/route", "step program"),
     ("moe/experts", "step program"),
     # a pool's row gathers and scatter-adds, opened inside moe/experts
     # (ops/held_experts.py)
     ("moe/dispatch", "step program"),
     ("moe/shared", "step program"),
+    # a LatentMoE's down- and up-projection around its routed experts
+    ("moe/latent", "step program"),
     ("lm/loss", "step program"),
     # attention's core, forward and backward, opened by
     # ops/flash_attention.fused_attention; in a decoder it nests inside

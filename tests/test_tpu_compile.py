@@ -174,13 +174,16 @@ def _cell_attention(which, direction):
     causal: 136 block pairs of 512, dq and dk/dv kernels); one sequence of
     the second decoder's grouped-query attention (8,192 x 32 query heads
     on 4 key/value heads of 128), in a window layer (a band of 2,048 keys:
-    70 pairs) and in a global one."""
+    70 pairs) and in a global one; one sequence of the third decoder's
+    share of its attention layer (four query heads on the one key/value
+    head they read)."""
     from geomx_tpu.ops import flash_attention_bwd, flash_attention_with_lse
     b, L, h, kv, d, dv, causal, window = {
         "bert": (16, 512, 16, 16, 64, 64, False, None),
         "latent": (1, 8192, 32, 32, 192, 128, True, None),
         "window": (1, 8192, 32, 4, 128, 128, True, 2048),
-        "global": (1, 8192, 32, 4, 128, 128, True, None)}[which]
+        "global": (1, 8192, 32, 4, 128, 128, True, None),
+        "share": (1, 8192, 4, 1, 128, 128, True, None)}[which]
     bf16 = lambda heads, e: jax.ShapeDtypeStruct((b, L, heads, e),
                                                  jnp.bfloat16)
     if direction == "forward":
@@ -276,6 +279,10 @@ CASES = {
         "global", "forward"),
     "flash_attention_bwd-bf16-grouped-global-sequence":
         lambda: _cell_attention("global", "backward"),
+    "flash_attention-bf16-four-on-one-sequence": lambda: _cell_attention(
+        "share", "forward"),
+    "flash_attention_bwd-bf16-four-on-one-sequence":
+        lambda: _cell_attention("share", "backward"),
     "flash_attention_bwd-f32-grouped-64-wide": lambda: _grouped_narrow(),
     "fused_ring_hop-L1024": lambda: _ring_hop(1024),
     "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
@@ -342,6 +349,67 @@ def test_v5e_compiler_accepts_the_kda_kernels(chip, direction):
     assert ("kda_scan_bwd" in text) == (direction == "backward")
     plan = kda_pallas.kda_plan(8192, 32, 128, 128, 64, jnp.bfloat16)
     assert (plan.heads, plan.chunks) == (4, 4)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_ssd_scan(chip, direction):
+    """The Mamba-2 scan in its chunkwise matrix form (plain XLA through
+    the door `Mamba2Mixer` calls) at the chip benchmark's widths, one
+    sequence of 8,192 tokens, the 16 heads of 64 and the one B/C group of
+    128 a chip holds, chunk 128, bf16 operands: no `while` (the hand-over
+    from chunk to chunk is one product), and it fits."""
+    from geomx_tpu.ops import dispatch
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=chip)
+    args = [on((1, 8192, 16, 64), jnp.bfloat16),
+            on((1, 8192, 16), jnp.float32), on((16,), jnp.float32),
+            on((1, 8192, 1, 128), jnp.bfloat16),
+            on((1, 8192, 1, 128), jnp.bfloat16)]
+    run = lambda *a: dispatch.ssd(*a, 128, jnp.bfloat16)
+    if direction == "backward":
+        fn = jax.grad(lambda *a: jnp.sum(run(*a)), argnums=(0, 1, 2, 3, 4))
+    else:
+        fn = run
+    with dispatch.kernels("native"):
+        compiled = jax.jit(fn).lower(*args).compile()
+    assert " while(" not in compiled.as_text()
+    # a sequence's decay matrices and their cotangents, not gigabytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_ungated_held_experts(chip, direction):
+    """The third decoder cell's routed experts: 16,384 tokens of the
+    1,024-wide latent, 22 of 512 experts a token, 8 held of width 2,688,
+    un-gated squared ReLU (no gate kernel), tiles of 512 in a first pool
+    of 11,264 places (twice what even routing sends the chip): the
+    grouped products' tiles at K = 1,024 / N = 2,688 fit VMEM both ways,
+    and a row of the latent is whole tiles, so the pools' rows go back
+    through `moe_row_scatter_add`."""
+    import re
+    from geomx_tpu.ops import dispatch
+    from geomx_tpu.ops.held_experts import held_experts
+    tokens, d, held, f, rows, pool, k = 16384, 1024, 8, 2688, 512, 11264, 22
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=chip)
+    args = [on((tokens, d), jnp.bfloat16), on((tokens, k), jnp.int32),
+            on((tokens, k), jnp.float32), on((held, d, f), jnp.float32),
+            on((held, f, d), jnp.float32)]
+    run = lambda x, idx, w, up, down: held_experts(
+        x, idx, w, None, up, down, 0, rows, False, pool)
+    if direction == "backward":
+        fn = jax.grad(lambda *a: jnp.sum(run(*a)[0]), argnums=(0, 2, 3, 4))
+    else:
+        fn = run
+    with dispatch.kernels("native"):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = _kernel_calls(text)
+    assert any(c.startswith("gmm") for c in calls), calls
+    assert any(c.startswith("tgmm") for c in calls) == (
+        direction == "backward"), calls
+    moves = [c.split(".")[0] for c in calls if c.startswith("moe_row")]
+    assert moves == ["moe_row_scatter_add"] * 2, calls
+    assert not re.search(r"= f32\[\d+,%d\]\S* scatter\(" % d, text)
 
 
 # (tokens, hidden, held, width, tile, first pool): the two decoder cells'

@@ -8,8 +8,10 @@ program that runs `ops.held_experts.held_experts` forward and backward
 (value and every gradient) and prints milliseconds per call, medians
 over ``--reps`` runs after a warm-up.  ``--config <file>`` takes ``T``,
 ``d``, ``f``, ``E``, the router's width and the experts a token from a
-configuration's file (`benchmark/configs/*.json`: the decoder cells'), and
-the tile and first pool from its ``program``; the loads are the Kimi
+configuration's file (`benchmark/configs/*.json`: the decoder cells'; a
+file with a ``moe_latent_size`` gives that as ``d``, one whose
+``mlp_hidden_act`` is ``relu2`` un-gated experts), and
+the tile and the first pool from its ``program``; the loads are the Kimi
 cell's patterns (8 held experts, 512 assignments each under even routing)
 repeated over the held experts and scaled to the file's even load.
 
@@ -221,7 +223,7 @@ def main(argv=None) -> int:
 
     ok = True
     rng = np.random.default_rng(0)
-    top_k, held, router = 8, args.held, args.router
+    top_k, held, router, gated = 8, args.held, args.router, True
     t, d, f = args.tokens, args.hidden, args.width
     shapes = args.shapes or "512"
     if args.config:
@@ -231,7 +233,9 @@ def main(argv=None) -> int:
                            config.get("num_experts_per_token"))
         held, router = config["num_experts"], config["router_experts"]
         t = config["per_chip_batch"] * config["sequence_length"]
-        d, f = config["hidden_size"], config["moe_intermediate_size"]
+        d = config.get("moe_latent_size") or config["hidden_size"]
+        f = config["moe_intermediate_size"]
+        gated = config.get("mlp_hidden_act") != "relu2"
         run_keys = config.get("program", {})
         pool = run_keys.get("expert_pool_places")
         shapes = args.shapes or (
@@ -247,6 +251,8 @@ def main(argv=None) -> int:
     r = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)
     mats = [jnp.asarray(rng.standard_normal(s) * 0.02, jnp.float32)
             for s in ((held, d, f), (held, d, f), (held, f, d))]
+    if not gated:
+        mats[0] = None          # relu(x W_up)^2 W_down: no gate
     idxs = {name: jnp.asarray(routing(rng, t, top_k, by_load[name],
                                       router - 1))
             for name in args.loads.split(",")}
