@@ -26,7 +26,8 @@ array lives; `train/step.make_loss_fn` takes it from there.  In the
 backward pass each block's mixer is rematerialised a sequence at a time and
 its feed-forward half on its own.
 
-Scopes (telemetry/layers.SCOPES): ``moe/route``, ``moe/experts``,
+Scopes (telemetry/layers.SCOPES): ``moe/route``, ``moe/experts`` (inside
+it ``ops/held_experts``' own ``moe/plan`` and ``moe/dispatch``),
 ``moe/shared``, ``moe/latent``, ``lm/loss``, and the mixers' own.  Module
 names are ``mixer``, ``ffn``, ``core``, ``norm`` and ``post_norm`` so that
 flax's own name stack never reads as one of them.

@@ -27,7 +27,15 @@ move costs the rows, not the places; elsewhere XLA's own scatter-add,
 which on a v5e pays by the index whether the row exists or not (93 ns a
 place in the step: why the first pool was made large and walked whatever
 arrives).
-What arrives beyond it is walked in pools of
+The plan (scope ``moe/plan``) is the one thing that pays by the routed
+assignment, held or not: ONE stable `lax.sort` of all ``T k`` by held
+expert carries each assignment's index and weight with the key, and the
+backward pass brings the weights' gradient back to ``[T, k]`` by a sort by
+that index (a permutation, so the sort is its inverse).  A 1-D gather or
+scatter of ``T k`` scalars costs a v5e 5-7 ns an index, many times a
+sort's extra operand (PERF.md, PR 39): nothing here gathers or scatters
+over ``T k``.
+What arrives beyond the first pool is walked in pools of
 ``2 * rows`` by a loop of as many trips as it needs: one expert may take
 every token (``T`` rows, the worst case) and nothing is dropped, because
 no capacity exists to overflow.  The walk counts the rows it processed;
@@ -62,6 +70,9 @@ from geomx_tpu.utils.profiler import profile_scope
 # the pools' row moves, forward and backward, whichever implementation
 # `ops/dispatch.py` picks (telemetry/layers.SCOPES)
 _DISPATCH = "moe/dispatch"
+# the plan: the sort of the routed assignments that carries token and
+# weight, the counts, and the sort that brings the weights' gradient back
+_PLAN = "moe/plan"
 
 # the kernels' tiles over the contracted and the output dimension, at most
 GMM_TILES = (1152, 768)
@@ -109,20 +120,24 @@ def _pools(num_held: int, rows: int, assignments: int, pool=None):
 
 def _plan(idx, weights, num_held: int, offset: int, rows: int, pool=None):
     """The held assignments in order of their expert, padded to whole
-    pools.  idx [T, k] global expert ids, weights [T, k]."""
+    pools.  idx [T, k] global expert ids, weights [T, k].  One stable sort
+    by held expert carries each assignment's index and weight along."""
     t, k = idx.shape
-    local = idx.reshape(-1) - offset
-    held = (local >= 0) & (local < num_held)
-    key = jnp.where(held, local, num_held).astype(jnp.int32)
-    order = jnp.argsort(key, stable=True)
-    counts = jnp.sum(key[:, None] == jnp.arange(num_held)[None, :], axis=0,
-                     dtype=jnp.int32)                              # [E]
-    pad = (0, _pools(num_held, rows, t * k, pool)[2] - t * k)
-    return {"token": jnp.pad((order // k).astype(jnp.int32), pad),
-            "weight": jnp.pad(
-                weights.reshape(-1)[order].astype(jnp.float32), pad),
-            "order": order, "counts": counts, "ends": jnp.cumsum(counts),
-            "tokens": t}
+    with profile_scope(_PLAN, "compute"):
+        local = idx.reshape(-1) - offset
+        held = (local >= 0) & (local < num_held)
+        key = jnp.where(held, local, num_held).astype(jnp.int32)
+        _, order, weight = lax.sort(
+            (key, lax.iota(jnp.int32, t * k),
+             weights.reshape(-1).astype(jnp.float32)),
+            num_keys=1, is_stable=True)
+        counts = jnp.sum(key[:, None] == jnp.arange(num_held)[None, :],
+                         axis=0, dtype=jnp.int32)                  # [E]
+        pad = (0, _pools(num_held, rows, t * k, pool)[2] - t * k)
+        return {"token": jnp.pad(order // k, pad),
+                "weight": jnp.pad(weight, pad),
+                "order": order, "counts": counts,
+                "ends": jnp.cumsum(counts), "tokens": t}
 
 
 def _walk(plan, num_held: int, rows: int, pool, body, carry):
@@ -270,9 +285,12 @@ def _bwd(offset, rows, interpret, pool, res, cotangents):
         plan, up.shape[0], rows, pool, body,
         (zeros(x), zeros(plan["weight"]), zeros(into), zeros(down)))
     dgate, dup = jnp.split(dinto, 2, axis=-1) if gated else (None, dinto)
-    # back from sorted order to [T, k]
-    dweights = jnp.zeros((idx.size,), jnp.float32).at[plan["order"]].set(
-        dw[:idx.size], unique_indices=True).reshape(weights.shape)
+    # back from sorted order to [T, k]: `order` is a permutation, so the
+    # sort by it is its inverse (and no two keys tie: a stable sort would
+    # carry an index beside them for nothing)
+    with profile_scope(_PLAN, "compute"):
+        dweights = lax.sort((plan["order"], dw[:idx.size]), num_keys=1,
+                            is_stable=False)[1].reshape(weights.shape)
     return (dx.astype(x.dtype), None, dweights.astype(weights.dtype),
             dgate.astype(gate.dtype) if gated else None,
             dup.astype(up.dtype), ddown.astype(down.dtype))

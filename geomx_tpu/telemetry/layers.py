@@ -71,6 +71,9 @@ SCOPES = (
     # a pool's row gathers and scatter-adds, opened inside moe/experts
     # (ops/held_experts.py)
     ("moe/dispatch", "step program"),
+    # the sorts of the routed assignments (the plan, and the weights'
+    # gradient back) and the counts, opened inside moe/experts too
+    ("moe/plan", "step program"),
     ("moe/shared", "step program"),
     # a LatentMoE's down- and up-projection around its routed experts
     ("moe/latent", "step program"),

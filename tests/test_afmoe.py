@@ -263,8 +263,8 @@ def test_the_compiled_step_names_the_decoders_layers():
     scopes = {entry.scope for entry in op_layers(text).values()
               if entry.scope}
     for needle in ("gqa/proj", "gqa/window/attn/core", "gqa/global/attn/core",
-                   "moe/route", "moe/experts", "moe/dispatch", "moe/shared",
-                   "lm/loss"):
+                   "moe/route", "moe/experts", "moe/dispatch", "moe/plan",
+                   "moe/shared", "lm/loss"):
         assert any(needle in s for s in scopes), (needle, sorted(scopes))
 
 

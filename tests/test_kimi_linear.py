@@ -179,6 +179,7 @@ def test_the_new_scopes_are_pairs_of_the_vocabulary():
                          ("moe/route", "step program"),
                          ("moe/experts", "step program"),
                          ("moe/dispatch", "step program"),
+                         ("moe/plan", "step program"),
                          ("moe/shared", "step program"),
                          ("lm/loss", "step program")]:
         assert layer_of(scope) == layer
@@ -210,6 +211,6 @@ def test_the_compiled_step_names_the_decoders_layers():
     scopes = {entry.scope for entry in op_layers(text).values()
               if entry.scope}
     for needle in ("kda/proj", "kda/scan", "mla/proj", "mla/attention",
-                   "moe/route", "moe/experts", "moe/dispatch", "moe/shared",
-                   "lm/loss"):
+                   "moe/route", "moe/experts", "moe/dispatch", "moe/plan",
+                   "moe/shared", "lm/loss"):
         assert any(needle in s for s in scopes), (needle, sorted(scopes))

@@ -312,7 +312,8 @@ def test_the_new_scopes_reach_the_compiled_step():
         {"params": p}, x, y, method="loss_and_aux")[0])).lower(
         params).as_text(debug_info=True)
     for scope in ("ssd/proj", "ssd/scan", "moe/latent", "moe/route",
-                  "moe/shared", "moe/experts", "moe/dispatch", "gqa/proj",
+                  "moe/shared", "moe/experts", "moe/dispatch", "moe/plan",
+                  "gqa/proj",
                   "gqa/global", "attn/core", "lm/loss"):
         assert scope + "/" in text, scope
     from geomx_tpu.telemetry.layers import layer_of

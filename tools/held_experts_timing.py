@@ -27,7 +27,12 @@ repeated over the held experts and scaled to the file's even load.
 (XLA's, and the kernel of `ops/moe_rows_pallas.py`) alone at four loads,
 many calls in one program with the operands made inside it (a host-timed
 call costs ~0.6 ms whatever it does, and reads cold operands), every row
-of the kernel's compared with XLA's (exit 2 where they differ).  A step of
+of the kernel's compared with XLA's (exit 2 where they differ); then the
+plan's pieces at the cells' 131,072 and 360,448 routed assignments
+(`PLAN_SIZES`): the sort that carries index and weight against `argsort`
+and a 1-D gather of the weights, and the sort that brings `dweights` back
+against the 1-D scatter, made and timed the same way, outputs compared bit
+for bit, a ``Verdict`` line each.  A step of
 the cell calls the layer four times (its four expert layers), each forward
 and backward once; the rematerialised forward needs the sorted assignments
 again and, where the block has a post-norm that reads `y` (the Trinity
@@ -192,6 +197,102 @@ def pieces(args, x, r, by_load, pool, rng) -> bool:
     return ok
 
 
+# (tokens, experts a token, held, routed over): the Trinity cell's routed
+# assignments (the Kimi cell's are as many) and the Nemotron cell's
+PLAN_SIZES = ((16384, 8, 16, 128), (16384, 22, 8, 512))
+
+
+def plan_pieces(args, rng) -> bool:
+    """What `ops/held_experts` pays by the routed assignment, alone: the
+    plan's sort with its payload (`sort3`: key, index, weight) against
+    `argsort` + the weights' 1-D gather (and `sort2`, the argsort alone),
+    and `dweights`' way back as a sort by `order` against the 1-D scatter.
+    `CALLS` calls in one program that makes each call's operands (the held
+    experts' ids turned, the weights scaled, the permutation shifted), the
+    making taken off.  False where a pair's outputs differ in any bit."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    def sort3(key, w):
+        return lax.sort((key, lax.iota(jnp.int32, key.size), w),
+                        num_keys=1, is_stable=True)[1:]
+
+    def argsort_gather(key, w):
+        order = jnp.argsort(key, stable=True)
+        return order, w[order]
+
+    def sort2(key, w):                  # the argsort alone
+        return jnp.argsort(key, stable=True), w
+
+    def scatter(order, dw):
+        return (jnp.zeros(dw.shape, dw.dtype).at[order].set(
+            dw, unique_indices=True),)
+
+    def sort_back(order, dw):
+        return (lax.sort((order, dw), num_keys=1, is_stable=False)[1],)
+
+    def every_call(move, make):
+        def one(c, acc, *operands):
+            made = lax.optimization_barrier(make(c, *operands))
+            moved = lax.optimization_barrier(
+                made if move is None else move(*made))
+            return acc + sum(m[0].astype(jnp.float32) for m in moved)
+        return jax.jit(lambda *operands: lax.fori_loop(
+            0, CALLS, lambda c, acc: one(c, acc, *operands),
+            jnp.zeros((), jnp.float32)))
+
+    scale = lambda c, w: w * (1.0 + c.astype(jnp.float32) * 2.0 ** -10)
+    on_chip = jax.default_backend() == "tpu"
+    ok = True
+    for tokens, top_k, held, router in PLAN_SIZES:
+        n = tokens * top_k
+        idx = jnp.asarray(routing(
+            rng, tokens, top_k, loads(held, n // router, tokens)["seeded"],
+            router - 1)).reshape(-1)
+        key = jnp.where(idx < held, idx, held).astype(jnp.int32)
+        w = jnp.asarray(rng.uniform(0.1, 0.5, n), jnp.float32)
+        order = jnp.argsort(key, stable=True)
+        turned = lambda c, key_, w_: (
+            jnp.where(key_ == held, held, (key_ + c) % held), scale(c, w_))
+        shifted = lambda c, order_, dw: ((order_ + c) % n, scale(c, dw))
+        # (piece, (what the layer did, what it does, others), ...)
+        for piece, moves, make, operands in (
+                ("plan_sort", (argsort_gather, sort3, sort2), turned,
+                 (key, w)),
+                ("dweights_back", (scatter, sort_back), shifted,
+                 (order, w))):
+            old, new = (move.__name__ for move in moves[:2])
+            unequal = sum(
+                int(jnp.sum(lax.bitcast_convert_type(a, jnp.int32)
+                            != lax.bitcast_convert_type(b, jnp.int32)))
+                for a, b in zip(*(jax.jit(move)(*operands)
+                                  for move in moves[:2])))
+            ok = ok and not unequal
+            line = {"piece": piece, "assignments": n, "held": held,
+                    "arrived": int(jnp.sum(key < held)),
+                    "unequal": unequal}
+            if on_chip:
+                made = median_ms(every_call(None, make), operands, args.reps)
+                line["make_ms"] = made / CALLS
+                for move in moves:
+                    line[move.__name__ + "_ms"] = (median_ms(
+                        every_call(move, make), operands, args.reps)
+                        - made) / CALLS
+                line["ns_an_index_saved"] = 1e6 * (
+                    line[old + "_ms"] - line[new + "_ms"]) / n
+            print(json.dumps(line), flush=True)
+            if on_chip:
+                print("Verdict " + json.dumps({
+                    "piece": piece, "assignments": n,
+                    old + "_ms": line[old + "_ms"],
+                    new + "_ms": line[new + "_ms"],
+                    "faster": min(old, new,
+                                  key=lambda name: line[name + "_ms"]),
+                    "ops/held_experts.py": new}), flush=True)
+    return ok
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("--config", default=None,
@@ -213,7 +314,9 @@ def main(argv=None) -> int:
                              "separated by spaces")
     parser.add_argument("--loads", default=",".join(LOADS))
     parser.add_argument("--pieces", action="store_true",
-                        help="time a pool's gather and scatter-add alone")
+                        help="time a pool's gather and scatter-add alone, "
+                             "and the plan's sorts against the 1-D gather "
+                             "and scatter they replaced")
     args = parser.parse_args(argv)
 
     import jax
@@ -293,6 +396,7 @@ def main(argv=None) -> int:
                     flush=True)
     if args.pieces:
         ok = pieces(args, x, r, by_load, first_pool(shapes[0]), rng)
+        ok = plan_pieces(args, rng) and ok
     if args.other:
         spec = importlib.util.spec_from_file_location("other", args.other)
         other = importlib.util.module_from_spec(spec)
@@ -300,17 +404,20 @@ def main(argv=None) -> int:
         run = program(other.held_experts, *shapes[0])
         for load, idx in idxs.items():
             _, grads = run(x, w, *mats, idx)
-            off = [float(jnp.linalg.norm((a - b).astype(jnp.float32).ravel())
-                         / jnp.maximum(jnp.linalg.norm(
-                             b.astype(jnp.float32).ravel()), 1e-30))
-                   for a, b in zip(reference[load], grads)]
+            # un-gated experts have no gate and no gradient for it
+            off = {name: float(
+                jnp.linalg.norm((a - b).astype(jnp.float32).ravel())
+                / jnp.maximum(jnp.linalg.norm(
+                    b.astype(jnp.float32).ravel()), 1e-30))
+                for name, a, b in zip(("x", "weights", "gate", "up", "down"),
+                                      reference[load], grads)
+                if a is not None}
             print(json.dumps({"variant": "other", "load": load,
                               "assignments": sum(by_load[load]),
                               "ms": median_ms(run, (x, w, *mats, idx),
                                               args.reps),
-                              "new_vs_other_grad_error": dict(zip(
-                                  ("x", "weights", "gate", "up", "down"),
-                                  off))}), flush=True)
+                              "new_vs_other_grad_error": off}),
+                  flush=True)
     return 0 if ok else 2
 
 
