@@ -9,6 +9,7 @@ import jax.numpy as jnp
 from geomx_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from geomx_tpu.models.cnn import GeoCNN
 from geomx_tpu.models.kimi_linear import KimiLinearConfig, KimiLinearLM
+from geomx_tpu.models.mellum import MellumConfig, MellumLM
 from geomx_tpu.models.mlp import MLP, AlexNet
 from geomx_tpu.models.nemotron_h import NemotronHConfig, NemotronHLM
 from geomx_tpu.models.resnet import (ResNet, ResNet18, ResNet20, ResNet32,
@@ -18,7 +19,8 @@ from geomx_tpu.models.seq_classifier import SeqClassifier
 __all__ = ["GeoCNN", "MLP", "AlexNet",
            "ResNet", "ResNet20", "ResNet32", "ResNet56", "ResNet18",
            "SeqClassifier", "KimiLinearConfig", "KimiLinearLM", "AfmoeConfig",
-           "AfmoeLM", "NemotronHConfig", "NemotronHLM", "get_model"]
+           "AfmoeLM", "NemotronHConfig", "NemotronHLM", "MellumConfig",
+           "MellumLM", "get_model"]
 
 # GEOMX_PRECISION -> the models' compute dtype.  Params always stay
 # fp32 (flax casts per-op from the fp32 masters); every model's
@@ -33,8 +35,9 @@ def get_model(name: str, num_classes: int = 10, precision: str = None,
     dtype explicitly; the default ``None`` keeps each model's
     historical default (byte-identical traces).  ``sizes``: the fields of
     `KimiLinearConfig` for ``"kimi_linear"``, of `AfmoeConfig` for
-    ``"afmoe"`` and of `NemotronHConfig` for ``"nemotron_h"``, causal
-    decoders that bring their own next-token loss (no ``num_classes``)."""
+    ``"afmoe"``, of `NemotronHConfig` for ``"nemotron_h"`` and of
+    `MellumConfig` for ``"mellum"``, causal decoders that bring their own
+    next-token loss (no ``num_classes``)."""
     name = name.lower()
     dt = {}
     if precision is not None:
@@ -45,6 +48,8 @@ def get_model(name: str, num_classes: int = 10, precision: str = None,
         return AfmoeLM(AfmoeConfig(**sizes), **dt)
     if name == "nemotron_h":
         return NemotronHLM(NemotronHConfig(**sizes), **dt)
+    if name == "mellum":
+        return MellumLM(MellumConfig(**sizes), **dt)
     if name in ("cnn", "geocnn", "lenet"):
         return GeoCNN(num_classes=num_classes, **dt)
     if name == "mlp":

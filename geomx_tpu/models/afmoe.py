@@ -16,7 +16,9 @@ after each half; the embedding scaled by `embedding_scale`; an untied head.
 
 This file holds the mixer and the configuration; the block, the
 feed-forward half, the router, the model and its blocked next-token loss
-are `models/decoder.py`'s, shared with `models/kimi_linear.py`.
+are `models/decoder.py`'s, shared with `models/kimi_linear.py`.  The mixer
+is `models/mellum.py`'s too, which gives both kinds of layer positions (a
+table per kind) and no gate.
 
 Scopes (telemetry/layers.SCOPES): ``gqa/proj`` (the four products in, the
 q/k norms, rotary, the gate, the product out), ``gqa/window`` and
@@ -58,17 +60,22 @@ def rotary(x, theta: float):
 
 
 class GQAMixer(nn.Module):
-    """``window`` None: a global layer (causal, no positions); else a
-    window layer (rotary, key j seen by query i iff 0 <= i - j < window).
-    k and v keep their ``num_kv_heads`` heads all the way into the
-    kernels: query head n reads key/value head n // (heads / kv heads)."""
+    """``window`` None: a global layer (causal over every earlier key);
+    else a window layer (key j seen by query i iff 0 <= i - j < window).
+    ``rope``: the layer's positions, rotate-half rotary on q and k from
+    the tables `ops/gqa_elementwise.rotary_tables` makes of it: None (no
+    positions at all), a theta, or a ``gqa_elementwise.Yarn``.  ``gated``
+    False: no gate kernel, the core's output goes to W_o as it is.  k and
+    v keep their ``num_kv_heads`` heads all the way into the kernels:
+    query head n reads key/value head n // (heads / kv heads)."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
     window: Optional[int]
-    rope_theta: float
+    rope: Any
     eps: float
     dtype: Any = jnp.float32
+    gated: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -84,15 +91,18 @@ class GQAMixer(nn.Module):
             q, k = dispatch.gqa_norm_rotary(
                 heads("q_kernel", h), heads("k_kernel", kv),
                 HeadScale(name="q_norm")(d), HeadScale(name="k_norm")(d),
-                self.eps, None if self.window is None else self.rope_theta)
+                self.eps, self.rope)
             v = heads("v_kernel", kv)
-            gate = jnp.dot(x, mat("gate_kernel", (hidden, h * d)),
-                           preferred_element_type=jnp.float32)
+            if self.gated:
+                gate = jnp.dot(x, mat("gate_kernel", (hidden, h * d)),
+                               preferred_element_type=jnp.float32)
         with profile_scope("gqa/global" if self.window is None
                            else "gqa/window", "kernel"):
             o = fused_attention(q, k, v, True, False, self.window)
         with profile_scope("gqa/proj", "compute"):
-            o = gated_ref(o.reshape(b, length, h * d), gate)
+            o = o.reshape(b, length, h * d)
+            if self.gated:
+                o = gated_ref(o, gate)
             return jnp.dot(o, mat("out_kernel", (h * d, hidden)))
 
 
@@ -131,9 +141,11 @@ class AfmoeConfig:
     def make_mixer(self, kind: str, dtype):
         if kind not in ("window", "global"):
             raise ValueError(f"no mixer {kind!r}")
+        window = kind == "window"       # rotary on window layers only
         return GQAMixer(self.num_heads, self.num_kv_heads, self.head_dim,
-                        self.window if kind == "window" else None,
-                        self.rope_theta, self.eps, dtype, name="core")
+                        self.window if window else None,
+                        self.rope_theta if window else None, self.eps, dtype,
+                        name="core")
 
 
 class AfmoeLM(DecoderLM):

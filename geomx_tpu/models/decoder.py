@@ -1,6 +1,6 @@
 """What the causal decoders share (`models/kimi_linear.py`,
-`models/afmoe.py`, `models/nemotron_h.py`): RMSNorm, the SwiGLU MLP, the
-router, the expert layer that holds some of its experts
+`models/afmoe.py`, `models/nemotron_h.py`, `models/mellum.py`): RMSNorm,
+the SwiGLU MLP, the router, the expert layer that holds some of its experts
 (`ops/held_experts.py`), the residual block whose two halves are
 rematerialised apart, the model around the blocks and its blocked
 next-token loss.
@@ -18,7 +18,8 @@ things of its own: `make_mixer(kind, dtype)` (the module under ``core``
 of a block's mixer half), `post_norms` (a second RMSNorm on each half's
 output), `embedding_scale` and `expert_form` (further fields of
 `HeldExpertsLayer`: {} for SwiGLU experts in the hidden width; a
-LatentMoE gives ``latent``, ``gated`` False and ``shared_width``).
+LatentMoE gives ``latent``, ``gated`` False and ``shared_width``; a
+softmax router gives ``scoring``).
 
 The model brings its own loss (`loss_and_aux`): mean next-token
 cross-entropy in float32, blocked over tokens so that no whole logits
@@ -110,17 +111,21 @@ class MLP(nn.Module):
                       mat("down_kernel", (self.width, hidden)))
 
 
-def route(x, router, bias, top_k: int, scaling: float):
-    """Sigmoid scores in float32, the ``top_k`` largest of score + bias,
-    weights normalised over the selected and scaled.  x [T, d].  The
+def route(x, router, bias, top_k: int, scaling: float,
+          scoring: str = "sigmoid"):
+    """Scores in float32 (``scoring`` "sigmoid": each expert's own;
+    "softmax": probabilities over all the experts), the ``top_k`` largest
+    of score + bias, weights normalised over the selected and scaled.
+    x [T, d].  The
     picked scores are read by a compare-and-sum over the experts, not by
     a gather: XLA's gather of [T, k] out of [T, E] costs 10 ns an index on
     a v5e, its transpose (a scatter-add) 9 ns, and both want the scores
     relaid flat (52 of a 572 ms step at 22 of 512: PERF.md, PR 38); the
     sum's one non-zero term is the score itself, forward and backward, so
     nothing is rounded."""
-    scores = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), router,
-                                    precision=_HIGHEST))
+    squash = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[scoring]
+    scores = squash(jnp.dot(x.astype(jnp.float32), router,
+                            precision=_HIGHEST))
     _, idx = lax.top_k(scores + bias, top_k)
     hit = idx[..., None] == jnp.arange(scores.shape[-1])     # [T, k, E]
     picked = jnp.sum(jnp.where(hit, scores[:, None, :], 0.0), -1)
@@ -128,9 +133,12 @@ def route(x, router, bias, top_k: int, scaling: float):
 
 
 class HeldExpertsLayer(nn.Module):
-    """Routes over ``num_experts``, holds ``num_held`` of them from
-    ``offset`` on, and computes the shared expert plus its own experts'
-    part of the result.  The selection bias is not trained by gradient:
+    """Routes over ``num_experts`` (`route` under ``scoring``), holds
+    ``num_held`` of them from ``offset`` on, and computes the shared expert
+    plus its own experts' part of the result; with ``shared_experts`` 0
+    (and no ``shared_width``) there is no shared expert: no shared kernels,
+    no ``moe/shared`` scope, the result is the held experts' part alone.
+    The selection bias is not trained by gradient:
     zeros, outside ``params``.  ``gated`` False: shared and routed experts
     are un-gated squared-ReLU MLPs (no gate kernels).  ``latent``: the
     routed experts live in that width, between a down- and an up-
@@ -151,6 +159,7 @@ class HeldExpertsLayer(nn.Module):
     gated: bool = True
     latent: int | None = None
     shared_width: int | None = None
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, x):
@@ -162,14 +171,16 @@ class HeldExpertsLayer(nn.Module):
             idx, weights = route(
                 tokens, mat("router_kernel", (hidden, self.num_experts)),
                 jnp.zeros((self.num_experts,), jnp.float32), self.top_k,
-                self.scaling)
-        with profile_scope("moe/shared", "compute"):
-            wide = self.shared_width or self.shared_experts * self.width
-            gate = ([cast("shared_gate_kernel", (hidden, wide))]
-                    if self.gated else [])
-            y = (swiglu if self.gated else relu2)(
-                tokens, *gate, cast("shared_up_kernel", (hidden, wide)),
-                cast("shared_down_kernel", (wide, hidden)))
+                self.scaling, self.scoring)
+        wide = self.shared_width or self.shared_experts * self.width
+        y = None
+        if wide:
+            with profile_scope("moe/shared", "compute"):
+                gate = ([cast("shared_gate_kernel", (hidden, wide))]
+                        if self.gated else [])
+                y = (swiglu if self.gated else relu2)(
+                    tokens, *gate, cast("shared_up_kernel", (hidden, wide)),
+                    cast("shared_down_kernel", (wide, hidden)))
         inner = self.latent or hidden
         if self.latent:
             with profile_scope("moe/latent", "compute"):
@@ -185,11 +196,12 @@ class HeldExpertsLayer(nn.Module):
                     (self.num_held, self.width, inner)),
                 self.offset, self.rows, None, self.pool)
             if not self.latent:
-                y = (y + routed).astype(dt)
+                y = (routed if y is None else y + routed).astype(dt)
         if self.latent:
             with profile_scope("moe/latent", "compute"):
-                y = y + jnp.dot(routed.astype(dt),
-                                cast("latent_up_kernel", (inner, hidden)))
+                up = jnp.dot(routed.astype(dt),
+                             cast("latent_up_kernel", (inner, hidden)))
+                y = up if y is None else y + up
         return y.reshape(x.shape), counts, dropped
 
 

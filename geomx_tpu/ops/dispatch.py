@@ -164,12 +164,13 @@ def row_scatter_add(y, out, token, sizes):
         y, out, token, sizes, interpret=mode == "interpret")
 
 
-def gqa_norm_rotary(q, k, q_scale, k_scale, eps: float, theta):
+def gqa_norm_rotary(q, k, q_scale, k_scale, eps: float, rope):
     """A grouped-query mixer's per-head RMSNorm of q ``[B, L, H, d]`` and
-    k ``[B, L, KV, d]`` (one learned scale each) and, where ``theta`` is
-    not None, rotate-half rotary at positions 0..L-1: ``(q, k)`` in their
-    own dtype.  With rotary, the kernel pair of ``gqa_elementwise`` with
-    its own backward where :func:`kernel_mode` names one and the shapes
+    k ``[B, L, KV, d]`` (one learned scale each) and, where ``rope`` is
+    not None (a theta, or a ``gqa_elementwise.Yarn``), rotate-half rotary
+    at positions 0..L-1 from its tables: ``(q, k)`` in their own dtype.
+    With rotary, the kernel pair of ``gqa_elementwise`` with its own
+    backward where :func:`kernel_mode` names one and the shapes
     are the kernels' (``gqa_elementwise.norm_rotary_plan``: a head of
     whole lane tiles, bf16 or float32, ``MIN_TILE`` tokens or more); else,
     and for the norm alone (which XLA streams as one pass itself), the
@@ -178,10 +179,10 @@ def gqa_norm_rotary(q, k, q_scale, k_scale, eps: float, theta):
     TPU it elides that round trip, and the kernels, which keep it, differ
     from its program in the last place of bf16 (PERF.md, PR 37)."""
     mode = kernel_mode()
-    if (mode is None or theta is None or k.dtype != q.dtype
+    if (mode is None or rope is None or k.dtype != q.dtype
             or gqa_elementwise.norm_rotary_plan(q.shape, k.shape,
                                                 q.dtype) is None):
         return gqa_elementwise.norm_rotary_ref(q, k, q_scale, k_scale, eps,
-                                               theta)
-    return gqa_elementwise.norm_rotary(q, k, q_scale, k_scale, eps, theta,
+                                               rope)
+    return gqa_elementwise.norm_rotary(q, k, q_scale, k_scale, eps, rope,
                                        mode == "interpret")

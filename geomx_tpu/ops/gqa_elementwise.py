@@ -13,7 +13,7 @@ minor dimension, four multiplies), forward, rematerialised and
 transposed: 94 of `trinitymini-fsa-1c`'s 202 ms under ``gqa/proj``
 (PERF.md, PR 33).  Here:
 
-- :func:`norm_rotary` ``(q, k, q_scale, k_scale, eps, theta)``: the norm
+- :func:`norm_rotary` ``(q, k, q_scale, k_scale, eps, rope)``: the norm
   and rotary of q ``[B, L, H, d]`` and k ``[B, L, KV, d]`` as ONE streamed
   pass, a Pallas kernel pair that reads and writes the caller's dtype.
   With ``d`` a multiple of 128 a head is whole lane tiles: its mean is a
@@ -21,7 +21,9 @@ transposed: 94 of `trinitymini-fsa-1c`'s 202 ms under ``gqa/proj``
   sine table whose first half is negated (``concat(-x2, x1) * sin`` equals
   ``roll(x) * concat(-sin, sin)`` to the bit): no slice, no negation, no
   concatenate.  The float32 tables ``[L, d]`` are XLA's
-  (:func:`rotary_tables`) and read a token tile at a time.  A bf16 caller
+  (:func:`rotary_tables`: ``rope`` a theta, or a :class:`Yarn`, whose
+  blended frequencies and factor on cos and sin are only another way of
+  filling them) and read a token tile at a time.  A bf16 caller
   keeps the rounding to bf16 between norm and rotary that the chain's
   code has (XLA's TPU program of the chain elides that round trip, so
   against it the kernel's q' and k' differ in the last place of bf16 in a
@@ -33,8 +35,8 @@ transposed: 94 of `trinitymini-fsa-1c`'s 202 ms under ``gqa/proj``
 - :func:`norm_rotary_ref` and :func:`gated_ref`: the jnp forms (JAX's own
   backward).  The first shares the kernels' arithmetic function for
   function: it is the only path off a TPU, at head sizes that are not
-  whole lane tiles and for the norm alone (``theta`` None, a global
-  layer), and the oracle `tests/test_gqa_elementwise.py` holds the
+  whole lane tiles and for the norm alone (``rope`` None: a layer with
+  no positions), and the oracle `tests/test_gqa_elementwise.py` holds the
   kernels to.  The second, ``(o * sigmoid(logits)).astype(o.dtype)``, is
   the gate everywhere.
 
@@ -55,6 +57,7 @@ and timed too, did not beat XLA's code on the jnp forms, and went
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -75,15 +78,61 @@ MIN_TILE = 16
 _F32 = jnp.float32
 
 
-def rotary_tables(length: int, d: int, theta: float):
-    """``(cos, signed sin)`` of rotate-half rotary over a head of ``d`` at
-    positions 0..length-1, float32 ``[length, d]``: both halves of a head
-    share an angle, and the first half's sine is negated so that
-    ``x * cos + roll(x, d / 2) * sin`` is the rotation."""
+class Yarn(NamedTuple):
+    """YaRN's positions as a `rope_parameters` entry of `rope_type`
+    ``"yarn"`` gives them: frequencies that turn more than ``beta_fast``
+    times within ``original`` positions stay, those that turn fewer than
+    ``beta_slow`` times are divided by ``factor``, a linear ramp between;
+    cos and sin times ``attention_factor``."""
+    theta: float
+    factor: float
+    original: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+def yarn_correction_range(d: int, rope: Yarn):
+    """``(low, high)``: the pairs of a head of ``d`` between which YaRN's
+    ramp runs, the pair that turns ``beta_fast`` times within ``original``
+    positions rounded down and the one that turns ``beta_slow`` times
+    rounded up, inside [0, d - 1] (18 and 35 at 128, theta 500,000,
+    8,192 positions, 32 and 1)."""
+    pair = lambda turns: (d * math.log(rope.original / (turns * 2 * math.pi))
+                          / (2 * math.log(rope.theta)))
+    low = max(math.floor(pair(rope.beta_fast)), 0)
+    high = min(math.ceil(pair(rope.beta_slow)), d - 1)
+    return low, high
+
+
+def rotary_frequencies(d: int, rope):
+    """``(inverse frequencies [d / 2] float32, factor on cos and sin)`` of
+    the positions ``rope`` names: a float is rotary's theta
+    (``theta^(-2 i / d)``, factor 1), a :class:`Yarn` its blend of those
+    and those over ``factor``."""
     half = d // 2
+    theta = rope.theta if isinstance(rope, Yarn) else rope
     inverse = theta ** (-jnp.arange(half, dtype=_F32) * 2.0 / d)
+    if not isinstance(rope, Yarn):
+        return inverse, 1.0
+    low, high = yarn_correction_range(d, rope)
+    ramp = jnp.clip((jnp.arange(half, dtype=_F32) - low)
+                    / (high - low if high > low else 0.001), 0.0, 1.0)
+    return (inverse / rope.factor * ramp + inverse * (1.0 - ramp),
+            rope.attention_factor)
+
+
+def rotary_tables(length: int, d: int, rope):
+    """``(cos, signed sin)`` of rotate-half rotary over a head of ``d`` at
+    positions 0..length-1 under ``rope`` (:func:`rotary_frequencies`),
+    float32 ``[length, d]``: both halves of a head share an angle, and the
+    first half's sine is negated so that
+    ``x * cos + roll(x, d / 2) * sin`` is the rotation."""
+    inverse, factor = rotary_frequencies(d, rope)
     angle = jnp.arange(length, dtype=_F32)[:, None] * inverse[None, :]
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if factor != 1.0:
+        cos, sin = factor * cos, factor * sin
     return (jnp.concatenate([cos, cos], -1), jnp.concatenate([-sin, sin], -1))
 
 
@@ -129,14 +178,13 @@ def _norm_turn_bwd(x, scale, tables, eps, g, roll):
     return dx.astype(x.dtype), dy * n
 
 
-def norm_rotary_ref(q, k, q_scale, k_scale, eps: float,
-                    theta: Optional[float]):
+def norm_rotary_ref(q, k, q_scale, k_scale, eps: float, rope):
     """The jnp form of :func:`norm_rotary`: any head size, the norm alone
-    where ``theta`` is None, JAX's backward."""
+    where ``rope`` is None, JAX's backward."""
     tables = None
-    if theta is not None:
+    if rope is not None:
         tables = tuple(t[None, :, None, :] for t in
-                       rotary_tables(q.shape[1], q.shape[-1], theta))
+                       rotary_tables(q.shape[1], q.shape[-1], rope))
     return (_norm_turn(q, q_scale, tables, eps, _roll_half),
             _norm_turn(k, k_scale, tables, eps, _roll_half))
 
@@ -219,7 +267,7 @@ def _norm_rotary_bwd_kernel(q_ref, k_ref, gq_ref, gk_ref, q_scale_ref,
         sum_ref[...] += jnp.sum(total, 0, keepdims=True)
 
 
-def _norm_rotary_call(q, k, q_scale, k_scale, cotangents, eps, theta,
+def _norm_rotary_call(q, k, q_scale, k_scale, cotangents, eps, rope,
                       interpret):
     """One kernel of the pair on q, k ``[B, L, heads, d]``: the forward
     (``cotangents`` empty: q', k') or the backward (``(gq, gk)``: dq, dk
@@ -257,54 +305,55 @@ def _norm_rotary_call(q, k, q_scale, k_scale, cotangents, eps, theta,
         name="gqa_norm_rotary_bwd" if backward else "gqa_norm_rotary_fwd",
         interpret=interpret,
     )(*flat, q_scale.astype(_F32).reshape(1, d),
-      k_scale.astype(_F32).reshape(1, d), *rotary_tables(length, d, theta))
+      k_scale.astype(_F32).reshape(1, d), *rotary_tables(length, d, rope))
 
 
 # Each kernel's call sits in a module-level jit with its statics named, so
 # that a step's four window layers share one trace of each body.
 
-@functools.partial(jax.jit, static_argnames=("eps", "theta", "interpret"))
-def norm_rotary_fwd(q, k, q_scale, k_scale, *, eps: float, theta: float,
+@functools.partial(jax.jit, static_argnames=("eps", "rope", "interpret"))
+def norm_rotary_fwd(q, k, q_scale, k_scale, *, eps: float, rope,
                     interpret: bool = False):
     """The forward kernel: normalised and turned q and k in their own
     dtype and shape."""
-    qo, ko = _norm_rotary_call(q, k, q_scale, k_scale, (), eps, theta,
+    qo, ko = _norm_rotary_call(q, k, q_scale, k_scale, (), eps, rope,
                                interpret)
     return qo.reshape(q.shape), ko.reshape(k.shape)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "theta", "interpret"))
+@functools.partial(jax.jit, static_argnames=("eps", "rope", "interpret"))
 def norm_rotary_bwd(q, k, q_scale, k_scale, gq, gk, *, eps: float,
-                    theta: float, interpret: bool = False):
+                    rope, interpret: bool = False):
     """The backward kernel, from the forward's inputs and its results'
     cotangents: ``(dq, dk, dq_scale, dk_scale)``, the scales' in float32
     ``[d]``."""
     dq, dk, dq_scale, dk_scale = _norm_rotary_call(
-        q, k, q_scale, k_scale, (gq, gk), eps, theta, interpret)
+        q, k, q_scale, k_scale, (gq, gk), eps, rope, interpret)
     return (dq.reshape(q.shape), dk.reshape(k.shape), dq_scale.reshape(-1),
             dk_scale.reshape(-1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def norm_rotary(q, k, q_scale, k_scale, eps: float, theta: float,
+def norm_rotary(q, k, q_scale, k_scale, eps: float, rope,
                 interpret: bool = False):
     """Per-head RMSNorm of q ``[B, L, H, d]`` and k ``[B, L, KV, d]``, then
-    rotate-half rotary at positions 0..L-1, through the kernel pair
-    (`norm_rotary_plan` says where it applies)."""
-    return norm_rotary_fwd(q, k, q_scale, k_scale, eps=eps, theta=theta,
+    rotate-half rotary at positions 0..L-1 under ``rope`` (a theta or a
+    :class:`Yarn`, hashable: the jits' static argument), through the
+    kernel pair (`norm_rotary_plan` says where it applies)."""
+    return norm_rotary_fwd(q, k, q_scale, k_scale, eps=eps, rope=rope,
                            interpret=interpret)
 
 
-def _norm_rotary_vjp_fwd(q, k, q_scale, k_scale, eps, theta, interpret):
-    out = norm_rotary_fwd(q, k, q_scale, k_scale, eps=eps, theta=theta,
+def _norm_rotary_vjp_fwd(q, k, q_scale, k_scale, eps, rope, interpret):
+    out = norm_rotary_fwd(q, k, q_scale, k_scale, eps=eps, rope=rope,
                           interpret=interpret)
     return out, (q, k, q_scale, k_scale)
 
 
-def _norm_rotary_vjp_bwd(eps, theta, interpret, saved, g):
+def _norm_rotary_vjp_bwd(eps, rope, interpret, saved, g):
     q, k, q_scale, k_scale = saved
     dq, dk, dq_scale, dk_scale = norm_rotary_bwd(
-        q, k, q_scale, k_scale, *g, eps=eps, theta=theta, interpret=interpret)
+        q, k, q_scale, k_scale, *g, eps=eps, rope=rope, interpret=interpret)
     return (dq, dk, dq_scale.astype(q_scale.dtype).reshape(q_scale.shape),
             dk_scale.astype(k_scale.dtype).reshape(k_scale.shape))
 

@@ -150,7 +150,8 @@ def test_rotary_is_on_window_layers_only():
     """A global layer with no positions is blind to where a (key, value)
     pair sits among the earlier ones; a window layer is not (its band
     covers the whole tiny sequence here, so only rotary tells)."""
-    mixer = lambda window: afmoe.GQAMixer(4, 2, 16, window, 10000.0, 1e-5)
+    mixer = lambda window: afmoe.GQAMixer(
+        4, 2, 16, window, None if window is None else 10000.0, 1e-5)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 10, 32))
     swapped = x.at[0, 2].set(x[0, 5]).at[0, 5].set(x[0, 2])
     last = {}

@@ -236,7 +236,7 @@ def test_the_mixer_keeps_its_parameters_and_its_values(kind):
     output and gradients within float32's roundings."""
     mixer = afmoe.GQAMixer(num_heads=4, num_kv_heads=2, head_dim=128,
                            window=24 if kind == "window" else None,
-                           rope_theta=THETA, eps=EPS)
+                           rope=THETA if kind == "window" else None, eps=EPS)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 64, 96))
     params = jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"]
     assert sorted(params) == ["gate_kernel", "k_kernel", "k_norm",

@@ -176,14 +176,18 @@ def _cell_attention(which, direction):
     on 4 key/value heads of 128), in a window layer (a band of 2,048 keys:
     70 pairs) and in a global one; one sequence of the third decoder's
     share of its attention layer (four query heads on the one key/value
-    head they read)."""
+    head they read); one sequence of the fourth decoder's (16,384 x 32 on
+    4 of 128) in a window layer (a band of 1,024 keys, two blocks wide)
+    and in a full one (528 block pairs)."""
     from geomx_tpu.ops import flash_attention_bwd, flash_attention_with_lse
     b, L, h, kv, d, dv, causal, window = {
         "bert": (16, 512, 16, 16, 64, 64, False, None),
         "latent": (1, 8192, 32, 32, 192, 128, True, None),
         "window": (1, 8192, 32, 4, 128, 128, True, 2048),
         "global": (1, 8192, 32, 4, 128, 128, True, None),
-        "share": (1, 8192, 4, 1, 128, 128, True, None)}[which]
+        "share": (1, 8192, 4, 1, 128, 128, True, None),
+        "window-16k": (1, 16384, 32, 4, 128, 128, True, 1024),
+        "global-16k": (1, 16384, 32, 4, 128, 128, True, None)}[which]
     bf16 = lambda heads, e: jax.ShapeDtypeStruct((b, L, heads, e),
                                                  jnp.bfloat16)
     if direction == "forward":
@@ -283,6 +287,14 @@ CASES = {
         "share", "forward"),
     "flash_attention_bwd-bf16-four-on-one-sequence":
         lambda: _cell_attention("share", "backward"),
+    "flash_attention-bf16-grouped-window-1024-of-16k": lambda:
+        _cell_attention("window-16k", "forward"),
+    "flash_attention_bwd-bf16-grouped-window-1024-of-16k": lambda:
+        _cell_attention("window-16k", "backward"),
+    "flash_attention-bf16-grouped-global-16k": lambda: _cell_attention(
+        "global-16k", "forward"),
+    "flash_attention_bwd-bf16-grouped-global-16k": lambda: _cell_attention(
+        "global-16k", "backward"),
     "flash_attention_bwd-f32-grouped-64-wide": lambda: _grouped_narrow(),
     "fused_ring_hop-L1024": lambda: _ring_hop(1024),
     "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
@@ -412,11 +424,12 @@ def test_v5e_compiler_accepts_the_ungated_held_experts(chip, direction):
     assert not re.search(r"= f32\[\d+,%d\]\S* scatter\(" % d, text)
 
 
-# (tokens, hidden, held, width, tile, first pool): the two decoder cells'
-# expert layers
+# (tokens, hidden, held, width, tile, first pool): the decoder cells'
+# SwiGLU expert layers, 8 picks a token (131,072 assignments)
 HELD_EXPERTS = {
     "kimi-8-of-256": (16384, 2304, 8, 1024, 512, None),
     "trinity-16-of-128": (16384, 2048, 16, 1024, 512, 32768),
+    "mellum-16-of-64": (16384, 2304, 16, 896, 512, 65536),
 }
 
 
@@ -504,7 +517,8 @@ def test_v5e_compiler_accepts_the_gqa_mixers_streamed_pass(chip, kind):
     from geomx_tpu.ops import gqa_elementwise as ge
     length, hidden, heads, kv_heads, d = 8192, 2048, 32, 4, 128
     mixer = GQAMixer(heads, kv_heads, d, 2048 if kind == "window" else None,
-                     10000.0, 1e-5, jnp.bfloat16)
+                     10000.0 if kind == "window" else None, 1e-5,
+                     jnp.bfloat16)
     on = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
                                            sharding=chip)
     x = jax.ShapeDtypeStruct((1, length, hidden), jnp.bfloat16)
@@ -524,6 +538,49 @@ def test_v5e_compiler_accepts_the_gqa_mixers_streamed_pass(chip, kind):
     q, k = (1, length, heads, d), (1, length, kv_heads, d)
     for backward in (False, True):
         plan = ge.norm_rotary_plan(q, k, jnp.bfloat16, backward)
+        assert plan.tile >= 128 and plan.vmem_bytes <= ge.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("kind", ["window", "global"])
+def test_v5e_compiler_accepts_the_mixer_under_either_table(chip, kind):
+    """`models/afmoe.GQAMixer` as `models/mellum.py` builds it, at the
+    fourth decoder cell's shape (one sequence of 16,384 tokens, hidden
+    2,304, bf16, 32 query heads on 4 of 128, no gate): a window layer (a
+    band of 1,024, plain rotary at theta 500,000) and a full one (YaRN's
+    table at the published numbers), forward and gradient in one program.
+    Either kind's q/k norm + rotary is the kernel pair of
+    `ops/gqa_elementwise.py`, each once: the tables are operands, so
+    YaRN's is no other kernel."""
+    from geomx_tpu.models.mellum import MellumConfig
+    from geomx_tpu.ops import dispatch
+    from geomx_tpu.ops import gqa_elementwise as ge
+    length, hidden = 16384, 2304
+    cfg = MellumConfig(
+        vocab=24576, hidden=hidden, layers=(), num_heads=32, num_kv_heads=4,
+        head_dim=128, window=1024, rope_theta=500000.0, expert_width=896,
+        num_experts=64, experts_held=16, expert_offset=0, top_k=8,
+        yarn=ge.Yarn(500000.0, 16.0, 8192, 32.0, 1.0, 1.2772588722239782))
+    mixer = cfg.make_mixer(kind, jnp.bfloat16)
+    assert isinstance(mixer.rope, ge.Yarn) == (kind == "global")
+    on = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                           sharding=chip)
+    x = jax.ShapeDtypeStruct((1, length, hidden), jnp.bfloat16)
+    params = jax.tree.map(on, jax.eval_shape(
+        mixer.init, jax.random.PRNGKey(0), x)["params"])
+    assert "gate_kernel" not in params
+    loss = lambda p, x: jnp.sum(
+        mixer.apply({"params": p}, x).astype(jnp.float32))
+    with dispatch.kernels("native"):
+        text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+            params, on(x)).compile().as_text()
+    calls = [c.split(".")[0] for c in _kernel_calls(text)]
+    for name in ("gqa_norm_rotary_fwd", "gqa_norm_rotary_bwd",
+                 "flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert calls.count(name) == 1, calls
+    for backward in (False, True):
+        plan = ge.norm_rotary_plan((1, length, 32, 128), (1, length, 4, 128),
+                                   jnp.bfloat16, backward)
         assert plan.tile >= 128 and plan.vmem_bytes <= ge.VMEM_BUDGET
 
 

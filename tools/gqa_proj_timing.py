@@ -1,13 +1,19 @@
 """Time the elementwise halves of a grouped-query mixer on the chip: the
-q / k norm with rotary, and the output gate, at the Trinity cell's shapes.
+q / k norm with rotary, and the output gate, at the Trinity cell's shapes
+or at those of ``--config <file>`` (a grouped-query decoder's
+`benchmark/configs/*.json`: one sequence of its `sequence_length`, its
+heads, and the eps and each layer kind's positions of the mixers its
+family builds: Trinity's `rope_theta` on the window layers alone,
+Mellum2's `rope_parameters` by layer type, YaRN's among them).
 
 For each shape (``L:H:KV:d``, default one sequence of the cell's layer,
 8,192 tokens of 32 query heads on 4 of 128), each layer kind (``window``:
-rotary; ``global``: none) and operand dtype, the variants of each half:
+rotary; ``global``: none, or the configuration's) and operand dtype, the
+variants of each half:
 
 - ``chain``: what `models/afmoe.GQAMixer` ran before PR 37
   (`decoder.RMSNorm` then `afmoe.rotary`; ``(o * sigmoid(logits))``),
-  JAX's backward;
+  JAX's backward; not under a YaRN table, which it never ran;
 - ``jnp``: the restated jnp form (`ops/gqa_elementwise.norm_rotary_ref`,
   `gated_ref`: a roll and a signed sine, one cast in and one out), JAX's
   backward;
@@ -40,6 +46,8 @@ program at real sizes is deleted).
     python tools/gqa_proj_timing.py [8192:32:4:128] [--dtypes bfloat16]
         [--kinds window] [--halves norm_rotary] [--skip chain,jnp]
         [--set MAX_TILE=128]
+    python tools/gqa_proj_timing.py \
+        --config benchmark/configs/mellum2-12b-ep4.json --halves norm_rotary
 
 ``--interpret`` rehearses it on the CPU at a small shape
 (``64:4:2:128``; no times).
@@ -69,27 +77,29 @@ def median_ms(fn, args, reps):
     return statistics.median(times)
 
 
-def oracle_norm_rotary(q, k, q_scale, k_scale, theta, gq, gk):
+def oracle_norm_rotary(q, k, q_scale, k_scale, eps, rope, gq, gk):
     """The chain in float64 on the host, rotate-half written plainly:
-    (q', k'), (dq, dk, dq_scale, dk_scale).  The float32 angles are taken
-    as given."""
+    (q', k'), (dq, dk, dq_scale, dk_scale).  The float32 frequencies and
+    angles (`gqa_elementwise.rotary_frequencies`: a theta's, or YaRN's
+    with its factor on cos and sin) are taken as given."""
     import numpy as np
+    from geomx_tpu.ops import gqa_elementwise as ge
     f64 = lambda a: np.asarray(a, np.float64)
     length, d = q.shape[1], q.shape[-1]
     half = d // 2
-    if theta is not None:
-        inverse = np.float32(theta) ** (
-            -np.arange(half, dtype=np.float32) * np.float32(2.0) / d)
-        angle = np.arange(length, dtype=np.float32)[:, None] * inverse[None]
-        cos, sin = (np.concatenate([f(f64(angle))] * 2, -1)[None, :, None]
-                    for f in (np.cos, np.sin))
+    if rope is not None:
+        inverse, factor = ge.rotary_frequencies(d, rope)
+        angle = (np.arange(length, dtype=np.float32)[:, None]
+                 * np.asarray(inverse, np.float32)[None])
+        cos, sin = (factor * np.concatenate([f(f64(angle))] * 2, -1)[
+            None, :, None] for f in (np.cos, np.sin))
 
     def one(x, scale, g):
         x, scale, g = f64(x), f64(scale), f64(g)
-        r = 1.0 / np.sqrt(np.mean(x * x, -1, keepdims=True) + EPS)
+        r = 1.0 / np.sqrt(np.mean(x * x, -1, keepdims=True) + eps)
         n = x * r
         out = n * scale
-        if theta is not None:
+        if rope is not None:
             out = out * cos + np.concatenate(
                 [-out[..., half:], out[..., :half]], -1) * sin
             gs = g * sin
@@ -104,6 +114,18 @@ def oracle_norm_rotary(q, k, q_scale, k_scale, theta, gq, gk):
     return (qo, ko), (dq, dk, dqs, dks)
 
 
+def mixers(config: dict) -> dict:
+    """{layer kind: the mixer the configuration's family builds}: its
+    `rope` (None, a theta, a `gqa_elementwise.Yarn`) and `eps` are what
+    the model's own layers run, whatever keys the file states them in."""
+    from benchmark.cells import Registry, _load_module
+    family = _load_module(
+        Registry(ROOT).find("families", config["family"], ".py"),
+        "benchmark_family_" + config["family"])
+    cfg = family.build_model(config).cfg
+    return {kind: cfg.make_mixer(kind, None) for kind in ("window", "global")}
+
+
 def oracle_gated(o, logits, g):
     import numpy as np
     o, logits, g = (np.asarray(a, np.float64) for a in (o, logits, g))
@@ -113,7 +135,10 @@ def oracle_gated(o, logits, g):
 
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    parser.add_argument("shapes", nargs="*", default=["8192:32:4:128"])
+    parser.add_argument("shapes", nargs="*", default=None)
+    parser.add_argument("--config", default=None,
+                        help="a grouped-query decoder configuration's file: "
+                             "the shape, eps and each kind's positions")
     parser.add_argument("--dtypes", default="bfloat16")
     parser.add_argument("--kinds", default="window,global")
     parser.add_argument("--calls", type=int, default=8)
@@ -144,6 +169,18 @@ def main(argv=None) -> int:
         name, value = item.split("=")
         assert hasattr(ge, name), name
         setattr(ge, name, int(value))
+
+    eps, ropes = EPS, {"window": THETA, "global": None}
+    shapes = args.shapes or ["8192:32:4:128"]
+    if args.config:
+        with open(args.config) as fh:
+            config = json.load(fh)
+        built = mixers(config)
+        eps = built["window"].eps
+        ropes = {kind: mixer.rope for kind, mixer in built.items()}
+        shapes = args.shapes or [":".join(str(config[key]) for key in (
+            "sequence_length", "num_attention_heads", "num_key_value_heads",
+            "head_dim"))]
 
     on_chip = jax.default_backend() == "tpu"
     if not on_chip and not args.interpret:
@@ -201,7 +238,7 @@ def main(argv=None) -> int:
                       / np.max(np.abs(w))) for g, w in zip(got, want)]
 
     verdicts = []
-    for shape in args.shapes:
+    for shape in shapes:
         length, h, kv, d = (int(x) for x in shape.split(":"))
         check = min(args.check_tokens, length)
         for dtype in args.dtypes.split(","):
@@ -215,30 +252,32 @@ def main(argv=None) -> int:
                            for key in keys[4:6])
             o, go = (normal(key, (1, length, h * d)) for key in keys[6:8])
             logits = 2.0 * normal(keys[8], (1, length, h * d), jnp.float32)
-            # half, layer kind, theta, operands, fixed operands, cotangents
-            cases = [("norm_rotary", kind, THETA if kind == "window" else
-                      None, (q, k), scales, (gq, gk))
-                     for kind in args.kinds.split(",")]
+            # half, layer kind, positions, operands, fixed operands,
+            # cotangents
+            cases = [("norm_rotary", kind, ropes[kind], (q, k), scales,
+                      (gq, gk)) for kind in args.kinds.split(",")]
             cases.append(("gate", "any", None, (o, logits), (), (go,)))
-            for half, kind, theta, operands, fixed, cots in cases:
+            for half, kind, rope, operands, fixed, cots in cases:
                 if half not in args.halves.split(","):
                     continue
                 made, base = len(operands), operands + cots
                 few = tuple(x[:, :check] for x in base)
                 if half == "norm_rotary":
-                    bind = lambda impl: (lambda *a: impl(*a, EPS, theta))
+                    bind = lambda impl: (lambda *a: impl(*a, eps, rope))
                     want_out, want_grads = oracle_norm_rotary(
-                        *few[:2], *fixed, theta, *few[2:])
+                        *few[:2], *fixed, eps, rope, *few[2:])
                 else:
                     bind = lambda impl: impl
                     want_out, want_grads = oracle_gated(*few)
-                line = {"half": half, "kind": kind,
+                line = {"half": half, "kind": kind, "rope": rope,
                         "dims": [length, h, kv, d], "dtype": dtype,
                         "calls": args.calls, "reps": args.reps,
                         "check_tokens": check, "set": args.set,
                         "device": jax.devices()[0].device_kind}
                 variants = dict(halves[half])
-                if theta is None:       # no kernel for the norm alone
+                if isinstance(rope, ge.Yarn):   # the chain knew a theta only
+                    variants.pop("chain", None)
+                if rope is None:        # no kernel for the norm alone
                     variants.pop("kernel", None)
                 else:
                     line["plans"] = [ge.norm_rotary_plan(

@@ -10,7 +10,9 @@ over ``--reps`` runs after a warm-up.  ``--config <file>`` takes ``T``,
 ``d``, ``f``, ``E``, the router's width and the experts a token from a
 configuration's file (`benchmark/configs/*.json`: the decoder cells'; a
 file with a ``moe_latent_size`` gives that as ``d``, one whose
-``mlp_hidden_act`` is ``relu2`` un-gated experts), and
+``mlp_hidden_act`` is ``relu2`` un-gated experts; `mellum2-12b-ep4.json`
+gives 16 held of 64 at 2 held picks a token, 2,048 assignments an expert
+under even routing, a first pool of 65,536 places), and
 the tile and the first pool from its ``program``; the loads are the Kimi
 cell's patterns (8 held experts, 512 assignments each under even routing)
 repeated over the held experts and scaled to the file's even load.
@@ -42,6 +44,8 @@ cell), the walk too.  One JSON line per variant and load.
     python tools/held_experts_timing.py \
         --config benchmark/configs/trinity-mini-ep8.json \
         --shapes "512:32768 1024 512"
+    python tools/held_experts_timing.py \
+        --config benchmark/configs/mellum2-12b-ep4.json --loads even,none
 """
 import argparse
 import importlib.util
