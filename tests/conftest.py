@@ -3,9 +3,19 @@
 reference's pseudo-distributed localhost scripts
 (scripts/cpu/run_vanilla_hips.sh runs 12 processes on 127.0.0.1)."""
 
+import faulthandler
 import os
+import signal
+import sys
+
+# the checkout on the path: `benchmark/` and `tools/` for the tests that
+# import them, `geomx_tpu` for a `pytest` started without `python -m`
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # keep libtpu logs out of /tmp
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (
@@ -37,8 +47,8 @@ def pytest_configure(config):
         "markers",
         "tier2: long-running convergence/e2e tests whose semantics a "
         "faster tier-1 sibling also covers; skipped by default so the "
-        "suite stays under ~5 min — run them with GEOMX_TEST_TIER=full "
-        "or -m tier2")
+        "tier-1 run stays inside its limit — run them with "
+        "GEOMX_TEST_TIER=full or -m tier2")
 
 
 def pytest_collection_modifyitems(config, items):
@@ -74,6 +84,55 @@ def _collected_garbage_between_files():
     (benchmark/test_benchmark_reference.py) reads as its own."""
     import gc
     gc.collect()
+
+
+TEST_LIMIT_S = 240
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_protocol(item):
+    """One test's limit, set-up (the module's fixtures it is the first to
+    ask for included) to tear-down: a test that waits for ever on a socket,
+    a thread or a compile costs `TEST_LIMIT_S` and fails under its own
+    name, not the whole run its time limit.  The alarm's handler writes
+    every thread's stack to stderr and raises in the worker's main thread,
+    where a test runs; a test stuck where no Python runs never sees it, so
+    half a minute later `faulthandler`'s own thread writes the stacks and
+    ends the worker, and xdist names the test it died in."""
+    def overrun(_signum, _frame):
+        faulthandler.dump_traceback(file=sys.stderr)
+        pytest.fail(f"{item.nodeid} ran past the {TEST_LIMIT_S} s every "
+                    f"test is given (tests/conftest.py)", pytrace=False)
+    was = signal.signal(signal.SIGALRM, overrun)
+    signal.setitimer(signal.ITIMER_REAL, TEST_LIMIT_S)
+    faulthandler.dump_traceback_later(TEST_LIMIT_S + 30, exit=True)
+    yield
+    faulthandler.cancel_dump_traceback_later()
+    signal.setitimer(signal.ITIMER_REAL, 0)
+    signal.signal(signal.SIGALRM, was)
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One described v5e device for the `test_tpu_compile_*.py` files, with
+    the persistent compile cache off: an executable compiled for an
+    unattached chip is written to the cache but cannot be read back
+    without one."""
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e!r}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
 
 
 @pytest.fixture(scope="session")

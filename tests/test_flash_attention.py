@@ -35,6 +35,18 @@ def dense_reference(q, k, v, causal, window=None):
     return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, axis=-1), v)
 
 
+def dense_vjp(dense, g, *qkv):
+    """The dense form's values and its pull of `g`: one compiled program."""
+    def run(*a):
+        out, pull = jax.vjp(dense, *a)
+        return out, pull(g)
+    return jax.jit(run)(*qkv)
+
+
+def jitted_grads(loss):
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+
 def _qkv(rng, shape, kv_heads=None):
     """q of `shape` [B, L, H, D]; k and v with `kv_heads` heads (H where
     None)."""
@@ -130,8 +142,8 @@ def test_gradients_match_dense_reference():
     def loss_ref(q, k, v):
         return jnp.sum(full_attention_reference(q, k, v, causal=True) ** 2)
 
-    gf = jax.grad(loss_fused, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    gf = jitted_grads(loss_fused)(q, k, v)
+    gr = jitted_grads(loss_ref)(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-5)
@@ -153,10 +165,10 @@ def test_fused_attention_grouped_and_windowed_gradients(shape, kv_heads,
         fused_attention(q, k, v, True, interpret, window) ** 2)
     dense = lambda q, k, v: jnp.sum(
         dense_reference(q, k, v, True, window) ** 2)
-    np.testing.assert_allclose(float(fused(q, k, v)), float(dense(q, k, v)),
-                               rtol=1e-5)
-    for got, want in zip(jax.grad(fused, argnums=(0, 1, 2))(q, k, v),
-                         jax.grad(dense, argnums=(0, 1, 2))(q, k, v)):
+    np.testing.assert_allclose(float(jax.jit(fused)(q, k, v)),
+                               float(jax.jit(dense)(q, k, v)), rtol=1e-5)
+    for got, want in zip(jitted_grads(fused)(q, k, v),
+                         jitted_grads(dense)(q, k, v)):
         assert got.shape == want.shape
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
@@ -284,8 +296,7 @@ def test_flash_backward_matches_dense_vjp(shape, causal, blocks, grouped):
     def dense(q, k, v):
         return dense_reference(q, k, v, causal, window)
 
-    _, vjp = jax.vjp(dense, q, k, v)
-    rq, rk, rv = vjp(g)
+    _, (rq, rk, rv) = dense_vjp(dense, g, q, k, v)
     np.testing.assert_allclose(np.asarray(dq), np.asarray(rq),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(np.asarray(dk), np.asarray(rk),
@@ -338,12 +349,12 @@ def test_unequal_head_sizes_forward_and_both_backward_kernels(length, d_qk,
                                         block_k=block, interpret=True)
     assert out.shape == v.shape
     dense = lambda q, k, v: full_attention_reference(q, k, v, causal=True)
-    ref, vjp = jax.vjp(dense, q, k, v)
+    ref, pulled = dense_vjp(dense, g, q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                atol=5e-6, rtol=1e-5)
     grads = flash_attention_bwd(q, k, v, out, lse, g, causal=True,
                                 block_q=block, block_k=block, interpret=True)
-    for got, want in zip(grads, vjp(g)):
+    for got, want in zip(grads, pulled):
         assert got.shape == want.shape
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=3e-5, rtol=3e-5)
@@ -354,8 +365,8 @@ def test_unequal_head_sizes_through_fused_attention_gradients():
     fused = lambda q, k, v: jnp.sum(fused_attention(q, k, v, True, True) ** 2)
     dense = lambda q, k, v: jnp.sum(
         full_attention_reference(q, k, v, causal=True) ** 2)
-    for got, want in zip(jax.grad(fused, argnums=(0, 1, 2))(q, k, v),
-                         jax.grad(dense, argnums=(0, 1, 2))(q, k, v)):
+    for got, want in zip(jitted_grads(fused)(q, k, v),
+                         jitted_grads(dense)(q, k, v)):
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-5)
 
@@ -399,10 +410,10 @@ def test_bf16_operands_p_and_ds_rounded_against_dense_on_rounded_inputs(
                                 interpret=True)
     assert [x.dtype for x in grads] == [jnp.bfloat16] * 3
     f32 = lambda x: x.astype(jnp.float32)
-    ref, vjp = jax.vjp(
+    ref, pulled = dense_vjp(
         lambda q, k, v: full_attention_reference(q, k, v, causal=causal),
-        f32(q), f32(k), f32(v))
-    for got, want in zip((out, *grads), (ref, *vjp(f32(g)))):
+        f32(g), f32(q), f32(k), f32(v))
+    for got, want in zip((out, *grads), (ref, *pulled)):
         gap = float(jnp.max(jnp.abs(f32(got) - want)) / jnp.max(jnp.abs(want)))
         assert gap <= BF16_TOLERANCE, gap
 

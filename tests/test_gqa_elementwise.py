@@ -126,7 +126,7 @@ def test_norm_rotary_gradients_are_no_further_from_float32_than_the_chains(
             outs = fn(*a)
             return sum(jnp.sum(o.astype(jnp.float32) * g.astype(jnp.float32))
                        for o, g in zip(outs, cots))
-        return jax.grad(of, (0, 1, 2, 3))
+        return jax.jit(jax.grad(of, (0, 1, 2, 3)))
 
     args = (q, k, q_scale, k_scale)
     with jax.default_matmul_precision("highest"):
@@ -244,9 +244,9 @@ def test_the_mixer_keeps_its_parameters_and_its_values(kind):
     assert params["q_norm"]["scale"].shape == (128,)
     assert params["k_norm"]["scale"].shape == (128,)
     loss = lambda p: jnp.sum(jnp.square(mixer.apply({"params": p}, x)))
-    want = jax.value_and_grad(loss)(params)
+    want = jax.jit(jax.value_and_grad(loss))(params)
     with dispatch.kernels("interpret"):
-        got = jax.value_and_grad(loss)(params)
+        got = jax.jit(jax.value_and_grad(loss))(params)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(a, b, rtol=2e-4,

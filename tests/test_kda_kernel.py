@@ -2,35 +2,31 @@
 against their two oracles: the jnp form (`ops/kda.kda_chunked`, whose
 `jax.vjp` is the backward's oracle) and the plain reference's
 token-by-token recurrence.  Interpret mode sees neither tiling, VMEM nor
-MXU precision: `tests/test_tpu_compile.py` asks the compiler, and
+MXU precision: `tests/test_tpu_compile_decoders.py` asks the compiler, and
 `tools/kda_timing.py` checks values on the chip.  A file of its own, so
 that `--dist loadfile` gives it a worker."""
 import importlib.util
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.references import kimi_linear as plain  # noqa: E402
-from benchmark.references.numerics import Numerics  # noqa: E402
-from geomx_tpu.ops import dispatch, kda_pallas  # noqa: E402
-from geomx_tpu.ops.kda import kda_chunked  # noqa: E402
-from geomx_tpu.ops.kda_pallas import (kda_plan, kda_scan,  # noqa: E402
+import decoder_checks as checks
+from benchmark.references import kimi_linear as plain
+from geomx_tpu.ops import dispatch, kda_pallas
+from geomx_tpu.ops.kda import kda_chunked
+from geomx_tpu.ops.kda_pallas import (kda_plan, kda_scan,
                                       kda_scan_bwd, kda_scan_fwd)
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _spec = importlib.util.spec_from_file_location(
     "kda_timing", os.path.join(ROOT, "tools", "kda_timing.py"))
 kda_timing = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(kda_timing)
 
-NX = Numerics("float32")
+NX = checks.NX
 DK, DV = 32, 16
 WEIGHT = jnp.cos(jnp.arange(float(DV)))
 
@@ -49,11 +45,7 @@ def inputs(seed, length, decay, h=2, shift=0.0):
 
 def value_and_grads(fn, args):
     """o and the gradients of all five inputs under a fixed weighting."""
-    run = jax.jit(jax.value_and_grad(
-        lambda *a: (lambda o: (jnp.sum(o * WEIGHT), o))(fn(*a)),
-        argnums=range(5), has_aux=True))
-    (_, o), grads = run(*args)
-    return o, grads
+    return checks.value_and_gradients(fn, args, range(5), WEIGHT)
 
 
 def recurrence(q, k, v, g, beta):
@@ -236,15 +228,18 @@ def test_heads_a_step_do_not_change_a_result(monkeypatch):
 
 def test_the_door_picks_kernel_or_jnp_form_from_the_mode():
     args = inputs(2, 64, 1.0)
-    plain_o = dispatch.kda(*args, chunk=32, sub=16)
+    door = lambda: jax.jit(lambda *a: dispatch.kda(*a, chunk=32, sub=16))
+    plain_o = door()(*args)
     np.testing.assert_array_equal(
-        np.asarray(plain_o), np.asarray(kda_chunked(*args, chunk=32, sub=16)))
+        np.asarray(plain_o),
+        np.asarray(jax.jit(lambda *a: kda_chunked(*a, chunk=32, sub=16))(
+            *args)))
     with dispatch.kernels("interpret"):
-        kernel_o = jax.jit(lambda *a: dispatch.kda(*a, chunk=32, sub=16))(
-            *args)
+        kernel_o = door()(*args)
     np.testing.assert_array_equal(
         np.asarray(kernel_o),
-        np.asarray(kda_scan(*args, 32, 16, jnp.float32, True)))
+        np.asarray(jax.jit(lambda *a: kda_scan(
+            *a, 32, 16, jnp.float32, True))(*args)))
     assert_close(kernel_o, plain_o, 2e-6, "door")
 
 

@@ -3,23 +3,15 @@
 logits), at tiny sizes on seeded weights: the blocked loss, the model's
 loss and gradient, the loss a model brings to `Trainer`.  Its two ops
 (chunked KDA, the held experts) are in `test_kimi_ops.py`."""
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.references import kimi_linear as plain  # noqa: E402
-from benchmark.references.numerics import Numerics  # noqa: E402
-from geomx_tpu.models import get_model  # noqa: E402
-from geomx_tpu.models import kimi_linear as kl  # noqa: E402
-
-NX = Numerics("float32")
+import decoder_checks as checks
+from benchmark.references import kimi_linear as plain
+from geomx_tpu.models import kimi_linear as kl
 
 
 def test_blocked_loss_equals_the_whole_one():
@@ -35,9 +27,11 @@ def test_blocked_loss_equals_the_whole_one():
 
     blocked = lambda h_, head_: kl.blocked_cross_entropy(
         h_, head_, labels, 16)[0]       # 70 tokens: a ragged last block
-    np.testing.assert_allclose(blocked(h, head), whole(h, head), rtol=1e-6)
-    for got, want in zip(jax.grad(blocked, (0, 1))(h, head),
-                         jax.grad(whole, (0, 1))(h, head)):
+    (got, got_grads), (want, want_grads) = [
+        jax.jit(jax.value_and_grad(f, (0, 1)))(h, head)
+        for f in (blocked, whole)]
+    np.testing.assert_allclose(got, want, rtol=1e-6)
+    for got, want in zip(got_grads, want_grads):
         np.testing.assert_allclose(got, want, atol=1e-5)
     hits = kl.blocked_cross_entropy(h, head, labels, 16)[1]
     assert float(hits) == float(jnp.sum(jnp.argmax(h @ head, -1) == labels))
@@ -53,105 +47,56 @@ TINY = dict(vocab=64, hidden=32, num_heads=2, kda_head_dim=16, conv_size=4,
             layers=(("kda", "mlp"), ("kda", "moe"), ("mla", "moe")))
 
 
-def tiny_model_and_batch(**over):
-    model = get_model("kimi_linear", **{**TINY, **over})
-    tokens = np.random.default_rng(0).integers(0, 64, (2, 41))
-    x, y = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
-    variables = jax.jit(lambda: model.init(jax.random.PRNGKey(1), x))()
-    return model, variables, x, y
+FAMILY = checks.Family("kimi_linear", TINY, plain, {
+    **{k: TINY[k] for k in ("layers", "num_heads", "qk_nope_dim",
+                            "qk_rope_dim", "kv_rank", "expert_offset",
+                            "top_k", "routed_scaling")}, "eps": 1e-5})
 
 
-def reference_sizes():
-    return {**{k: TINY[k] for k in ("layers", "num_heads", "qk_nope_dim",
-                                    "qk_rope_dim", "kv_rank", "expert_offset",
-                                    "top_k", "routed_scaling")}, "eps": 1e-5}
+@pytest.fixture(scope="module")
+def built():
+    return checks.Built(FAMILY)
 
 
-def test_model_loss_and_gradient_equal_the_plain_reference():
-    model, variables, x, y = tiny_model_and_batch()
-    ours = lambda p: model.apply({"params": p}, x, y, method="loss_and_aux")[0]
-    theirs = lambda p: plain.loss(p, x, y, reference_sizes(), NX)
-    params = variables["params"]
-    np.testing.assert_allclose(ours(params), theirs(params), rtol=2e-6)
-    got, want = jax.grad(ours)(params), jax.grad(theirs)(params)
-    norm = np.sqrt(sum(float(jnp.sum(w * w)) for w in jax.tree.leaves(want)))
-    off = np.sqrt(sum(float(jnp.sum((g - w) ** 2)) for g, w in
-                      zip(jax.tree.leaves(got), jax.tree.leaves(want))))
-    assert off / norm < 2e-5
+def test_model_loss_and_gradient_equal_the_plain_reference(built):
+    got, want = checks.loss_equals_the_reference(built)
+    assert checks.relative_distance(got, want) < 2e-5
 
 
-def test_whole_logits_agree_with_the_blocked_loss_and_the_reference():
-    model, variables, x, y = tiny_model_and_batch()
-    logits = model.apply(variables, x)
-    np.testing.assert_allclose(
-        logits, plain.logits(variables["params"], x, reference_sizes(), NX),
-        atol=2e-5)
-    loss, aux = model.apply(variables, x, y, method="loss_and_aux")
-    logz = jax.nn.logsumexp(logits, -1)
-    picked = jnp.take_along_axis(logits, y[..., None], -1)[..., 0]
-    np.testing.assert_allclose(loss, jnp.mean(logz - picked), rtol=1e-6)
-    assert set(aux["counters"]) == {
+def test_whole_logits_agree_with_the_blocked_loss_and_the_reference(built):
+    counters = checks.whole_logits_agree(built, atol=2e-5)
+    assert set(counters) == {
         "moe/assignments_min", "moe/assignments_mean", "moe/assignments_max",
         "moe/dropped", "moe/pool_fill"}
-    assert float(aux["counters"]["moe/dropped"]) == 0.0
     # 2 expert layers x 4 held of 16 experts: 80 tokens x top-4 a layer,
     # a quarter of them here on average
-    counters = {k: float(v) for k, v in aux["counters"].items()}
     assert (counters["moe/assignments_min"] <= counters["moe/assignments_mean"]
             <= counters["moe/assignments_max"] <= 80)
     assert 0 < counters["moe/assignments_mean"] * 8 <= 2 * 80 * 4
     # rows moved over places walked: each layer's first pool (2 x 4 held x
     # the tile's rows) holds what arrived
-    pools = 2 * 2 * 4 * model.cfg.expert_rows
+    pools = 2 * 2 * 4 * built.model.cfg.expert_rows
     np.testing.assert_allclose(
         counters["moe/pool_fill"],
         counters["moe/assignments_mean"] * 8 / pools, rtol=1e-6)
 
 
 def test_a_model_without_expert_layers_counts_nothing():
-    model, variables, x, y = tiny_model_and_batch(
-        layers=(("kda", "mlp"), ("mla", "mlp")))
-    _, aux = model.apply(variables, x, y, method="loss_and_aux")
+    bare = checks.Built(FAMILY, layers=(("kda", "mlp"), ("mla", "mlp")))
+    _, aux = jax.jit(lambda p: bare.model.apply(
+        {"params": p}, bare.x, bare.y, method="loss_and_aux"))(bare.params)
     assert set(aux) == {"accuracy"}
 
 
-def test_rematerialisation_changes_no_number():
-    grads = []
-    for remat in (True, False):
-        model, variables, x, y = tiny_model_and_batch(remat=remat)
-        grads.append(jax.grad(lambda p: model.apply(
-            {"params": p}, x, y, method="loss_and_aux")[0])(
-                variables["params"]))
-    for a, b in zip(*map(jax.tree.leaves, grads)):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+def test_rematerialisation_changes_no_number(built):
+    checks.rematerialisation_changes_no_number(built, rtol=1e-4, atol=1e-5)
 
 
 def test_trainer_takes_the_loss_from_the_model_and_counts():
-    """`Trainer.fit` on the decoder: per-token labels through the loader,
-    the model's loss in the step, its counters in `LoopStats`."""
-    import optax
-    from geomx_tpu import GeoConfig, HiPSTopology
-    from geomx_tpu.sync import get_sync_algorithm
-    from geomx_tpu.train import Trainer
-    model, _, _, _ = tiny_model_and_batch()
-    tokens = np.random.default_rng(1).integers(0, 64, (8, 41)).astype(np.int32)
-    x, y = tokens[:, :-1], tokens[:, 1:]
-    cfg = GeoConfig(num_parties=1, workers_per_party=1, sync_mode="fsa",
-                    compression="none")
-    topo = HiPSTopology(1, 1)
-    trainer = Trainer(model, topo, optax.adam(1e-2),
-                      sync=get_sync_algorithm(cfg), config=cfg)
-    state = trainer.init_state(jax.random.PRNGKey(0), x[:2])
-    loader = trainer.make_loader(x, y, 2, seed=0)
-    state, records = trainer.fit(state, loader, epochs=3, log_every=1,
-                                 log_fn=lambda _line: None)
-    losses = [r["loss"] for r in records if "loss" in r]
-    assert len(losses) == 12 and losses[-1] < losses[0]
-    counters = trainer.loop_stats.as_dict()["counters"]
+    counters = checks.trainer_fits(FAMILY, 1e-2, epochs=3, seed=0)
     assert counters["moe/dropped"] == {"count": 12, "total": 0.0,
                                        "last": 0.0, "max": 0.0}
     # 2 x 40 tokens x top-4 of 16, 4 held: 80 assignments a layer on average
-    assert counters["moe/assignments_mean"]["count"] == 12
     assert 5.0 < counters["moe/assignments_mean"]["last"] < 40.0
 
 
@@ -195,21 +140,10 @@ def test_the_new_scopes_are_pairs_of_the_vocabulary():
     assert plain_name.scope == "step/forward_backward"
 
 
-def test_the_compiled_step_names_the_decoders_layers():
+def test_the_compiled_step_names_the_decoders_layers(built):
     """Every new scope reaches the compiled program's instruction names,
     among them the bodies of the `while`s (the scan, the experts' loop)."""
-    from geomx_tpu.telemetry.layers import op_layers
-    model, variables, x, y = tiny_model_and_batch()
-    from geomx_tpu.utils.profiler import profile_scope
-
-    def step(p):
-        with profile_scope("step/forward_backward"):
-            return jax.grad(lambda p_: model.apply(
-                {"params": p_}, x, y, method="loss_and_aux")[0])(p)
-
-    text = jax.jit(step).lower(variables["params"]).compile().as_text()
-    scopes = {entry.scope for entry in op_layers(text).values()
-              if entry.scope}
+    scopes = built.scopes()
     for needle in ("kda/proj", "kda/scan", "mla/proj", "mla/attention",
                    "moe/route", "moe/experts", "moe/dispatch", "moe/plan",
                    "moe/shared", "lm/loss"):

@@ -4,25 +4,19 @@ masked loop over experts), at tiny sizes on seeded weights: chunked KDA
 (`ops/kda.py`), the held-experts layer and its shares
 (`ops/held_experts.py`).  The model, its loss and `Trainer` are in
 `test_kimi_linear.py`: two files, so that two workers share the time."""
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import decoder_checks as checks
+from benchmark.references import kimi_linear as plain
+from geomx_tpu.models import kimi_linear as kl
+from geomx_tpu.ops.held_experts import held_experts
+from geomx_tpu.ops.kda import kda_chunked, unit_lower_inverse
 
-from benchmark.references import kimi_linear as plain  # noqa: E402
-from benchmark.references.numerics import Numerics  # noqa: E402
-from geomx_tpu.models import kimi_linear as kl  # noqa: E402
-from geomx_tpu.ops.held_experts import held_experts  # noqa: E402
-from geomx_tpu.ops.kda import kda_chunked, unit_lower_inverse  # noqa: E402
-
-NX = Numerics("float32")
+NX = checks.NX
 
 
 def kda_inputs(seed, b, length, h, dk, dv, decay):
@@ -55,10 +49,11 @@ def test_chunked_kda_equals_the_token_recurrence(length, chunk, decay):
     weight = jnp.cos(jnp.arange(16.0))
     chunked = lambda *a: chunked_kda(*a, chunk=chunk)
     recurrent = lambda *a: plain.delta_rule_recurrence(NX, *a, block=8)
-    np.testing.assert_allclose(chunked(*args), recurrent(*args), atol=2e-6)
-    grads = [jax.grad(lambda *a: jnp.sum(f(*a) * weight), argnums=range(5))(
-        *args) for f in (chunked, recurrent)]
-    for got, want in zip(*grads):
+    (got, got_grads), (want, want_grads) = [
+        checks.value_and_gradients(f, args, range(5), weight)
+        for f in (chunked, recurrent)]
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    for got, want in zip(got_grads, want_grads):
         scale = float(jnp.max(jnp.abs(want)))
         np.testing.assert_allclose(got, want, atol=2e-5 * scale)
 
@@ -67,12 +62,12 @@ def test_strong_decay_overflows_nowhere():
     """exp(-20) a token: a cumulative product's reciprocal would be
     exp(1280) inside one chunk; differences never leave (0, 1]."""
     q, k, v, g, beta = kda_inputs(3, 1, 64, 1, 16, 16, 0.0)
-    out = chunked_kda(q, k, v, g - 20.0, beta)
-    grad = jax.grad(lambda g_: jnp.sum(chunked_kda(q, k, v, g_, beta)))(
-        g - 20.0)
+    out, grad = checks.value_and_gradients(
+        lambda g_: chunked_kda(q, k, v, g_, beta), (g - 20.0,), 0)
     assert bool(jnp.all(jnp.isfinite(out))) and bool(
         jnp.all(jnp.isfinite(grad)))
-    want = plain.delta_rule_recurrence(NX, q, k, v, g - 20.0, beta)
+    want = jax.jit(lambda *a: plain.delta_rule_recurrence(NX, *a))(
+        q, k, v, g - 20.0, beta)
     np.testing.assert_allclose(out, want, atol=1e-6)
 
 
@@ -131,20 +126,11 @@ def test_the_shares_add_up():
     the uncut reference's layer."""
     params = expert_weights(1)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 40, HIDDEN))
-    whole = plain.moe(NX, x, params, 0, TOP_K, SCALING)
-    shared = shared_expert(params, x)
-    total, arrived = shared, 0
-    for offset in range(0, EXPERTS, 4):
-        y, counts, dropped = expert_layer(4, offset).apply(
-            {"params": share_of(params, offset, 4)}, x)
-        # the program's share equals the reference's share
-        np.testing.assert_allclose(
-            y, plain.moe(NX, x, share_of(params, offset, 4), offset, TOP_K,
-                         SCALING), atol=2e-5)
-        total = total + (y - shared)
-        arrived += int(jnp.sum(counts))
-        assert int(dropped) == 0
-    np.testing.assert_allclose(total, whole, atol=5e-5)
+    reference = jax.jit(lambda p, offset: plain.moe(
+        NX, x, p, offset, TOP_K, SCALING), static_argnums=1)
+    arrived = checks.expert_shares_add_up(
+        lambda offset: expert_layer(4, offset), reference, params, x,
+        reference(params, 0), jax.jit(shared_expert)(params, x))
     assert arrived == 2 * 40 * TOP_K       # every assignment, exactly once
 
 
@@ -157,15 +143,15 @@ def test_a_skewed_router_drops_nothing(rows):
     params = share_of(expert_weights(3, router_skew=5), 4, 4)
     x = jax.random.normal(jax.random.PRNGKey(4), (1, 700, HIDDEN)) + 1.0
     layer = expert_layer(4, 4, rows)
-    y, counts, dropped = layer.apply({"params": params}, x)
-    assert int(counts[1]) >= 690 and int(dropped) == 0
-    np.testing.assert_allclose(
-        y, plain.moe(NX, x, params, 4, TOP_K, SCALING), atol=5e-5)
     weight = jnp.sin(jnp.arange(float(HIDDEN)))
-    ours = jax.grad(lambda p, x_: jnp.sum(
-        layer.apply({"params": p}, x_)[0] * weight), (0, 1))(params, x)
-    theirs = jax.grad(lambda p, x_: jnp.sum(
-        plain.moe(NX, x_, p, 4, TOP_K, SCALING) * weight), (0, 1))(params, x)
+    (y, counts, dropped), ours = checks.value_and_gradients(
+        lambda p, x_: layer.apply({"params": p}, x_), (params, x), (0, 1),
+        weight)
+    want, theirs = checks.value_and_gradients(
+        lambda p, x_: plain.moe(NX, x_, p, 4, TOP_K, SCALING), (params, x),
+        (0, 1), weight)
+    assert int(counts[1]) >= 690 and int(dropped) == 0
+    np.testing.assert_allclose(y, want, atol=5e-5)
     for got, want in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs)):
         np.testing.assert_allclose(
             got, want, atol=3e-5 * max(1.0, float(jnp.max(jnp.abs(want)))))
@@ -181,9 +167,9 @@ def test_held_experts_with_no_assignment_at_all(rows):
     p = expert_weights(0)
     mats = [p[n][:2] for n in ("experts_gate_kernel", "experts_up_kernel",
                                "experts_down_kernel")]
-    y, counts, dropped = held_experts(x, idx, w, *mats, 0, rows)
+    (y, counts, dropped), grads = checks.value_and_gradients(
+        lambda x_, *m: held_experts(x_, idx, w, *m, 0, rows), (x, *mats),
+        range(4))
     assert not np.any(np.asarray(y)) and not np.any(np.asarray(counts))
     assert int(dropped) == 0
-    grads = jax.grad(lambda x_, *m: jnp.sum(
-        held_experts(x_, idx, w, *m, 0, rows)[0]), range(4))(x, *mats)
     assert all(not np.any(np.asarray(g)) for g in grads)

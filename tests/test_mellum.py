@@ -8,26 +8,18 @@ the router, the expert layer without a shared expert and its four shares,
 and the other decoders' parameter trees."""
 import hashlib
 import math
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.references import mellum as plain  # noqa: E402
-from benchmark.references.kimi_linear import rms_norm  # noqa: E402
-from benchmark.references.numerics import Numerics  # noqa: E402
-from geomx_tpu.models import decoder, get_model, mellum  # noqa: E402
-from geomx_tpu.ops import dispatch  # noqa: E402
-from geomx_tpu.ops import gqa_elementwise as ge  # noqa: E402
-
-NX = Numerics("float32")
+import decoder_checks as checks
+from benchmark.references import mellum as plain
+from benchmark.references.kimi_linear import rms_norm
+from geomx_tpu.models import decoder, get_model, mellum
+from geomx_tpu.ops import dispatch
+from geomx_tpu.ops import gqa_elementwise as ge
 
 # `rope_parameters.full_attention` of Mellum2-12B-A2.5B's config.json
 PUBLISHED = dict(theta=500000.0, factor=16.0, original=8192, beta_fast=32.0,
@@ -43,27 +35,15 @@ TINY = dict(vocab=64, hidden=32, num_heads=8, num_kv_heads=2, head_dim=16,
 PROGRAM = dict(loss_block=32, expert_rows=8, expert_pool=64)
 
 
-def tiny_model_and_batch(**over):
-    model = get_model("mellum", **{**TINY, "yarn": ge.Yarn(**TINY_YARN),
-                                   **PROGRAM, **over})
-    tokens = np.random.default_rng(0).integers(0, 64, (2, 41))
-    x, y = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
-    variables = jax.jit(lambda: model.init(jax.random.PRNGKey(1), x))()
-    # norms' scales off one, so that a norm left out or misplaced shows
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, a: a + 0.1 * jax.random.normal(
-            jax.random.PRNGKey(len(path)), a.shape)
-        if path[-1].key == "scale" else a, variables["params"])
-    return model, params, x, y
+NX = checks.NX
+FAMILY = checks.Family(
+    "mellum", {**TINY, "yarn": ge.Yarn(**TINY_YARN), **PROGRAM}, plain,
+    {**TINY, "yarn": TINY_YARN, "eps": 1e-6})
 
 
-def reference_sizes(**over):
-    return {**TINY, "yarn": TINY_YARN, "eps": 1e-6, **over}
-
-
-def loss_of(model, x, y):
-    return lambda p: model.apply({"params": p}, x, y,
-                                 method="loss_and_aux")[0]
+@pytest.fixture(scope="module")
+def built():
+    return checks.Built(FAMILY)
 
 
 def test_the_shared_pieces_have_one_copy():
@@ -76,13 +56,8 @@ def test_the_shared_pieces_have_one_copy():
         False, 1.0, 0)
 
 
-def test_model_loss_and_every_gradient_leaf_equal_the_plain_reference():
-    model, params, x, y = tiny_model_and_batch()
-    ours = loss_of(model, x, y)
-    theirs = lambda p: plain.loss(p, x, y, reference_sizes(), NX)
-    np.testing.assert_allclose(ours(params), theirs(params), rtol=2e-6)
-    got, want = jax.grad(ours)(params), jax.grad(theirs)(params)
-    assert jax.tree.structure(got) == jax.tree.structure(want)
+def test_model_loss_and_every_gradient_leaf_equal_the_plain_reference(built):
+    got, want = checks.loss_equals_the_reference(built)
     flat = jax.tree_util.tree_flatten_with_path(want)[0]
     for (path, w), g in zip(flat, jax.tree.leaves(got)):
         scale = float(jnp.max(jnp.abs(w)))
@@ -93,27 +68,12 @@ def test_model_loss_and_every_gradient_leaf_equal_the_plain_reference():
     assert names.count("scale") == 3 * (2 + 2) + 1
 
 
-def test_whole_logits_agree_with_the_blocked_loss_and_the_reference():
-    model, params, x, y = tiny_model_and_batch()
-    logits = model.apply({"params": params}, x)
-    np.testing.assert_allclose(
-        logits, plain.logits(params, x, reference_sizes(), NX), atol=3e-5)
-    loss, aux = model.apply({"params": params}, x, y, method="loss_and_aux")
-    logz = jax.nn.logsumexp(logits, -1)
-    picked = jnp.take_along_axis(logits, y[..., None], -1)[..., 0]
-    np.testing.assert_allclose(loss, jnp.mean(logz - picked), rtol=1e-6)
-    assert float(aux["counters"]["moe/dropped"]) == 0.0
+def test_whole_logits_agree_with_the_blocked_loss_and_the_reference(built):
+    checks.whole_logits_agree(built, atol=3e-5)
 
 
-def test_the_kernels_give_what_the_dense_fall_back_gives():
-    model, params, x, y = tiny_model_and_batch()
-    ours = loss_of(model, x, y)
-    want = jax.value_and_grad(ours)(params)
-    with dispatch.kernels("interpret"):
-        got = jax.value_and_grad(ours)(params)
-    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
-        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-5)
+def test_the_kernels_give_what_the_dense_fall_back_gives(built):
+    checks.kernels_give_the_dense_fall_back(built)
 
 
 def test_yarn_table_at_the_published_numbers_is_the_references_own():
@@ -171,13 +131,18 @@ def test_norm_rotary_under_a_yarn_table_is_the_plain_chain(length):
         with dispatch.kernels("interpret"):
             return dispatch.gqa_norm_rotary(*a, 1e-6, rope)
 
-    want, pull_want = jax.vjp(lambda *a: plain_chain(*a, yarn), q, k,
-                              q_scale, k_scale)
+    def value_and_pull(form):
+        def run(*a):
+            out, pull = jax.vjp(form, *a)
+            return out, pull((gq, gk))
+        return jax.jit(run)(q, k, q_scale, k_scale)
+
+    want, pulled_want = value_and_pull(lambda *a: plain_chain(*a, yarn))
     for form in (kernel, lambda *a: ge.norm_rotary_ref(*a, 1e-6, rope)):
-        got, pull = jax.vjp(form, q, k, q_scale, k_scale)
+        got, pulled = value_and_pull(form)
         for a, b in zip(got, want):
             np.testing.assert_allclose(a, b, atol=2e-5)
-        for a, b in zip(pull((gq, gk)), pull_want((gq, gk))):
+        for a, b in zip(pulled, pulled_want):
             np.testing.assert_allclose(
                 a, b, atol=3e-5 * float(jnp.max(jnp.abs(b))))
     # the kernels did run under the door's interpret mode
@@ -198,9 +163,10 @@ def test_both_kinds_of_layer_take_positions_from_their_own_table():
     last = {}
     for kind in ("window", "global"):
         mixer = cfg.make_mixer(kind, jnp.float32)
-        params = mixer.init(jax.random.PRNGKey(1), x)
+        params = jax.jit(mixer.init)(jax.random.PRNGKey(1), x)
         assert "gate_kernel" not in params["params"]
-        last[kind] = [mixer.apply(params, v)[0, -1] for v in (x, swapped)]
+        apply = jax.jit(mixer.apply)
+        last[kind] = [apply(params, v)[0, -1] for v in (x, swapped)]
         assert float(jnp.max(jnp.abs(last[kind][0] - last[kind][1]))) > 1e-3
     assert float(jnp.max(jnp.abs(last["window"][0] - last["global"][0]))) \
         > 1e-3
@@ -242,7 +208,7 @@ def whole_layer(hidden=32, width=24, experts=16, seed=3):
         jax.random.normal(ks[4], (2, 20, hidden))
 
 
-def test_an_expert_layer_with_no_shared_expert_has_no_shared_kernels():
+def test_an_expert_layer_with_no_shared_expert_has_no_shared_kernels(built):
     whole, x = whole_layer()
     layer = decoder.HeldExpertsLayer(16, 16, 0, 4, 24, 1.0, shared_experts=0,
                                      rows=8, pool=64, scoring="softmax")
@@ -258,9 +224,7 @@ def test_an_expert_layer_with_no_shared_expert_has_no_shared_kernels():
     assert {"shared_gate_kernel", "shared_up_kernel",
             "shared_down_kernel"} <= set(names)
     # and the whole model's compiled step opens every scope but that one
-    model, params, x, y = tiny_model_and_batch()
-    text = jax.jit(jax.grad(loss_of(model, x, y))).lower(params).as_text(
-        debug_info=True)
+    text = built.lowered.as_text(debug_info=True)
     for scope in ("gqa/proj", "gqa/window", "gqa/global", "attn/core",
                   "moe/route", "moe/experts", "moe/plan", "moe/dispatch",
                   "lm/loss"):
@@ -274,32 +238,17 @@ def test_the_four_expert_shares_add_up_to_the_uncut_reference():
     every chip computes alike, are what the reference gives with all 16
     held."""
     whole, x = whole_layer()
-    uncut = plain.moe(NX, x, whole, 0, 4)
-    total, arrived = 0.0, 0
-    for share in range(4):
-        lo = 4 * share
-        part = {k: (v[lo:lo + 4] if k.startswith("experts_") else v)
-                for k, v in whole.items()}
-        layer = decoder.HeldExpertsLayer(16, 4, lo, 4, 24, 1.0,
-                                         shared_experts=0, rows=8, pool=32,
-                                         scoring="softmax")
-        y, counts, dropped = layer.apply({"params": part}, x)
-        np.testing.assert_allclose(y, plain.moe(NX, x, part, lo, 4),
-                                   atol=2e-5)
-        total = total + y
-        arrived += int(jnp.sum(counts))
-        assert int(dropped) == 0
-    np.testing.assert_allclose(total, uncut, atol=5e-5)
+    reference = jax.jit(lambda p, lo: plain.moe(NX, x, p, lo, 4),
+                        static_argnums=1)
+    arrived = checks.expert_shares_add_up(
+        lambda lo: decoder.HeldExpertsLayer(
+            16, 4, lo, 4, 24, 1.0, shared_experts=0, rows=8, pool=32,
+            scoring="softmax"), reference, whole, x, reference(whole, 0))
     assert arrived == 2 * 20 * 4        # every assignment lands on one share
 
 
-def test_rematerialisation_changes_no_number():
-    grads = []
-    for remat in (True, False):
-        model, params, x, y = tiny_model_and_batch(remat=remat)
-        grads.append(jax.grad(loss_of(model, x, y))(params))
-    for a, b in zip(*map(jax.tree.leaves, grads)):
-        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+def test_rematerialisation_changes_no_number(built):
+    checks.rematerialisation_changes_no_number(built, rtol=1e-4, atol=1e-5)
 
 
 # tiny configurations of the other decoders and the digest of their
@@ -348,28 +297,6 @@ def test_the_other_decoders_parameter_trees_are_unchanged(name):
 
 
 def test_trainer_takes_the_loss_from_the_model_and_counts():
-    """`get_model("mellum")` through `Trainer.fit` and FSA's dense tier
-    with no branch on its name."""
-    import optax
-    from geomx_tpu import GeoConfig, HiPSTopology
-    from geomx_tpu.sync import get_sync_algorithm
-    from geomx_tpu.train import Trainer
-    cfg = GeoConfig(num_parties=1, workers_per_party=1, sync_mode="fsa",
-                    compression="none")
-    model = get_model("mellum", **{**TINY, "yarn": ge.Yarn(**TINY_YARN),
-                                   **PROGRAM})
-    trainer = Trainer(model, HiPSTopology(1, 1), optax.adam(1e-3),
-                      sync=get_sync_algorithm(cfg), config=cfg)
-    tokens = np.random.default_rng(1).integers(0, 64, (8, 41)).astype(
-        np.int32)
-    x, y = tokens[:, :-1], tokens[:, 1:]
-    state = trainer.init_state(jax.random.PRNGKey(0), x[:2])
-    state, records = trainer.fit(state, trainer.make_loader(x, y, 2),
-                                 epochs=2, log_every=1,
-                                 log_fn=lambda _line: None)
-    losses = [r["loss"] for r in records if "loss" in r]
-    assert len(losses) == 8 and losses[-1] < losses[0]
-    counters = trainer.loop_stats.as_dict()["counters"]
-    assert counters["moe/dropped"]["total"] == 0.0
-    assert counters["moe/assignments_mean"]["count"] == 8
+    """`get_model("mellum")` through `Trainer.fit` and FSA's dense tier."""
+    counters = checks.trainer_fits(FAMILY, 1e-3, epochs=2)
     assert 0.0 < counters["moe/pool_fill"]["max"] <= 1.0

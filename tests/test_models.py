@@ -20,8 +20,9 @@ ZOO = ["cnn", "mlp", "alexnet", "resnet20",
 def test_forward_shape(name):
     model = get_model(name, num_classes=10)
     x = jnp.asarray(np.random.RandomState(0).rand(2, 32, 32, 3), jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    out = model.apply(variables, x, train=False)
+    variables = jax.jit(lambda: model.init(jax.random.PRNGKey(0), x,
+                                           train=False))()
+    out = jax.jit(lambda v: model.apply(v, x, train=False))(variables)
     assert out.shape == (2, 10)
     assert out.dtype == jnp.float32
     assert bool(jnp.all(jnp.isfinite(out)))
@@ -31,14 +32,15 @@ def test_gradients_flow():
     model = get_model("mlp")
     x = jnp.asarray(np.random.RandomState(1).rand(4, 32, 32, 3), jnp.float32)
     y = jnp.asarray([0, 1, 2, 3])
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
+    variables = jax.jit(lambda: model.init(jax.random.PRNGKey(0), x,
+                                           train=False))()
 
     def loss(v):
         logits = model.apply(v, x, train=True)
         onehot = jax.nn.one_hot(y, 10)
         return -jnp.mean(jnp.sum(onehot * jax.nn.log_softmax(logits), -1))
 
-    grads = jax.grad(loss)(variables)
+    grads = jax.jit(jax.grad(loss))(variables)
     norms = [float(jnp.linalg.norm(g)) for g in jax.tree.leaves(grads)]
     assert all(np.isfinite(n) for n in norms)
     assert any(n > 0 for n in norms)
@@ -54,8 +56,6 @@ def test_resnet20_space_to_depth_variant_trains():
     """The flag-gated TPU stem experiment (bench config vanilla_s2d)
     trains: the 2x2 space-to-depth stem halves every stage's resolution
     but keeps a working ResNet-20 sibling."""
-    import jax
-    import numpy as np
     import optax
 
     from geomx_tpu.models import get_model
@@ -88,15 +88,14 @@ def test_resnet20_mxu_shortcuts_projection_shape():
     cin, 3/4 of activations discarded) with space_to_depth + unstrided
     1x1 (contraction 4*cin, lossless): same output shapes, 4x the MXU
     systolic fill on the projection matmul."""
-    import jax
-    import jax.numpy as jnp
-
     from geomx_tpu.models import ResNet20
 
     model = ResNet20(num_classes=10, mxu_shortcuts=True)
     x = jnp.zeros((2, 32, 32, 3), jnp.float32)
-    variables = model.init(jax.random.PRNGKey(0), x, train=False)
-    logits = model.apply(variables, x, train=False)
+    variables = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0), x, train=False))
+    logits = jax.eval_shape(lambda v: model.apply(v, x, train=False),
+                            variables)
     assert logits.shape == (2, 10)
     # the two transition shortcuts contract over 4*cin channels
     kernels = {

@@ -7,25 +7,17 @@ path of `ops/held_experts` against a loop over experts, the new scopes,
 and **the shares add up**: the head shares of a Mamba-2 layer and of an
 attention layer, and the expert shares of a LatentMoE, sum to the uncut
 reference's layer."""
-import os
-import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
-
-from benchmark.references import nemotron_h as plain  # noqa: E402
-from benchmark.references.numerics import Numerics  # noqa: E402
-from geomx_tpu.models import afmoe, decoder, get_model  # noqa: E402
-from geomx_tpu.models import kimi_linear, nemotron_h  # noqa: E402
-from geomx_tpu.ops.held_experts import held_experts  # noqa: E402
-
-NX = Numerics("float32")
+import decoder_checks as checks
+from benchmark.references import nemotron_h as plain
+from geomx_tpu.models import afmoe, decoder
+from geomx_tpu.models import kimi_linear, nemotron_h
+from geomx_tpu.ops.held_experts import held_experts
 
 # the whole tiny layer: 8 Mamba-2 heads of 8 in 4 B/C groups of 16, 8
 # query heads on 2 key/value heads of 16, 16 experts of 24 in a latent 16
@@ -42,22 +34,18 @@ LAYERS = (("mamba", None), (None, "moe"), ("attention", None), (None, "moe"),
 PROGRAM = dict(ssd_chunk=16, loss_block=32, expert_rows=8, expert_pool=64)
 
 
-def tiny_model_and_batch(**over):
-    model = get_model("nemotron_h", **{**SHARE, "layers": LAYERS, **PROGRAM,
-                                       **over})
-    tokens = np.random.default_rng(0).integers(0, 64, (2, 41))
-    x, y = jnp.asarray(tokens[:, :-1]), jnp.asarray(tokens[:, 1:])
-    variables = jax.jit(lambda: model.init(jax.random.PRNGKey(1), x))()
-    # norms' scales off one, so that a norm left out or misplaced shows
-    params = jax.tree_util.tree_map_with_path(
-        lambda path, a: a + 0.1 * jax.random.normal(
-            jax.random.PRNGKey(len(path)), a.shape)
-        if path[-1].key == "scale" else a, variables["params"])
-    return model, params, x, y
-
-
 def sizes(base, **over):
     return {**base, "layers": LAYERS, "eps": 1e-5, **over}
+
+
+NX = checks.NX
+FAMILY = checks.Family("nemotron_h", {**SHARE, "layers": LAYERS, **PROGRAM},
+                       plain, sizes(SHARE))
+
+
+@pytest.fixture(scope="module")
+def built():
+    return checks.Built(FAMILY)
 
 
 def seeded(shapes, seed=0):
@@ -82,13 +70,8 @@ def test_the_shared_pieces_have_one_copy():
     assert afmoe.AfmoeConfig.expert_form == {}
 
 
-def test_model_loss_and_every_gradient_leaf_equal_the_plain_reference():
-    model, params, x, y = tiny_model_and_batch()
-    ours = lambda p: model.apply({"params": p}, x, y,
-                                 method="loss_and_aux")[0]
-    theirs = lambda p: plain.loss(p, x, y, sizes(SHARE), NX)
-    np.testing.assert_allclose(ours(params), theirs(params), rtol=2e-6)
-    got, want = jax.grad(ours)(params), jax.grad(theirs)(params)
+def test_model_loss_and_every_gradient_leaf_equal_the_plain_reference(built):
+    got, want = checks.loss_equals_the_reference(built)
     whole = np.sqrt(sum(float(jnp.sum(w * w)) for w in jax.tree.leaves(want)))
     flat = jax.tree_util.tree_flatten_with_path(want)[0]
     assert len(flat) == len(jax.tree.leaves(got)) == 3 + 2 * 9 + 5 + 2 * 8
@@ -103,8 +86,8 @@ def test_model_loss_and_every_gradient_leaf_equal_the_plain_reference():
         assert float(jnp.sum(w * w)) > 0, name
 
 
-def test_a_layer_is_one_half_with_one_norm():
-    model, params, x, y = tiny_model_and_batch()
+def test_a_layer_is_one_half_with_one_norm(built):
+    model, params = built.model, built.params
     for i, (mixer, ffn) in enumerate(LAYERS):
         layer = params[f"layer{i + 1}"]
         assert set(layer) == {"mixer" if ffn is None else "ffn"}
@@ -127,25 +110,17 @@ def test_a_layer_is_one_half_with_one_norm():
     # the halves of a two-half block still both run (`Block` elsewhere)
     block = decoder.Block("mamba", None, model.cfg)
     h = jnp.ones((2, 24, 32))
-    out, counts, dropped = block.apply(
+    out, counts, dropped = jax.jit(block.apply)(
         {"params": {"mixer": params["layer1"]["mixer"]}}, h)
     assert out.shape == h.shape and counts.shape == (0,) and dropped == 0
     only = decoder.Block(None, "moe", model.cfg)
-    out, counts, dropped = only.apply(
+    out, counts, dropped = jax.jit(only.apply)(
         {"params": {"ffn": params["layer2"]["ffn"]}}, h)
     assert out.shape == h.shape and counts.shape == (4,) and dropped == 0
 
 
-def test_remat_changes_nothing():
-    model, params, x, y = tiny_model_and_batch()
-    plain_model = tiny_model_and_batch(remat=False)[0]
-    loss = lambda m: (lambda p: m.apply({"params": p}, x, y,
-                                        method="loss_and_aux")[0])
-    np.testing.assert_allclose(loss(model)(params), loss(plain_model)(params),
-                               rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(jax.grad(loss(model))(params)),
-                    jax.tree.leaves(jax.grad(loss(plain_model))(params))):
-        np.testing.assert_allclose(a, b, rtol=5e-6, atol=5e-6)
+def test_remat_changes_nothing(built):
+    checks.rematerialisation_changes_no_number(built, rtol=5e-6, atol=5e-6)
 
 
 @pytest.mark.parametrize("pool", [None, 8, 16, 40, 64, 128])
@@ -176,17 +151,17 @@ def test_ungated_held_experts_equal_a_loop_over_experts(pool):
                 NX, x, up[e], down[e])
         return y
 
-    y, counts, dropped = kernel(x, weights, up, down)
+    args = (x, weights, up, down)
+    probe = jax.random.normal(keys[4], (tokens, d))
+    (y, counts, dropped), got = checks.value_and_gradients(
+        kernel, args, range(4), probe)
+    want_y, want = checks.value_and_gradients(loop, args, range(4), probe)
     assert int(dropped) == 0
     assert int(jnp.sum(counts)) == int(jnp.sum(
         (idx >= offset) & (idx < offset + held)))
-    np.testing.assert_allclose(y, loop(x, weights, up, down), atol=2e-5)
-    probe = jax.random.normal(keys[4], (tokens, d))
-    grads = [jax.grad(lambda *a: jnp.sum(f(*a) * probe), argnums=range(4))(
-        x, weights, up, down)
-        for f in (lambda *a: kernel(*a)[0], loop)]
-    for name, got, want in zip(("x", "weights", "up", "down"), *grads):
-        np.testing.assert_allclose(got, want, atol=5e-5, err_msg=name)
+    np.testing.assert_allclose(y, want_y, atol=2e-5)
+    for name, g, w in zip(("x", "weights", "up", "down"), got, want):
+        np.testing.assert_allclose(g, w, atol=5e-5, err_msg=name)
 
 
 def mamba_whole_and_shares(hidden=32):
@@ -226,18 +201,22 @@ def mamba_whole_and_shares(hidden=32):
 def test_the_head_shares_of_a_mamba_layer_add_up_to_the_uncut_layer():
     whole, shares = mamba_whole_and_shares()
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 40, 32))
-    want = plain.mamba2(NX, x, whole, sizes(WHOLE))
+    reference = lambda base: jax.jit(
+        lambda p: plain.mamba2(NX, x, p, sizes(base)))
+    want = reference(WHOLE)(whole)
     mixer = nemotron_h.Mamba2Mixer(2, 8, 1, 16, 4, 1e-5, chunk=16)
-    parts = [mixer.apply({"params": p}, x) for p in shares]
+    share = jax.jit(lambda p: mixer.apply({"params": p}, x))
+    parts = [share(p) for p in shares]
     np.testing.assert_allclose(sum(parts), want, atol=2e-5)
     # and a share is the reference given the same share
-    np.testing.assert_allclose(
-        parts[1], plain.mamba2(NX, x, shares[1], sizes(SHARE)), atol=2e-5)
+    np.testing.assert_allclose(parts[1], reference(SHARE)(shares[1]),
+                               atol=2e-5)
     assert float(jnp.max(jnp.abs(parts[0]))) > 1e-2
     # the whole layer at once through the program, groups and all
     all_at_once = nemotron_h.Mamba2Mixer(8, 8, 4, 16, 4, 1e-5, chunk=16)
-    np.testing.assert_allclose(all_at_once.apply({"params": whole}, x), want,
-                               atol=2e-5)
+    np.testing.assert_allclose(
+        jax.jit(lambda p: all_at_once.apply({"params": p}, x))(whole), want,
+        atol=2e-5)
 
 
 def test_the_head_shares_of_an_attention_layer_add_up_to_the_uncut_layer():
@@ -247,25 +226,26 @@ def test_the_head_shares_of_an_attention_layer_add_up_to_the_uncut_layer():
                     "v_kernel": (hidden, kv * d),
                     "out_kernel": (heads * d, hidden)}, seed=6)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 40, hidden))
-    want = plain.attention(NX, x, whole, sizes(WHOLE), query_block=16)
+    reference = jax.jit(lambda x_, **kw: plain.attention(
+        NX, x_, whole, sizes(WHOLE), **kw), static_argnames="query_block")
+    want = reference(x, query_block=16)
     mixer = nemotron_h.AttentionMixer(2, 1, d)
+    apply = jax.jit(lambda p: mixer.apply({"params": p}, x))
     total = 0.0
     for share in range(4):      # query heads 2s, 2s + 1 read kv head s // 2
         q_at = np.arange(2 * share * d, 2 * (share + 1) * d)
         kv_at = np.arange((share // 2) * d, (share // 2 + 1) * d)
-        part = mixer.apply({"params": {
+        total = total + apply({
             "q_kernel": whole["q_kernel"][:, q_at],
             "k_kernel": whole["k_kernel"][:, kv_at],
             "v_kernel": whole["v_kernel"][:, kv_at],
-            "out_kernel": whole["out_kernel"][q_at]}}, x)
-        total = total + part
+            "out_kernel": whole["out_kernel"][q_at]})
     np.testing.assert_allclose(total, want, atol=2e-5)
     # no position signal: the last token's output does not care where the
     # earlier tokens sit
     swapped = x.at[:, [3, 17]].set(x[:, [17, 3]])
-    np.testing.assert_allclose(
-        plain.attention(NX, swapped, whole, sizes(WHOLE))[:, -1],
-        want[:, -1], atol=2e-5)
+    np.testing.assert_allclose(reference(swapped)[:, -1], want[:, -1],
+                               atol=2e-5)
 
 
 def test_the_expert_shares_of_a_latent_layer_add_up_to_the_uncut_layer():
@@ -280,37 +260,23 @@ def test_the_expert_shares_of_a_latent_layer_add_up_to_the_uncut_layer():
                     "experts_up_kernel": (experts, latent, f),
                     "experts_down_kernel": (experts, f, latent)}, seed=8)
     x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, hidden))
-    want = plain.latent_moe(NX, x, whole, sizes(WHOLE))
-    once = plain.shared(NX, x, whole)
-    total, arrived = 0.0, 0
-    for share in range(4):
-        at = slice(4 * share, 4 * share + 4)
-        layer = decoder.HeldExpertsLayer(
-            experts, 4, 4 * share, 6, f, 5.0, rows=8, pool=64, gated=False,
-            latent=latent, shared_width=wide)
-        y, counts, dropped = layer.apply({"params": dict(
-            whole, experts_up_kernel=whole["experts_up_kernel"][at],
-            experts_down_kernel=whole["experts_down_kernel"][at])}, x)
-        np.testing.assert_allclose(
-            y - once, plain.routed(NX, x, dict(
-                whole, experts_up_kernel=whole["experts_up_kernel"][at],
-                experts_down_kernel=whole["experts_down_kernel"][at]),
-                sizes(WHOLE, expert_offset=4 * share)), atol=2e-5)
-        total = total + (y - once)
-        arrived += int(jnp.sum(counts))
-        assert int(dropped) == 0
-    np.testing.assert_allclose(total + once, want, atol=5e-5)
+    want = jax.jit(lambda p: plain.latent_moe(NX, x, p, sizes(WHOLE)))(whole)
+    once = jax.jit(lambda p: plain.shared(NX, x, p))(whole)
+    arrived = checks.expert_shares_add_up(
+        lambda lo: decoder.HeldExpertsLayer(
+            experts, 4, lo, 6, f, 5.0, rows=8, pool=64, gated=False,
+            latent=latent, shared_width=wide),
+        lambda part, lo: once + jax.jit(lambda p: plain.routed(
+            NX, x, p, sizes(WHOLE, expert_offset=lo)))(part),
+        whole, x, want, once)
     assert arrived == 2 * 24 * 6        # every assignment fell on one share
 
 
-def test_the_new_scopes_reach_the_compiled_step():
+def test_the_new_scopes_reach_the_compiled_step(built):
     """`ssd/proj`, `ssd/scan`, `moe/latent` and the attention layer's
     `gqa/proj`, `gqa/global` tag ops of the lowered loss, forward and
     backward."""
-    model, params, x, y = tiny_model_and_batch()
-    text = jax.jit(jax.grad(lambda p: model.apply(
-        {"params": p}, x, y, method="loss_and_aux")[0])).lower(
-        params).as_text(debug_info=True)
+    text = built.lowered.as_text(debug_info=True)
     for scope in ("ssd/proj", "ssd/scan", "moe/latent", "moe/route",
                   "moe/shared", "moe/experts", "moe/dispatch", "moe/plan",
                   "gqa/proj",

@@ -198,8 +198,8 @@ def test_fused_block_gradients_match_jnp_block():
         mr, lr, orr = _block(q, k, v, m, l_acc, o, scale, mask)
         return jnp.sum(orr ** 2) + jnp.sum(lr) + jnp.sum(mr)
 
-    gf = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(loss_f, argnums=(0, 1, 2)))(q, k, v)
+    gr = jax.jit(jax.grad(loss_r, argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-5)
@@ -276,8 +276,8 @@ def test_fused_ring_gradients_match_jnp_ring():
                               out_specs=P(None, "sp", None, None))
         return lambda q, k, v: jnp.sum(fn(q, k, v))
 
-    gf = jax.grad(make_loss(True), argnums=(0, 1, 2))(q, k, v)
-    gj = jax.grad(make_loss(False), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(make_loss(True), argnums=(0, 1, 2)))(q, k, v)
+    gj = jax.jit(jax.grad(make_loss(False), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gj):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-5, rtol=2e-5)
@@ -332,8 +332,8 @@ def test_fused_ulysses_gradients_match_jnp():
                               out_specs=P(None, "sp", None, None))
         return lambda q, k, v: jnp.sum(fn(q, k, v))
 
-    gf = jax.grad(make_loss(True), argnums=(0, 1, 2))(q, k, v)
-    gj = jax.grad(make_loss(False), argnums=(0, 1, 2))(q, k, v)
+    gf = jax.jit(jax.grad(make_loss(True), argnums=(0, 1, 2)))(q, k, v)
+    gj = jax.jit(jax.grad(make_loss(False), argnums=(0, 1, 2)))(q, k, v)
     for a, b in zip(gf, gj):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-5, rtol=2e-5)

@@ -930,6 +930,7 @@ def test_native_wire_roundtrip_and_ledger_accounting():
                                             NativeInferenceServer)
     from geomx_tpu.telemetry.ledger import get_request_ledger
     reset_request_ledger()
+    threads_before = set(threading.enumerate())
     # serving-sized features (the honesty bound is about framing
     # overhead amortized over REAL payloads, not a 48-byte toy row)
     gw, rep, W = _matmul_gateway(max_batch=8, dim=784)
@@ -948,15 +949,17 @@ def test_native_wire_roundtrip_and_ledger_accounting():
         np.testing.assert_allclose(
             out2["outputs"], np.ones((1, 784), np.float32) @ W,
             rtol=1e-4)
-        # the server counts a reply's bytes after it has sent them, so
-        # the client may hold the reply before the ledger holds its frame
-        deadline = time.monotonic() + 5.0
-        while True:
-            s = get_request_ledger().summary()
-            if s["wire"]["native"]["frames"] >= 4 \
-                    or time.monotonic() > deadline:
-                break
-            time.sleep(0.01)
+        # the server counts a reply's bytes after it has sent them, and
+        # the gateway's worker records a request after it has woken its
+        # waiter, so the client may hold the reply before the ledger holds
+        # either.  Each thread ends behind its last count: the
+        # connection's at the client's close, the worker at `stop`
+        cli.close()
+        for thread in set(threading.enumerate()) - threads_before:
+            if thread.name.endswith("(_serve_conn)"):
+                thread.join(10.0)
+        gw.stop()
+        s = get_request_ledger().summary()
         assert s["by_transport"].get("native", 0) == 3
         lane = s["wire"]["native"]
         assert lane["frames"] == 4          # 2 rx + 2 tx

@@ -4,29 +4,28 @@ token-by-token recurrence of the plain reference
 (`benchmark/references/nemotron_h.state_space_recurrence`), in values and
 in the gradients of all five inputs, float32, at several chunk counts,
 lengths that are no multiple of the chunk, several heads a group."""
-import os
-import sys
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+import decoder_checks as checks
+from benchmark.references import nemotron_h as plain
+from geomx_tpu.ops import dispatch
+from geomx_tpu.ops.ssd import ssd_chunked
 
-from benchmark.references import nemotron_h as plain  # noqa: E402
-from benchmark.references.numerics import Numerics  # noqa: E402
-from geomx_tpu.ops import dispatch  # noqa: E402
-from geomx_tpu.ops.ssd import ssd_chunked  # noqa: E402
-
-NX = Numerics("float32")
+NX = checks.NX
 
 
-def ssd_recurrence(*args):
+@functools.partial(jax.jit, static_argnames="block")
+def ssd_recurrence(*args, block=8):
     """A token at a time, float32 at `highest`, in blocks of 8 tokens."""
-    return plain.state_space_recurrence(NX, *args, block=8)
+    return plain.state_space_recurrence(NX, *args, block=block)
+
+
+chunked = jax.jit(ssd_chunked, static_argnames="chunk")
 
 
 def inputs(seed, b, length, heads, p, groups, n, step):
@@ -56,14 +55,13 @@ def test_chunked_form_equals_the_token_recurrence_in_values(
         length, chunk, heads, groups, step):
     args = inputs(length, 2, length, heads, 8, groups, 16, step)
     want = ssd_recurrence(*args)
-    got = ssd_chunked(*args, chunk=chunk)
+    got = chunked(*args, chunk=chunk)
     assert got.shape == want.shape == (2, length, heads, 8)
     scale = float(jnp.max(jnp.abs(want)))
     np.testing.assert_allclose(got, want, atol=3e-6 * scale)
     # the recurrence's blocks are its own business
-    np.testing.assert_allclose(
-        plain.state_space_recurrence(NX, *args, block=64), want,
-        atol=3e-6 * scale)
+    np.testing.assert_allclose(ssd_recurrence(*args, block=64), want,
+                               atol=3e-6 * scale)
 
 
 @pytest.mark.parametrize("length,chunk,heads,groups,step", CASES)
@@ -72,9 +70,9 @@ def test_chunked_form_equals_the_token_recurrence_in_gradients(
     """Of x, dt, a, B and C, under a seeded weighting of the outputs."""
     args = inputs(length + 1, 2, length, heads, 8, groups, 16, step)
     weight = jax.random.normal(jax.random.PRNGKey(7), (2, length, heads, 8))
-    grads = [jax.grad(lambda *a: jnp.sum(f(*a) * weight), argnums=range(5))(
-        *args) for f in (lambda *a: ssd_chunked(*a, chunk=chunk),
-                         ssd_recurrence)]
+    grads = [checks.value_and_gradients(f, args, range(5), weight)[1]
+             for f in (lambda *a: ssd_chunked(*a, chunk=chunk),
+                       ssd_recurrence)]
     for name, got, want in zip("x dt a b c".split(), *grads):
         assert got.shape == want.shape, name
         np.testing.assert_allclose(
@@ -87,8 +85,8 @@ def test_the_state_starts_at_zero_and_padding_neither_writes_nor_decays():
     the chunked form pads with is invisible, values and gradients."""
     args = inputs(3, 1, 80, 2, 8, 1, 16, 0.05)
     cut = lambda n: tuple(v if v.ndim == 1 else v[:, :n] for v in args)
-    whole = ssd_chunked(*args, chunk=32)
-    np.testing.assert_allclose(ssd_chunked(*cut(50), chunk=32),
+    whole = chunked(*args, chunk=32)
+    np.testing.assert_allclose(chunked(*cut(50), chunk=32),
                                whole[:, :50], atol=1e-6)
     # token 0 sees its own write only: y_0 = dt_0 (C_0 . B_0) x_0
     x, dt, _, b, c = args
@@ -99,10 +97,10 @@ def test_the_state_starts_at_zero_and_padding_neither_writes_nor_decays():
 
 def test_the_door_gives_the_chunked_form_and_bf16_operands_stay_close():
     args = inputs(11, 2, 128, 4, 8, 2, 16, 0.01)
-    np.testing.assert_array_equal(dispatch.ssd(*args, 32),
-                                  ssd_chunked(*args, chunk=32))
+    door = jax.jit(dispatch.ssd, static_argnums=(5, 6))
+    np.testing.assert_array_equal(door(*args, 32), chunked(*args, chunk=32))
     want = ssd_recurrence(*args)
-    low = dispatch.ssd(*args, 32, jnp.bfloat16)
+    low = door(*args, 32, jnp.bfloat16)
     assert low.dtype == jnp.float32
     gap = float(jnp.max(jnp.abs(low - want)) / jnp.max(jnp.abs(want)))
     assert 1e-4 < gap < 2e-2, gap       # bf16 operands, float32 sums

@@ -1,0 +1,160 @@
+"""Ask the TPU's compiler, without a TPU (see
+``test_tpu_compile_engine.py``): the attention kernels, at the chip
+benchmark's own calls and at the shapes that once failed on the chip."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import tpu_compile_checks as checks
+from tpu_compile_checks import f32
+
+
+def _flash_fwd(L, dtype):
+    from geomx_tpu.ops import flash_attention
+    qkv = jax.ShapeDtypeStruct((2, L, 4, 64), dtype)
+    return functools.partial(flash_attention, causal=True), [qkv] * 3
+
+
+def _flash_bwd(L):
+    from geomx_tpu.ops import flash_attention_bwd
+    qkv = f32(2, L, 4, 64)
+    return (functools.partial(flash_attention_bwd, causal=True),
+            [qkv, qkv, qkv, qkv, f32(2, 4, L), qkv])
+
+
+def _latent_fwd(L):
+    """Latent attention's head sizes: 192-wide q and k, 128-wide v."""
+    from geomx_tpu.ops import flash_attention
+    bf16 = lambda d: jax.ShapeDtypeStruct((1, L, 8, d), jnp.bfloat16)
+    return (functools.partial(flash_attention, causal=True),
+            [bf16(192), bf16(192), bf16(128)])
+
+
+def _latent_bwd(L):
+    from geomx_tpu.ops import flash_attention_bwd
+    qk, v = f32(1, L, 8, 192), f32(1, L, 8, 128)
+    return (functools.partial(flash_attention_bwd, causal=True),
+            [qk, qk, v, v, f32(1, 8, L), v])
+
+
+def _cell_attention(which, direction):
+    """The chip benchmark's own attention calls, bf16: a BERT-large layer
+    (16 x 512 x 16 x 64, four heads a step, one backward kernel) and one
+    sequence of the decoder's latent attention (8,192 x 32 x 192/128,
+    causal: 136 block pairs of 512, dq and dk/dv kernels); one sequence of
+    the second decoder's grouped-query attention (8,192 x 32 query heads
+    on 4 key/value heads of 128), in a window layer (a band of 2,048 keys:
+    70 pairs) and in a global one; one sequence of the third decoder's
+    share of its attention layer (four query heads on the one key/value
+    head they read); one sequence of the fourth decoder's (16,384 x 32 on
+    4 of 128) in a window layer (a band of 1,024 keys, two blocks wide)
+    and in a full one (528 block pairs)."""
+    from geomx_tpu.ops import flash_attention_bwd, flash_attention_with_lse
+    b, L, h, kv, d, dv, causal, window = {
+        "bert": (16, 512, 16, 16, 64, 64, False, None),
+        "latent": (1, 8192, 32, 32, 192, 128, True, None),
+        "window": (1, 8192, 32, 4, 128, 128, True, 2048),
+        "global": (1, 8192, 32, 4, 128, 128, True, None),
+        "share": (1, 8192, 4, 1, 128, 128, True, None),
+        "window-16k": (1, 16384, 32, 4, 128, 128, True, 1024),
+        "global-16k": (1, 16384, 32, 4, 128, 128, True, None)}[which]
+    bf16 = lambda heads, e: jax.ShapeDtypeStruct((b, L, heads, e),
+                                                 jnp.bfloat16)
+    if direction == "forward":
+        return (functools.partial(flash_attention_with_lse, causal=causal,
+                                  window=window),
+                [bf16(h, d), bf16(kv, d), bf16(kv, dv)])
+    return (functools.partial(flash_attention_bwd, causal=causal,
+                              window=window),
+            [bf16(h, d), bf16(kv, d), bf16(kv, dv), bf16(h, dv),
+             f32(b, h, L), bf16(h, dv)])
+
+
+def _grouped_narrow():
+    """Grouped heads narrower than a lane tile (8 on 2 of 64, float32, a
+    band of 300 keys over 1,024): a query head and its key/value head sit
+    differently in their tiles, so the kernels slice the heads' own
+    columns."""
+    from geomx_tpu.ops import flash_attention_bwd
+    q, kv = f32(1, 1024, 8, 64), f32(1, 1024, 2, 64)
+    return (functools.partial(flash_attention_bwd, causal=True, window=300),
+            [q, kv, kv, q, f32(1, 8, 1024), q])
+
+
+def _ring_hop(L):
+    from geomx_tpu.parallel._fused_block import _hop_pallas
+    qkv, ml = f32(8, L, 64), f32(8, L)
+    return ((lambda q, k, v, m, l, o: _hop_pallas(
+        q, k, v, m, l, o, 0.125, True, 128, False)),
+        [qkv, qkv, qkv, ml, ml, qkv])
+
+
+CASES = {
+    "flash_attention-f32-L100": lambda: _flash_fwd(100, jnp.float32),
+    "flash_attention-bf16-L1024": lambda: _flash_fwd(1024, jnp.bfloat16),
+    "flash_attention-bf16-L8192": lambda: _flash_fwd(8192, jnp.bfloat16),
+    "flash_attention_bwd-L100": lambda: _flash_bwd(100),
+    "flash_attention_bwd-L8192": lambda: _flash_bwd(8192),
+    "flash_attention-latent-192-128-L8192": lambda: _latent_fwd(8192),
+    "flash_attention_bwd-latent-192-128-L8192": lambda: _latent_bwd(8192),
+    "flash_attention_bwd-latent-192-128-L100": lambda: _latent_bwd(100),
+    "flash_attention-bf16-bert-layer": lambda: _cell_attention(
+        "bert", "forward"),
+    "flash_attention_bwd-bf16-bert-layer": lambda: _cell_attention(
+        "bert", "backward"),
+    "flash_attention-bf16-latent-sequence": lambda: _cell_attention(
+        "latent", "forward"),
+    "flash_attention_bwd-bf16-latent-sequence": lambda: _cell_attention(
+        "latent", "backward"),
+    "flash_attention-bf16-grouped-window-sequence": lambda: _cell_attention(
+        "window", "forward"),
+    "flash_attention_bwd-bf16-grouped-window-sequence":
+        lambda: _cell_attention("window", "backward"),
+    "flash_attention-bf16-grouped-global-sequence": lambda: _cell_attention(
+        "global", "forward"),
+    "flash_attention_bwd-bf16-grouped-global-sequence":
+        lambda: _cell_attention("global", "backward"),
+    "flash_attention-bf16-four-on-one-sequence": lambda: _cell_attention(
+        "share", "forward"),
+    "flash_attention_bwd-bf16-four-on-one-sequence":
+        lambda: _cell_attention("share", "backward"),
+    "flash_attention-bf16-grouped-window-1024-of-16k": lambda:
+        _cell_attention("window-16k", "forward"),
+    "flash_attention_bwd-bf16-grouped-window-1024-of-16k": lambda:
+        _cell_attention("window-16k", "backward"),
+    "flash_attention-bf16-grouped-global-16k": lambda: _cell_attention(
+        "global-16k", "forward"),
+    "flash_attention_bwd-bf16-grouped-global-16k": lambda: _cell_attention(
+        "global-16k", "backward"),
+    "flash_attention_bwd-f32-grouped-64-wide": lambda: _grouped_narrow(),
+    "fused_ring_hop-L1024": lambda: _ring_hop(1024),
+    "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_v5e_compiler_accepts(chip, case):
+    assert "tpu_custom_call" in checks.compiled_text(chip, *CASES[case]())
+
+
+@pytest.mark.parametrize("which,want", [
+    ("bert", {"flash_attention_fwd", "flash_attention_bwd"}),
+    ("latent", {"flash_attention_fwd", "flash_attention_bwd_dq",
+                "flash_attention_bwd_dkv"}),
+])
+def test_attention_kernels_carry_the_name_the_benchmark_reads(chip, which,
+                                                              want):
+    """`flash_attn_roofline_pct` finds the kernels by the prefix
+    `flash_attention` of their instruction names
+    (benchmark/layer_metrics/flash_attn_roofline_pct.PREFIXES): a kernel
+    under another name would leave the share's divisor short."""
+    calls = []
+    for direction in ("forward", "backward"):
+        calls += checks.kernel_calls(checks.compiled_text(
+            chip, *_cell_attention(which, direction)))
+    assert {c.split(".")[0] for c in calls} == want, calls
+    assert all(c.startswith("flash_attention") for c in calls)
+

@@ -1,0 +1,144 @@
+"""Ask the TPU's compiler, without a TPU (see
+``test_tpu_compile_engine.py``): the decoders' kernels and scans (KDA,
+SSD, the grouped-query mixer's streamed pass) at the decoder cells'
+shapes.  The held experts' are in ``test_tpu_compile_experts_forward.py``
+and ``test_tpu_compile_experts_backward.py``."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import tpu_compile_checks as checks
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_kda_scan(chip, direction):
+    """The chunked delta-rule scan (plain XLA: a `while` over the chunks
+    and the batched products around it) at the chip benchmark's widths,
+    one sequence of 8,192 tokens and 8 of the 32 heads of 128, bf16
+    operands: what the compiler makes of the sub-block layout, and that it
+    fits."""
+    from geomx_tpu.ops.kda import kda_chunked
+    wide = lambda dtype: jax.ShapeDtypeStruct((1, 8, 8192, 128), dtype,
+                                              sharding=chip)
+    args = [wide(jnp.float32), wide(jnp.float32), wide(jnp.bfloat16),
+            wide(jnp.float32),
+            jax.ShapeDtypeStruct((1, 8, 8192), jnp.float32, sharding=chip)]
+    run = functools.partial(kda_chunked, dtype=jnp.bfloat16)
+    compiled = jax.jit(checks.directed(run, direction)).lower(*args).compile()
+    assert " while(" in compiled.as_text()
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_kda_kernels(chip, direction):
+    """The scan's Pallas kernels (`ops/kda_pallas.py`) lowered native at
+    the chip benchmark's shape, one sequence of 8,192 tokens and all 32
+    heads of 128, bf16 operands, through the door `KDAMixer` calls: the
+    tiling of a chunk's slices, the sublane rolls of the decay pass, the
+    float32 products at `HIGHEST`, and the VMEM the plan asks of the
+    compiler (`vmem_limit_bytes`), forward and backward."""
+    from geomx_tpu.ops import dispatch, kda_pallas
+    wide = lambda dtype: jax.ShapeDtypeStruct((1, 32, 8192, 128), dtype,
+                                              sharding=chip)
+    args = [wide(jnp.float32), wide(jnp.float32), wide(jnp.bfloat16),
+            wide(jnp.float32),
+            jax.ShapeDtypeStruct((1, 32, 8192), jnp.float32, sharding=chip)]
+    run = lambda *a: dispatch.kda(*a, chunk=64, sub=16, dtype=jnp.bfloat16)
+    with dispatch.kernels("native"):
+        text = jax.jit(checks.directed(run, direction)).lower(
+            *args).compile().as_text()
+    assert "tpu_custom_call" in text and " while(" not in text
+    assert ("kda_scan_bwd" in text) == (direction == "backward")
+    plan = kda_pallas.kda_plan(8192, 32, 128, 128, 64, jnp.bfloat16)
+    assert (plan.heads, plan.chunks) == (4, 4)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_v5e_compiler_accepts_the_ssd_scan(chip, direction):
+    """The Mamba-2 scan in its chunkwise matrix form (plain XLA through
+    the door `Mamba2Mixer` calls) at the chip benchmark's widths, one
+    sequence of 8,192 tokens, the 16 heads of 64 and the one B/C group of
+    128 a chip holds, chunk 128, bf16 operands: no `while` (the hand-over
+    from chunk to chunk is one product), and it fits."""
+    from geomx_tpu.ops import dispatch
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=chip)
+    args = [on((1, 8192, 16, 64), jnp.bfloat16),
+            on((1, 8192, 16), jnp.float32), on((16,), jnp.float32),
+            on((1, 8192, 1, 128), jnp.bfloat16),
+            on((1, 8192, 1, 128), jnp.bfloat16)]
+    run = lambda *a: dispatch.ssd(*a, 128, jnp.bfloat16)
+    with dispatch.kernels("native"):
+        compiled = jax.jit(checks.directed(run, direction)).lower(
+            *args).compile()
+    assert " while(" not in compiled.as_text()
+    # a sequence's decay matrices and their cotangents, not gigabytes
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.parametrize("kind", ["window", "global"])
+def test_v5e_compiler_accepts_the_gqa_mixers_streamed_pass(chip, kind):
+    """`models/afmoe.GQAMixer` at the Trinity cell's shape (one sequence of
+    8,192 tokens, hidden 2,048, bf16, 32 query heads on 4 of 128; a window
+    of 2,048 with rotary, or global without), forward and gradient in one
+    program.  A window layer's q/k norm + rotary is the kernel pair of
+    `ops/gqa_elementwise.py`, each once and by the names
+    `tools/scope_ops.py --scope gqa/proj` lists them under, and its plans
+    fit their VMEM budget; a global layer's norm alone is XLA's (PERF.md,
+    PR 37).  Neither program holds a concatenate as large as q or k
+    (rotary's halves)."""
+    import math
+    import re
+    from geomx_tpu.models.afmoe import GQAMixer
+    from geomx_tpu.ops import gqa_elementwise as ge
+    length, hidden, heads, kv_heads, d = 8192, 2048, 32, 4, 128
+    mixer = GQAMixer(heads, kv_heads, d, 2048 if kind == "window" else None,
+                     10000.0 if kind == "window" else None, 1e-5,
+                     jnp.bfloat16)
+    _, text = checks.mixer_step_text(chip, mixer, length, hidden)
+    calls = [c.split(".")[0] for c in checks.kernel_calls(text)]
+    for name in ("gqa_norm_rotary_fwd", "gqa_norm_rotary_bwd"):
+        assert calls.count(name) == (kind == "window"), calls
+    wide = {length * heads * d, length * kv_heads * d}
+    for shape in re.findall(r"= \w+\[([\d,]+)\]\S* concatenate\(", text):
+        assert math.prod(int(n) for n in shape.split(",")) not in wide, shape
+    q, k = (1, length, heads, d), (1, length, kv_heads, d)
+    for backward in (False, True):
+        plan = ge.norm_rotary_plan(q, k, jnp.bfloat16, backward)
+        assert plan.tile >= 128 and plan.vmem_bytes <= ge.VMEM_BUDGET
+
+
+@pytest.mark.parametrize("kind", ["window", "global"])
+def test_v5e_compiler_accepts_the_mixer_under_either_table(chip, kind):
+    """`models/afmoe.GQAMixer` as `models/mellum.py` builds it, at the
+    fourth decoder cell's shape (one sequence of 16,384 tokens, hidden
+    2,304, bf16, 32 query heads on 4 of 128, no gate): a window layer (a
+    band of 1,024, plain rotary at theta 500,000) and a full one (YaRN's
+    table at the published numbers), forward and gradient in one program.
+    Either kind's q/k norm + rotary is the kernel pair of
+    `ops/gqa_elementwise.py`, each once: the tables are operands, so
+    YaRN's is no other kernel."""
+    from geomx_tpu.models.mellum import MellumConfig
+    from geomx_tpu.ops import gqa_elementwise as ge
+    length, hidden = 16384, 2304
+    cfg = MellumConfig(
+        vocab=24576, hidden=hidden, layers=(), num_heads=32, num_kv_heads=4,
+        head_dim=128, window=1024, rope_theta=500000.0, expert_width=896,
+        num_experts=64, experts_held=16, expert_offset=0, top_k=8,
+        yarn=ge.Yarn(500000.0, 16.0, 8192, 32.0, 1.0, 1.2772588722239782))
+    mixer = cfg.make_mixer(kind, jnp.bfloat16)
+    assert isinstance(mixer.rope, ge.Yarn) == (kind == "global")
+    params, text = checks.mixer_step_text(chip, mixer, length, hidden)
+    assert "gate_kernel" not in params
+    calls = [c.split(".")[0] for c in checks.kernel_calls(text)]
+    for name in ("gqa_norm_rotary_fwd", "gqa_norm_rotary_bwd",
+                 "flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert calls.count(name) == 1, calls
+    for backward in (False, True):
+        plan = ge.norm_rotary_plan((1, length, 32, 128), (1, length, 4, 128),
+                                   jnp.bfloat16, backward)
+        assert plan.tile >= 128 and plan.vmem_bytes <= ge.VMEM_BUDGET
+

@@ -1,0 +1,25 @@
+"""Ask the TPU's compiler, without a TPU (see
+``test_tpu_compile_engine.py``): the held experts' walk at the decoder
+cells' sizes, forward.  A case is half a minute of libtpu: backward is a file
+of its own, so that two workers share them."""
+
+import pytest
+
+import tpu_compile_checks as checks
+
+
+@pytest.mark.parametrize("direction", ["forward"])
+def test_v5e_compiler_accepts_the_ungated_held_experts(chip, direction):
+    checks.held_experts_accepted(chip, checks.UNGATED_EXPERTS, direction,
+                                 top_k=22, gated=False)
+
+
+@pytest.mark.parametrize("direction", ["forward"])
+@pytest.mark.parametrize("cell", sorted(checks.HELD_EXPERTS))
+def test_v5e_compiler_accepts_the_held_experts(chip, cell, direction):
+    checks.held_experts_accepted(chip, checks.HELD_EXPERTS[cell], direction)
+
+
+@pytest.mark.parametrize("cell", sorted(checks.HELD_EXPERTS))
+def test_the_row_kernel_carries_the_name_the_docs_give(chip, cell):
+    checks.row_kernel_carries_its_name(chip, cell)
