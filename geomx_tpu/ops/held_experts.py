@@ -18,7 +18,8 @@ expert's share; a caller whose experts see more gives twice its own even
 load, a multiple of ``rows``) and is always walked, so up to twice even
 load the layer's program does not change with what the router does.  The
 pool's rows come in by XLA's row gather, which costs what its bytes cost
-inside a program (6 ns a place on a v5e, PERF.md), and go back through
+inside a program (6 ns a place on a v5e, PERF.md; a place past the runs
+reads a real row too, so the gather has nothing to fill), and go back through
 ``ops.dispatch.row_scatter_add`` (both under scope ``moe/dispatch``): on
 a TPU, at a width of whole tiles (a multiple of 1,024: the door says
 why), the kernel of ``ops/moe_rows_pallas.py``, two copies a row that
@@ -45,6 +46,16 @@ The grouped products skip a pool's tiles that hold no assignment and the
 row moves pay by the row, so a layer's time follows the router's load
 (0.16 ms a tile of 512 forward and backward at K = 1,024, N = 2,688 on a
 v5e: PERF.md, PR 38).
+
+Nothing is masked or scaled at ``[places, width]``.  The grouped products
+write and read the runs' rows only (a boundary tile's other rows are kept
+out by a select in the kernel, never by a product), and the scatter-add
+drops every place past the runs, so those rows of a pool's arrays hold
+whatever the kernels left there, NaN included, and no pass zeroes them.
+A routing weight is linear through the second product, so it goes into
+the hidden layer inside the fusion that makes it; the one sum over a
+row that leaves the pool, the weights' gradient, is masked as a
+``[places]`` vector, by a select.
 
 A loop with a data-dependent trip count has no reverse-mode derivative in
 JAX, so the backward pass is written out (`custom_vjp`): the same walk,
@@ -90,7 +101,8 @@ def _tile(n: int, cap: int) -> int:
 
 def _gmm(lhs, rhs, sizes, rows, interpret, transpose_rhs=False):
     """lhs [m, k] x rhs [E, k, n] (or [E, n, k], transposed) by runs of
-    `sizes` rows -> [m, n] float32; rows past the runs are not written."""
+    `sizes` rows -> [m, n] float32; rows past the runs are neither read
+    into a result nor written: they hold what the buffer held."""
     k = lhs.shape[1]
     n = rhs.shape[1] if transpose_rhs else rhs.shape[2]
     return gmm(lhs, rhs, sizes, jnp.float32,
@@ -159,27 +171,32 @@ def places_walked(assignments, num_held: int, rows: int, pool=None):
 
 
 def _pool(plan, lo, pool: int):
-    """(token ids, weights, valid, rows of each expert's run) of the
-    `pool` sorted assignments from `lo` on.  Rows past the last held
-    assignment point outside the token range (a gather fills them with
-    zeros, a scatter drops them)."""
+    """(the tokens to gather, the tokens to scatter to, weights, valid,
+    rows of each expert's run) of the `pool` sorted assignments from `lo`
+    on.  A place past the last held assignment gathers a real row (the
+    token of an assignment held elsewhere, or of the padding: nothing
+    reads what it gives) and scatters outside the token range (dropped)."""
     i = jnp.arange(pool, dtype=jnp.int32)
     valid = lo + i < plan["ends"][-1]
-    token = jnp.where(valid, lax.dynamic_slice(plan["token"], (lo,), (pool,)),
-                      plan["tokens"] + i)
-    weight = jnp.where(valid,
-                       lax.dynamic_slice(plan["weight"], (lo,), (pool,)), 0.0)
+    source = lax.dynamic_slice(plan["token"], (lo,), (pool,))
+    target = jnp.where(valid, source, plan["tokens"] + i)
+    weight = lax.dynamic_slice(plan["weight"], (lo,), (pool,))
     ends = jnp.clip(plan["ends"], lo, lo + pool)
     starts = jnp.clip(plan["ends"] - plan["counts"], lo, lo + pool)
-    return token, weight, valid, ends - starts
+    return source, target, weight, valid, ends - starts
 
 
-def _hidden(xs, into, sizes, valid, rows, interpret, gated: bool):
+def _gather(a, source):
+    """The pool's rows of `a` [T, d]; every id of `source` is a token's."""
+    with profile_scope(_DISPATCH, "kernel"):
+        return a.at[source].get(mode="promise_in_bounds")
+
+
+def _hidden(xs, into, sizes, rows, interpret, gated: bool):
     """(the first product ``xs`` x ``into``, the hidden layer it gives) of
-    a pool, float32; zeros in rows of no run.  Gated: the product is
-    [a, u] and the hidden layer silu(a) * u; else relu(a)^2."""
-    pre = jnp.where(valid[:, None],
-                    _gmm(xs, into, sizes, rows, interpret), 0.0)
+    a pool, float32, in the runs' rows.  Gated: the product is [a, u] and
+    the hidden layer silu(a) * u; else relu(a)^2."""
+    pre = _gmm(xs, into, sizes, rows, interpret)
     if not gated:
         return pre, jnp.square(jax.nn.relu(pre))
     a, u = jnp.split(pre, 2, axis=-1)
@@ -227,14 +244,14 @@ def _forward(x, idx, weights, gate, up, down, offset, rows, interpret, pool):
 
     def body(lo, places, carry):
         y, done = carry
-        token, weight, valid, sizes = _pool(plan, lo, places)
+        source, target, weight, _, sizes = _pool(plan, lo, places)
+        xs = _gather(x, source)
+        h = _hidden(xs, into, sizes, rows, interpret, gated)[1]
+        # (w h) W_down = w (h W_down): the weight at the hidden width
+        out = _gmm((h * weight[:, None]).astype(x.dtype), down, sizes, rows,
+                   interpret)
         with profile_scope(_DISPATCH, "kernel"):
-            xs = x.at[token].get(mode="fill", fill_value=0)
-        h = _hidden(xs, into, sizes, valid, rows, interpret, gated)[1]
-        out = _gmm(h.astype(x.dtype), down, sizes, rows, interpret)
-        out = jnp.where(valid[:, None], out * weight[:, None], 0.0)
-        with profile_scope(_DISPATCH, "kernel"):
-            y = row_scatter_add(y, out, token, sizes)
+            y = row_scatter_add(y, out, target, sizes)
         return y, done + jnp.sum(sizes)
 
     y, done = _walk(
@@ -260,24 +277,23 @@ def _bwd(offset, rows, interpret, pool, res, cotangents):
 
     def body(lo, places, carry):
         dx, dw, dinto, ddown = carry
-        token, weight, valid, sizes = _pool(plan, lo, places)
-        with profile_scope(_DISPATCH, "kernel"):
-            xs = x.at[token].get(mode="fill", fill_value=0)
-            dys = dy.at[token].get(mode="fill", fill_value=0)
-        pre, h = _hidden(xs, into, sizes, valid, rows, interpret, gated)
+        source, target, weight, valid, sizes = _pool(plan, lo, places)
+        xs, dys = _gather(x, source), _gather(dy, source)
+        pre, h = _hidden(xs, into, sizes, rows, interpret, gated)
         # <h W_down, dy> = <h, dy W_down^T>: one product gives the weight's
         # gradient and, scaled by the weight, the hidden layer's
-        g = jnp.where(valid[:, None],
-                      _gmm(dys, down_c, sizes, rows, interpret, True), 0.0)
-        dw = lax.dynamic_update_slice(dw, jnp.sum(h * g, axis=-1), (lo,))
+        g = _gmm(dys, down_c, sizes, rows, interpret, True)
+        # a select, not a product: a place past the runs may hold NaN
+        dw = lax.dynamic_update_slice(
+            dw, jnp.where(valid, jnp.sum(h * g, axis=-1), 0.0), (lo,))
         dpre = _hidden_bwd(pre, g * weight[:, None], gated).astype(x.dtype)
-        dout = (dys * weight[:, None]).astype(x.dtype)
-        ddown = _tgmm(h.astype(x.dtype), dout, sizes, rows, interpret, ddown)
+        # the operand the forward pass rounded, so no dys * weight is made
+        ddown = _tgmm((h * weight[:, None]).astype(x.dtype), dys, sizes,
+                      rows, interpret, ddown)
         dinto = _tgmm(xs, dpre, sizes, rows, interpret, dinto)
-        dxs = jnp.where(valid[:, None],
-                        _gmm(dpre, into, sizes, rows, interpret, True), 0.0)
+        dxs = _gmm(dpre, into, sizes, rows, interpret, True)
         with profile_scope(_DISPATCH, "kernel"):
-            dx = row_scatter_add(dx, dxs, token, sizes)
+            dx = row_scatter_add(dx, dxs, target, sizes)
         return dx, dw, dinto, ddown
 
     zeros = lambda a: jnp.zeros(a.shape, jnp.float32)

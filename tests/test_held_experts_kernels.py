@@ -5,15 +5,17 @@ one tile across run boundaries, rows of the cells' widths, and
 against without it.  Interpret mode completes a copy at its
 start, so it holds the arithmetic, the tails' masks and the segment walk,
 not the overlap of copies in flight: the chip does (`chip_smoke.py`,
-`tools/held_experts_timing.py --pieces`).  The plan's sorts are in
-`test_held_experts_plan.py`."""
+`tools/held_experts_timing.py --pieces`).  The layer with every place past
+the runs poisoned (nothing there is masked at `[places, width]`) against
+a dense oracle.  The plan's sorts are in `test_held_experts_plan.py`."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from geomx_tpu.ops import dispatch, moe_rows_pallas as rows_ops
+from geomx_tpu.ops import dispatch, held_experts as layer
+from geomx_tpu.ops import moe_rows_pallas as rows_ops
 from geomx_tpu.ops.held_experts import held_experts
 
 TOKENS = 96
@@ -143,3 +145,72 @@ def test_held_experts_through_the_door(dtype, tolerance, rows, pool):
         np.testing.assert_allclose(
             np.asarray(got, np.float32), want,
             atol=tolerance * max(1.0, float(np.max(np.abs(want)))))
+
+
+@pytest.mark.parametrize("pool", [256, 32],
+                         ids=["first_pool_only", "later_pools"])
+@pytest.mark.parametrize("d", [2048, 2304],
+                         ids=["2048_the_kernel", "2304_xlas_scatter_add"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_places_past_the_runs_are_never_read(gated, d, pool, monkeypatch):
+    """Every place past the runs of every grouped product's result is set
+    to NaN (a pool's body masks and scales nothing at `[places, width]`:
+    the products and the scatter-add leave those rows alone): y and all
+    five gradients are finite and the dense oracle's, which weighs the
+    hidden layer before the second product as the layer does.  Both
+    expert forms, both ways back (2,048: the row kernel interpreted;
+    2,304: XLA's scatter-add), a first pool that holds everything and one
+    that overflows into later pools whose last is part empty."""
+    rng = np.random.default_rng(d + pool)
+    tokens, width, held, offset, top_k, router, rows = 96, 64, 4, 3, 4, 12, 16
+    x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+    idx = routing(rng, tokens, top_k, router)
+    w = jnp.asarray(rng.uniform(0.1, 0.5, (tokens, top_k)), jnp.float32)
+    mats = [jnp.asarray(rng.standard_normal(shape) * 0.1, jnp.float32)
+            for shape in ((held, d, width), (held, d, width),
+                          (held, width, d))]
+    if not gated:
+        mats[0] = None
+    r = jnp.asarray(rng.standard_normal((tokens, d)), jnp.float32)
+    product = layer._gmm
+
+    def poisoned(lhs, rhs, sizes, *rest):
+        out = product(lhs, rhs, sizes, *rest)
+        past = jnp.arange(out.shape[0]) >= jnp.sum(sizes)
+        return jnp.where(past[:, None], jnp.nan, out)
+
+    def ours(x_, w_, *m):
+        y, counts, dropped = held_experts(x_, idx, w_, *m, offset, rows,
+                                          None, pool)
+        return jnp.sum(y * r), (y, counts, dropped)
+
+    def dense(x_, w_, gate, up, down):
+        y = jnp.zeros_like(x_)
+        for e in range(held):
+            weight = jnp.sum(jnp.where(idx == offset + e, w_, 0.0), axis=-1)
+            h = x_ @ up[e]
+            h = (jnp.square(jax.nn.relu(h)) if gate is None
+                 else jax.nn.silu(x_ @ gate[e]) * h)
+            y = y + (weight[:, None] * h) @ down[e]
+        return jnp.sum(y * r), y
+
+    argnums = tuple(i for i, m in enumerate((x, w, *mats)) if m is not None)
+    monkeypatch.setattr(layer, "_gmm", poisoned)
+    with dispatch.kernels("interpret"):
+        (_, (y, counts, dropped)), got = jax.jit(jax.value_and_grad(
+            ours, argnums=argnums, has_aux=True))(x, w, *mats)
+    (_, want_y), want = jax.jit(jax.value_and_grad(
+        dense, argnums=argnums, has_aux=True))(x, w, *mats)
+    arrived = int(jnp.sum(counts))
+    assert int(dropped) == 0 and rows_ops.slabs_are_whole(d) == (d == 2048)
+    # places past the runs: in the first pool, or in the last later one
+    assert (pool - arrived if pool == 256
+            else -(arrived - pool) % (2 * rows)) > 0
+    for name, ours_, theirs in zip(
+            ("y", "dx", "dweights", *(["dgate"] * gated), "dup", "ddown"),
+            (y, *got), (want_y, *want)):
+        assert bool(jnp.all(jnp.isfinite(ours_))), name
+        theirs = np.asarray(theirs, np.float32)
+        np.testing.assert_allclose(
+            np.asarray(ours_, np.float32), theirs, err_msg=name,
+            atol=3e-5 * max(1.0, float(np.max(np.abs(theirs)))))

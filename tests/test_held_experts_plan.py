@@ -1,11 +1,13 @@
 """The held experts' plan (`ops/held_experts._plan`): token and weight ride
 its one sort and `dweights` comes back by a sort, bit for bit what the
-argsort, gather and scatter they replaced gave (PR 39), and nothing in the
-layer's jaxpr gathers or scatters over the routed assignments.  The row
+argsort, gather and scatter they replaced gave (PR 39), nothing in the
+layer's jaxpr gathers or scatters over the routed assignments, and nothing
+in its lowered program selects or multiplies at `[places, d]`.  The row
 kernel and the layer through the door are in
 `test_held_experts_kernels.py`: two files, so that two workers share the
 compiles."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,7 @@ import numpy as np
 import pytest
 
 import decoder_checks as checks
+from geomx_tpu.ops import dispatch
 from geomx_tpu.ops.held_experts import held_experts
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -168,6 +171,43 @@ def test_no_gather_or_scatter_over_the_routed_assignments(gated):
     assert not any(hit for _, hit in moves), moves
 
 
+@pytest.mark.parametrize("d", [2048, 2304])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_no_select_or_multiply_over_a_pools_rows(gated, d):
+    """The layer's forward and backward, lowered for a TPU (the grouped
+    products and, at 2,048, the row kernel are custom calls), hold no
+    `select` and no `multiply` whose result is `[places, d]`, for the
+    first pool's places or a later pool's: a pool's body masks and scales
+    at the hidden width and at `[places]` only, and a gather that cannot
+    miss has nothing to fill.  (The parent's had five `where` passes, a
+    `dys * weight` and three fill selects a pool.)"""
+    rng = np.random.default_rng(7)
+    tokens, width, held, top_k, router, rows, pool = 40, 128, 4, 4, 12, 8, 64
+    idx = _routing_with(rng, tokens, top_k, held, router, "some")[0]
+    w = jnp.asarray(rng.uniform(0.1, 0.5, (tokens, top_k)), jnp.float32)
+    x = jnp.asarray(rng.standard_normal((tokens, d)), jnp.bfloat16)
+    mats = [jnp.zeros(shape, jnp.float32) for shape in (
+        (held, d, width), (held, d, width), (held, width, d))]
+    if not gated:
+        mats[0] = None
+
+    def loss(x_, w_, *m):
+        return jnp.sum(held_experts(x_, idx, w_, *m, 0, rows, None, pool)[0])
+
+    argnums = tuple(i for i, m in enumerate((x, w, *mats)) if m is not None)
+    with dispatch.kernels("native"):
+        text = jax.jit(jax.value_and_grad(loss, argnums=argnums)).trace(
+            x, w, *mats).lower(lowering_platforms=("tpu",)).as_text()
+    wide = re.compile(rf"tensor<({pool}|{2 * rows})x{d}x(f32|bf16)>$")
+    ops = [line.strip() for line in text.splitlines()
+           if wide.search(line.rstrip())]
+    assert len(ops) >= 16, "a pool's rows are there: gathers, products"
+    assert sum("tpu_custom_call" in op for op in ops) >= 4
+    passes = [op for op in ops
+              if re.search(r"stablehlo\.(select|multiply)\b", op)]
+    assert not passes, passes
+
+
 def test_the_timing_tools_plan_pieces_agree_off_the_chip(monkeypatch, capsys):
     """`tools/held_experts_timing.py --pieces`' plan pieces at a small
     size: off a TPU no time is printed, but each pair's outputs (the one
@@ -187,4 +227,27 @@ def test_the_timing_tools_plan_pieces_agree_off_the_chip(monkeypatch, capsys):
         ("plan_sort", 1056), ("dweights_back", 1056)]
     assert all(line["unequal"] == 0 and 0 < line["arrived"] < line[
         "assignments"] for line in lines)
+    assert not any(key.endswith("_ms") for line in lines for key in line)
+
+
+def test_the_timing_tools_scatter_by_places_runs_off_the_chip(monkeypatch,
+                                                              capsys):
+    """`tools/held_experts_timing.py --pieces`' scatter-add by places at a
+    small size: off a TPU every size and load's program runs, and neither
+    a time nor the line through the sizes is printed."""
+    import json
+    import types
+    monkeypatch.syspath_prepend(os.path.join(ROOT, "tools"))
+    import held_experts_timing as tool
+    monkeypatch.setattr(tool, "SCATTER_PLACES", (16, 32, 64))
+    monkeypatch.setattr(tool, "SCATTER_WIDTH", 256)
+    tool.scatter_by_places(types.SimpleNamespace(reps=1), 24,
+                           np.random.default_rng(0))
+    out = capsys.readouterr().out
+    lines = [json.loads(line) for line in out.split("\n")
+             if line.startswith("{")]
+    assert [(line["places"], line["load"], line["real"]) for line in lines
+            ] == [(16, "none", 0), (16, "half", 8), (32, "none", 0),
+                  (32, "half", 16), (64, "none", 0), (64, "half", 32)]
+    assert "Line" not in out
     assert not any(key.endswith("_ms") for line in lines for key in line)

@@ -29,7 +29,11 @@ repeated over the held experts and scaled to the file's even load.
 (XLA's, and the kernel of `ops/moe_rows_pallas.py`) alone at four loads,
 many calls in one program with the operands made inside it (a host-timed
 call costs ~0.6 ms whatever it does, and reads cold operands), every row
-of the kernel's compared with XLA's (exit 2 where they differ); then the
+of the kernel's compared with XLA's (exit 2 where they differ), and XLA's
+scatter-add at 2,304 wide over 8,192, 32,768 and 65,536 places
+(`SCATTER_PLACES`: the Kimi cell's first pool, the Mellum cell's even load
+and its first pool), with the line through the three that says what part
+of a call is fixed; then the
 plan's pieces at the cells' 131,072 and 360,448 routed assignments
 (`PLAN_SIZES`): the sort that carries index and weight against `argsort`
 and a 1-D gather of the weights, and the sort that brings `dweights` back
@@ -106,16 +110,37 @@ PIECE_LOADS = ("none", "even", "seeded", "one_takes_all")
 CALLS = 8       # of a piece in one timed program
 
 
+def every_call(move):
+    """One program of (token, sizes, *operands): `CALLS` times make the
+    operands (one elementwise pass behind an optimization barrier: what
+    the step's layers leave the move) and move them (None: make them
+    only)."""
+    import jax
+    import jax.numpy as jnp
+
+    def one(c, acc, token, sizes, operands):
+        step = 1.0 + c.astype(jnp.float32) * 2.0 ** -10
+        made = jax.lax.optimization_barrier(tuple(
+            (a * step.astype(a.dtype)) for a in operands))
+        moved = jax.lax.optimization_barrier(
+            made[0] if move is None else move(*made, token, sizes))
+        return acc + moved[0, 0].astype(jnp.float32)
+    return jax.jit(lambda token, sizes, *operands: jax.lax.fori_loop(
+        0, CALLS, lambda c, acc: one(c, acc, token, sizes, operands),
+        jnp.zeros((), jnp.float32)))
+
+
 def pieces(args, x, r, by_load, pool, rng) -> bool:
     """The first pool's two moves alone: XLA's row gather, and the
     scatter-add as XLA's (plain and with `unique_indices`) beside the
     kernel (`ops/moe_rows_pallas.py`), into a made y and into the zeros a
     walk starts from (the kernel's relayout of y is then nothing).
     `CALLS` calls share one jitted program that makes each call's operands
-    inside it (one elementwise pass behind an optimization barrier: what
-    the step's layers leave the move), and the making, timed alone, is
-    taken off.  One JSON line a
-    load and piece; False where the kernel's rows differ from XLA's."""
+    inside it (`every_call`), and the making, timed alone, is taken off.
+    The gather as the layer does it (every id a token's, nothing to fill)
+    beside the parent's (ids past the runs out of range, filled with
+    zeros by a select over the pool).  One JSON line a load and piece;
+    False where the kernel's rows differ from XLA's."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -135,22 +160,10 @@ def pieces(args, x, r, by_load, pool, rng) -> bool:
         return (jnp.asarray(np.concatenate(runs + [pad]), jnp.int32),
                 jnp.asarray(sizes, jnp.int32))
 
-    def every_call(move, operands):
-        """One program: `CALLS` times make the operands and move them
-        (None: make them only)."""
-        def one(c, acc, token, sizes):
-            step = 1.0 + c.astype(jnp.float32) * 2.0 ** -10
-            made = jax.lax.optimization_barrier(tuple(
-                (a * step.astype(a.dtype)) for a in operands))
-            moved = jax.lax.optimization_barrier(
-                made[0] if move is None else move(*made, token, sizes))
-            return acc + moved[0, 0].astype(jnp.float32)
-        return jax.jit(lambda token, sizes: jax.lax.fori_loop(
-            0, CALLS, lambda c, acc: one(c, acc, token, sizes),
-            jnp.zeros((), jnp.float32)))
-
     gathers = {
-        "xla": lambda x_, token, sizes: x_.at[token].get(
+        "xla": lambda x_, token, sizes: x_.at[token % t].get(
+            mode="promise_in_bounds"),
+        "xla_fill": lambda x_, token, sizes: x_.at[token].get(
             mode="fill", fill_value=0)}
     scatters = {
         "xla": lambda y, out, token, sizes: rows_ops.row_scatter_add_ref(
@@ -169,9 +182,8 @@ def pieces(args, x, r, by_load, pool, rng) -> bool:
             ("scatter_add_into_zeros",
              {name: zeros(move) for name, move in scatters.items()},
              (out0,))):
-        make = every_call(None, operands)
-        programs = {name: every_call(move, operands)
-                    for name, move in moves.items()}
+        make = every_call(None)
+        programs = {name: every_call(move) for name, move in moves.items()}
         for load in PIECE_LOADS:
             token, sizes = sorted_places(by_load[load])
             real = int(jnp.sum(sizes))
@@ -189,16 +201,74 @@ def pieces(args, x, r, by_load, pool, rng) -> bool:
                 ok = ok and line["kernel_largest_gap"] <= 1e-5 * max(
                     1.0, float(jnp.max(jnp.abs(want))))
             if not interpret:
-                made = median_ms(make, (token, sizes), args.reps)
+                made = median_ms(make, (token, sizes, *operands), args.reps)
                 line["make_ms"] = made / CALLS
                 for name, fn in programs.items():
                     line[name + "_ms"] = (median_ms(
-                        fn, (token, sizes), args.reps) - made) / CALLS
+                        fn, (token, sizes, *operands), args.reps)
+                        - made) / CALLS
                 line["xla_ns_a_place"] = 1e6 * line["xla_ms"] / pool
                 if "kernel" in moves and real:
                     line["kernel_ns_a_row"] = 1e6 * line["kernel_ms"] / real
             print(json.dumps(line), flush=True)
     return ok
+
+
+# XLA's scatter-add where the row kernel is not taken (hidden 2,304): the
+# Kimi cell's first pool, the Mellum cell's even load and its first pool
+SCATTER_PLACES = (8192, 32768, 65536)
+SCATTER_WIDTH = 2304
+
+
+def scatter_by_places(args, tokens, rng) -> None:
+    """XLA's scatter-add of `[places, 2304]` float32 rows into the zeros a
+    walk starts from, for each of `SCATTER_PLACES`, half the places holding
+    a row (tokens drawn with repeats, as two held picks a token give) and
+    none, timed as `pieces` times its moves; then the least-squares line
+    ms = fixed + places x slope through the three sizes a load: a fixed
+    part that is most of a small pool's call says a split or chunked way
+    back would pay it again, a line through zero that it would cost
+    nothing.  One JSON line a size and load, one `Line` a load; off a TPU
+    the programs run and no time is printed."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from geomx_tpu.ops import moe_rows_pallas as rows_ops
+
+    on_chip = jax.default_backend() == "tpu"
+    move = lambda out, token, sizes: rows_ops.row_scatter_add_ref(
+        jnp.zeros((tokens, SCATTER_WIDTH), jnp.float32), out, token, None)
+    make, scatter = every_call(None), every_call(move)
+    times = {"none": [], "half": []}
+    for places in SCATTER_PLACES:
+        out = jnp.asarray(rng.standard_normal((places, SCATTER_WIDTH)),
+                          jnp.float32)
+        for load, real in (("none", 0), ("half", places // 2)):
+            token = jnp.asarray(np.concatenate([
+                rng.integers(0, tokens, real),
+                tokens + np.arange(places - real)]), jnp.int32)
+            sizes = jnp.asarray([real], jnp.int32)
+            line = {"piece": "xla_scatter_add_by_places", "places": places,
+                    "d": SCATTER_WIDTH, "tokens": tokens, "load": load,
+                    "real": real}
+            if on_chip:
+                made = median_ms(make, (token, sizes, out), args.reps)
+                line["make_ms"] = made / CALLS
+                line["xla_ms"] = (median_ms(scatter, (token, sizes, out),
+                                            args.reps) - made) / CALLS
+                line["xla_ns_a_place"] = 1e6 * line["xla_ms"] / places
+                times[load].append(line["xla_ms"])
+            else:
+                jax.block_until_ready(scatter(token, sizes, out))
+            print(json.dumps(line), flush=True)
+    if on_chip:
+        for load, ms in times.items():
+            slope, fixed = np.polyfit(SCATTER_PLACES, ms, 1)
+            print("Line " + json.dumps({
+                "piece": "xla_scatter_add_by_places", "load": load,
+                "fixed_ms": float(fixed), "ns_a_place": 1e6 * float(slope),
+                "fixed_share_of_smallest": float(fixed) / ms[0]}),
+                flush=True)
 
 
 # (tokens, experts a token, held, routed over): the Trinity cell's routed
@@ -236,7 +306,7 @@ def plan_pieces(args, rng) -> bool:
     def sort_back(order, dw):
         return (lax.sort((order, dw), num_keys=1, is_stable=False)[1],)
 
-    def every_call(move, make):
+    def every_plan_call(move, make):
         def one(c, acc, *operands):
             made = lax.optimization_barrier(make(c, *operands))
             moved = lax.optimization_barrier(
@@ -277,11 +347,12 @@ def plan_pieces(args, rng) -> bool:
                     "arrived": int(jnp.sum(key < held)),
                     "unequal": unequal}
             if on_chip:
-                made = median_ms(every_call(None, make), operands, args.reps)
+                made = median_ms(every_plan_call(None, make), operands,
+                                 args.reps)
                 line["make_ms"] = made / CALLS
                 for move in moves:
                     line[move.__name__ + "_ms"] = (median_ms(
-                        every_call(move, make), operands, args.reps)
+                        every_plan_call(move, make), operands, args.reps)
                         - made) / CALLS
                 line["ns_an_index_saved"] = 1e6 * (
                     line[old + "_ms"] - line[new + "_ms"]) / n
@@ -319,8 +390,9 @@ def main(argv=None) -> int:
     parser.add_argument("--loads", default=",".join(LOADS))
     parser.add_argument("--pieces", action="store_true",
                         help="time a pool's gather and scatter-add alone, "
-                             "and the plan's sorts against the 1-D gather "
-                             "and scatter they replaced")
+                             "XLA's scatter-add at 2,304 by places, and the "
+                             "plan's sorts against the 1-D gather and "
+                             "scatter they replaced")
     args = parser.parse_args(argv)
 
     import jax
@@ -400,6 +472,7 @@ def main(argv=None) -> int:
                     flush=True)
     if args.pieces:
         ok = pieces(args, x, r, by_load, first_pool(shapes[0]), rng)
+        scatter_by_places(args, t, rng)
         ok = plan_pieces(args, rng) and ok
     if args.other:
         spec = importlib.util.spec_from_file_location("other", args.other)
