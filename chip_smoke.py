@@ -3,8 +3,8 @@
 
 One process drives the main path once through the entry points a user
 calls, at the full width of the flagship (ResNet-20 for CIFAR-10: 3x3
-stages of 16/32/64 channels, 272,474 parameters) and the bench's own
-per-chip batch (2,048), on seeded synthetic CIFAR-shaped uint8 data
+stages of 16/32/64 channels, 272,474 parameters) and a per-chip
+batch of 2,048, on seeded synthetic CIFAR-shaped uint8 data
 asked for by name.  It checks what comes out and fails on the first
 phase that is wrong; there is no fallback anywhere in it.
 
@@ -61,19 +61,28 @@ import numpy as np
 
 FLAGSHIP_PARAMS = 272_474
 PER_CHIP_BATCH = 2048
-# the paper's five, by their names in bench.py's own table
-FIVE = ("vanilla_local", "dist_sync_hips", "bsc", "fp16_mpq", "hfa_dgt")
+# the paper's five (BASELINE.json's configs) as GeoConfig overrides.
+# hfa_dgt: 3 deferral channels with k=0.5 (the reference's
+# scripts/cpu/run_dgt.sh runs DMLC_UDP_CHANNEL_NUM=3), so that two steps
+# in three move the top half of the blocks and the third drains
+CONFIGS = {
+    "vanilla_local": {"sync_mode": "fsa", "compression": "none"},
+    "dist_sync_hips": {"sync_mode": "fsa", "compression": "none"},
+    "bsc": {"sync_mode": "fsa", "compression": "bsc,0.01"},
+    "fp16_mpq": {"sync_mode": "fsa", "compression": "mpq,0.01"},
+    "hfa_dgt": {"sync_mode": "hfa", "hfa_k1": 20, "hfa_k2": 10,
+                "enable_dgt": 2, "udp_channel_num": 3, "dgt_k": 0.5,
+                "compression": "none"},
+}
+FIVE = tuple(CONFIGS)
 # those whose dc tier runs the fused bucket/BSC kernels on a TPU
 # (hfa_dgt's tree-level DGT fuses the gradient tree itself)
 FUSED_CONFIGS = ("vanilla_local", "dist_sync_hips", "bsc", "fp16_mpq")
 
 
 def config_overrides(name: str) -> dict:
-    """The GeoConfig overrides of one of bench.py's configs — read from
-    bench.py, so the smoke and the bench cannot drift apart."""
-    import bench
-    return dict(next(ov for n, ov, _parties in bench._build_configs(1)
-                     if n == name))
+    """The GeoConfig overrides of one of the five configs (a copy)."""
+    return dict(CONFIGS[name])
 
 
 class SmokeFailure(AssertionError):

@@ -31,7 +31,7 @@ def run(extra_args=(), config_fn=lambda a: {}, sync_default="fsa"):
     if args.cpu:
         jax.config.update("jax_platforms", "cpu")
     # persistent compile cache: repeat demo runs start warm (same
-    # directory as bench.py and the tests, whatever the launch cwd)
+    # directory as the tests, whatever the launch cwd)
     from geomx_tpu.utils import enable_compile_cache
     enable_compile_cache()
 

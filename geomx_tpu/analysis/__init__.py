@@ -3,7 +3,7 @@
 - ``core``   — jaxpr walker, pass framework, ``Finding``s, severity gate
 - ``passes`` — collective-consistency (+ ``audit_cross_party``),
   donation/aliasing, dtype-flow & wire accounting, compressed-path purity
-- ``hlo``    — lowered-HLO assertions (the --compare-kernels matchers)
+- ``hlo``    — lowered-HLO assertions (fused-vs-unfused matchers)
 - ``corpus`` — seeded known-bad programs the auditor must flag
 
 Trace-hygiene linting for the repo's own sources lives in

@@ -2,7 +2,7 @@
 
 Three PRs in a row hand-rolled one-off static checks — PR 4's "dense
 scatter/cumsum ops are GONE from the lowered HLO" regression, PR 5's
-byte-identical-jaxpr telemetry guarantee, bench's DCE-based collective
+byte-identical-jaxpr telemetry guarantee, a DCE-based collective
 counting — because the correctness properties this system lives on are
 *program-shape* properties, not runtime ones: every party must execute
 the same collective sequence (or the mesh deadlocks/diverges silently,
@@ -217,7 +217,7 @@ def enforce(findings: Sequence[Finding], gate: str = "error") -> List[Finding]:
 
 
 def summarize(findings: Sequence[Finding]) -> Dict[str, int]:
-    """Finding counts per rule id (the shape bench --audit emits)."""
+    """Finding counts per rule id."""
     out: Dict[str, int] = {}
     for f in findings:
         out[f.rule_id] = out.get(f.rule_id, 0) + 1

@@ -3,9 +3,8 @@
 Each entry builds a minimal program exhibiting one defect class from
 the pass catalog and runs the matching audit entry point.  The corpus
 is the auditor's own regression suite — tests/test_analysis.py asserts
-every entry is flagged with the right rule id, and ``bench.py --audit``
-replays it in CI so a pass that silently stops firing fails the gate,
-not a production trace.
+every entry is flagged with the right rule id, so a pass that silently
+stops firing fails the gate, not a production trace.
 
 Entries (name -> expected rule):
 

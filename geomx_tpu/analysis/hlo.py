@@ -2,12 +2,12 @@
 
 PR 4's fused-kernel regression ("the ops that materialize a dense
 gradient-sized intermediate are GONE from the fused graphs") lived as
-private string matchers duplicated between ``bench.py`` and
-``tests/test_bsc_pallas.py``.  This module is the single owner: cross-
+private string matchers in ``tests/test_bsc_pallas.py`` and a harness
+since removed.  This module is the single owner: cross-
 lower a function for the TPU platform on any host (the same ``jax.export``
 mechanism as the Mosaic lowering guards), count the HBM-materializing
 stablehlo ops in the module text, and render the fused-vs-unfused
-verdict bench's ``--compare-kernels`` mode reports and the tests assert.
+verdict the tests assert.
 """
 
 from __future__ import annotations

@@ -31,7 +31,7 @@ from geomx_tpu.analysis.core import (AuditContext, AuditPass, EqnSite,
 
 # every cross-device primitive jax can put in a shard_map'd program on
 # this jaxlib; psum2/all_gather_invariant are newer spellings kept for
-# forward-compat (bench's DCE counter uses the same set)
+# forward-compat
 COLLECTIVE_PRIMS = frozenset({
     "psum", "psum2", "all_gather", "all_gather_invariant", "all_to_all",
     "ppermute", "pbroadcast", "psum_scatter", "reduce_scatter"})
@@ -65,14 +65,52 @@ def _collective_axes(eqn) -> Tuple[str, ...]:
 def count_collectives(jaxpr, axis: Optional[str] = None) -> int:
     """Number of collective equations in a traced program (recursing
     through pjit/shard_map/scan/cond bodies), optionally restricted to
-    those communicating over the named ``axis`` — the counter bench's
-    --compare-bucketing/--compare-pipeline accounting is built on."""
+    those communicating over the named ``axis``."""
     n = 0
     for site in walk_jaxpr(jaxpr):
         if site.primitive in COLLECTIVE_PRIMS:
             if axis is None or axis in _collective_axes(site.eqn):
                 n += 1
     return n
+
+
+def _collectives_by_axis(jaxpr) -> Dict[str, Dict[str, int]]:
+    """``{axis: {primitive: n}}`` over every collective of a traced
+    program; one over several axes counts under each."""
+    out: Dict[str, Dict[str, int]] = {}
+    for site in walk_jaxpr(jaxpr):
+        if site.primitive in COLLECTIVE_PRIMS:
+            for axis in _collective_axes(site.eqn):
+                by_prim = out.setdefault(axis, {})
+                by_prim[site.primitive] = by_prim.get(site.primitive, 0) + 1
+    return out
+
+
+def weight_path_collectives(
+        step: Callable, state, xb, yb,
+        keep: Sequence[str] = ("params", "opt_state"),
+) -> Tuple[Dict[str, Dict[str, int]], Dict[str, Dict[str, int]]]:
+    """Which collectives the weight update waits on.  Trace
+    ``step(state, xb, yb) -> (new_state, metrics)``, dead-code-eliminate
+    it down to the ``keep`` fields of ``new_state`` (``dce_jaxpr``
+    recurses through pjit/shard_map/cond) and count what survives.
+
+    Returns ``(on_path, whole)``, each ``{axis: {primitive: n}}``:
+    ``on_path`` of the eliminated program, ``whole`` of the step as
+    traced.  A BatchNorm-stat pmean feeds ``model_state`` alone: name
+    that field in ``keep`` to count it (tests/test_pipeline.py), leave
+    it out to count the gradient's path only (tests/test_zero.py)."""
+    import jax
+    from jax.interpreters import partial_eval as pe
+
+    closed, out_shapes = jax.make_jaxpr(step, return_shape=True)(
+        state, xb, yb)
+    flat, treedef = jax.tree.flatten(out_shapes)
+    new_state, _metrics = jax.tree.unflatten(treedef, range(len(flat)))
+    kept = set(jax.tree.leaves([getattr(new_state, f) for f in keep]))
+    dced, _used_ins = pe.dce_jaxpr(closed.jaxpr,
+                                   [i in kept for i in range(len(flat))])
+    return _collectives_by_axis(dced), _collectives_by_axis(closed.jaxpr)
 
 
 def collective_signature(jaxpr) -> Tuple[Tuple[str, Tuple[str, ...],
@@ -542,7 +580,7 @@ def audit_wire_accounting(compressor, params, num_parties: int = 2,
     """GX-DTYPE-002: diff ``compressor.wire_bytes(params)`` against the
     bytes the traced dc-tier collectives actually carry.  An accounting
     that under-reports hides wire cost from every telemetry consumer
-    (``dc_compression_ratio``, byte counters, bench records); one that
+    (``dc_compression_ratio``, byte counters); one that
     hardcodes fp32 for a 16-bit wire inflates it 2x.  Tolerances absorb
     lane padding (``abs_tol`` per program) and rounding.
 

@@ -351,8 +351,8 @@ class BucketedCompressor(Compressor):
                 "pad_fraction": (padded - fill) / padded if padded else 0.0}
 
     def bucket_report(self, grads: Any) -> List[dict]:
-        """Per-bucket payload table (what bench's --compare-bucketing and
-        the profiler spans report): true/padded elements, member-leaf
+        """Per-bucket payload table (what the profiler spans report):
+        true/padded elements, member-leaf
         count, and the inner compressor's wire bytes for the bucket."""
         leaves = jax.tree.leaves(grads)
         bk = self._bucketer(leaves)

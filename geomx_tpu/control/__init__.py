@@ -17,9 +17,7 @@ static env config.
   ``GET /control``.
 
 Gated by ``GEOMX_CONTROL``; the disabled step jaxpr is byte-identical
-to a controller-excised build.  Acceptance: ``bench.py
---compare-control`` (a seeded chaos WAN-degradation replay the
-controller must beat every static config on).
+to a controller-excised build.  Acceptance: tests/test_control.py.
 """
 
 from geomx_tpu.control.actuators import (CONTROL_KEY, ControlActuator,

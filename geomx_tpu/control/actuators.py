@@ -9,7 +9,7 @@ Three actuation boundaries, by cost:
   count below it, and unemitted slots ride the wire as sentinels the
   decompressor already drops.  ``Trainer.apply_control`` swaps the
   operand host-side with a matching sharding, so the jit cache stays at
-  one entry (pinned by ``bench.py --compare-control``).
+  one entry (pinned by tests/test_control.py).
 - **depth / relay** — pipeline-depth switching is a RECOMPILE boundary
   modeled on ``Trainer.apply_membership`` (per-decision cached step
   programs, error-feedback state carried across the swap, the
@@ -97,8 +97,8 @@ def current_ratio_scale():
 class DecisionLog:
     """Thread-safe bounded history of applied decisions.  Entries are
     plain JSON-able dicts with NO wall-clock fields — two runs of the
-    same seeded scenario must produce byte-identical logs (the
-    ``bench.py --compare-control`` determinism gate)."""
+    same seeded scenario must produce byte-identical logs
+    (tests/test_control.py)."""
 
     def __init__(self, capacity: int = 256):
         if capacity <= 0:
@@ -137,7 +137,7 @@ def get_decision_log() -> DecisionLog:
 
 
 def reset_decision_log() -> DecisionLog:
-    """Fresh global decision log (test / bench-run isolation)."""
+    """Fresh global decision log (test isolation)."""
     global _global_log
     with _global_log_lock:
         _global_log = DecisionLog()

@@ -33,9 +33,9 @@ guarded feedback policies over the telemetry plane's sensors
 
 Everything here is a pure function of the observation stream plus
 bounded internal counters: the same seeded scenario produces the same
-decision sequence, which is what makes the chaos-replay acceptance
-(``bench.py --compare-control``) and its bit-identical decision-log
-gate possible.  No wall clock, no RNG.
+decision sequence, which is what makes a chaos replay and its
+bit-identical decision log (tests/test_control.py,
+tests/test_capsule.py) possible.  No wall clock, no RNG.
 """
 
 from __future__ import annotations

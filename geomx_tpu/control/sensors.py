@@ -40,7 +40,7 @@ class ControlObservation:
     compute_fraction: Optional[float] = None
     host_stall: Optional[float] = None
     # absolute per-step compute seconds when the caller can supply it
-    # (bench's WAN model does); fraction-only consumers leave it None
+    # (a WAN model does); fraction-only consumers leave it None
     compute_s: Optional[float] = None
     # in-graph probe registry reads (geomx_step_probe)
     ef_residual_norm: Optional[float] = None
@@ -101,7 +101,7 @@ class ControlSensors:
     ``min_confidence``: the staleness gate applied to link estimates
     (links below it are invisible to every policy).  ``compute_s_fn``:
     optional callable ``step -> seconds`` supplying absolute compute
-    time when the host knows it (bench's WAN model; a profiler-derived
+    time when the host knows it (a WAN model; a profiler-derived
     estimate in live runs).  ``registry_fn``: the REPLAY path
     (telemetry/capsule.py) — a callable ``step -> registry-like``
     serving the registry view recorded AT that step, so an offline

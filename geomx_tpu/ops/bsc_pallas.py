@@ -1,8 +1,7 @@
 """Fused Bi-Sparse (BSC) compression Pallas kernels.
 
-Three kernels replace the dc-tier sparse hot path that a builder's capture
-(BENCH_CAPTURED_r05) showed costing more chip time than the wire bytes
-it saved:
+Three kernels replace the dc-tier sparse hot path whose unfused form
+cost more chip time than the wire bytes it saved:
 
 ``bsc_boundary_probe``
     The boundary's samples: the momentum-corrected magnitudes at the

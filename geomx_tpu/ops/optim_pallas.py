@@ -36,8 +36,8 @@ Contract (same as ``bsc_pallas``):
   are freely interchangeable between runs;
 - Adam's bias corrections ``1 - beta**t`` depend on the traced step
   count, so they enter the kernel as (1, 1) SMEM scalars; everything
-  elementwise stays inside the kernel (the DCE gate in ``bench.py
-  --compare-mfu`` pins that the lowered fused module contains NO
+  elementwise stays inside the kernel (tests/test_optim_pallas.py
+  pins that the lowered fused module contains NO
   ``stablehlo.multiply`` — every flop of the update lives behind the
   ``tpu_custom_call``).
 

@@ -15,7 +15,7 @@ the loop:
   a renormalized mean over surviving parties);
 - ``chaos``     — seeded, reproducible schedules of party blackouts,
   link flaps and message-drop epochs that drive the controller
-  in-process (tests, ``bench.py --compare-resilience``).
+  in-process (tests/test_resilience.py).
 
 See docs/resilience.md for the membership/catch-up protocol and the
 chaos schedule format.

@@ -65,14 +65,13 @@ Example: ``"seed=7;blackout@3:party=1,steps=4;drop@10:rate=30,steps=5"``.
 ``set_drop_rate_override``): the server's relay hop sleeps the shaped
 extra time inside its ``RelayToGlobal`` span, so WAN *degradation* —
 not just blackout/loss — is deterministically replayable, and the
-LinkObservatory measures exactly what the schedule injected (the
-controller acceptance harness of ``bench.py --compare-control``).
+LinkObservatory measures exactly what the schedule injected
+(tests/test_control.py).
 
 Determinism contract: the same spec (or the same ``random`` arguments)
 produces the same event sequence, and the engine reseeds the protocol
 drop RNG from the schedule seed, so a chaos run is replayable bit for
-bit — the property every resilience test and
-``bench.py --compare-resilience`` stands on.
+bit — the property every resilience test stands on.
 """
 
 from __future__ import annotations
@@ -107,7 +106,7 @@ def shard_node_index(node: str) -> "Optional[int]":
 
 # host-plane lifecycle hook (``kill@``/``restart@``): the in-process
 # counterpart of protocol.set_drop_rate_override — whoever owns the
-# processes (the recovery bench, a test harness, a supervisor) installs
+# processes (a test harness, a supervisor) installs
 # a callable ``hook(action, node)`` with action in ("kill", "restart")
 # and node in _NODES, and the engine drives it on schedule.
 _lifecycle_hook = None
@@ -159,7 +158,7 @@ class ChaosSchedule:
 
     def spec(self) -> str:
         """Canonical spec string (round-trips through ``from_spec``) —
-        what the bench record and test failures print."""
+        what test failures print."""
         parts = [f"seed={self.seed}"]
         for e in self.events:
             if e.kind in ("blackout", "readmit"):
@@ -454,7 +453,7 @@ class ChaosEngine:
             # host-plane process lifecycle: driven through the installed
             # hook, never directly — the engine knows WHEN, the owner of
             # the processes knows HOW (crash semantics, durable dirs,
-            # ports).  bench.py --compare-recovery is the reference user.
+            # ports).  tests/test_durability.py is the reference user.
             if _lifecycle_hook is None:
                 raise ValueError(
                     f"chaos event {e} needs a node lifecycle hook "

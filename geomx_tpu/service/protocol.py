@@ -877,8 +877,8 @@ def reseed_drop_rng(seed: int) -> None:
 # corrupted frame keeps its CRC of the ORIGINAL bytes, so the receiver's
 # integrity check fails, the connection drops, and the sender's
 # retry/reconnect path re-delivers a clean copy — the end-to-end story
-# the wire-CRC gate exists to prove.  Keyed by wire sender id (the
-# bench's workers use party == sender_id); -1 matches every sender.
+# the wire-CRC gate exists to prove.  Keyed by wire sender id; -1
+# matches every sender.
 _corrupt_rates: "dict[int, int]" = {}
 _corrupt_rng = _random.Random(0xC0DE)
 

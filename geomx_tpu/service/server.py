@@ -80,7 +80,7 @@ class _KeyState:
         # float addition is commutative but not associative, and at
         # 16+ parties an arrival-ordered running sum would make the
         # merged bits depend on thread scheduling — the many-party
-        # bit-exact chaos gate (bench --compare-manyparty) and shard
+        # bit-exact chaos gate (tests/test_manyparty.py) and shard
         # migration both need arrival-order-independent merges.
         # Cost: up to num_workers gradients per key held for the open
         # round (vs one accumulated array before) — a deliberate

@@ -34,11 +34,8 @@ the WHOLE gradient pytree into one contiguous fp32 vector and runs the
 deferral schedule once — one contribution EWMA, one top-k, one pending
 read-modify-write, one inner all-reduce — instead of per-leaf.  Per-leaf
 DGT on a ~25-leaf model meant ~25 tiny sorts + 100 extra state buffers
-threaded through every dispatch; round 4 measured the combined cost of
-that plus HFA's dead milestone carriage as +4.5 ms/step at 1x1
-(BENCH_CAPTURED_r04 hfa_dgt 18.2 ms vs vanilla 13.7 ms, where no sync
-runs at all — both sources fixed together in round 5, so the split
-between them was never measured separately).  Ranking is therefore
+threaded through every dispatch (its cost on the chip, with HFA's dead
+milestone carriage: not measured by the benchmark).  Ranking is therefore
 GLOBAL across the model's blocks
 rather than per-tensor; the reference ranks within each pushed key
 (kv_app.h:1088-1196), but its k is the same fraction everywhere, so the

@@ -59,11 +59,7 @@ class HFA(SyncAlgorithm):
         if self.num_parties <= 1:
             # one party: the global tier never fires (the Python gate in
             # sync_params), so a milestone copy + compressor state would
-            # be dead weight threaded through every dispatch — this plus
-            # the per-leaf DGT schedule (sync/dgt.py module docstring)
-            # together cost +4.5 ms/step at 1x1 in a builder's capture
-            # (BENCH_CAPTURED_r04: hfa_dgt 18.2 ms vs vanilla 13.7 ms,
-            # where HFA computes nothing at all)
+            # be dead weight threaded through every dispatch
             return {}
         return {
             # last globally-agreed parameters (reference stored_milestone)

@@ -52,7 +52,7 @@ anyway.  The model-state sync (BatchNorm stats) is double-buffered as a
 whole: each step launches worker-pmean + dc-pmean of its fresh stats
 into the buffer and applies the previous step's fully-aggregated stats,
 so BOTH stat tiers are one step stale and NO dc-axis collective output
-is consumed in-step (``bench.py --compare-pipeline`` verifies this
+is consumed in-step (tests/test_pipeline.py verifies this
 structurally in the DCE'd jaxpr).  ``lax.optimization_barrier`` separates the two tiers so
 the flattened party-mean buckets are pinned as a unit before the DCN
 launch and XLA cannot fuse the stale buffer's consumers into the

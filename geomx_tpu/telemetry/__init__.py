@@ -60,8 +60,7 @@ from geomx_tpu.telemetry.links import (LinkObservatory,
 from geomx_tpu.telemetry.probes import telemetry_enabled
 from geomx_tpu.telemetry.registry import (MetricRegistry, get_registry,
                                           reset_registry)
-from geomx_tpu.telemetry.roofline import (publish_roofline, roofline_record,
-                                          trainer_roofline)
+from geomx_tpu.telemetry.roofline import publish_roofline, roofline_record
 from geomx_tpu.telemetry.tracing import merge_traces, rounds_in_trace
 
 __all__ = [
@@ -72,7 +71,7 @@ __all__ = [
     "merge_traces", "rounds_in_trace",
     "attribute_trace", "attribute_merged", "classify_span",
     "publish_attribution",
-    "roofline_record", "trainer_roofline", "publish_roofline",
+    "roofline_record", "publish_roofline",
     "LinkObservatory", "get_link_observatory", "reset_link_observatory",
     "RoundLedger", "get_round_ledger", "reset_round_ledger",
     "FlightRecorder", "flight_enabled", "flight_recorder_from_config",

@@ -17,12 +17,11 @@ four DISJOINT phases whose durations sum to the window exactly —
                     host work).
 
 Because the partition is disjoint the four fractions sum to ~1.0 by
-construction, which is the acceptance invariant ``bench.py --attribute``
-gates on.
+construction (tests/test_observatory.py holds the invariant).
 
 Classification is keyed on the span names/categories the repo already
-records: ``train/step`` marks the step window (``Trainer.fit`` and bench
-emit it), ``train/compute`` + ``kernel``-category spans
+records: ``train/step`` marks the step window (``Trainer.fit`` emits
+it), ``train/compute`` + ``kernel``-category spans
 (``bsc/select_pack``, ``bsc/scatter_add``) are compute, and
 ``comm``-category spans (``dc_pipeline/launch``/``apply``, the bucketed
 engine's ``dc_allreduce/bucket*`` spans, the host plane's
@@ -59,8 +58,8 @@ def classify_span(name: str, category: str = "") -> Optional[str]:
     ==========================  =========  =============================
     match                       class      emitted by
     ==========================  =========  =============================
-    name ``train/step``         step       Trainer.fit / bench
-    name ``train/compute``      compute    Trainer.fit / bench
+    name ``train/step``         step       Trainer.fit
+    name ``train/compute``      compute    Trainer.fit
     category ``kernel``         compute    ``bsc/select_pack`` etc.
     category ``compute``        compute    any explicit compute span
     category ``comm``           comms      ``dc_pipeline/launch``,

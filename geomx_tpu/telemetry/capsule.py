@@ -117,7 +117,7 @@ def sample_registry(registry=None,
 class RegistrySampler:
     """Periodic whole-registry sampler: a bounded time series of
     :func:`sample_registry` snapshots.  :meth:`sample` takes one sample
-    at an explicit ``now`` (the bench's virtual clock); :meth:`start`
+    at an explicit ``now`` (a caller's virtual clock); :meth:`start`
     runs a wall-clock daemon loop at ``interval_s`` for live runs."""
 
     def __init__(self, registry=None, interval_s: float = DEFAULT_SAMPLE_S,
@@ -349,8 +349,8 @@ class RunCapsule:
     def _summary(self, steps: List[dict], journal: List[dict],
                  now: Optional[float] = None) -> dict:
         """Pre-computed cross-section summary stored IN the archive so
-        ``tools/runcap.py diff``/``explain`` (and benchtrend's
-        regression explainer) stay stdlib-only readers."""
+        ``tools/runcap.py diff``/``explain`` stay stdlib-only
+        readers."""
         out: Dict[str, Any] = {"num_steps": len(steps)}
         if steps:
             out["first_t"] = steps[0]["t"]

@@ -12,8 +12,7 @@ quantized-collective cost curves for exactly this purpose.
 - **links**: per-party uplink models ``seconds(B) = a + B*ib``.  When
   the run fed *paired* observations — the payload transfer on the
   ``global`` peer plus a heartbeat-sized probe on the ``probe`` peer
-  (what ``bench.py --compare-capsule`` records; the scheduler's
-  heartbeats are the live analogue) — the pair solves ``(a, ib)``
+  (the scheduler's heartbeats are the live analogue) — the pair solves ``(a, ib)``
   EXACTLY per step, so latency shaping and bandwidth shaping separate
   and the model tracks chaos windows step by step.  Without probes it
   falls back to a least-squares affine fit over the journal plus a
@@ -32,10 +31,9 @@ bucket_bytes)`` config, derives its per-step wire bytes from the
 capsule's recorded parameter layout via the compressors' own static
 wire accounting (:func:`candidate_wire_bytes` — the same
 ``wire_bytes`` the GX-DTYPE-002 audit holds honest), and integrates
-the per-step prediction over the capsule's timeline.  ``bench.py
---compare-capsule`` validates the model's *ranking* of a ratio x
-depth x compressor grid against measured runs and reports per-config
-relative error (docs/performance.md "What-if search over capsules").
+the per-step prediction over the capsule's timeline
+(tests/test_capsule.py pins the fit and the prediction on synthetic
+capsules; docs/performance.md "What-if search over capsules").
 
 Known limits (documented, not hidden): compute is treated as
 config-invariant (a candidate whose compressor changes on-chip time —
@@ -318,7 +316,7 @@ class StepTimeCostModel:
         }
 
     def to_json(self) -> dict:
-        """JSON form (bench artifact / docs examples) — fits without
+        """JSON form (artifacts, docs examples) — fits without
         the per-sample residual tables."""
         out = {
             "compute_s": self.compute_s,

@@ -14,8 +14,8 @@ Two export surfaces over the process-global registry
   machine-readable trail of step probes, membership transitions and
   relay failures that outlives the process.
 
-:func:`parse_prometheus_text` is the minimal parser the test suite (and
-``bench.py --compare-telemetry``) round-trips the exposition through —
+:func:`parse_prometheus_text` is the minimal parser the test suite
+round-trips the exposition through —
 it understands exactly what :func:`render_prometheus` can produce, which
 is the point: a rendering the parser rejects is a bug in the renderer.
 """

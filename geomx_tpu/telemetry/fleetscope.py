@@ -24,7 +24,7 @@ three pieces in one jax-free module (safe in the scheduler process):
   monitor: ``record(t, good, bad)`` appends to a bounded series and
   ``evaluate(now)`` is a pure fold over it — the same series evaluated
   at the same instants produces the same breach list, bit-identical
-  (``bench.py --fleetscope`` gates this across two same-seed runs).  A
+  (tests/test_fleetscope.py holds this across two same-seed runs).  A
   breach onset emits a ``flight_anomaly`` event and bumps
   ``geomx_fleet_burn_breaches_total`` so SloPolicy and operators act
   on fleet truth, not gateway-local numbers;
@@ -197,7 +197,7 @@ class PropagationTracker:
 
     def summary(self) -> Dict[str, Any]:
         """p50/p99 propagation over completed rounds + per-transport
-        completion counts (the ``--fleetscope`` both-doors gate)."""
+        completion counts."""
         recs = self.rounds()
         spans = sorted(r["propagation_s"] for r in recs
                        if "propagation_s" in r)
@@ -229,7 +229,7 @@ def get_propagation_tracker() -> PropagationTracker:
 
 def reset_propagation_tracker(capacity: Optional[int] = None
                               ) -> PropagationTracker:
-    """Fresh global tracker (test isolation / bench runs)."""
+    """Fresh global tracker (test isolation)."""
     global _prop_tracker
     with _prop_lock:
         _prop_tracker = PropagationTracker(
@@ -426,7 +426,7 @@ class FleetScope:
     to discover nodes from (roster + heartbeat dead list + its own
     metrics endpoint).  ``targets_fn``: the injectable alternative — a
     zero-arg callable returning node descriptor dicts (the
-    :func:`roster_targets` shape); tests and the bench drive this.
+    :func:`roster_targets` shape); the tests drive this.
     ``fetch_fn(url, timeout_s) -> text`` is injectable the same way, so
     the degradation tests can serve torn bodies and timeouts without a
     socket.  All polling state is per node-name; a fold is a pure
@@ -546,7 +546,7 @@ class FleetScope:
     def poll_once(self, now: Optional[float] = None) -> dict:
         """One sweep + fold: poll every discoverable node, fold health
         and rollups, tick the burn monitor, version the document.
-        ``now`` is injectable (virtual time in tests/bench) and is the
+        ``now`` is injectable (virtual time in tests) and is the
         only clock the fold reads."""
         now = time.time() if now is None else float(now)
         nodes = self.targets()

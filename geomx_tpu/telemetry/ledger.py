@@ -573,7 +573,7 @@ def get_round_ledger() -> RoundLedger:
 
 
 def reset_round_ledger(capacity: Optional[int] = None) -> RoundLedger:
-    """Fresh global ledger (test isolation / bench runs)."""
+    """Fresh global ledger (test isolation)."""
     global _ledger
     with _ledger_lock:
         _ledger = RoundLedger(capacity=capacity)
@@ -793,7 +793,7 @@ def peek_request_ledger() -> Optional[RequestLedger]:
 
 
 def reset_request_ledger(capacity: Optional[int] = None) -> RequestLedger:
-    """Fresh global request ledger (test isolation / bench runs)."""
+    """Fresh global request ledger (test isolation)."""
     global _request_ledger
     with _request_ledger_lock:
         _request_ledger = RequestLedger(capacity=capacity)

@@ -13,8 +13,8 @@ The master switch is ``GEOMX_TELEMETRY`` (or ``GeoConfig(telemetry=
 True)``).  The gate is *static at trace time* and guards a single call
 site in ``train/step.py``: with telemetry off, the traced step's jaxpr
 is byte-identical to a build with this module excised (pinned by
-``tests/test_telemetry.py`` and re-verified by ``bench.py
---compare-telemetry``), so the default-off path costs exactly nothing.
+``tests/test_telemetry.py``), so the default-off path costs exactly
+nothing.
 
 Probe catalog (all values replicated across the mesh, so they ride the
 replicated metrics output):
@@ -57,8 +57,8 @@ def canonicalize_jaxpr(text: str) -> str:
     """Strip run-dependent noise from a jaxpr's string form so two
     traces of the SAME program compare equal: the only non-deterministic
     tokens are function object addresses in custom_jvp thunk params
-    (``<function ... at 0x...>``).  The jaxpr-identity verdict (bench
-    --compare-telemetry, tests/test_telemetry.py) compares on this."""
+    (``<function ... at 0x...>``).  The jaxpr-identity verdict
+    (tests/test_telemetry.py) compares on this."""
     import re
     return re.sub(r" at 0x[0-9a-fA-F]+>", " at 0xADDR>", text)
 

@@ -2,11 +2,10 @@
 
 ROADMAP item 5 says MFU sits at ~0.17 and "the compute side, not the
 wire, now bounds single-chip speed" — this module makes that kind of
-claim *derivable from a running program* instead of a bench one-off:
+claim *derivable from a running program* instead of a one-off:
 
 - :func:`compiled_costs` reads model FLOPs and HBM bytes-accessed per
-  step from XLA's ``compiled.cost_analysis()`` (the same source the
-  bench's MFU column uses);
+  step from XLA's ``compiled.cost_analysis()``;
 - :func:`roofline_record` grades the measured step time against the
   three rooflines that can bound it — peak compute, memory bandwidth,
   and the WAN wire (bytes from ``sync.wire_accounting``) — and emits a
@@ -26,8 +25,8 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 # Published per-chip peaks, keyed by the EXACT ``device_kind`` JAX reports
-# (``jax.devices()[0].device_kind``).  The one table bench.py and the
-# roofline records read; a device that is not in it is an error, never a
+# (``jax.devices()[0].device_kind``).  The one table the roofline
+# records read; a device that is not in it is an error, never a
 # default or a calibration — add its row, with its source, to use it.
 #
 # "TPU v5 lite" is the v5e.  Source: Google Cloud documentation, "TPU
@@ -162,37 +161,3 @@ def publish_roofline(rec: Dict[str, Any], registry=None) -> None:
                       ("resource",))
     for res, t in (rec.get("bound_times_s") or {}).items():
         fam_t.labels(resource=res).set(float(t))
-
-
-def trainer_roofline(trainer, state, xb, yb, step_time_s: float,
-                     device_kind: Optional[str] = None,
-                     wire_seconds: Optional[float] = None
-                     ) -> Dict[str, Any]:
-    """Roofline record for a live trainer: FLOPs/bytes from the compiled
-    step, wire bytes from the sync algorithm's static accounting, peaks
-    from the device table (``ValueError`` for a device it does not
-    list).  ``wire_seconds``: measured/injected per-step WAN time — when
-    given, the wire roofline uses the *achieved* rate
-    (wire_bytes/wire_seconds) so the verdict reflects the link actually
-    in use."""
-    import jax
-
-    if device_kind is None:
-        device_kind = jax.devices()[0].device_kind
-    peaks = device_peaks(device_kind)
-    compiled = trainer.train_step.lower(state, xb, yb).compile()
-    costs = compiled_costs(compiled)
-    params = jax.tree.map(lambda a: a[0, 0], state.params)
-    wire = float((trainer.sync.wire_accounting(params) or {}).get(
-        "dc_wire_bytes", 0.0)) or None
-    wire_bw = (wire / wire_seconds
-               if wire and wire_seconds and wire_seconds > 0 else None)
-    rec = roofline_record(
-        flops=costs.get("flops"), step_time_s=step_time_s,
-        peak_flops_per_s=peaks["bf16_flops_per_s"],
-        hbm_bytes=costs.get("bytes_accessed"),
-        hbm_bytes_per_s=peaks["hbm_bytes_per_s"], wire_bytes=wire,
-        wire_bytes_per_s=wire_bw)
-    rec["device_kind"] = device_kind
-    rec["cost_analysis_available"] = costs.get("available", False)
-    return rec

@@ -137,7 +137,7 @@ def process_names(doc: dict) -> Dict[int, str]:
 
 def rounds_in_trace(doc: dict) -> Dict[Tuple[str, int], List[dict]]:
     """Group a (merged or single) trace's correlated events by
-    (key, round_id) — the assertion surface for tests and bench."""
+    (key, round_id) — the assertion surface for tests."""
     out: Dict[Tuple[str, int], List[dict]] = {}
     for ev in doc.get("traceEvents", []):
         if ev.get("ph") not in ("X", "i"):

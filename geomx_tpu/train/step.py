@@ -313,9 +313,8 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
         # interpret mode off-TPU (CI, CPU meshes): ops/dispatch.py's
         # answer, as for every other kernel.
         # GEOMX_FUSED_OPTIM_INTERPRET overrides it (=0 forces the native
-        # Mosaic lowering: bench --compare-mfu uses it to cross-lower
-        # the step for the DCE structure gate on a CPU host — such a
-        # build LOWERS anywhere but only RUNS on TPU)
+        # Mosaic lowering, to cross-lower the step on a CPU host — such
+        # a build LOWERS anywhere but only RUNS on TPU)
         import os as _os
         from geomx_tpu.ops.dispatch import kernel_mode
         # graftlint: disable=GXL006 — build-time gate
@@ -549,7 +548,7 @@ def build_train_step(loss_fn: Callable, tx: optax.GradientTransformation,
                     metrics)
             # step metadata: the live-party count baked into this traced
             # step (static — the membership epoch is a recompile boundary);
-            # bench.py --compare-resilience reads it back as evidence that
+            # tests/test_resilience.py reads it back as evidence that
             # degraded steps really ran the renormalized survivor mean
             metrics["num_live_parties"] = jnp.asarray(sync.num_live,
                                                       jnp.float32)

@@ -101,8 +101,7 @@ class Trainer:
         self._fused_optim = fused_optim_enabled(self.config)
         # input-pipeline overlap depth (data/loader.py): how many
         # assembled+device_put batches the producer thread keeps in
-        # flight ahead of the step; 0 = synchronous (the host_stall
-        # baseline bench.py --compare-mfu measures against)
+        # flight ahead of the step; 0 = synchronous
         self._prefetch = max(0, int(getattr(self.config, "prefetch", 2)))
         sp_model = getattr(model, "sp_mode", None) is not None
         if getattr(topology, "sp_degree", 1) > 1 and not sp_model:
@@ -570,7 +569,8 @@ class Trainer:
         - ``kind == "ratio"``: rewrite the ``bsc_ratio_scale`` operand
           in ``sync_state["control"]`` host-side with the SAME sharding
           the compiled step expects — the jit cache stays warm, no
-          recompile (the bench pins the cached-executable count).
+          recompile (tests/test_control.py pins the cached-executable
+          count).
         - ``kind == "depth"``: wrap/unwrap ``PipelinedSync`` — a
           recompile boundary modeled on :meth:`apply_membership`
           (per-decision cached step programs; dc-tier error-feedback
@@ -952,8 +952,7 @@ class Trainer:
                 layout["pad_fraction"])
             if self._zero_plan is not None:
                 # ZeRO bucket-shard layout: what one chip actually owns
-                # (the memory claim's denominator, scraped instead of
-                # bench-only)
+                # (the memory claim's denominator)
                 w = self._zero_plan.W
                 reg.gauge("geomx_zero_workers",
                           "Worker-axis width the weight update is "
@@ -1007,41 +1006,6 @@ class Trainer:
         if not att["num_steps"]:
             return None
         return att["summary"]
-
-    def step_memory_stats(self, state: TrainState, xb, yb):
-        """Compiled-step memory accounting from XLA's
-        ``compiled.memory_analysis()`` — the source of bench
-        ``--compare-zero``'s memory claim.  Adds the sharded-state
-        accounting (bytes of optimizer + sync state one chip holds, from
-        the placed arrays' shapes) so the 1/W claim is checkable even
-        where the backend offers no analysis object.  Its
-        ``temp_size_in_bytes`` is not what a TPU loads: the compiler sizes
-        temporaries to what it believes free (PERF.md section 7, PR 27);
-        what the chip reserved for the loaded step is
-        ``lifecycle.step_reserved_bytes()``."""
-        placed = layers.state_bytes_per_chip(state, self.mesh.devices.size)
-        out = {f"{name}_bytes_per_chip": placed[name]
-               for name in ("opt_state", "sync_state", "params")}
-        try:
-            ma = self._compiled_step(state, xb, yb).memory_analysis()
-        except Exception as e:  # backend without AOT memory stats
-            out["memory_analysis"] = {"unavailable": repr(e)}
-            return out
-        if ma is None:
-            out["memory_analysis"] = {"unavailable": "None"}
-            return out
-        fields = {}
-        for k in ("temp_size_in_bytes", "argument_size_in_bytes",
-                  "output_size_in_bytes", "alias_size_in_bytes",
-                  "generated_code_size_in_bytes"):
-            if hasattr(ma, k):
-                fields[k] = int(getattr(ma, k))
-        fields["step_memory_bytes"] = (
-            fields.get("temp_size_in_bytes", 0)
-            + fields.get("argument_size_in_bytes", 0)
-            + fields.get("output_size_in_bytes", 0))
-        out["memory_analysis"] = fields
-        return out
 
     def _compiled_step(self, state, xb, yb):
         """The active step program, compiled ahead of time for these
@@ -1378,9 +1342,8 @@ class Trainer:
                             # accelerator the async-dispatch catch-up), so
                             # it stays inside the compute span — attributed
                             # host_stall is then genuinely the input
-                            # pipeline and dispatch gaps, which is what the
-                            # GEOMX_PREFETCH acceptance (bench.py
-                            # --compare-mfu) measures
+                            # pipeline and dispatch gaps, which is what
+                            # GEOMX_PREFETCH shortens
                             synced = None
                             if log_every and it % log_every == 0:
                                 with stats.phase("fit/log_sync"):
@@ -1427,8 +1390,7 @@ class Trainer:
                         log_fn(json.dumps(rec))
             if self._telemetry and prof.running:
                 # publish the fit's phase-fraction summary from the step
-                # spans recorded above (geomx_phase_fraction gauges) — the
-                # scrapeable form of bench --attribute's breakdown
+                # spans recorded above (geomx_phase_fraction gauges)
                 from geomx_tpu.telemetry.attribution import (
                     attribute_trace, publish_attribution)
                 att = attribute_trace(prof.to_doc(), since_us=fit_since_us)

@@ -4,7 +4,7 @@ Three subsystems grew their own copy of the same crash-safety pattern —
 ``Profiler.dump``/``Measure.dump`` (via the old ``utils/fileio``
 helper), and ``resilience/durability.py``'s ``_atomic_write`` (which
 PR 10 extended with a directory fsync).  This module folds them into
-one owner so every durable artifact — Chrome traces, bench records,
+one owner so every durable artifact — Chrome traces,
 flight-recorder bundles, durable-store snapshots, run capsules — gets
 the same guarantees:
 

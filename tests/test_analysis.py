@@ -17,6 +17,8 @@ Four layers of evidence, all CPU:
   ``AuditError`` past the severity gate.
 """
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -232,6 +234,97 @@ def test_wire_audit_keeps_honest_gather_compressors_clean_at_n4():
         assert findings == [], (spec, [f.message for f in findings])
     lie = next(e for e in CORPUS if e.name == "scatter_wire_lie").run()
     assert {f.rule_id for f in lie} == {"GX-DTYPE-002"}
+
+
+# --------------------------------------------------------------------------
+# weight_path_collectives: what survives elimination down to kept fields
+# --------------------------------------------------------------------------
+
+class _ToyState(NamedTuple):
+    params: jax.Array
+    opt_state: jax.Array
+    model_state: jax.Array
+
+
+def _toy_step(body):
+    """``step(state, xb, yb) -> (new_state, metrics)`` whose per-device
+    ``body(p, o, m, x)`` returns the three new fields, on a 2x2
+    (dc, worker) mesh."""
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from geomx_tpu.parallel.collectives import shard_map_compat
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                ("dc", "worker"))
+    spec = P("dc", "worker")
+    fn = shard_map_compat(body, mesh, in_specs=(spec,) * 4,
+                          out_specs=(spec,) * 3)
+
+    def step(state, xb, yb):
+        p, o, m = fn(state.params, state.opt_state, state.model_state, xb)
+        return _ToyState(p, o, m), {"loss": jnp.sum(yb)}
+
+    z = jnp.zeros((2, 2, 8), jnp.float32)
+    return step, _ToyState(z, z, z), z, z
+
+
+def test_weight_path_leaves_out_a_collective_feeding_no_kept_field():
+    from geomx_tpu.analysis.passes import weight_path_collectives
+
+    def body(p, o, m, x):
+        g = jax.lax.psum(x, "worker")
+        return p - g, o + g, jax.lax.pmean(m + x, "dc")
+
+    on_path, whole = weight_path_collectives(*_toy_step(body))
+    assert on_path == {"worker": {"psum": 1}}
+    assert whole == {"worker": {"psum": 1}, "dc": {"psum": 1}}
+    # name the field and the statistic's collective is on the path
+    on_path, _ = weight_path_collectives(
+        *_toy_step(body), keep=("params", "opt_state", "model_state"))
+    assert on_path == whole
+
+
+def test_weight_path_counts_by_axis_and_primitive():
+    from geomx_tpu.analysis.passes import weight_path_collectives
+
+    def body(p, o, m, x):
+        shard = jax.lax.psum_scatter(x, "worker", scatter_dimension=2,
+                                     tiled=True)
+        g = jax.lax.all_gather(shard, "worker", axis=2, tiled=True)
+        g = jax.lax.psum(g, ("dc", "worker"))   # counts under both axes
+        return p - g, o + jax.lax.psum(x, "dc"), m
+
+    on_path, whole = weight_path_collectives(*_toy_step(body))
+    assert on_path == whole == {
+        "worker": {"reduce_scatter": 1, "all_gather": 1, "psum": 1},
+        "dc": {"psum": 2}}
+    # only params kept: opt_state's own dc psum falls away
+    on_path, _ = weight_path_collectives(*_toy_step(body),
+                                         keep=("params",))
+    assert on_path["dc"] == {"psum": 1}
+
+
+def test_weight_path_enters_nested_programs():
+    """Collectives under jit inside shard_map and in both branches of a
+    cond are found, and eliminated with the branch output they feed."""
+    from geomx_tpu.analysis.passes import weight_path_collectives
+
+    @jax.jit
+    def inner(x):
+        return jax.lax.psum(x, "worker")
+
+    def body(p, o, m, x):
+        g = inner(x)
+        kept, dropped = jax.lax.cond(
+            jnp.sum(x) > 0,
+            lambda v: (jax.lax.psum(v, "dc"),
+                       jax.lax.ppermute(v, "dc", [(0, 1), (1, 0)])),
+            lambda v: (jax.lax.psum(2 * v, "dc"), v), g)
+        return p - kept, o, m + dropped
+
+    on_path, whole = weight_path_collectives(*_toy_step(body))
+    assert whole["worker"] == {"psum": 1}
+    assert whole["dc"] == {"psum": 2, "ppermute": 1}
+    assert on_path == {"worker": {"psum": 1}, "dc": {"psum": 2}}
 
 
 # --------------------------------------------------------------------------

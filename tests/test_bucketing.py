@@ -4,8 +4,6 @@ error-feedback round-tripping through the bucket layout, MPQ
 bucket-granularity routing, the dc-tier default policy, and the
 collective-count reduction the fusion exists to deliver."""
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,8 +16,6 @@ from geomx_tpu.compression import (BiSparseCompressor, BucketedCompressor,
                                    TwoBitCompressor, maybe_bucketed)
 from geomx_tpu.parallel.collectives import shard_map_compat
 from geomx_tpu.topology import DC_AXIS, WORKER_AXIS
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _tree(rng, dtype=np.float32):
@@ -263,7 +259,7 @@ def test_fsa_buckets_dc_tier_by_default():
 def test_hfa_buckets_global_delta_by_default():
     """HFA's K1*K2 global-delta allreduce crosses the same WAN hop as
     FSA's gradients and gets the same fused-bucket default; tree-level
-    DGT (the hfa_dgt bench config) must still never double-wrap."""
+    DGT (chip_smoke.py's hfa_dgt config) must still never double-wrap."""
     from geomx_tpu.sync import HFA, DGTCompressor
     assert isinstance(HFA().dc_compressor, BucketedCompressor)
     assert isinstance(HFA(bucket_bytes=0).dc_compressor, NoCompressor)
@@ -403,24 +399,35 @@ def test_bucketed_training_matches_per_leaf_losses(topo2x4):
 
 # ---------- the point of it all: collective launches per step ----------
 
-def test_collective_launch_count_drops_to_num_buckets():
-    """Trace the dc all-reduce jaxpr and count collective primitives:
-    per-leaf launches O(num_leaves), bucketed launches O(num_buckets)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "bench_under_test", os.path.join(REPO, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
+@pytest.mark.parametrize(
+    "spec", ["none", "fp16", "2bit,0.5", "bsc,0.01", "mpq,0.01"])
+def test_collective_launch_count_drops_to_num_buckets(spec):
+    """Trace the dc all-reduce on a 2-party mesh and count collective
+    primitives: per-leaf launches O(num_leaves), bucketed launches
+    O(num_buckets)."""
+    from geomx_tpu.analysis.passes import (_traced_allreduce_jaxpr,
+                                           count_collectives)
+    from geomx_tpu.compression import get_compressor
+    from geomx_tpu.compression.bucketing import DEFAULT_BUCKET_BYTES
+    from geomx_tpu.models import get_model
 
-    result = bench._compare_bucketing(model_name="cnn",
-                                      specs=("none", "bsc,0.01"))
-    n_leaves = result["num_leaves"]
+    model = get_model("cnn", num_classes=10)
+    params = jax.jit(lambda r, x: model.init(r, x, train=False))(
+        jax.random.PRNGKey(0), jnp.zeros((2, 32, 32, 3), jnp.float32)
+    )["params"]
+    n_leaves = len(jax.tree.leaves(params))
     assert n_leaves > 4
-    for name, rec in result["specs"].items():
-        assert rec["per_leaf"]["collectives"] >= n_leaves
-        assert (rec["bucketed"]["collectives"]
-                <= 2 * rec["bucketed"]["num_buckets"])
-        assert rec["bucketed"]["collectives"] < rec["per_leaf"]["collectives"]
-    # global selection must not cost more wire than per-leaf BSC
-    bsc = result["specs"]["bsc,0.01"]
-    assert bsc["bucketed"]["wire_bytes"] <= bsc["per_leaf"]["wire_bytes"]
+
+    def launches(comp):
+        return count_collectives(_traced_allreduce_jaxpr(comp, params))
+
+    per_leaf = get_compressor(spec)
+    bucketed = BucketedCompressor(get_compressor(spec), DEFAULT_BUCKET_BYTES)
+    n_per_leaf, n_bucketed = launches(per_leaf), launches(bucketed)
+    assert n_per_leaf >= n_leaves
+    assert n_bucketed <= 2 * len(bucketed.init_state(params))
+    assert n_bucketed < n_per_leaf
+    if spec.startswith("bsc"):
+        # global selection must not cost more wire than per-leaf BSC
+        assert (bucketed.wire_bytes(params)
+                <= per_leaf.wire_bytes(params))

@@ -5,12 +5,8 @@ GraftPilot decision sequence, the fitted step-time cost model
 (telemetry/costmodel.py), the runcap CLI, and the ride-along
 satellites — the shared atomic-write owner (utils/atomicio.py), the
 flight-bundle registry section, the event-log dropped-records counter,
-observatory replay equivalence (ingest_trace vs ingest_ledger), and
-the benchtrend CAPSULE series.
-
-``bench.py --compare-capsule`` proves the same machinery on a real
-3-party chaos-shaped training run; these tests pin the mechanisms in
-milliseconds.
+and observatory replay equivalence (ingest_trace vs ingest_ledger).
+These tests pin the mechanisms in milliseconds.
 """
 
 import importlib.util
@@ -520,8 +516,7 @@ def test_runcap_cli_and_stdlib_only(tmp_path, registry):
          str(tmp_path / "missing.json")], capture_output=True,
         text=True, env=env)
     assert bad_rc.returncode == 2
-    # diff/explain/info never import the repo (benchtrend's contract
-    # for calling them stays stdlib-only)
+    # diff/explain/info never import the repo (stdlib-only readers)
     probe = subprocess.run(
         [sys.executable, "-c",
          "import sys; sys.path.insert(0, sys.argv[1]); import runcap; "
@@ -578,65 +573,3 @@ def test_eventlog_rotation_counts_dropped_records(tmp_path, registry):
     fam = registry.get("geomx_eventlog_dropped_records_total")
     assert fam is not None
     assert fam.children()[0][1].value == float(log.dropped_records)
-
-
-# ---- benchtrend CAPSULE series --------------------------------------------
-
-
-def _capsule_series_rec(ok=True, rank=True, err=0.01, capsule=None):
-    rec = {"mode": "compare_capsule", "ok": ok,
-           "capsule_recorded": True,
-           "replay_snapshot_bit_identical": True,
-           "replay_decisions_bit_identical": True,
-           "cost_model_rank_exact": rank,
-           "cost_model_error_bounded": True,
-           "explain_names_degraded_link": True,
-           "explain_names_phase": True,
-           "cost_model_max_rel_err": err}
-    if capsule:
-        rec["artifacts"] = {"capsule": capsule}
-    return rec
-
-
-def test_benchtrend_gates_capsule_series(tmp_path):
-    bt = _load_tool("benchtrend")
-    d = tmp_path / "series"
-    d.mkdir()
-    (d / "CAPSULE_r01.json").write_text(
-        json.dumps(_capsule_series_rec()))
-    (d / "CAPSULE_r02.json").write_text(
-        json.dumps(_capsule_series_rec(err=0.0105)))
-    rep = bt.run(str(d))
-    assert rep["passed"], rep["regressions"]
-    (d / "CAPSULE_r03.json").write_text(
-        json.dumps(_capsule_series_rec(rank=False)))
-    rep = bt.run(str(d))
-    assert not rep["passed"]
-    assert any(v["metric"] == "cost_model_rank_exact"
-               for v in rep["regressions"])
-    # the committed series is green
-    repo = os.path.join(os.path.dirname(__file__), "..")
-    rep = bt.run(repo, patterns=["CAPSULE_r*.json"])
-    assert rep["passed"], rep
-
-
-def test_benchtrend_regression_explained_from_capsules(tmp_path,
-                                                       registry):
-    clean, bad = _two_capsules(tmp_path, registry)
-    bt = _load_tool("benchtrend")
-    d = tmp_path / "series"
-    d.mkdir()
-    (d / "CAPSULE_r01.json").write_text(json.dumps(
-        _capsule_series_rec(capsule=clean)))
-    (d / "CAPSULE_r02.json").write_text(json.dumps(
-        _capsule_series_rec(rank=False, capsule=bad)))
-    rep = bt.run(str(d))
-    assert not rep["passed"]
-    findings = rep["capsule_explain"]["CAPSULE"]
-    assert any(f["kind"] == "link" and "party1" in f["name"]
-               for f in findings)
-    # no capsules referenced -> no explain section, still fails cleanly
-    (d / "CAPSULE_r02.json").write_text(json.dumps(
-        _capsule_series_rec(rank=False)))
-    rep = bt.run(str(d))
-    assert not rep["passed"] and rep["capsule_explain"] == {}

@@ -128,8 +128,7 @@ def test_device_cache_loader_matches_host_path():
 def test_prefetch_batches_bit_identical_to_synchronous():
     """epoch(prefetch=N) moves batch assembly to a producer thread but
     must not change a single byte — augmentation RNG included — nor the
-    batch order (the GEOMX_PREFETCH determinism contract the
-    --compare-mfu acceptance gates)."""
+    batch order (the GEOMX_PREFETCH determinism contract)."""
     topo = HiPSTopology(num_parties=2, workers_per_party=4)
     rng = np.random.RandomState(9)
     x = (rng.rand(256, 16, 16, 3) * 255).astype(np.uint8)
@@ -203,7 +202,7 @@ def test_trainer_prefetch_params_bit_identical():
 
 
 def test_real_cifar10_binary_layout_is_discovered(tmp_path):
-    """The auto-switch the bench TTA relies on (VERDICT r4 #4): when the
+    """The auto-switch a time-to-accuracy run relies on: when the
     canonical cifar-10-batches-bin layout is present under the data
     root — however it got there (tools/fetch_cifar10.py with egress, or
     a pre-mounted volume) — load_dataset returns the REAL records with
@@ -238,8 +237,8 @@ def test_real_cifar10_binary_layout_is_discovered(tmp_path):
         d["train_x"][0], rec0[1:].reshape(3, 32, 32).transpose(1, 2, 0))
 
     # and the fetch tool agrees the dataset is "present" at the SAME
-    # root the bench passes to ensure() (GEOMX_DATA_DIR), so the TTA
-    # phase attempts no download for a pre-mounted volume
+    # root a caller passes to ensure() (GEOMX_DATA_DIR), so that no
+    # download is attempted for a pre-mounted volume
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))), "tools"))

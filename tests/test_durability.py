@@ -502,47 +502,3 @@ def test_server_restart_publishes_incident(tmp_path):
         node="server_r0").value == 2
     assert reg.get("geomx_host_incidents_total").labels(
         kind="server_restart").value >= 1
-
-
-# ---- benchtrend RECOVERY series -------------------------------------------
-
-
-def test_benchtrend_gates_recovery_series(tmp_path):
-    import importlib
-    import json
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
-                                    "tools"))
-    try:
-        benchtrend = importlib.import_module("benchtrend")
-    finally:
-        sys.path.pop(0)
-    base = {"mode": "compare_recovery", "ok": True,
-            "params_bit_exact": True, "server_restarted": True,
-            "scheduler_restarted": True, "recovery_stall_bounded": True,
-            "scheduler_ids_stable": True, "scheduler_no_mass_evict": True,
-            "corrupt_zero_crashes": True, "corrupt_crc_nonzero": True,
-            "corrupt_loss_unchanged": True, "frame_cap_enforced": True,
-            "recovery_stall_s": 0.4}
-    (tmp_path / "RECOVERY_r01.json").write_text(json.dumps(base))
-    worse = dict(base)
-    worse["params_bit_exact"] = False
-    worse["ok"] = False
-    (tmp_path / "RECOVERY_r02.json").write_text(json.dumps(worse))
-    report = benchtrend.run(str(tmp_path))
-    regressed = {v["metric"] for v in report["regressions"]}
-    assert "params_bit_exact" in regressed and "ok" in regressed
-    # a healthy successor passes
-    (tmp_path / "RECOVERY_r02.json").write_text(json.dumps(base))
-    assert benchtrend.run(str(tmp_path))["passed"] is True
-
-
-def test_committed_recovery_record_is_green():
-    repo = os.path.join(os.path.dirname(__file__), os.pardir)
-    path = os.path.join(repo, "RECOVERY_r01.json")
-    import json
-    rec = json.load(open(path))
-    assert rec["mode"] == "compare_recovery"
-    assert rec["ok"] is True
-    assert rec["params_bit_exact"] is True
-    assert rec["corrupt"]["crc_errors"] > 0

@@ -211,6 +211,24 @@ def test_fleet_document_shape_and_rollups():
     assert json.loads(body)["fleet_version"] == doc["fleet_version"]
 
 
+def test_gxtop_renders_the_fleet_document():
+    """tools/gxtop.py names every node of the document with its health
+    and heads the table with the rollups' version and counts."""
+    import importlib.util
+    import os
+    spec = importlib.util.spec_from_file_location(
+        "gxtop", os.path.join(os.path.dirname(__file__), os.pardir,
+                              "tools", "gxtop.py"))
+    gxtop = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gxtop)
+    _, doc = _two_polls()
+    text = gxtop.render(doc)
+    assert text.startswith("fleet v2  nodes ok/stale/dead: 3/0/0")
+    for name, entry in doc["nodes"].items():
+        row = next(ln for ln in text.splitlines() if ln.startswith(name))
+        assert entry["health"] in row
+
+
 def test_roster_targets_shapes():
     roster = {
         "serve": [(900, "127.0.0.1", 8100, "gateway"),

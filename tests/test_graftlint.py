@@ -145,7 +145,7 @@ def test_env_read_outside_config_fires_gxl006_package_only():
     # config.py itself is the sanctioned reader
     assert _lint_source(src, path="geomx_tpu/config.py") == []
     # outside the package the rule doesn't apply
-    assert _lint_source(src, in_package=False, path="bench.py") == []
+    assert _lint_source(src, in_package=False, path="chip_smoke.py") == []
 
 
 # --------------------------------------------------------------------------

@@ -53,8 +53,7 @@ def test_unknown_model_raises():
 
 @pytest.mark.tier2
 def test_resnet20_space_to_depth_variant_trains():
-    """The flag-gated TPU stem experiment (bench config vanilla_s2d)
-    trains: the 2x2 space-to-depth stem halves every stage's resolution
+    """The flag-gated TPU stem experiment trains: the 2x2 space-to-depth stem halves every stage's resolution
     but keeps a working ResNet-20 sibling."""
     import optax
 

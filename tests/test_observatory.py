@@ -16,14 +16,12 @@ The contracts under test:
   crafted histories, deterministic auto-dump naming the poisoned
   party, and the trainer wiring (warn when riding without probes);
 - satellites: profiler dump span/dropped accounting, event-log
-  rotation counter, scheduler /healthz + build-info gauge, benchtrend
-  pass/fail on crafted series.
+  rotation counter, scheduler /healthz + build-info gauge.
 """
 
 import json
 import math
 import os
-import sys
 import urllib.request
 
 import numpy as np
@@ -52,8 +50,6 @@ from geomx_tpu.telemetry.roofline import (compiled_costs, device_peaks,
 from geomx_tpu.topology import HiPSTopology
 from geomx_tpu.train import Trainer
 from geomx_tpu.utils.profiler import Profiler
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _span(name, cat, ts, dur, pid=1, tid=1, args=None):
@@ -147,22 +143,46 @@ def test_attribute_trace_intergap_is_host_stall():
     assert att_raw["steps"][0]["host_stall"] == pytest.approx(0.0)
 
 
+def _modeled_trace(compute_us, dcn_us, comm_on_weight_path):
+    """A Chrome-trace timeline of steps of the given compute durations
+    with a DCN delay placed by the step's dependency structure:
+
+    - collective ON the weight path (synchronous): the step blocks on
+      the wire, so the comm span follows compute serially inside the
+      step window;
+    - collective OFF the weight path (pipelined): the collective
+      launched as step t's gradients land completes under step t+1's
+      compute, so the comm span overlaps the next window."""
+    events = []
+    t = 0.0
+    inflight_end = 0.0
+    for i, c in enumerate(compute_us):
+        comm_start = t + c  # launch when the grads are ready
+        if comm_on_weight_path:
+            step_dur = c + dcn_us
+        else:
+            step_dur = max(c, inflight_end - t)
+            inflight_end = comm_start + dcn_us
+        events.append(_span("train/step", "step", t, step_dur,
+                            args={"step": i}))
+        events.append(_span("train/compute", "compute", t, c))
+        events.append(_span("dc_allreduce/injected" if comm_on_weight_path
+                            else "dc_pipeline/launch", "comm", comm_start,
+                            dcn_us, tid=2))
+        t += step_dur
+    return {"traceEvents": events}
+
+
 def test_exposed_comms_drop_under_pipeline_depth_1():
     """THE acceptance case: identical compute + DCN delay, but the
     pipelined timeline launches each collective to land under the NEXT
     step's compute — the exposed fraction must drop (to zero when
-    compute covers the delay).  Uses bench's modeled-timeline builder
-    so the bench mode's math is the tested math."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
+    compute covers the delay)."""
     compute_us = [50_000.0] * 6
     dcn_us = 30_000.0
-    att_sync = attribute_trace(bench._modeled_attribution_trace(
+    att_sync = attribute_trace(_modeled_trace(
         compute_us, dcn_us, comm_on_weight_path=True))
-    att_pipe = attribute_trace(bench._modeled_attribution_trace(
+    att_pipe = attribute_trace(_modeled_trace(
         compute_us, dcn_us, comm_on_weight_path=False))
     assert sum(att_sync["summary"].values()) == pytest.approx(1.0)
     assert sum(att_pipe["summary"].values()) == pytest.approx(1.0)
@@ -175,9 +195,9 @@ def test_exposed_comms_drop_under_pipeline_depth_1():
     assert att_pipe["summary"]["hidden_comms"] > 0.0
     # delay larger than compute: overlap is partial but still a strict
     # improvement over the synchronous timeline
-    att_sync2 = attribute_trace(bench._modeled_attribution_trace(
+    att_sync2 = attribute_trace(_modeled_trace(
         compute_us, 80_000.0, comm_on_weight_path=True))
-    att_pipe2 = attribute_trace(bench._modeled_attribution_trace(
+    att_pipe2 = attribute_trace(_modeled_trace(
         compute_us, 80_000.0, comm_on_weight_path=False))
     assert (att_pipe2["summary"]["exposed_comms"]
             < att_sync2["summary"]["exposed_comms"])
@@ -564,7 +584,7 @@ def test_trainer_flight_records_carry_scoped_phase_breakdown(tmp_path):
     """The wired publish path feeds a phase summary into every flight
     record (the exposed_comms_jump rule's input), attributed over a
     window that restarts at each publish — spans from earlier profiled
-    work (a previous fit, a bench warmup) must not leak into it."""
+    work (a previous fit, a warm-up) must not leak into it."""
     import jax
 
     from geomx_tpu.utils.profiler import get_profiler
@@ -673,152 +693,3 @@ def test_scheduler_healthz_and_build_info():
         c.close()
     finally:
         sched.stop()
-
-
-# --------------------------------------------------------------------------
-# benchtrend: crafted series pass/fail
-# --------------------------------------------------------------------------
-
-def _bt():
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    try:
-        import benchtrend
-    finally:
-        sys.path.pop(0)
-    return benchtrend
-
-
-def _write_capture(d, name, value, mfu, step_ms, kind="TPU v5 lite"):
-    (d / name).write_text(json.dumps({
-        "metric": "m", "value": value, "unit": "samples/sec",
-        "mfu": mfu, "device": {"device_kind": kind},
-        "configs": {"vanilla": {"step_time_ms": step_ms, "mfu": mfu}},
-    }))
-
-
-def test_benchtrend_passes_within_band(tmp_path):
-    bt = _bt()
-    _write_capture(tmp_path, "BENCH_CAPTURED_r01.json", 1000.0, 0.17, 13.0)
-    _write_capture(tmp_path, "BENCH_CAPTURED_r02.json", 950.0, 0.165, 13.5)
-    report = bt.run(str(tmp_path), band=0.10)
-    assert report["passed"]
-    assert all(v["status"] == "ok"
-               for v in report["verdicts"]["BENCH_CAPTURED"])
-
-
-def test_benchtrend_fails_on_throughput_regression(tmp_path):
-    bt = _bt()
-    _write_capture(tmp_path, "BENCH_CAPTURED_r01.json", 1000.0, 0.17, 13.0)
-    _write_capture(tmp_path, "BENCH_CAPTURED_r02.json", 800.0, 0.17, 13.0)
-    report = bt.run(str(tmp_path), band=0.10)
-    assert not report["passed"]
-    bad = {v["metric"] for v in report["regressions"]}
-    assert "value" in bad
-    assert report["verdicts"]["BENCH_CAPTURED"][-1]["latest_run"] == \
-        "BENCH_CAPTURED_r02.json"
-
-
-def test_benchtrend_fails_on_step_time_regression_only_past_band(tmp_path):
-    bt = _bt()
-    _write_capture(tmp_path, "BENCH_CAPTURED_r01.json", 1000.0, 0.17, 10.0)
-    _write_capture(tmp_path, "BENCH_CAPTURED_r02.json", 1000.0, 0.17, 10.9)
-    assert bt.run(str(tmp_path), band=0.10)["passed"]   # +9% in band
-    _write_capture(tmp_path, "BENCH_CAPTURED_r02.json", 1000.0, 0.17, 11.5)
-    report = bt.run(str(tmp_path), band=0.10)            # +15% out
-    assert not report["passed"]
-    assert {v["metric"] for v in report["regressions"]} == \
-        {"configs.vanilla.step_time_ms"}
-
-
-def test_benchtrend_skips_cross_device_comparison(tmp_path):
-    bt = _bt()
-    _write_capture(tmp_path, "BENCH_CAPTURED_r01.json", 1000.0, 0.17,
-                   13.0, kind="TPU v5 lite")
-    _write_capture(tmp_path, "BENCH_CAPTURED_r02.json", 10.0, 0.01,
-                   900.0, kind="cpu")
-    report = bt.run(str(tmp_path), band=0.10)
-    assert report["passed"]
-    assert all(v["status"] == "skipped_device_mismatch"
-               for v in report["verdicts"]["BENCH_CAPTURED"])
-
-
-def test_benchtrend_multichip_ok_flip_is_a_regression(tmp_path):
-    bt = _bt()
-    (tmp_path / "MULTICHIP_r01.json").write_text(json.dumps(
-        {"n_devices": 8, "ok": True, "rc": 0, "skipped": False,
-         "tail": ""}))
-    (tmp_path / "MULTICHIP_r02.json").write_text(json.dumps(
-        {"n_devices": 8, "ok": False, "rc": 1, "skipped": False,
-         "tail": "boom"}))
-    report = bt.run(str(tmp_path), band=0.10)
-    assert not report["passed"]
-    assert {v["metric"] for v in report["regressions"]} == {"ok", "rc_ok"}
-
-
-def test_benchtrend_control_series_gated(tmp_path):
-    bt = _bt()
-
-    def _write_control(name, beats, ttt):
-        (tmp_path / name).write_text(json.dumps({
-            "mode": "compare_control",
-            "controller_beats_all_static": beats,
-            "decision_log_deterministic": True,
-            "ratio_retune_without_recompile": True,
-            "controller": {"time_to_target_s": ttt}}))
-
-    _write_control("CONTROL_r01.json", True, 2.0)
-    _write_control("CONTROL_r02.json", True, 2.1)     # +5% in band
-    report = bt.run(str(tmp_path), band=0.10)
-    assert report["passed"]
-    assert {v["metric"] for v in report["verdicts"]["CONTROL"]} == {
-        "controller_beats_all_static", "decision_log_deterministic",
-        "ratio_retune_without_recompile", "controller.time_to_target_s"}
-    # a gate flip AND a time-to-target blowup both regress
-    _write_control("CONTROL_r03.json", False, 5.0)
-    report = bt.run(str(tmp_path), band=0.10)
-    assert not report["passed"]
-    assert {v["metric"] for v in report["regressions"]} == {
-        "controller_beats_all_static", "controller.time_to_target_s"}
-
-
-def test_benchtrend_missing_metric_reported_not_fatal(tmp_path):
-    bt = _bt()
-    _write_capture(tmp_path, "BENCH_CAPTURED_r01.json", 1000.0, 0.17, 13.0)
-    (tmp_path / "BENCH_CAPTURED_r02.json").write_text(json.dumps({
-        "metric": "m", "value": 1010.0, "unit": "samples/sec",
-        "device": {"device_kind": "TPU v5 lite"}}))   # mfu/configs gone
-    report = bt.run(str(tmp_path), band=0.10)
-    assert report["passed"]
-    missing = {v["metric"] for v in
-               report["verdicts"]["BENCH_CAPTURED"]
-               if v["status"] == "missing"}
-    assert "mfu" in missing
-
-
-def test_benchtrend_unreadable_series_fails(tmp_path):
-    bt = _bt()
-    _write_capture(tmp_path, "BENCH_CAPTURED_r01.json", 1000.0, 0.17, 13.0)
-    (tmp_path / "BENCH_CAPTURED_r02.json").write_text("{not json")
-    report = bt.run(str(tmp_path))
-    assert not report["passed"]
-    assert report["unreadable"]
-
-
-def test_benchtrend_cli_json_and_exit_codes(tmp_path, capsys):
-    bt = _bt()
-    _write_capture(tmp_path, "BENCH_CAPTURED_r01.json", 1000.0, 0.17, 13.0)
-    _write_capture(tmp_path, "BENCH_CAPTURED_r02.json", 500.0, 0.17, 13.0)
-    rc = bt.main(["--repo-dir", str(tmp_path), "--json"])
-    out = json.loads(capsys.readouterr().out.strip())
-    assert rc == 1 and not out["passed"]
-    _write_capture(tmp_path, "BENCH_CAPTURED_r02.json", 990.0, 0.17, 13.0)
-    assert bt.main(["--repo-dir", str(tmp_path), "--json"]) == 0
-    assert bt.main(["--repo-dir", str(tmp_path), "--band", "-1"]) == 2
-
-
-def test_benchtrend_committed_series_passes():
-    """The repo's own committed trajectory must gate green — this is
-    the CI `benchtrend` step's exact invocation."""
-    bt = _bt()
-    report = bt.run(REPO)
-    assert report["passed"], report["regressions"]

@@ -14,8 +14,8 @@ Evidence layers, all in Pallas interpret mode on the CPU backend:
   optax chain within accumulated-FMA tolerance.
 - *Structure*: the fused bucket update cross-lowers to tpu_custom_call
   with ZERO stablehlo.multiply; the per-leaf chain keeps its multiplies
-  and has no custom call (the bench --compare-mfu DCE gate's unit
-  form).
+  and has no custom call; the whole train step holds the kernels
+  only when fused.
 - *Training integration*: GeoConfig(fused_optim=True) lands on the
   unfused trajectory through the full shard_mapped step (replicated and
   ZeRO-sharded), and the loud rejections (plain optax tx, bucketing
@@ -234,6 +234,28 @@ def test_fused_step_matches_unfused(kind, zero):
     gap = max(jax.tree.leaves(jax.tree.map(
         lambda a, b: float(np.max(np.abs(a - b))), pf, pu)))
     assert gap < 1e-5, gap
+
+
+@pytest.mark.parametrize("fused", [True, False],
+                         ids=["fused", "per_leaf"])
+def test_step_lowering_holds_the_kernels_only_when_fused(fused):
+    """The whole train step lowered for a TPU: the fused build holds
+    Mosaic calls, the per-leaf optax chain none (the Trainer is built
+    under ``kernels("native")`` so that the optimizer takes its kernels;
+    the lowering itself runs on the CPU's dispatch)."""
+    from geomx_tpu.analysis.hlo import lower_text
+    from geomx_tpu.ops.dispatch import kernels
+
+    xs, ys = _data(steps=1)
+    with kernels("native"):
+        tr, topo = _trainer(fused_optimizer("sgd", learning_rate=0.1,
+                                            momentum=0.9),
+                            fused_optim=fused)
+    st = tr.init_state(jax.random.PRNGKey(0), xs[0, 0, 0, :2])
+    sh = topo.batch_sharding(tr.mesh)
+    calls = lower_text(tr.train_step, st, jax.device_put(xs[0], sh),
+                       jax.device_put(ys[0], sh)).count("tpu_custom_call")
+    assert (calls >= 1) if fused else (calls == 0), calls
 
 
 def test_fused_requires_fused_optimizer():

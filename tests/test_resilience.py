@@ -553,3 +553,29 @@ def test_resilience_env_knobs(monkeypatch):
         ctrl.mark_dead(1)
     # no chaos configured -> no schedule
     assert ChaosSchedule.from_config(GeoConfig()) is None
+
+
+def test_blackout_readmit_cycle_leaves_replicas_identical():
+    """A seeded schedule blacks party 1 out at step 3 for three steps:
+    the run steps through both recompile boundaries, the live count goes
+    2 -> 1 -> 2, the survivors have a catch-up payload for the returning
+    party, and after the cycle every replica holds the same params."""
+    trainer, state, xb, yb, _, _ = _mk_trainer(FSA())
+    ctrl = PartyLivenessController(num_parties=2)
+    sched = ChaosSchedule.from_spec("seed=1234;blackout@3:party=1,steps=3")
+    current, live, catchup = ctrl.epoch, [], None
+    with ChaosEngine(sched, ctrl) as eng:
+        for step in range(9):
+            eng.tick(step)
+            ep = ctrl.epoch
+            if ep.version != current.version:
+                if ep.num_live > current.num_live:
+                    catchup = trainer.catchup_payload(state)
+                state = trainer.apply_membership(state, ep)
+                current = ep
+            state, m = trainer.train_step(state, xb, yb)
+            live.append(float(m["num_live_parties"]))
+    assert live == [2.0] * 3 + [1.0] * 3 + [2.0] * 3
+    assert catchup
+    for leaf in jax.tree.leaves(jax.device_get(state.params)):
+        assert np.array_equal(leaf[0, 0], leaf[1, 0])

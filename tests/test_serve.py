@@ -40,7 +40,9 @@ from geomx_tpu.serve.registry import (ModelRegistry, RegistryClient,
                                       RegistryServer)
 from geomx_tpu.serve.replica import ServingReplica
 from geomx_tpu.telemetry.ledger import (REQUEST_PHASES, RequestLedger,
-                                        reset_request_ledger)
+                                        get_round_ledger,
+                                        reset_request_ledger,
+                                        reset_round_ledger)
 
 
 # --------------------------------------------------------------------------
@@ -816,6 +818,50 @@ def test_train_while_serving_delta_refresh_bit_exact(tmp_path):
         replica_cli.close()
         srv.stop()
         srv.join(5.0)
+
+
+def test_delta_refresh_bytes_on_the_round_ledger(tmp_path):
+    """Delta-only refresh by the wire's own books: the registry frames
+    carry their round and declared size, so the round ledger attributes
+    every pushed byte.  The base publish is round 0; each later round's
+    pair frames declare no more than arrived and are a fraction of the
+    dense checkpoint."""
+    reset_round_ledger()
+    rng = np.random.default_rng(11)
+    srv = RegistryServer(durable_dir=str(tmp_path))
+    srv.start()
+    trainer = RegistryClient(srv.addr, sender=0, timeout_s=10.0)
+    params = {"0000/w": rng.normal(size=(256, 64)).astype(np.float32),
+              "0001/b": rng.normal(size=(4096,)).astype(np.float32)}
+    dense_bytes = sum(v.nbytes for v in params.values())
+    rounds = 3
+    try:
+        trainer.publish("v1", params)
+        for r in range(1, rounds + 1):
+            layers = {}
+            for k, v in params.items():
+                n = v.size // 100
+                layers[k] = (rng.normal(size=n).astype(np.float32),
+                             rng.choice(v.size, size=n,
+                                        replace=False).astype(np.int64))
+            trainer.push_delta("v1", r, layers)
+        base_rx = delta_rx = 0
+        for rec in get_round_ledger().records():
+            if not str(rec.get("key", "")).startswith("v1/"):
+                continue
+            got = int(rec["wire"].get("push_rx_bytes", 0))
+            if int(rec["round"]) == 0:
+                base_rx += got
+            else:
+                delta_rx += got
+                assert 0 < int(rec["declared_rx_bytes"]) <= got, rec
+        assert base_rx >= dense_bytes
+        assert 0 < delta_rx / rounds < 0.5 * dense_bytes
+    finally:
+        trainer.close()
+        srv.stop()
+        srv.join(5.0)
+        reset_round_ledger()
 
 
 # --------------------------------------------------------------------------

@@ -517,7 +517,7 @@ def test_join_gates_on_stop_forward_completion(monkeypatch):
 def test_ps_plane_throughput_tool():
     """tools/bench_service.py drives W concurrent clients through the
     sync merge barrier and reports goodput — the PS plane's perf story
-    (bench.py covers only the SPMD plane)."""
+    (the chip benchmark covers only the SPMD plane)."""
     import importlib.util
     import os as _os
     spec = importlib.util.spec_from_file_location(

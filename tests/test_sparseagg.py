@@ -321,6 +321,85 @@ def test_lattice_wire_bytes_honest():
         leaf) == 4096
 
 
+@pytest.mark.parametrize("make,with_state", [
+    (lambda: FP16Compressor(sparse_agg=True), False),
+    (lambda: TwoBitCompressor(0.5, sparse_agg=True), True),
+], ids=["fp16", "2bit"])
+def test_lattice_traces_an_integer_psum_and_no_gather(make, with_state):
+    """The structure the tier exists for: under the gate the leaf's
+    allreduce is an integer-lattice psum, and nothing is gathered."""
+    from geomx_tpu.analysis.core import walk_jaxpr
+    from geomx_tpu.analysis.passes import _GATHER_PRIMS
+
+    P_, n = 3, 4096
+    mesh = _dc_mesh(P_)
+    comp = make()
+
+    def f(gs, ss):
+        out, s2 = comp.allreduce_leaf(
+            gs[0], ss[0] if with_state else (), DC_AXIS, P_)
+        return out[None], (s2[None] if with_state else gs[:0])
+
+    fn = shard_map_compat(f, mesh, in_specs=(P(DC_AXIS),) * 2,
+                          out_specs=(P(DC_AXIS),) * 2)
+    z = jnp.zeros((P_, n), jnp.float32)
+    sites = list(walk_jaxpr(jax.make_jaxpr(fn)(z, z)))
+    int_psums = [
+        s for s in sites if s.primitive in ("psum", "psum2")
+        and {str(v.aval.dtype) for v in s.eqn.invars
+             if hasattr(v, "aval")} & {"int8", "int16", "int32"}]
+    assert len(int_psums) >= 1
+    assert not [s for s in sites if s.primitive in _GATHER_PRIMS]
+
+
+def test_zero_shard_streams_bit_exact_across_engines(rng):
+    """ZeRO composition: the shard-sized streams of
+    ``BucketedCompressor.allreduce_shards`` run the same owner-routed
+    merge, outputs and EF state bit-identical between the jnp and the
+    Pallas (interpret) engines."""
+    from geomx_tpu.compression import BucketedCompressor
+    from geomx_tpu.ops.dispatch import kernels
+
+    P_, W = 3, 2
+    mesh = _dc_mesh(P_)
+    params = [jnp.asarray(rng.normal(0, 1, s).astype(np.float32))
+              for s in (3000, 1100)]
+
+    def run():
+        bucketed = BucketedCompressor(
+            BiSparseCompressor(ratio=0.02, min_sparse_size=1,
+                               sparse_agg=True),
+            bucket_bytes=64 * 1024, pad_to=128 * W)
+        bk = bucketed.zero_bucketer(params)
+        shards = [b[:n // W]
+                  for b, n in zip(bk.flatten(params), bk.bucket_sizes)]
+        state = bucketed.init_shard_state(params, W)
+
+        def f(sh, ss):
+            out, s2 = bucketed.allreduce_shards(
+                [a[0] for a in sh], jax.tree.map(lambda a: a[0], ss),
+                DC_AXIS, P_, bk)
+            return ([a[None] for a in out],
+                    jax.tree.map(lambda a: a[None], s2))
+
+        fn = shard_map_compat(f, mesh, in_specs=(P(DC_AXIS),) * 2,
+                              out_specs=(P(DC_AXIS),) * 2)
+
+        def stack(t):
+            return jax.tree.map(lambda a: jnp.stack([a] * P_), t)
+
+        return [np.asarray(a) for a in jax.tree.leaves(
+            jax.jit(fn)(stack(shards), stack(state)))]
+
+    oj = run()
+    with kernels("interpret"):
+        of = run()
+    assert len(oj) == len(of)
+    for a, b in zip(oj, of):
+        np.testing.assert_array_equal(a, b)
+    assert any((a != 0).any() for a in oj)
+
+
 # ---------- host-plane merge ----------
 
 
@@ -385,6 +464,13 @@ def test_server_sparse_merge_bit_exact_across_arrival_orders():
         1: _pairs_payload([-1e8, 2.0], [3, 20]),
         2: _pairs_payload([1.0, -1.0], [3, 10]),
     }
+    from geomx_tpu.telemetry import get_registry
+
+    def sparse_merges():
+        fam = get_registry().get("geomx_server_sparse_merges_total")
+        return sum(ch.value for _, ch in fam.children()) if fam else 0.0
+
+    before = sparse_merges()
     outs = []
     for order in ((0, 1, 2), (2, 1, 0), (1, 2, 0)):
         srv = GeoPSServer(num_workers=3, mode="sync").start()
@@ -400,6 +486,8 @@ def test_server_sparse_merge_bit_exact_across_arrival_orders():
         srv.join(5)
     assert np.array_equal(outs[0], outs[1])
     assert np.array_equal(outs[0], outs[2])
+    # every round was merged in pair form, never densified on arrival
+    assert sparse_merges() - before >= 3
 
 
 def test_densify_sums_duplicate_indices_like_legacy():

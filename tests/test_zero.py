@@ -143,6 +143,9 @@ def test_zero_membership_carry_keeps_worker_shards():
     tr, topo = _trainer(True, compression="bsc,0.05,min_sparse_size=16")
     st = tr.init_state(jax.random.PRNGKey(0), xs[0, 0, 0])
     st = _run(tr, topo, st, xs, ys)
+    # the compressed shard path trains: finite params after its steps
+    assert all(np.isfinite(np.asarray(leaf)).all()
+               for leaf in jax.tree.leaves(st.params))
     before = jax.tree.map(np.asarray, st.sync_state)
     st2 = tr.apply_membership(st, (True, False), policy="carry")
     after = jax.tree.map(np.asarray, st2.sync_state)
@@ -191,23 +194,26 @@ def test_zero_ef_residuals_are_shard_local():
 # structure: collectives, donation, purity
 # --------------------------------------------------------------------------
 
-def _weight_path_counts(tr, st, xb, yb):
-    from bench import _weight_path_collectives
-    return _weight_path_collectives(tr.train_step, st, xb, yb)
-
-
-def test_zero_weight_path_swaps_allreduce_for_scatter_gather():
-    from geomx_tpu.analysis.passes import _GATHER_PRIMS, _SCATTER_PRIMS
+@pytest.mark.parametrize("compression", ["none", "bsc,0.01"])
+def test_zero_weight_path_swaps_allreduce_for_scatter_gather(compression):
+    """On the path to params / opt_state (BatchNorm-stat pmeans feed
+    model_state and are left out on purpose: statistics maintenance, not
+    the weight update) the replicated step keeps its worker-axis psum;
+    the ZeRO step keeps psum_scatter + all_gather and no worker-axis
+    psum."""
+    from geomx_tpu.analysis.passes import (_GATHER_PRIMS, _SCATTER_PRIMS,
+                                           weight_path_collectives)
     xs, ys = _data()
     counts = {}
     for zero in (False, True):
-        tr, topo = _trainer(zero)
+        tr, topo = _trainer(zero, compression=compression)
         st = tr.init_state(jax.random.PRNGKey(0), xs[0, 0, 0])
         sh = topo.batch_sharding(tr.mesh)
-        counts[zero] = _weight_path_counts(
-            tr, st, jax.device_put(xs[0], sh), jax.device_put(ys[0], sh))
-    rep_w = counts[False]["worker_axis"]
-    zero_w = counts[True]["worker_axis"]
+        on_path, _whole = weight_path_collectives(
+            tr.train_step, st, jax.device_put(xs[0], sh),
+            jax.device_put(ys[0], sh))
+        counts[zero] = on_path.get("worker", {})
+    rep_w, zero_w = counts[False], counts[True]
     assert rep_w.get("psum", 0) > 0
     assert not any(k in rep_w for k in _SCATTER_PRIMS)
     assert zero_w.get("psum", 0) == 0, zero_w
@@ -248,10 +254,13 @@ def test_zero_donated_step_aliases_sharded_state():
     assert compiled_params == frozenset(range(n_state))
 
 
-def test_zero_compressed_shard_path_purity():
+@pytest.mark.parametrize("sparse_agg", [False, True],
+                         ids=["gather", "sparse_agg"])
+def test_zero_compressed_shard_path_purity(sparse_agg):
     """GX-PURITY at the shard floor: the ZeRO dc tier's collectives all
-    carry sub-shard payloads for bsc; a decompress-before-collective
-    variant is flagged."""
+    carry sub-shard payloads for bsc, on the gather path and on the
+    owner-routed merge; a decompress-before-collective variant is
+    flagged."""
     from geomx_tpu.analysis import audit_zero_compressed_path
     from geomx_tpu.compression.bisparse import BiSparseCompressor
     from geomx_tpu.compression.bucketing import BucketedCompressor
@@ -260,7 +269,7 @@ def test_zero_compressed_shard_path_purity():
     params = {"a": jnp.zeros((6000,), jnp.float32),
               "b": jnp.zeros((300,), jnp.float32)}
     comp = BucketedCompressor(BiSparseCompressor(
-        ratio=0.05, min_sparse_size=16))
+        ratio=0.05, min_sparse_size=16, sparse_agg=sparse_agg))
     ZeroPlan(W_).bind_compressor(comp)
     assert audit_zero_compressed_path(comp, params, num_shards=W_) == []
 
@@ -277,7 +286,7 @@ def test_zero_compressed_shard_path_purity():
                     (u.reshape(g.shape), v.reshape(g.shape)))
 
     leaky = BucketedCompressor(DenseLeak(
-        ratio=0.05, min_sparse_size=16))
+        ratio=0.05, min_sparse_size=16, sparse_agg=sparse_agg))
     ZeroPlan(W_).bind_compressor(leaky)
     findings = audit_zero_compressed_path(leaky, params, num_shards=W_)
     assert findings and all(f.rule_id == "GX-PURITY-001"

@@ -1,7 +1,7 @@
 """Host PS-plane throughput microbench (no accelerator needed).
 
-The SPMD plane's performance is covered by bench.py; this tool measures
-the OTHER plane — the process-separated TCP parameter-server service
+The SPMD plane's performance is the chip benchmark's (`benchmark/`);
+this tool measures the OTHER plane — the process-separated TCP parameter-server service
 that backs the async modes (MixedSync/HFA over real WAN deployments,
 reference ps-lite Van/ZMQVan).  It drives W concurrent worker clients
 push+pulling an N-MB tensor against one sync-mode server for R rounds

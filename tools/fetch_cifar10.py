@@ -1,11 +1,10 @@
 """Fetch CIFAR-10 (binary version) into the data root.
 
-The bench's north star is time-to-92%-accuracy on REAL CIFAR-10
+The north star is time-to-92%-accuracy on REAL CIFAR-10
 (BASELINE.md); the dataset is not redistributable inside the repo, so
 this script provisions it at run time when the environment has network
-egress.  `bench.py` calls `ensure(quiet=True)` before the
-time-to-accuracy run and falls back to the synthetic proxy (recording
-the denial) when the download is impossible.
+egress.  `ensure(quiet=True)` returns instead of raising when the
+download is impossible.
 
 Usage: python tools/fetch_cifar10.py [dest_root]
 Dest defaults to $GEOMX_DATA_DIR or /root/data; the extracted layout is

@@ -74,7 +74,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 DEFAULT_ROOTS = ("geomx_tpu", "tools", "tests", "examples", "scripts",
-                 "bench.py", "__graft_entry__.py")
+                 "__graft_entry__.py")
 BASELINE_PATH = os.path.join(REPO_ROOT, "tools", "graftlint_baseline.json")
 
 # entry points whose function-valued arguments are traced

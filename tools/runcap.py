@@ -14,14 +14,11 @@ whole observability state.  This tool is the operator's read side:
 - ``explain <a> <b>``      — the ranked "what moved" findings: the
   degraded link, the phase fraction that grew, the probe or honesty
   ratio that drifted — what a tripped perf gate should NAME instead
-  of just flipping red.  ``tools/benchtrend.py`` calls this
-  automatically when a gated series regresses and both runs carry
-  capsule artifacts.
+  of just flipping red.
 
 ``diff``/``explain``/``info`` are pure stdlib readers over the
-capsule's pre-computed ``summary`` section (benchtrend imports them
-without pulling in jax or the repo); only ``snapshot`` re-runs the
-real replay fold.
+capsule's pre-computed ``summary`` section (importable without jax or
+the repo); only ``snapshot`` re-runs the real replay fold.
 
 Exit status: 0 on success, 2 on usage / unreadable-capsule errors.
 """
