@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from geomx_tpu.models.afmoe import AfmoeConfig, AfmoeLM
 from geomx_tpu.models.cnn import GeoCNN
+from geomx_tpu.models.glm4_moe_lite import Glm4MoeLiteConfig, Glm4MoeLiteLM
 from geomx_tpu.models.kimi_linear import KimiLinearConfig, KimiLinearLM
 from geomx_tpu.models.mellum import MellumConfig, MellumLM
 from geomx_tpu.models.mlp import MLP, AlexNet
@@ -20,7 +21,7 @@ __all__ = ["GeoCNN", "MLP", "AlexNet",
            "ResNet", "ResNet20", "ResNet32", "ResNet56", "ResNet18",
            "SeqClassifier", "KimiLinearConfig", "KimiLinearLM", "AfmoeConfig",
            "AfmoeLM", "NemotronHConfig", "NemotronHLM", "MellumConfig",
-           "MellumLM", "get_model"]
+           "MellumLM", "Glm4MoeLiteConfig", "Glm4MoeLiteLM", "get_model"]
 
 # GEOMX_PRECISION -> the models' compute dtype.  Params always stay
 # fp32 (flax casts per-op from the fp32 masters); every model's
@@ -35,9 +36,10 @@ def get_model(name: str, num_classes: int = 10, precision: str = None,
     dtype explicitly; the default ``None`` keeps each model's
     historical default (byte-identical traces).  ``sizes``: the fields of
     `KimiLinearConfig` for ``"kimi_linear"``, of `AfmoeConfig` for
-    ``"afmoe"``, of `NemotronHConfig` for ``"nemotron_h"`` and of
-    `MellumConfig` for ``"mellum"``, causal decoders that bring their own
-    next-token loss (no ``num_classes``)."""
+    ``"afmoe"``, of `NemotronHConfig` for ``"nemotron_h"``, of
+    `MellumConfig` for ``"mellum"`` and of `Glm4MoeLiteConfig` for
+    ``"glm4_moe_lite"``, causal decoders that bring their own next-token
+    loss (no ``num_classes``)."""
     name = name.lower()
     dt = {}
     if precision is not None:
@@ -50,6 +52,8 @@ def get_model(name: str, num_classes: int = 10, precision: str = None,
         return NemotronHLM(NemotronHConfig(**sizes), **dt)
     if name == "mellum":
         return MellumLM(MellumConfig(**sizes), **dt)
+    if name == "glm4_moe_lite":
+        return Glm4MoeLiteLM(Glm4MoeLiteConfig(**sizes), **dt)
     if name in ("cnn", "geocnn", "lenet"):
         return GeoCNN(num_classes=num_classes, **dt)
     if name == "mlp":

@@ -1,9 +1,10 @@
 """What the causal decoders share (`models/kimi_linear.py`,
-`models/afmoe.py`, `models/nemotron_h.py`, `models/mellum.py`): RMSNorm,
-the SwiGLU MLP, the router, the expert layer that holds some of its experts
+`models/afmoe.py`, `models/nemotron_h.py`, `models/mellum.py`,
+`models/glm4_moe_lite.py`): RMSNorm, the SwiGLU MLP, the latent-attention
+mixer, the router, the expert layer that holds some of its experts
 (`ops/held_experts.py`), the residual block whose two halves are
-rematerialised apart, the model around the blocks and its blocked
-next-token loss.
+rematerialised apart, the model around the blocks, its blocked next-token
+loss and the multi-token-prediction modules behind it.
 
     h += [PostNorm](Mixer(RMSNorm(h)));  h += [PostNorm](FFN(RMSNorm(h)))
 
@@ -19,7 +20,9 @@ of a block's mixer half), `post_norms` (a second RMSNorm on each half's
 output), `embedding_scale` and `expert_form` (further fields of
 `HeldExpertsLayer`: {} for SwiGLU experts in the hidden width; a
 LatentMoE gives ``latent``, ``gated`` False and ``shared_width``; a
-softmax router gives ``scoring``).
+softmax router gives ``scoring``).  A configuration may also give
+``mtp_depth`` (0 where it does not), with ``mtp_weight`` and ``mtp_block``
+((mixer, ffn) of a module's block): see `MTPModule`.
 
 The model brings its own loss (`loss_and_aux`): mean next-token
 cross-entropy in float32, blocked over tokens so that no whole logits
@@ -27,11 +30,13 @@ array lives; `train/step.make_loss_fn` takes it from there.  In the
 backward pass each block's mixer is rematerialised a sequence at a time and
 its feed-forward half on its own.
 
-Scopes (telemetry/layers.SCOPES): ``moe/route``, ``moe/experts`` (inside
-it ``ops/held_experts``' own ``moe/plan`` and ``moe/dispatch``),
-``moe/shared``, ``moe/latent``, ``lm/loss``, and the mixers' own.  Module
-names are ``mixer``, ``ffn``, ``core``, ``norm`` and ``post_norm`` so that
-flax's own name stack never reads as one of them.
+Scopes (telemetry/layers.SCOPES): ``mla/proj`` and ``mla/attention``
+(`LatentMixer`), ``moe/route``, ``moe/experts`` (inside it
+``ops/held_experts``' own ``moe/plan`` and ``moe/dispatch``),
+``moe/shared``, ``moe/latent``, ``lm/loss``, ``mtp/module`` and inside it
+``mtp/combine``, and the other mixers' own.  Module names are ``mixer``,
+``ffn``, ``core``, ``norm``, ``post_norm``, ``block`` and ``mtp<k>`` so
+that flax's own name stack never reads as one of them.
 """
 
 from __future__ import annotations
@@ -43,6 +48,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from geomx_tpu.ops.flash_attention import fused_attention
+from geomx_tpu.ops.gqa_elementwise import rotary_tables
 from geomx_tpu.ops.held_experts import held_experts, places_walked
 from geomx_tpu.utils.profiler import profile_scope
 
@@ -109,6 +116,83 @@ class MLP(nn.Module):
         return swiglu(x, mat("gate_kernel", (hidden, self.width)),
                       mat("up_kernel", (hidden, self.width)),
                       mat("down_kernel", (self.width, hidden)))
+
+
+class LatentMixer(nn.Module):
+    """Latent attention (MLA): keys and values through a ``kv_rank``-wide
+    latent with its own RMSNorm, each head's key a ``nope_dim`` part of its
+    own beside ONE ``rope_dim`` part all heads share, ``v_dim``-wide values,
+    causal softmax(q k^T / sqrt(nope + rope)) v through
+    `ops/flash_attention.fused_attention`.
+
+    ``q_rank`` None: one W_q (``q_kernel``); else the query goes through a
+    latent of that width with its own RMSNorm (``q_a_kernel``, ``q_norm``,
+    ``q_b_kernel``).  ``rope`` None: no positions at all; else rotate-half
+    rotary over the ``rope_dim`` parts from the tables
+    `ops/gqa_elementwise.rotary_tables` makes of it (a theta or a `Yarn`),
+    positions 0..L-1: each head's of q, and the shared key part once,
+    before it is handed to every head."""
+    num_heads: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    kv_rank: int
+    eps: float
+    dtype: Any = jnp.float32
+    q_rank: Optional[int] = None
+    rope: Any = None
+
+    @nn.compact
+    def __call__(self, x):
+        h, dt, hidden = self.num_heads, self.dtype, x.shape[-1]
+        b, length, _ = x.shape
+        qk = self.nope_dim + self.rope_dim
+        mat = lambda name, shape: self.param(name, _fan_in, shape)
+        with profile_scope("mla/proj", "compute"):
+            if self.q_rank is None:
+                q = jnp.dot(x, mat("q_kernel", (hidden, h * qk)).astype(dt))
+            else:
+                q = RMSNorm(self.eps, name="q_norm")(jnp.dot(
+                    x, mat("q_a_kernel", (hidden, self.q_rank)).astype(dt)))
+                q = jnp.dot(q, mat("q_b_kernel",
+                                   (self.q_rank, h * qk)).astype(dt))
+            kv = jnp.dot(x, mat("kv_a_kernel",
+                                (hidden, self.kv_rank + self.rope_dim))
+                         .astype(dt))
+            latent = RMSNorm(self.eps, name="kv_norm")(
+                kv[..., :self.kv_rank])
+            shared = kv[..., self.kv_rank:]        # one key part, all heads
+            kv_b = jnp.dot(latent, mat(
+                "kv_b_kernel", (self.kv_rank, h * (self.nope_dim + self.v_dim))
+            ).astype(dt)).reshape(b, length, h, self.nope_dim + self.v_dim)
+            if self.rope is not None:
+                cos, sin = rotary_tables(length, self.rope_dim, self.rope)
+                shared = turn(shared, cos, sin)
+            k = jnp.concatenate(
+                [kv_b[..., :self.nope_dim], jnp.broadcast_to(
+                    shared[:, :, None, :], (b, length, h, self.rope_dim))],
+                -1)
+            v = kv_b[..., self.nope_dim:]
+            q = q.reshape(b, length, h, qk)
+            if self.rope is not None:
+                q = jnp.concatenate(
+                    [q[..., :self.nope_dim],
+                     turn(q[..., self.nope_dim:], cos[:, None], sin[:, None])],
+                    -1)
+        with profile_scope("mla/attention", "kernel"):
+            o = fused_attention(q, k, v, True)
+        with profile_scope("mla/proj", "compute"):
+            return jnp.dot(o.reshape(b, length, h * self.v_dim),
+                           mat("out_kernel", (h * self.v_dim, hidden))
+                           .astype(dt))
+
+
+def turn(x, cos, sin):
+    """Rotate-half rotary of the last axis under `rotary_tables`' (cos,
+    signed sin), broadcastable to x; arithmetic in float32."""
+    x32 = x.astype(jnp.float32)
+    return (x32 * cos + jnp.roll(x32, x.shape[-1] // 2, -1) * sin).astype(
+        x.dtype)
 
 
 def route(x, router, bias, top_k: int, scaling: float,
@@ -277,6 +361,34 @@ class Block(nn.Module):
                                 name="ffn")(h)
 
 
+class MTPModule(nn.Module):
+    """One depth of multi-token prediction (DeepSeek-V3, arXiv:2412.19437,
+    eq. 21-23): the stream of the depth before (the main model's before
+    its final norm at depth 1) and the embedding of the token one further
+    on, each under an RMSNorm of its own, joined by one matrix, through
+    one more block:
+
+        h' = [N_h(h) ; N_e(Emb(t))] W_eh;   h'' = Block(h')
+
+    Returns (h'' for the next depth, N_out(h'') for the shared head, the
+    block's assignments arrived and dropped).  Embedding and head are the
+    model's own and stay with it."""
+    cfg: Any
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, stream, embedded):
+        c, dt, hidden = self.cfg, self.dtype, stream.shape[-1]
+        with profile_scope("mtp/combine", "compute"):
+            joined = jnp.concatenate(
+                [RMSNorm(c.eps, name="hidden_norm")(stream),
+                 RMSNorm(c.eps, name="token_norm")(embedded)], -1)
+            h = jnp.dot(joined, self.param(
+                "join_kernel", _fan_in, (2 * hidden, hidden)).astype(dt))
+        h, counts, dropped = Block(*c.mtp_block, c, dt, name="block")(h)
+        return h, RMSNorm(c.eps, name="out_norm")(h), counts, dropped
+
+
 class DecoderLM(nn.Module):
     cfg: Any
     dtype: Any = jnp.float32
@@ -290,40 +402,95 @@ class DecoderLM(nn.Module):
         self.final_norm = RMSNorm(c.eps, name="final_norm")
         self.head_kernel = self.param("head_kernel", _fan_in,
                                       (c.hidden, c.vocab))
+        self.mtp = [MTPModule(c, self.dtype, name=f"mtp{k + 1}")
+                    for k in range(getattr(c, "mtp_depth", 0))]
 
-    def features(self, tokens):
-        """(normed features [B, L, hidden], assignments that arrived at
-        each held expert of each expert layer, assignments dropped)."""
+    def embed(self, tokens):
         h = self.embedding.astype(self.dtype)[tokens.astype(jnp.int32)]
         if self.cfg.embedding_scale != 1.0:
             h = h * jnp.asarray(self.cfg.embedding_scale, self.dtype)
+        return h
+
+    def features(self, tokens):
+        """(normed features [B, L, hidden], assignments that arrived at
+        each held expert of each expert layer, assignments dropped, the
+        stream before the final norm)."""
+        h = self.embed(tokens)
         arrived, dropped = [], jnp.zeros((), jnp.int32)
         for block in self.blocks:
             h, counts, lost = block(h)
             arrived.append(counts)
             dropped = dropped + lost
-        return self.final_norm(h), jnp.concatenate(arrived), dropped
+        return self.final_norm(h), jnp.concatenate(arrived), dropped, h
 
     def __call__(self, tokens, train: bool = False):
         """Whole logits [B, L, vocab] in float32: init, eval, small
         inputs.  Training takes `loss_and_aux`."""
-        return jnp.dot(self.features(tokens)[0],
-                       self.head_kernel.astype(self.dtype),
+        h, _, _, stream = self.features(tokens)
+        if self.is_initializing():      # the modules' parameters exist too
+            for module in self.mtp:
+                stream = module(stream, self.embed(tokens))[0]
+        return jnp.dot(h, self.head_kernel.astype(self.dtype),
                        preferred_element_type=jnp.float32)
+
+    def mtp_losses(self, stream, labels):
+        """The modules' chain over ``stream`` [B, L, hidden] (the main
+        model's before its final norm): depth k joins position i's stream
+        of depth k - 1 with the embedding of token i + k (``labels``
+        shifted k - 1 to the left) and is held to token i + k + 1
+        (``labels`` shifted k) at the L - k positions a row that have one;
+        the module runs on all L positions, the others' labels masked:
+        causal, so nothing that counts reads them.  Returns (mean
+        cross-entropy of each depth [D], argmax hits of each depth [D],
+        assignments arrived, dropped)."""
+        b, length = labels.shape
+        ahead = lambda k, fill: jnp.pad(
+            labels[:, k:], ((0, 0), (0, k)), constant_values=fill)
+        losses, hits, arrived, dropped = [], [], [], jnp.zeros((), jnp.int32)
+        for k, module in enumerate(self.mtp, start=1):
+            with profile_scope("mtp/combine", "compute"):
+                embedded = self.embed(ahead(k - 1, 0))
+            stream, h, counts, lost = module(stream, embedded)
+            with profile_scope("lm/loss", "compute"):
+                total, hit = blocked_cross_entropy(
+                    h.reshape(-1, h.shape[-1]),
+                    self.head_kernel.astype(self.dtype),
+                    ahead(k, -1).reshape(-1), self.cfg.loss_block)
+            real = b * (length - k)
+            losses.append(total / real)
+            hits.append(hit / real)
+            arrived.append(counts)
+            dropped = dropped + lost
+        return (jnp.stack(losses), jnp.stack(hits), jnp.concatenate(arrived),
+                dropped)
 
     def loss_and_aux(self, tokens, labels, train: bool = True):
         """(mean cross-entropy of ``labels`` [B, L], aux).  ``aux`` holds
         ``accuracy`` and, where an expert layer exists, ``counters``:
         scalars a step (assignments per held expert and layer as min,
         mean, max, those dropped, and the rows the expert layers' dispatch
-        moved over the places their pools walked)."""
-        h, arrived, dropped = self.features(tokens)
+        moved over the places their pools walked).  With
+        multi-token-prediction modules (``mtp_depth`` D > 0) the loss is
+        ``main + mtp_weight / D x (sum of the depths' losses)``
+        (arXiv:2412.19437, eq. 24-25), ``accuracy`` stays the main head's,
+        the counters gain ``lm/main_loss``, ``mtp/loss`` (the depths' mean)
+        and ``mtp/accuracy``, and the expert counters count the modules'
+        expert layers with the others."""
+        h, arrived, dropped, stream = self.features(tokens)
         with profile_scope("lm/loss", "compute"):
             total, hits = blocked_cross_entropy(
                 h.reshape(-1, h.shape[-1]),
                 self.head_kernel.astype(self.dtype), labels.reshape(-1),
                 self.cfg.loss_block)
-        aux = {"accuracy": hits / labels.size}
+        aux, counters = {"accuracy": hits / labels.size}, {}
+        if self.mtp:
+            with profile_scope("mtp/module", "compute"):
+                further, further_hits, counts, lost = self.mtp_losses(
+                    stream, labels)
+            counters = {"mtp/loss": jnp.mean(further),
+                        "mtp/accuracy": jnp.mean(further_hits)}
+            arrived = jnp.concatenate([arrived, counts])
+            dropped = dropped + lost
         if arrived.size:
             c = self.cfg
             walked = jnp.sum(places_walked(
@@ -331,13 +498,19 @@ class DecoderLM(nn.Module):
                 c.experts_held, c.expert_rows, c.expert_pool))
             moved = jnp.sum(arrived) - dropped
             arrived = arrived.astype(jnp.float32)
-            aux["counters"] = {
+            counters.update({
                 "moe/assignments_min": jnp.min(arrived),
                 "moe/assignments_mean": jnp.mean(arrived),
                 "moe/assignments_max": jnp.max(arrived),
                 "moe/dropped": dropped.astype(jnp.float32),
-                "moe/pool_fill": moved / walked.astype(jnp.float32)}
-        return total / labels.size, aux
+                "moe/pool_fill": moved / walked.astype(jnp.float32)})
+        loss = total / labels.size
+        if self.mtp:
+            counters["lm/main_loss"] = loss
+            loss = loss + self.cfg.mtp_weight * counters["mtp/loss"]
+        if counters:
+            aux["counters"] = counters
+        return loss, aux
 
 
 def blocked_cross_entropy(h, head, labels, block: int):

@@ -1,15 +1,16 @@
 """A causal decoder of Kimi-Linear blocks: Kimi Delta Attention (KDA,
 `ops/kda.py`; on a TPU the kernels of `ops/kda_pallas.py`) and latent
-attention (MLA, through `ops/flash_attention.py` with 192-wide q/k and
-128-wide v) as mixers, a SwiGLU MLP or an expert layer that holds some of
-its experts (`ops/held_experts.py`) as feed-
-forward, pre-RMSNorm residual blocks, an untied head.
+attention (MLA: `models/decoder.LatentMixer` with one W_q and no
+positions, 192-wide q/k and 128-wide v) as mixers, a SwiGLU MLP or an
+expert layer that holds some of its experts (`ops/held_experts.py`) as
+feed-forward, pre-RMSNorm residual blocks, an untied head.
 
     h += Mixer(RMSNorm(h));  h += FFN(RMSNorm(h))
 
-This file holds the two mixers and the configuration; the block, the
-feed-forward half, the router, the model and its blocked next-token loss
-(`loss_and_aux`) are `models/decoder.py`'s, shared with `models/afmoe.py`.
+This file holds the KDA mixer and the configuration; the latent mixer
+(shared with `models/glm4_moe_lite.py`), the block, the feed-forward half,
+the router, the model and its blocked next-token loss (`loss_and_aux`) are
+`models/decoder.py`'s, shared with `models/afmoe.py`.
 
 Upstream's initialisation of the decay gate is not a zero-mean normal:
 ``A_log`` starts at log U(1, 16) and ``dt_bias`` at the inverse softplus
@@ -36,11 +37,11 @@ from jax import lax
 
 from geomx_tpu.models.decoder import (MLP, Block, DecoderLM,  # noqa: F401
                                       FFNBranch, HeldExpertsLayer,
-                                      MixerBranch, RMSNorm, _fan_in, _normal,
+                                      LatentMixer, MixerBranch, RMSNorm,
+                                      _fan_in, _normal,
                                       blocked_cross_entropy, causal_conv,
                                       route, swiglu)
 from geomx_tpu.ops import dispatch
-from geomx_tpu.ops.flash_attention import fused_attention
 from geomx_tpu.utils.profiler import profile_scope
 
 A_LOG_CENTRE = 1.96          # mean of log U(1, 16)
@@ -105,46 +106,6 @@ class KDAMixer(nn.Module):
             return jnp.einsum("bhle,hed->bld", o.astype(dt), out.astype(dt))
 
 
-class MLAMixer(nn.Module):
-    num_heads: int
-    nope_dim: int
-    rope_dim: int
-    v_dim: int
-    kv_rank: int
-    eps: float
-    dtype: Any = jnp.float32
-
-    @nn.compact
-    def __call__(self, x):
-        h, dt, hidden = self.num_heads, self.dtype, x.shape[-1]
-        b, length, _ = x.shape
-        qk = self.nope_dim + self.rope_dim
-        mat = lambda name, shape: self.param(name, _fan_in, shape)
-        with profile_scope("mla/proj", "compute"):
-            q = jnp.dot(x, mat("q_kernel", (hidden, h * qk)).astype(dt))
-            kv = jnp.dot(x, mat("kv_a_kernel",
-                                (hidden, self.kv_rank + self.rope_dim))
-                         .astype(dt))
-            latent = RMSNorm(self.eps, name="kv_norm")(
-                kv[..., :self.kv_rank])
-            shared = kv[..., self.kv_rank:]        # one key part, all heads
-            kv_b = jnp.dot(latent, mat(
-                "kv_b_kernel", (self.kv_rank, h * (self.nope_dim + self.v_dim))
-            ).astype(dt)).reshape(b, length, h, self.nope_dim + self.v_dim)
-            k = jnp.concatenate(
-                [kv_b[..., :self.nope_dim], jnp.broadcast_to(
-                    shared[:, :, None, :], (b, length, h, self.rope_dim))],
-                -1)
-            v = kv_b[..., self.nope_dim:]
-            q = q.reshape(b, length, h, qk)
-        with profile_scope("mla/attention", "kernel"):
-            o = fused_attention(q, k, v, True)
-        with profile_scope("mla/proj", "compute"):
-            return jnp.dot(o.reshape(b, length, h * self.v_dim),
-                           mat("out_kernel", (h * self.v_dim, hidden))
-                           .astype(dt))
-
-
 @dataclasses.dataclass(frozen=True)
 class KimiLinearConfig:
     """``layers``: one (mixer, ffn) pair a block, mixer "kda" | "mla", ffn
@@ -186,9 +147,10 @@ class KimiLinearConfig:
             return KDAMixer(self.num_heads, self.kda_head_dim, self.conv_size,
                             self.eps, self.kda_chunk, self.kda_sub, dtype,
                             name="core")
-        return MLAMixer(self.num_heads, self.qk_nope_dim, self.qk_rope_dim,
-                        self.v_head_dim, self.kv_rank, self.eps, dtype,
-                        name="core")
+        # one W_q and no positions at all (`q_lora_rank` null, `mla_use_nope`)
+        return LatentMixer(self.num_heads, self.qk_nope_dim, self.qk_rope_dim,
+                           self.v_head_dim, self.kv_rank, self.eps, dtype,
+                           name="core")
 
 
 class KimiLinearLM(DecoderLM):

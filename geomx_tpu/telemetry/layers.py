@@ -18,9 +18,10 @@ The vocabulary (docs/telemetry.md has the operator's table):
   compression engine (compression/) inside ``step/sync_grads``;
 - ``<axis>_pipeline/*``: the pipelined sync engine (sync/pipeline.py);
 - ``collective/worker``, ``collective/dc``: the tier collectives;
-- ``kda/*``, ``mla/*``, ``gqa/*``, ``ssd/*``, ``moe/*``, ``lm/loss``: a
-  decoder's layers inside ``step/forward_backward`` (models/kimi_linear.py,
-  models/afmoe.py, models/nemotron_h.py, models/decoder.py);
+- ``kda/*``, ``mla/*``, ``gqa/*``, ``ssd/*``, ``moe/*``, ``lm/loss``,
+  ``mtp/*``: a decoder's layers inside ``step/forward_backward``
+  (models/kimi_linear.py, models/afmoe.py, models/nemotron_h.py,
+  models/decoder.py);
 - ``attn/core``: the attention kernels and what surrounds them
   (ops/flash_attention.fused_attention), forward and backward;
 - ``train/step``, ``fit/*``, ``loader/*``: host spans of the loop;
@@ -78,6 +79,12 @@ SCOPES = (
     # a LatentMoE's down- and up-projection around its routed experts
     ("moe/latent", "step program"),
     ("lm/loss", "step program"),
+    # a multi-token-prediction module, around everything it runs (its
+    # block's mla/*, moe/*, attn/core and its lm/loss nest inside and keep
+    # their meaning), and inside it the join: two norms, the next token's
+    # lookup, the joining matrix (models/decoder.py)
+    ("mtp/module", "step program"),
+    ("mtp/combine", "step program"),
     # attention's core, forward and backward, opened by
     # ops/flash_attention.fused_attention; in a decoder it nests inside
     # mla/attention, gqa/window or gqa/global

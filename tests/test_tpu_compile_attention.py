@@ -51,7 +51,9 @@ def _cell_attention(which, direction):
     share of its attention layer (four query heads on the one key/value
     head they read); one sequence of the fourth decoder's (16,384 x 32 on
     4 of 128) in a window layer (a band of 1,024 keys, two blocks wide)
-    and in a full one (528 block pairs)."""
+    and in a full one (528 block pairs); one sequence of the fifth
+    decoder's latent attention (16,384 x 20 x 256/256 causal: one head a
+    step at blocks of 512, 528 block pairs)."""
     from geomx_tpu.ops import flash_attention_bwd, flash_attention_with_lse
     b, L, h, kv, d, dv, causal, window = {
         "bert": (16, 512, 16, 16, 64, 64, False, None),
@@ -60,7 +62,8 @@ def _cell_attention(which, direction):
         "global": (1, 8192, 32, 4, 128, 128, True, None),
         "share": (1, 8192, 4, 1, 128, 128, True, None),
         "window-16k": (1, 16384, 32, 4, 128, 128, True, 1024),
-        "global-16k": (1, 16384, 32, 4, 128, 128, True, None)}[which]
+        "global-16k": (1, 16384, 32, 4, 128, 128, True, None),
+        "latent-256": (1, 16384, 20, 20, 256, 256, True, None)}[which]
     bf16 = lambda heads, e: jax.ShapeDtypeStruct((b, L, heads, e),
                                                  jnp.bfloat16)
     if direction == "forward":
@@ -129,6 +132,10 @@ CASES = {
         "global-16k", "forward"),
     "flash_attention_bwd-bf16-grouped-global-16k": lambda: _cell_attention(
         "global-16k", "backward"),
+    "flash_attention-bf16-latent-256-wide-16k": lambda: _cell_attention(
+        "latent-256", "forward"),
+    "flash_attention_bwd-bf16-latent-256-wide-16k": lambda: _cell_attention(
+        "latent-256", "backward"),
     "flash_attention_bwd-f32-grouped-64-wide": lambda: _grouped_narrow(),
     "fused_ring_hop-L1024": lambda: _ring_hop(1024),
     "fused_ring_hop-L2048": lambda: _ring_hop(2048),   # 8,192 over 4 chips
@@ -158,3 +165,38 @@ def test_attention_kernels_carry_the_name_the_benchmark_reads(chip, which,
     assert {c.split(".")[0] for c in calls} == want, calls
     assert all(c.startswith("flash_attention") for c in calls)
 
+
+
+# (q length, kv length, heads, d, dv, key/value heads) -> (block_q, block_k,
+# heads a step, one backward kernel, VMEM bytes): `attention_plan`'s answer
+# for every shape an accepted cell calls `fused_attention` with, bf16, as
+# the parent of PR 45 gave them, and the fifth decoder's beside them
+CELL_PLANS = {
+    "bertlarge (both cells)": ((512, 512, 16, 64, 64, 16, False),
+                               (512, 512, 4, True, 10_485_760)),
+    "kimilinear latent": ((8192, 8192, 32, 192, 128, 32, True),
+                          (512, 512, 2, False, 10_485_760)),
+    "trinitymini window and global": ((8192, 8192, 32, 128, 128, 4, True),
+                                      (512, 512, 4, False, 11_010_048)),
+    "nemotron3super share": ((8192, 8192, 4, 128, 128, 1, True),
+                             (512, 512, 4, False, 9_961_472)),
+    "mellum2 window and global": ((16384, 16384, 32, 128, 128, 4, True),
+                                  (512, 512, 4, False, 11_010_048)),
+    "glm47flash latent": ((16384, 16384, 20, 256, 256, 20, True),
+                          (512, 512, 1, False, 9_437_184)),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_PLANS))
+def test_every_cells_attention_plan_is_pinned(cell):
+    """A change to the plan that helps one head width shows here for every
+    cell; 20 heads of 256 (divisors up to `MAX_HEADS`: 1, 2, 4, 5) take
+    one head a step at `MAX_BLOCK`, the only slab whose dk/dv kernel fits
+    `VMEM_BUDGET` at blocks of 512."""
+    from geomx_tpu.ops.flash_attention import (VMEM_BUDGET, AttentionPlan,
+                                               attention_plan)
+    (q_len, kv_len, heads, d, dv, kv_heads, causal), want = CELL_PLANS[cell]
+    plan = attention_plan(q_len, kv_len, heads, d, dv, jnp.bfloat16, causal,
+                          kv_heads=kv_heads)
+    assert plan == AttentionPlan(*want)
+    assert plan.vmem_bytes <= VMEM_BUDGET

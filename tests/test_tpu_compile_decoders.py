@@ -142,3 +142,51 @@ def test_v5e_compiler_accepts_the_mixer_under_either_table(chip, kind):
                                    jnp.bfloat16, backward)
         assert plan.tile >= 128 and plan.vmem_bytes <= ge.VMEM_BUDGET
 
+
+
+def test_v5e_compiler_accepts_the_fifth_decoders_step(chip):
+    """`glm-4.7-flash-ep8` as its family builds it, at the cell's sizes
+    (one sequence of 16,384 tokens, bf16; 706.5 M parameters): the loss
+    the model brings (next token + 0.3 x second-next through the module)
+    and its gradient as one program under the native kernels.  Six latent
+    layers (five blocks and the module's) call the flash kernels at 20
+    heads of 256/256, forward, dq and dk/dv once each; the five expert
+    layers' pools go back through the row kernel (hidden 2,048 is whole
+    slabs); and this program's temporaries (one sequence's mixer
+    internals, the dense layer's and a loss block's, 6.03 GB as PR 45
+    compiled it) leave the fp32 weights and Adam's two moments (three
+    times the arguments, 8.48 GB) their place under the chip's 16.91 GB;
+    the trainer's own step, which hands each gradient leaf to Adam as it
+    comes, compiled to 8.48 + 8.02 GB."""
+    import collections
+    import json
+    import os
+    from benchmark.cells import Registry
+    from geomx_tpu.ops import dispatch
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = Registry(root).cell("glm47flash-fsa-1c")
+    config = cell["config"]
+    model = cell["family"].build_model(config)
+    on = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                           sharding=chip)
+    x = on(jax.ShapeDtypeStruct((1, config["sequence_length"]), jnp.int32))
+    params = jax.tree.map(on, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), x)["params"])
+    count = sum(leaf.size for leaf in jax.tree.leaves(params))
+    assert count == config["parameters"]["total"] == 706_518_528
+    step = jax.value_and_grad(lambda p, x_, y_: model.apply(
+        {"params": p}, x_, y_, method="loss_and_aux"), has_aux=True)
+    with dispatch.kernels("native"):
+        compiled = jax.jit(step).lower(params, x, x).compile()
+    calls = collections.Counter(
+        c.split(".")[0] for c in checks.kernel_calls(compiled.as_text()))
+    for name in ("flash_attention_fwd", "flash_attention_bwd_dq",
+                 "flash_attention_bwd_dkv"):
+        assert calls[name] == 6, calls
+    assert calls["gmm"] and calls["tgmm"] and calls["moe_row_scatter_add"]
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(4 * count, rel=1e-3)
+    assert memory.temp_size_in_bytes < 6.5e9, json.dumps(
+        {"temp": memory.temp_size_in_bytes})
+    assert (3 * memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16.91e9)

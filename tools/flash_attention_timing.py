@@ -20,7 +20,9 @@ backward.  Variants:
 
 Named shapes: ``bert`` 16 x 512 x 16 x 64 (the BERT cells' layer),
 ``latent`` 1 x 8,192 x 32 x 192/128 causal (one sequence of the
-decoder's MLA layer), ``mid`` 16 x 2,048 x 16 x 64.
+Kimi cell's MLA layer), ``mid`` 16 x 2,048 x 16 x 64, ``latent256`` 1 x
+16,384 x 20 x 256/256 causal (the GLM cell's one sequence: its heads,
+widths and length read from ``benchmark/configs/glm-4.7-flash-ep8.json``).
 
 One JSON line a shape and dtype on stdout: the plan
 (``attention_plan``), each variant's first-run seconds and ms per call,
@@ -52,7 +54,21 @@ sys.path.insert(0, ROOT)
 NAMED = {"bert": (16, 512, 16, 64, 64, False),
          "latent": (1, 8192, 32, 192, 128, True),
          "mid": (16, 2048, 16, 64, 64, False)}
-CALLS = {"bert": 24, "latent": 2, "mid": 4}
+CALLS = {"bert": 24, "latent": 2, "mid": 4, "latent256": 1}
+
+
+def latent_shape(path):
+    """(B, L, H, D, Dv, causal) of a configuration file's latent attention:
+    `qk_nope_head_dim` + `qk_rope_head_dim` wide q and k, `v_head_dim`
+    wide v, one sequence of `sequence_length`."""
+    with open(os.path.join(ROOT, path)) as f:
+        config = json.load(f)
+    return (1, config["sequence_length"], config["num_attention_heads"],
+            config["qk_nope_head_dim"] + config["qk_rope_head_dim"],
+            config["v_head_dim"], True)
+
+
+NAMED["latent256"] = latent_shape("benchmark/configs/glm-4.7-flash-ep8.json")
 
 
 def load_other(ref, path):

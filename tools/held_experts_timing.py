@@ -10,9 +10,13 @@ over ``--reps`` runs after a warm-up.  ``--config <file>`` takes ``T``,
 ``d``, ``f``, ``E``, the router's width and the experts a token from a
 configuration's file (`benchmark/configs/*.json`: the decoder cells'; a
 file with a ``moe_latent_size`` gives that as ``d``, one whose
-``mlp_hidden_act`` is ``relu2`` un-gated experts; `mellum2-12b-ep4.json`
+``mlp_hidden_act`` is ``relu2`` un-gated experts; the held experts are the
+file's ``num_experts`` or, in a DeepSeek-style key set, its
+``n_routed_experts``; `mellum2-12b-ep4.json`
 gives 16 held of 64 at 2 held picks a token, 2,048 assignments an expert
-under even routing, a first pool of 65,536 places), and
+under even routing, a first pool of 65,536 places;
+`glm-4.7-flash-ep8.json` 8 held of 64 at 0.5 held picks a token, 1,024
+an expert, a first pool of 16,384), and
 the tile and the first pool from its ``program``; the loads are the Kimi
 cell's patterns (8 held experts, 512 assignments each under even routing)
 repeated over the held experts and scaled to the file's even load.
@@ -50,6 +54,8 @@ cell), the walk too.  One JSON line per variant and load.
         --shapes "512:32768 1024 512"
     python tools/held_experts_timing.py \
         --config benchmark/configs/mellum2-12b-ep4.json --loads even,none
+    python tools/held_experts_timing.py \
+        --config benchmark/configs/glm-4.7-flash-ep8.json
 """
 import argparse
 import importlib.util
@@ -410,7 +416,9 @@ def main(argv=None) -> int:
             config = json.load(fh)
         top_k = config.get("num_experts_per_tok",
                            config.get("num_experts_per_token"))
-        held, router = config["num_experts"], config["router_experts"]
+        # the held experts' count is the key the file's `reduced` lists
+        held = config.get("n_routed_experts", config.get("num_experts"))
+        router = config["router_experts"]
         t = config["per_chip_batch"] * config["sequence_length"]
         d = config.get("moe_latent_size") or config["hidden_size"]
         f = config["moe_intermediate_size"]
