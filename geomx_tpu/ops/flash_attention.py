@@ -41,14 +41,35 @@ callers get float32 operands at the MXU's default precision, as before.
 
 **Backward.**  The backward works on transposed scores (`s^T = K Q^T`),
 so that `lse` and `delta` are rows that broadcast down the sublanes and
-only `dq` needs a transpose, a small one.  Where the whole sequence is one
-block pair (L <= 512) ONE kernel computes `s`, `p`, `dp`, `ds` once and
-all three gradients from them (five products), with
-`delta = rowsum(P * dP)` (= rowsum(dO * O)) taken inside it; longer
-sequences run a dq kernel and a dk/dv kernel (seven products) with
-`delta` from XLA as rows of L values.  `lse` crosses HBM as `[B, H, L]`
-float32 rows.  Off a TPU the dense jnp reference runs both ways via
-`jax.custom_vjp`.
+only `dq` needs a transpose, a small one.  ONE kernel computes `s`, `p`,
+`dp`, `ds` once a block pair and all three gradients from them (five
+products) wherever a run of its key-major walk finishes a dq block:
+
+- one block pair is the whole sequence (L <= 512), with
+  `delta = rowsum(P * dP)` (= rowsum(dO * O)) taken inside it;
+- a causal call of several pairs with `block_q == block_k` and `Lq ==
+  Lk`.  The walk takes key block 0 against q blocks 0, 1, ..., then key
+  block 1 against q blocks 1, 2, ...: the run of key block j opens on the
+  diagonal pair (j, j), and dq block j takes from the runs 0..j (under a
+  band from j - w..j) and from no later one, so that pair is the last
+  that adds to it.  dq^T of EVERY q block stays in VMEM as float32
+  `[nq, heads * D, bq]` for the walk of a (batch, head group); dq's out
+  block is indexed by the key block, so a run holds it: the diagonal
+  pair writes the scaled, transposed block there and Pallas copies it
+  out when the run ends.  No partial dq crosses HBM.  The array costs
+  `4 * L * heads * D` bytes (16 MiB for one 256-wide head of 16,384
+  rows), beside what VMEM_BUDGET counts: :func:`attention_plan` takes
+  the one kernel where that is at most MAX_RESIDENT_DQ (for grouped
+  heads the kernel's heads are whole groups, so a group of eight keeps
+  eight heads' dq^T: those calls stay with two kernels) and the call
+  raises Mosaic's VMEM limit over the plan's bytes.
+
+Every other call runs a dq kernel and a dk/dv kernel (seven products:
+`s^T` and `dP^T` twice).  Past one pair `delta` comes from XLA as rows of
+L values.  `lse` crosses HBM as `[B, H, L]` float32 rows.  The sums run
+in the same order in either form (dq over key blocks ascending, dk and dv
+over q blocks ascending).  Off a TPU the dense jnp reference runs both
+ways via `jax.custom_vjp`.
 
 Numerics match `parallel/ring_attention.full_attention_reference`
 (tests/test_flash_attention.py), including fully-masked rows (causal +
@@ -57,6 +78,7 @@ padding) which produce zeros, not NaNs.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple, Optional
 
@@ -77,6 +99,11 @@ _LANES = 128
 VMEM_BUDGET = 12 * 2 ** 20
 MAX_BLOCK = 512      # rows of q or k a step: a [512, 512] f32 score tile
 MAX_HEADS = 8        # heads a step: bounds the unrolled code
+# The float32 dq of every q block of a sequence, which ONE backward kernel
+# keeps in VMEM for its whole walk beside what VMEM_BUDGET counts (a v5e
+# has 128 MiB; the call raises Mosaic's limit over the plan's bytes).
+# 16 MiB: one 256-wide head of 16,384 rows, two 192-wide of 8,192.
+MAX_RESIDENT_DQ = 16 * 2 ** 20
 
 
 class AttentionPlan(NamedTuple):
@@ -86,6 +113,12 @@ class AttentionPlan(NamedTuple):
     heads: int              # heads a step, a divisor of H
     fused_backward: bool    # one backward kernel, else dq and dk/dv kernels
     vmem_bytes: int         # the largest kernel's estimate
+    resident_bytes: int = 0  # of them, the one backward kernel's dq^T
+
+    @property
+    def backward_kernels(self) -> str:
+        """`one` / `two`: what a trace's span and the timing tool print."""
+        return "one" if self.fused_backward else "two"
 
 
 def _default_block(length: int) -> int:
@@ -106,15 +139,18 @@ def _kv_heads(heads: int, group: int) -> int:
     return max(heads // group, 1)
 
 
-def _vmem_bytes(bq, bk, heads, group, d, dv, itemsize, multi_block):
+def _vmem_bytes(bq, bk, heads, group, d, dv, itemsize, one_backward):
     """Bytes of VMEM the largest of the kernels asks for: double-buffered
-    blocks in and out, the float32 accumulators, and the [bk, bq]
-    temporaries (s, p, dp, ds in float32, p and ds rounded).  `heads`
-    query heads a step read `_kv_heads` key/value heads; a kernel that
-    makes dk and dv takes whole groups (`max(heads, group)` query heads).
-    With several blocks a kernel makes either dq or dk and dv, and the
-    forward keeps a lane-replicated running max and normaliser; with equal
-    head counts and equal blocks the dk/dv kernel is the largest."""
+    blocks in and out, the float32 accumulators (one q block's dq^T; the
+    further blocks ONE backward kernel of a long sequence keeps are
+    :func:`_resident_dq_bytes`), and the [bk, bq] temporaries (s, p, dp,
+    ds in float32, p and ds rounded).  `heads` query heads a step read
+    `_kv_heads` key/value heads; a kernel that makes dk and dv takes whole
+    groups (`max(heads, group)` query heads).  `one_backward`: one kernel
+    makes dq, dk and dv, and is the largest; else a kernel makes either dq
+    or dk and dv, the forward keeps a lane-replicated running max and
+    normaliser, and with equal head counts and equal blocks the dk/dv
+    kernel is the largest."""
     def blocks(g):
         qk, vv = g * d, g * dv
         kk, kv = _kv_heads(g, group) * d, _kv_heads(g, group) * dv
@@ -122,7 +158,7 @@ def _vmem_bytes(bq, bk, heads, group, d, dv, itemsize, multi_block):
 
     scores = bq * bk * (4 * 4 + 2 * itemsize)
     qk, vv, kk, kv, blocks_in = blocks(max(heads, group))
-    if not multi_block:
+    if one_backward:
         out = bq * qk + bk * (kk + kv)
         return 2 * itemsize * (blocks_in + out) + 4 * out + scores
     out = bk * (kk + kv)
@@ -134,11 +170,18 @@ def _vmem_bytes(bq, bk, heads, group, d, dv, itemsize, multi_block):
     return max(dkv, dq, fwd)
 
 
+def _resident_dq_bytes(q_blocks, bq, heads, group, d):
+    """The float32 dq^T of `q_blocks` blocks that one backward kernel
+    keeps: [whole groups' heads x d, bq] each."""
+    return 4 * q_blocks * bq * max(heads, group) * d
+
+
 def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
                    dtype, causal: bool, block_q: Optional[int] = None,
                    block_k: Optional[int] = None,
                    kv_heads: Optional[int] = None) -> AttentionPlan:
-    """Blocks and heads a grid step, from the shapes alone.
+    """Blocks, heads a grid step and the backward's form, from the shapes
+    alone.
 
     Blocks: a given `block_q` / `block_k` is honoured (capped at the
     length); else :func:`_default_block`.  Heads a step: the largest
@@ -148,12 +191,18 @@ def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
     fits.  With `kv_heads` < H (grouped-query heads) a step's query heads
     share one key/value head or are whole groups, and the key/value slab
     is lane-aligned too; the kernels that make dk and dv then take
-    `max(heads, H // kv_heads)` query heads.  One backward kernel where
-    one block pair is the whole sequence (and, grouped, whole groups fit
-    it).  `causal` (and a window) choose no size: they shorten the list
-    of block pairs the grid walks
+    `max(heads, H // kv_heads)` query heads.
+
+    One backward kernel (`fused_backward`) where, with one q block's dq^T,
+    it fits VMEM_BUDGET for whole groups of heads and a run of its walk
+    finishes a dq block: one block pair is the whole sequence, or the
+    call is `causal` with `block_q == block_k` and `q_len == kv_len` (the
+    key-major walk opens each run on the diagonal pair, the last that
+    adds to that dq block) and the dq^T of every q block
+    (`resident_bytes`) is at most MAX_RESIDENT_DQ.  Else a dq kernel and
+    a dk/dv kernel.  Beyond that `causal` (and a window) choose no size:
+    they shorten the list of block pairs the grid walks
     (:func:`_block_pairs`)."""
-    del causal
     kv_heads = kv_heads or heads
     if heads % kv_heads:
         raise ValueError(f"{heads} query heads over {kv_heads} key/value "
@@ -161,7 +210,9 @@ def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
     group = heads // kv_heads
     bq = min(block_q, q_len) if block_q else _default_block(q_len)
     bk = min(block_k, kv_len) if block_k else _default_block(kv_len)
-    fused = -(-q_len // bq) == 1 and -(-kv_len // bk) == 1
+    nq = -(-q_len // bq)
+    one_pair = nq == 1 and -(-kv_len // bk) == 1
+    fused = one_pair
     itemsize = jnp.dtype(dtype).itemsize
 
     def aligned(g, all_of):
@@ -172,14 +223,20 @@ def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
              if heads % g == 0 and (g % group == 0 or group % g == 0)
              and aligned(g, heads)
              and aligned(_kv_heads(g, group), kv_heads)]
-    cost = lambda g: _vmem_bytes(bq, bk, g, group, d, dv, itemsize,
-                                 not fused)
+    cost = lambda g: _vmem_bytes(bq, bk, g, group, d, dv, itemsize, fused)
     fit = lambda g: g <= MAX_HEADS and cost(g) <= VMEM_BUDGET
     if fused and group > 1 and not any(map(fit, slabs)):
         fused = False       # whole groups do not fit one backward kernel
     fits = [g for g in slabs if fit(g)]
     g = max(fits) if fits else min(slabs)
-    return AttentionPlan(bq, bk, g, fused, cost(g))
+    resident = _resident_dq_bytes(nq, bq, g, group, d)
+    if not one_pair and causal and bq == bk and q_len == kv_len:
+        fused = (resident <= MAX_RESIDENT_DQ and _vmem_bytes(
+            bq, bk, g, group, d, dv, itemsize, True) <= VMEM_BUDGET)
+    if not fused:
+        return AttentionPlan(bq, bk, g, False, cost(g))
+    return AttentionPlan(bq, bk, g, True, cost(g) + resident - resident // nq,
+                         resident)
 
 
 def _band(window, causal: bool, kv_len: int):
@@ -408,12 +465,14 @@ class _Specs(NamedTuple):
     """BlockSpecs of one kernel: a group of query heads' slabs (rows at
     the pair's q block, `d` or `dv` wide), their key/value heads' slabs
     (rows at the pair's k block) and the per-row float32 rows (lse,
-    delta)."""
+    delta); `dq_of_run`: dq's rows at the pair's k block, which a
+    key-major run holds from its first pair to its last."""
     q: pl.BlockSpec
     k: pl.BlockSpec
     v: pl.BlockSpec
     o: pl.BlockSpec
     rows: pl.BlockSpec
+    dq_of_run: pl.BlockSpec
 
 
 class _Call(NamedTuple):
@@ -449,7 +508,10 @@ class _Call(NamedTuple):
             v=pl.BlockSpec((1, bk, kv * self.dv), at_k),
             o=pl.BlockSpec((1, bq, heads * self.dv), at_q),
             rows=pl.BlockSpec((1, heads, 1, bq),
-                              lambda b, g, t, qi, kj, fl: (b, g, 0, qi[t])))
+                              lambda b, g, t, qi, kj, fl: (b, g, 0, qi[t])),
+            dq_of_run=pl.BlockSpec(
+                (1, bq, heads * self.d),
+                lambda b, g, t, qi, kj, fl: (b, kj[t], g)))
 
     def pairs(self, q_len, kv_len, causal, k_inner):
         return _block_pairs(self.nq, self.nk, self.plan.block_q,
@@ -478,6 +540,8 @@ def _grid_spec(pairs, grid, in_specs, out_specs, scratch_shapes):
 
 _SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
+# over the plan's bytes, for what Mosaic keeps that the plan does not count
+_VMEM_HEADROOM = 16 * 2 ** 20
 
 
 @functools.partial(jax.jit,
@@ -560,27 +624,41 @@ def _bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     scores: s^T = K Q^T [bk, bq], so lse and delta [1, bq] broadcast down
     the sublanes.  Query head h reads key/value head `h // group`; a
     group's heads add into that head's dk and dv.  Makes dq (`want_dq`:
-    pairs arrive q-major), dk and dv (`want_dkv`: k-major) or, where one
-    pair is the whole sequence, all three from one s, p, dp, ds.
+    pairs arrive q-major), dk and dv (`want_dkv`: k-major) or all three
+    from one s, p, dp, ds.  All three: pairs arrive k-major, dq^T is kept
+    for every q block of the walk, and the run of key block j opens on
+    the pair (j, j), the last that adds to dq block j: that pair writes
+    it to the out block the run holds (the plan's rule; one pair that is
+    the whole sequence is such a walk).
     `delta_in`: delta arrives as rows [1, heads, 1, bq]; else it is
     rowsum(P * dP) over the pair, which is rowsum(dO * O) only where the
-    pair holds every key.  Scratch, float32: dq^T [heads d, bq], dk
-    [bk, kv d], dv [bk, kv dv]."""
+    pair holds every key.  Scratch, float32: dq^T [heads d, bq] (all
+    three: [q blocks, heads d, bq]), dk [bk, kv d], dv [bk, kv dv]."""
     refs = list(refs)
     delta_ref = refs.pop(0) if delta_in else None
     dq_ref = refs.pop(0) if want_dq else None
     dk_ref, dv_ref = (refs.pop(0), refs.pop(0)) if want_dkv else (None, None)
-    dqt_acc = refs.pop(0) if want_dq else None
+    dqt_all = refs.pop(0) if want_dq else None
     dk_acc, dv_acc = refs if want_dkv else (None, None)
     t = pl.program_id(2)
     i, j, flags = qi_ref[t], kj_ref[t], fl_ref[t]
     dtype = q_ref.dtype
+    resident = want_dq and want_dkv
+    dqt_acc = dqt_all.at[i] if resident else dqt_all
+
+    if resident:
+        @pl.when(t == 0)
+        def _zero_dq():
+            def zero(n, carry):
+                dqt_all[n] = jnp.zeros(dqt_all.shape[1:], jnp.float32)
+                return carry
+            jax.lax.fori_loop(0, dqt_all.shape[0], zero, 0)
 
     @pl.when((flags & 1) != 0)
     def _init():
-        for acc in (dqt_acc, dk_acc, dv_acc):
+        for acc in (None if resident else dqt_acc, dk_acc, dv_acc):
             if acc is not None:
-                acc[:] = jnp.zeros_like(acc)
+                acc[:] = jnp.zeros(acc.shape, jnp.float32)
 
     def step(masked):
         if masked:
@@ -620,11 +698,14 @@ def _bwd_kernel(qi_ref, kj_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
 
     _run_bodies(step, flags, bodies)
 
-    @pl.when((flags & 2) != 0)
-    def _finalize():
-        if want_dq:
+    if want_dq:
+        @pl.when((flags & (1 if resident else 2)) != 0)
+        def _dq_out():
             dq_ref[0] = (dqt_acc[:] * scale).T.astype(dq_ref.dtype)
-        if want_dkv:
+
+    if want_dkv:
+        @pl.when((flags & 2) != 0)
+        def _dkv_out():
             dk_ref[0] = (dk_acc[:] * scale).astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
@@ -642,11 +723,13 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
     matrix — p is recomputed per block pair from the forward's logsumexp
     (the standard flash-attention backward; delta_i = rowsum(dO_i * O_i)
     folds the softmax normalizer's gradient).  v, out and do may have a
-    head size of their own (Dv).  One kernel where the plan's block pair
-    is the whole sequence (`out` is then not read: delta is rowsum(P dP)
-    inside it), else a dq kernel and a dk/dv kernel.  With grouped heads
-    dk and dv have k's and v's own heads: a kernel that makes them takes
-    whole groups a step and sums over each."""
+    head size of their own (Dv).  One kernel where the plan says so
+    (`AttentionPlan.fused_backward`: one block pair is the whole
+    sequence, and `out` is then not read, delta is rowsum(P dP) inside it;
+    or a causal walk whose dq^T stays in VMEM), else a dq kernel and a
+    dk/dv kernel.  With grouped heads dk and dv have k's and v's own
+    heads: a kernel that makes them takes whole groups a step and sums
+    over each."""
     B, Lq, H, D = q.shape
     Lk, Hkv, Dv = k.shape[1], k.shape[2], v.shape[-1]
     scale = 1.0 / float(np.sqrt(D))
@@ -654,6 +737,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
     plan, pq, pk = call.plan, call.pad_q, call.pad_k
     bq, bk = plan.block_q, plan.block_k
     Lqp, Lkp = Lq + pq, Lk + pk
+    delta_in = call.nq * call.nk > 1
 
     def rows(x):     # [B, H, Lq] f32 -> [B, H, 1, Lqp]
         x = jnp.pad(x, ((0, 0), (0, 0), (0, pq))) if pq else x
@@ -662,7 +746,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
     slabs = lambda x, pad: _slabs(x.astype(call.dtype), pad)
     operands = [slabs(q, pq), slabs(k, pk), slabs(v, pk), slabs(do, pq),
                 rows(lse)]
-    if not plan.fused_backward:
+    if delta_in:
         # delta: [B, H, Lq] rows — one fused multiply-reduce over dO and O
         operands.append(rows(jnp.sum(
             do.astype(jnp.float32) * out.astype(jnp.float32),
@@ -681,8 +765,9 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
             _bwd_kernel, scale=scale, heads=G, group=call.group, d=D, dv=Dv,
             bq=bq, bk=bk, q_len=Lq, kv_len=Lk, causal=causal,
             window=call.window, want_dq=want_dq, want_dkv=want_dkv,
-            delta_in=not plan.fused_backward, bodies=bodies)
-        dq_acc = pltpu.VMEM((G * D, bq), jnp.float32)
+            delta_in=delta_in, bodies=bodies)
+        resident = want_dq and want_dkv
+        dq_acc = pltpu.VMEM((call.nq,) * resident + (G * D, bq), jnp.float32)
         dkv_acc = [pltpu.VMEM((bk, kv * D), jnp.float32),
                    pltpu.VMEM((bk, kv * Dv), jnp.float32)]
         return pl.pallas_call(
@@ -691,14 +776,18 @@ def flash_attention_bwd(q, k, v, out, lse, do, causal: bool = False,
                 pairs, (B, H // G),
                 [spec.q, spec.k, spec.v, spec.o]
                 + [spec.rows] * (len(operands) - 4),
-                [spec.q] * want_dq + [spec.k, spec.v] * want_dkv,
+                [spec.dq_of_run if resident else spec.q] * want_dq
+                + [spec.k, spec.v] * want_dkv,
                 [dq_acc] * want_dq + dkv_acc * want_dkv),
             out_shape=[dq_shape] * want_dq + [dk_shape, dv_shape] * want_dkv,
-            compiler_params=_SEMANTICS, interpret=interpret, name=name,
+            compiler_params=dataclasses.replace(
+                _SEMANTICS, vmem_limit_bytes=plan.vmem_bytes + _VMEM_HEADROOM)
+            if resident else _SEMANTICS,
+            interpret=interpret, name=name,
         )(*pairs, *operands)
 
     if plan.fused_backward:
-        dq, dk, dv = kernel_call("flash_attention_bwd", True, True, True)
+        dq, dk, dv = kernel_call("flash_attention_bwd", False, True, True)
     else:
         dq, = kernel_call("flash_attention_bwd_dq", True, True, False)
         dk, dv = kernel_call("flash_attention_bwd_dkv", False, False, True)
@@ -782,13 +871,19 @@ def _fused_fwd(q, k, v, causal, interpret, window):
 
 def _fused_bwd(causal, interpret, window, res, g):
     q, k, v, out, lse = res
-    with profile_scope(_SCOPE, "kernel"):
-        if lse is not None:  # kernel path: flash backward
-            return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                                       interpret=interpret, window=window)
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _dense(q_, k_, v_, causal, window), q, k, v)
-        return vjp(g)
+    if lse is None:
+        with profile_scope(_SCOPE, "kernel"):
+            _, vjp = jax.vjp(
+                lambda q_, k_, v_: _dense(q_, k_, v_, causal, window),
+                q, k, v)
+            return vjp(g)
+    # kernel path: flash backward; the span says which form the plan took
+    plan = _prepare(q, k, v, causal, window, None, None).plan
+    with profile_scope(_SCOPE, "kernel", args={
+            "backward_kernels": plan.backward_kernels,
+            "resident_bytes": plan.resident_bytes}):
+        return flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
+                                   interpret=interpret, window=window)
 
 
 fused_attention.defvjp(_fused_fwd, _fused_bwd)

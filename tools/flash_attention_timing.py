@@ -1,6 +1,7 @@
 """Time the flash-attention kernels on the chip, at the cells' shapes.
 
-For each shape (``name`` or ``B:L:H:D:Dv:causal``) and dtype it makes
+For each shape (``name`` or ``B:L:H:D:Dv:causal[:Hkv[:window]]``) and
+dtype it makes
 ``calls`` sets of q on the device and times one jitted program that runs
 all of them against one k, v (and one dO), ending in
 ``block_until_ready``.  A call to the device costs the host about 0.6 ms
@@ -22,10 +23,19 @@ Named shapes: ``bert`` 16 x 512 x 16 x 64 (the BERT cells' layer),
 ``latent`` 1 x 8,192 x 32 x 192/128 causal (one sequence of the
 Kimi cell's MLA layer), ``mid`` 16 x 2,048 x 16 x 64, ``latent256`` 1 x
 16,384 x 20 x 256/256 causal (the GLM cell's one sequence: its heads,
-widths and length read from ``benchmark/configs/glm-4.7-flash-ep8.json``).
+widths and length read from ``benchmark/configs/glm-4.7-flash-ep8.json``),
+``trinity-global`` 1 x 8,192 x 32 on 4 of 128 causal and ``mellum-global``
+1 x 16,384 x 32 on 4 of 128 causal, ``trinity-window`` and
+``mellum-window`` the same under a band of 2,048 and of 1,024 keys (the
+grouped-query cells' layers, whose backward stays two kernels: the group of eight's dq^T is
+32 and 64 MiB and eight heads a step stream 15.2 MB; ``--set
+MAX_RESIDENT_DQ=67108864 --set VMEM_BUDGET=16777216`` times the one
+kernel there, ``--set MAX_RESIDENT_DQ=0`` the two kernels at any shape).
 
 One JSON line a shape and dtype on stdout: the plan
-(``attention_plan``), each variant's first-run seconds and ms per call,
+(``attention_plan``: blocks, heads a step, ``backward_kernels`` one or
+two, the VMEM bytes with ``resident_bytes``, the dq^T one backward kernel
+keeps, apart), each variant's first-run seconds and ms per call,
 and the largest difference of each variant's output and gradients from
 the dense form in float32 at ``highest`` precision on the same inputs
 (over ``--check-heads`` heads, relative to the reference's largest
@@ -51,10 +61,17 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
+# (B, L, H, D, Dv, causal[, key/value heads[, window]])
 NAMED = {"bert": (16, 512, 16, 64, 64, False),
          "latent": (1, 8192, 32, 192, 128, True),
-         "mid": (16, 2048, 16, 64, 64, False)}
-CALLS = {"bert": 24, "latent": 2, "mid": 4, "latent256": 1}
+         "mid": (16, 2048, 16, 64, 64, False),
+         "trinity-global": (1, 8192, 32, 128, 128, True, 4),
+         "mellum-global": (1, 16384, 32, 128, 128, True, 4),
+         "trinity-window": (1, 8192, 32, 128, 128, True, 4, 2048),
+         "mellum-window": (1, 16384, 32, 128, 128, True, 4, 1024)}
+CALLS = {"bert": 24, "latent": 2, "mid": 4, "latent256": 1,
+         "trinity-global": 2, "mellum-global": 1,
+         "trinity-window": 2, "mellum-window": 1}
 
 
 def latent_shape(path):
@@ -90,17 +107,25 @@ def load_other(ref, path):
     return module
 
 
-def dense(q, k, v, causal, precision=None):
-    """Plain XLA: operands as given, float32 scores and softmax."""
+def dense(q, k, v, causal, window=None, precision=None):
+    """Plain XLA: operands as given (k and v repeated to q's heads where
+    they have fewer), float32 scores and softmax; `window`: the causal
+    band's width in keys."""
     import jax
     import jax.numpy as jnp
     scale = q.shape[-1] ** -0.5
+    if k.shape[2] != q.shape[2]:
+        group = q.shape[2] // k.shape[2]
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=precision,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         rows = jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 0)
         cols = jax.lax.broadcasted_iota(jnp.int32, s.shape[-2:], 1)
-        s = jnp.where(cols <= rows, s, -1e30)
+        seen = cols <= rows
+        if window is not None:
+            seen = seen & (rows - cols < window)
+        s = jnp.where(seen, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v,
                       precision=precision,
@@ -121,7 +146,8 @@ def median_ms(fn, args, reps):
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     parser.add_argument("shapes", nargs="+",
-                        help="bert | latent | mid | B:L:H:D:Dv:causal(0/1)")
+                        help="a name of NAMED | "
+                             "B:L:H:D:Dv:causal(0/1)[:Hkv[:window]]")
     parser.add_argument("--dtypes", default="bfloat16,float32")
     parser.add_argument("--calls", type=int, default=None)
     parser.add_argument("--reps", type=int, default=5)
@@ -135,7 +161,8 @@ def main(argv=None) -> int:
     parser.add_argument("--set", action="append", default=[],
                         metavar="NAME=INT", help="a constant of this "
                         "file's module to try another plan with "
-                        "(VMEM_BUDGET, MAX_BLOCK, MAX_HEADS)")
+                        "(VMEM_BUDGET, MAX_BLOCK, MAX_HEADS, "
+                        "MAX_RESIDENT_DQ)")
     parser.add_argument("--interpret", action="store_true",
                         help="rehearse on the CPU: no times, kernels "
                              "interpreted")
@@ -161,20 +188,20 @@ def main(argv=None) -> int:
         modules["other"] = other
     skip = set(filter(None, args.skip.split(",")))
 
-    def kernel_pair(module, causal):
+    def kernel_pair(module, causal, window):
         """(forward with lse, forward + backward) of one module."""
-        fwd = functools.partial(module.flash_attention_with_lse,
-                                causal=causal, interpret=args.interpret)
+        given = dict(causal=causal, interpret=args.interpret)
+        if window is not None:
+            given["window"] = window
+        fwd = functools.partial(module.flash_attention_with_lse, **given)
 
         def both(q, k, v, g):
             out, lse = fwd(q, k, v)
-            return module.flash_attention_bwd(
-                q, k, v, out, lse, g, causal=causal,
-                interpret=args.interpret)
+            return module.flash_attention_bwd(q, k, v, out, lse, g, **given)
         return (lambda q, k, v, g: fwd(q, k, v)[0]), both
 
-    def xla_pair(causal, per_sequence, precision=None):
-        one = functools.partial(dense, causal=causal, precision=precision)
+    def xla_pair(causal, window, per_sequence):
+        one = functools.partial(dense, causal=causal, window=window)
         if per_sequence:
             one = lambda q, k, v, inner=one: jax.lax.map(
                 lambda x: inner(x[0][None], x[1][None], x[2][None])[0],
@@ -183,6 +210,28 @@ def main(argv=None) -> int:
         def both(q, k, v, g):
             return jax.vjp(one, q, k, v)[1](g)
         return (lambda q, k, v, g: one(q, k, v)), both
+
+    def yardstick(q, k, v, g, causal, window):
+        """(out, (dq, dk, dv)) of the dense form at `highest`, one query
+        head at a time (a head's scores at 16,384 are 1 GB), dk and dv
+        summed over the query heads that read a key/value head."""
+        group = q.shape[2] // k.shape[2]
+        heads_first = lambda x: jnp.moveaxis(x, 2, 0)[:, :, :, None]
+
+        def one(x):
+            out, pull = jax.vjp(functools.partial(
+                dense, causal=causal, window=window, precision="highest"),
+                *x[:3])
+            return (out,) + pull(x[3])
+
+        out, dq, dk, dv = (
+            jnp.moveaxis(x[:, :, :, 0], 0, 2) for x in jax.lax.map(
+                one, tuple(map(heads_first, (
+                    q, jnp.repeat(k, group, axis=2),
+                    jnp.repeat(v, group, axis=2), g)))))
+        over = lambda x: x.reshape(*x.shape[:2], -1, group,
+                                   x.shape[-1]).sum(3)
+        return out, (dq, over(dk), over(dv))
 
     def split(x, d):
         """[..., L, H d] -> [..., L, H, d].  The operands are made as the
@@ -210,9 +259,11 @@ def main(argv=None) -> int:
         return jax.jit(run)
 
     for shape in args.shapes:
-        B, L, H, D, Dv, causal = NAMED.get(shape) or (
+        B, L, H, D, Dv, causal, *grouped = NAMED.get(shape) or (
             int(x) for x in shape.split(":"))
-        causal = bool(causal)
+        causal, Hkv, window = bool(causal), *(grouped + [H, None][
+            len(grouped):])
+        group = H // Hkv
         count = args.calls or CALLS.get(shape, 4)
         for dtype in args.dtypes.split(","):
             dt = jnp.dtype(dtype)
@@ -220,33 +271,40 @@ def main(argv=None) -> int:
             draw = lambda key, *dims: jax.random.normal(
                 key, dims, jnp.float32).astype(dt)
             qs = draw(keys[0], count, B, L, H * D)
-            k, v = draw(keys[1], B, L, H * D), draw(keys[2], B, L, H * Dv)
+            k = draw(keys[1], B, L, Hkv * D)
+            v = draw(keys[2], B, L, Hkv * Dv)
             g = draw(keys[3], B, L, H * Dv)
-            plan = this.attention_plan(L, L, H, D, Dv, dt, causal)
-            line = {"shape": shape, "dims": [B, L, H, D, Dv],
-                    "causal": causal, "dtype": dtype, "calls": count,
-                    "plan": plan._asdict(), "set": args.set,
+            plan = this.attention_plan(L, L, H, D, Dv, dt, causal,
+                                       kv_heads=Hkv)
+            line = {"shape": shape, "dims": [B, L, H, D, Dv, Hkv],
+                    "causal": causal, "window": window, "dtype": dtype,
+                    "calls": count,
+                    "plan": dict(plan._asdict(),
+                                 backward_kernels=plan.backward_kernels),
+                    "set": args.set,
                     "reps": args.reps,
                     "device": jax.devices()[0].device_kind}
             score_bytes = 4.0 * H * L * L
-            variants = {name: kernel_pair(module, causal)
+            variants = {name: kernel_pair(module, causal, window)
                         for name, module in modules.items()}
             if score_bytes <= args.xla_score_bytes:
                 variants["xla"] = xla_pair(
-                    causal, B * score_bytes > args.xla_score_bytes)
+                    causal, window, B * score_bytes > args.xla_score_bytes)
             else:
                 line["xla"] = "left out: %.1f GB of scores a sequence" % (
                     score_bytes / 1e9)
 
-            # the yardstick: float32 at `highest`, over a few heads
-            ch = min(args.check_heads, H)
-            cut = lambda x, e: split(x, e)[..., :ch, :].astype(jnp.float32)
-            few = (cut(qs[0], D), cut(k, D), cut(v, Dv), cut(g, Dv))
-            want = jax.jit(xla_pair(causal, True, "highest")[1])(*few)
-            want_out = jax.jit(xla_pair(causal, True, "highest")[0])(*few)
+            # the yardstick: float32 at `highest`, over a few key/value
+            # heads and the query heads that read them
+            ch = min(args.check_heads, Hkv) if group == 1 else 1
+            cut = lambda x, e, n: split(x, e)[..., :n, :].astype(jnp.float32)
+            few = (cut(qs[0], D, ch * group), cut(k, D, ch), cut(v, Dv, ch),
+                   cut(g, Dv, ch * group))
+            want_out, want = jax.jit(functools.partial(
+                yardstick, causal=causal, window=window))(*few)
 
             def gap(got, ref):
-                got = got[..., :ch, :].astype(jnp.float32)
+                got = got[..., :ref.shape[-2], :].astype(jnp.float32)
                 return float(jnp.max(jnp.abs(got - ref))
                              / jnp.max(jnp.abs(ref)))
 
