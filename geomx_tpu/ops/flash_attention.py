@@ -58,11 +58,13 @@ products) wherever a run of its key-major walk finishes a dq block:
   pair writes the scaled, transposed block there and Pallas copies it
   out when the run ends.  No partial dq crosses HBM.  The array costs
   `4 * L * heads * D` bytes (16 MiB for one 256-wide head of 16,384
-  rows), beside what VMEM_BUDGET counts: :func:`attention_plan` takes
-  the one kernel where that is at most MAX_RESIDENT_DQ (for grouped
-  heads the kernel's heads are whole groups, so a group of eight keeps
-  eight heads' dq^T: those calls stay with two kernels) and the call
-  raises Mosaic's VMEM limit over the plan's bytes.
+  rows; for grouped heads the kernel's heads are whole groups, so a
+  group of eight 128-wide heads keeps 32 MiB at 8,192 rows and 64 MiB
+  at 16,384), beside what VMEM_BUDGET counts, and the call raises
+  Mosaic's VMEM limit to the plan's bytes + _VMEM_HEADROOM:
+  :func:`attention_plan` takes the one kernel where that limit is at
+  most ONE_KERNEL_VMEM, a share of the core's VMEM, under a band as
+  without one.
 
 Every other call runs a dq kernel and a dk/dv kernel (seven products:
 `s^T` and `dP^T` twice).  Past one pair `delta` comes from XLA as rows of
@@ -99,11 +101,14 @@ _LANES = 128
 VMEM_BUDGET = 12 * 2 ** 20
 MAX_BLOCK = 512      # rows of q or k a step: a [512, 512] f32 score tile
 MAX_HEADS = 8        # heads a step: bounds the unrolled code
-# The float32 dq of every q block of a sequence, which ONE backward kernel
-# keeps in VMEM for its whole walk beside what VMEM_BUDGET counts (a v5e
-# has 128 MiB; the call raises Mosaic's limit over the plan's bytes).
-# 16 MiB: one 256-wide head of 16,384 rows, two 192-wide of 8,192.
-MAX_RESIDENT_DQ = 16 * 2 ** 20
+# What ONE backward kernel of several block pairs may ask Mosaic for in
+# all, since its call sets the limit itself: what it streams, the float32
+# dq^T of every q block that it keeps for its whole walk, _VMEM_HEADROOM.
+# Three quarters of a v5e core's 128 MiB: a group of eight 128-wide heads
+# at 16,384 rows asks for 92.5 MiB (64 of them dq^T), at 32,768 for 156.
+ONE_KERNEL_VMEM = 96 * 2 ** 20
+# over the plan's bytes, for what Mosaic keeps that the plan does not count
+_VMEM_HEADROOM = 16 * 2 ** 20
 
 
 class AttentionPlan(NamedTuple):
@@ -193,16 +198,17 @@ def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
     is lane-aligned too; the kernels that make dk and dv then take
     `max(heads, H // kv_heads)` query heads.
 
-    One backward kernel (`fused_backward`) where, with one q block's dq^T,
-    it fits VMEM_BUDGET for whole groups of heads and a run of its walk
-    finishes a dq block: one block pair is the whole sequence, or the
-    call is `causal` with `block_q == block_k` and `q_len == kv_len` (the
-    key-major walk opens each run on the diagonal pair, the last that
-    adds to that dq block) and the dq^T of every q block
-    (`resident_bytes`) is at most MAX_RESIDENT_DQ.  Else a dq kernel and
-    a dk/dv kernel.  Beyond that `causal` (and a window) choose no size:
-    they shorten the list of block pairs the grid walks
-    (:func:`_block_pairs`)."""
+    One backward kernel (`fused_backward`) where a run of its walk
+    finishes a dq block and it fits: one block pair is the whole sequence
+    and the kernel is one of those VMEM_BUDGET chooses the heads for
+    (whole groups of them); or the call is `causal` with `block_q ==
+    block_k` and `q_len == kv_len` (the key-major walk opens each run on
+    the diagonal pair, the last that adds to that dq block) and what the
+    kernel asks for in all, the bytes whole groups of heads stream, the
+    dq^T of every q block (`resident_bytes`) and _VMEM_HEADROOM, is at
+    most ONE_KERNEL_VMEM.  Else a dq kernel and a dk/dv kernel.  Beyond
+    that `causal` (and a window) choose no size: they shorten the list of
+    block pairs the grid walks (:func:`_block_pairs`)."""
     kv_heads = kv_heads or heads
     if heads % kv_heads:
         raise ValueError(f"{heads} query heads over {kv_heads} key/value "
@@ -230,13 +236,14 @@ def attention_plan(q_len: int, kv_len: int, heads: int, d: int, dv: int,
     fits = [g for g in slabs if fit(g)]
     g = max(fits) if fits else min(slabs)
     resident = _resident_dq_bytes(nq, bq, g, group, d)
+    if fused:       # one pair: its one block of dq^T is in cost(g)
+        return AttentionPlan(bq, bk, g, True, cost(g), resident)
     if not one_pair and causal and bq == bk and q_len == kv_len:
-        fused = (resident <= MAX_RESIDENT_DQ and _vmem_bytes(
-            bq, bk, g, group, d, dv, itemsize, True) <= VMEM_BUDGET)
-    if not fused:
-        return AttentionPlan(bq, bk, g, False, cost(g))
-    return AttentionPlan(bq, bk, g, True, cost(g) + resident - resident // nq,
-                         resident)
+        one_kernel = (_vmem_bytes(bq, bk, g, group, d, dv, itemsize, True)
+                      + resident - resident // nq)
+        if one_kernel + _VMEM_HEADROOM <= ONE_KERNEL_VMEM:
+            return AttentionPlan(bq, bk, g, True, one_kernel, resident)
+    return AttentionPlan(bq, bk, g, False, cost(g))
 
 
 def _band(window, causal: bool, kv_len: int):
@@ -540,8 +547,6 @@ def _grid_spec(pairs, grid, in_specs, out_specs, scratch_shapes):
 
 _SEMANTICS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "arbitrary"))
-# over the plan's bytes, for what Mosaic keeps that the plan does not count
-_VMEM_HEADROOM = 16 * 2 ** 20
 
 
 @functools.partial(jax.jit,

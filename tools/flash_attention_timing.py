@@ -27,10 +27,10 @@ widths and length read from ``benchmark/configs/glm-4.7-flash-ep8.json``),
 ``trinity-global`` 1 x 8,192 x 32 on 4 of 128 causal and ``mellum-global``
 1 x 16,384 x 32 on 4 of 128 causal, ``trinity-window`` and
 ``mellum-window`` the same under a band of 2,048 and of 1,024 keys (the
-grouped-query cells' layers, whose backward stays two kernels: the group of eight's dq^T is
-32 and 64 MiB and eight heads a step stream 15.2 MB; ``--set
-MAX_RESIDENT_DQ=67108864 --set VMEM_BUDGET=16777216`` times the one
-kernel there, ``--set MAX_RESIDENT_DQ=0`` the two kernels at any shape).
+grouped-query cells' layers, whose ONE backward kernel keeps the group of
+eight's dq^T, 32 and 64 MiB, and asks Mosaic for 60.5 and 92.5 MiB in
+all; ``--set ONE_KERNEL_VMEM=0`` times the two kernels at any shape, and
+a larger or smaller bound is defended here).
 
 One JSON line a shape and dtype on stdout: the plan
 (``attention_plan``: blocks, heads a step, ``backward_kernels`` one or
@@ -162,7 +162,7 @@ def main(argv=None) -> int:
                         metavar="NAME=INT", help="a constant of this "
                         "file's module to try another plan with "
                         "(VMEM_BUDGET, MAX_BLOCK, MAX_HEADS, "
-                        "MAX_RESIDENT_DQ)")
+                        "ONE_KERNEL_VMEM)")
     parser.add_argument("--interpret", action="store_true",
                         help="rehearse on the CPU: no times, kernels "
                              "interpreted")
