@@ -13,6 +13,7 @@ from geomx_tpu.models.kimi_linear import KimiLinearConfig, KimiLinearLM
 from geomx_tpu.models.mellum import MellumConfig, MellumLM
 from geomx_tpu.models.mlp import MLP, AlexNet
 from geomx_tpu.models.nemotron_h import NemotronHConfig, NemotronHLM
+from geomx_tpu.models.ouro import OuroConfig, OuroLM
 from geomx_tpu.models.resnet import (ResNet, ResNet18, ResNet20, ResNet32,
                                      ResNet56)
 from geomx_tpu.models.seq_classifier import SeqClassifier
@@ -21,7 +22,8 @@ __all__ = ["GeoCNN", "MLP", "AlexNet",
            "ResNet", "ResNet20", "ResNet32", "ResNet56", "ResNet18",
            "SeqClassifier", "KimiLinearConfig", "KimiLinearLM", "AfmoeConfig",
            "AfmoeLM", "NemotronHConfig", "NemotronHLM", "MellumConfig",
-           "MellumLM", "Glm4MoeLiteConfig", "Glm4MoeLiteLM", "get_model"]
+           "MellumLM", "Glm4MoeLiteConfig", "Glm4MoeLiteLM", "OuroConfig",
+           "OuroLM", "get_model"]
 
 # GEOMX_PRECISION -> the models' compute dtype.  Params always stay
 # fp32 (flax casts per-op from the fp32 masters); every model's
@@ -37,9 +39,9 @@ def get_model(name: str, num_classes: int = 10, precision: str = None,
     historical default (byte-identical traces).  ``sizes``: the fields of
     `KimiLinearConfig` for ``"kimi_linear"``, of `AfmoeConfig` for
     ``"afmoe"``, of `NemotronHConfig` for ``"nemotron_h"``, of
-    `MellumConfig` for ``"mellum"`` and of `Glm4MoeLiteConfig` for
-    ``"glm4_moe_lite"``, causal decoders that bring their own next-token
-    loss (no ``num_classes``)."""
+    `MellumConfig` for ``"mellum"``, of `Glm4MoeLiteConfig` for
+    ``"glm4_moe_lite"`` and of `OuroConfig` for ``"ouro"``, causal decoders
+    that bring their own next-token loss (no ``num_classes``)."""
     name = name.lower()
     dt = {}
     if precision is not None:
@@ -54,6 +56,8 @@ def get_model(name: str, num_classes: int = 10, precision: str = None,
         return MellumLM(MellumConfig(**sizes), **dt)
     if name == "glm4_moe_lite":
         return Glm4MoeLiteLM(Glm4MoeLiteConfig(**sizes), **dt)
+    if name == "ouro":
+        return OuroLM(OuroConfig(**sizes), **dt)
     if name in ("cnn", "geocnn", "lenet"):
         return GeoCNN(num_classes=num_classes, **dt)
     if name == "mlp":

@@ -18,7 +18,8 @@ This file holds the mixer and the configuration; the block, the
 feed-forward half, the router, the model and its blocked next-token loss
 are `models/decoder.py`'s, shared with `models/kimi_linear.py`.  The mixer
 is `models/mellum.py`'s too, which gives both kinds of layer positions (a
-table per kind) and no gate.
+table per kind) and no gate, and `models/ouro.py`'s, which also leaves the
+q/k norms out.
 
 Scopes (telemetry/layers.SCOPES): ``gqa/proj`` (the four products in, the
 q/k norms, rotary, the gate, the product out), ``gqa/window`` and
@@ -35,10 +36,10 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax.numpy as jnp
 
-from geomx_tpu.models.decoder import DecoderLM, HeadScale, _fan_in
+from geomx_tpu.models.decoder import DecoderLM, HeadScale, _fan_in, turn
 from geomx_tpu.ops import dispatch
 from geomx_tpu.ops.flash_attention import fused_attention
-from geomx_tpu.ops.gqa_elementwise import gated_ref
+from geomx_tpu.ops.gqa_elementwise import gated_ref, rotary_tables
 from geomx_tpu.utils.profiler import profile_scope
 
 
@@ -65,7 +66,9 @@ class GQAMixer(nn.Module):
     ``rope``: the layer's positions, rotate-half rotary on q and k from
     the tables `ops/gqa_elementwise.rotary_tables` makes of it: None (no
     positions at all), a theta, or a ``gqa_elementwise.Yarn``.  ``gated``
-    False: no gate kernel, the core's output goes to W_o as it is.  k and
+    False: no gate kernel, the core's output goes to W_o as it is.
+    ``qk_norm`` False: q and k are not normalised (no ``q_norm`` /
+    ``k_norm`` scales), rotary alone through `decoder.turn`.  k and
     v keep their ``num_kv_heads`` heads all the way into the kernels:
     query head n reads key/value head n // (heads / kv heads)."""
     num_heads: int
@@ -76,6 +79,7 @@ class GQAMixer(nn.Module):
     eps: float
     dtype: Any = jnp.float32
     gated: bool = True
+    qk_norm: bool = True
 
     @nn.compact
     def __call__(self, x):
@@ -88,10 +92,14 @@ class GQAMixer(nn.Module):
                 return jnp.dot(x, mat(name, (hidden, n * d))).reshape(
                     b, length, n, d)
 
-            q, k = dispatch.gqa_norm_rotary(
-                heads("q_kernel", h), heads("k_kernel", kv),
-                HeadScale(name="q_norm")(d), HeadScale(name="k_norm")(d),
-                self.eps, self.rope)
+            q, k = heads("q_kernel", h), heads("k_kernel", kv)
+            if self.qk_norm:
+                q, k = dispatch.gqa_norm_rotary(
+                    q, k, HeadScale(name="q_norm")(d),
+                    HeadScale(name="k_norm")(d), self.eps, self.rope)
+            elif self.rope is not None:
+                cos, sin = rotary_tables(length, d, self.rope)
+                q, k = (turn(y, cos[:, None], sin[:, None]) for y in (q, k))
             v = heads("v_kernel", kv)
             if self.gated:
                 gate = jnp.dot(x, mat("gate_kernel", (hidden, h * d)),

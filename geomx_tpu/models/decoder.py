@@ -22,7 +22,10 @@ output), `embedding_scale` and `expert_form` (further fields of
 LatentMoE gives ``latent``, ``gated`` False and ``shared_width``; a
 softmax router gives ``scoring``).  A configuration may also give
 ``mtp_depth`` (0 where it does not), with ``mtp_weight`` and ``mtp_block``
-((mixer, ffn) of a module's block): see `MTPModule`.
+((mixer, ffn) of a module's block): see `MTPModule`; and ``loops`` (1 where
+it does not) with ``exit_beta``: the whole stack applied ``loops`` times
+over the same parameters, a head pass and an exit gate a
+loop step (`DecoderLM.features`, `DecoderLM.looped_loss`).
 
 The model brings its own loss (`loss_and_aux`): mean next-token
 cross-entropy in float32, blocked over tokens so that no whole logits
@@ -33,9 +36,12 @@ its feed-forward half on its own.
 Scopes (telemetry/layers.SCOPES): ``mla/proj`` and ``mla/attention``
 (`LatentMixer`), ``moe/route``, ``moe/experts`` (inside it
 ``ops/held_experts``' own ``moe/plan`` and ``moe/dispatch``),
-``moe/shared``, ``moe/latent``, ``lm/loss``, ``mtp/module`` and inside it
-``mtp/combine``, and the other mixers' own.  Module names are ``mixer``,
-``ffn``, ``core``, ``norm``, ``post_norm``, ``block`` and ``mtp<k>`` so
+``moe/shared``, ``moe/latent``, ``ffn/mlp`` (the dense SwiGLU half),
+``lm/loss``, ``loop/exit`` (a looped stack's exit gate, distribution and
+entropy), ``mtp/module`` and inside it ``mtp/combine``, and the other
+mixers' own.  Module names are ``mixer``,
+``ffn``, ``core``, ``norm``, ``post_norm``, ``block``, ``exit_gate`` and
+``mtp<k>`` so
 that flax's own name stack never reads as one of them.
 """
 
@@ -322,7 +328,8 @@ class FFNBranch(nn.Module):
         c, dt = self.cfg, self.dtype
         x = RMSNorm(c.eps, name="norm")(h)
         if self.kind == "mlp":
-            y = MLP(c.dense_width, dt, name="core")(x)
+            with profile_scope("ffn/mlp", "compute"):
+                y = MLP(c.dense_width, dt, name="core")(x)
             counts, dropped = jnp.zeros((0,), jnp.int32), \
                 jnp.zeros((), jnp.int32)
         else:
@@ -404,6 +411,12 @@ class DecoderLM(nn.Module):
                                       (c.hidden, c.vocab))
         self.mtp = [MTPModule(c, self.dtype, name=f"mtp{k + 1}")
                     for k in range(getattr(c, "mtp_depth", 0))]
+        self.loops = getattr(c, "loops", 1)
+        if self.loops > 1:
+            if self.mtp:
+                raise ValueError("no multi-token prediction behind a looped "
+                                 "stack: which pass's stream would it read")
+            self.exit_gate = ExitGate(name="exit_gate")
 
     def embed(self, tokens):
         h = self.embedding.astype(self.dtype)[tokens.astype(jnp.int32)]
@@ -411,11 +424,10 @@ class DecoderLM(nn.Module):
             h = h * jnp.asarray(self.cfg.embedding_scale, self.dtype)
         return h
 
-    def features(self, tokens):
-        """(normed features [B, L, hidden], assignments that arrived at
-        each held expert of each expert layer, assignments dropped, the
-        stream before the final norm)."""
-        h = self.embed(tokens)
+    def stack(self, h):
+        """One pass of the blocks and the final norm: (normed stream,
+        assignments that arrived at each held expert of each expert layer,
+        assignments dropped, the stream before the final norm)."""
         arrived, dropped = [], jnp.zeros((), jnp.int32)
         for block in self.blocks:
             h, counts, lost = block(h)
@@ -423,13 +435,43 @@ class DecoderLM(nn.Module):
             dropped = dropped + lost
         return self.final_norm(h), jnp.concatenate(arrived), dropped, h
 
+    def features(self, tokens):
+        """(normed features [B, L, hidden], assignments that arrived at
+        each held expert of each expert layer, assignments dropped, the
+        stream before the final norm).  With ``loops`` T > 1 the stack runs
+        T times over the same parameters, the NORMED stream of a pass the
+        input of the next, and the features are the T normed streams
+        [T, B, L, hidden] (the counts those of the T passes, the last
+        pass's stream before its norm).  The T passes stand in the program
+        one after the other: as one scan over the loop steps the step
+        compiles in 38 s and not 64 and holds 1.35 GiB less, but it is a
+        fifth slower (0.865 against 1.033 samples/s/chip at 4 x 6 blocks
+        of 8,192 tokens: inside a `while` every mixer half really is
+        rematerialised, where XLA here shares a pass's attention with its
+        rematerialised copy; PERF.md section 6, PR 48)."""
+        h = self.embed(tokens)
+        if self.loops == 1:
+            return self.stack(h)
+        streams, arrived, dropped = [], [], jnp.zeros((), jnp.int32)
+        for _ in range(self.loops):
+            h, counts, lost, before = self.stack(h)
+            streams.append(h)
+            arrived.append(counts)
+            dropped = dropped + lost
+        return jnp.stack(streams), jnp.concatenate(arrived), dropped, before
+
     def __call__(self, tokens, train: bool = False):
-        """Whole logits [B, L, vocab] in float32: init, eval, small
-        inputs.  Training takes `loss_and_aux`."""
+        """Whole logits [B, L, vocab] in float32 (a looped stack's: the
+        last pass's): init, eval, small inputs.  Training takes
+        `loss_and_aux`."""
         h, _, _, stream = self.features(tokens)
         if self.is_initializing():      # the modules' parameters exist too
             for module in self.mtp:
                 stream = module(stream, self.embed(tokens))[0]
+        if self.loops > 1:
+            if self.is_initializing():
+                self.exit_gate(h)
+            h = h[-1]
         return jnp.dot(h, self.head_kernel.astype(self.dtype),
                        preferred_element_type=jnp.float32)
 
@@ -477,6 +519,8 @@ class DecoderLM(nn.Module):
         and ``mtp/accuracy``, and the expert counters count the modules'
         expert layers with the others."""
         h, arrived, dropped, stream = self.features(tokens)
+        if self.loops > 1:
+            return self.looped_loss(h, labels)
         with profile_scope("lm/loss", "compute"):
             total, hits = blocked_cross_entropy(
                 h.reshape(-1, h.shape[-1]),
@@ -512,11 +556,78 @@ class DecoderLM(nn.Module):
             aux["counters"] = counters
         return loss, aux
 
+    def looped_loss(self, streams, labels):
+        """The expected-exit loss of a looped stack (arXiv:2510.25741,
+        stage I under a uniform prior) over its T normed streams
+        [T, B, L, hidden]:
 
-def blocked_cross_entropy(h, head, labels, block: int):
+            L = mean_i [ sum_t p^t_i ce^t_i - exit_beta H(p_i) ]
+
+        ``ce^t_i`` the next-token cross-entropy of step t's logits, ``p_i``
+        token i's exit distribution (`exit_distribution` of the shared
+        gate's logits on the normed streams), ``H`` its entropy, all in
+        float32.  ONE blocked pass over the T x tokens rows of the head.
+        ``accuracy`` is the last step's; the counters hold each step's
+        mean cross-entropy and mean exit mass, the mean entropy and the
+        expected loss without the entropy term."""
+        loops, tokens = self.loops, labels.size
+        with profile_scope("loop/exit", "compute"):
+            log_p = exit_distribution(
+                self.exit_gate(streams).reshape(loops, tokens))
+            p = jnp.exp(log_p)
+            entropy = -jnp.sum(p * log_p) / tokens
+        with profile_scope("lm/loss", "compute"):
+            total, hits, sums = blocked_cross_entropy(
+                streams.reshape(loops * tokens, -1),
+                self.head_kernel.astype(self.dtype),
+                jnp.tile(labels.reshape(-1), loops), self.cfg.loss_block,
+                p.reshape(-1), loops)
+        expected = total / tokens
+        counters = {"lm/main_loss": expected, "loop/exit_entropy": entropy}
+        masses = jnp.mean(p, axis=1)
+        for t in range(loops):
+            counters[f"loop/loss_{t + 1}"] = sums[t] / tokens
+            counters[f"loop/exit_mass_{t + 1}"] = masses[t]
+        return expected - self.cfg.exit_beta * entropy, {
+            "accuracy": hits[-1] / tokens, "counters": counters}
+
+
+class ExitGate(nn.Module):
+    """A looped stack's exit gate: one linear map hidden -> 1 with a bias,
+    shared by the loop steps, on the normed streams; logits in float32."""
+
+    @nn.compact
+    def __call__(self, h):
+        kernel = self.param("kernel", _fan_in, (h.shape[-1], 1))
+        bias = self.param("bias", nn.initializers.zeros, (1,))
+        return jnp.dot(h.astype(jnp.float32), kernel[:, 0],
+                       precision=_HIGHEST) + bias[0]
+
+
+def exit_distribution(a):
+    """log p [T, N] of gate logits a [T, N]: with lambda^t = sigmoid(a^t),
+    p^t = lambda^t prod_{j<t} (1 - lambda^j) for t < T and the last step
+    takes the rest, p^T = prod_{j<T} (1 - lambda^j).  From `log_sigmoid`
+    of a and of -a, so that no log 0 arises; a token's masses sum to 1."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-a[:-1]), axis=0)   # log prod(1-l)
+    before = jnp.concatenate([jnp.zeros_like(a[:1]), stay[:-1]])
+    return jnp.concatenate(
+        [jax.nn.log_sigmoid(a[:-1]) + before, stay[-1:]])
+
+
+def blocked_cross_entropy(h, head, labels, block: int, weights=None,
+                          groups: int = 1):
     """(sum of cross-entropies, number of argmax hits) over tokens h
     [T, d], ``block`` tokens at a time: a block's float32 logits are the
-    most that lives, forward and (rematerialised) backward."""
+    most that lives, forward and (rematerialised) backward.
+
+    With ``weights`` [T] (float32): (sum of weight x cross-entropy,
+    differentiable in the weights too; the hits [groups]; the unweighted
+    sums [groups]) of the rows' ``groups`` equal runs (a looped stack's
+    steps), each run blocked on its own."""
+    if weights is not None:
+        return _weighted_cross_entropy(h, head, labels, block, weights,
+                                       groups)
     t = h.shape[0]
     block = min(block, t)
     pad = (-t) % block
@@ -526,14 +637,8 @@ def blocked_cross_entropy(h, head, labels, block: int):
 
     @jax.checkpoint
     def one(carry, xs):
-        h_, y_ = xs
-        logits = jnp.dot(h_, head, preferred_element_type=jnp.float32)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(
-            logits, jnp.maximum(y_, 0)[:, None], axis=-1)[:, 0]
-        real = y_ >= 0
-        hits = jnp.sum(real & (jnp.argmax(logits, -1) == y_))
-        return (carry[0] + jnp.sum(jnp.where(real, logz - picked, 0.0)),
+        each, hits = _block_losses(head, *xs)
+        return (carry[0] + jnp.sum(each),
                 carry[1] + hits.astype(jnp.float32)), None
 
     (total, hits), _ = lax.scan(
@@ -541,3 +646,39 @@ def blocked_cross_entropy(h, head, labels, block: int):
         (h.reshape(-1, block, h.shape[-1]),
          labels.astype(jnp.int32).reshape(-1, block)))
     return total, hits
+
+
+def _block_losses(head, h_, y_):
+    """(cross-entropy of each row, 0 where its label is negative; argmax
+    hits) of one block of rows: the block's float32 logits live here."""
+    logits = jnp.dot(h_, head, preferred_element_type=jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(
+        logits, jnp.maximum(y_, 0)[:, None], axis=-1)[:, 0]
+    real = y_ >= 0
+    hits = jnp.sum(real & (jnp.argmax(logits, -1) == y_))
+    return jnp.where(real, logz - picked, 0.0), hits
+
+
+def _weighted_cross_entropy(h, head, labels, block, weights, groups):
+    run = h.shape[0] // groups
+    block = min(block, run)
+    pad = (-run) % block
+    rows = lambda a, fill: jnp.pad(
+        a.reshape((groups, run) + a.shape[1:]),
+        ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 1),
+        constant_values=fill).reshape((-1, block) + a.shape[1:])
+
+    @jax.checkpoint
+    def one(carry, xs):
+        h_, y_, w_ = xs
+        each, hits = _block_losses(head, h_, y_)
+        return carry + jnp.sum(w_ * each), (
+            hits.astype(jnp.float32), jnp.sum(each))
+
+    total, (hits, sums) = lax.scan(
+        one, jnp.zeros((), jnp.float32),
+        (rows(h, 0), rows(labels.astype(jnp.int32), -1),
+         rows(weights.astype(jnp.float32), 0)))
+    per_group = lambda a: jnp.sum(a.reshape(groups, -1), axis=1)
+    return total, per_group(hits), per_group(sums)

@@ -18,8 +18,9 @@ The vocabulary (docs/telemetry.md has the operator's table):
   compression engine (compression/) inside ``step/sync_grads``;
 - ``<axis>_pipeline/*``: the pipelined sync engine (sync/pipeline.py);
 - ``collective/worker``, ``collective/dc``: the tier collectives;
-- ``kda/*``, ``mla/*``, ``gqa/*``, ``ssd/*``, ``moe/*``, ``lm/loss``,
-  ``mtp/*``: a decoder's layers inside ``step/forward_backward``
+- ``kda/*``, ``mla/*``, ``gqa/*``, ``ssd/*``, ``moe/*``, ``ffn/mlp``,
+  ``lm/loss``, ``loop/exit``, ``mtp/*``: a decoder's layers inside
+  ``step/forward_backward``
   (models/kimi_linear.py, models/afmoe.py, models/nemotron_h.py,
   models/decoder.py);
 - ``attn/core``: the attention kernels and what surrounds them
@@ -78,7 +79,13 @@ SCOPES = (
     ("moe/shared", "step program"),
     # a LatentMoE's down- and up-projection around its routed experts
     ("moe/latent", "step program"),
+    # the dense SwiGLU half of a block (models/decoder.FFNBranch)
+    ("ffn/mlp", "step program"),
     ("lm/loss", "step program"),
+    # a looped stack's exit gate: its product, the exit distribution and
+    # the entropy (models/decoder.DecoderLM.looped_loss); the T head passes
+    # stand under lm/loss
+    ("loop/exit", "step program"),
     # a multi-token-prediction module, around everything it runs (its
     # block's mla/*, moe/*, attn/core and its lm/loss nest inside and keep
     # their meaning), and inside it the join: two norms, the next token's

@@ -199,3 +199,49 @@ def test_v5e_compiler_accepts_the_fifth_decoders_step(chip):
         {"temp": memory.temp_size_in_bytes})
     assert (3 * memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 16.91e9)
+
+
+def test_v5e_compiler_accepts_the_looped_decoders_step(chip):
+    """`ouro-2.6b-6of48` as its family builds it, at the cell's sizes (one
+    sequence of 8,192 tokens, bf16; 509.7 M parameters, four loop steps):
+    the expected-exit loss the model brings and its gradient as one
+    program under the native kernels.  The four passes stand in the
+    program one after the other: the flash kernels at 16 equal heads of
+    128 are called 24 times forward and 24 times backward (the ONE
+    backward kernel); the 24 rematerialised forward calls are not there,
+    XLA shares each with the pass's own (as one scan over the loop steps
+    the program holds 12 + 6 calls and runs 48 + 24); and this program's
+    temporaries (the 24 applications' saved halves, the four normed
+    streams, a loss block, 7.72 GB as PR 48 compiled it) leave the fp32
+    weights and Adam's two moments (three times the arguments, 6.12 GB)
+    their place under the chip's 16.91 GB."""
+    import collections
+    import json
+    import os
+    from benchmark.cells import Registry
+    from geomx_tpu.ops import dispatch
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cell = Registry(root).cell("ouro26b-fsa-1c")
+    config = cell["config"]
+    model = cell["family"].build_model(config)
+    on = lambda leaf: jax.ShapeDtypeStruct(leaf.shape, leaf.dtype,
+                                           sharding=chip)
+    x = on(jax.ShapeDtypeStruct((1, config["sequence_length"]), jnp.int32))
+    params = jax.tree.map(on, jax.eval_shape(
+        model.init, jax.random.PRNGKey(0), x)["params"])
+    count = sum(leaf.size for leaf in jax.tree.leaves(params))
+    assert count == config["parameters"]["total"] == 509_661_185
+    step = jax.value_and_grad(lambda p, x_, y_: model.apply(
+        {"params": p}, x_, y_, method="loss_and_aux"), has_aux=True)
+    with dispatch.kernels("native"):
+        compiled = jax.jit(step).lower(params, x, x).compile()
+    calls = collections.Counter(
+        c.split(".")[0] for c in checks.kernel_calls(compiled.as_text()))
+    assert dict(calls) == {"flash_attention_fwd": 24,
+                           "flash_attention_bwd": 24}, calls
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes == pytest.approx(4 * count, rel=1e-3)
+    assert memory.temp_size_in_bytes < 8.2e9, json.dumps(
+        {"temp": memory.temp_size_in_bytes})
+    assert (3 * memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 16.91e9)

@@ -30,7 +30,10 @@ widths and length read from ``benchmark/configs/glm-4.7-flash-ep8.json``),
 grouped-query cells' layers, whose ONE backward kernel keeps the group of
 eight's dq^T, 32 and 64 MiB, and asks Mosaic for 60.5 and 92.5 MiB in
 all; ``--set ONE_KERNEL_VMEM=0`` times the two kernels at any shape, and
-a larger or smaller bound is defended here).
+a larger or smaller bound is defended here), ``ouro-full`` 1 x 8,192 x
+16 on 16 of 128 causal (the looped cell's layer, 24 applications a step:
+heads, width and length read from
+``benchmark/configs/ouro-2.6b-6of48.json``).
 
 One JSON line a shape and dtype on stdout: the plan
 (``attention_plan``: blocks, heads a step, ``backward_kernels`` one or
@@ -69,7 +72,7 @@ NAMED = {"bert": (16, 512, 16, 64, 64, False),
          "mellum-global": (1, 16384, 32, 128, 128, True, 4),
          "trinity-window": (1, 8192, 32, 128, 128, True, 4, 2048),
          "mellum-window": (1, 16384, 32, 128, 128, True, 4, 1024)}
-CALLS = {"bert": 24, "latent": 2, "mid": 4, "latent256": 1,
+CALLS = {"bert": 24, "latent": 2, "mid": 4, "latent256": 1, "ouro-full": 2,
          "trinity-global": 2, "mellum-global": 1,
          "trinity-window": 2, "mellum-window": 1}
 
@@ -86,6 +89,20 @@ def latent_shape(path):
 
 
 NAMED["latent256"] = latent_shape("benchmark/configs/glm-4.7-flash-ep8.json")
+
+
+def full_shape(path):
+    """(B, L, H, D, D, causal, key/value heads) of a configuration file's
+    full attention layers: `num_attention_heads` on `num_key_value_heads`
+    of `head_dim`, one sequence of `sequence_length`."""
+    with open(os.path.join(ROOT, path)) as f:
+        config = json.load(f)
+    return (1, config["sequence_length"], config["num_attention_heads"],
+            config["head_dim"], config["head_dim"], True,
+            config["num_key_value_heads"])
+
+
+NAMED["ouro-full"] = full_shape("benchmark/configs/ouro-2.6b-6of48.json")
 
 
 def load_other(ref, path):
