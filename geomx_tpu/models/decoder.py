@@ -29,9 +29,10 @@ loop step (`DecoderLM.features`, `DecoderLM.looped_loss`).
 
 The model brings its own loss (`loss_and_aux`): mean next-token
 cross-entropy in float32, blocked over tokens so that no whole logits
-array lives; `train/step.make_loss_fn` takes it from there.  In the
-backward pass each block's mixer is rematerialised a sequence at a time and
-its feed-forward half on its own.
+array lives, its gradient made in the same pass
+(`blocked_cross_entropy`); `train/step.make_loss_fn` takes it from
+there.  In the backward pass each block's mixer is rematerialised a
+sequence at a time and its feed-forward half on its own.
 
 Scopes (telemetry/layers.SCOPES): ``mla/proj`` and ``mla/attention``
 (`LatentMixer`), ``moe/route``, ``moe/experts`` (inside it
@@ -619,48 +620,18 @@ def blocked_cross_entropy(h, head, labels, block: int, weights=None,
                           groups: int = 1):
     """(sum of cross-entropies, number of argmax hits) over tokens h
     [T, d], ``block`` tokens at a time: a block's float32 logits are the
-    most that lives, forward and (rematerialised) backward.
+    most that lives.
 
     With ``weights`` [T] (float32): (sum of weight x cross-entropy,
     differentiable in the weights too; the hits [groups]; the unweighted
     sums [groups]) of the rows' ``groups`` equal runs (a looped stack's
-    steps), each run blocked on its own."""
-    if weights is not None:
-        return _weighted_cross_entropy(h, head, labels, block, weights,
-                                       groups)
-    t = h.shape[0]
-    block = min(block, t)
-    pad = (-t) % block
-    if pad:
-        h = jnp.pad(h, ((0, pad), (0, 0)))
-        labels = jnp.pad(labels, (0, pad), constant_values=-1)
+    steps), each run blocked on its own.
 
-    @jax.checkpoint
-    def one(carry, xs):
-        each, hits = _block_losses(head, *xs)
-        return (carry[0] + jnp.sum(each),
-                carry[1] + hits.astype(jnp.float32)), None
-
-    (total, hits), _ = lax.scan(
-        one, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-        (h.reshape(-1, block, h.shape[-1]),
-         labels.astype(jnp.int32).reshape(-1, block)))
-    return total, hits
-
-
-def _block_losses(head, h_, y_):
-    """(cross-entropy of each row, 0 where its label is negative; argmax
-    hits) of one block of rows: the block's float32 logits live here."""
-    logits = jnp.dot(h_, head, preferred_element_type=jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    picked = jnp.take_along_axis(
-        logits, jnp.maximum(y_, 0)[:, None], axis=-1)[:, 0]
-    real = y_ >= 0
-    hits = jnp.sum(real & (jnp.argmax(logits, -1) == y_))
-    return jnp.where(real, logz - picked, 0.0), hits
-
-
-def _weighted_cross_entropy(h, head, labels, block, weights, groups):
+    Differentiated, the one pass over the blocks makes the gradient
+    beside the loss (`_blocked_losses`): three head products a block
+    and nothing left for the backward pass but a scale.  Only the first
+    output carries a gradient: the hits and the groups' sums are
+    counters, and their cotangents are dropped."""
     run = h.shape[0] // groups
     block = min(block, run)
     pad = (-run) % block
@@ -668,17 +639,86 @@ def _weighted_cross_entropy(h, head, labels, block, weights, groups):
         a.reshape((groups, run) + a.shape[1:]),
         ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 1),
         constant_values=fill).reshape((-1, block) + a.shape[1:])
-
-    @jax.checkpoint
-    def one(carry, xs):
-        h_, y_, w_ = xs
-        each, hits = _block_losses(head, h_, y_)
-        return carry + jnp.sum(w_ * each), (
-            hits.astype(jnp.float32), jnp.sum(each))
-
-    total, (hits, sums) = lax.scan(
-        one, jnp.zeros((), jnp.float32),
-        (rows(h, 0), rows(labels.astype(jnp.int32), -1),
-         rows(weights.astype(jnp.float32), 0)))
+    total, hits, sums = _blocked_losses(
+        rows(h, 0), head, rows(labels.astype(jnp.int32), -1),
+        None if weights is None else rows(weights.astype(jnp.float32), 0))
+    if weights is None:
+        return total, jnp.sum(hits)
     per_group = lambda a: jnp.sum(a.reshape(groups, -1), axis=1)
     return total, per_group(hits), per_group(sums)
+
+
+def _block_losses(head, h_, y_):
+    """(cross-entropy of each row, 0 where its label is negative; argmax
+    hits; the logits; their logsumexp) of one block of rows: the block's
+    float32 logits live here."""
+    logits = jnp.dot(h_, head, preferred_element_type=jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    picked = jnp.take_along_axis(
+        logits, jnp.maximum(y_, 0)[:, None], axis=-1)[:, 0]
+    real = y_ >= 0
+    hits = jnp.sum(real & (jnp.argmax(logits, -1) == y_))
+    return jnp.where(real, logz - picked, 0.0), hits, logits, logz
+
+
+@jax.custom_vjp
+def _blocked_losses(h, head, y, w):
+    """(sum of [weight x] cross-entropy, hits by block, unweighted sums
+    by block) of blocked rows h [n, block, d], labels y [n, block] and
+    weights w [n, block] or None: one head product a block."""
+    def one(_, xs):
+        each, hits = _block_losses(head, xs[0], xs[1])[:2]
+        return None, _block_sums(each, hits, xs[2])
+
+    _, (totals, hits, sums) = lax.scan(one, None, (h, y, w))
+    return jnp.sum(totals), hits, sums
+
+
+def _block_sums(each, hits, w_):
+    """A block's (sum of [weight x] cross-entropy, hits, unweighted sum)."""
+    return (jnp.sum(each if w_ is None else w_ * each),
+            hits.astype(jnp.float32), jnp.sum(each))
+
+
+def _blocked_losses_fwd(h, head, y, w):
+    """The same pass with the gradient made beside the loss, while a
+    block's logits live: g = softmax - onehot, zero on a row whose label
+    is negative and times the row's weight, enters ``dh_ = g head^T`` and
+    ``dhead += h_^T g`` in the head's dtype (what the MXU makes of a
+    float32 operand at default precision); the products add up in
+    float32, dhead over the blocks in the head's dtype."""
+    def one(dhead, xs):
+        h_, y_, w_ = xs
+        each, hits, logits, logz = _block_losses(head, h_, y_)
+        real = y_ >= 0
+        scale = real.astype(jnp.float32) if w is None else jnp.where(
+            real, w_, 0.0)
+        p = jnp.exp(logits - logz[:, None])
+        onehot = y_[:, None] == lax.broadcasted_iota(
+            jnp.int32, logits.shape, 1)
+        g = (scale[:, None] * jnp.where(onehot, p - 1.0, p)).astype(
+            head.dtype)
+        dh_ = lax.dot_general(g, head, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        dhead = dhead + lax.dot_general(
+            h_, g, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(head.dtype)
+        return dhead, _block_sums(each, hits, w_) + (
+            dh_.astype(h.dtype), None if w is None else each)
+
+    dhead, (totals, hits, sums, dh, each) = lax.scan(
+        one, jnp.zeros_like(head), (h, y, w))
+    return (jnp.sum(totals), hits, sums), (dh, dhead, each)
+
+
+def _blocked_losses_bwd(residuals, cotangents):
+    """The cotangent of the total scales what the forward rule made; no
+    product and no pass over logits."""
+    dh, dhead, each = residuals
+    ct = cotangents[0]
+    scaled = lambda a: (ct * a.astype(jnp.float32)).astype(a.dtype)
+    return (scaled(dh), scaled(dhead), None,
+            None if each is None else ct * each)
+
+
+_blocked_losses.defvjp(_blocked_losses_fwd, _blocked_losses_bwd)

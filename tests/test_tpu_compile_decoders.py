@@ -201,22 +201,12 @@ def test_v5e_compiler_accepts_the_fifth_decoders_step(chip):
             < 16.91e9)
 
 
-def test_v5e_compiler_accepts_the_looped_decoders_step(chip):
-    """`ouro-2.6b-6of48` as its family builds it, at the cell's sizes (one
-    sequence of 8,192 tokens, bf16; 509.7 M parameters, four loop steps):
-    the expected-exit loss the model brings and its gradient as one
-    program under the native kernels.  The four passes stand in the
-    program one after the other: the flash kernels at 16 equal heads of
-    128 are called 24 times forward and 24 times backward (the ONE
-    backward kernel); the 24 rematerialised forward calls are not there,
-    XLA shares each with the pass's own (as one scan over the loop steps
-    the program holds 12 + 6 calls and runs 48 + 24); and this program's
-    temporaries (the 24 applications' saved halves, the four normed
-    streams, a loss block, 7.72 GB as PR 48 compiled it) leave the fp32
-    weights and Adam's two moments (three times the arguments, 6.12 GB)
-    their place under the chip's 16.91 GB."""
-    import collections
-    import json
+@functools.lru_cache(maxsize=None)
+def looped_step(chip):
+    """`ouro-2.6b-6of48` as its family builds it, at the cell's sizes: the
+    loss the model brings and its gradient as one program under the
+    native kernels, compiled once for the cases below: (the number of
+    parameters, the compiled program)."""
     import os
     from benchmark.cells import Registry
     from geomx_tpu.ops import dispatch
@@ -234,7 +224,27 @@ def test_v5e_compiler_accepts_the_looped_decoders_step(chip):
     step = jax.value_and_grad(lambda p, x_, y_: model.apply(
         {"params": p}, x_, y_, method="loss_and_aux"), has_aux=True)
     with dispatch.kernels("native"):
-        compiled = jax.jit(step).lower(params, x, x).compile()
+        return count, jax.jit(step).lower(params, x, x).compile()
+
+
+def test_v5e_compiler_accepts_the_looped_decoders_step(chip):
+    """`ouro-2.6b-6of48` as its family builds it, at the cell's sizes (one
+    sequence of 8,192 tokens, bf16; 509.7 M parameters, four loop steps):
+    the expected-exit loss the model brings and its gradient as one
+    program under the native kernels.  The four passes stand in the
+    program one after the other: the flash kernels at 16 equal heads of
+    128 are called 24 times forward and 24 times backward (the ONE
+    backward kernel); the 24 rematerialised forward calls are not there,
+    XLA shares each with the pass's own (as one scan over the loop steps
+    the program holds 12 + 6 calls and runs 48 + 24); and this program's
+    temporaries (the 24 applications' saved halves, the four normed
+    streams, a loss block with its gradient's two products, 7.72 GB as
+    PRs 48 and 49 compiled it) leave the fp32 weights and Adam's two
+    moments (three times the arguments, 6.12 GB) their place under the
+    chip's 16.91 GB."""
+    import collections
+    import json
+    count, compiled = looped_step(chip)
     calls = collections.Counter(
         c.split(".")[0] for c in checks.kernel_calls(compiled.as_text()))
     assert dict(calls) == {"flash_attention_fwd": 24,
@@ -245,3 +255,24 @@ def test_v5e_compiler_accepts_the_looped_decoders_step(chip):
         {"temp": memory.temp_size_in_bytes})
     assert (3 * memory.argument_size_in_bytes + memory.temp_size_in_bytes
             < 16.91e9)
+
+
+def test_the_looped_decoders_loss_makes_its_gradient_going_forward(chip):
+    """Under `lm/loss` the same program holds ONE scan over the 16 blocks
+    of 2,048 rows (4 steps x 8,192 tokens) with three products in its
+    body: the block's float32 logits [2048, 49152], dhead's [2048, 49152]
+    (hidden 2,048 is a block's rows too) and dh's [2048, 2048], all three
+    in the forward half of the scope.  The backward half (JAX's
+    `transpose(`) holds no product and no `while`: it scales what the
+    forward made, so no second pass makes a block's logits."""
+    import re
+    text = looped_step(chip)[1].as_text()
+    under = r'[^\n]*op_name="([^"]*lm/loss[^"]*)"'
+    products = re.findall(
+        r"= (\w+)\[([\d,]*)\]\S* convolution\(" + under, text)
+    assert sorted((dtype, shape) for dtype, shape, _ in products) == [
+        ("f32", "2048,2048"), ("f32", "2048,49152"), ("f32", "2048,49152")]
+    loops = re.findall(r" while\(" + under, text)
+    assert len(loops) == 1, loops
+    assert not [name for *_, name in products if "transpose(" in name]
+    assert "transpose(" not in loops[0]
