@@ -38,6 +38,9 @@ Scopes (telemetry/layers.SCOPES): ``mla/proj`` and ``mla/attention``
 (`LatentMixer`), ``moe/route``, ``moe/experts`` (inside it
 ``ops/held_experts``' own ``moe/plan`` and ``moe/dispatch``),
 ``moe/shared``, ``moe/latent``, ``ffn/mlp`` (the dense SwiGLU half),
+``block/norm`` (the residual stream's norms and adds: a half's ``norm``,
+``post_norm`` and ``h + y``, the final norm, a prediction module's output
+norm), ``lm/embed`` (the lookup, its multiplier and cast),
 ``lm/loss``, ``loop/exit`` (a looped stack's exit gate, distribution and
 entropy), ``mtp/module`` and inside it ``mtp/combine``, and the other
 mixers' own.  Module names are ``mixer``,
@@ -309,11 +312,13 @@ class MixerBranch(nn.Module):
     @nn.compact
     def __call__(self, carry, h):
         c = self.cfg
-        x = RMSNorm(c.eps, name="norm")(h[None])
+        with profile_scope("block/norm", "compute"):
+            x = RMSNorm(c.eps, name="norm")(h[None])
         y = c.make_mixer(self.kind, self.dtype)(x)
-        if c.post_norms:
-            y = RMSNorm(c.eps, name="post_norm")(y)
-        return carry, h + y[0]
+        with profile_scope("block/norm", "compute"):
+            if c.post_norms:
+                y = RMSNorm(c.eps, name="post_norm")(y)
+            return carry, h + y[0]
 
 
 class FFNBranch(nn.Module):
@@ -327,7 +332,8 @@ class FFNBranch(nn.Module):
     @nn.compact
     def __call__(self, h):
         c, dt = self.cfg, self.dtype
-        x = RMSNorm(c.eps, name="norm")(h)
+        with profile_scope("block/norm", "compute"):
+            x = RMSNorm(c.eps, name="norm")(h)
         if self.kind == "mlp":
             with profile_scope("ffn/mlp", "compute"):
                 y = MLP(c.dense_width, dt, name="core")(x)
@@ -339,9 +345,10 @@ class FFNBranch(nn.Module):
                 c.expert_width, c.routed_scaling, c.shared_experts,
                 c.expert_rows, dt, c.expert_pool, name="core",
                 **c.expert_form)(x)
-        if c.post_norms:
-            y = RMSNorm(c.eps, name="post_norm")(y)
-        return h + y, counts, dropped
+        with profile_scope("block/norm", "compute"):
+            if c.post_norms:
+                y = RMSNorm(c.eps, name="post_norm")(y)
+            return h + y, counts, dropped
 
 
 class Block(nn.Module):
@@ -394,7 +401,9 @@ class MTPModule(nn.Module):
             h = jnp.dot(joined, self.param(
                 "join_kernel", _fan_in, (2 * hidden, hidden)).astype(dt))
         h, counts, dropped = Block(*c.mtp_block, c, dt, name="block")(h)
-        return h, RMSNorm(c.eps, name="out_norm")(h), counts, dropped
+        with profile_scope("block/norm", "compute"):
+            normed = RMSNorm(c.eps, name="out_norm")(h)
+        return h, normed, counts, dropped
 
 
 class DecoderLM(nn.Module):
@@ -420,10 +429,11 @@ class DecoderLM(nn.Module):
             self.exit_gate = ExitGate(name="exit_gate")
 
     def embed(self, tokens):
-        h = self.embedding.astype(self.dtype)[tokens.astype(jnp.int32)]
-        if self.cfg.embedding_scale != 1.0:
-            h = h * jnp.asarray(self.cfg.embedding_scale, self.dtype)
-        return h
+        with profile_scope("lm/embed", "compute"):
+            h = self.embedding.astype(self.dtype)[tokens.astype(jnp.int32)]
+            if self.cfg.embedding_scale != 1.0:
+                h = h * jnp.asarray(self.cfg.embedding_scale, self.dtype)
+            return h
 
     def stack(self, h):
         """One pass of the blocks and the final norm: (normed stream,
@@ -434,7 +444,9 @@ class DecoderLM(nn.Module):
             h, counts, lost = block(h)
             arrived.append(counts)
             dropped = dropped + lost
-        return self.final_norm(h), jnp.concatenate(arrived), dropped, h
+        with profile_scope("block/norm", "compute"):
+            normed = self.final_norm(h)
+        return normed, jnp.concatenate(arrived), dropped, h
 
     def features(self, tokens):
         """(normed features [B, L, hidden], assignments that arrived at

@@ -19,8 +19,8 @@ The vocabulary (docs/telemetry.md has the operator's table):
 - ``<axis>_pipeline/*``: the pipelined sync engine (sync/pipeline.py);
 - ``collective/worker``, ``collective/dc``: the tier collectives;
 - ``kda/*``, ``mla/*``, ``gqa/*``, ``ssd/*``, ``moe/*``, ``ffn/mlp``,
-  ``lm/loss``, ``loop/exit``, ``mtp/*``: a decoder's layers inside
-  ``step/forward_backward``
+  ``block/norm``, ``lm/embed``, ``lm/loss``, ``loop/exit``, ``mtp/*``: a
+  decoder's layers inside ``step/forward_backward``
   (models/kimi_linear.py, models/afmoe.py, models/nemotron_h.py,
   models/decoder.py);
 - ``attn/core``: the attention kernels and what surrounds them
@@ -81,6 +81,15 @@ SCOPES = (
     ("moe/latent", "step program"),
     # the dense SwiGLU half of a block (models/decoder.FFNBranch)
     ("ffn/mlp", "step program"),
+    # the residual stream's norms and adds: a half's norm, post-norm and
+    # ``h + y``, the final norm, a prediction module's output norm
+    # (models/decoder.py; a mixer's own norms stay in its ``*/proj``).  A
+    # fusion carries its root's op_name: a norm XLA folds into a
+    # neighbour's product is charged there, not here
+    ("block/norm", "step program"),
+    # the embedding's row gather, multiplier and cast, and through the
+    # name stack the scatter-add of its gradient (DecoderLM.embed)
+    ("lm/embed", "step program"),
     ("lm/loss", "step program"),
     # a looped stack's exit gate: its product, the exit distribution and
     # the entropy (models/decoder.DecoderLM.looped_loss); the T head passes
@@ -116,12 +125,23 @@ SCOPES = (
 )
 
 FORWARD_BACKWARD = "step/forward_backward"
+# the three passes under step/forward_backward (OpLayer.pass_): the first
+# forward, the forward recomputed inside the backward (JAX names it
+# ``rematted_computation`` inside ``transpose(``: jax.checkpoint's
+# transpose rule), and the backward proper
+FIRST, RECOMPUTED, BACKWARD = "first", "recomputed", "backward"
+PASSES = (FIRST, RECOMPUTED, BACKWARD)
+_REMATTED = "rematted_computation"
 SYNC_GRADS = "step/sync_grads"
 OPTIMIZER = "step/optimizer"
 
 # the phases of one iteration of ``Trainer.fit``, in order
 FIT_PHASES = ("fit/next_batch", "fit/dispatch", "fit/log_sync",
               "fit/log_fn", "fit/eval")
+DISPATCH, LOG_SYNC = "fit/dispatch", "fit/log_sync"
+# no phase: the device with nothing queued, across the phases from a log
+# boundary to the next dispatch (LoopStats.drained)
+DRAINED = "fit/drained"
 
 FIRST_DISPATCH = "fit/first_dispatch"
 FIRST_BOUNDARY = "fit/first_boundary"
@@ -154,10 +174,13 @@ class OpLayer(NamedTuple):
     ``layer``: the innermost scope's layer;
     ``direction``: ``forward`` / ``backward`` under
     ``step/forward_backward`` (JAX's own ``transpose(`` wrapper marks the
-    backward pass), else None."""
+    backward pass), else None: ``backward`` holds the recomputed forward;
+    ``pass_``: one of :data:`PASSES` under ``step/forward_backward``, which
+    tells the recomputed forward from both, else None."""
     scope: Optional[str]
     layer: Optional[str]
     direction: Optional[str]
+    pass_: Optional[str] = None
 
 
 UNSCOPED = OpLayer("", None, None)
@@ -166,7 +189,9 @@ UNNAMED = OpLayer(None, None, None)
 
 def classify_op_name(op_name: str) -> OpLayer:
     """``jit(step)/step/forward_backward/transpose(jvp())/dot_general`` ->
-    ``("step/forward_backward", "step program", "backward")``."""
+    ``("step/forward_backward", "step program", "backward", "backward")``;
+    with ``/checkpoint/rematted_computation/`` behind the ``transpose(``
+    the pass is ``recomputed`` and the direction still ``backward``."""
     parts = op_name.split("/")
     found, layer = [], None
     i = 0
@@ -181,10 +206,12 @@ def classify_op_name(op_name: str) -> OpLayer:
         i += 2
     if not found:
         return UNSCOPED
-    direction = None
+    direction = pass_ = None
     if found[0] == FORWARD_BACKWARD:
         direction = "backward" if "transpose(" in op_name else "forward"
-    return OpLayer("/".join(found), layer, direction)
+        pass_ = (RECOMPUTED if _REMATTED in op_name
+                 else BACKWARD if direction == "backward" else FIRST)
+    return OpLayer("/".join(found), layer, direction, pass_)
 
 
 _COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
@@ -213,7 +240,7 @@ def op_layers(hlo_text: Iterable[str]) -> Dict[str, OpLayer]:
     ``op_name`` inside the body of a ``while`` or a branch of a
     ``conditional`` is the loop's own cost (the copies to and from fast
     memory that the compiler schedules around a scan's steps) and takes
-    the loop's scope, layer and direction; at entry level it stays
+    the loop's scope, layer, direction and pass; at entry level it stays
     :data:`UNNAMED`.
 
     ``hlo_text``: ``compiled.as_text()`` or any iterable of its lines (a
@@ -304,6 +331,18 @@ def open_phase():
     return stack[-1] if stack else (OUTSIDE, None)
 
 
+def _zero_phase() -> dict:
+    return {"count": 0, "total_s": 0.0, "max_s": 0.0, "max_step": -1}
+
+
+def _add_occurrence(rec: dict, seconds: float, step: int) -> None:
+    rec["count"] += 1
+    rec["total_s"] += seconds
+    if seconds > rec["max_s"]:
+        rec["max_s"] = seconds
+        rec["max_step"] = step
+
+
 class LoopStats:
     """Always-on counters of one ``Trainer.fit``: for each phase of
     :data:`FIT_PHASES` its count, total seconds, longest single
@@ -314,16 +353,23 @@ class LoopStats:
     phase opens carry it.  ``counters``: what the model counts in a step
     (``metrics["counters"]``: named scalars, e.g. the assignments an
     expert layer dropped), added up over the steps read at log
-    boundaries: count, total, last, max."""
+    boundaries: count, total, last, max.  ``drained`` (``fit/drained``,
+    kept like a phase): the device with nothing queued, from the return
+    of a ``fit/log_sync`` (the newest step's results are on the host) to
+    the return of the next ``fit/dispatch``, log_fn, eval and the wait for
+    the batch between them included; both ends are reads the two phases
+    make anyway, so a boundary costs nothing more and the steps between
+    boundaries one comparison."""
 
     def __init__(self):
         self.step = 0
         self.steps = 0
         self.wall_s = 0.0
-        self.phases = {name: {"count": 0, "total_s": 0.0, "max_s": 0.0,
-                              "max_step": -1} for name in FIT_PHASES}
+        self.phases = {name: _zero_phase() for name in FIT_PHASES}
+        self.drained = _zero_phase()
         self.counters: Dict[str, dict] = {}
         self._start = time.perf_counter()
+        self._drained_since: Optional[float] = None
 
     def count(self, values: dict) -> None:
         """One step's counters, as read at a log boundary."""
@@ -346,17 +392,19 @@ class LoopStats:
                 yield
             finally:
                 end = time.perf_counter()
-                rec = self.phases[name]
-                rec["count"] += 1
-                rec["total_s"] += end - begin
-                if end - begin > rec["max_s"]:
-                    rec["max_s"] = end - begin
-                    rec["max_step"] = self.step
+                _add_occurrence(self.phases[name], end - begin, self.step)
+                if name == LOG_SYNC:
+                    self._drained_since = end
+                elif name == DISPATCH and self._drained_since is not None:
+                    _add_occurrence(self.drained, end - self._drained_since,
+                                    self.step)
+                    self._drained_since = None
                 self.wall_s = end - self._start
 
     def as_dict(self) -> dict:
         out = {"steps": self.steps, "wall_s": self.wall_s,
-               "phases": {k: dict(v) for k, v in self.phases.items()}}
+               "phases": {k: dict(v) for k, v in self.phases.items()},
+               DRAINED: dict(self.drained)}
         if self.counters:
             out["counters"] = {k: dict(v) for k, v in self.counters.items()}
         return out

@@ -924,6 +924,8 @@ class Trainer:
                               "spent in each phase", ("phase",))
             for phase, rec in self.loop_stats.phases.items():
                 fam_l.labels(phase=phase).set(rec["total_s"])
+            fam_l.labels(phase=layers.DRAINED).set(
+                self.loop_stats.drained["total_s"])
         steps = iteration - self._telem_last_it
         if steps > 0:
             reg.counter("geomx_train_steps_total",

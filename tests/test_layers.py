@@ -93,14 +93,14 @@ def test_step_hlo_holds_the_scopes_and_the_table_reads_them(compression,
 def test_classify_op_name_and_op_layers_by_hand():
     c = layers.classify_op_name
     assert c("jit(step)/step/forward_backward/jvp()/dot_general") == (
-        "step/forward_backward", "step program", "forward")
+        "step/forward_backward", "step program", "forward", "first")
     assert c("jit(s)/shard_map/step/forward_backward/transpose(jvp())/"
              "transpose") == ("step/forward_backward", "step program",
-                              "backward")
+                              "backward", "backward")
     assert c("jit(s)/step/sync_grads/dc_allreduce/bucket12/bsc/select_pack/"
              "pallas_call") == (
         "step/sync_grads/dc_allreduce/bucket12/bsc/select_pack", "kernels",
-        None)
+        None, None)
     assert c("jit(s)/shard_map/add") == layers.UNSCOPED
     hlo = """HloModule jit_s, entry_computation_layout={(f32[4]{0})->f32[4]{0}}
 
@@ -185,10 +185,11 @@ ENTRY %main (a: (s32[], f32[4])) -> (s32[], f32[4]) {
 }
 """
     table = layers.op_layers(hlo)
-    outer = ("step/forward_backward", "step program", "forward")
+    outer = ("step/forward_backward", "step program", "forward", "first")
     assert table["while.1"] == table["copy.4"] == outer
     assert table["while.3"] == table["copy.6"] == outer
-    scan = ("step/forward_backward/kda/scan", "kernels", "backward")
+    scan = ("step/forward_backward/kda/scan", "kernels", "backward",
+            "backward")
     assert table["while.2"] == scan
     assert table["copy-start.9"] == table["copy-done.9"] == scan
     assert table["copy.1"] == table["while.5"] == table["copy.8"] \
@@ -276,7 +277,7 @@ def test_fit_phase_seconds_reach_the_registry_when_telemetry_is_on(data):
     fam = get_registry().get("geomx_fit_phase_seconds")
     got = {labels: child.value for labels, child in fam.children()}
     assert got[("fit/dispatch",)] > 0
-    assert set(p for (p,) in got) == set(layers.FIT_PHASES)
+    assert set(p for (p,) in got) == {*layers.FIT_PHASES, layers.DRAINED}
     reset_registry()
 
 

@@ -6,12 +6,19 @@ imports its `instructions_of`, because a PR that is no `benchmark` PR may
 not edit `benchmark/` to share it: ROADMAP D20).  Run on the chip:
 
     chiprun -- python3 tools/scope_ops.py --workload kimilinear-fsa-1c \
-        --seed <n> --scope kda/scan [--top 40]
+        --seed <n> --scope kda/scan [--top 40] [--exact]
 
-- SCOPE: the scope's sum a step, forward (rematerialised included) and
-  backward, and the instructions the compiler made inside the loops the
-  scope's callers run (no op name; the loop's scope is theirs);
-- PIECE lines: the time by direction and by the op name's tail after the
+``--exact``: only the instructions whose scope path holds ``--scope`` and
+nothing else, so ``--scope step/forward_backward --exact`` lists that
+scope's self time (what ``fwd_bwd_self_ms`` reads), the tails then from
+the name stack's last ``step/forward_backward`` on with ``layer<i>``
+dropped, so that the blocks fold together.
+
+- SCOPE: the scope's sum a step, by pass (first forward, recomputed
+  forward, backward: ``telemetry/layers.PASSES``), and the instructions
+  the compiler made inside the loops the scope's callers run (no op name;
+  the loop's scope is theirs);
+- PIECE lines: the time by pass and by the op name's tail after the
   scope (``while/body/dot_general``, ``exp``, ...), with the opcodes;
 - OP lines: the ``--top`` largest instructions;
 - AROUND: the unnamed instructions (copies, fast-memory prefetches) by
@@ -42,11 +49,15 @@ def op_names(hlo_text: str) -> dict:
     return out
 
 
-def tail_of(op_name: str, scope: str) -> str:
-    """The name stack after the scope, transposes and jvps dropped."""
-    tail = op_name.split(scope, 1)[-1].strip("/")
+def tail_of(op_name: str, scope: str, exact: bool = False) -> str:
+    """The name stack after the scope, transposes and jvps dropped; with
+    ``exact`` after its last occurrence, the blocks' names dropped too."""
+    if exact:
+        tail = re.sub(r"layer\d+/", "", op_name.rsplit(scope, 1)[-1])
+    else:
+        tail = op_name.split(scope, 1)[-1]
     return re.sub(r"(transpose|jvp|checkpoint|rematted_computation)\(|\)",
-                  "", tail) or "."
+                  "", tail.strip("/")) or "."
 
 
 def main(argv=None):
@@ -55,6 +66,7 @@ def main(argv=None):
     parser.add_argument("--seed", type=int, required=True)
     parser.add_argument("--scope", default="kda/scan")
     parser.add_argument("--top", type=int, default=40)
+    parser.add_argument("--exact", action="store_true")
     args = parser.parse_args(argv)
 
     import numpy as np
@@ -87,13 +99,18 @@ def main(argv=None):
     steps = trace["steps"]
     ms = lambda seconds: 1e3 * seconds / steps
 
+    if args.exact:
+        under = lambda scope: not scope.replace(args.scope, "").strip("/")
+    else:
+        under = lambda scope: args.scope in scope
     inside, homes = [], set()
     for name, seconds in trace["by_op_s"].items():
         entry = table.get(name)
-        if entry is not None and entry.scope and args.scope in entry.scope:
+        if entry is not None and entry.scope and under(entry.scope):
             opcode, result, home = where.get(name, ("?", "?", "?"))
-            inside.append((seconds, name, entry.direction, opcode, result,
-                           tail_of(names.get(name, ""), args.scope)
+            inside.append((seconds, name, entry.pass_ or entry.direction,
+                           opcode, result,
+                           tail_of(names.get(name, ""), args.scope, args.exact)
                            if name in names else "(unnamed)"))
             homes.add(home)
     inside.sort(reverse=True)
@@ -101,23 +118,23 @@ def main(argv=None):
         "scope": args.scope, "steps": steps,
         "ms": ms(sum(r[0] for r in inside)),
         **{d + "_ms": ms(sum(r[0] for r in inside if r[2] == d))
-           for d in ("forward", "backward")},
+           for d in layers.PASSES},
         "unnamed_ms": ms(sum(r[0] for r in inside if r[5] == "(unnamed)")),
         "instructions": len(inside)}))
     pieces = {}
-    for seconds, _name, direction, opcode, _result, tail in inside:
-        rec = pieces.setdefault((direction, tail), [0.0, 0, set()])
+    for seconds, _name, which, opcode, _result, tail in inside:
+        rec = pieces.setdefault((which, tail), [0.0, 0, set()])
         rec[0] += seconds
         rec[1] += 1
         rec[2].add(opcode)
-    for (direction, tail), (seconds, count, opcodes) in sorted(
+    for (which, tail), (seconds, count, opcodes) in sorted(
             pieces.items(), key=lambda kv: -kv[1][0])[:args.top]:
         print("PIECE " + json.dumps({
-            "ms": ms(seconds), "count": count, "direction": direction,
+            "ms": ms(seconds), "count": count, "pass": which,
             "tail": tail, "opcodes": sorted(opcodes)}))
-    for seconds, name, direction, opcode, result, tail in inside[:args.top]:
+    for seconds, name, which, opcode, result, tail in inside[:args.top]:
         print("OP " + json.dumps({
-            "ms": ms(seconds), "name": name, "direction": direction,
+            "ms": ms(seconds), "name": name, "pass": which,
             "opcode": opcode, "type": result, "tail": tail}))
     around = {}
     for name, seconds in trace["by_op_s"].items():
